@@ -1,0 +1,671 @@
+#!/usr/bin/env python3
+"""The quickest proof that the serving path still starts on the chip.
+
+One process (it owns the chip; the node runners' spawned network processes
+are JAX-free) drives the system's main path once through the entry points a
+user calls: a ``ValidatorNode`` with the HTTP endpoint and one ``WorkerNode``,
+``POST /request-model`` for qwen3-4b at its published widths and all 36
+layers (weights drawn from the job's seed), then ``POST /v1/generate`` — a
+cold greedy request, the same request again (prefix-cache hit), one that
+leaves the cached prefix mid-page (copy-on-write), four concurrent requests of different lengths (one longer than ``prefill_chunk``,
+one speculative) and one SSE stream read to ``[DONE]`` — under the repo's
+default ``MLConfig`` (continuous batching, int8 KV pages, page 16, 8 slots,
+speculative decode armed).
+
+It fails (exit code != 0, no result line) when JAX finds no accelerator, when
+any phase fails, or outside the repo. Phases:
+
+* ``kernels``: the three paged Pallas kernels against their ``jnp``
+  references on a small input at the model's head widths, bf16 / int8 /
+  packed-int4 pages.
+* ``serve`` (default, one chip): the traffic above, then the checks — the
+  worker advertises the accelerator; every request went through the
+  ``ContinuousEngine`` with the Pallas kernel in its lowered step program
+  (``tpu_custom_call``); the repeated greedy request reproduced its token
+  stream; every stream has the requested length; page conservation is clean;
+  no program was built after warm-up and ``jit_cache_sizes()`` did not move;
+  ``/stats`` shows int8 pages and prefix-cache hits.
+* ``--chips 4``: only the tensor-parallel path and what it is compared
+  with — the same traffic against a ``tensor_parallel=4`` deployment, the
+  per-device memory spread, the kernel and the collectives in the lowered
+  step, then the same requests replayed on a tp=1 engine of the same seed on
+  device 0 and the token streams compared.
+
+Timings printed on the way are smoke timings (cold compiles included), not
+benchmark numbers. The last line of stdout is the result the driver reads.
+"""
+
+from __future__ import annotations
+
+import argparse
+import gc
+import http.client
+import json
+import socket
+import sys
+import tempfile
+import threading
+import time
+
+MODEL = "qwen3-4b"
+# inline ModelConfig JSON for /request-model; None = the registry preset
+MODEL_CONFIG: dict | None = None
+PLATFORM = "tpu"  # what jax.devices()[0].platform must say
+SEQ_LEN = 4096  # context planned for (MLConfig.max_seq_len's default)
+SEED = 0  # the hosted job's weight seed (host_model's default)
+
+_LONG = (
+    "Summarise the following log for the on-call engineer. "
+    + "worker w3 page pool at 91 percent, slot 5 preempted for an "
+    "interactive arrival, prefix trie evicted 12 pages, decode chunk 8. " * 3
+)
+# (name, message, max_new_tokens, extra body fields)
+WARM = ("warm", "Tell me about the paged KV cache of this server, briefly.", 16, {})
+# shares WARM's first pages and leaves it mid-page: the copy-on-write path
+COUSIN = ("cousin", "Tell me about the paged KV cache of this server, at length.", 16, {})
+CONCURRENT = (
+    ("short", "Hello there.", 24, {}),
+    ("medium", "List three things a TPU does well, one line each please.", 16, {}),
+    ("long", _LONG, 32, {}),
+    ("spec", "la la la la la la la la la la la la la la la la la la", 12,
+     {"speculative": True}),
+)
+STREAM = ("stream", "Stream me a short answer about block tables.", 20, {})
+
+
+class SmokeFailure(Exception):
+    """A phase failed; the message says which check and what it saw."""
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise SmokeFailure(what)
+    print(f"  ok: {what}", flush=True)
+
+
+# -- HTTP ---------------------------------------------------------------
+def _http(port: int, method: str, path: str, body=None, timeout=600.0):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        payload = json.dumps(body).encode() if body is not None else None
+        headers = {"Content-Type": "application/json"} if payload else {}
+        conn.request(method, path, body=payload, headers=headers)
+        resp = conn.getresponse()
+        data = resp.read()
+    finally:
+        conn.close()
+    return resp.status, (json.loads(data) if data else {})
+
+
+def _sse(port: int, path: str, body: dict, timeout=600.0) -> list[str]:
+    """POST and return the SSE ``data:`` payloads in order."""
+    payload = json.dumps(body).encode()
+    with socket.create_connection(("127.0.0.1", port), timeout=timeout) as s:
+        s.sendall(
+            f"POST {path} HTTP/1.1\r\nHost: smoke\r\n"
+            "Content-Type: application/json\r\n"
+            f"Content-Length: {len(payload)}\r\n\r\n".encode() + payload
+        )
+        buf = b""
+        while chunk := s.recv(65536):
+            buf += chunk
+    head, _, rest = buf.partition(b"\r\n\r\n")
+    if b" 200 " not in head.split(b"\r\n", 1)[0]:
+        raise SmokeFailure(f"SSE request refused: {head[:200]!r}")
+    return [
+        blk.strip()[len("data: "):]
+        for blk in rest.decode().split("\n\n")
+        if blk.strip().startswith("data: ")
+    ]
+
+
+def _gen_body(message: str, n: int, extra: dict) -> dict:
+    return {"hf_name": MODEL, "message": message, "max_new_tokens": n,
+            "do_sample": False, **extra}
+
+
+# -- the deployment -------------------------------------------------------
+def tap_streams() -> list:
+    """Passive tap on ``ContinuousEngine.submit``: every request admitted
+    to a slot engine in this process, in order. The HTTP API returns text
+    (and the byte tokenizer of a seed-weight model drops most ids), so the
+    token-level checks read the engine's own request records instead. A
+    request served by the static fallback never shows up here."""
+    from tensorlink_tpu.engine.continuous import ContinuousEngine
+
+    seen: list = []
+    orig = ContinuousEngine.submit
+
+    def submit(self, prompt, **kw):
+        req = orig(self, prompt, **kw)
+        seen.append(req)
+        return req
+
+    ContinuousEngine.submit = submit
+    return seen
+
+
+def start_cluster(ml, tmp: str):
+    from tensorlink_tpu.core.config import ValidatorConfig, WorkerConfig
+    from tensorlink_tpu.nodes.runners import ValidatorNode, WorkerNode
+
+    common = dict(local_test=True, log_dir=f"{tmp}/logs", env_file=f"{tmp}/.env")
+    validator = ValidatorNode(ValidatorConfig(
+        endpoint=True, endpoint_port=0, key_dir=f"{tmp}/keys_v", ml=ml, **common
+    )).start()
+    try:
+        worker = WorkerNode(WorkerConfig(
+            seed_validators=[["127.0.0.1", validator.port]],
+            key_dir=f"{tmp}/keys_w", ml=ml, **common,
+        )).start()
+    except BaseException:
+        validator.stop()
+        raise
+    deadline = time.monotonic() + 30
+    while not validator.status()["peers"]:
+        if time.monotonic() > deadline:
+            stop_cluster(validator, worker)
+            raise SmokeFailure("worker never connected to the validator")
+        time.sleep(0.2)
+    ipc = type(validator.queues.cmd).__name__
+    print(f"ipc: {'native shm ring' if ipc == 'RingChannel' else 'mp.Queue'} "
+          f"({ipc})", flush=True)
+    return validator, worker
+
+
+def stop_cluster(validator, worker) -> None:
+    worker.stop()
+    validator.stop()
+
+
+def host_model(port: int) -> float:
+    t0 = time.monotonic()
+    body = {"hf_name": MODEL, "seq_len": SEQ_LEN}
+    if MODEL_CONFIG is not None:
+        body["config"] = MODEL_CONFIG
+    status, out = _http(port, "POST", "/request-model", body)
+    check(status == 200 and out.get("status") == "ready",
+          f"/request-model {MODEL} ready ({status} {out})")
+    _, hz = _http(port, "GET", "/healthz")
+    check(MODEL in hz.get("hosted_models", []), f"/healthz hosts {MODEL}")
+    return time.monotonic() - t0
+
+
+def drive(port: int, taps: list, built: list, get_engine):
+    """The traffic. Returns ``{name: (request record, response text)}`` and
+    what was compiled when warm-up ended: ``{"jit": the engine's
+    jit_cache_sizes(), "built": how many programs had been built}``
+    (``get_engine`` fetches the slot engine once the first request built
+    it)."""
+    out: dict = {}
+
+    def one(name, message, n, extra):
+        before = len(taps)
+        t0 = time.monotonic()
+        status, body = _http(
+            port, "POST", "/v1/generate", _gen_body(message, n, extra)
+        )
+        dt = time.monotonic() - t0
+        if status != 200:
+            raise SmokeFailure(f"{name}: /v1/generate -> {status} {body}")
+        usage = body.get("usage", {})
+        print(f"  {name}: prompt {usage.get('prompt_tokens')} tok, "
+              f"completion {usage.get('completion_tokens')}/{n} tok, "
+              f"{dt:.2f}s (smoke timing)", flush=True)
+        if usage.get("completion_tokens") != n:
+            raise SmokeFailure(f"{name}: {usage} != {n} completion tokens")
+        return before, usage, body.get("response", "")
+
+    print("requests:", flush=True)
+    # warm-up, one of each program the hot loop owns: cold prefill, then the
+    # same again (prefix-cache hit; promotion to the trie happens when the
+    # first slot tears down), then a prompt that leaves the cached prefix
+    # mid-page (copy-on-write)
+    for name, spec in (("warm", WARM), ("repeat", WARM), ("cousin", COUSIN)):
+        before, usage, text = one(name, *spec[1:])
+        out[name] = (_tapped(taps, before, usage, name), text)
+    warm = {"jit": dict(get_engine().jit_cache_sizes()), "built": len(built)}
+    step = max((b for b in built if "ragged_step" in b[0]),
+               key=lambda b: b[1], default=None)
+    if step is not None:
+        print(f"  (first build of {step[0]}: {step[1]:.1f}s, compile or "
+              "cache fetch)", flush=True)
+
+    # four at once: mixed prefill+decode ragged steps, chunked prefill of
+    # the long prompt, draft rows for the speculative one
+    before = len(taps)
+    results: dict = {}
+
+    def run(spec):
+        try:
+            results[spec[0]] = one(*spec)
+        except BaseException as e:  # surfaced on the main thread below
+            results[spec[0]] = e
+
+    threads = [threading.Thread(target=run, args=(s,)) for s in CONCURRENT]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=900)
+    for name, *_ in CONCURRENT:
+        r = results.get(name)
+        if r is None or isinstance(r, BaseException):
+            raise SmokeFailure(f"{name}: {r!r}")
+        out[name] = (_tapped(taps, before, r[1], name), r[2])
+
+    # SSE, read to [DONE]
+    before = len(taps)
+    name, message, n, extra = STREAM
+    t0 = time.monotonic()
+    events = _sse(port, "/v1/generate",
+                  {**_gen_body(message, n, extra), "stream": True})
+    if not events or events[-1] != "[DONE]":
+        raise SmokeFailure(f"stream: no [DONE] (last events {events[-2:]})")
+    final = json.loads(events[-2])
+    usage = final.get("usage", {})
+    print(f"  stream: {len(events)} SSE events, completion "
+          f"{usage.get('completion_tokens')}/{n} tok, "
+          f"{time.monotonic() - t0:.2f}s (smoke timing)", flush=True)
+    if usage.get("completion_tokens") != n:
+        raise SmokeFailure(f"stream: {usage} != {n} completion tokens")
+    out["stream"] = (_tapped(taps, before, usage, name), "")
+    return out, warm
+
+
+def _tapped(taps: list, before: int, usage: dict, name: str):
+    """The engine-side record of one HTTP request: among the requests
+    admitted since ``before``, the one with this prompt length (the
+    concurrent prompts all differ in length)."""
+    hits = [r for r in taps[before:]
+            if len(r.prompt) == usage.get("prompt_tokens")]
+    if len(hits) != 1:
+        raise SmokeFailure(
+            f"{name}: {len(hits)} slot-engine admissions match the request "
+            f"({len(taps) - before} since it was sent) — served by the "
+            "static fallback?"
+        )
+    return hits[0]
+
+
+def engine_of(worker):
+    """The one hosted job's slot engine on the worker (built lazily at the
+    first continuous request)."""
+    jobs = list(worker.executor.jobs.values())
+    if len(jobs) != 1 or jobs[0].cont is None:
+        raise SmokeFailure(
+            f"worker holds {len(jobs)} job(s), slot engine "
+            f"{'absent' if jobs and jobs[0].cont is None else 'n/a'} — "
+            "the request was not served by the ContinuousEngine"
+        )
+    return jobs[0].cont
+
+
+def kernel_in_program(cont) -> bool:
+    """The Pallas kernel is in the step program this engine dispatches."""
+    return "tpu_custom_call" in cont.lower_step().as_text()
+
+
+def conservation_error(cont) -> str:
+    try:
+        cont.check_page_conservation()
+    except AssertionError as e:
+        return str(e)
+    return ""
+
+
+def common_checks(port: int, worker, cont, taps: list, built: list,
+                  res: dict, warm: dict) -> None:
+    from tensorlink_tpu.engine.continuous import ContinuousEngine
+
+    late = built[warm["built"]:]
+    check(not late, f"no program built after warm-up ({len(built)} before; "
+          f"after: {late or 'none'})")
+    jit_now = dict(cont.jit_cache_sizes())
+    if cont.tensor_parallel == 1:
+        check(jit_now == warm["jit"],
+              f"compile set unchanged since warm-up: {jit_now}")
+    elif jit_now != warm["jit"]:
+        # sharded, jit's dispatch cache keys on how a placement is SPELLED
+        # (P(None, None, 'tp') vs its rank-expanded form), so the count can
+        # grow with no program built — the line above is the hard check
+        print(f"  note: dispatch-cache entries grew with no build: "
+              f"{warm['jit']} -> {jit_now}", flush=True)
+    cap = worker.executor.capacity()
+    check(cap["platform"] == PLATFORM,
+          f"worker advertises platform {cap['platform']!r} "
+          f"({cap['n_devices']} device(s), {cap['hbm_bytes'] / 1e9:.2f} GB)")
+    check(isinstance(cont, ContinuousEngine) and cont.use_kernel,
+          f"served by {type(cont).__name__}, use_kernel={cont.use_kernel}")
+    check(kernel_in_program(cont),
+          "lowered step program contains the Pallas kernel (tpu_custom_call)")
+    n_http = 3 + len(CONCURRENT) + 1
+    check(len(taps) == n_http,
+          f"{len(taps)} slot-engine admissions for {n_http} HTTP requests")
+    bad = [
+        f"{name}: finished={req.finished} error={req.error!r} "
+        f"{len(req.tokens)}/{req.budget} tokens"
+        for name, (req, _text) in res.items()
+        if not (req.finished and req.error is None
+                and len(req.tokens) == req.budget
+                and all(0 <= t < cont.cfg.vocab_size for t in req.tokens))
+    ]
+    check(not bad, "every stream finished at its requested length, ids in "
+          f"vocab (bad: {bad or 'none'})")
+    (w_req, w_text), (r_req, r_text) = res["warm"], res["repeat"]
+    check(w_req.tokens == r_req.tokens and w_text == r_text,
+          f"repeated greedy request reproduced its stream {w_req.tokens[:6]}…")
+    err = conservation_error(cont)
+    check(not err, f"page conservation clean {err}")
+    _, stats = _http(port, "GET", "/stats")
+    eng = next(
+        (m.get("serving", {}).get("engine") for m in stats.get("models", [])
+         if m.get("name") == MODEL), None,
+    ) or {}
+    check(eng.get("kv_quant") == "int8" and eng.get("prefill_tokens_skipped", 0) > 0,
+          f"/stats engine: kv_quant={eng.get('kv_quant')!r} "
+          f"prefill_tokens_skipped={eng.get('prefill_tokens_skipped')} "
+          f"spec_drafted={eng.get('spec_drafted')} "
+          f"kv_page_bytes={eng.get('kv_page_bytes')}")
+
+
+# -- phases ---------------------------------------------------------------
+def kernels_phase(cfg) -> None:
+    """Kernel vs ``jnp`` reference on the device, small input, the model's
+    head widths: what interpret mode on a CPU cannot show."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tensorlink_tpu.models.quant import quantize_kv, quantize_kv4
+    from tensorlink_tpu.ops import attention as A
+
+    print("phase kernels:", flush=True)
+    S, C, page, n_pp = 4, 16, 16, 4
+    Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    P = 1 + S * n_pp
+    rng = np.random.default_rng(SEED)
+    q = jnp.asarray(rng.normal(size=(S, C, Hq, hd)), jnp.bfloat16)
+    kf = jnp.asarray(rng.normal(size=(P, Hkv, page, hd)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(P, Hkv, page, hd)), jnp.float32)
+    bt = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(S, n_pp), jnp.int32)
+    # decode slot, fresh prefill, mid-page prefill offset, idle slot
+    starts = jnp.asarray([37, 0, 21, 0], jnp.int32)
+    n_valid = jnp.asarray([1, 16, 9, 0], jnp.int32)
+    lengths = jnp.asarray([38, 16, 30, 0], jnp.int32)
+    scale = hd ** -0.5
+    modes = {
+        "bf16": (kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), {}),
+    }
+    for name, quant in (("int8", quantize_kv), ("int4", quantize_kv4)):
+        (k8, ks), (v8, vs) = quant(kf), quant(vf)
+        modes[name] = (k8, v8, {"k_scale": ks, "v_scale": vs})
+    for name, (kp, vp, kw) in modes.items():
+        cases = {
+            "ragged": (A.ragged_paged_attention, A.ragged_paged_attention_ref,
+                       (q, kp, vp, bt, starts, n_valid)),
+            "decode": (A.paged_attention, A.paged_attention_ref,
+                       (q[:, 0], kp, vp, bt, lengths)),
+            "prefill": (A.paged_prefill_attention, A.paged_prefill_attention_ref,
+                        (q[2], kp, vp, bt[2], starts[2])),
+        }
+        for kname, (kern, ref, args) in cases.items():
+            got = np.asarray(kern(*args, scale=scale, **kw), np.float32)
+            with jax.default_matmul_precision("highest"):
+                want = np.asarray(ref(*args, scale=scale, **kw), np.float32)
+            err = float(np.abs(got - want).max())
+            # outputs are O(1) averages of unit-normal values delivered in
+            # bf16: 2e-2 (+2%) admits a couple of ulps of that rounding,
+            # not a wrong scale row, head or page
+            check(np.allclose(got, want, rtol=2e-2, atol=2e-2),
+                  f"{kname} kernel, {name} pages: max |kernel - ref| = {err:.2e}")
+
+
+def serve_phase(ml, tmp: str, built: list) -> None:
+    import jax
+
+    taps = tap_streams()
+    validator, worker = start_cluster(ml, tmp)
+    try:
+        port = validator.api.port
+        print(f"phase serve: load {host_model(port):.1f}s "
+              "(plan, recruit, init weights from seed)", flush=True)
+        res, warm = drive(port, taps, built, lambda: engine_of(worker))
+        print("checks:", flush=True)
+        common_checks(port, worker, engine_of(worker), taps, built, res, warm)
+    finally:
+        stop_cluster(validator, worker)
+    for d in jax.local_devices()[:1]:
+        st = d.memory_stats() or {}
+        print(f"memory {d}: peak_bytes_in_use "
+              f"{st.get('peak_bytes_in_use', 0) / 1e9:.2f} GB of "
+              f"{st.get('bytes_limit', 0) / 1e9:.2f} GB", flush=True)
+
+
+def _tp_deployment(ml_tp, tmp: str, built: list, degree: int):
+    """The sharded deployment, start to stop. Returns the model config,
+    each request as plain data and a weak reference to the engine: nothing
+    that outlives this frame may hold the engine's arrays, or the
+    reference engine will not fit on device 0 beside them."""
+    import weakref
+
+    import jax
+
+    taps = tap_streams()
+    validator, worker = start_cluster(ml_tp, tmp)
+    try:
+        port = validator.api.port
+        print(f"phase tp={degree}: load {host_model(port):.1f}s", flush=True)
+        res, warm = drive(port, taps, built, lambda: engine_of(worker))
+        cont = engine_of(worker)
+        print("checks:", flush=True)
+        check(cont.tensor_parallel == degree and cont._tp_step is not None,
+              f"engine runs tensor_parallel={cont.tensor_parallel}")
+        common_checks(port, worker, cont, taps, built, res, warm)
+        text = cont.lower_step().as_text()
+        check("all_gather" in text,
+              f"lowered tp step: {text.count('tpu_custom_call')} kernel "
+              f"call(s), {text.count('all_gather')} all_gather op(s)")
+        # every device holds ~1/degree of what is sharded: per-device bytes
+        # of the engine's own arrays, beside what the runtime reports
+        devs = list(cont._tp_mesh.devices.flat)
+        held = {d: 0 for d in devs}
+        total = 0
+        for leaf in jax.tree.leaves((cont.engine.params, cont.cache)):
+            total += leaf.nbytes
+            for sh in leaf.addressable_shards:
+                held[sh.device] += sh.data.nbytes
+        print(f"  unsharded params+pages: {total / 1e9:.2f} GB", flush=True)
+        for d in devs:
+            in_use = (d.memory_stats() or {}).get("bytes_in_use", 0)
+            print(f"  {d}: engine arrays {held[d] / 1e9:.2f} GB, "
+                  f"bytes_in_use {in_use / 1e9:.2f} GB", flush=True)
+        lo, hi = min(held.values()), max(held.values())
+        check(hi <= 1.02 * lo and hi < 0.45 * total,
+              f"weights+pages spread evenly: {lo / 1e9:.2f}–{hi / 1e9:.2f} GB "
+              f"per device (replicated embeddings included)")
+        # the request records' transport closures reach the engine: keep
+        # their data only
+        records = {
+            name: dict(prompt=list(req.prompt), budget=req.budget,
+                       sampling=req.sampling, eos=tuple(req.eos),
+                       seed=req.seed, speculative=req.speculative,
+                       tokens=list(req.tokens))
+            for name, (req, _text) in res.items()
+        }
+        return cont.cfg, records, weakref.ref(cont)
+    finally:
+        stop_cluster(validator, worker)
+        worker.executor.jobs.clear()
+        taps.clear()
+
+
+def tp_phase(ml, tmp: str, built: list, degree: int) -> None:
+    """tensor_parallel=``degree`` through the nodes, then the same requests
+    on a tp=1 engine of the same seed on device 0."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    cfg, records, engine_ref = _tp_deployment(
+        dataclasses.replace(ml, tensor_parallel=degree), tmp, built, degree
+    )
+    gc.collect()
+    in_use = (jax.local_devices()[0].memory_stats() or {}).get("bytes_in_use", 0)
+    check(engine_ref() is None and in_use < 1e9,
+          f"tp={degree} engine released ({in_use / 1e9:.2f} GB still in use "
+          "on device 0)")
+
+    # the comparison: same seed, same knobs, one device, each request solo
+    from tensorlink_tpu.engine.continuous import ContinuousEngine
+    from tensorlink_tpu.engine.generate import GenerationEngine
+    from tensorlink_tpu.models.transformer import forward, init_params
+
+    t0 = time.monotonic()
+    params = init_params(cfg, jax.random.PRNGKey(SEED))
+    ref = ContinuousEngine(
+        GenerationEngine(
+            cfg, params, max_seq_len=min(cfg.max_seq_len, ml.max_seq_len),
+            seq_buckets=ml.seq_buckets, batch_buckets=ml.batch_buckets,
+        ),
+        max_slots=ml.cont_max_slots, page_size=ml.cont_page_size,
+        chunk_steps=ml.cont_chunk_steps, prefill_chunk=ml.prefill_chunk,
+        prefix_cache=ml.prefix_cache, kv_quant=ml.kv_quant,
+        spec_decode=ml.spec_decode, spec_draft=ml.spec_draft,
+    )
+    print(f"phase tp=1 reference on {jax.local_devices()[0]}:", flush=True)
+    diverged = []
+    for name, rec in records.items():
+        mine = ref.submit(
+            list(rec["prompt"]), max_new_tokens=rec["budget"],
+            sampling=rec["sampling"], eos_ids=rec["eos"], seed=rec["seed"],
+            speculative=rec["speculative"],
+        )
+        ref.run_until_idle()
+        theirs = rec["tokens"]
+        same = mine.tokens == theirs
+        print(f"  {name}: {len(mine.tokens)} tokens, "
+              f"{'identical' if same else 'DIVERGED'}", flush=True)
+        if not same:
+            i = next((i for i, (a, b) in enumerate(zip(mine.tokens, theirs))
+                      if a != b), min(len(mine.tokens), len(theirs)) - 1)
+            # how close a call the reference's own choice was: its dense
+            # forward's logits at the diverging position
+            prefix = np.asarray([rec["prompt"] + mine.tokens[:i]], np.int32)
+            logits = np.asarray(
+                forward(params, prefix, cfg)[0][0, -1], np.float32
+            )
+            top = np.argsort(logits)[-3:][::-1]
+            print(f"    first divergence at token {i}: tp=1 chose "
+                  f"{mine.tokens[i]}, tp={degree} chose {theirs[i]}; "
+                  f"tp=1 dense-forward logits: top3 "
+                  f"{[(int(t), float(logits[t])) for t in top]}, "
+                  f"margin over tp={degree}'s token "
+                  f"{float(logits[mine.tokens[i]] - logits[theirs[i]]):.4f}",
+                  flush=True)
+            diverged.append(name)
+    err = conservation_error(ref)
+    check(not err, f"reference engine's page conservation clean {err}")
+    print(f"  reference built and replayed in {time.monotonic() - t0:.1f}s "
+          "(smoke timing)", flush=True)
+    check(not diverged,
+          f"tp={degree} token streams equal tp=1's "
+          f"(diverged: {diverged or 'none'})")
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: only the tensor-parallel path and its tp=1 "
+                    "comparison (needs a four-chip host)")
+    args = ap.parse_args(argv)
+
+    import jax
+    import jaxlib
+
+    from tensorlink_tpu.core.config import MLConfig
+    from tensorlink_tpu.core.devices import configure_compile_cache
+    from tensorlink_tpu.models.base import ModelConfig
+    from tensorlink_tpu.models.registry import config_presets
+    from tensorlink_tpu.native import load_tlring
+
+    cache_dir = configure_compile_cache()
+    cache = {"hits": 0, "misses": 0}
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            cache["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            cache["misses"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    # every executable built (compiled, or fetched from the persistent
+    # cache) in this process, in order: (function name, seconds)
+    built: list[tuple[str, float]] = []
+
+    def on_duration(event: str, duration: float, **kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            built.append((str(kw.get("fun_name", "?")), float(duration)))
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+    t_start = time.monotonic()
+    devs = jax.devices()  # a backend that does not come up raises here
+    dev = devs[0]
+    try:
+        import libtpu
+
+        libtpu_v = getattr(libtpu, "__version__", "?")
+    except ImportError:
+        libtpu_v = "absent"
+    print(f"versions: python {sys.version.split()[0]}, jax {jax.__version__}, "
+          f"jaxlib {jaxlib.__version__}, libtpu {libtpu_v}")
+    stats = dev.memory_stats() or {}
+    print(f"device: {dev.platform} {dev.device_kind!r} x{len(devs)}, "
+          f"bytes_limit {stats.get('bytes_limit', 0) / 1e9:.2f} GB")
+    print(f"compile cache: {cache_dir}")
+    print(f"native ring library: "
+          f"{'built' if load_tlring() is not None else 'NOT built (no g++?) — nodes use mp.Queue'}",
+          flush=True)
+    try:
+        if dev.platform != PLATFORM:
+            raise SmokeFailure(
+                f"needs a {PLATFORM} device, JAX found {dev.platform!r}"
+            )
+        if len(devs) < args.chips:
+            raise SmokeFailure(
+                f"--chips {args.chips} needs as many devices, found {len(devs)}"
+            )
+        cfg = (ModelConfig.from_json(MODEL_CONFIG) if MODEL_CONFIG is not None
+               else config_presets()[MODEL])
+        print(f"model: {MODEL} d_model {cfg.d_model}, {cfg.n_layers} layers, "
+              f"{cfg.n_heads}/{cfg.n_kv_heads} heads, hd {cfg.head_dim}, "
+              f"d_ff {cfg.d_ff}, vocab {cfg.vocab_size}; weights from seed "
+              f"{SEED}", flush=True)
+        ml = MLConfig()  # the repo's defaults are what is being proven
+        print(f"MLConfig: continuous={ml.continuous_batching} "
+              f"kv_quant={ml.kv_quant} page={ml.cont_page_size} "
+              f"slots={ml.cont_max_slots} prefill_chunk={ml.prefill_chunk} "
+              f"spec_decode={ml.spec_decode} max_seq_len={ml.max_seq_len}",
+              flush=True)
+        with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
+            if args.chips == 1:
+                kernels_phase(cfg)
+                serve_phase(ml, tmp, built)
+            else:
+                tp_phase(ml, tmp, built, args.chips)
+    except SmokeFailure as e:
+        print(f"FAILED: {e}", file=sys.stderr, flush=True)
+        return 1
+    print(f"compile cache: {cache['hits']} hit(s), {cache['misses']} miss(es); "
+          f"{len(built)} program(s) built, {sum(b[1] for b in built):.1f}s")
+    print(f"total {time.monotonic() - t_start:.1f}s (smoke timing)")
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devs),
+    }}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

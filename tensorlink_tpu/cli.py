@@ -88,7 +88,17 @@ def main(argv: list[str] | None = None) -> int:
     if args.local:
         cfg.local_test = True
 
-    node = make_node(cfg).start()
+    from tensorlink_tpu.core.devices import configure_compile_cache
+
+    configure_compile_cache()  # every role's compiles share the one cache
+    try:
+        node = make_node(cfg).start()
+    except Exception as e:
+        # a node that cannot start (no accelerator backend, port in use,
+        # bad keys) is a failed launch, not a degraded one
+        print(f"run-node: {cfg.role} failed to start: "
+              f"{type(e).__name__}: {e}", file=sys.stderr)
+        return 1
     print(json.dumps({"id": node.node_id, "role": node.role, "port": node.port}))
 
     stop = {"flag": False}

@@ -234,7 +234,7 @@ class MLConfig:
     # chunks of this many steps (one host round trip per chunk instead of
     # per token — engine/generate.py::generate_chunked); 0 keeps the
     # per-token host loop (lowest time-to-first-delta on local devices).
-    # Set 16-64 when the chip is reached over a high-latency tunnel; a
+    # Set 16-64 where the host round trip per token dominates; a
     # stop-sequence cancel still cuts the stream at the exact token (only
     # device compute, not emission, runs to the chunk end).
     stream_chunk_steps: int = 0

@@ -105,6 +105,15 @@ from .scheduler import (
 )
 
 
+class PagedUnsupported(ValueError):
+    """A declared refusal: this model or deployment is one the paged slot
+    engine does not serve (sliding-window attention, a tensor-parallel
+    degree the model or the host cannot shard to). The worker's hosting
+    seam turns exactly this type into the static batcher; anything else a
+    constructor or a compile raises — a bad knob, a lowering error, an
+    out-of-memory — is a fault and propagates."""
+
+
 def paged_unsupported(cfg) -> str | None:
     """Why the paged engine can't serve a model config — None when it
     can. THE hosting-time routing predicate (ml/validator.py): models it
@@ -389,7 +398,7 @@ class ContinuousEngine:
         tensor_parallel: int = 1,
     ):
         if engine.cfg.sliding_window is not None:
-            raise ValueError(
+            raise PagedUnsupported(
                 "continuous batching does not support sliding-window "
                 "attention yet — serve through the static batcher"
             )
@@ -420,29 +429,29 @@ class ContinuousEngine:
         # tp > 1 serves this model sharded over a tp mesh axis: weights
         # as head-major column slices, KV pages by kv head, every
         # control-state array replicated — streams stay bit-identical to
-        # tp=1 (tests/test_tp.py). ValueError here routes the worker's
+        # tp=1 (tests/test_tp.py). PagedUnsupported here routes the worker's
         # hosting seam to its static fallback, same as any other refusal.
         self.tensor_parallel = max(int(tensor_parallel or 1), 1)
         self._tp_mesh = None
         self._tp_step = None
         if self.tensor_parallel > 1:
             if len(jax.devices()) < self.tensor_parallel:
-                raise ValueError(
+                raise PagedUnsupported(
                     f"tensor_parallel={self.tensor_parallel} needs as many "
                     f"devices, have {len(jax.devices())}"
                 )
             reason = tp_shardable(self.cfg, self.tensor_parallel)
             if reason is not None:
-                raise ValueError(
+                raise PagedUnsupported(
                     f"tensor_parallel={self.tensor_parallel}: {reason}"
                 )
             if pool is not None:
-                raise ValueError(
+                raise PagedUnsupported(
                     "tensor parallelism does not compose with a shared "
                     "page pool yet — the pool's page arrays are unsharded"
                 )
             if getattr(engine, "quant", None):
-                raise ValueError(
+                raise PagedUnsupported(
                     "weight-quantized engines cannot shard over a tp axis "
                     "— QTensor scale layouts have no partition specs yet"
                 )
@@ -2790,6 +2799,40 @@ class ContinuousEngine:
             self._slots[s].spec_state.drafted += len(d)
         return n_spec
 
+    def _step_operands(self, blk, starts, n_valid, n_spec, emit, remaining,
+                       eos_arr) -> tuple:
+        """The step program's positional operands: this chunk's packed
+        block and per-slot control rows beside the engine's resident
+        state (weights, page cache, sampling knobs, histograms)."""
+        return (
+            self.engine.params, jnp.asarray(blk), self.cache,
+            jnp.asarray(starts), jnp.asarray(n_valid),
+            jnp.asarray(n_spec), jnp.asarray(emit),
+            jnp.asarray(self._seeds), jnp.asarray(self._steps),
+            jnp.asarray(self._temp), jnp.asarray(self._topk),
+            jnp.asarray(self._topp), jnp.asarray(self._pres),
+            jnp.asarray(self._freq), self._counts,
+            jnp.asarray(remaining), jnp.asarray(eos_arr),
+        )
+
+    def lower_step(self):
+        """The step program lowered at this engine's own shapes and
+        placement, not run: what ``chip_smoke.py`` reads to prove the
+        Pallas kernel (``tpu_custom_call``) and, sharded, the collectives
+        are in the program that serves. Call it on an idle engine."""
+        S, C = self.max_slots, self.prefill_chunk
+        zi = np.zeros(S, np.int32)
+        ops = self._step_operands(
+            np.zeros((S, C), np.int32), zi, zi, zi, np.zeros(S, bool),
+            zi, np.full((S, self._EOS_WIDTH), -1, np.int32),
+        )
+        if self._tp_step is not None:
+            return self._tp_step.lower(*ops)
+        return paged_ragged_step.lower(
+            *ops, self.cfg, self.chunk_steps, self.spec_width,
+            self.use_kernel,
+        )
+
     # tlint: hot-path
     def step_chunk(self, *, admit_only: bool = False) -> bool:
         """Admit queued requests, then run ONE compiled step program.
@@ -2824,32 +2867,18 @@ class ContinuousEngine:
         t_chunk = time.monotonic()
         host_dur = t_chunk - t_host
         self._host_gap_ms = round(host_dur * 1e3, 3)
+        ops = self._step_operands(
+            blk, starts, n_valid, n_spec, emit, remaining, eos_arr
+        )
         if self._tp_step is not None:
             # sharded hot path: same program semantics, weights/KV are
             # device-local shards; control arrays stay host-replicated
             tokens, n_tok, spec_m, n_exec, self.cache, _done, \
-                _steps_dev, self._counts, _rem = self._tp_step(
-                    self.engine.params, jnp.asarray(blk), self.cache,
-                    jnp.asarray(starts), jnp.asarray(n_valid),
-                    jnp.asarray(n_spec), jnp.asarray(emit),
-                    jnp.asarray(self._seeds), jnp.asarray(self._steps),
-                    jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp), jnp.asarray(self._pres),
-                    jnp.asarray(self._freq), self._counts,
-                    jnp.asarray(remaining), jnp.asarray(eos_arr),
-                )
+                _steps_dev, self._counts, _rem = self._tp_step(*ops)
         else:
             tokens, n_tok, spec_m, n_exec, self.cache, _done, \
                 _steps_dev, self._counts, _rem = paged_ragged_step(
-                    self.engine.params, jnp.asarray(blk), self.cache,
-                    jnp.asarray(starts), jnp.asarray(n_valid),
-                    jnp.asarray(n_spec), jnp.asarray(emit),
-                    jnp.asarray(self._seeds), jnp.asarray(self._steps),
-                    jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp), jnp.asarray(self._pres),
-                    jnp.asarray(self._freq), self._counts,
-                    jnp.asarray(remaining), jnp.asarray(eos_arr),
-                    self.cfg, self.chunk_steps, self.spec_width,
+                    *ops, self.cfg, self.chunk_steps, self.spec_width,
                     self.use_kernel,
                 )
         n_exec = int(n_exec)
@@ -3047,6 +3076,6 @@ class ContinuousEngine:
 
 
 __all__ = [
-    "ContinuousEngine", "ContinuousRequest", "pack_prefill_budgets",
-    "paged_unsupported",
+    "ContinuousEngine", "ContinuousRequest", "PagedUnsupported",
+    "pack_prefill_budgets", "paged_unsupported",
 ]

@@ -395,8 +395,6 @@ def _make_zero1_step(
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..parallel.mesh import get_shard_map
-
     if mesh is None:
         raise ValueError("zero1=True requires a mesh with a dp axis")
     if dp_axis not in dict(mesh.shape):
@@ -422,7 +420,6 @@ def _make_zero1_step(
     tp = int(mesh.shape[tp_axis]) if tp_axis else 1
     world = dp * tp  # the flattened update-slice grid
     local_micro = n_micro // dp
-    shard_map = get_shard_map()
     replicated = NamedSharding(mesh, P())
 
     def _tp_dim(spec):
@@ -571,19 +568,25 @@ def _make_zero1_step(
         out_sspecs = (
             (sspecs[0], sspecs[1]) if grad_clip is not None else sspecs
         )
+        # the TP forward's fixed-order all_gathers are typed varying though
+        # every shard holds the same bytes (as in the serving step,
+        # engine/paged.py) — the replicated outputs hold by construction
+        check_vma = tp_axis is None
         if lm is None:
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda p, s, t: region(p, s, t, None),
                 mesh=mesh,
                 in_specs=(pspecs, sspecs, P(dp_axis)),
                 out_specs=(pspecs, out_sspecs, P(), P()),
+                check_vma=check_vma,
             )
             new_params, new_state, loss, gnorm = fn(params, opt_state, toks)
         else:
-            fn = shard_map(
+            fn = jax.shard_map(
                 region, mesh=mesh,
                 in_specs=(pspecs, sspecs, P(dp_axis), P(dp_axis)),
                 out_specs=(pspecs, out_sspecs, P(), P()),
+                check_vma=check_vma,
             )
             new_params, new_state, loss, gnorm = fn(
                 params, opt_state, toks, lm

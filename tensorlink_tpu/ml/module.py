@@ -1883,9 +1883,7 @@ class DistributedModel:
     # ------------------------------------------------------------------
     def _train_forward(self, tokens, attn_mask, tag: str) -> Any:
         """Forward chain with train=True; workers record vjps under ``tag``.
-        Returns logits (jax array on the user process)."""
-        import jax.numpy as jnp
-
+        Returns logits (numpy — the user process stays off jax)."""
         x = np.asarray(tokens, np.int32)
         out = None
         for stage in self.plan.stages:
@@ -1908,7 +1906,7 @@ class DistributedModel:
                  "train": True, "tag": tag},
             )
             out = np.asarray(resp["out"])
-        return jnp.asarray(out)
+        return out
 
     def _train_backward(self, dlogits, tag: str) -> None:
         """Reverse chain: cotangents flow last→first (head hop first when
@@ -2219,7 +2217,7 @@ class DistributedModel:
         return export_hf(self.cfg, merged, out_dir)
 
     def _merge_stage_params(self, trees: list[dict]) -> dict:
-        import jax
+        import jax  # tree utilities only: no backend is initialised
 
         full: dict = {}
         layer_trees = []
@@ -2304,22 +2302,26 @@ def _ce_sum_and_grad(logits, tokens, loss_mask):
     """Next-token cross-entropy SUM (not mean) + dlogits, fp32 — cotangents
     of the sum accumulate linearly across micro-batches, so dividing once by
     the total token count at optimizer-step time reproduces the token-mean
-    loss of engine/training.py::causal_lm_loss exactly."""
-    import jax
-    import jax.numpy as jnp
+    loss of engine/training.py::causal_lm_loss.
 
-    logits = jnp.asarray(logits)
-    tokens = jnp.asarray(np.asarray(tokens, np.int32))
-    mask = jnp.asarray(np.asarray(loss_mask, bool))
-
-    def loss_fn(lg):
-        lg32 = lg[:, :-1].astype(jnp.float32)
-        tg = tokens[:, 1:]
-        m = mask[:, 1:]
-        logz = jax.nn.logsumexp(lg32, axis=-1)
-        gold = jnp.take_along_axis(lg32, tg[..., None], axis=-1)[..., 0]
-        return ((logz - gold) * m).sum()
-
-    nll_sum, dlogits = jax.value_and_grad(loss_fn)(logits)
-    n_tok = np.asarray(mask[:, 1:].sum())
-    return np.asarray(nll_sum), np.asarray(dlogits), n_tok
+    numpy, not jax: the user side of a job never initialises a JAX
+    backend, so a ``UserNode`` in its own process on an accelerator host
+    cannot take the chip from the worker process that owns it."""
+    logits = np.asarray(logits)
+    lg = logits[:, :-1].astype(np.float32)
+    tg = np.asarray(tokens, np.int64)[:, 1:, None]
+    m = np.asarray(loss_mask, bool)[:, 1:]
+    mx = lg.max(axis=-1, keepdims=True)
+    ex = np.exp(lg - mx)
+    z = ex.sum(axis=-1, keepdims=True)
+    logz = (np.log(z) + mx)[..., 0]
+    gold = np.take_along_axis(lg, tg, axis=-1)[..., 0]
+    nll_sum = ((logz - gold) * m).sum(dtype=np.float32)
+    # d(sum nll)/dlogits = (softmax - onehot(target)) on counted positions;
+    # the last position predicts nothing and gets a zero cotangent
+    d = ex / z
+    np.put_along_axis(d, tg, np.take_along_axis(d, tg, axis=-1) - 1.0, axis=-1)
+    d *= m[..., None]
+    dlogits = np.zeros(logits.shape, logits.dtype)
+    dlogits[:, :-1] = d.astype(logits.dtype)
+    return nll_sum, dlogits, m.sum()

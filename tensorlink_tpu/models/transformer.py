@@ -35,6 +35,14 @@ P = jax.sharding.PartitionSpec
 # ---------------------------------------------------------------------------
 
 
+@partial(jax.jit, static_argnames=("shape", "scale", "dtype"))
+def _dense_init(key, shape, scale, dtype):
+    """One weight leaf, drawn, scaled and cast in ONE program: the f32 draw
+    of a stacked leaf (3.6 GB for qwen3-4b's ``w_gate``) never exists in
+    device memory beside the model, only the cast result does."""
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> dict:
     """Random-init parameter pytree (shapes double as the loader's schema)."""
     dt = dtype or cfg.dtype
@@ -44,7 +52,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> dict:
 
     def dense(k, *shape, scale=None):
         s = scale if scale is not None else shape[-2] ** -0.5
-        return (jax.random.normal(k, shape, jnp.float32) * s).astype(dt)
+        return _dense_init(k, shape, float(s), jnp.dtype(dt))
 
     def norm_p(with_bias: bool, *shape):
         p = {"scale": jnp.ones(shape, dt)}
@@ -569,9 +577,9 @@ def _stage_impl(
         and T_in > 1
         and T_in % min(128, T_in) == 0  # irregular bucket -> einsum, not a
         and seq_mesh is None  # trace-time crash of serving
-        # off the TPU the kernel only runs in interpret mode, which
-        # BENCH_r10 measured at 0.99x the einsum (pure overhead) — fall
-        # through to einsum there unless a test opts in explicitly
+        # off the TPU the kernel only runs in interpret mode, which is
+        # pure overhead over the einsum — fall through to einsum there
+        # unless a test opts in explicitly
         and (
             jax.default_backend() == "tpu"
             or _os.environ.get("TLTPU_FLASH_INTERPRET") == "1"
@@ -597,27 +605,9 @@ def _stage_impl(
         else:
             # GSPMD cannot partition a pallas_call, so run it manually via
             # shard_map: batch shards on data, heads on tensor — attention
-            # is independent per (batch, head), so no collectives
-            try:
-                from jax import shard_map
-            except ImportError:  # pre-0.8 jax
-                from jax.experimental.shard_map import shard_map
-            import inspect
-
-            # the pallas_call's out_shape carries no varying-axis metadata,
-            # so replication checking must be off — but the kwarg's NAME
-            # keys on the actual signature, not the import location: some
-            # jax versions export jax.shard_map while still taking
-            # check_rep
-            _sm_params = inspect.signature(shard_map).parameters
-            if "check_vma" in _sm_params:
-                _sm_kw = {"check_vma": False}
-            elif "check_rep" in _sm_params:
-                _sm_kw = {"check_rep": False}
-            else:
-                _sm_kw = {}
-            from jax.sharding import PartitionSpec as _P
-
+            # is independent per (batch, head), so no collectives. The
+            # pallas_call's out_shape carries no varying-axis metadata, so
+            # the VMA check is off.
             sizes = dict(flash_mesh.shape)
             dp = (
                 "data"
@@ -631,15 +621,15 @@ def _stage_impl(
                 and cfg.n_kv_heads % sizes["tensor"] == 0
                 else None
             )
-            spec = _P(dp, None, tp, None)
+            spec = P(dp, None, tp, None)
 
             def attn_fn(q, k_all, v_all, _bias, scale):
-                return shard_map(
+                return jax.shard_map(
                     lambda ql, kl, vl: _flash(ql, kl, vl, scale),
                     mesh=flash_mesh,
                     in_specs=(spec, spec, spec),
                     out_specs=spec,
-                    **_sm_kw,
+                    check_vma=False,
                 )(q, k_all, v_all)
     if seq_mesh is not None:
         if cache is not None:
@@ -768,7 +758,12 @@ def slice_stage_params(
         if "embed" not in out and "lm_head" not in params:
             out["embed"] = params["embed"]  # tied head needs the embedding
     if hi > lo:
-        out["layers"] = jax.tree.map(lambda a: a[lo:hi], params["layers"])
+        # a stage that holds every layer takes the stacked leaves as they
+        # are: slicing [0:L] would copy the whole model beside itself
+        out["layers"] = jax.tree.map(
+            lambda a: a if (lo, hi) == (0, a.shape[0]) else a[lo:hi],
+            params["layers"],
+        )
     return out
 
 

@@ -94,9 +94,15 @@ class BaseNode:
             raise RuntimeError(f"network process failed to start: {info}")
         self.node_id, self.port = info["id"], info["port"]
         self.bridge.start()
-        if self.config.seed_validators:
-            self.send_request("bootstrap", {})
-        self._start_ml()
+        try:
+            if self.config.seed_validators:
+                self.send_request("bootstrap", {})
+            self._start_ml()
+        except BaseException:
+            # e.g. the accelerator backend did not come up: take the
+            # network process down with us instead of leaving it serving
+            self.stop()
+            raise
         self.log.info("up id=%s port=%s", self.node_id[:12], self.port)
         return self
 
@@ -178,8 +184,10 @@ class WorkerNode(BaseNode):
     CONFIG = WorkerConfig
 
     def _start_ml(self) -> None:
+        from tensorlink_tpu.core.devices import configure_compile_cache
         from tensorlink_tpu.ml.worker import DistributedWorker
 
+        configure_compile_cache()
         self.executor = DistributedWorker(self)
         self.send_request("set_capacity", self.executor.capacity())
         self._ml_thread = threading.Thread(

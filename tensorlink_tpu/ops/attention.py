@@ -57,17 +57,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _compiler_params(**kw):
-    """jax-0.4.37 compat: ``pltpu.CompilerParams`` was still named
-    ``TPUCompilerParams`` there — resolve whichever this jax exports so the
-    kernels (and their CPU-interpret tests) run on both sides of the
-    rename."""
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    return cls(**kw)
-
-
 def _flash_kernel(
     q_ref,  # [1, bq, hd]
     k_ref,  # [1, bk, hd]
@@ -212,7 +201,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -238,7 +227,41 @@ def _unpack4(x):
     plain jnp and trace fine inside pallas)."""
     from ..models.quant import unpack_int4
 
-    return unpack_int4(x).astype(jnp.float32).astype(jnp.float32)
+    return unpack_int4(x).astype(jnp.float32)
+
+
+def _load_page(k_ref, v_ref, ks_ref, vs_ref, h, packed: bool):
+    """One grid step's KV page in f32 plus, for quantized pages, this kv
+    head's per-position scales as lane-major ``[1, page]`` rows.
+
+    The scale operands arrive as the page's whole ``[Hkv, page]`` plane —
+    a ``(1, Hkv, page)`` block spans the array's full trailing dims, the
+    one shape the TPU tiling accepts here without padding every scale to
+    a lane tile in HBM — and the head's row is picked in-kernel. The rows
+    stay on the lane axis: callers fold ``k_scale`` into the score
+    columns and ``v_scale`` into the softmax weights (``q·(k·s) ==
+    (q·k)·s`` per key position), so the dequant costs ``page`` multiplies
+    per query row and never needs a lane→sublane relayout. Packed int4
+    pages (two values per byte) unpack in the same VMEM read."""
+    if packed:
+        k = _unpack4(k_ref[0, 0])  # [page, hd]
+        v = _unpack4(v_ref[0, 0])
+    else:
+        k = k_ref[0, 0].astype(jnp.float32)  # [page, hd]
+        v = v_ref[0, 0].astype(jnp.float32)
+    if ks_ref is None:
+        return k, v, None, None
+    ks = ks_ref[0, pl.ds(h, 1), :].astype(jnp.float32)  # [1, page]
+    vs = vs_ref[0, pl.ds(h, 1), :].astype(jnp.float32)
+    return k, v, ks, vs
+
+
+def _scale_spec(Hkv: int, page: int, page_idx):
+    """BlockSpec of a per-page scale plane: the same physical page index
+    as the KV block (``page_idx``'s first coordinate), every kv head."""
+    return pl.BlockSpec(
+        (1, Hkv, page), lambda *a: (page_idx(*a)[0], 0, 0)
+    )
 
 
 def _gather_pages(pages, scales, block_tables, shape):
@@ -366,7 +389,7 @@ def _paged_prefill_kernel(
     q_ref,  # [1, C·G, hd]
     k_ref,  # [1, 1, page, hd] — page bt[0, i] of kv head h
     v_ref,  # [1, 1, page, hd]
-    *rest,  # quantized: ks_ref, vs_ref [1, 1, page] then out + scratch
+    *rest,  # quantized: ks_ref, vs_ref [1, Hkv, page] then out + scratch
     scale: float,
     page: int,
     n_pp: int,
@@ -379,6 +402,7 @@ def _paged_prefill_kernel(
     else:
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
+    h = pl.program_id(0)
     i = pl.program_id(1)
     start = start_ref[0]
 
@@ -398,25 +422,17 @@ def _paged_prefill_kernel(
     @pl.when(i * page <= start + C - 1)
     def _compute():
         q = q_ref[0].astype(jnp.float32)  # [C·G, hd]
-        if packed:
-            # int4 pages (two values per byte): the nibble unpack joins
-            # the dequant in the VMEM read — the HBM fetch carried a
-            # QUARTER of the fp16 bytes
-            k = _unpack4(k_ref[0, 0])  # [page, hd]
-            v = _unpack4(v_ref[0, 0])
-        else:
-            k = k_ref[0, 0].astype(jnp.float32)  # [page, hd]
-            v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # int8/int4 pages: the per-(position, head) scale multiply
-            # fuses into the VMEM read — arithmetic stays f32 on the MXU
-            # while the HBM page fetch carried the quantized bytes
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
+        # int8/int4 pages: the HBM fetch carried the quantized bytes; the
+        # dequant rides the score columns / softmax weights (_load_page)
+        k, v, ks, vs = _load_page(
+            k_ref, v_ref, ks_ref, vs_ref, h, packed
+        )
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [C·G, page]
+        if quantized:
+            sc = sc * ks
         # query row r is chunk position r // G at absolute start + r // G
         q_pos = start + jax.lax.broadcasted_iota(
             jnp.int32, (CG, page), 0
@@ -432,7 +448,7 @@ def _paged_prefill_kernel(
         p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p * vs if quantized else p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_ref[:] = m_new
@@ -495,9 +511,6 @@ def paged_prefill_attention(
     def page_idx(h, i, bt, st, p=page, c=C):
         return (jnp.where(i * p <= st[0] + c - 1, bt[0, i], 0), h, 0, 0)
 
-    def scale_idx(h, i, bt, st, p=page, c=C):
-        return (jnp.where(i * p <= st[0] + c - 1, bt[0, i], 0), h, 0)
-
     in_specs = [
         pl.BlockSpec((1, C * G, hd), lambda h, i, bt, st: (h, 0, 0)),
         pl.BlockSpec((1, 1, page, hdk), page_idx),
@@ -507,10 +520,7 @@ def paged_prefill_attention(
     if quantized:
         # int8 pages ride with their per-(position, head) scales — same
         # physical page index, dequant fused in-kernel at the VMEM read
-        in_specs += [
-            pl.BlockSpec((1, 1, page), scale_idx),
-            pl.BlockSpec((1, 1, page), scale_idx),
-        ]
+        in_specs += [_scale_spec(Hkv, page, page_idx)] * 2
         args += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
@@ -528,7 +538,7 @@ def paged_prefill_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((Hkv, C * G, hd), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -625,7 +635,7 @@ def _ragged_kernel(
     q_ref,  # [1, 1, C·G, hd]
     k_ref,  # [1, 1, page, hd] — page bt[s, i] of kv head h
     v_ref,  # [1, 1, page, hd]
-    *rest,  # quantized: ks_ref, vs_ref [1, 1, page] then out + scratch
+    *rest,  # quantized: ks_ref, vs_ref [1, Hkv, page] then out + scratch
     scale: float,
     page: int,
     n_pp: int,
@@ -639,6 +649,7 @@ def _ragged_kernel(
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
     s = pl.program_id(0)
+    h = pl.program_id(1)
     i = pl.program_id(2)
     start = start_ref[s]
     nv = nv_ref[s]
@@ -660,23 +671,15 @@ def _ragged_kernel(
     @pl.when((nv > 0) & (i * page <= start + nv - 1))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # [C·G, hd]
-        if packed:
-            # int4 pages: nibble unpack + dequant fused into the VMEM
-            # read — the HBM fetch carried a quarter of the fp16 bytes
-            k = _unpack4(k_ref[0, 0])  # [page, hd]
-            v = _unpack4(v_ref[0, 0])
-        else:
-            k = k_ref[0, 0].astype(jnp.float32)  # [page, hd]
-            v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # int8/int4 pages: dequant fused into the VMEM read — the
-            # HBM fetch carried the quantized bytes, the MXU math stays f32
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
+        k, v, ks, vs = _load_page(
+            k_ref, v_ref, ks_ref, vs_ref, h, packed
+        )
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [C·G, page]
+        if quantized:
+            sc = sc * ks
         # query row r is block position r // G at absolute start + r // G
         row = jax.lax.broadcasted_iota(jnp.int32, (CG, page), 0) // G
         q_pos = start + row
@@ -691,7 +694,7 @@ def _ragged_kernel(
         p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p * vs if quantized else p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_ref[:] = m_new
@@ -764,14 +767,6 @@ def ragged_paged_attention(
             h, 0, 0,
         )
 
-    def scale_idx(s, h, i, bt, st, nv, p=page):
-        return (
-            jnp.where(
-                (nv[s] > 0) & (i * p <= st[s] + nv[s] - 1), bt[s, i], 0
-            ),
-            h, 0,
-        )
-
     in_specs = [
         pl.BlockSpec(
             (1, 1, C * G, hd), lambda s, h, i, bt, st, nv: (s, h, 0, 0)
@@ -783,10 +778,7 @@ def ragged_paged_attention(
     if quantized:
         # int8 pages ride with their per-(position, head) scales — same
         # physical page index, dequant fused in-kernel at the VMEM read
-        in_specs += [
-            pl.BlockSpec((1, 1, page), scale_idx),
-            pl.BlockSpec((1, 1, page), scale_idx),
-        ]
+        in_specs += [_scale_spec(Hkv, page, page_idx)] * 2
         args += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
@@ -805,7 +797,7 @@ def ragged_paged_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, Hkv, C * G, hd), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -828,7 +820,7 @@ def _paged_kernel(
     q_ref,  # [1, 1, G, hd]
     k_ref,  # [1, 1, page, hd] — page bt[s, i] of kv head h
     v_ref,  # [1, 1, page, hd]
-    *rest,  # quantized: ks_ref, vs_ref [1, 1, page] then out + scratch
+    *rest,  # quantized: ks_ref, vs_ref [1, Hkv, page] then out + scratch
     scale: float,
     page: int,
     n_pp: int,
@@ -841,6 +833,7 @@ def _paged_kernel(
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
     s = pl.program_id(0)
+    h = pl.program_id(1)
     i = pl.program_id(2)
     length = len_ref[s]
 
@@ -855,23 +848,15 @@ def _paged_kernel(
     @pl.when(i * page < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # [G, hd]
-        if packed:
-            # int4 pages: nibble unpack + dequant fused into the VMEM
-            # read — the HBM fetch carried a quarter of the fp16 bytes
-            k = _unpack4(k_ref[0, 0])  # [page, hd]
-            v = _unpack4(v_ref[0, 0])
-        else:
-            k = k_ref[0, 0].astype(jnp.float32)  # [page, hd]
-            v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # int8/int4 pages: dequant fused into the VMEM read — the
-            # HBM fetch carried the quantized bytes, the MXU math stays f32
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
+        k, v, ks, vs = _load_page(
+            k_ref, v_ref, ks_ref, vs_ref, h, packed
+        )
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [G, page]
+        if quantized:
+            sc = sc * ks
         pos = i * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
         ok = pos < length  # [1, page]
         sc = jnp.where(ok, sc, NEG_INF)
@@ -881,7 +866,7 @@ def _paged_kernel(
         p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)  # [G, page]
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p * vs if quantized else p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_ref[:] = m_new
@@ -929,29 +914,19 @@ def paged_attention(
         _paged_kernel, scale=scale, page=page, n_pp=n_pp,
         quantized=quantized, packed=packed,
     )
+    def page_idx(s, h, i, bt, ln):
+        return (bt[s, i], h, 0, 0)
+
     in_specs = [
         pl.BlockSpec((1, 1, G, hd), lambda s, h, i, bt, ln: (s, h, 0, 0)),
-        pl.BlockSpec(
-            (1, 1, page, hdk),
-            lambda s, h, i, bt, ln: (bt[s, i], h, 0, 0),
-        ),
-        pl.BlockSpec(
-            (1, 1, page, hdk),
-            lambda s, h, i, bt, ln: (bt[s, i], h, 0, 0),
-        ),
+        pl.BlockSpec((1, 1, page, hdk), page_idx),
+        pl.BlockSpec((1, 1, page, hdk), page_idx),
     ]
     args = [qg, k_pages, v_pages]
     if quantized:
         # int8 pages ride with their per-(position, head) scales — same
         # physical page index, dequant fused in-kernel at the VMEM read
-        in_specs += [
-            pl.BlockSpec(
-                (1, 1, page), lambda s, h, i, bt, ln: (bt[s, i], h, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, page), lambda s, h, i, bt, ln: (bt[s, i], h, 0)
-            ),
-        ]
+        in_specs += [_scale_spec(Hkv, page, page_idx)] * 2
         args += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
@@ -969,7 +944,7 @@ def paged_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, Hkv, G, hd), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -93,46 +93,6 @@ def shard(mesh: Mesh, spec: P):
     return NamedSharding(mesh, spec)
 
 
-def get_shard_map():
-    """shard_map across jax versions (moved out of experimental in 0.8).
-
-    On jax builds predating VMA tracking (< 0.5: no ``lax.pvary``),
-    :func:`mark_varying` is an identity, so shard_map's replication
-    inference can't be satisfied for loops whose carry changes
-    replication (ring collectives, pipeline scans) — there
-    ``check_rep=False`` is forced, matching what those versions require."""
-    import functools
-    import inspect
-
-    from jax import lax
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    if hasattr(lax, "pvary") or hasattr(lax, "pcast"):
-        return shard_map
-    params = inspect.signature(shard_map).parameters
-    if "check_rep" in params:
-        return functools.partial(shard_map, check_rep=False)
-    return shard_map
-
-
-def mark_varying(x, axis_name: str):
-    """Mark an array varying over a manual axis (VMA) across jax versions
-    (lax.pvary → lax.pcast in 0.9). Versions predating VMA tracking
-    (< 0.5: no lax.pvary at all) don't distinguish varying from
-    replicated inside shard_map, so the identity is the correct no-op
-    there."""
-    from jax import lax
-
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_name, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis_name)
-    return x
-
-
 def put(mesh: Mesh, tree, specs):
     """device_put a pytree with a matching PartitionSpec pytree."""
     return jax.tree.map(

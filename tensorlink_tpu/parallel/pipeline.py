@@ -30,8 +30,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-
-from tensorlink_tpu.parallel.mesh import get_shard_map, mark_varying as _vary
+def _vary(x, axis_name: str):
+    return lax.pcast(x, axis_name, to="varying")
 
 
 def _tmap(fn, *trees):
@@ -94,13 +94,11 @@ def gpipe(
     (parity test: tests/test_pipeline.py). ``stage_fn`` must map its input
     pytree to an output of identical structure/shapes (passthrough leaves —
     e.g. per-micro masks — are simply returned unchanged)."""
-    shard_map = get_shard_map()
-
     n_stage = mesh.shape[axis_name]
     param_specs = jax.tree.map(lambda _: P(axis_name), stacked_params)
     micro_specs = jax.tree.map(lambda _: P(), micros)
     out_specs = jax.tree.map(lambda _: P(axis_name), micros)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_gpipe_local, stage_fn=stage_fn, axis_name=axis_name),
         mesh=mesh,
         in_specs=(param_specs, micro_specs),

@@ -175,11 +175,12 @@ def _ring_attention_local(
 
     # initial accumulators must be marked varying over the ring axis or the
     # scan carry types disagree (jax VMA check under shard_map)
-    from tensorlink_tpu.parallel.mesh import mark_varying
+    def varying(x):
+        return lax.pcast(x, axis_name, to="varying")
 
-    m0 = mark_varying(jnp.full((B, Hkv, G, Tq), NEG_INF, jnp.float32), axis_name)
-    l0 = mark_varying(jnp.zeros((B, Hkv, G, Tq), jnp.float32), axis_name)
-    o0 = mark_varying(jnp.zeros((B, Tq, Hkv, G, hd), jnp.float32), axis_name)
+    m0 = varying(jnp.full((B, Hkv, G, Tq), NEG_INF, jnp.float32))
+    l0 = varying(jnp.zeros((B, Hkv, G, Tq), jnp.float32))
+    o0 = varying(jnp.zeros((B, Tq, Hkv, G, hd), jnp.float32))
     kv_start0 = idx * k.shape[1]
     if quantized:
         k8, ks = _quant_chunk(k)
@@ -214,13 +215,9 @@ def ring_attention(
     rotates int8 K/V + scales around the ring instead of full-precision
     blocks: ≈½ the bf16 ICI bytes per hop, divergence bounded and
     test-pinned."""
-    from tensorlink_tpu.parallel.mesh import get_shard_map
-
-    shard_map = get_shard_map()
-
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             _ring_attention_local,
             axis_name=axis_name,

@@ -84,14 +84,13 @@ def test_quantized_psum_and_all_gather_match_plain():
     unlike a ring-reduce), and quantized_all_gather reassembles the
     shards it was given."""
     from jax.sharding import PartitionSpec as P
-    from tensorlink_tpu.parallel.mesh import get_shard_map
     from tensorlink_tpu.parallel.ring import (
         quantized_all_gather, quantized_psum,
     )
 
     n = 4
     mesh = build_mesh({"seq": n}, jax.devices("cpu")[:n])
-    sm = get_shard_map()
+    sm = jax.shard_map
     x = jax.random.normal(jax.random.PRNGKey(3), (n * 2, 64), jnp.float32)
 
     qsum = sm(

@@ -303,7 +303,7 @@ def test_quantized_all_gather_tiled_fixed_order():
     the identical result, each shard's rows carry only ITS OWN
     quantization error, and a replicated input round-trips within the
     int8 bound."""
-    from tensorlink_tpu.parallel.mesh import build_mesh, get_shard_map
+    from tensorlink_tpu.parallel.mesh import build_mesh
     from tensorlink_tpu.parallel.ring import quantized_all_gather
 
     if len(jax.devices()) < 2:
@@ -311,13 +311,16 @@ def test_quantized_all_gather_tiled_fixed_order():
     from jax.sharding import PartitionSpec as P
 
     mesh = build_mesh({"tp": 2}, jax.devices()[:2])
-    shard_map = get_shard_map()
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(4, 8), jnp.float32)  # [rows, 2 shards of 4]
 
-    fn = shard_map(
+    # the gather is replicated by construction (every shard concatenates
+    # the same chunks), which the VMA check cannot infer through the
+    # dequantize — so the check is off, as for any hand-written collective
+    fn = jax.shard_map(
         lambda a: quantized_all_gather(a, "tp", axis=1, tiled=True),
         mesh=mesh, in_specs=(P(None, "tp"),), out_specs=P(),
+        check_vma=False,
     )
     out = np.asarray(fn(x))
     assert out.shape == x.shape
