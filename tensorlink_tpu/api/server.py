@@ -15,6 +15,7 @@ import asyncio
 import json
 import re
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable
 from urllib.parse import unquote, urlparse
@@ -622,7 +623,15 @@ class TensorlinkAPI:
             self._inflight -= n
 
     async def _stream_generate(self, gen, fmt, writer, rid: str = "") -> None:
-        """SSE: ML thread pushes deltas through call_soon_threadsafe."""
+        """SSE: ML thread pushes deltas through call_soon_threadsafe.
+
+        Records one ``http_first_byte`` span per request: this handler's
+        entry to the first delta written, on the API's own monotonic
+        clock. Minus the engine's ``first_token`` span (submit to first
+        emit, on the engine's clock) it is everything outside the engine
+        on both legs, with no clock shared between hosts."""
+        t_entry = time.monotonic()
+        first_byte = False
         loop = asyncio.get_running_loop()
         q: asyncio.Queue = asyncio.Queue()
 
@@ -662,6 +671,12 @@ class TensorlinkAPI:
             if kind == "delta":
                 writer.write(sse_event(fmt.stream_chunk(item)))
                 await writer.drain()
+                if not first_byte:
+                    first_byte = True
+                    get_tracer().record(
+                        rid, "http_first_byte", site="api",
+                        dur_s=time.monotonic() - t_entry,
+                    )
             elif kind == "meta":
                 writer.write(sse_event(fmt.stream_prelude(item)))
                 await writer.drain()

@@ -26,8 +26,13 @@ compared for durations (tlint TL004 discipline).
 
 **Flight recorder.** A bounded per-engine ring of per-step records
 (occupied slots, prefill grants, tokens emitted, page occupancy,
-preemptions), appended at the same per-chunk boundary, dumped on engine
-error — chaos-test postmortems read data instead of print archaeology.
+preemptions, and the chunk's host phases in ms beside ``t0``, the
+monotonic stamp of its entry), appended at the same per-chunk boundary,
+dumped on engine error — chaos-test postmortems read data instead of
+print archaeology. A record's ``step`` is the id its chunk carries
+everywhere else: the ``chunk=`` argument of the engine's ``tlink:chunk``
+profiler annotation and the ``chunk`` attribute of the request spans
+that rode in it.
 """
 
 from __future__ import annotations
@@ -194,15 +199,22 @@ class FlightRecorder:
     is dumped (``last_dump``) so a chaos failure ships its final N steps
     of slot/page state with the exception instead of losing them."""
 
-    def __init__(self, capacity: int = 256):
+    def __init__(self, capacity: int = 1024):
         self.capacity = int(capacity)
         self._lock = threading.Lock()
         self._ring: deque[dict] = deque(maxlen=self.capacity)  #: guarded by self._lock
-        self._step = itertools.count(1)
+        self._next = 1  # single writer: the engine's driver thread
         self.last_dump: dict | None = None  #: guarded by self._lock
 
+    @property
+    def next_step(self) -> int:
+        """The ``step`` the next :meth:`record` will carry: what the
+        engine names a chunk by while it runs, before its record exists."""
+        return self._next
+
     def record(self, **fields) -> None:
-        rec = {"step": next(self._step), **fields}
+        rec = {"step": self._next, **fields}
+        self._next += 1
         with self._lock:
             self._ring.append(rec)
 

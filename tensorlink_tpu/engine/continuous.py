@@ -195,7 +195,44 @@ def _sample_rows(logits, keys, temp, top_k, top_p, pres, freq, counts):
         )
         return sample(lg[None], key, sp, cnt[None])[0]
 
-    return jax.vmap(one)(logits, keys, temp, top_k, top_p, pres, freq, counts)
+    with jax.named_scope("sample"):
+        return jax.vmap(one)(
+            logits, keys, temp, top_k, top_p, pres, freq, counts
+        )
+
+
+# host phases of one chunk, in the order step_chunk goes through them;
+# "between" (the previous chunk's exit to this one's entry) comes first
+CHUNK_PHASES = (
+    "admit", "pack", "dispatch", "wait", "drain", "deliver", "post",
+)
+
+
+class _Phase:
+    """One host phase of a chunk, marked where the work happens: a
+    ``tlink:<name>`` annotation on the profiler's host line (found by no
+    session, it costs an object) and a ``time.monotonic()`` pair added to
+    the chunk's phase table in seconds. No device sync: every phase
+    starts and ends where the host already waits or never did."""
+
+    __slots__ = ("acc", "name", "ann", "t0")
+
+    def __init__(self, acc: dict, name: str, **args):
+        self.acc = acc
+        self.name = name
+        self.ann = jax.profiler.TraceAnnotation(f"tlink:{name}", **args)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.monotonic()
+        self.acc[self.name] = t1 - self.t0
+        self.acc["end"] = t1  # the last phase's end is the chunk's exit
+        self.ann.__exit__(*exc)
+        return False
 
 
 # the engine's counter families: (legacy /stats key, prometheus name,
@@ -275,6 +312,19 @@ _ENGINE_COUNTERS = (
      "admissions that attempted a cross-replica prefix pull"),
     ("fleet_pull_fallbacks", "tlink_engine_fleet_pull_fallbacks_total",
      "fleet pulls that degraded to the next rung (local prefill)"),
+    # flat token packing (ROADMAP S3): rows of the packed [S, C] block
+    # that carried a token against rows the ragged pass computed
+    ("ragged_rows_valid", "tlink_engine_ragged_rows_valid_total",
+     "rows of the packed block that carried a token"),
+    ("ragged_rows_computed", "tlink_engine_ragged_rows_computed_total",
+     "rows of the packed block the ragged pass computed (slots x chunk)"),
+) + tuple(
+    # the anatomy of a chunk on the host (docs/SERVING.md "Observability"):
+    # cumulative microseconds per phase of step_chunk, so a window reads
+    # each as a difference whatever the flight recorder's length
+    (f"chunk_us_{ph}", f"tlink_engine_chunk_us_{ph}_total",
+     f"host microseconds in a chunk's {ph} phase")
+    for ph in ("between",) + CHUNK_PHASES
 )
 
 
@@ -391,7 +441,7 @@ class ContinuousEngine:
         worker_role: str = "mixed",
         trace_site: str = "",
         metrics: MetricsRegistry | None = None,
-        flight_capacity: int = 256,
+        flight_capacity: int = 1024,
         pool: SharedPagePool | None = None,
         model_id: str = "",
         page_quota: int = 0,
@@ -681,18 +731,15 @@ class ContinuousEngine:
             "model FLOPs utilization of the last background train step",
             fn=lambda: self._train_mfu,
         )
-        # host work on the decode critical path, per chunk: admission,
-        # grant assembly (_pack_ragged), draft lookup — everything
-        # between the previous chunk's sync and this chunk's dispatch.
-        # ROADMAP item 5 found ONE device sync per chunk but left this
-        # host span unbudgeted; now it's a gauge + FlightRecorder field
-        # (rot-guarded in tests/test_tp.py).
+        # the last chunk's admit + pack in ms (bench.py reads it); the
+        # per-phase chunk_us_* counters and the flight recorder's fields
+        # are the record (rot-guarded in tests/test_tp.py)
         self._host_gap_ms = 0.0
-        self.metrics.gauge(
-            "tlink_engine_host_gap_ms",
-            "host work between chunk syncs (admission + grant assembly), ms",
-            fn=lambda: self._host_gap_ms,
-        )
+        # monotonic stamp of the last dispatched chunk's exit while the
+        # engine still had work: the next chunk's "between" starts there
+        self._chunk_exit_t: float | None = None
+        # the flight-recorder step of the chunk in progress
+        self._chunk_step = 0
         if pool is not None:
             # per-tenant pool occupancy: these render under the model's
             # label at /metrics (the registry-per-model grouping), which
@@ -998,7 +1045,10 @@ class ContinuousEngine:
                 # sum to the first_token span's TTFT)
                 base = req.prefill_done_t or req.admit_t or req.submit_t
                 self._trace(req, "first_decode", dur_s=now - base)
-                self._trace(req, "first_token", dur_s=now - req.submit_t)
+                self._trace(
+                    req, "first_token", dur_s=now - req.submit_t,
+                    chunk=self._chunk_step,
+                )
         req.tokens.append(tok)
         cancel = False
         if req.stream_cb is not None:
@@ -2503,11 +2553,8 @@ class ContinuousEngine:
             "train_mfu": round(self._train_mfu, 5),
             # tensor parallelism (docs/SHARDING.md): shard degree of the
             # hot path (1 = single device) — a router treats the whole
-            # mesh as one placement unit — and the host-side gap on the
-            # decode critical path (work between chunk syncs: admission,
-            # grant assembly, draft lookup, ragged packing)
+            # mesh as one placement unit
             "tensor_parallel": self.tensor_parallel,
-            "host_gap_ms": self._host_gap_ms,
         })
         if self.pool is not None:
             # co-hosting: the shared pool's occupancy plus THIS tenant's
@@ -2849,46 +2896,125 @@ class ContinuousEngine:
         own done-point, and evicts finished slots at the boundary.
         Returns True while any work (live slots or queued requests)
         remains — the driver's requeue signal."""
-        # host-gap budget (docs/SHARDING.md): everything between the
-        # previous chunk's boundary sync and this chunk's dispatch —
-        # admission, grant assembly, draft lookup, ragged packing — is
-        # host work the device waits behind. Timed here so the span is
-        # visible per chunk without adding a sync of its own.
-        t_host = time.monotonic()
-        self._admit()
-        if admit_only:
-            return self.has_work()
-        S = self.max_slots
-        pack = self._pack_ragged()
-        if pack is None:
-            return self.has_work()
-        blk, starts, n_valid, n_spec, emit, remaining, eos_arr, \
-            completing, handoff_done, grants = pack
-        t_chunk = time.monotonic()
-        host_dur = t_chunk - t_host
-        self._host_gap_ms = round(host_dur * 1e3, 3)
-        ops = self._step_operands(
-            blk, starts, n_valid, n_spec, emit, remaining, eos_arr
+        # the anatomy of a chunk (docs/SERVING.md "Observability"): the
+        # phases below are marked where the work happens, on the
+        # profiler's host line (tlink:<phase> inside one tlink:chunk that
+        # carries this chunk's flight-recorder step) and as monotonic
+        # pairs in the chunk's record and the chunk_us_* counters. No
+        # sync is added: the device is waited for at int(n_exec), as
+        # before.
+        t0 = time.monotonic()
+        between = (
+            t0 - self._chunk_exit_t if self._chunk_exit_t is not None else 0.0
         )
-        if self._tp_step is not None:
-            # sharded hot path: same program semantics, weights/KV are
-            # device-local shards; control arrays stay host-replicated
-            tokens, n_tok, spec_m, n_exec, self.cache, _done, \
-                _steps_dev, self._counts, _rem = self._tp_step(*ops)
-        else:
-            tokens, n_tok, spec_m, n_exec, self.cache, _done, \
-                _steps_dev, self._counts, _rem = paged_ragged_step(
-                    *ops, self.cfg, self.chunk_steps, self.spec_width,
-                    self.use_kernel,
-                )
-        n_exec = int(n_exec)
-        toks_host = np.asarray(tokens)
-        n_tok_host = np.asarray(n_tok)
-        spec_m_host = np.asarray(spec_m)
-        # the chunk's host-visible wall time — measured at the ONE
-        # existing boundary sync (the asarray drain above), so span
-        # recording adds no device round trips of its own
-        chunk_dur = time.monotonic() - t_chunk
+        step = self._chunk_step = self.recorder.next_step
+        ph: dict = {}
+        fields = None
+        with jax.profiler.TraceAnnotation("tlink:chunk", chunk=step):
+            with _Phase(ph, "admit"):
+                self._admit()
+            pack = None
+            if not admit_only:
+                with _Phase(ph, "pack"):
+                    pack = self._pack_ragged()
+            if pack is not None:
+                blk, starts, n_valid, n_spec, emit, remaining, eos_arr, \
+                    completing, handoff_done, grants = pack
+                with _Phase(ph, "dispatch"):
+                    ops = self._step_operands(
+                        blk, starts, n_valid, n_spec, emit, remaining,
+                        eos_arr,
+                    )
+                    if self._tp_step is not None:
+                        # sharded hot path: same program semantics,
+                        # weights/KV are device-local shards; control
+                        # arrays stay host-replicated
+                        tokens, n_tok, spec_m, n_exec, self.cache, _done, \
+                            _steps_dev, self._counts, _rem = (
+                                self._tp_step(*ops)
+                            )
+                    else:
+                        tokens, n_tok, spec_m, n_exec, self.cache, _done, \
+                            _steps_dev, self._counts, _rem = (
+                                paged_ragged_step(
+                                    *ops, self.cfg, self.chunk_steps,
+                                    self.spec_width, self.use_kernel,
+                                )
+                            )
+                with _Phase(ph, "wait"):
+                    # the first value that blocks: the device is done here
+                    n_exec = int(n_exec)
+                with _Phase(ph, "drain"):
+                    toks_host = np.asarray(tokens)
+                    n_tok_host = np.asarray(n_tok)
+                    spec_m_host = np.asarray(spec_m)
+                # the chunk's host-visible wall time — measured at the
+                # ONE existing boundary sync, so span recording adds no
+                # device round trips of its own
+                chunk_dur = ph["dispatch"] + ph["wait"] + ph["drain"]
+                with _Phase(ph, "deliver"):
+                    delivered_total = self._deliver(
+                        grants, completing, handoff_done, emit, n_spec,
+                        n_exec, toks_host, n_tok_host, spec_m_host,
+                        chunk_dur,
+                    )
+                with _Phase(ph, "post"):
+                    self._count("ragged_rows_valid", int(n_valid.sum()))
+                    self._count("ragged_rows_computed", blk.size)
+                    # flight recorder (core/trace.py): the postmortem's
+                    # per-step state; the append follows the phase's end
+                    # because the record holds post_ms
+                    fields = dict(
+                        live_slots=(
+                            int(self._active.sum()) + len(self._prefilling)
+                        ),
+                        prefilling=len(self._prefilling),
+                        decode_steps=n_exec if bool(emit.any()) else 0,
+                        prefill_granted=int(sum(grants.values())),
+                        spec_drafted=int(n_spec.sum()),
+                        tokens_emitted=delivered_total,
+                        pages_free=self.alloc.n_free,
+                        pages_in_transit=self._pages_in_transit(),
+                        preemptions=int(self._stat["preemptions"].value),
+                    )
+                    self._refresh_prefix_digest()
+        more = self.has_work()
+        if fields is None:
+            # nothing was dispatched (admission only, or nothing live):
+            # no record; the round stays part of what lies between two
+            # chunks unless the engine ran out of work
+            if not more:
+                self._chunk_exit_t = None
+            return more
+        ph["between"] = between
+        us = {
+            k: int(ph[k] * 1e6 + 0.5) for k in ("between",) + CHUNK_PHASES
+        }
+        for k, v in us.items():
+            self._count(f"chunk_us_{k}", v)
+        # host_ms and chunk_ms keep their meaning: step_chunk's entry to
+        # the dispatch, and the dispatch to the end of the drain
+        self._host_gap_ms = (us["admit"] + us["pack"]) / 1e3
+        self.recorder.record(
+            **fields,
+            chunk_ms=(us["dispatch"] + us["wait"] + us["drain"]) / 1e3,
+            host_ms=self._host_gap_ms,
+            t0=t0,
+            **{f"{k}_ms": v / 1e3 for k, v in us.items()},
+        )
+        self._chunk_exit_t = ph["end"] if more else None
+        return more
+
+    # tlint: hot-path
+    def _deliver(self, grants, completing, handoff_done, emit, n_spec,
+                 n_exec, toks_host, n_tok_host, spec_m_host,
+                 chunk_dur) -> int:
+        """The deliver phase of a chunk, after the drain: prefill
+        bookkeeping, each emitting slot's tokens up to its own
+        done-point through ``_emit``, and eviction of finished slots.
+        Returns the tokens delivered."""
+        S = self.max_slots
+        step = self._chunk_step
         # prefill bookkeeping: the grants landed on device; completed
         # prompts switch to decode mode before delivery (their first
         # token is column 0 of this very chunk)
@@ -2899,7 +3025,7 @@ class ContinuousEngine:
             self._count("prefill_tokens", g)
             self._trace(
                 req, "prefill_chunk", dur_s=chunk_dur, tokens=g,
-                pos=req.prefill_pos,
+                pos=req.prefill_pos, chunk=step,
             )
         now = time.monotonic()
         for s in completing:
@@ -2914,7 +3040,7 @@ class ContinuousEngine:
                 self._trace(
                     req, "prefill",
                     dur_s=(now - req.admit_t) if req.admit_t else None,
-                    tokens=req.prefill_pos,
+                    tokens=req.prefill_pos, chunk=step,
                 )
             del self._prefilling[s]
             self._active[s] = True
@@ -2932,7 +3058,7 @@ class ContinuousEngine:
             self._trace(
                 req, "prefill",
                 dur_s=(now - req.admit_t) if req.admit_t else None,
-                tokens=req.prefill_pos,
+                tokens=req.prefill_pos, chunk=step,
             )
             self._tok[s] = int(req.prefill_tokens[-1])
             self._frozen.add(s)
@@ -2985,23 +3111,7 @@ class ContinuousEngine:
             delivered_total += emitted
             if finished:
                 self._evict(s)
-        # flight recorder (core/trace.py): one bounded append per chunk,
-        # at the same boundary — the postmortem's per-step state
-        self.recorder.record(
-            live_slots=int(self._active.sum()) + len(self._prefilling),
-            prefilling=len(self._prefilling),
-            decode_steps=n_exec if bool(emit.any()) else 0,
-            prefill_granted=int(sum(grants.values())),
-            spec_drafted=int(n_spec.sum()),
-            tokens_emitted=delivered_total,
-            pages_free=self.alloc.n_free,
-            pages_in_transit=self._pages_in_transit(),
-            preemptions=int(self._stat["preemptions"].value),
-            chunk_ms=round(chunk_dur * 1e3, 3),
-            host_ms=self._host_gap_ms,
-        )
-        self._refresh_prefix_digest()
-        return self.has_work()
+        return delivered_total
 
     def _refresh_prefix_digest(self) -> None:
         """Rebuild the fleet digests (both tiers) when membership

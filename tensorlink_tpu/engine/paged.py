@@ -803,6 +803,15 @@ class PrefixCache:
         return self.evict(len(self._by_page))
 
 
+# the step program's device phases, in execution order: each is one
+# top-level loop of ``paged_ragged_step`` under a ``jax.named_scope`` of
+# this name (inside them: attn, kv_write, mlp, lm_head, sample)
+RAGGED_PASS = "tlink.ragged_pass"
+VERIFY_EMIT = "tlink.verify_emit"
+DECODE_CONT = "tlink.decode_cont"
+STEP_PHASES = (RAGGED_PASS, VERIFY_EMIT, DECODE_CONT)
+
+
 def _paged_qkv(h, lp, cfg: ModelConfig, cos, sin):
     """Shared projection prologue of the paged blocks — q/k/v with
     biases, both qk-norm variants, and (partial-dim) rope. IDENTICAL math
@@ -861,21 +870,25 @@ def _paged_residual(
     norms and residual adds are untouched by sharding."""
     B, T = attn_raw.shape[:2]
     ap = lp["attn"]
-    attn_flat = _tp_gather(attn_raw.reshape(B, T, -1), tp_axis, tp_quant)
-    attn_out = _mm(attn_flat, ap["wo"])
-    if "bo" in ap:
-        attn_out = attn_out + ap["bo"]
-    attn_out = _tp_gather(attn_out, tp_axis, tp_quant)
+    with jax.named_scope("attn"):
+        attn_flat = _tp_gather(attn_raw.reshape(B, T, -1), tp_axis, tp_quant)
+        attn_out = _mm(attn_flat, ap["wo"])
+        if "bo" in ap:
+            attn_out = attn_out + ap["bo"]
+        attn_out = _tp_gather(attn_out, tp_axis, tp_quant)
+
+    def mlp(h):
+        with jax.named_scope("mlp"):
+            return _mlp(h, lp["mlp"], cfg, tp_axis, tp_quant)
+
     if cfg.norm_position == "post":
         x = x + _norm(attn_out, lp["ln1"], cfg)
-        x = x + _norm(_mlp(x, lp["mlp"], cfg, tp_axis, tp_quant), lp["ln2"], cfg)
+        x = x + _norm(mlp(x), lp["ln2"], cfg)
     elif cfg.parallel_residual:
-        x = x + attn_out + _mlp(
-            _norm(x, lp["ln2"], cfg), lp["mlp"], cfg, tp_axis, tp_quant
-        )
+        x = x + attn_out + mlp(_norm(x, lp["ln2"], cfg))
     else:
         x = x + attn_out
-        x = x + _mlp(_norm(x, lp["ln2"], cfg), lp["mlp"], cfg, tp_axis, tp_quant)
+        x = x + mlp(_norm(x, lp["ln2"], cfg))
     return x
 
 
@@ -967,23 +980,26 @@ def _paged_block(x, lp, cfg: ModelConfig, cos, sin, cache_kv, write_pg,
     prologue/epilogue above) — the parity tests pin the two paths
     token-for-token — but swaps the contiguous-cache dynamic_update_slice
     for a flat page scatter and the masked einsum for paged attention."""
-    h = x if cfg.norm_position == "post" else _norm(x, lp["ln1"], cfg)
-    q, k, v = _paged_qkv(h, lp, cfg, cos, sin)  # [S, 1, H, hd]
+    with jax.named_scope("attn"):
+        h = x if cfg.norm_position == "post" else _norm(x, lp["ln1"], cfg)
+        q, k, v = _paged_qkv(h, lp, cfg, cos, sin)  # [S, 1, H, hd]
 
     # per-slot scatter of the new token's KV through THE one write path
     # (quantizes in int8 mode); cache_kv is this layer's pages
-    kv = _scatter_kv(cache_kv, write_pg, write_off, k[:, 0], v[:, 0])
+    with jax.named_scope("kv_write"):
+        kv = _scatter_kv(cache_kv, write_pg, write_off, k[:, 0], v[:, 0])
     attn = paged_attention if kernel else paged_attention_ref
-    if len(kv) == 4:
-        attn_raw = attn(
-            q[:, 0], kv[0], kv[1], block_tables, att_len,
-            scale=_attn_scale(cfg), k_scale=kv[2], v_scale=kv[3],
-        )[:, None]
-    else:
-        attn_raw = attn(
-            q[:, 0], kv[0].astype(q.dtype), kv[1].astype(q.dtype),
-            block_tables, att_len, scale=_attn_scale(cfg),
-        )[:, None]  # [S, 1, Hq, hd]
+    with jax.named_scope("attn"):
+        if len(kv) == 4:
+            attn_raw = attn(
+                q[:, 0], kv[0], kv[1], block_tables, att_len,
+                scale=_attn_scale(cfg), k_scale=kv[2], v_scale=kv[3],
+            )[:, None]
+        else:
+            attn_raw = attn(
+                q[:, 0], kv[0].astype(q.dtype), kv[1].astype(q.dtype),
+                block_tables, att_len, scale=_attn_scale(cfg),
+            )[:, None]  # [S, 1, Hq, hd]
     return _paged_residual(x, attn_raw, lp, cfg, tp_axis, tp_quant), kv
 
 
@@ -1180,12 +1196,13 @@ def _verify_emit(blk, logits_v, base, n_spec, emit, seeds, steps, temp,
         counts, steps, remaining, ~emit, jnp.zeros_like(emit),
         jnp.zeros(S, jnp.int32), jnp.zeros(S, jnp.int32),
     )
-    (counts, steps, remaining, _stopped, ended, last, m), toks = (
-        jax.lax.scan(
-            vstep, init,
-            (logits_v.transpose(1, 0, 2), draft_next.T, has_draft.T),
+    with jax.named_scope(VERIFY_EMIT):
+        (counts, steps, remaining, _stopped, ended, last, m), toks = (
+            jax.lax.scan(
+                vstep, init,
+                (logits_v.transpose(1, 0, 2), draft_next.T, has_draft.T),
+            )
         )
-    )
     return toks.T, last, m, ended, counts, steps, remaining
 
 
@@ -1199,25 +1216,28 @@ def _ragged_block(x, lp, cfg: ModelConfig, cos, sin, cache_kv, write_pg,
     decode slot's single token and a mid-prefill slot's chunk go through
     the SAME projection, the SAME page scatter and the SAME ragged
     attention — the kernel-level erasure of the prefill/decode split."""
-    h = x if cfg.norm_position == "post" else _norm(x, lp["ln1"], cfg)
-    q, k, v = _paged_qkv(h, lp, cfg, cos, sin)  # [S, C, H, hd]
+    with jax.named_scope("attn"):
+        h = x if cfg.norm_position == "post" else _norm(x, lp["ln1"], cfg)
+        q, k, v = _paged_qkv(h, lp, cfg, cos, sin)  # [S, C, H, hd]
 
     # block scatter through the one write path (quantizes in int8 mode):
     # position (s, j) lands at (write_pg[s, j], write_off[s, j]); padding
     # rows and idle slots land on scratch page 0, unreachable from any
     # block table
-    kv = _scatter_kv(cache_kv, write_pg, write_off, k, v)
+    with jax.named_scope("kv_write"):
+        kv = _scatter_kv(cache_kv, write_pg, write_off, k, v)
     attn = ragged_paged_attention if kernel else ragged_paged_attention_ref
-    if len(kv) == 4:
-        attn_raw = attn(
-            q, kv[0], kv[1], block_tables, starts, n_valid,
-            scale=_attn_scale(cfg), k_scale=kv[2], v_scale=kv[3],
-        )
-    else:
-        attn_raw = attn(
-            q, kv[0].astype(q.dtype), kv[1].astype(q.dtype), block_tables,
-            starts, n_valid, scale=_attn_scale(cfg),
-        )  # [S, C, Hq, hd]
+    with jax.named_scope("attn"):
+        if len(kv) == 4:
+            attn_raw = attn(
+                q, kv[0], kv[1], block_tables, starts, n_valid,
+                scale=_attn_scale(cfg), k_scale=kv[2], v_scale=kv[3],
+            )
+        else:
+            attn_raw = attn(
+                q, kv[0].astype(q.dtype), kv[1].astype(q.dtype),
+                block_tables, starts, n_valid, scale=_attn_scale(cfg),
+            )  # [S, C, Hq, hd]
     return _paged_residual(x, attn_raw, lp, cfg, tp_axis, tp_quant), kv
 
 
@@ -1240,30 +1260,35 @@ def _ragged_step_impl(
     page = cache.page_size
     n_pp = cache.pages_per_slot
     bt = cache.block_tables
-    write_pg, write_off, pos, _valid = _ragged_write_indices(
-        bt, starts, n_valid, page, n_pp, C
-    )
-
-    x = _embed_tokens(params, blk, cfg)  # [S, C, d]
-    positions = pos
-    if cfg.pos == "learned":
-        x = x + params["embed"]["pos"][positions].astype(cfg.dtype)
-    cos = sin = None
-    if cfg.pos == "rope":
-        cos, sin = rope_tables(positions, _rope_dim(cfg), cfg.rope_theta)
-
-    def scan_fn(carry, xs):
-        lp, ckv = xs[0], xs[1:]
-        y, ckv = _ragged_block(
-            carry, lp, cfg, cos, sin, ckv, write_pg, write_off,
-            bt, starts, n_valid, kernel, tp_axis, tp_quant,
+    # the program's three phases carry names of their own (STEP_PHASES):
+    # each is one top-level loop, in this order, which is how a profiler
+    # trace whose events keep no scope still tells them apart
+    # (tests/test_step_scopes.py pins names and order)
+    with jax.named_scope(RAGGED_PASS):
+        write_pg, write_off, pos, _valid = _ragged_write_indices(
+            bt, starts, n_valid, page, n_pp, C
         )
-        return y, ckv
 
-    x, kv_new = jax.lax.scan(
-        scan_fn, x, (params["layers"], *_cache_kv(cache))
-    )
-    x = _norm(x, params["final_norm"], cfg)
+        x = _embed_tokens(params, blk, cfg)  # [S, C, d]
+        positions = pos
+        if cfg.pos == "learned":
+            x = x + params["embed"]["pos"][positions].astype(cfg.dtype)
+        cos = sin = None
+        if cfg.pos == "rope":
+            cos, sin = rope_tables(positions, _rope_dim(cfg), cfg.rope_theta)
+
+        def scan_fn(carry, xs):
+            lp, ckv = xs[0], xs[1:]
+            y, ckv = _ragged_block(
+                carry, lp, cfg, cos, sin, ckv, write_pg, write_off,
+                bt, starts, n_valid, kernel, tp_axis, tp_quant,
+            )
+            return y, ckv
+
+        x, kv_new = jax.lax.scan(
+            scan_fn, x, (params["layers"], *_cache_kv(cache))
+        )
+        x = _norm(x, params["final_norm"], cfg)
     # verification rows: the last spec_width rows of each slot's valid
     # span — base = n_valid - 1 - n_spec, so a non-speculating slot
     # (n_spec 0: plain decode, completing prefill, idle) gathers exactly
@@ -1277,8 +1302,9 @@ def _ragged_step_impl(
         base[:, None] + jnp.arange(W)[None, :],
         jnp.maximum(n_valid - 1, 0)[:, None],
     )  # [S, W]
-    h_v = x[jnp.arange(S)[:, None], gather]  # [S, W, d]
-    logits_v = _logits(params, h_v, cfg, tp_axis, tp_quant)  # [S, W, V]
+    with jax.named_scope(RAGGED_PASS):
+        h_v = x[jnp.arange(S)[:, None], gather]  # [S, W, d]
+        logits_v = _logits(params, h_v, cfg, tp_axis, tp_quant)  # [S, W, V]
 
     toks0, nxt, spec_m, ended, counts, steps, remaining = _verify_emit(
         blk, logits_v, base, n_spec, emit, seeds, steps, temp, top_k,
@@ -1313,9 +1339,10 @@ def _ragged_step_impl(
         jnp.int32(1), nxt, cache, done, steps, counts, remaining,
         spec_m, tokens,
     )
-    n_exec, _tok, cache, done, steps, counts, remaining, n_tok, tokens = (
-        jax.lax.while_loop(cond, body, init)
-    )
+    with jax.named_scope(DECODE_CONT):
+        n_exec, _tok, cache, done, steps, counts, remaining, n_tok, tokens = (
+            jax.lax.while_loop(cond, body, init)
+        )
     return (
         tokens, n_tok, spec_m, n_exec, cache, done, steps, counts,
         remaining,

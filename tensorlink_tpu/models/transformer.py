@@ -484,12 +484,13 @@ def _logits(
     untied ``lm_head`` holds LOCAL vocab columns and the logits reassemble
     via :func:`_tp_gather` so sampling sees the full distribution,
     identical on every shard."""
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["tok"].T.astype(cfg.dtype)
-    else:
-        logits = _tp_gather(_mm(x, params["lm_head"]), tp_axis, tp_quant)
-    if cfg.logit_cap is not None:
-        logits = cfg.logit_cap * jnp.tanh(logits / cfg.logit_cap)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = x @ params["embed"]["tok"].T.astype(cfg.dtype)
+        else:
+            logits = _tp_gather(_mm(x, params["lm_head"]), tp_axis, tp_quant)
+        if cfg.logit_cap is not None:
+            logits = cfg.logit_cap * jnp.tanh(logits / cfg.logit_cap)
     return logits
 
 

@@ -43,6 +43,11 @@ per-(position, head) dequant multiply (plus the int4 nibble unpack) into
 the VMEM read (the models/quant.py weight pattern), so the MXU arithmetic
 is unchanged. The ``_ref`` twins dequantize at the same gather, pinned
 against the kernels in tests/test_ops.py.
+
+Every ``pl.pallas_call`` names its kernel (``name=``) after its entry
+point: a profiler trace's reduction finds the kernels by these names, so
+renaming a Python function must not rename them
+(tests/test_step_scopes.py).
 """
 
 from __future__ import annotations
@@ -188,6 +193,7 @@ def flash_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B * Hkv * G, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
@@ -524,6 +530,7 @@ def paged_prefill_attention(
         args += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
+        name="paged_prefill_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(Hkv, n_pp),
@@ -782,6 +789,7 @@ def ragged_paged_attention(
         args += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
+        name="ragged_paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(S, Hkv, n_pp),
@@ -930,6 +938,7 @@ def paged_attention(
         args += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S, Hkv, n_pp),

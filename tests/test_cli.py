@@ -93,28 +93,3 @@ def test_status_report_format(tmp_path):
         assert "worker" in out and "peers (0)" in out
     finally:
         node.stop()
-
-
-def test_step_timer_and_device_memory():
-    from tensorlink_tpu.utils.profiling import StepTimer, device_memory
-
-    t = StepTimer(warmup=1)
-    for _ in range(3):
-        with t.step():
-            time.sleep(0.01)
-    assert len(t.times) == 2 and t.mean >= 0.01
-
-    mem = device_memory()
-    assert mem and mem[0]["platform"] == "cpu"
-
-
-def test_profiler_trace_writes(tmp_path):
-    import jax.numpy as jnp
-
-    from tensorlink_tpu.utils.profiling import annotate, trace
-
-    with trace(tmp_path / "tr"):
-        with annotate("matmul"):
-            (jnp.ones((64, 64)) @ jnp.ones((64, 64))).block_until_ready()
-    files = list((tmp_path / "tr").rglob("*"))
-    assert files, "no trace output written"

@@ -223,7 +223,16 @@ LEGACY_ENGINE_KEYS = (
     # host-tier promotions, and cross-replica prefix pulls
     "prefix_demotions", "host_tier_hits",
     "fleet_pulls", "fleet_pull_fallbacks",
+    # flat packing's count (ROADMAP S3): rows of the packed block that
+    # carried a token / rows the ragged pass computed
+    "ragged_rows_valid", "ragged_rows_computed",
+    # the anatomy of a chunk: cumulative host microseconds per phase
+    "chunk_us_between", "chunk_us_admit", "chunk_us_pack",
+    "chunk_us_dispatch", "chunk_us_wait", "chunk_us_drain",
+    "chunk_us_deliver", "chunk_us_post",
 )
+PHASES = ("between", "admit", "pack", "dispatch", "wait", "drain",
+          "deliver", "post")
 
 
 def test_engine_stats_keys_are_byte_compatible(tiny_engine):
@@ -271,6 +280,66 @@ def test_engine_metrics_render_matches_stats(tiny_engine):
     # callback gauges render live values
     free = [s for s in fams["tlink_engine_kv_pages_free"]["samples"]]
     assert float(free[0].rsplit(" ", 1)[1]) == ce.alloc.n_free
+    ce.close()
+
+
+def test_chunk_phase_counters_grow_by_the_records_values(tiny_engine):
+    """Each chunk adds its record's ``<phase>_ms`` to ``chunk_us_<phase>``
+    (integers, microseconds), so a window reads a phase as a difference
+    of two /stats reads whatever the flight recorder's length; the same
+    cells render at /metrics."""
+    from tensorlink_tpu.engine.continuous import ContinuousEngine
+
+    ce = ContinuousEngine(
+        tiny_engine, max_slots=2, page_size=8, chunk_steps=4
+    )
+    ce.submit([1, 2, 3], max_new_tokens=10, seed=1)
+    ce.step_chunk()
+    s0, n0 = ce.stats, len(ce.recorder)
+    ce.run_until_idle()
+    s1, recs = ce.stats, ce.recorder.records()[n0:]
+    assert len(recs) >= 2
+    for ph in PHASES:
+        grew = s1[f"chunk_us_{ph}"] - s0[f"chunk_us_{ph}"]
+        assert isinstance(grew, int)
+        assert grew == round(sum(r[f"{ph}_ms"] for r in recs) * 1e3), ph
+    assert s1["chunk_us_wait"] > s0["chunk_us_wait"]
+    fams = parse_exposition(ce.metrics.render({"model": "tiny"}))
+    sample = fams["tlink_engine_chunk_us_wait_total"]["samples"][0]
+    assert float(sample.rsplit(" ", 1)[1]) == s1["chunk_us_wait"]
+    assert "tlink_engine_ragged_rows_computed_total" in fams
+    snap = ce.serving_snapshot()
+    assert snap["chunk_us_deliver"] == s1["chunk_us_deliver"]
+    assert "host_gap_ms" not in snap
+    ce.close()
+
+
+def test_row_counters_count_valid_and_computed_rows(tiny_engine):
+    """ROADMAP S3's count: a chunk adds slots x chunk rows to
+    ``ragged_rows_computed`` whatever is live, and only the rows that
+    carried a token to ``ragged_rows_valid``: the prompt's tokens in the
+    prefill chunk, one row per live slot in a decode-only chunk."""
+    from tensorlink_tpu.engine.continuous import ContinuousEngine
+
+    S, C = 3, 16
+    ce = ContinuousEngine(
+        tiny_engine, max_slots=S, page_size=8, chunk_steps=2,
+        prefill_chunk=C,
+    )
+    ce.submit([1, 2, 3, 4, 5], max_new_tokens=8, seed=1)
+    ce.submit([6, 7, 8], max_new_tokens=8, seed=2)
+    ce.step_chunk()  # both prompts prefill in one block
+    s = ce.stats
+    assert s["ragged_rows_computed"] == S * C
+    assert s["ragged_rows_valid"] == 5 + 3
+    ce.step_chunk()  # decode only: one row per live slot
+    assert ce.live_slots == 2
+    d = {k: ce.stats[k] - s[k] for k in s}
+    assert d["ragged_rows_computed"] == S * C
+    assert d["ragged_rows_valid"] == 2
+    ce.step_chunk(admit_only=True)  # dispatches nothing: counts nothing
+    assert ce.stats["ragged_rows_computed"] == 2 * S * C
+    ce.run_until_idle()
     ce.close()
 
 

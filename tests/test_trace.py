@@ -14,7 +14,13 @@ Hard contracts pinned here:
   (freeze/export/commit on the source site, stage/adopt on the
   destination site);
 - the flight recorder ring is bounded, appends one record per chunk, and
-  dumps on engine error (``recorder.last_dump`` carries the final steps).
+  dumps on engine error (``recorder.last_dump`` carries the final steps);
+- a chunk's record holds its host phases (between, admit, pack,
+  dispatch, wait, drain, deliver, post), which add up to the wall time
+  from one chunk's entry to the next; the same phases are
+  ``tlink:<phase>`` annotations inside one ``tlink:chunk`` on a profiler
+  trace's host line, joined to the record by ``chunk`` == ``step``; and
+  a request's spans name the chunks it rode in.
 """
 
 import jax
@@ -28,7 +34,7 @@ from tensorlink_tpu.core.trace import (
     get_tracer,
     mint_trace_id,
 )
-from tensorlink_tpu.engine.continuous import ContinuousEngine
+from tensorlink_tpu.engine.continuous import CHUNK_PHASES, ContinuousEngine
 from tensorlink_tpu.engine.generate import GenerationEngine
 from tensorlink_tpu.engine.sampling import SamplingParams
 from tensorlink_tpu.models import ModelConfig, init_params
@@ -291,3 +297,169 @@ def test_engine_records_one_entry_per_chunk_and_dumps_on_error(tiny_engine):
     ce2.run_until_idle()
     ce2.close()
     assert ce2.recorder.last_dump is None
+
+
+# ---------------------------------------------------------------------------
+# the anatomy of a chunk: host phases in the record, on the profiler's
+# host line, and in the request's spans
+# ---------------------------------------------------------------------------
+
+PHASES = ("admit", "pack", "dispatch", "wait", "drain", "deliver", "post")
+
+
+def test_chunk_record_holds_every_phase_and_they_add_up(tiny_engine):
+    assert CHUNK_PHASES == PHASES  # the names are part of /stats and the docs
+    ce = _cont(tiny_engine, chunk_steps=2)
+    ce.submit([1, 2, 3], max_new_tokens=12, seed=3)
+    ce.run_until_idle()
+    recs = ce.recorder.records()
+    assert len(recs) >= 3
+    for r in recs:
+        for key in ("t0", "between_ms") + tuple(f"{p}_ms" for p in PHASES):
+            assert isinstance(r[key], float) and r[key] >= 0.0, key
+        # the two older fields keep their meaning
+        assert r["host_ms"] == pytest.approx(
+            r["admit_ms"] + r["pack_ms"], abs=1e-6)
+        assert r["chunk_ms"] == pytest.approx(
+            r["dispatch_ms"] + r["wait_ms"] + r["drain_ms"], abs=1e-6)
+    # the engine had no work before the first chunk: nothing lay between
+    assert recs[0]["between_ms"] == 0.0
+    # entry to entry: a chunk's seven phases and the next one's "between"
+    for a, b in zip(recs, recs[1:]):
+        wall_ms = (b["t0"] - a["t0"]) * 1e3
+        parts = sum(a[f"{p}_ms"] for p in PHASES) + b["between_ms"]
+        assert parts == pytest.approx(wall_ms, abs=1.0)
+        assert b["between_ms"] > 0.0
+    assert recs[-1]["host_ms"] == ce._host_gap_ms
+    ce.close()
+
+
+def test_between_is_not_counted_across_an_idle_engine(tiny_engine):
+    """``between`` is host time the device waits behind: after the engine
+    ran out of work, the wait for the next request is not part of it."""
+    import time
+
+    ce = _cont(tiny_engine, chunk_steps=2)
+    ce.submit([1, 2, 3], max_new_tokens=2, seed=3)
+    ce.run_until_idle()
+    time.sleep(0.05)
+    ce.submit([4, 5], max_new_tokens=2, seed=3)
+    ce.run_until_idle()
+    recs = ce.recorder.records()
+    first_of_second = next(r for r in recs if r["t0"] > recs[0]["t0"] + 0.05)
+    assert first_of_second["between_ms"] == 0.0
+    ce.close()
+
+
+def _tlink_events(trace_dir):
+    """(name, start_ns, end_ns, stats) of the ``tlink:`` annotations."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("tlink:"):
+                    out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda ev: ev[1])
+
+
+@pytest.mark.filterwarnings("ignore::DeprecationWarning")
+def test_profiler_trace_holds_chunks_joined_to_records_by_id(
+        tiny_engine, tmp_path):
+    """Taken through the program alone: each ``tlink:chunk`` annotation
+    carries the ``step`` of the chunk's flight-recorder record, and the
+    seven phases lie inside it, in order."""
+    ce = _cont(tiny_engine, chunk_steps=2)
+    ce.submit([1, 2, 3], max_new_tokens=2, seed=3)
+    ce.run_until_idle()  # the step program is built before the trace
+    n0 = len(ce.recorder)
+    ce.submit([1, 2, 3], max_new_tokens=4, seed=3)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        ce.step_chunk()
+        ce.step_chunk()
+    finally:
+        jax.profiler.stop_trace()
+    recs = ce.recorder.records()[n0:]
+    events = _tlink_events(tmp_path)
+    chunks = [e for e in events if e[0] == "tlink:chunk"]
+    assert [c[3]["chunk"] for c in chunks] == [r["step"] for r in recs]
+    assert len(chunks) == 2
+    for _name, a, b, _stats in chunks:
+        inside = [e for e in events
+                  if e[0] != "tlink:chunk" and a <= e[1] and e[2] <= b]
+        assert [e[0] for e in inside] == [f"tlink:{p}" for p in PHASES]
+        assert all(x[2] <= y[1] for x, y in zip(inside, inside[1:]))
+    ce.close()
+
+
+class _Writer:
+    def __init__(self):
+        self.sent = []
+
+    def write(self, data):
+        self.sent.append(data)
+
+    async def drain(self):
+        pass
+
+
+def test_streamed_request_spans_name_its_chunks(tiny_engine):
+    """One trace id holds the API's ``http_first_byte`` (handler entry to
+    the first delta written, on the API's clock) and the engine's
+    ``first_token`` (submit to first emit, on the engine's): the
+    difference is the way in and out. ``prefill_chunk``, ``prefill`` and
+    ``first_token`` carry the flight-recorder step of their chunk."""
+    import asyncio
+    from concurrent.futures import ThreadPoolExecutor
+
+    from tensorlink_tpu.api.formatter import ResponseFormatter
+    from tensorlink_tpu.api.schemas import GenerationRequest
+    from tensorlink_tpu.api.server import TensorlinkAPI
+
+    # prompt 20 > prefill_chunk 8: three prefill chunks before the token
+    ce = _cont(tiny_engine, chunk_steps=2, prefill_chunk=8)
+
+    class _Exec:
+        def generate_api(self, gen, on_delta, trace_id, meta_cb):
+            req = ce.submit(
+                list(range(1, 21)), max_new_tokens=5, seed=1,
+                trace_id=trace_id,
+                stream_cb=lambda tok: on_delta(f"{tok} ") or False,
+            )
+            ce.run_until_idle()
+            return {"prompt_tokens": 20, "finish_reason": "length",
+                    "completion_tokens": len(req.tokens)}
+
+    api = TensorlinkAPI.__new__(TensorlinkAPI)
+    api.executor = _Exec()
+    api._pool = ThreadPoolExecutor(1)
+    api._req_ids = {}
+    rid = mint_trace_id()
+    gen = GenerationRequest.parse({"hf_name": "m", "stream": True})
+    writer = _Writer()
+    try:
+        asyncio.run(api._stream_generate(
+            gen, ResponseFormatter("m", "simple"), writer, rid))
+    finally:
+        api._pool.shutdown()
+    assert writer.sent[-1] == b"data: [DONE]\n\n"
+    spans = get_tracer().collect(rid)
+    by = {}
+    for sp in spans:
+        by.setdefault(sp["name"], []).append(sp)
+    (first_byte,), (first_token,) = by["http_first_byte"], by["first_token"]
+    assert first_byte["site"] == "api"
+    assert first_byte["dur_ms"] >= first_token["dur_ms"] > 0
+    steps = {r["step"] for r in ce.recorder.records()}
+    rode = [sp["chunk"] for sp in by["prefill_chunk"]]
+    assert len(rode) == 3 and rode == sorted(rode) and set(rode) <= steps
+    assert by["prefill"][0]["chunk"] == rode[-1] == first_token["chunk"]
+    ce.close()
