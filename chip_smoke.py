@@ -16,8 +16,10 @@ It fails (exit code != 0, no result line) when JAX finds no accelerator, when
 any phase fails, or outside the repo. Phases:
 
 * ``kernels``: the three paged Pallas kernels against their ``jnp``
-  references on a small input at the model's head widths, bf16 / int8 /
-  packed-int4 pages.
+  references at the model's head widths, bf16 / int8 / packed-int4 pages:
+  a small input, then the served cells' shapes (8 slots, chunk 128, 256
+  pages a slot, contexts 250 and 1,200), there also at the head shapes of
+  two other presets (seven query rows a kv head; 32 kv heads of width 96).
 * ``serve`` (default, one chip): the traffic above, then the checks — the
   worker advertises the accelerator; every request went through the
   ``ContinuousEngine`` with the Pallas kernel in its lowered step program
@@ -370,8 +372,14 @@ def common_checks(port: int, worker, cont, taps: list, built: list,
 
 # -- phases ---------------------------------------------------------------
 def kernels_phase(cfg) -> None:
-    """Kernel vs ``jnp`` reference on the device, small input, the model's
-    head widths: what interpret mode on a CPU cannot show."""
+    """Kernel vs ``jnp`` reference on the device at the model's head
+    widths: what interpret mode on a CPU cannot show. A small input, then
+    the served cells' shapes (8 slots, chunk 128, 256 pages a slot,
+    contexts 250 and 1,200): block edges and VMEM limits only show
+    there. At those shapes also the heads of two other presets, which
+    take the walk's other paths: qwen2.5-7b's 28/4 (seven query rows a
+    kv head, off the 8-row tile) and phi3-mini's 32/32 of width 96 (one
+    row a head, kv heads in blocks, pages under a lane row)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -380,44 +388,67 @@ def kernels_phase(cfg) -> None:
     from tensorlink_tpu.ops import attention as A
 
     print("phase kernels:", flush=True)
-    S, C, page, n_pp = 4, 16, 16, 4
-    Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    P = 1 + S * n_pp
-    rng = np.random.default_rng(SEED)
-    q = jnp.asarray(rng.normal(size=(S, C, Hq, hd)), jnp.bfloat16)
-    kf = jnp.asarray(rng.normal(size=(P, Hkv, page, hd)), jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(P, Hkv, page, hd)), jnp.float32)
-    bt = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(S, n_pp), jnp.int32)
-    # decode slot, fresh prefill, mid-page prefill offset, idle slot
-    starts = jnp.asarray([37, 0, 21, 0], jnp.int32)
-    n_valid = jnp.asarray([1, 16, 9, 0], jnp.int32)
-    lengths = jnp.asarray([38, 16, 30, 0], jnp.int32)
-    scale = hd ** -0.5
-    modes = {
-        "bf16": (kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), {}),
-    }
-    for name, quant in (("int8", quantize_kv), ("int4", quantize_kv4)):
-        (k8, ks), (v8, vs) = quant(kf), quant(vf)
-        modes[name] = (k8, v8, {"k_scale": ks, "v_scale": vs})
-    for name, (kp, vp, kw) in modes.items():
-        cases = {
-            "ragged": (A.ragged_paged_attention, A.ragged_paged_attention_ref,
-                       (q, kp, vp, bt, starts, n_valid)),
-            "decode": (A.paged_attention, A.paged_attention_ref,
-                       (q[:, 0], kp, vp, bt, lengths)),
-            "prefill": (A.paged_prefill_attention, A.paged_prefill_attention_ref,
-                        (q[2], kp, vp, bt[2], starts[2])),
+    page = 16
+    model = (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
+    # (label, chunk, pages a slot, starts, n_valid, decode lengths, slot
+    # whose row feeds the one-slot prefill kernel)
+    small = ("small", 16, 4, [37, 0, 21, 0], [1, 16, 9, 0], [38, 16, 30, 0], 2)
+    cells = ("cells", 128, 256, [249, 1199, 1072, 0, 122, 0, 245, 3968],
+             [1, 1, 128, 128, 128, 0, 5, 128],
+             [250, 1200, 1200, 128, 250, 0, 250, 4096], 2)
+    shapes = (
+        # decode slot, fresh prefill, mid-page prefill offset, idle slot
+        (model, *small),
+        # decode rows at both contexts, a chunk ending at 1,200, a fresh
+        # and a mid-page chunk, an idle slot, verify rows, a full slot
+        (model, *cells),
+        ((28, 4, 128), "cells, 28/4 heads", *cells[1:]),
+        ((32, 32, 96), "cells, 32/32 heads of 96", *cells[1:]),
+    )
+    for (Hq, Hkv, hd), label, C, n_pp, starts, n_valid, lengths, pf in shapes:
+        scale = hd ** -0.5
+        S = len(starts)
+        P = 1 + S * n_pp
+        rng = np.random.default_rng(SEED)
+        q = jnp.asarray(rng.normal(size=(S, C, Hq, hd)), jnp.bfloat16)
+        kf = jnp.asarray(
+            rng.normal(size=(P, Hkv, page, hd)).astype(np.float32))
+        vf = jnp.asarray(
+            rng.normal(size=(P, Hkv, page, hd)).astype(np.float32))
+        bt = jnp.asarray(
+            rng.permutation(np.arange(1, P)).reshape(S, n_pp), jnp.int32)
+        starts, n_valid, lengths = (
+            jnp.asarray(x, jnp.int32) for x in (starts, n_valid, lengths))
+        modes = {
+            "bf16": (kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), {}),
         }
-        for kname, (kern, ref, args) in cases.items():
-            got = np.asarray(kern(*args, scale=scale, **kw), np.float32)
-            with jax.default_matmul_precision("highest"):
-                want = np.asarray(ref(*args, scale=scale, **kw), np.float32)
-            err = float(np.abs(got - want).max())
-            # outputs are O(1) averages of unit-normal values delivered in
-            # bf16: 2e-2 (+2%) admits a couple of ulps of that rounding,
-            # not a wrong scale row, head or page
-            check(np.allclose(got, want, rtol=2e-2, atol=2e-2),
-                  f"{kname} kernel, {name} pages: max |kernel - ref| = {err:.2e}")
+        for name, quant in (("int8", quantize_kv), ("int4", quantize_kv4)):
+            (k8, ks), (v8, vs) = quant(kf), quant(vf)
+            modes[name] = (k8, v8, {"k_scale": ks, "v_scale": vs})
+        for name, (kp, vp, kw) in modes.items():
+            cases = {
+                "ragged": (A.ragged_paged_attention,
+                           A.ragged_paged_attention_ref,
+                           (q, kp, vp, bt, starts, n_valid)),
+                "decode": (A.paged_attention, A.paged_attention_ref,
+                           (q[:, 0], kp, vp, bt, lengths)),
+            }
+            if (Hq, Hkv, hd) == model:  # no caller in the step program
+                cases["prefill"] = (A.paged_prefill_attention,
+                                    A.paged_prefill_attention_ref,
+                                    (q[pf], kp, vp, bt[pf], starts[pf]))
+            for kname, (kern, ref, args) in cases.items():
+                got = np.asarray(kern(*args, scale=scale, **kw), np.float32)
+                with jax.default_matmul_precision("highest"):
+                    want = np.asarray(ref(*args, scale=scale, **kw), np.float32)
+                err = float(np.abs(got - want).max())
+                # outputs are O(1) averages of unit-normal values delivered
+                # in bf16: 2e-2 (+2%) admits a couple of ulps of that
+                # rounding, not a wrong scale row, head or page
+                check(np.isfinite(got).all()
+                      and np.allclose(got, want, rtol=2e-2, atol=2e-2),
+                      f"{label}: {kname} kernel, {name} pages: max |kernel - "
+                      f"ref| = {err:.2e}")
 
 
 def serve_phase(ml, tmp: str, built: list) -> None:

@@ -318,6 +318,12 @@ _ENGINE_COUNTERS = (
      "rows of the packed block that carried a token"),
     ("ragged_rows_computed", "tlink_engine_ragged_rows_computed_total",
      "rows of the packed block the ragged pass computed (slots x chunk)"),
+    # the paged kernels' live-span walk (ROADMAP S7): pages the walk
+    # reads against the page slots a capacity-wide walk would visit
+    ("attn_pages_live", "tlink_engine_attn_pages_live_total",
+     "KV pages the attention passes of a chunk walk (contexts as packed)"),
+    ("attn_pages_capacity", "tlink_engine_attn_pages_capacity_total",
+     "page slots of those passes (slots x pages per slot)"),
 ) + tuple(
     # the anatomy of a chunk on the host (docs/SERVING.md "Observability"):
     # cumulative microseconds per phase of step_chunk, so a window reads
@@ -2961,6 +2967,19 @@ class ContinuousEngine:
                 with _Phase(ph, "post"):
                     self._count("ragged_rows_valid", int(n_valid.sum()))
                     self._count("ragged_rows_computed", blk.size)
+                    # what the kernels' walk follows, from the contexts
+                    # as packed: every slot with a row rides the ragged
+                    # pass, the emitting ones each further step (at the
+                    # packed context: off by at most a page a slot)
+                    pages = -(-(starts + n_valid) // self.page_size)
+                    self._count("attn_pages_live", int(
+                        pages[n_valid > 0].sum()
+                        + (n_exec - 1) * pages[emit].sum()
+                    ))
+                    self._count(
+                        "attn_pages_capacity",
+                        n_exec * blk.shape[0] * self.cache.pages_per_slot,
+                    )
                     # flight recorder (core/trace.py): the postmortem's
                     # per-step state; the append follows the phase's end
                     # because the record holds post_ms

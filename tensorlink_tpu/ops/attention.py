@@ -12,15 +12,24 @@ innermost, carrying running max/denominator/accumulator in VMEM scratch
 unified prefill+decode kernel (engine/paged.py::paged_ragged_step): one
 fixed-shape ``[slots, chunk]`` query block where per-slot ``(start,
 n_valid)`` are data — a decode-only slot carries 1 valid query, a
-mid-prefill slot up to a chunk, padding slots 0 — with KV gathered page
-by page through a scalar-prefetched block table; only each slot's LIVE
-pages stream from HBM and compute follows ``start + n_valid``, not
-capacity. :func:`paged_attention` (decode-only) and
-:func:`paged_prefill_attention` (one slot's offset chunk) are the legacy
-two-program pair it unified; the ``*_ref`` functions are the
-pure-jax.numpy references the CPU path and the parity tests run — the
-ragged reference is pinned bitwise against the legacy pair's
-composition.
+mid-prefill slot up to a chunk, padding slots 0. It and
+:func:`paged_attention` (the decode continuation's one query a slot) are
+ONE kernel body, the live-span page walk (:func:`_paged_walk_kernel`):
+grid ``(slot, kv-head block)`` — one head block where a slot's kv heads
+fit a grid step's VMEM, as qwen3-4b's 8 do — the page pool left in HBM,
+and inside a slot a loop over the KV blocks its live span reaches —
+whole pages (every kv head of a page is one contiguous copy) gathered
+through the scalar-prefetched block table, several pages a block so
+that a score tile spans 128 key positions, the next block's copies in
+flight under this block's arithmetic. Query rows are walked the same way: row blocks up to
+``n_valid``, each against KV up to its own causal limit. Nothing is
+walked, fetched or computed past ``start + n_valid``: cost follows what
+is live, not the slot's page capacity. :func:`paged_prefill_attention`
+(one slot's offset chunk, grid ``(kv_head, page)``) is the legacy
+prefill entry point and has no caller in the step program; the
+``*_ref`` functions are the pure-jax.numpy references the CPU path and
+the parity tests run — the ragged reference is pinned bitwise against
+the legacy pair's composition.
 
 Scope: **forward-only, causal, offset-0 prefill** — exactly the serving
 engine's fresh-cache prefill (engine/generate.py::_prefill). Training and
@@ -38,11 +47,15 @@ Quantized paged KV (``MLConfig.kv_quant="int8"`` / ``"int4"``): every paged
 entry point accepts optional ``k_scale``/``v_scale`` arrays ``[P, Hkv,
 page]`` marking the pages quantized — the kernels fetch the quantized KV
 bytes per page (half for int8; a page whose trailing dim is ``hd // 2``
-is PACKED int4, two values per byte — a quarter) and fuse the
-per-(position, head) dequant multiply (plus the int4 nibble unpack) into
-the VMEM read (the models/quant.py weight pattern), so the MXU arithmetic
-is unchanged. The ``_ref`` twins dequantize at the same gather, pinned
-against the kernels in tests/test_ops.py.
+is PACKED int4, two values per byte) and fuse the per-(position, head)
+dequant multiply (plus the int4 nibble unpack) into the VMEM read (the
+models/quant.py weight pattern), so the MXU arithmetic is unchanged.
+The walk's manual copies need operands whose minor dim is whole lane
+rows: int8 and bf16 pages of 128-wide heads go in as they are stored,
+scale planes as one lane row a page (:func:`_scale_rows`), packed int4
+pages lane-padded (:func:`_lane_pad`: a quarter of the bytes in HBM,
+half on the way into the kernel). The ``_ref`` twins dequantize at the
+same gather, pinned against the kernels in tests/test_ops.py.
 
 Every ``pl.pallas_call`` names its kernel (``name=``) after its entry
 point: a profiler trace's reduction finds the kernels by these names, so
@@ -221,7 +234,8 @@ def flash_attention(
 
 
 # ---------------------------------------------------------------------------
-# Paged decode attention (continuous batching, engine/paged.py)
+# Paged attention references and the one-slot prefill kernel (continuous
+# batching, engine/paged.py)
 # ---------------------------------------------------------------------------
 
 
@@ -602,10 +616,12 @@ def ragged_paged_attention_ref(
     speculating slot is just ``k + 1`` valid query rows at its current
     ``start`` — its token plus ``k`` draft tokens — and needs NO new
     masking: the causal ``q_pos`` rule above already makes draft row
-    ``j`` attend exactly ``<= start + j``, which is bitwise the context
-    ``k`` sequential decode steps would each see (pinned against the
-    sequential ``paged_attention_ref`` oracle in tests/test_ops.py::
-    test_ragged_verify_rows_match_sequential_decode_bitwise)."""
+    ``j`` attend exactly ``<= start + j``, which is exactly the context
+    ``k`` sequential decode steps would each see (held to the
+    sequential ``paged_attention_ref`` oracle within a few ulps in
+    tests/test_ops.py::test_ragged_verify_rows_match_sequential_decode:
+    the two references contract einsums of different shapes, and
+    tolerance, not bit equality, is the contract between them)."""
     S, C, Hq, hd = q.shape
     P, Hkv, page, _ = k_pages.shape
     n_pp = block_tables.shape[1]
@@ -635,85 +651,363 @@ def ragged_paged_attention_ref(
     return out.reshape(S, C, Hq, hd).astype(q.dtype)
 
 
-def _ragged_kernel(
+# ---------------------------------------------------------------------------
+# The live-span page walk: the one kernel body behind paged_attention and
+# ragged_paged_attention
+# ---------------------------------------------------------------------------
+
+# A score tile spans at least this many key positions (one full lane row)
+# and a row block holds at most this many query rows: both block sizes
+# follow from the operands' shapes, nothing is configured.
+_MIN_TILE = 128
+
+# Rows of a packed-sublane (bf16) tile: a row block that starts at a
+# dynamic offset must start on a tile edge, so its height is a multiple.
+_ROW_TILE = 16
+
+# What one grid step's blocks, buffers and f32 tiles may take of VMEM by
+# _heads_per_block's estimate (the scoped default is 16 MiB on a v5e).
+_VMEM_BUDGET = 12 * 2**20
+
+
+def _pages_per_block(page: int, n_pp: int) -> int:
+    """Pages a KV block of the walk holds: enough that a score tile spans
+    ``_MIN_TILE`` key positions (8 pages of 16), never more than a slot
+    has."""
+    return max(1, min(-(-_MIN_TILE // page), n_pp))
+
+
+def _positions_per_row_block(C: int, G: int) -> int:
+    """Chunk positions a row block holds: whole positions (``G`` query
+    rows each) dividing the chunk, so that every row block is whole. The
+    whole chunk if it is at most ``_MIN_TILE`` rows (one block, sliced
+    statically: any height will do). Else the largest divisor whose
+    ``cb·G`` rows are whole ``_ROW_TILE`` tiles within ``_MIN_TILE`` rows,
+    or failing that (a group size like 9) the smallest such divisor
+    above it."""
+    if C * G <= _MIN_TILE:
+        return C
+    whole = [cb for cb in range(1, C + 1)
+             if C % cb == 0 and cb * G % _ROW_TILE == 0]
+    within = [cb for cb in whole if cb * G <= _MIN_TILE]
+    return max(within) if within else min(whole, default=C)
+
+
+def _heads_per_block(
+    Hkv: int, CG: int, R: int, T: int, hd: int, q_itemsize: int,
+    kv_row_bytes: int,
+) -> int:
+    """KV heads a grid step computes: all of them where its VMEM fits
+    ``_VMEM_BUDGET``, else the largest divisor of ``Hkv`` that does. A
+    head costs its double-buffered query and output blocks (``CG`` rows
+    of ``hd`` values), two KV buffers (``T`` stored rows, K and V) and
+    the f32 tiles of one block's arithmetic: K and V dequantized, the
+    ``[R, T]`` scores, weights and mask, the accumulator and its
+    update."""
+    head = (
+        4 * CG * hd * q_itemsize + 4 * T * kv_row_bytes
+        + 4 * (2 * T * hd + 3 * R * T + 3 * R * hd)
+    )
+    return max(
+        hb for hb in range(1, Hkv + 1)
+        if Hkv % hb == 0 and (hb == 1 or hb * head <= _VMEM_BUDGET)
+    )
+
+
+def _lane_pad(x: jax.Array) -> jax.Array:
+    """``x`` with its minor dim padded to whole lane rows: a manual copy
+    cannot slice an HBM operand whose minor dim is not a multiple of 128
+    (Mosaic refuses the slice). int8 and bf16 pages of 128-wide heads
+    pass through untouched; packed int4 pages and pages of a head_dim
+    under 128 pay a padded copy of the layer's pool a call, on top of the
+    relayout XLA makes of such a pool for any kernel (it stores a minor
+    dim under a lane row page-minor). That cost follows the pool's
+    capacity, not the live span: ~0.4 ms of a 0.6 ms call at int4 and
+    ~0.9 ms a call at head_dim 64 for a 2,049-page pool, where the grid
+    walk took 2-6 ms (PERF.md section 6, PR 25)."""
+    short = -x.shape[-1] % _MIN_TILE
+    if not short:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, short)])
+
+
+def _scale_rows(scales: jax.Array, hb: int) -> jax.Array:
+    """A scale pool ``[P, Hkv, page]`` as one lane row a page and block of
+    ``hb`` heads, ``[P, Hkv/hb, 1, hb·page]`` (lane-padded): a page's
+    plane is under a lane row, and see :func:`_lane_pad`. This is not a
+    new copy in the step program: it stores the scale pool page-minor
+    and re-lays it for the kernel anyway (as it did for the BlockSpec
+    kernels, into lane-padded planes eight times this size)."""
+    P, Hkv, page = scales.shape
+    return _lane_pad(scales.reshape(P, Hkv // hb, 1, hb * page))
+
+
+def _walk_trips(start, n_valid, rb, *, cb: int, page: int, ppb: int):
+    """The walk's trip counts for row block ``rb`` of a slot whose valid
+    queries sit at positions ``start .. start + n_valid - 1``: ``(row
+    blocks to walk, key positions row block rb may attend, live pages
+    holding them, KV blocks holding those)``.
+
+    A row block sees keys up to its own last valid query, so a later row
+    block walks further than an earlier one and nothing walks past the
+    live span; a slot with no valid query walks nothing. The decode
+    kernel is the case ``start = max(length - 1, 0)``, ``n_valid =
+    min(length, 1)``. Integer arithmetic only: the kernel calls it on
+    scalars read from SMEM, the tests on Python ints."""
+    n_rb = (n_valid + cb - 1) // cb
+    kv_len = start + jnp.minimum((rb + 1) * cb, n_valid)
+    n_pages = (kv_len + page - 1) // page
+    n_kb = (n_pages + ppb - 1) // ppb
+    return n_rb, kv_len, n_pages, n_kb
+
+
+def _paged_walk_kernel(
     bt_ref,  # scalar-prefetch: block tables [S, n_pp]
-    start_ref,  # scalar-prefetch: per-slot start positions [S]
-    nv_ref,  # scalar-prefetch: per-slot valid counts [S]
-    q_ref,  # [1, 1, C·G, hd]
-    k_ref,  # [1, 1, page, hd] — page bt[s, i] of kv head h
-    v_ref,  # [1, 1, page, hd]
-    *rest,  # quantized: ks_ref, vs_ref [1, Hkv, page] then out + scratch
+    start_ref,  # scalar-prefetch: absolute position of each slot's row 0
+    nv_ref,  # scalar-prefetch: valid query positions per slot
+    q_ref,  # [1, hb, C·G, hd] (VMEM): this step's block of kv heads
+    k_hbm,  # [P, Hkv, page, hdk] — the whole pool, left in HBM
+    v_hbm,
+    *rest,  # quantized: ks_hbm, vs_hbm [P, Hkv/hb, 1, lanes]; out + scratch
     scale: float,
     page: int,
-    n_pp: int,
+    ppb: int,
     G: int,
+    cb: int,
     quantized: bool,
-    packed: bool = False,
+    packed: bool,
 ):
+    """One slot and one block of ``hb`` kv heads of the walk (grid
+    ``(slot, head block)``): row blocks of ``cb`` chunk positions up to
+    the slot's last valid query, each against KV blocks of ``ppb`` pages
+    up to its own causal limit, double-buffered."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem, \
+            m_ref, l_ref, acc_ref = rest
     else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
+        o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = rest
     s = pl.program_id(0)
-    h = pl.program_id(1)
-    i = pl.program_id(2)
+    hblk = pl.program_id(1)
     start = start_ref[s]
     nv = nv_ref[s]
+    Hkv, CG, hd = q_ref.shape[1:]  # Hkv: the heads of this block
+    # the block's heads of a page: all of it where one block holds them
+    # all, and contiguous in the pool either way
+    heads = () if Hkv == k_hbm.shape[1] else (pl.ds(hblk * Hkv, Hkv),)
+    hdk = hd // 2 if packed else hd  # bytes of a packed int4 row
+    R = cb * G  # query rows a row block holds
+    T = ppb * page  # key positions a KV block holds
+    trips = functools.partial(_walk_trips, start, nv, cb=cb, page=page,
+                              ppb=ppb)
 
-    @pl.when(i == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    CG = q_ref.shape[2]
-    # pages wholly past the slot's LAST VALID query position hold no
-    # attendable KV — skip their compute entirely (padding slots skip
-    # everything); the BlockSpec index map clamps their fetch to the
-    # scratch page, so both FLOPs and HBM traffic follow each slot's
-    # live span (start + n_valid), not the block or page capacity —
-    # the ragged win: a decode-only slot costs a decode slot, a
-    # prefill-heavy slot costs its chunk, in ONE dispatch
-    @pl.when((nv > 0) & (i * page <= start + nv - 1))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [C·G, hd]
-        k, v, ks, vs = _load_page(
-            k_ref, v_ref, ks_ref, vs_ref, h, packed
-        )
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [C·G, page]
+    def page_copies(kb, buf, p, wait: bool):
+        # a copy is one whole page, every kv head of the block: [Hkv,
+        # page, hdk] is contiguous in the pool. A wait needs the copy's
+        # shape only, so it names page 0 and never reads the block table
+        pg = (0 if wait else bt_ref[s, kb * ppb + p],)
+        copies = [
+            pltpu.make_async_copy(
+                k_hbm.at[pg + heads], kbuf.at[buf, :, p], sem.at[buf, 0]),
+            pltpu.make_async_copy(
+                v_hbm.at[pg + heads], vbuf.at[buf, :, p], sem.at[buf, 1]),
+        ]
         if quantized:
-            sc = sc * ks
-        # query row r is block position r // G at absolute start + r // G
-        row = jax.lax.broadcasted_iota(jnp.int32, (CG, page), 0) // G
-        q_pos = start + row
-        k_pos = i * page + jax.lax.broadcasted_iota(
-            jnp.int32, (CG, page), 1
-        )
-        ok = (k_pos <= q_pos) & (row < nv)
-        sc = jnp.where(ok, sc, NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p * vs if quantized else p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = m_new
+            copies += [
+                pltpu.make_async_copy(
+                    ks_hbm.at[pg + (hblk,)], ksbuf.at[buf, p],
+                    sem.at[buf, 2]),
+                pltpu.make_async_copy(
+                    vs_hbm.at[pg + (hblk,)], vsbuf.at[buf, p],
+                    sem.at[buf, 3]),
+            ]
+        return copies
 
-    @pl.when(i == n_pp - 1)
-    def _finalize():
-        # invalid rows (and whole padding slots) never ran _compute with
-        # an unmasked key: l == 0 there and the floor yields a zero row,
-        # matching ragged_paged_attention_ref's zeroing
-        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(
-            o_ref.dtype
-        )
+    def live_pages(kb, buf, n_pages, wait: bool):
+        # only the block's LIVE pages move: a block-table entry past the
+        # slot's live span is never read as an address
+        for p in range(ppb):
+            @pl.when(kb * ppb + p < n_pages)
+            def _():
+                for c in page_copies(kb, buf, p, wait):
+                    c.wait() if wait else c.start()
+
+    def scale_rows(sbuf, buf):
+        # [Hkv, T] f32, positions on the lane axis: a copied row holds
+        # one page's plane as (head, position) lanes, and head h's rows
+        # of the block's pages are laid side by side
+        return jnp.concatenate([
+            jnp.concatenate(
+                [sbuf[buf, p, :, pl.ds(h * page, page)] for p in range(ppb)],
+                axis=-1,
+            )
+            for h in range(Hkv)
+        ], axis=0).astype(jnp.float32)
+
+    q_row = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
+    k_col = jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
+    k_row = jax.lax.broadcasted_iota(jnp.int32, (T, hd), 0)
+    k_lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+
+    def rows_of(rb):
+        # one row block is the whole query block, whatever its height; a
+        # dynamic offset lands on a tile edge (_positions_per_row_block)
+        if CG == R:
+            return slice(None)
+        return pl.ds(pl.multiple_of(rb * R, R), R)
+
+    def row_block(rb, carry):
+        _, kv_len, n_pages, n_kb = trips(rb)
+        rows = rows_of(rb)
+        q = q_ref[0, :, rows, :].astype(jnp.float32) * scale  # [Hkv, R, hd]
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # query row r of the block is chunk position rb·cb + r // G
+        c_pos = rb * cb + q_row // G
+        live_pages(0, 0, n_pages, wait=False)
+
+        def kv_block(kb, carry):
+            buf = kb % 2
+
+            @pl.when(kb + 1 < n_kb)
+            def _():  # the next block's copies fly under this one's math
+                live_pages(kb + 1, 1 - buf, n_pages, wait=False)
+
+            live_pages(kb, buf, n_pages, wait=True)
+            k = kbuf[buf, :, :, :, pl.ds(0, hdk)]  # [Hkv, ppb, page, hdk]
+            v = vbuf[buf, :, :, :, pl.ds(0, hdk)]
+            if packed:
+                k, v = _unpack4(k), _unpack4(v)
+            else:
+                k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+            k = k.reshape(Hkv, T, hd)
+            v = v.reshape(Hkv, T, hd)
+            # positions of the buffer past kv_len were not copied, or
+            # lie past the span inside a copied page: whatever they hold
+            # (NaN included) must not reach p @ v as 0 × NaN
+            left = kv_len - kb * T  # live key positions of this block
+            v = jnp.where((k_row < left)[None], v, 0.0)
+            sc = jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )  # [Hkv, R, T]
+            if quantized:
+                # the dequant rides the score columns and the softmax
+                # weights: per-position scales stay on the lane axis
+                ks, vs = scale_rows(ksbuf, buf), scale_rows(vsbuf, buf)
+                vs = jnp.where(k_lane < left, vs, 0.0)
+                sc = sc * ks[:, None, :]
+            ok = ((kb * T + k_col <= start + c_pos) & (c_pos < nv))[None]
+            sc = jnp.where(ok, sc, NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
+            alpha = jnp.where(
+                m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new)
+            )
+            p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(
+                p, axis=2, keepdims=True
+            )
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p * vs[:, None, :] if quantized else p, v,
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[...] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, n_kb, kv_block, 0)
+        # rows past the last valid query met no unmasked key: l == 0 and
+        # the floor yields a zero row, like the references
+        o_ref[0, :, rows, :] = (
+            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        ).astype(o_ref.dtype)
+        return carry
+
+    def dead_row_block(rb, carry):
+        o_ref[0, :, rows_of(rb), :] = jnp.zeros((Hkv, R, hd), o_ref.dtype)
+        return carry
+
+    n_rb = trips(0)[0]
+    jax.lax.fori_loop(0, n_rb, row_block, 0)
+    jax.lax.fori_loop(n_rb, CG // R, dead_row_block, 0)
+
+
+def _paged_walk(
+    name: str,
+    qg: jax.Array,  # [S, Hkv, C·G, hd] — kv-head-major query rows
+    k_pages: jax.Array,  # [P, Hkv, page, hdk]
+    v_pages: jax.Array,
+    block_tables: jax.Array,  # int32 [S, n_pp]
+    starts: jax.Array,  # int32 [S]
+    n_valid: jax.Array,  # int32 [S]
+    k_scale: jax.Array | None,
+    v_scale: jax.Array | None,
+    *,
+    G: int,
+    scale: float,
+    interpret: bool,
+) -> jax.Array:
+    """The ``pl.pallas_call`` of the walk, named ``name``; returns
+    ``[S, Hkv, C·G, hd]``. Block sizes come from the shapes: KV blocks of
+    :func:`_pages_per_block` pages, row blocks of
+    :func:`_positions_per_row_block` chunk positions, grid steps of
+    :func:`_heads_per_block` kv heads."""
+    S, Hkv, CG, hd = qg.shape
+    _, _, page, hdk = k_pages.shape  # hdk = hd // 2 for packed int4
+    n_pp = block_tables.shape[1]
+    ppb = _pages_per_block(page, n_pp)
+    cb = _positions_per_row_block(CG // G, G)
+    quantized = k_scale is not None
+    kernel = functools.partial(
+        _paged_walk_kernel, scale=scale, page=page, ppb=ppb, G=G, cb=cb,
+        quantized=quantized, packed=quantized and hdk * 2 == hd,
+    )
+    args = [qg, _lane_pad(k_pages), _lane_pad(v_pages)]
+    hb = _heads_per_block(
+        Hkv, CG, cb * G, ppb * page, hd, qg.dtype.itemsize,
+        args[1].shape[-1] * args[1].dtype.itemsize,
+    )
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    q_spec = pl.BlockSpec((1, hb, CG, hd), lambda s, h, *_: (s, h, 0, 0))
+    # two buffers of one KV block each, laid out so that page p of a
+    # block lands at [:, p]: the same (page, hdk) trailing tile as the
+    # pool, and a leading-dim merge away from [Hkv, T, hd]
+    scratch = [
+        pltpu.VMEM((2, hb, ppb) + a.shape[2:], a.dtype) for a in args[1:]
+    ]
+    if quantized:
+        args += [_scale_rows(k_scale, hb), _scale_rows(v_scale, hb)]
+        scratch += [
+            pltpu.VMEM((2, ppb) + a.shape[2:], a.dtype) for a in args[3:]
+        ]
+    scratch += [
+        pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)),
+        pltpu.VMEM((hb, cb * G, 1), jnp.float32),  # running max
+        pltpu.VMEM((hb, cb * G, 1), jnp.float32),  # running denominator
+        pltpu.VMEM((hb, cb * G, hd), jnp.float32),  # accumulator
+    ]
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S, Hkv // hb),
+            in_specs=[q_spec] + [in_hbm] * (len(args) - 1),
+            out_specs=q_spec,
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+        ),
+        interpret=interpret,
+    )(
+        block_tables,
+        jnp.asarray(starts, jnp.int32),
+        jnp.asarray(n_valid, jnp.int32),
+        *args,
+    )
 
 
 # tlint: hot-path
@@ -733,159 +1027,40 @@ def ragged_paged_attention(
 ) -> jax.Array:
     """Ragged paged attention (TPU); returns ``[S, C, Hq, hd]``.
 
-    Grid ``(slot, kv_head, page_idx)`` — the decode kernel's grid with
-    the prefill kernel's whole-chunk query block: block tables, per-slot
-    starts and valid counts ride scalar prefetch, each grid step's k/v
-    BlockSpec indexes the PHYSICAL page ``block_tables[s, i]`` (clamped
-    to the scratch page once past the slot's live span, so the pipeline
-    skips the copy), GQA queries group on the kv-head axis, and the
-    online softmax carries ``[C·G, 1]`` running max/denominator. ONE
-    compiled program serves every (prefill/decode mix, offset, length,
-    page assignment) — slot roles are data, not shape, which is what
-    deletes the separate-prefill-then-decode dispatch seam. Speculative
-    verify slots (k+1 valid rows at a decode slot's current start) ride
-    the same causal ``q_pos`` masking — see the reference's "Verify
-    mode" note."""
+    The live-span walk (:func:`_paged_walk_kernel`) over the whole-chunk
+    query block: grid ``(slot, kv-head block)``, block tables, per-slot
+    starts and valid counts on scalar prefetch, the page pool left in
+    HBM. Inside a slot, row blocks of up to 128 query rows are walked up
+    to ``n_valid`` and each walks KV blocks of whole pages (8 pages of
+    16: a 128-wide score tile, the block's kv heads in one batched
+    matmul) up to its own causal limit, the next block's page copies in flight under this
+    block's arithmetic. So a decode row in the block costs a decode row,
+    a mid-prefill slot its chunk against its context, a padding slot
+    its zero output. ONE compiled program serves every (prefill/decode
+    mix, offset, length, page assignment) — slot roles are data, not
+    shape. Speculative verify slots (k+1 valid rows at a decode slot's
+    current start) ride the same causal ``q_pos`` masking — see the
+    reference's "Verify mode" note."""
     S, C, Hq, hd = q.shape
-    P, Hkv, page, hdk = k_pages.shape  # hdk = hd // 2 for packed int4
-    n_pp = block_tables.shape[1]
+    Hkv = k_pages.shape[1]
     G = Hq // Hkv
-    # [S, C, Hq, hd] -> [S, Hkv, C·G, hd]: kv-head-major so one grid
-    # row's queries share the page block prefetch pulled in
+    # [S, C, Hq, hd] -> [S, Hkv, C·G, hd]: kv-head-major, so the rows of
+    # one kv head are one matmul operand against that head's pages
     qg = (
         q.reshape(S, C, Hkv, G, hd)
         .transpose(0, 2, 1, 3, 4)
         .reshape(S, Hkv, C * G, hd)
     )
-    quantized = k_scale is not None
-    packed = quantized and hdk * 2 == hd
-    kernel = functools.partial(
-        _ragged_kernel, scale=scale, page=page, n_pp=n_pp, G=G,
-        quantized=quantized, packed=packed,
-    )
-    # pages wholly past the slot's live span clamp their fetch to scratch
-    # page 0 (repeated block indexes are not re-copied by the pipeline):
-    # HBM traffic follows start + n_valid per slot, not the capacity
-    def page_idx(s, h, i, bt, st, nv, p=page):
-        return (
-            jnp.where(
-                (nv[s] > 0) & (i * p <= st[s] + nv[s] - 1), bt[s, i], 0
-            ),
-            h, 0, 0,
-        )
-
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, C * G, hd), lambda s, h, i, bt, st, nv: (s, h, 0, 0)
-        ),
-        pl.BlockSpec((1, 1, page, hdk), page_idx),
-        pl.BlockSpec((1, 1, page, hdk), page_idx),
-    ]
-    args = [qg, k_pages, v_pages]
-    if quantized:
-        # int8 pages ride with their per-(position, head) scales — same
-        # physical page index, dequant fused in-kernel at the VMEM read
-        in_specs += [_scale_spec(Hkv, page, page_idx)] * 2
-        args += [k_scale, v_scale]
-    out = pl.pallas_call(
-        kernel,
-        name="ragged_paged_attention",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(S, Hkv, n_pp),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, C * G, hd),
-                lambda s, h, i, bt, st, nv: (s, h, 0, 0),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((C * G, 1), jnp.float32),
-                pltpu.VMEM((C * G, 1), jnp.float32),
-                pltpu.VMEM((C * G, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((S, Hkv, C * G, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+    out = _paged_walk(
+        "ragged_paged_attention", qg, k_pages, v_pages, block_tables,
+        starts, n_valid, k_scale, v_scale, G=G, scale=scale,
         interpret=interpret,
-    )(
-        block_tables,
-        jnp.asarray(starts, jnp.int32),
-        jnp.asarray(n_valid, jnp.int32),
-        *args,
     )
     return (
         out.reshape(S, Hkv, C, G, hd)
         .transpose(0, 2, 1, 3, 4)
         .reshape(S, C, Hq, hd)
     )
-
-
-def _paged_kernel(
-    bt_ref,  # scalar-prefetch: block tables [S, n_pp]
-    len_ref,  # scalar-prefetch: lengths [S]
-    q_ref,  # [1, 1, G, hd]
-    k_ref,  # [1, 1, page, hd] — page bt[s, i] of kv head h
-    v_ref,  # [1, 1, page, hd]
-    *rest,  # quantized: ks_ref, vs_ref [1, Hkv, page] then out + scratch
-    scale: float,
-    page: int,
-    n_pp: int,
-    quantized: bool,
-    packed: bool = False,
-):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    s = pl.program_id(0)
-    h = pl.program_id(1)
-    i = pl.program_id(2)
-    length = len_ref[s]
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # pages wholly past the slot's length hold no live KV — skip their
-    # compute entirely (the ragged win: cost follows length, not capacity)
-    @pl.when(i * page < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [G, hd]
-        k, v, ks, vs = _load_page(
-            k_ref, v_ref, ks_ref, vs_ref, h, packed
-        )
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [G, page]
-        if quantized:
-            sc = sc * ks
-        pos = i * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        ok = pos < length  # [1, page]
-        sc = jnp.where(ok, sc, NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)  # [G, page]
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p * vs if quantized else p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = m_new
-
-    @pl.when(i == n_pp - 1)
-    def _finalize():
-        # a free slot (length 0) never ran _compute: l == 0 and the floor
-        # yields a zero row, matching paged_attention_ref
-        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(
-            o_ref.dtype
-        )
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -903,61 +1078,24 @@ def paged_attention(
 ) -> jax.Array:
     """Paged decode attention; returns ``[S, Hq, hd]``.
 
-    Grid ``(slot, kv_head, page_idx)``: the block table rides scalar
-    prefetch, so each grid step's k/v BlockSpec indexes the PHYSICAL page
-    ``block_tables[s, i]`` — the gather happens in the pipeline's HBM→VMEM
-    copies and repeated KV heads are never materialized (GQA queries group
-    on the kv-head axis like the flash kernel). The kv-head-major page
-    layout gives each block TPU-native ``(page, hd)`` trailing tiles. One
+    The same live-span walk as :func:`ragged_paged_attention` with one
+    query position a slot, at ``lengths - 1``: grid ``(slot, kv-head
+    block)``, a loop over the KV blocks the slot's length reaches
+    (whole-page copies, double-buffered, the block's kv heads in one
+    batched matmul), nothing for a free slot (length 0) but its zero
+    output. GQA queries group on the
+    kv-head axis, so repeated KV heads are never materialized. One
     compiled program serves every (length mix, page assignment) — the
     block table and lengths are data, not shape."""
     S, Hq, hd = q.shape
-    P, Hkv, page, hdk = k_pages.shape  # hdk = hd // 2 for packed int4
-    n_pp = block_tables.shape[1]
-    G = Hq // Hkv
-    qg = q.reshape(S, Hkv, G, hd)
-    quantized = k_scale is not None
-    packed = quantized and hdk * 2 == hd
-    kernel = functools.partial(
-        _paged_kernel, scale=scale, page=page, n_pp=n_pp,
-        quantized=quantized, packed=packed,
+    Hkv = k_pages.shape[1]
+    lengths = jnp.asarray(lengths, jnp.int32)
+    out = _paged_walk(
+        "paged_attention", q.reshape(S, Hkv, Hq // Hkv, hd), k_pages,
+        v_pages, block_tables, jnp.maximum(lengths - 1, 0),
+        jnp.minimum(lengths, 1),
+        k_scale, v_scale, G=Hq // Hkv, scale=scale, interpret=interpret,
     )
-    def page_idx(s, h, i, bt, ln):
-        return (bt[s, i], h, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda s, h, i, bt, ln: (s, h, 0, 0)),
-        pl.BlockSpec((1, 1, page, hdk), page_idx),
-        pl.BlockSpec((1, 1, page, hdk), page_idx),
-    ]
-    args = [qg, k_pages, v_pages]
-    if quantized:
-        # int8 pages ride with their per-(position, head) scales — same
-        # physical page index, dequant fused in-kernel at the VMEM read
-        in_specs += [_scale_spec(Hkv, page, page_idx)] * 2
-        args += [k_scale, v_scale]
-    out = pl.pallas_call(
-        kernel,
-        name="paged_attention",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(S, Hkv, n_pp),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, G, hd), lambda s, h, i, bt, ln: (s, h, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((S, Hkv, G, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(block_tables, lengths, *args)
     return out.reshape(S, Hq, hd)
 
 

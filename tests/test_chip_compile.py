@@ -54,14 +54,14 @@ def v5e(v5e_chips):
     return SingleDeviceSharding(v5e_chips[0])
 
 
-def _pages(dev, mode: str, hkv: int):
+def _pages(dev, mode: str, hkv: int, hd: int = HD):
     """(k/v page spec, {k_scale, v_scale} specs) for a page storage mode."""
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=dev)
 
     if mode == "bf16":
-        return sds((P, hkv, PAGE, HD), jnp.bfloat16), {}
-    hdk = HD // 2 if mode == "int4" else HD  # int4: two values per byte
+        return sds((P, hkv, PAGE, hd), jnp.bfloat16), {}
+    hdk = hd // 2 if mode == "int4" else hd  # int4: two values per byte
     sc = sds((P, hkv, PAGE), jnp.float32)
     return sds((P, hkv, PAGE, hdk), jnp.int8), {"k_scale": sc, "v_scale": sc}
 
@@ -110,6 +110,44 @@ def test_decode_kernel_compiles_for_v5e(v5e, mode, heads):
         A.paged_attention, _q(v5e, S, hq, HD), kv, kv,
         _i32(v5e, S, N_PP), _i32(v5e, S), **scales,
     )
+
+
+# (Hq, Hkv, head_dim) of other presets a worker may be asked to serve:
+# group sizes of one and seven query rows a kv head (under and off the
+# 8-row tile: the row slices), 32 kv heads (a slot's heads do not fit one
+# grid step's VMEM at chunk 128: the head blocks), head widths under and
+# over a lane row (the lane padding, the buffers)
+OTHER_HEADS = (
+    ("olmo2-7b", (32, 32, 128)),
+    ("phi3-mini", (32, 32, 96)),
+    ("gemma-7b", (16, 16, 256)),
+    ("12x12-hd64", (12, 12, 64)),
+    ("qwen2p5-7b", (28, 4, 128)),
+    ("qwen2p5-7b-tp4shard", (7, 1, 128)),
+)
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("kernel", ["decode", "ragged-C128"])
+@pytest.mark.parametrize(
+    "heads", [h for _, h in OTHER_HEADS], ids=[n for n, _ in OTHER_HEADS]
+)
+def test_walk_compiles_at_other_head_shapes(v5e, heads, kernel, mode):
+    """Both kernels of the step program at head shapes other than the
+    benchmark's: nothing falls back to the CPU, so a shape Mosaic
+    refuses would fail a worker's warm-up on the chip."""
+    hq, hkv, hd = heads
+    kv, scales = _pages(v5e, mode, hkv, hd)
+    if kernel == "decode":
+        _compiles_with_kernel(
+            A.paged_attention, _q(v5e, S, hq, hd), kv, kv,
+            _i32(v5e, S, N_PP), _i32(v5e, S), **scales,
+        )
+    else:
+        _compiles_with_kernel(
+            A.ragged_paged_attention, _q(v5e, S, 128, hq, hd), kv, kv,
+            _i32(v5e, S, N_PP), _i32(v5e, S), _i32(v5e, S), **scales,
+        )
 
 
 @pytest.mark.parametrize("mode", MODES)

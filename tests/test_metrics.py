@@ -226,6 +226,9 @@ LEGACY_ENGINE_KEYS = (
     # flat packing's count (ROADMAP S3): rows of the packed block that
     # carried a token / rows the ragged pass computed
     "ragged_rows_valid", "ragged_rows_computed",
+    # the paged kernels' live-span walk (ROADMAP S7): pages walked /
+    # page slots of the same passes
+    "attn_pages_live", "attn_pages_capacity",
     # the anatomy of a chunk: cumulative host microseconds per phase
     "chunk_us_between", "chunk_us_admit", "chunk_us_pack",
     "chunk_us_dispatch", "chunk_us_wait", "chunk_us_drain",
@@ -311,6 +314,43 @@ def test_chunk_phase_counters_grow_by_the_records_values(tiny_engine):
     snap = ce.serving_snapshot()
     assert snap["chunk_us_deliver"] == s1["chunk_us_deliver"]
     assert "host_gap_ms" not in snap
+    ce.close()
+
+
+def test_page_counters_follow_the_slot_contexts(tiny_engine):
+    """The live-span walk's count (ROADMAP S7): every attention pass of a
+    chunk adds slots x pages-per-slot to ``attn_pages_capacity``; to
+    ``attn_pages_live`` the ragged pass adds ceil(context / page) of every
+    slot with a row, and each continuation step that of the emitting
+    slots: a slot still mid-prompt rides the ragged pass only."""
+    from tensorlink_tpu.engine.continuous import ContinuousEngine
+
+    S, C, page = 3, 16, 8
+    ce = ContinuousEngine(
+        tiny_engine, max_slots=S, page_size=page, chunk_steps=3,
+        prefill_chunk=C,
+    )
+    n_pp = ce.cache.pages_per_slot
+    ce.submit(list(range(1, 21)), max_new_tokens=12, seed=1)  # 20 > C
+    ce.submit([6, 7, 8], max_new_tokens=12, seed=2)
+    ce.step_chunk()
+    # slot A: 16 of its 20 prompt tokens, no emit: 2 pages, one pass.
+    # slot B: its whole prompt, emits: 1 page in each of the 3 passes
+    s = ce.stats
+    assert s["decode_steps"] == 3
+    assert s["attn_pages_capacity"] == 3 * S * n_pp
+    assert s["attn_pages_live"] == 2 + 3 * 1
+    ce.step_chunk()
+    # slot A: its last 4 prompt tokens, context 20: 3 pages, emits.
+    # slot B: decode row at context 3 + 3: 1 page
+    d = {k: ce.stats[k] - s[k] for k in s}
+    assert d["attn_pages_capacity"] == 3 * S * n_pp
+    assert d["attn_pages_live"] == 3 * (3 + 1)
+    ce.step_chunk(admit_only=True)  # dispatches nothing: counts nothing
+    assert ce.stats["attn_pages_capacity"] == 6 * S * n_pp
+    fams = parse_exposition(ce.metrics.render({"model": "tiny"}))
+    assert "tlink_engine_attn_pages_live_total" in fams
+    ce.run_until_idle()
     ce.close()
 
 

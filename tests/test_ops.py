@@ -7,6 +7,8 @@ engine with cfg.flash_attention on. The paged decode kernel
 (continuous batching) is pinned against its pure-jnp reference and the
 reference against the dense einsum path."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -381,16 +383,20 @@ def test_ragged_ref_matches_decode_and_prefill_refs_bitwise():
         assert np.array_equal(ref[s, : nv[s]], np.asarray(pf)[: nv[s]]), s
 
 
-def test_ragged_verify_rows_match_sequential_decode_bitwise():
+def test_ragged_verify_rows_match_sequential_decode():
     """THE speculative-verification pin (docs/SERVING.md "Speculative
     decoding"): a verifying slot — k+1 valid query rows at its current
-    start — produces, at every row j, BITWISE the attention output of a
+    start — produces, at every row j, the attention output of a
     sequential decode step at length ``start + j + 1`` with the same
-    query. The ragged reference's causal ``q_pos`` masking already
-    encodes verify mode; no new kernel logic exists to drift. (Row 0 is
-    the existing decode-composition pin; rows 1..k are what speculation
-    adds.) The Pallas kernel is held to the reference on the same
-    verify-shaped block."""
+    query, to a few ulps. The ragged reference's causal ``q_pos``
+    masking already encodes verify mode; no new kernel logic exists to
+    drift. The two references contract einsums of different shapes, and
+    the CPU backend does not promise them the same summation order
+    (``0.13055041`` against ``0.13055044``): tolerance is the contract
+    (ROADMAP D9), not bit equality. (Row 0 is the existing
+    decode-composition pin; rows 1..k are what speculation adds.) The
+    Pallas kernel is held to the reference on the same verify-shaped
+    block."""
     rng = np.random.default_rng(11)
     S, C, Hq, Hkv, hd, page, n_pp = 2, 8, 4, 2, 16, 8, 4
     start, k = 13, 4  # a decode slot at length 13 verifying 4 drafts
@@ -408,11 +414,275 @@ def test_ragged_verify_rows_match_sequential_decode_bitwise():
             q[0:1, j], kp, vp, bt[0:1],
             jnp.asarray([start + j + 1], jnp.int32), scale=scale,
         )
-        assert np.array_equal(ref[0, j], np.asarray(dec)[0]), j
+        np.testing.assert_allclose(
+            ref[0, j], np.asarray(dec)[0], rtol=0, atol=1e-6, err_msg=str(j)
+        )
     got = ragged_paged_attention(
         q, kp, vp, bt, st, nvj, scale=scale, interpret=True
     )
     np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the live-span walk of the two paged kernels: block and row-block edges
+# ---------------------------------------------------------------------------
+def test_walk_trip_counts_follow_the_live_span():
+    """The pure helpers behind the walk: block sizes from shapes (a score
+    tile of at least 128 key positions, row blocks of at most 128 query
+    rows that divide the chunk) and, from ``(start, n_valid)``, how many
+    row blocks a slot walks and how many pages and KV blocks each row
+    block reaches — its own causal limit, never the capacity."""
+    from tensorlink_tpu.ops.attention import (
+        _heads_per_block, _pages_per_block, _positions_per_row_block,
+        _walk_trips,
+    )
+
+    assert _pages_per_block(16, 256) == 8  # the served shape: 8 x 16
+    assert _pages_per_block(16, 4) == 4  # clamps to what a slot has
+    assert _pages_per_block(8, 20) == 16
+    assert _pages_per_block(256, 16) == 1
+    assert _positions_per_row_block(128, 4) == 32  # 128 rows a block
+    assert _positions_per_row_block(1, 4) == 1  # the decode kernel
+    assert _positions_per_row_block(16, 4) == 16  # one block, 64 rows
+    assert _positions_per_row_block(48, 4) == 24  # divides the chunk
+    assert _positions_per_row_block(8, 16) == 8
+    # group sizes that are not a power of two (28/4 heads, its tp=4
+    # shard 7/1) and one (32/32): a row block at a dynamic offset is
+    # whole 16-row tiles, one block of any height is the whole chunk
+    assert _positions_per_row_block(128, 7) == 16  # 112 rows
+    assert _positions_per_row_block(1, 7) == 1  # the decode kernel
+    assert _positions_per_row_block(8, 7) == 8  # one block, 56 rows
+    assert _positions_per_row_block(128, 1) == 128  # one block
+    assert _positions_per_row_block(256, 1) == 128
+    assert _positions_per_row_block(128, 9) == 16  # 144 rows: none fits
+    # kv heads a grid step: all where VMEM allows (qwen3-4b's 8, its
+    # decode kernel, a tp shard), a divisor where not (olmo2-7b 32/32,
+    # gemma-7b 16/16 at head_dim 256)
+    assert _heads_per_block(8, 512, 128, 128, 128, 2, 128) == 8
+    assert _heads_per_block(8, 4, 4, 128, 128, 2, 128) == 8
+    assert _heads_per_block(2, 512, 128, 128, 128, 2, 128) == 2
+    assert _heads_per_block(32, 128, 128, 128, 128, 2, 128) == 16
+    assert _heads_per_block(32, 1, 1, 128, 128, 2, 256) == 32
+    assert _heads_per_block(16, 128, 128, 128, 256, 2, 256) == 8
+    assert _heads_per_block(3, 2**20, 128, 128, 128, 2, 128) == 1
+
+    def trips(start, nv, rb, cb=32, page=16, ppb=8):
+        return tuple(
+            int(x) for x in _walk_trips(start, nv, rb, cb=cb, page=page,
+                                        ppb=ppb)
+        )
+
+    # (row blocks, key positions, live pages, KV blocks)
+    assert trips(0, 0, 0) == (0, 0, 0, 0)  # a padding slot walks nothing
+    assert trips(249, 1, 0) == (1, 250, 16, 2)  # a decode row at 250
+    assert trips(1199, 1, 0) == (1, 1200, 75, 10)
+    assert trips(0, 128, 0) == (4, 32, 2, 1)  # a fresh chunk: row block
+    assert trips(0, 128, 3) == (4, 128, 8, 1)  # 0 sees 32 keys, 3 all 128
+    assert trips(1072, 128, 1) == (4, 1136, 71, 9)
+    assert trips(245, 5, 0) == (1, 250, 16, 2)  # verify rows
+    assert trips(100, 33, 1) == (2, 133, 9, 2)  # one row over a row block
+    # the decode kernel's case: start = max(length - 1, 0), n_valid =
+    # min(length, 1)
+    for length, want in ((0, (0, 0, 0)), (127, (127, 8, 1)),
+                         (128, (128, 8, 1)), (129, (129, 9, 2))):
+        got = trips(max(length - 1, 0), min(length, 1), 0, cb=1)
+        assert got == (min(length, 1), *want), (length, got)
+
+
+def _poison(kp, vp, bt, live_len):
+    """Pools in which every position a slot must not read holds NaN: the
+    pages its dead block-table entries name (all point at one NaN page),
+    and the positions past ``live_len`` inside its last live page. ``kp``
+    and ``vp`` are f32 pages, or the scale planes ``[P, Hkv, page]`` of
+    quantized ones (an int8 value cannot be NaN; its scale can). Returns
+    (poisoned k, v, block tables, finite k, v for the reference)."""
+    kp, vp, bt = np.array(kp), np.array(vp), np.array(bt)
+    page = kp.shape[2]
+    nan_page = kp.shape[0]  # one more page, all NaN
+    pad = np.full((1,) + kp.shape[1:], np.nan, np.float32)
+    kp, vp = np.concatenate([kp, pad]), np.concatenate([vp, pad])
+    for s, n in enumerate(live_len):
+        n_live = -(-int(n) // page)
+        bt[s, n_live:] = nan_page
+        if n % page:
+            kp[bt[s, n_live - 1], :, n % page:] = np.nan
+            vp[bt[s, n_live - 1], :, n % page:] = np.nan
+    return (jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
+            jnp.asarray(np.nan_to_num(kp)), jnp.asarray(np.nan_to_num(vp)))
+
+
+def _poisoned_int8(rng, P, Hkv, page, hd, bt, live_len):
+    """int8 pools whose scale planes are poisoned like :func:`_poison`
+    (the values behind a NaN scale are full-scale 127s): ``(k8, v8, block
+    tables, poisoned {k_scale, v_scale}, finite ones for the
+    reference)``."""
+    _, _, k8, ks, v8, vs = _quantized_pages(rng, P, Hkv, page, hd)
+    ks_p, vs_p, bt, ks_r, vs_r = _poison(ks, vs, bt, live_len)
+    full = jnp.full((1, Hkv, page, hd), 127, jnp.int8)
+    k8, v8 = jnp.concatenate([k8, full]), jnp.concatenate([v8, full])
+    return (k8, v8, bt, {"k_scale": ks_p, "v_scale": vs_p},
+            {"k_scale": ks_r, "v_scale": vs_r})
+
+
+@pytest.mark.parametrize(
+    "S,Hq,Hkv,hd,page,n_pp,lens,poison",
+    [
+        # on, under and over a block edge (8 pages of 16 = 128 keys), a
+        # free slot between live ones; 20 pages a slot: the last block
+        # is partial (n_pp not a multiple of the block's 8 pages)
+        (4, 8, 2, 32, 16, 20, [128, 0, 127, 129], False),
+        # into and to the end of that partial last block
+        (4, 8, 2, 32, 16, 20, [320, 257, 1, 300], False),
+        # one kv head: a tensor-parallel shard's view
+        (3, 4, 1, 32, 16, 20, [130, 16, 255], False),
+        # small pages: a block is 16 pages of 8
+        (3, 4, 2, 16, 8, 20, [129, 0, 160], False),
+        # poison: dead block-table entries name a NaN page, positions
+        # past the length in the last live page are NaN: output finite
+        (4, 8, 2, 32, 16, 20, [128, 0, 121, 139], True),
+        # one query row a kv head (32/32-style heads) and seven (28/4,
+        # and its tensor-parallel shard 7/1): group sizes under and off
+        # the 8-row tile
+        (3, 4, 4, 32, 16, 20, [129, 0, 250], False),
+        (3, 14, 2, 32, 16, 20, [128, 17, 301], False),
+        (3, 7, 1, 32, 16, 20, [127, 0, 320], True),
+        # int8 pages: NaN scales behind dead entries and past the length
+        (4, 8, 2, 32, 16, 20, [128, 0, 121, 139], "int8"),
+        (3, 7, 1, 32, 16, 20, [127, 0, 320], "int8"),
+    ],
+    ids=["block-edges", "partial-last-block", "hkv1", "page8", "poison",
+         "g1", "g7", "g7-hkv1-poison", "poison-int8", "poison-int8-g7"],
+)
+def test_paged_kernel_walks_the_live_span(
+    S, Hq, Hkv, hd, page, n_pp, lens, poison
+):
+    """The decode kernel's loop over the KV blocks a slot's length
+    reaches: exact at the block edges, on a block table that does not
+    divide into blocks, with a free slot between live ones, and reading
+    nothing — not as an address, not as a value — past the live span."""
+    rng = np.random.default_rng(31)
+    P = 1 + S * n_pp
+    q = jnp.asarray(rng.normal(size=(S, Hq, hd)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(P, Hkv, page, hd)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(P, Hkv, page, hd)).astype(np.float32))
+    bt = jnp.asarray(rng.permutation(np.arange(1, P))
+                     .reshape(S, n_pp).astype(np.int32))
+    kr, vr, kw, kw_ref = kp, vp, {}, {}
+    if poison == "int8":
+        kp, vp, bt, kw, kw_ref = _poisoned_int8(
+            rng, P, Hkv, page, hd, bt, lens)
+        kr, vr = kp, vp
+    elif poison:
+        kp, vp, bt, kr, vr = _poison(kp, vp, bt, lens)
+    lens = jnp.asarray(lens, jnp.int32)
+    scale = hd**-0.5
+    ref = np.asarray(
+        paged_attention_ref(q, kr, vr, bt, lens, scale=scale, **kw_ref))
+    got = np.asarray(paged_attention(
+        q, kp, vp, bt, lens, scale=scale, interpret=True, **kw))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+    assert np.abs(got[np.asarray(lens) == 0]).max(initial=0) == 0
+
+
+@pytest.mark.parametrize(
+    "S,C,Hq,Hkv,hd,page,n_pp,starts,nv,poison",
+    [
+        # start + n_valid on, under and over a KV block edge, a padding
+        # slot between live ones, a partial last block (20 pages a slot)
+        (4, 8, 8, 2, 32, 16, 20, [120, 0, 119, 121], [8, 0, 8, 8], False),
+        # two row blocks (64 positions x 4 rows = 256 rows): a decode
+        # row beside a full prefill slot and a padding slot
+        (3, 64, 8, 2, 32, 16, 20, [200, 70, 0], [1, 64, 0], False),
+        # n_valid on, under and over a row-block edge (32 positions)
+        (3, 64, 8, 2, 32, 16, 20, [0, 100, 37], [32, 31, 33], False),
+        # one kv head (a tensor-parallel shard), verify-shaped rows
+        (3, 8, 4, 1, 32, 16, 20, [250, 0, 127], [5, 8, 1], False),
+        # poison: NaN behind every dead block-table entry and past the
+        # live span inside the last live page
+        (4, 8, 8, 2, 32, 16, 20, [120, 0, 113, 131], [8, 0, 8, 8], True),
+        (3, 64, 8, 2, 32, 16, 20, [200, 70, 0], [1, 64, 0], True),
+        # one query row a kv head: one row block up to 128 positions,
+        # two at 256
+        (3, 64, 4, 4, 32, 16, 20, [200, 70, 0], [1, 64, 0], False),
+        (2, 256, 2, 2, 16, 16, 20, [10, 0], [256, 130], False),
+        # seven rows a kv head (28/4 and its shard 7/1): four row blocks
+        # of 16 positions = 112 rows, and one block of 56 rows
+        (3, 64, 14, 2, 32, 16, 20, [200, 70, 0], [1, 64, 0], False),
+        (3, 64, 7, 1, 32, 16, 20, [0, 100, 37], [16, 15, 17], True),
+        (3, 8, 7, 1, 32, 16, 20, [250, 0, 127], [5, 8, 1], False),
+        # int8 pages: NaN scales behind dead entries and past the span
+        (4, 8, 8, 2, 32, 16, 20, [120, 0, 113, 131], [8, 0, 8, 8], "int8"),
+        (3, 64, 8, 2, 32, 16, 20, [200, 70, 0], [1, 64, 0], "int8"),
+    ],
+    ids=["block-edges", "decode-row-beside-prefill", "row-block-edges",
+         "hkv1-verify", "poison", "poison-row-blocks", "g1", "g1-row-blocks",
+         "g7-row-blocks", "g7-hkv1-poison", "g7-one-block", "poison-int8",
+         "poison-int8-row-blocks"],
+)
+def test_ragged_kernel_walks_the_live_span(
+    S, C, Hq, Hkv, hd, page, n_pp, starts, nv, poison
+):
+    """The ragged kernel's two loops: row blocks up to ``n_valid`` and,
+    for each, KV blocks up to its own causal limit. Exact at both kinds
+    of edge, zero rows past ``n_valid`` (whole padding slots and unwalked
+    row blocks included), and nothing read past the live span."""
+    rng = np.random.default_rng(32)
+    q, kp, vp, bt, st, nvj = _ragged_case(
+        rng, S, C, Hq, Hkv, hd, page, n_pp, starts, nv
+    )
+    kr, vr, kw, kw_ref = kp, vp, {}, {}
+    live = [a + b if b else 0 for a, b in zip(starts, nv)]
+    if poison == "int8":
+        kp, vp, bt, kw, kw_ref = _poisoned_int8(
+            rng, kp.shape[0], Hkv, page, hd, bt, live)
+        kr, vr = kp, vp
+    elif poison:
+        kp, vp, bt, kr, vr = _poison(kp, vp, bt, live)
+    scale = hd**-0.5
+    ref = np.asarray(ragged_paged_attention_ref(
+        q, kr, vr, bt, st, nvj, scale=scale, **kw_ref))
+    got = np.asarray(ragged_paged_attention(
+        q, kp, vp, bt, st, nvj, scale=scale, interpret=True, **kw))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+    for s in range(S):
+        assert np.abs(got[s, nv[s]:]).max(initial=0) == 0
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_walk_over_blocks_of_kv_heads(monkeypatch, quantized):
+    """Where every kv head of a slot does not fit one grid step's VMEM
+    (olmo2-7b's 32 heads at chunk 128), the grid's second axis walks
+    blocks of heads: each copies its own heads of a page and its own
+    lanes of the scale planes. Forced here by a budget one head fills."""
+    from tensorlink_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_VMEM_BUDGET", 1)
+    assert A._heads_per_block(4, 32, 32, 128, 32, 4, 32) == 1
+    rng = np.random.default_rng(33)
+    S, C, Hq, Hkv, hd, page, n_pp = 3, 8, 8, 4, 32, 16, 20
+    starts, nv = [120, 0, 250], [8, 0, 1]
+    q, kp, vp, bt, st, nvj = _ragged_case(
+        rng, S, C, Hq, Hkv, hd, page, n_pp, starts, nv
+    )
+    kw = {}
+    if quantized:
+        _, _, kp, ks, vp, vs = _quantized_pages(rng, kp.shape[0], Hkv, page, hd)
+        kw = {"k_scale": ks, "v_scale": vs}
+    scale = hd**-0.5
+    lens = jnp.asarray([a + b for a, b in zip(starts, nv)], jnp.int32)
+    # the un-jitted functions: a cached trace would keep its own budget
+    for kern, ref, args in (
+        (A.ragged_paged_attention, ragged_paged_attention_ref,
+         (q, kp, vp, bt, st, nvj)),
+        (A.paged_attention, paged_attention_ref, (q[:, 0], kp, vp, bt, lens)),
+    ):
+        got = jax.jit(functools.partial(
+            kern.__wrapped__, scale=scale, interpret=True, **kw))(*args)
+        want = ref(*args, scale=scale, **kw)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.slow  # compiles dedicated ragged shapes — CI engine job runs
