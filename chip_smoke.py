@@ -28,10 +28,19 @@ any phase fails, or outside the repo. Phases:
   no program was built after warm-up and ``jit_cache_sizes()`` did not move;
   ``/stats`` shows int8 pages and prefix-cache hits.
 * ``--chips 4``: only the tensor-parallel path and what it is compared
-  with — the same traffic against a ``tensor_parallel=4`` deployment, the
-  per-device memory spread, the kernel and the collectives in the lowered
-  step, then the same requests replayed on a tp=1 engine of the same seed on
-  device 0 and the token streams compared.
+  with — Qwen2.5-7B at its published widths (28/4 heads of 128: one kv head
+  and seven query heads a chip; 15.2 GB of bf16 weights, which one chip
+  cannot hold) hosted with ``tensor_parallel=4``, the same traffic, the
+  per-device memory spread (one copy of the weights a chip), the kernel and
+  the collectives in the lowered step; then, with the hosted job's q/k/v
+  biases overwritten from a seed (a fresh init leaves them zero, and a zero
+  bias proves nothing about how a bias is sharded), the step's two passes
+  teacher-forced over seeded sequences at the benchmark cell's shapes (eight
+  prompts of 128 decoded to 384; one context near 4,096) and their logits
+  held against the plain float32 reference's full forward
+  (``benchmarks/reference/decoder.py``) under ``tolerance.json``'s rule.
+  There is no tp=1 replay: no one chip holds this model, and stream identity
+  does not survive bf16 near-ties anyway (PR 21).
 
 Timings printed on the way are smoke timings (cold compiles included), not
 benchmark numbers. The last line of stdout is the result the driver reads.
@@ -40,7 +49,6 @@ benchmark numbers. The last line of stdout is the result the driver reads.
 from __future__ import annotations
 
 import argparse
-import gc
 import http.client
 import json
 import socket
@@ -50,6 +58,11 @@ import threading
 import time
 
 MODEL = "qwen3-4b"
+TP_MODEL = "qwen2p5-7b"  # what --chips 4 hosts: the model that needs four
+# the logits comparison of --chips 4: (prompt tokens, decode steps,
+# sequences) — the four-chip cell's shape, then one context near 4,096 so
+# the kernels' walk crosses some 250 pages at seven query rows a kv head
+LOGIT_CASES = ((128, 256, 8), (4000, 48, 1))
 # inline ModelConfig JSON for /request-model; None = the registry preset
 MODEL_CONFIG: dict | None = None
 PLATFORM = "tpu"  # what jax.devices()[0].platform must say
@@ -472,17 +485,160 @@ def serve_phase(ml, tmp: str, built: list) -> None:
               f"{st.get('bytes_limit', 0) / 1e9:.2f} GB", flush=True)
 
 
-def _tp_deployment(ml_tp, tmp: str, built: list, degree: int):
-    """The sharded deployment, start to stop. Returns the model config,
-    each request as plain data and a weak reference to the engine: nothing
-    that outlives this frame may hold the engine's arrays, or the
-    reference engine will not fit on device 0 beside them."""
-    import weakref
+def _arch(cfg) -> dict:
+    """``decoder.arch_of``'s keys from the program's config."""
+    return {"heads": cfg.n_heads, "kv_heads": cfg.n_kv_heads,
+            "head_dim": cfg.head_dim, "eps": cfg.norm_eps,
+            "theta": cfg.rope_theta, "qk_norm": cfg.qk_norm,
+            "tied": cfg.tie_embeddings, "layers": cfg.n_layers}
+
+
+def seed_biases(params: dict) -> None:
+    """Overwrite q/k/v biases in place with seeded values, each placed as
+    the leaf it replaces."""
+    import jax
+    import jax.numpy as jnp
+
+    attn = params["layers"]["attn"]
+    key = jax.random.PRNGKey(SEED + 11)
+    for name in ("bq", "bk", "bv"):
+        if name not in attn:
+            continue
+        key, k = jax.random.split(key)
+        old = attn[name]
+        new = (0.5 * jax.random.normal(k, old.shape, jnp.float32)).astype(old.dtype)
+        attn[name] = jax.device_put(new, old.sharding)
+
+
+def logits_phase(cont, ml) -> None:
+    """The served path's logits against the plain reference's, on the
+    hosted job's own weights: each case's prompt through the ragged pass
+    in chunks, then one continuation step a token through the paged cache
+    (``engine/paged.py::make_logits_probe``: the step program's two passes,
+    returning logits where the step samples), teacher-forced on seeded
+    tokens; the reference is one full forward, float32, no cache."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding
+
+    from benchmarks.reference import decoder
+    from benchmarks.harness.spec import BENCH_DIR
+    from tensorlink_tpu.engine.paged import (
+        PagedKVCache, make_logits_probe, tp_cache_specs,
+    )
+
+    with open(BENCH_DIR / "reference" / "tolerance.json") as f:
+        tol = float(json.load(f)["max_gap_sigmas"])
+    cfg, params, mesh = cont.cfg, cont.engine.params, cont._tp_mesh
+    S, C, page = cont.max_slots, cont.prefill_chunk, cont.page_size
+    seed_biases(params)
+    print("phase logits: q/k/v biases overwritten from the seed", flush=True)
+    ragged, decode = make_logits_probe(mesh, cfg, kernel=cont.use_kernel)
+    arch = _arch(cfg)
+    quant = cont.kv_quant != "none"
+    rng = np.random.default_rng(SEED + 12)
+    worst_gap = worst_diff = 0.0
+    for n_prompt, n_new, n_seq in LOGIT_CASES:
+        t0 = time.monotonic()
+        seqs = rng.integers(0, cfg.vocab_size, (n_seq, n_prompt + n_new))
+        want = decoder.forward_logits(
+            params, seqs, arch, slice(n_prompt - 1, n_prompt + n_new))
+        sigma = want.std(axis=-1)  # [n_seq, n_new + 1]
+        t_ref = time.monotonic() - t0
+        # a cache of the probe's own, sharded as the engine's: the engine's
+        # pages hold prefixes computed under the old biases
+        cache = jax.jit(
+            lambda: PagedKVCache.init(
+                cfg, S, page_size=page, max_len=cont.max_seq_len,
+                kv_quant=cont.kv_quant),
+            out_shardings=jax.tree.map(
+                lambda sp: NamedSharding(mesh, sp), tp_cache_specs(quant)),
+        )()
+        n_pp = cache.pages_per_slot
+        bt = rng.permutation(np.arange(1, 1 + S * n_pp)).reshape(S, n_pp)
+        cache = dataclasses.replace(
+            cache, block_tables=jnp.asarray(bt, jnp.int32))
+        live = np.arange(S) < n_seq
+        rows = np.zeros((S, n_prompt + n_new), np.int64)
+        rows[:n_seq] = seqs
+
+        gaps, diffs = [], []
+
+        def hold(pos: int, logits) -> None:
+            got = np.asarray(logits, np.float32)[:n_seq]
+            ref = want[:, pos - (n_prompt - 1)]
+            sg = sigma[:, pos - (n_prompt - 1)]
+            pick = got.argmax(-1)
+            gaps.append((ref.max(-1) - ref[np.arange(n_seq), pick]) / sg)
+            diffs.append(np.abs(got - ref).max(-1) / sg)
+
+        for lo in range(0, n_prompt, C):
+            n = min(C, n_prompt - lo)
+            blk = np.zeros((S, C), np.int32)
+            blk[:, :n] = rows[:, lo:lo + n]
+            logits, cache = ragged(
+                params, jnp.asarray(blk), cache,
+                jnp.asarray(np.where(live, lo, 0), jnp.int32),
+                jnp.asarray(np.where(live, n, 0), jnp.int32))
+        hold(n_prompt - 1, logits)
+        for t in range(n_prompt, n_prompt + n_new):
+            logits, cache = decode(
+                params, jnp.asarray(rows[:, t], jnp.int32), cache,
+                jnp.asarray(live))
+            hold(t, logits)
+        gaps, diffs = np.stack(gaps, 1), np.stack(diffs, 1)
+        worst_gap = max(worst_gap, float(gaps.max()))
+        worst_diff = max(worst_diff, float(diffs.max()))
+        print(f"  {n_seq} x (prompt {n_prompt} + {n_new} decode steps): "
+              f"served argmax under the reference maximum by max "
+              f"{gaps.max():.4f}, mean {gaps.mean():.4f} deviations "
+              f"({int((gaps == 0).sum())}/{gaps.size} are the reference's "
+              f"argmax); max |served - reference| logit {diffs.max():.4f}, "
+              f"mean of maxima {diffs.mean():.4f} deviations; reference "
+              f"{t_ref:.1f}s, served {time.monotonic() - t0 - t_ref:.1f}s "
+              "(smoke timing)", flush=True)
+        if (n_prompt, n_new, n_seq) == LOGIT_CASES[0]:
+            # the control: the same comparison against a reference that
+            # leaves the biases out must fail, or the seeded biases (and
+            # how they are sharded) were never under test
+            attn = params["layers"]["attn"]
+            bare = {**params, "layers": {**params["layers"], "attn": {
+                k: v for k, v in attn.items() if k not in ("bq", "bk", "bv")}}}
+            ref0 = decoder.forward_logits(
+                bare, seqs[:1, :n_prompt], arch, slice(n_prompt - 1, n_prompt))
+            got0 = np.asarray(want[:1, :1])
+            off = float((np.abs(got0 - ref0).max(-1) / sigma[:1, :1]).max())
+            check(off > 4 * tol,
+                  f"control: a reference without the biases lies {off:.3f} "
+                  "deviations from the one with them")
+        del cache, want
+    # tolerance.json's rule, on every compared position; and no single
+    # logit further off than the same distance (bf16 activations over int8
+    # pages against float32: 0.074 deviations at worst on the chip, PERF.md
+    # section 6, PR 26; a reference without the biases lies 5.5 away)
+    check(worst_gap <= tol,
+          f"served argmax within {tol} deviations of the reference maximum "
+          f"everywhere (worst {worst_gap:.4f})")
+    check(worst_diff <= tol,
+          f"no logit further than {tol} deviations from the reference "
+          f"(worst {worst_diff:.4f})")
+
+
+def tp_phase(ml, tmp: str, built: list, degree: int) -> None:
+    """``tensor_parallel=degree`` through the nodes: load, traffic, checks,
+    memory a device, then the logits comparison on the hosted job."""
+    import dataclasses
 
     import jax
 
+    from tensorlink_tpu.engine.continuous import device_bytes
+
     taps = tap_streams()
-    validator, worker = start_cluster(ml_tp, tmp)
+    validator, worker = start_cluster(
+        dataclasses.replace(ml, tensor_parallel=degree), tmp)
     try:
         port = validator.api.port
         print(f"phase tp={degree}: load {host_model(port):.1f}s", flush=True)
@@ -496,120 +652,46 @@ def _tp_deployment(ml_tp, tmp: str, built: list, degree: int):
         check("all_gather" in text,
               f"lowered tp step: {text.count('tpu_custom_call')} kernel "
               f"call(s), {text.count('all_gather')} all_gather op(s)")
-        # every device holds ~1/degree of what is sharded: per-device bytes
-        # of the engine's own arrays, beside what the runtime reports
+        # one copy of the weights a device, each holding its share: the
+        # engine's own arrays beside what the runtime reports in use
         devs = list(cont._tp_mesh.devices.flat)
-        held = {d: 0 for d in devs}
-        total = 0
-        for leaf in jax.tree.leaves((cont.engine.params, cont.cache)):
-            total += leaf.nbytes
-            for sh in leaf.addressable_shards:
-                held[sh.device] += sh.data.nbytes
-        print(f"  unsharded params+pages: {total / 1e9:.2f} GB", flush=True)
+        weights = device_bytes(cont.engine.params)
+        pages = device_bytes(cont.cache)
+        total = sum(x.nbytes for x in jax.tree.leaves(cont.engine.params))
+        print(f"  unsharded weights: {total / 1e9:.2f} GB", flush=True)
+        in_use = {}
         for d in devs:
-            in_use = (d.memory_stats() or {}).get("bytes_in_use", 0)
-            print(f"  {d}: engine arrays {held[d] / 1e9:.2f} GB, "
-                  f"bytes_in_use {in_use / 1e9:.2f} GB", flush=True)
-        lo, hi = min(held.values()), max(held.values())
-        check(hi <= 1.02 * lo and hi < 0.45 * total,
-              f"weights+pages spread evenly: {lo / 1e9:.2f}–{hi / 1e9:.2f} GB "
-              f"per device (replicated embeddings included)")
-        # the request records' transport closures reach the engine: keep
-        # their data only
-        records = {
-            name: dict(prompt=list(req.prompt), budget=req.budget,
-                       sampling=req.sampling, eos=tuple(req.eos),
-                       seed=req.seed, speculative=req.speculative,
-                       tokens=list(req.tokens))
-            for name, (req, _text) in res.items()
-        }
-        return cont.cfg, records, weakref.ref(cont)
+            st = d.memory_stats() or {}
+            in_use[d] = st.get("bytes_in_use", 0)
+            print(f"  {d}: weights {weights[d] / 1e9:.2f} GB, pages "
+                  f"{pages[d] / 1e9:.2f} GB, bytes_in_use "
+                  f"{in_use[d] / 1e9:.2f} GB, peak "
+                  f"{st.get('peak_bytes_in_use', 0) / 1e9:.2f} GB", flush=True)
+        lo, hi = min(weights.values()), max(weights.values())
+        check(hi <= 1.05 * lo and hi < 0.45 * total,
+              f"weights spread evenly: {lo / 1e9:.2f}-{hi / 1e9:.2f} GB a "
+              "device (replicated embeddings included)")
+        if PLATFORM == "tpu":  # the CPU reports no bytes_in_use
+            slack = 0.5e9  # histograms, control rows, the runtime's own
+            check(all(in_use[d] < weights[d] + pages[d] + slack for d in devs),
+                  "one copy of the weights a device: bytes_in_use is under "
+                  f"weights + pages + {slack / 1e9:.1f} GB on every device")
+        logits_phase(cont, ml)
     finally:
         stop_cluster(validator, worker)
         worker.executor.jobs.clear()
         taps.clear()
 
 
-def tp_phase(ml, tmp: str, built: list, degree: int) -> None:
-    """tensor_parallel=``degree`` through the nodes, then the same requests
-    on a tp=1 engine of the same seed on device 0."""
-    import dataclasses
-
-    import jax
-    import numpy as np
-
-    cfg, records, engine_ref = _tp_deployment(
-        dataclasses.replace(ml, tensor_parallel=degree), tmp, built, degree
-    )
-    gc.collect()
-    in_use = (jax.local_devices()[0].memory_stats() or {}).get("bytes_in_use", 0)
-    check(engine_ref() is None and in_use < 1e9,
-          f"tp={degree} engine released ({in_use / 1e9:.2f} GB still in use "
-          "on device 0)")
-
-    # the comparison: same seed, same knobs, one device, each request solo
-    from tensorlink_tpu.engine.continuous import ContinuousEngine
-    from tensorlink_tpu.engine.generate import GenerationEngine
-    from tensorlink_tpu.models.transformer import forward, init_params
-
-    t0 = time.monotonic()
-    params = init_params(cfg, jax.random.PRNGKey(SEED))
-    ref = ContinuousEngine(
-        GenerationEngine(
-            cfg, params, max_seq_len=min(cfg.max_seq_len, ml.max_seq_len),
-            seq_buckets=ml.seq_buckets, batch_buckets=ml.batch_buckets,
-        ),
-        max_slots=ml.cont_max_slots, page_size=ml.cont_page_size,
-        chunk_steps=ml.cont_chunk_steps, prefill_chunk=ml.prefill_chunk,
-        prefix_cache=ml.prefix_cache, kv_quant=ml.kv_quant,
-        spec_decode=ml.spec_decode, spec_draft=ml.spec_draft,
-    )
-    print(f"phase tp=1 reference on {jax.local_devices()[0]}:", flush=True)
-    diverged = []
-    for name, rec in records.items():
-        mine = ref.submit(
-            list(rec["prompt"]), max_new_tokens=rec["budget"],
-            sampling=rec["sampling"], eos_ids=rec["eos"], seed=rec["seed"],
-            speculative=rec["speculative"],
-        )
-        ref.run_until_idle()
-        theirs = rec["tokens"]
-        same = mine.tokens == theirs
-        print(f"  {name}: {len(mine.tokens)} tokens, "
-              f"{'identical' if same else 'DIVERGED'}", flush=True)
-        if not same:
-            i = next((i for i, (a, b) in enumerate(zip(mine.tokens, theirs))
-                      if a != b), min(len(mine.tokens), len(theirs)) - 1)
-            # how close a call the reference's own choice was: its dense
-            # forward's logits at the diverging position
-            prefix = np.asarray([rec["prompt"] + mine.tokens[:i]], np.int32)
-            logits = np.asarray(
-                forward(params, prefix, cfg)[0][0, -1], np.float32
-            )
-            top = np.argsort(logits)[-3:][::-1]
-            print(f"    first divergence at token {i}: tp=1 chose "
-                  f"{mine.tokens[i]}, tp={degree} chose {theirs[i]}; "
-                  f"tp=1 dense-forward logits: top3 "
-                  f"{[(int(t), float(logits[t])) for t in top]}, "
-                  f"margin over tp={degree}'s token "
-                  f"{float(logits[mine.tokens[i]] - logits[theirs[i]]):.4f}",
-                  flush=True)
-            diverged.append(name)
-    err = conservation_error(ref)
-    check(not err, f"reference engine's page conservation clean {err}")
-    print(f"  reference built and replayed in {time.monotonic() - t0:.1f}s "
-          "(smoke timing)", flush=True)
-    check(not diverged,
-          f"tp={degree} token streams equal tp=1's "
-          f"(diverged: {diverged or 'none'})")
-
-
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: only the tensor-parallel path and its tp=1 "
-                    "comparison (needs a four-chip host)")
+                    help="4: only the tensor-parallel path and its logits "
+                    "against the reference (needs a four-chip host)")
     args = ap.parse_args(argv)
+    global MODEL
+    if args.chips == 4 and MODEL_CONFIG is None:
+        MODEL = TP_MODEL
 
     import jax
     import jaxlib

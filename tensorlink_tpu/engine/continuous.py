@@ -60,6 +60,7 @@ cache on or off.
 from __future__ import annotations
 
 import itertools
+import math
 import threading
 import time
 from dataclasses import dataclass, field
@@ -92,6 +93,7 @@ from .paged import (
     pages_needed,
     scatter_page,
     tp_cache_specs,
+    tp_gather_costs,
 )
 from .sampling import SamplingParams, sample
 from .spec import SpecController
@@ -324,6 +326,12 @@ _ENGINE_COUNTERS = (
      "KV pages the attention passes of a chunk walk (contexts as packed)"),
     ("attn_pages_capacity", "tlink_engine_attn_pages_capacity_total",
      "page slots of those passes (slots x pages per slot)"),
+    # the tensor-parallel step's activation gathers (docs/SHARDING.md):
+    # from the shapes the host packed, per dispatched chunk; 0 at tp=1
+    ("tp_gather_bytes", "tlink_engine_tp_gather_bytes_total",
+     "bytes each chip received in the tp step's all-gathers"),
+    ("tp_gather_calls", "tlink_engine_tp_gather_calls_total",
+     "all-gathers the tp step executed"),
 ) + tuple(
     # the anatomy of a chunk on the host (docs/SERVING.md "Observability"):
     # cumulative microseconds per phase of step_chunk, so a window reads
@@ -413,6 +421,53 @@ class ContinuousRequest:
     spec_state: object = None
 
 
+def device_bytes(tree) -> dict:
+    """``{device: bytes}`` of ``tree``'s arrays resident on each device
+    that holds any of them (a replicated leaf counts on every device it is
+    on)."""
+    held: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        # from the sharding alone: reading a shard's ``data`` would leave a
+        # one-device view of it alive beside the array
+        n = math.prod(leaf.sharding.shard_shape(leaf.shape)) * leaf.dtype.itemsize
+        for d in leaf.sharding.addressable_devices:
+            held[d] = held.get(d, 0) + n
+    return held
+
+
+def tp_serving_refusal(
+    cfg, tp: int, *, shared_pool: bool = False, weight_quant: bool = False
+) -> str | None:
+    """Why a slot engine cannot serve ``cfg`` sharded ``tp`` ways here, or
+    None when it can. One rule for the engine (which refuses with
+    :class:`PagedUnsupported`) and for the worker's load (which makes the
+    weights in the layout of the engine that will serve them)."""
+    tp = int(tp)
+    if tp <= 1:
+        return None
+    if cfg.sliding_window is not None:
+        return "sliding-window attention has no paged serving path"
+    if len(jax.devices()) < tp:
+        return (
+            f"tensor_parallel={tp} needs as many devices, have "
+            f"{len(jax.devices())}"
+        )
+    reason = tp_shardable(cfg, tp)
+    if reason is not None:
+        return f"tensor_parallel={tp}: {reason}"
+    if shared_pool:
+        return (
+            "tensor parallelism does not compose with a shared page pool "
+            "yet — the pool's page arrays are unsharded"
+        )
+    if weight_quant:
+        return (
+            "weight-quantized engines cannot shard over a tp axis — "
+            "QTensor scale layouts have no partition specs yet"
+        )
+    return None
+
+
 class ContinuousEngine:
     """Slot-batched continuous decode over one GenerationEngine's model.
 
@@ -491,26 +546,13 @@ class ContinuousEngine:
         self._tp_mesh = None
         self._tp_step = None
         if self.tensor_parallel > 1:
-            if len(jax.devices()) < self.tensor_parallel:
-                raise PagedUnsupported(
-                    f"tensor_parallel={self.tensor_parallel} needs as many "
-                    f"devices, have {len(jax.devices())}"
-                )
-            reason = tp_shardable(self.cfg, self.tensor_parallel)
+            reason = tp_serving_refusal(
+                self.cfg, self.tensor_parallel,
+                shared_pool=pool is not None,
+                weight_quant=bool(getattr(engine, "quant", None)),
+            )
             if reason is not None:
-                raise PagedUnsupported(
-                    f"tensor_parallel={self.tensor_parallel}: {reason}"
-                )
-            if pool is not None:
-                raise PagedUnsupported(
-                    "tensor parallelism does not compose with a shared "
-                    "page pool yet — the pool's page arrays are unsharded"
-                )
-            if getattr(engine, "quant", None):
-                raise PagedUnsupported(
-                    "weight-quantized engines cannot shard over a tp axis "
-                    "— QTensor scale layouts have no partition specs yet"
-                )
+                raise PagedUnsupported(reason)
             self._tp_mesh = serving_mesh(self.tensor_parallel)
         # -- co-hosting (docs/SERVING.md "Co-hosting multiple models") ---
         # with a shared pool the physical page arrays live in the pool
@@ -531,11 +573,24 @@ class ContinuousEngine:
             # "already attached" and the empty pool could never GC
             self.alloc = None
         else:
-            self.cache = PagedKVCache.init(
-                self.cfg, self.max_slots, page_size=self.page_size,
-                max_len=self.max_seq_len, dtype=engine.cache_dtype,
-                kv_quant=kv_quant,
-            )
+            def new_cache():
+                return PagedKVCache.init(
+                    self.cfg, self.max_slots, page_size=self.page_size,
+                    max_len=self.max_seq_len, dtype=engine.cache_dtype,
+                    kv_quant=kv_quant,
+                )
+
+            if self._tp_mesh is None:
+                self.cache = new_cache()
+            else:
+                # each chip zeroes its own kv heads' pages: the whole pool
+                # never sits on device 0 beside a model sized to need the
+                # mesh. Outputs carry the step's cache specs, so the
+                # donated cache keeps its sharding from the first chunk on.
+                self.cache = jax.jit(new_cache, out_shardings=jax.tree.map(
+                    lambda s: NamedSharding(self._tp_mesh, s),
+                    tp_cache_specs(kv_quant != "none"),
+                ))()
             self.alloc = PageAllocator(self.cache.n_pages)
         # chunked prefill: the prompt suffix beyond any cache hit prefills
         # in fixed-shape grants of the packed [slots, chunk] block, so a
@@ -607,17 +662,14 @@ class ContinuousEngine:
             # serve-and-train hot-swap keeps the layout with no extra
             # seam. Donated outputs mirror the input specs — the cache
             # keeps its sharding across chunks, steady-state.
+            # (a worker that loads for this engine makes the weights in
+            # this layout, ml/worker.py::_load_stage: the put is then no
+            # copy, and the job keeps ONE copy a chip)
             engine.params = jax.tree.map(
                 lambda x, s: jax.device_put(
                     x, NamedSharding(self._tp_mesh, s)
                 ),
                 engine.params, tp_partition_specs(self.cfg),
-            )
-            self.cache = jax.tree.map(
-                lambda x, s: jax.device_put(
-                    x, NamedSharding(self._tp_mesh, s)
-                ),
-                self.cache, tp_cache_specs(self.cache.quantized),
             )
             self._tp_step = make_tp_ragged_step(
                 self._tp_mesh, self.cfg,
@@ -625,6 +677,14 @@ class ContinuousEngine:
                 kernel=self.use_kernel,
                 tp_quant=bool(self.cfg.collective_quant),
             )
+            self._tp_gather = tp_gather_costs(
+                self.cfg, self.tensor_parallel,
+                bool(self.cfg.collective_quant),
+            )
+        # weights resident per device, fullest and emptiest: what a
+        # deployment sizes slots and context against (one copy a chip
+        # under tp, tests/test_tp_load.py)
+        self.weights_bytes_device = list(device_bytes(engine.params).values())
         self._prefilling: dict[int, ContinuousRequest] = {}
         # -- live slot migration (docs/FAILURE_MODEL.md) -----------------
         # slots frozen for export: excluded from stepping, their pages
@@ -2561,6 +2621,8 @@ class ContinuousEngine:
             # hot path (1 = single device) — a router treats the whole
             # mesh as one placement unit
             "tensor_parallel": self.tensor_parallel,
+            "weights_bytes_device_max": max(self.weights_bytes_device),
+            "weights_bytes_device_min": min(self.weights_bytes_device),
         })
         if self.pool is not None:
             # co-hosting: the shared pool's occupancy plus THIS tenant's
@@ -2980,6 +3042,17 @@ class ContinuousEngine:
                         "attn_pages_capacity",
                         n_exec * blk.shape[0] * self.cache.pages_per_slot,
                     )
+                    if self._tp_step is not None:
+                        # the ragged pass gathers every block row through
+                        # the layers and the verify rows through the head,
+                        # each continuation step one row a slot
+                        S = blk.shape[0]
+                        rows, head, calls = self._tp_gather
+                        self._count("tp_gather_bytes", int(
+                            rows * (blk.size + (n_exec - 1) * S)
+                            + head * S * (self.spec_width + n_exec - 1)
+                        ))
+                        self._count("tp_gather_calls", calls * n_exec)
                     # flight recorder (core/trace.py): the postmortem's
                     # per-step state; the append follows the phase's end
                     # because the record holds post_ms

@@ -1242,28 +1242,19 @@ def _ragged_block(x, lp, cfg: ModelConfig, cos, sin, cache_kv, write_pg,
 
 
 # tlint: hot-path
-def _ragged_step_impl(
-    params, blk, cache, starts, n_valid, n_spec, emit, seeds, steps,
-    temp, top_k, top_p, pres, freq, counts, remaining, eos,
-    cfg: ModelConfig, n_steps: int, spec_width: int, kernel: bool,
+def _ragged_pass(
+    params, blk, cache, starts, n_valid, n_spec,
+    cfg: ModelConfig, spec_width: int, kernel: bool,
     tp_axis: str | None = None, tp_quant: bool = False,
 ):
-    """Unjitted body of :func:`paged_ragged_step` — also traced inside
-    the tensor-parallel shard_map (:func:`make_tp_ragged_step`). There
-    ``params`` holds head-major column slices, the per-layer KV pages
-    hold the LOCAL kv heads (axis 2 of ``[L, P, n_kv, page, hd]``), and
-    every control-state array (block tables, starts/n_valid, sampling
-    knobs, histograms) is replicated — so the sampling epilogue sees
-    gathered full-width logits and draws the SAME token on every
-    shard."""
+    """The step's first phase: every layer over the packed ``[S, C]``
+    block through the pages, then the vocabulary head over each slot's
+    verification rows. Returns ``(logits_v [S, W, V], base, kv_new)``;
+    the caller advances the lengths."""
     S, C = blk.shape
     page = cache.page_size
     n_pp = cache.pages_per_slot
     bt = cache.block_tables
-    # the program's three phases carry names of their own (STEP_PHASES):
-    # each is one top-level loop, in this order, which is how a profiler
-    # trace whose events keep no scope still tells them apart
-    # (tests/test_step_scopes.py pins names and order)
     with jax.named_scope(RAGGED_PASS):
         write_pg, write_off, pos, _valid = _ragged_write_indices(
             bt, starts, n_valid, page, n_pp, C
@@ -1305,6 +1296,34 @@ def _ragged_step_impl(
     with jax.named_scope(RAGGED_PASS):
         h_v = x[jnp.arange(S)[:, None], gather]  # [S, W, d]
         logits_v = _logits(params, h_v, cfg, tp_axis, tp_quant)  # [S, W, V]
+    return logits_v, base, kv_new
+
+
+# tlint: hot-path
+def _ragged_step_impl(
+    params, blk, cache, starts, n_valid, n_spec, emit, seeds, steps,
+    temp, top_k, top_p, pres, freq, counts, remaining, eos,
+    cfg: ModelConfig, n_steps: int, spec_width: int, kernel: bool,
+    tp_axis: str | None = None, tp_quant: bool = False,
+):
+    """Unjitted body of :func:`paged_ragged_step` — also traced inside
+    the tensor-parallel shard_map (:func:`make_tp_ragged_step`). There
+    ``params`` holds head-major column slices, the per-layer KV pages
+    hold the LOCAL kv heads (axis 2 of ``[L, P, n_kv, page, hd]``), and
+    every control-state array (block tables, starts/n_valid, sampling
+    knobs, histograms) is replicated — so the sampling epilogue sees
+    gathered full-width logits and draws the SAME token on every
+    shard."""
+    S = blk.shape[0]
+    W = int(spec_width)
+    # the program's three phases carry names of their own (STEP_PHASES):
+    # each is one top-level loop, in this order, which is how a profiler
+    # trace whose events keep no scope still tells them apart
+    # (tests/test_step_scopes.py pins names and order)
+    logits_v, base, kv_new = _ragged_pass(
+        params, blk, cache, starts, n_valid, n_spec, cfg, W, kernel,
+        tp_axis, tp_quant,
+    )
 
     toks0, nxt, spec_m, ended, counts, steps, remaining = _verify_emit(
         blk, logits_v, base, n_spec, emit, seeds, steps, temp, top_k,
@@ -1447,6 +1466,35 @@ def tp_cache_specs(quantized: bool, axis: str = "tp") -> "PagedKVCache":
     )
 
 
+def tp_gather_costs(cfg: ModelConfig, tp: int, quant: bool = False):
+    """What the tensor-parallel step's activation gathers move, from the
+    shapes alone: ``(bytes a chip receives per block row through all the
+    layers, bytes per row of the vocabulary head, all-gathers a pass)``.
+    A pass (the ragged pass, or one continuation step) gathers four
+    activations a layer (:func:`_paged_residual`: the heads' outputs and
+    the ``wo`` columns; ``_mlp``: the hidden and the ``w_down`` columns) and, for
+    an untied head, the logits (``_logits``); a chip receives the other
+    ``tp - 1`` shards of each. ``quant`` is the int8 gather: one byte an
+    element and an f32 scale a row and shard, in two collectives."""
+    tp = int(tp)
+    if tp <= 1:
+        return 0.0, 0.0, 0
+    item = 1 if quant else jnp.dtype(cfg.dtype).itemsize
+    scales = 4 * tp if quant else 0
+
+    def recv(width: int) -> float:
+        return (width * item + scales) * (tp - 1) / tp
+
+    layers = cfg.n_layers * sum(
+        recv(w) for w in (cfg.q_dim, cfg.d_model, cfg.d_ff, cfg.d_model)
+    )
+    head = 0.0 if cfg.tie_embeddings else recv(cfg.vocab_size)
+    calls = (4 * cfg.n_layers + (0 if cfg.tie_embeddings else 1)) * (
+        2 if quant else 1
+    )
+    return layers, head, calls
+
+
 # Compiled tensor-parallel ragged-step programs, keyed by every static
 # that shapes the trace. Engines sharing (mesh, model, chunk geometry)
 # share ONE program — churn in slots/requests/spec mixes never adds
@@ -1486,9 +1534,11 @@ def make_tp_ragged_step(
     pspecs = tp_partition_specs(cfg, axis=axis)
     rep = P()
 
-    def body(params, blk, cache, starts, n_valid, n_spec, emit, seeds,
-             steps, temp, top_k, top_p, pres, freq, counts, remaining,
-             eos):
+    # the name is the compiled module's (``jit_tp_ragged_step``): what a
+    # profiler trace calls this program's executions
+    def tp_ragged_step(params, blk, cache, starts, n_valid, n_spec, emit,
+                       seeds, steps, temp, top_k, top_p, pres, freq, counts,
+                       remaining, eos):
         return _ragged_step_impl(
             params, blk, cache, starts, n_valid, n_spec, emit, seeds,
             steps, temp, top_k, top_p, pres, freq, counts, remaining,
@@ -1510,8 +1560,8 @@ def make_tp_ragged_step(
             # out_specs hold by construction (fixed-order gathers), which
             # the bit-identity tests pin, not the type checker
             jax.shard_map(
-                body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
+                tp_ragged_step, mesh=mesh, in_specs=in_specs,
+                out_specs=out_specs, check_vma=False,
             ),
             donate_argnums=(2, 14),  # cache, counts — as the 1-dev step
         )
@@ -1554,6 +1604,51 @@ def make_tp_ragged_step(
     ).lower(params, blk, cache, *rest)
     _TP_RAGGED_CACHE[key] = step
     return step
+
+
+def make_logits_probe(mesh, cfg: ModelConfig, *, kernel: bool = False,
+                      tp_quant: bool = False, axis: str = "tp"):
+    """The serving step's two passes as programs of their own that return
+    logits where the step samples: ``(ragged, decode)``, over ``mesh``'s
+    ``axis`` exactly as :func:`make_tp_ragged_step` shards them. What
+    compares the served path with a reference forward logit by logit
+    (tests/test_tp_load.py, ``chip_smoke.py``); nothing serves through it.
+
+    ``ragged(params, blk, cache, starts, n_valid)`` runs the ragged pass
+    over the packed block and returns each slot's logits at its last valid
+    row ``[S, V]`` and the cache with those rows written;
+    ``decode(params, tok, cache, active)`` is one continuation step
+    (:func:`_decode_step_impl`)."""
+    def ragged(params, blk, cache, starts, n_valid):
+        logits_v, _base, kv_new = _ragged_pass(
+            params, blk, cache, starts, n_valid, jnp.zeros_like(n_valid),
+            cfg, 1, kernel, axis, tp_quant,
+        )
+        lengths = jnp.where(n_valid > 0, starts + n_valid, cache.lengths)
+        return logits_v[:, 0], _with_kv(cache, kv_new, lengths=lengths)
+
+    def decode(params, tok, cache, active):
+        return _decode_step_impl(
+            params, tok, cache, active, cfg, kernel, axis, tp_quant
+        )
+
+    pspecs = tp_partition_specs(cfg, axis=axis)
+    rep = P()
+
+    def sharded(fn, n_ctl: int):
+        def build(quantized: bool):
+            cspecs = tp_cache_specs(quantized, axis)
+            return jax.jit(jax.shard_map(
+                fn, mesh=mesh, in_specs=(pspecs, rep, cspecs) + (rep,) * n_ctl,
+                out_specs=(rep, cspecs), check_vma=False,
+            ))
+
+        plain, quant = build(False), build(True)
+        return lambda params, x, cache, *ctl: (
+            plain if cache.k_scale is None else quant
+        )(params, x, cache, *ctl)
+
+    return sharded(ragged, 2), sharded(decode, 1)
 
 
 # tlint: hot-path  # tlint: one-program
@@ -1669,7 +1764,9 @@ __all__ = [
     "paged_decode_step",
     "paged_ragged_step",
     "make_tp_ragged_step",
+    "make_logits_probe",
     "tp_cache_specs",
+    "tp_gather_costs",
     "copy_page",
     "gather_page",
     "scatter_page",

@@ -423,10 +423,27 @@ class DistributedWorker:
         lo, hi = stage["layer_lo"], stage["layer_hi"]
         first, holds_head = stage["first"], stage["holds_head"]
 
+        training = bool(p.get("training", False))
+        quant = model.get("quant")
+        # a stage the tensor-parallel slot engine will serve gets its
+        # weights made (or read) shard by shard, straight into the layout
+        # that engine's step reads: no leaf is ever whole on one device —
+        # a model one chip cannot hold loads — and the job keeps one copy
+        # a chip (docs/SHARDING.md "Loading a tensor-parallel job")
+        serve_tp = self._serving_tp(cfg, stage, training, quant)
+        mesh = None
+        if serve_tp > 1:
+            from tensorlink_tpu.parallel.mesh import serving_mesh
+
+            mesh = serving_mesh(serve_tp)
+
         if model.get("ckpt"):
             from tensorlink_tpu.engine.loader import load_params
 
-            _, full = load_params(model["ckpt"], cfg, layer_range=(lo, hi))
+            _, full = load_params(
+                model["ckpt"], cfg, layer_range=(lo, hi),
+                tensor_parallel=serve_tp,
+            )
             # loader returns embed/final_norm/head too; keep what the stage owns
             params = {"layers": full["layers"]} if hi > lo else {}
             if first:
@@ -439,16 +456,26 @@ class DistributedWorker:
                     params["embed"] = full["embed"]
         else:
             seed = int(model.get("seed", 0))
-            full = init_params(cfg, jax.random.PRNGKey(seed))
+            shardings = None
+            if serve_tp > 1:
+                from tensorlink_tpu.models.transformer import tp_partition_specs
+                from tensorlink_tpu.parallel.mesh import shard
+
+                shardings = jax.tree.map(
+                    lambda spec: shard(mesh, spec), tp_partition_specs(cfg)
+                )
+            full = init_params(
+                cfg, jax.random.PRNGKey(seed), shardings=shardings
+            )
             params = slice_stage_params(
                 full, lo, hi, first=first, holds_head=holds_head
             )
             del full
 
-        mesh = self._build_stage_mesh(cfg, stage)
-        if mesh is not None:
-            params = self._shard_params(params, cfg, stage, mesh)
-        training = bool(p.get("training", False))
+        if serve_tp == 1:
+            mesh = self._build_stage_mesh(cfg, stage)
+            if mesh is not None:
+                params = self._shard_params(params, cfg, stage, mesh)
         if self.node.config.ml.collective_quant and not training:
             # EQuARX-style quantized collectives (parallel/ring.py): the
             # sequence-parallel ring rotates int8 K/V + scales over ICI.
@@ -457,8 +484,7 @@ class DistributedWorker:
             # lose the K/V gradient (same rule as weight quant below:
             # training needs exact math)
             cfg = cfg.with_(collective_quant=True)
-        quant = p.get("model", {}).get("quant")
-        if p.get("model", {}).get("flash"):
+        if model.get("flash"):
             # Pallas flash prefill for this job's serving ENGINE — i.e.
             # whole-model stages only (ops/attention.py; the engine gates it
             # to fresh-cache prefills, and a sharded engine routes the
@@ -517,7 +543,8 @@ class DistributedWorker:
                 # batch buckets include 1, so never shard cache batch on the
                 # data axis here; kv heads ride the tensor axis
                 cache_specs=(
-                    self._cache_specs_for(rt, batch=1) if mesh is not None else None
+                    self._cache_specs_for(rt, batch=1, serve_tp=serve_tp)
+                    if mesh is not None else None
                 ),
                 max_seq_len=min(cfg.max_seq_len, ml_cfg.max_seq_len),
                 seq_buckets=ml_cfg.seq_buckets,
@@ -552,9 +579,22 @@ class DistributedWorker:
             # engine) instead of leaving clients to wait out the RPC timeout
             old.cont.close(RuntimeError("stage reloaded"))
             old.cont = None
+        from tensorlink_tpu.core.trace import get_tracer
+        from tensorlink_tpu.engine.continuous import device_bytes
+
+        held = device_bytes(params).values()
+        dt = time.monotonic() - t0
         self.log.info(
-            "loaded %s layers [%d,%d) first=%s head=%s in %.1fs",
-            model.get("name", "?"), lo, hi, first, holds_head, time.monotonic() - t0,
+            "loaded %s layers [%d,%d) first=%s head=%s tp=%d in %.1fs; "
+            "weights a device %.2f-%.2f GB",
+            model.get("name", "?"), lo, hi, first, holds_head, serve_tp, dt,
+            min(held) / 1e9, max(held) / 1e9,
+        )
+        # the load as a span, under the job's id (no request rides a load)
+        get_tracer().record(
+            job_id, "load_stage", site=str(self.node.node_id or ""),
+            dur_s=dt, tp=serve_tp, weights_bytes_device_max=max(held),
+            weights_bytes_device_min=min(held),
         )
         self._respond(
             p["peer"], proto.MODULE_LOADED, p["rid"],
@@ -596,6 +636,31 @@ class DistributedWorker:
 
         return build_mesh(axes, devs[:n])
 
+    def _serving_tp(self, cfg, stage: dict, training: bool, quant) -> int:
+        """The shard degree of the slot engine that will serve this stage:
+        ``MLConfig.tensor_parallel`` when a whole-model serving job can
+        take it, else 1 (the planner's GSPMD layout). An operator who asked
+        for ``tensor_parallel > 1`` and cannot have it is told why here,
+        at the load, not at the first request."""
+        ml = self.node.config.ml
+        tp = int(getattr(ml, "tensor_parallel", 1) or 1)
+        whole = stage["first"] and stage["last"] and stage["holds_head"]
+        if tp <= 1 or training or not whole or not ml.continuous_batching:
+            return 1
+        from tensorlink_tpu.engine.continuous import tp_serving_refusal
+
+        reason = tp_serving_refusal(
+            cfg, tp, shared_pool=int(ml.cont_pool_pages or 0) > 0,
+            weight_quant=bool(quant),
+        )
+        if reason is not None:
+            self.log.warning(
+                "tensor_parallel=%d not taken for this job (%s): planner "
+                "layout, static serving", tp, reason,
+            )
+            return 1
+        return tp
+
     def _shard_params(self, params, cfg, stage: dict, mesh):
         from tensorlink_tpu.parallel.mesh import put
         from tensorlink_tpu.parallel.planner import StagePlan, stage_param_specs
@@ -604,15 +669,31 @@ class DistributedWorker:
         try:
             return put(mesh, params, specs)
         except ValueError as e:
+            if int(getattr(self.node.config.ml, "tensor_parallel", 1) or 1) > 1:
+                # the deployment asked for a sharded model: replicating one
+                # that was sized to need the mesh is an OOM three calls
+                # later, with the reason lost
+                raise ValueError(
+                    f"stage parameters do not shard over {dict(mesh.shape)} "
+                    f"({e}) and the deployment asks for tensor_parallel > 1: "
+                    "not replicating"
+                ) from e
             self.log.warning("param sharding failed (%s); replicating", e)
             return params
 
-    def _cache_specs_for(self, rt: StageRuntime, batch: int):
+    def _cache_specs_for(self, rt: StageRuntime, batch: int, serve_tp: int = 1):
         """KV-cache PartitionSpecs on this stage's mesh: kv heads on tensor
         (when they divide), batch on data only when the batch divides it —
-        serving batches of 1 must not fail against a data axis."""
+        serving batches of 1 must not fail against a data axis. Under the
+        serving tp layout the mesh is ``serving_mesh`` and kv heads ride
+        its ``tp`` axis."""
         from tensorlink_tpu.models.transformer import cache_specs
 
+        if serve_tp > 1:
+            return cache_specs(
+                rt.cfg, data_axis=None, tensor_axis="tp",
+                quantized=rt.cache_quant,
+            )
         axes = rt.stage.get("mesh_axes") or {}
         tp = axes.get("tensor", 1)
         dp = axes.get("data", 1)
@@ -2138,6 +2219,10 @@ class DistributedWorker:
             # instead of quietly serving from the other engine.
             self.log.info("continuous batching unavailable: %s", e)
             return None
+        # one copy a chip: a sharded engine re-placed the weights it was
+        # given (no copy when _load_stage made them in its layout); the
+        # stage must not keep another layout of them alive beside it
+        rt.params = rt.engine.params
         return cont
 
     def _shared_kv_pool(self, rt: "StageRuntime", ml):

@@ -35,29 +35,56 @@ P = jax.sharding.PartitionSpec
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("shape", "scale", "dtype"))
-def _dense_init(key, shape, scale, dtype):
+@partial(jax.jit, static_argnames=("shape", "scale", "dtype", "sharding"))
+def _dense_init(key, shape, scale, dtype, sharding=None):
     """One weight leaf, drawn, scaled and cast in ONE program: the f32 draw
     of a stacked leaf (3.6 GB for qwen3-4b's ``w_gate``) never exists in
-    device memory beside the model, only the cast result does."""
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+    device memory beside the model, only the cast result does. Under a
+    ``sharding`` each device draws only its own slice (the threefry bits
+    are a function of the element's index, ``jax_threefry_partitionable``),
+    so the values are the unsharded draw's, whatever the layout."""
+    w = (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+    if sharding is not None:
+        w = lax.with_sharding_constraint(w, sharding)
+    return w
 
 
-def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> dict:
-    """Random-init parameter pytree (shapes double as the loader's schema)."""
+def init_params(
+    cfg: ModelConfig, key: jax.Array, dtype=None, shardings=None
+) -> dict:
+    """Random-init parameter pytree (shapes double as the loader's schema).
+
+    ``shardings`` is a pytree of ``jax.sharding.Sharding`` matching the
+    result (``tp_partition_specs`` on the serving mesh): every leaf is then
+    made under its sharding, no leaf whole on one device, with the values
+    the same seed gives unsharded (tests/test_tp_load.py pins both)."""
     dt = dtype or cfg.dtype
     d, f, hd = cfg.d_model, cfg.d_ff, cfg.head_dim
     L, V = cfg.n_layers, cfg.vocab_size
     keys = iter(jax.random.split(key, 32))
 
+    # leaves are made last, each under its sharding: until then a leaf is
+    # the function that makes it
     def dense(k, *shape, scale=None):
         s = scale if scale is not None else shape[-2] ** -0.5
-        return _dense_init(k, shape, float(s), jnp.dtype(dt))
+        return lambda sh: _dense_init(k, shape, float(s), jnp.dtype(dt), sh)
+
+    def const(value, *shape):
+        def make(sh):
+            x = jnp.full(shape, value, dt)
+            return x if sh is None else jax.device_put(x, sh)
+        return make
+
+    def zeros(*shape):
+        return const(0, *shape)
+
+    def ones(*shape):
+        return const(1, *shape)
 
     def norm_p(with_bias: bool, *shape):
-        p = {"scale": jnp.ones(shape, dt)}
+        p = {"scale": ones(*shape)}
         if with_bias:
-            p["bias"] = jnp.zeros(shape, dt)
+            p["bias"] = zeros(*shape)
         return p
 
     ln_bias = cfg.norm == "layernorm"
@@ -69,19 +96,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> dict:
     }
     if cfg.attn_bias:
         attn |= {
-            "bq": jnp.zeros((L, cfg.q_dim), dt),
-            "bk": jnp.zeros((L, cfg.kv_dim), dt),
-            "bv": jnp.zeros((L, cfg.kv_dim), dt),
+            "bq": zeros(L, cfg.q_dim),
+            "bk": zeros(L, cfg.kv_dim),
+            "bv": zeros(L, cfg.kv_dim),
         }
     if cfg.attn_out_bias or cfg.family == "gpt2":
-        attn["bo"] = jnp.zeros((L, d), dt)
+        attn["bo"] = zeros(L, d)
     if cfg.qk_norm:
-        attn |= {"q_norm": jnp.ones((L, hd), dt), "k_norm": jnp.ones((L, hd), dt)}
+        attn |= {"q_norm": ones(L, hd), "k_norm": ones(L, hd)}
     if cfg.qk_norm_full:  # OLMo-2: norm over the whole projection dim
-        attn |= {
-            "q_norm": jnp.ones((L, cfg.q_dim), dt),
-            "k_norm": jnp.ones((L, cfg.kv_dim), dt),
-        }
+        attn |= {"q_norm": ones(L, cfg.q_dim), "k_norm": ones(L, cfg.kv_dim)}
 
     if cfg.moe:
         E = cfg.n_experts
@@ -99,16 +123,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> dict:
         }
         if cfg.mlp_bias:
             mlp |= {
-                "b_gate": jnp.zeros((L, f), dt),
-                "b_up": jnp.zeros((L, f), dt),
-                "b_down": jnp.zeros((L, d), dt),
+                "b_gate": zeros(L, f),
+                "b_up": zeros(L, f),
+                "b_down": zeros(L, d),
             }
     else:  # fused (GPT-2): up -> act -> down, with biases
         mlp = {
             "w_up": dense(next(keys), L, d, f),
-            "b_up": jnp.zeros((L, f), dt),
+            "b_up": zeros(L, f),
             "w_down": dense(next(keys), L, f, d, scale=f**-0.5),
-            "b_down": jnp.zeros((L, d), dt),
+            "b_down": zeros(L, d),
         }
 
     params = {
@@ -125,7 +149,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> dict:
         params["embed"]["pos"] = dense(next(keys), cfg.max_seq_len, d, scale=0.02)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(keys), d, V)
-    return params
+    if shardings is None:
+        return jax.tree.map(lambda make: make(None), params)
+    return jax.tree.map(lambda make, sh: make(sh), params, shardings)
 
 
 # ---------------------------------------------------------------------------
@@ -218,6 +244,9 @@ def attention(
     return out.reshape(B, T, Hq, hd)
 
 
+TP_GATHER = "tlink.tp_gather"
+
+
 # tlint: hot-path
 def _tp_gather(h: jax.Array, tp_axis: str | None, quant: bool) -> jax.Array:
     """Reassemble an activation whose LAST axis is split over ``tp_axis``.
@@ -231,11 +260,16 @@ def _tp_gather(h: jax.Array, tp_axis: str | None, quant: bool) -> jax.Array:
     wire bytes, bounded divergence (opt-in via collective_quant)."""
     if tp_axis is None:
         return h
-    if quant:
-        from ..parallel.ring import quantized_all_gather
+    # the program's own name for its collectives (metadata only, like the
+    # step's phase scopes): a trace attributes their time to it
+    with jax.named_scope(TP_GATHER):
+        if quant:
+            from ..parallel.ring import quantized_all_gather
 
-        return quantized_all_gather(h, tp_axis, axis=h.ndim - 1, tiled=True)
-    return lax.all_gather(h, tp_axis, axis=h.ndim - 1, tiled=True)
+            return quantized_all_gather(
+                h, tp_axis, axis=h.ndim - 1, tiled=True
+            )
+        return lax.all_gather(h, tp_axis, axis=h.ndim - 1, tiled=True)
 
 
 def _mlp(

@@ -236,18 +236,16 @@ def test_ragged_step_fits_one_v5e_beside_the_weights(v5e):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM, ma
 
 
-@pytest.mark.slow
-def test_tp4_ragged_step_compiles_for_a_v5e_2x2_mesh(v5e_chips):
-    """The tensor-parallel step over the four described chips: kernel and
-    all-gathers present, each device holding about a quarter."""
+def _tp4_step_compiled(v5e_chips, cfg):
+    """The tensor-parallel step for ``cfg`` compiled over the four
+    described chips at a default MLConfig worker's shapes: (compiled,
+    logical bytes of weights + page pool)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     from tensorlink_tpu.engine.paged import make_tp_ragged_step, tp_cache_specs
-    from tensorlink_tpu.models.registry import config_presets
     from tensorlink_tpu.models.transformer import tp_partition_specs
 
-    cfg = config_presets()["qwen3-4b"]
     mesh = Mesh(np.array(v5e_chips).reshape(1, 4), ("data", "tp"))
 
     def on(spec_tree):
@@ -268,9 +266,47 @@ def test_tp4_ragged_step_compiles_for_a_v5e_2x2_mesh(v5e_chips):
 
     ops, resident = _step_operands(cfg, place, on(tp_cache_specs(True)))
     step = make_tp_ragged_step(mesh, cfg, n_steps=8, spec_width=9, kernel=True)
-    compiled = step.lower(*ops).compile()
+    return step.lower(*ops).compile(), resident
+
+
+@pytest.mark.slow
+def test_tp4_ragged_step_compiles_for_a_v5e_2x2_mesh(v5e_chips):
+    """The tensor-parallel step over the four described chips: kernel and
+    all-gathers present, each device holding about a quarter."""
+    from tensorlink_tpu.models.registry import config_presets
+
+    compiled, resident = _tp4_step_compiled(
+        v5e_chips, config_presets()["qwen3-4b"])
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-gather" in text
     ma = compiled.memory_analysis()
     # a quarter of what shards, plus the replicated embedding table
     assert ma.argument_size_in_bytes < 0.35 * resident, (ma, resident)
+
+
+def test_qwen2p5_7b_tp4_step_compiles_at_its_published_shapes(v5e_chips):
+    """Qwen2.5-7B as ``qwen2p5-7b-tp4.decode-closed`` serves it: 28/4 heads
+    of 128 (one kv head and seven query heads a chip), d_ff 18944 / 4, an
+    untied vocabulary head of 152064 / 4 columns, q/k/v biases, int8 pages,
+    8 slots x 4096. Not slow-marked: it is the one guard, off the chip, of
+    the only four-chip cell. The step holds the Pallas walk and the
+    gathers, and a chip's arguments are its share: a quarter of the layers
+    and the head, all of the embedding table, a quarter of the pages."""
+    import dataclasses
+
+    from tensorlink_tpu.models.registry import config_presets
+
+    cfg = dataclasses.replace(config_presets()["qwen2p5-7b"], max_seq_len=4096)
+    compiled, resident = _tp4_step_compiled(v5e_chips, cfg)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+    ma = compiled.memory_analysis()
+    embed = cfg.vocab_size * cfg.d_model * 2
+    share = (resident - embed) / 4 + embed
+    print(f"qwen2p5-7b tp=4 on a described v5e 2x2: arguments a chip "
+          f"{ma.argument_size_in_bytes / 1e9:.3f} GB (share {share / 1e9:.3f}), "
+          f"temp {ma.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"{text.count('all-gather(')} all-gathers, "
+          f"{text.count('tpu_custom_call')} kernel calls")
+    assert 0.98 * share < ma.argument_size_in_bytes < 1.02 * share + 2**26, ma
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 0.6 * V5E_HBM, ma

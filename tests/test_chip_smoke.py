@@ -91,3 +91,27 @@ def test_chip_smoke_control_flow_on_cpu(smoke, capsys):
                    "compile set unchanged since warm-up",
                    "compile cache:"):
         assert needle in out, (needle, out[-3000:])
+
+
+@pytest.mark.slow  # as above, on four virtual devices
+def test_chip_smoke_tp4_control_flow_on_cpu(smoke, monkeypatch, capsys):
+    """``--chips 4`` at a tiny Qwen2.5 shape (seven query heads on each of
+    four kv heads, q/k/v biases, an untied head): the sharded load, the
+    traffic, the memory spread and the logits comparison all run."""
+    from tensorlink_tpu.models import ModelConfig
+
+    tiny = ModelConfig(
+        family="qwen2", vocab_size=260, d_model=112, n_layers=2, n_heads=28,
+        n_kv_heads=4, head_dim=16, d_ff=128, max_seq_len=256, attn_bias=True,
+        tie_embeddings=False, dtype=jnp.float32,
+    )
+    monkeypatch.setattr(smoke, "MODEL_CONFIG", tiny.to_json())
+    monkeypatch.setattr(smoke, "LOGIT_CASES", ((40, 8, 4), (200, 8, 1)))
+    assert smoke.main(["--chips", "4"]) == 0
+    out = capsys.readouterr().out
+    assert json.loads(out.strip().splitlines()[-1])["ok"] is True
+    for needle in ("phase tp=4:", "engine runs tensor_parallel=4",
+                   "weights spread evenly", "phase logits:",
+                   "control: a reference without the biases",
+                   "served argmax within", "no logit further than"):
+        assert needle in out, (needle, out[-3000:])

@@ -229,6 +229,8 @@ LEGACY_ENGINE_KEYS = (
     # the paged kernels' live-span walk (ROADMAP S7): pages walked /
     # page slots of the same passes
     "attn_pages_live", "attn_pages_capacity",
+    # the tensor-parallel step's activation gathers (0 at tp = 1)
+    "tp_gather_bytes", "tp_gather_calls",
     # the anatomy of a chunk: cumulative host microseconds per phase
     "chunk_us_between", "chunk_us_admit", "chunk_us_pack",
     "chunk_us_dispatch", "chunk_us_wait", "chunk_us_drain",
