@@ -1,4 +1,4 @@
-"""Device acquisition and the compile-cache rule.
+"""Device acquisition, the compile-cache rule and the one lowering option.
 
 One process owns the accelerator: the one that runs the ML threads (a
 ``WorkerNode``/``ValidatorNode`` caller, the ``bench.py`` child,
@@ -10,7 +10,8 @@ failing to get the accelerator; a CPU run is asked for explicitly with
 ``JAX_PLATFORMS=cpu``.
 
 :func:`configure_compile_cache` is the one place that points JAX's
-persistent compilation cache somewhere.
+persistent compilation cache somewhere, and the one place that says how
+much Python source an HLO operation carries.
 """
 
 from __future__ import annotations
@@ -59,7 +60,15 @@ def configure_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache under the one rule every
     entry point shares: ``JAX_COMPILATION_CACHE_DIR``, when set, is the
     directory (JAX reads it itself; no directory is set in code);
-    otherwise :data:`COMPILE_CACHE_DIR`. Returns the directory in use."""
+    otherwise :data:`COMPILE_CACHE_DIR`. Returns the directory in use.
+
+    Programs are lowered without Python stack frames in their operations'
+    metadata (``jax_traceback_in_locations_limit`` 0; JAX's default is ten
+    frames an operation) unless ``JAX_TRACEBACK_IN_LOCATIONS_LIMIT`` is
+    set: the TPU profiler resolves the frames of every device event when a
+    trace is stopped, a quarter of what stopping one costs (36.8 -> 27.3 s
+    for 296,000 events of the serving step; PERF.md section 6, PR 27). The
+    ``jax.named_scope`` paths stay in ``op_name``."""
     import jax
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
@@ -68,4 +77,6 @@ def configure_compile_cache() -> str:
     # ~a minute on the chip, the page-management one-liners are not worth
     # a file each
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    if not os.environ.get("JAX_TRACEBACK_IN_LOCATIONS_LIMIT"):
+        jax.config.update("jax_traceback_in_locations_limit", 0)
     return str(jax.config.jax_compilation_cache_dir)

@@ -95,7 +95,7 @@ from .paged import (
     tp_cache_specs,
     tp_gather_costs,
 )
-from .sampling import SamplingParams, sample
+from .sampling import SamplingParams, penalized, sample
 from .spec import SpecController
 from .scheduler import (
     DEFAULT_PRIORITY,
@@ -188,7 +188,18 @@ def _row_keys(seeds: jax.Array, steps: jax.Array) -> jax.Array:
 @jax.jit
 def _sample_rows(logits, keys, temp, top_k, top_p, pres, freq, counts):
     """Row-independent sampling: each slot draws from its own key over its
-    own logits, so neighbors can never perturb a request's stream."""
+    own logits, so neighbors can never perturb a request's stream.
+
+    The greedy/sampled choice is made ONCE over the slots, outside the
+    per-row ``vmap``: under the ``vmap`` ``sample``'s own ``cond`` has a
+    batched predicate and lowers to a select, so the vocabulary sort,
+    softmax, cumsum and Gumbel draw ran for every row of every call
+    whatever the slots asked for. A scalar predicate keeps the ``cond`` a
+    branch: a block in which no slot samples (an idle or released slot's
+    ``_temp`` is 0) applies the penalties and takes an argmax; a block
+    with one sampled slot runs the per-row ``sample`` for all, which
+    already gives a ``temp == 0`` row its argmax. Tokens are bitwise the
+    same either way (tests/test_sampling_epilogue.py)."""
 
     def one(lg, key, t, k, p, pp, fp, cnt):
         sp = SamplingParams(
@@ -197,10 +208,19 @@ def _sample_rows(logits, keys, temp, top_k, top_p, pres, freq, counts):
         )
         return sample(lg[None], key, sp, cnt[None])[0]
 
-    with jax.named_scope("sample"):
+    def sampled(_):
         return jax.vmap(one)(
             logits, keys, temp, top_k, top_p, pres, freq, counts
         )
+
+    def greedy(_):  # ``sample``'s penalties and its ``greedy`` branch
+        lg = penalized(
+            logits.astype(jnp.float32), counts, pres[:, None], freq[:, None]
+        )
+        return lg.argmax(-1).astype(jnp.int32)
+
+    with jax.named_scope("sample"):
+        return jax.lax.cond((temp > 0.0).any(), sampled, greedy, None)
 
 
 # host phases of one chunk, in the order step_chunk goes through them;
@@ -332,6 +352,18 @@ _ENGINE_COUNTERS = (
      "bytes each chip received in the tp step's all-gathers"),
     ("tp_gather_calls", "tlink_engine_tp_gather_calls_total",
      "all-gathers the tp step executed"),
+    # the sampling epilogue (ROADMAP S1): what the packed slots asked of
+    # it, per dispatched chunk from the host's own arrays. A sampler call
+    # is one _sample_rows over [slots, vocabulary]: each verify row
+    # walked and each continuation step
+    ("sampler_calls", "tlink_engine_sampler_calls_total",
+     "sampler calls the step program executed"),
+    ("sampler_calls_sampled", "tlink_engine_sampler_calls_sampled_total",
+     "sampler calls that took the sort/softmax branch (a slot samples)"),
+    ("verify_rows_walked", "tlink_engine_verify_rows_walked_total",
+     "verify rows walked (longest emitting draft + 1, 0 if none emits)"),
+    ("verify_rows_capacity", "tlink_engine_verify_rows_capacity_total",
+     "verify rows the program holds (spec_width a dispatched chunk)"),
 ) + tuple(
     # the anatomy of a chunk on the host (docs/SERVING.md "Observability"):
     # cumulative microseconds per phase of step_chunk, so a window reads
@@ -2989,6 +3021,8 @@ class ContinuousEngine:
                 blk, starts, n_valid, n_spec, emit, remaining, eos_arr, \
                     completing, handoff_done, grants = pack
                 with _Phase(ph, "dispatch"):
+                    # read before delivery releases a finished slot
+                    any_sampled = bool((self._temp > 0).any())
                     ops = self._step_operands(
                         blk, starts, n_valid, n_spec, emit, remaining,
                         eos_arr,
@@ -3042,6 +3076,16 @@ class ContinuousEngine:
                         "attn_pages_capacity",
                         n_exec * blk.shape[0] * self.cache.pages_per_slot,
                     )
+                    # the epilogue's work follows the slots: the walk is
+                    # as long as the longest emitting draft, and every
+                    # call sorts only if some packed slot samples
+                    walked = int(np.where(emit, n_spec + 1, 0).max())
+                    n_calls = walked + n_exec - 1
+                    self._count("sampler_calls", n_calls)
+                    if any_sampled:
+                        self._count("sampler_calls_sampled", n_calls)
+                    self._count("verify_rows_walked", walked)
+                    self._count("verify_rows_capacity", self.spec_width)
                     if self._tp_step is not None:
                         # the ragged pass gathers every block row through
                         # the layers and the verify rows through the head,

@@ -1155,6 +1155,15 @@ def _verify_emit(blk, logits_v, base, n_spec, emit, seeds, steps, temp,
     (``n_spec == 0``) walks exactly one row — its last valid row — which
     reduces to the plain single-draw epilogue, token for token.
 
+    The walk is as long as the longest draft of the block, as data:
+    ``max(n_spec over emitting slots) + 1`` rows (none when nothing
+    emits), a ``while_loop`` with a traced bound. Every row past that
+    bound finds every slot ``stopped``: it would emit 0 and leave the
+    carry as it was, so leaving it out changes no output. A block of
+    plain decodes walks one row whatever ``W`` is. The traced bound also
+    keeps the phase a loop of the lowered program at any ``W``
+    (``STEP_PHASES``: a trace tells the phases apart by loop order).
+
     Returns ``(tokens [S, W], last, m, ended, counts, steps, remaining)``
     where ``m`` is each slot's emitted count this pass (the verify-pass
     amortization the kill switch measures) and ``ended`` marks slots
@@ -1167,12 +1176,16 @@ def _verify_emit(blk, logits_v, base, n_spec, emit, seeds, steps, temp,
     nxt_rows = jnp.clip(base[:, None] + j_idx + 1, 0, blk.shape[1] - 1)
     draft_next = jnp.take_along_axis(blk, nxt_rows, axis=1)  # [S, W]
     has_draft = j_idx < n_spec[:, None]  # [S, W]
+    n_walk = jnp.minimum(jnp.max(jnp.where(emit, n_spec + 1, 0)), W)
 
     from .continuous import _row_keys, _sample_rows
 
-    def vstep(carry, xs):
-        counts, steps, remaining, stopped, ended, last, m = carry
-        lg, dnext, hd = xs
+    def vstep(st):
+        j, counts, steps, remaining, stopped, ended, last, m, toks = st
+        lg, dnext, hd = (
+            jax.lax.dynamic_index_in_dim(a, j, 1, keepdims=False)
+            for a in (logits_v, draft_next, has_draft)
+        )
         keys = _row_keys(seeds, steps)
         t = _sample_rows(lg, keys, temp, top_k, top_p, pres, freq, counts)
         live = emit & ~stopped
@@ -1190,20 +1203,21 @@ def _verify_emit(blk, logits_v, base, n_spec, emit, seeds, steps, temp,
         m = m + liv32
         ended = ended | end_now
         stopped = stopped | (live & ~accept)
-        return (counts, steps, remaining, stopped, ended, last, m), t
+        toks = jax.lax.dynamic_update_index_in_dim(toks, t, j, 1)
+        return (
+            j + 1, counts, steps, remaining, stopped, ended, last, m, toks,
+        )
 
     init = (
-        counts, steps, remaining, ~emit, jnp.zeros_like(emit),
-        jnp.zeros(S, jnp.int32), jnp.zeros(S, jnp.int32),
+        jnp.int32(0), counts, steps, remaining, ~emit,
+        jnp.zeros_like(emit), jnp.zeros(S, jnp.int32),
+        jnp.zeros(S, jnp.int32), jnp.zeros((S, W), jnp.int32),
     )
     with jax.named_scope(VERIFY_EMIT):
-        (counts, steps, remaining, _stopped, ended, last, m), toks = (
-            jax.lax.scan(
-                vstep, init,
-                (logits_v.transpose(1, 0, 2), draft_next.T, has_draft.T),
-            )
+        _j, counts, steps, remaining, _stopped, ended, last, m, toks = (
+            jax.lax.while_loop(lambda st: st[0] < n_walk, vstep, init)
         )
-    return toks.T, last, m, ended, counts, steps, remaining
+    return toks, last, m, ended, counts, steps, remaining
 
 
 def _ragged_block(x, lp, cfg: ModelConfig, cos, sin, cache_kv, write_pg,

@@ -96,6 +96,14 @@ class SamplingParams:
         )
 
 
+def penalized(logits, counts, pres, freq):
+    """OpenAI-style repetition control over the context so far: float32
+    ``logits [B, V]`` less ``pres`` where a token occurred and ``freq`` per
+    occurrence (``pres`` / ``freq`` broadcast against ``[B, V]``)."""
+    cf = counts.astype(jnp.float32)
+    return logits - pres * (cf > 0) - freq * cf
+
+
 @jax.jit
 def sample(
     logits: jax.Array,  # [B, V] float
@@ -117,22 +125,28 @@ def sample(
     the round-2 decode benchmark (25 tok/s vs 101 roofline). Inside an
     enclosing jit the wrapper inlines and changes nothing.
 
-    Scalar knobs apply to every row (with an all-greedy fast path that
-    skips the vocab argsort); ``[B, 1]`` knobs mix per-row settings in one
-    batch and select greedy/sampled per row.
+    Scalar knobs apply to every row; ``[B, 1]`` knobs mix per-row
+    settings in one batch and select greedy/sampled per row. The
+    ``lax.cond`` at the end skips the vocab argsort when no row samples,
+    for a caller that is NOT under a ``vmap`` (the legacy engine's
+    callers). Under a ``vmap`` over rows the predicate is batched and the
+    ``cond`` lowers to a select: both branches run for every row. That is
+    how the continuous engine's step paid a sort, a softmax and a cumsum
+    over the vocabulary per greedy row (``verify_emit_ms`` 106 ms a chunk
+    on a v5e, PERF.md section 6, PR 27); its ``_sample_rows`` now makes
+    the choice once over the slots, outside the ``vmap``, and calls this
+    function only when some slot samples.
     """
     logits = logits.astype(jnp.float32)
     B, V = logits.shape
     if counts is not None:
-        # OpenAI-style repetition control over the context so far
         pres = jnp.broadcast_to(
             jnp.atleast_1d(p.presence_penalty).reshape(-1, 1), (B, 1)
         )
         freq = jnp.broadcast_to(
             jnp.atleast_1d(p.frequency_penalty).reshape(-1, 1), (B, 1)
         )
-        cf = counts.astype(jnp.float32)
-        logits = logits - pres * (cf > 0) - freq * cf
+        logits = penalized(logits, counts, pres, freq)
     temp = jnp.broadcast_to(jnp.atleast_1d(p.temperature).reshape(-1, 1), (B, 1))
     top_k = jnp.broadcast_to(jnp.atleast_1d(p.top_k).reshape(-1, 1), (B, 1))
     top_p = jnp.broadcast_to(jnp.atleast_1d(p.top_p).reshape(-1, 1), (B, 1))

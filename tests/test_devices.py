@@ -37,6 +37,43 @@ def test_compile_cache_rule(env_dir):
     assert f"RET={want}" in p.stdout and f"CFG={want}" in p.stdout, p.stdout
 
 
+_LOWER_CHILD = """
+from tensorlink_tpu.core.devices import configure_compile_cache
+import jax, jax.numpy as jnp
+configure_compile_cache()
+def f(x):
+    with jax.named_scope("tlink.probe"):
+        return jnp.sin(x) + 1
+txt = jax.jit(f).lower(jnp.ones(4)).compile().as_text()
+print("LIMIT=%d" % jax.config.jax_traceback_in_locations_limit)
+print("FRAMES=%d" % txt.count("stack_frame_id"))
+print("SCOPED=%d" % txt.count("tlink.probe"))
+"""
+
+
+@pytest.mark.parametrize("env_limit", [None, "10"],
+                         ids=["env-unset", "env-set"])
+def test_programs_lower_without_stack_frames(env_limit):
+    """The same helper lowers programs without Python stack frames in
+    their operations' metadata (the TPU profiler resolves them for every
+    device event when a trace is stopped) and keeps the named-scope path;
+    JAX's own JAX_TRACEBACK_IN_LOCATIONS_LIMIT, when set, wins."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_ENABLE_COMPILATION_CACHE": "false"}
+    env.pop("JAX_TRACEBACK_IN_LOCATIONS_LIMIT", None)
+    if env_limit is not None:
+        env["JAX_TRACEBACK_IN_LOCATIONS_LIMIT"] = env_limit
+    p = subprocess.run(
+        [sys.executable, "-c", _LOWER_CHILD], capture_output=True, text=True,
+        env=env, cwd=REPO, timeout=120,
+    )
+    assert p.returncode == 0, p.stderr[-800:]
+    out = dict(line.split("=") for line in p.stdout.split())
+    assert int(out["LIMIT"]) == int(env_limit or 0), p.stdout
+    assert (int(out["FRAMES"]) > 0) == (env_limit is not None), p.stdout
+    assert int(out["SCOPED"]) > 0, p.stdout
+
+
 def test_no_other_compile_cache_directory_in_the_tree():
     """The rule lives in ONE function: no other file names the config knob
     (a second setter is how bench.py and the profiler script each grew

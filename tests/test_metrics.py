@@ -231,6 +231,10 @@ LEGACY_ENGINE_KEYS = (
     "attn_pages_live", "attn_pages_capacity",
     # the tensor-parallel step's activation gathers (0 at tp = 1)
     "tp_gather_bytes", "tp_gather_calls",
+    # the sampling epilogue (ROADMAP S1): calls, those that sorted, and
+    # the verify walk's length against the rows the program holds
+    "sampler_calls", "sampler_calls_sampled",
+    "verify_rows_walked", "verify_rows_capacity",
     # the anatomy of a chunk: cumulative host microseconds per phase
     "chunk_us_between", "chunk_us_admit", "chunk_us_pack",
     "chunk_us_dispatch", "chunk_us_wait", "chunk_us_drain",

@@ -99,10 +99,20 @@ def test_three_top_level_loops_in_phase_order(tiny_engine, spec_width):
         assert path.split("/")[1:] == [phase, "while"], (path, phase)
     assert STEP_PHASES == (
         "tlink.ragged_pass", "tlink.verify_emit", "tlink.decode_cont")
+    # compiled, the three are still loops of the entry computation, in
+    # that order: the layer scan with its trip count known, the verify
+    # walk with none (its bound is data: the longest emitting draft + 1),
+    # so no pass can inline it even at spec_width 1
+    compiled = ce.lower_step().compile().as_text()
+    whiles = [ln for ln in compiled[compiled.index("ENTRY"):].splitlines()
+              if " while(" in ln]
+    assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)
+            for ln in whiles] == list(STEP_PHASES)
+    assert "known_trip_count" in whiles[0]
+    assert "known_trip_count" not in whiles[1]
     # the inner names are there, under the phases: an operation inside a
     # loop's body names its whole path only once compiled
-    paths = set(re.findall(
-        r'op_name="([^"]*)"', ce.lower_step().compile().as_text()))
+    paths = set(re.findall(r'op_name="([^"]*)"', compiled))
     for phase, inner in (("tlink.ragged_pass", "attn"),
                          ("tlink.ragged_pass", "kv_write"),
                          ("tlink.ragged_pass", "mlp"),
