@@ -15,7 +15,7 @@ speculative decode armed).
 It fails (exit code != 0, no result line) when JAX finds no accelerator, when
 any phase fails, or outside the repo. Phases:
 
-* ``kernels``: the three paged Pallas kernels against their ``jnp``
+* ``kernels``: the two paged Pallas kernels against their ``jnp``
   references at the model's head widths, bf16 / int8 / packed-int4 pages:
   a small input, then the served cells' shapes (8 slots, chunk 128, 256
   pages a slot, contexts 250 and 1,200), there also at the head shapes of
@@ -403,12 +403,11 @@ def kernels_phase(cfg) -> None:
     print("phase kernels:", flush=True)
     page = 16
     model = (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
-    # (label, chunk, pages a slot, starts, n_valid, decode lengths, slot
-    # whose row feeds the one-slot prefill kernel)
-    small = ("small", 16, 4, [37, 0, 21, 0], [1, 16, 9, 0], [38, 16, 30, 0], 2)
+    # (label, chunk, pages a slot, starts, n_valid, decode lengths)
+    small = ("small", 16, 4, [37, 0, 21, 0], [1, 16, 9, 0], [38, 16, 30, 0])
     cells = ("cells", 128, 256, [249, 1199, 1072, 0, 122, 0, 245, 3968],
              [1, 1, 128, 128, 128, 0, 5, 128],
-             [250, 1200, 1200, 128, 250, 0, 250, 4096], 2)
+             [250, 1200, 1200, 128, 250, 0, 250, 4096])
     shapes = (
         # decode slot, fresh prefill, mid-page prefill offset, idle slot
         (model, *small),
@@ -418,7 +417,7 @@ def kernels_phase(cfg) -> None:
         ((28, 4, 128), "cells, 28/4 heads", *cells[1:]),
         ((32, 32, 96), "cells, 32/32 heads of 96", *cells[1:]),
     )
-    for (Hq, Hkv, hd), label, C, n_pp, starts, n_valid, lengths, pf in shapes:
+    for (Hq, Hkv, hd), label, C, n_pp, starts, n_valid, lengths in shapes:
         scale = hd ** -0.5
         S = len(starts)
         P = 1 + S * n_pp
@@ -446,10 +445,6 @@ def kernels_phase(cfg) -> None:
                 "decode": (A.paged_attention, A.paged_attention_ref,
                            (q[:, 0], kp, vp, bt, lengths)),
             }
-            if (Hq, Hkv, hd) == model:  # no caller in the step program
-                cases["prefill"] = (A.paged_prefill_attention,
-                                    A.paged_prefill_attention_ref,
-                                    (q[pf], kp, vp, bt[pf], starts[pf]))
             for kname, (kern, ref, args) in cases.items():
                 got = np.asarray(kern(*args, scale=scale, **kw), np.float32)
                 with jax.default_matmul_precision("highest"):

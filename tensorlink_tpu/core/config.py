@@ -208,7 +208,7 @@ class MLConfig:
     # resumed stream is bit-identical to an uninterrupted run
     sched_preemption: bool = True
     # "slo" (priority + aging + preemption) or "fcfs" (PR-2 behavior:
-    # strict arrival order, no preemption) — the bench's baseline knob
+    # strict arrival order, no preemption)
     sched_policy: str = "slo"
     # backpressure: reject admission when the estimated queue wait for
     # the request's class exceeds this many seconds (0 disables the

@@ -1,7 +1,7 @@
 """Device acquisition, the compile-cache rule and the one lowering option.
 
 One process owns the accelerator: the one that runs the ML threads (a
-``WorkerNode``/``ValidatorNode`` caller, the ``bench.py`` child,
+``WorkerNode``/``ValidatorNode`` caller, ``benchmarks/run.py``,
 ``chip_smoke.py``). :func:`acquire_devices` initialises the backend in that
 process, in place — a second process probing the chip would take it from
 the first. A backend that does not come up raises: the worker does not

@@ -16,8 +16,7 @@ sprinkle): spans are recorded only at boundaries the host already
 synchronizes (the per-chunk boundary in the slot engine, admission, the
 migration verbs). Recording is a ``time.monotonic()`` read plus a dict
 append under a short lock — no device sync, no compiled programs, and
-with no trace id on a request the engine skips the calls entirely
-(bench-measured disabled-mode overhead).
+with no trace id on a request the engine skips the calls entirely.
 
 Span timestamps: ``dur_ms`` comes from ``time.monotonic`` pairs on one
 host (drift-free). ``ts`` is a wall-clock epoch anchor recorded ONCE per

@@ -229,6 +229,9 @@ CHUNK_PHASES = (
     "admit", "pack", "dispatch", "wait", "drain", "deliver", "post",
 )
 
+FLIGHT_CAPACITY = 1024  # chunks the flight recorder keeps
+MIGRATION_TTL_S = 120.0  # an engine's ``migration_ttl_s`` starts here
+
 
 class _Phase:
     """One host phase of a chunk, marked where the work happens: a
@@ -529,12 +532,10 @@ class ContinuousEngine:
         sched_policy: str = "slo",
         sched_max_wait_s: float = 60.0,
         default_priority: str = DEFAULT_PRIORITY,
-        migration_ttl_s: float = 120.0,
         handoff_after_prefill: bool = False,
         worker_role: str = "mixed",
         trace_site: str = "",
         metrics: MetricsRegistry | None = None,
-        flight_capacity: int = 1024,
         pool: SharedPagePool | None = None,
         model_id: str = "",
         page_quota: int = 0,
@@ -730,7 +731,7 @@ class ContinuousEngine:
         # staged tickets whose client never attaches (it died mid-drain)
         # are garbage-collected after this many seconds so their pages
         # can't leak; close() frees the rest before the conservation check
-        self.migration_ttl_s = float(migration_ttl_s)
+        self.migration_ttl_s = MIGRATION_TTL_S
         self.drain_state = "serving"  # "serving" | "draining"
         # -- disaggregated prefill/decode (docs/SERVING.md) --------------
         # the drain fence GENERALIZED into steady-state handoff: a
@@ -759,10 +760,10 @@ class ContinuousEngine:
         # already synchronizes (admission, the per-chunk drain, the
         # migration verbs) and ONLY for requests carrying a trace id —
         # zero compiled programs, zero extra device syncs, near-zero cost
-        # when tracing is off (bench-measured)
+        # when tracing is off
         self.tracer = get_tracer()
         self.trace_site = str(trace_site)
-        self.recorder = FlightRecorder(flight_capacity)
+        self.recorder = FlightRecorder(FLIGHT_CAPACITY)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._stat = {
             key: self.metrics.counter(name, help)
@@ -829,10 +830,6 @@ class ContinuousEngine:
             "model FLOPs utilization of the last background train step",
             fn=lambda: self._train_mfu,
         )
-        # the last chunk's admit + pack in ms (bench.py reads it); the
-        # per-phase chunk_us_* counters and the flight recorder's fields
-        # are the record (rot-guarded in tests/test_tp.py)
-        self._host_gap_ms = 0.0
         # monotonic stamp of the last dispatched chunk's exit while the
         # engine still had work: the next chunk's "between" starts there
         self._chunk_exit_t: float | None = None
@@ -3130,11 +3127,10 @@ class ContinuousEngine:
             self._count(f"chunk_us_{k}", v)
         # host_ms and chunk_ms keep their meaning: step_chunk's entry to
         # the dispatch, and the dispatch to the end of the drain
-        self._host_gap_ms = (us["admit"] + us["pack"]) / 1e3
         self.recorder.record(
             **fields,
             chunk_ms=(us["dispatch"] + us["wait"] + us["drain"]) / 1e3,
-            host_ms=self._host_gap_ms,
+            host_ms=(us["admit"] + us["pack"]) / 1e3,
             t0=t0,
             **{f"{k}_ms": v / 1e3 for k, v in us.items()},
         )

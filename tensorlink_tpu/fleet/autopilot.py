@@ -33,12 +33,12 @@ decisions without acting. Every decision lands in a bounded history
 (the ``/fleet`` route) and in labeled ``tlink_autopilot_*`` counters.
 
 The loop is a plain daemon thread (``start``/``stop``) but every
-decision lives in :meth:`tick`, directly callable — tests and the bench
+decision lives in :meth:`tick`, directly callable — tests
 drive ticks synchronously between engine chunks.
 
 The ACTIONS layer is pluggable: :class:`EngineFleetActions` operates on
 in-process :class:`~tensorlink_tpu.engine.continuous.ContinuousEngine`
-replicas (the bench/test harness and local serving), honoring the
+replicas (the test harness and local serving), honoring the
 engines' single-driver discipline through a caller-supplied ``exec_on``
 (e.g. ``ContinuousBatcher.run_on_driver``); the validator wires a
 bridge-backed actions object for remote replicas (DRAIN verbs).

@@ -20,7 +20,7 @@ the destination (stage_prefix). A stale map misguides one RPC, never
 bytes.
 
 :func:`make_fleet_fetcher` closes the loop for in-process fleets (the
-bench's multi-replica legs and the tests): it builds the
+tests): it builds the
 ``engine.fetch_prefix`` callback from a view provider plus per-replica
 pull functions, implementing the fallback ladder's third rung — best
 candidate first, next on refusal, None (→ re-prefill) when the map has

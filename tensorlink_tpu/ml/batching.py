@@ -723,7 +723,7 @@ class ContinuousBatcher:
 
     - ``engine=`` (a GenerationEngine or ContinuousEngine): drives a local
       slot engine on a dispatcher thread — the in-process serving path,
-      used by the bench's serving leg and tests.
+      used by tests.
     - ``model=`` single-stage DistributedModel: pure pass-through; each
       request RPCs the worker with ``continuous=True`` and the worker's
       slot engine co-batches concurrent requests (admission happens where

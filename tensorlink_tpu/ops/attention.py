@@ -24,12 +24,11 @@ that a score tile spans 128 key positions, the next block's copies in
 flight under this block's arithmetic. Query rows are walked the same way: row blocks up to
 ``n_valid``, each against KV up to its own causal limit. Nothing is
 walked, fetched or computed past ``start + n_valid``: cost follows what
-is live, not the slot's page capacity. :func:`paged_prefill_attention`
-(one slot's offset chunk, grid ``(kv_head, page)``) is the legacy
-prefill entry point and has no caller in the step program; the
-``*_ref`` functions are the pure-jax.numpy references the CPU path and
-the parity tests run — the ragged reference is pinned bitwise against
-the legacy pair's composition.
+is live, not the slot's page capacity. The ``*_ref`` functions are the
+pure-jax.numpy references the CPU path and the parity tests run — the
+ragged reference is pinned bitwise against the composition of the
+one-slot pair (:func:`paged_prefill_attention_ref` and
+:func:`paged_attention_ref`).
 
 Scope: **forward-only, causal, offset-0 prefill** — exactly the serving
 engine's fresh-cache prefill (engine/generate.py::_prefill). Training and
@@ -234,8 +233,7 @@ def flash_attention(
 
 
 # ---------------------------------------------------------------------------
-# Paged attention references and the one-slot prefill kernel (continuous
-# batching, engine/paged.py)
+# Paged attention references (continuous batching, engine/paged.py)
 # ---------------------------------------------------------------------------
 
 
@@ -248,40 +246,6 @@ def _unpack4(x):
     from ..models.quant import unpack_int4
 
     return unpack_int4(x).astype(jnp.float32)
-
-
-def _load_page(k_ref, v_ref, ks_ref, vs_ref, h, packed: bool):
-    """One grid step's KV page in f32 plus, for quantized pages, this kv
-    head's per-position scales as lane-major ``[1, page]`` rows.
-
-    The scale operands arrive as the page's whole ``[Hkv, page]`` plane —
-    a ``(1, Hkv, page)`` block spans the array's full trailing dims, the
-    one shape the TPU tiling accepts here without padding every scale to
-    a lane tile in HBM — and the head's row is picked in-kernel. The rows
-    stay on the lane axis: callers fold ``k_scale`` into the score
-    columns and ``v_scale`` into the softmax weights (``q·(k·s) ==
-    (q·k)·s`` per key position), so the dequant costs ``page`` multiplies
-    per query row and never needs a lane→sublane relayout. Packed int4
-    pages (two values per byte) unpack in the same VMEM read."""
-    if packed:
-        k = _unpack4(k_ref[0, 0])  # [page, hd]
-        v = _unpack4(v_ref[0, 0])
-    else:
-        k = k_ref[0, 0].astype(jnp.float32)  # [page, hd]
-        v = v_ref[0, 0].astype(jnp.float32)
-    if ks_ref is None:
-        return k, v, None, None
-    ks = ks_ref[0, pl.ds(h, 1), :].astype(jnp.float32)  # [1, page]
-    vs = vs_ref[0, pl.ds(h, 1), :].astype(jnp.float32)
-    return k, v, ks, vs
-
-
-def _scale_spec(Hkv: int, page: int, page_idx):
-    """BlockSpec of a per-page scale plane: the same physical page index
-    as the KV block (``page_idx``'s first coordinate), every kv head."""
-    return pl.BlockSpec(
-        (1, Hkv, page), lambda *a: (page_idx(*a)[0], 0, 0)
-    )
 
 
 def _gather_pages(pages, scales, block_tables, shape):
@@ -366,8 +330,8 @@ def paged_prefill_attention_ref(
     k_scale: jax.Array | None = None,  # f32 [P, Hkv, page] — int8 pages
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """Pure-jnp offset-carrying paged prefill attention — the CPU serving
-    path and the ground truth the Pallas kernel is pinned against.
+    """Pure-jnp offset-carrying paged prefill attention: the one-slot
+    ground truth the ragged kernel and its reference are pinned against.
 
     This is what lifts the offset-0-only restriction of the monolithic
     flash prefill: query ``j`` sits at absolute position ``start + j`` and
@@ -401,178 +365,6 @@ def paged_prefill_attention_ref(
     w = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("ckgx,xkd->ckgd", w, v.astype(jnp.float32))
     return out.reshape(C, Hq, hd).astype(q.dtype)
-
-
-def _paged_prefill_kernel(
-    bt_ref,  # scalar-prefetch: block-table row [1, n_pp]
-    start_ref,  # scalar-prefetch: absolute position of q[0], [1]
-    q_ref,  # [1, C·G, hd]
-    k_ref,  # [1, 1, page, hd] — page bt[0, i] of kv head h
-    v_ref,  # [1, 1, page, hd]
-    *rest,  # quantized: ks_ref, vs_ref [1, Hkv, page] then out + scratch
-    scale: float,
-    page: int,
-    n_pp: int,
-    G: int,
-    quantized: bool,
-    packed: bool = False,
-):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    h = pl.program_id(0)
-    i = pl.program_id(1)
-    start = start_ref[0]
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    CG = q_ref.shape[1]
-    C = CG // G
-    # pages wholly past the chunk's last visible position hold no
-    # attendable KV — skip their compute, and the BlockSpec index map
-    # clamps their fetch to the scratch page (a repeated block index is
-    # not re-copied by the pipeline), so both FLOPs and HBM traffic
-    # follow start + C, not the slot's page capacity
-    @pl.when(i * page <= start + C - 1)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [C·G, hd]
-        # int8/int4 pages: the HBM fetch carried the quantized bytes; the
-        # dequant rides the score columns / softmax weights (_load_page)
-        k, v, ks, vs = _load_page(
-            k_ref, v_ref, ks_ref, vs_ref, h, packed
-        )
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [C·G, page]
-        if quantized:
-            sc = sc * ks
-        # query row r is chunk position r // G at absolute start + r // G
-        q_pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (CG, page), 0
-        ) // G
-        k_pos = i * page + jax.lax.broadcasted_iota(
-            jnp.int32, (CG, page), 1
-        )
-        ok = k_pos <= q_pos
-        sc = jnp.where(ok, sc, NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p * vs if quantized else p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = m_new
-
-    @pl.when(i == n_pp - 1)
-    def _finalize():
-        # every query attends at least its own (just-written) key, so
-        # l > 0; the floor only guards degenerate inputs
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(
-            o_ref.dtype
-        )
-
-
-# tlint: hot-path
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_prefill_attention(
-    q: jax.Array,  # [C, Hq, hd]
-    k_pages: jax.Array,  # [P, Hkv, page, hd]
-    v_pages: jax.Array,  # [P, Hkv, page, hd]
-    bt_row: jax.Array,  # int32 [n_pp]
-    start: jax.Array,  # int32 scalar
-    *,
-    scale: float,
-    interpret: bool = False,
-    k_scale: jax.Array | None = None,  # f32 [P, Hkv, page] — int8 pages
-    v_scale: jax.Array | None = None,
-) -> jax.Array:
-    """Offset-carrying paged prefill attention (TPU); returns
-    ``[C, Hq, hd]``.
-
-    Grid ``(kv_head, page_idx)`` with the slot's block-table row and the
-    chunk's start offset riding scalar prefetch: each grid step's k/v
-    BlockSpec indexes the PHYSICAL page ``bt_row[i]`` (the gather is the
-    pipeline's HBM→VMEM copy), GQA queries group on the kv-head axis so
-    repeated KV heads are never materialized, and the online softmax
-    carries ``[C·G, 1]`` running max/denominator like the flash kernel.
-    One compiled program serves every (offset, page assignment) — the
-    block table and start are data, not shape."""
-    C, Hq, hd = q.shape
-    P, Hkv, page, hdk = k_pages.shape  # hdk = hd // 2 for packed int4
-    n_pp = bt_row.shape[0]
-    G = Hq // Hkv
-    # [C, Hq, hd] -> [Hkv, C·G, hd]: kv-head-major so one grid row's
-    # queries share the page block that prefetch pulled in
-    qg = (
-        q.reshape(C, Hkv, G, hd)
-        .transpose(1, 0, 2, 3)
-        .reshape(Hkv, C * G, hd)
-    )
-    quantized = k_scale is not None
-    packed = quantized and hdk * 2 == hd
-    kernel = functools.partial(
-        _paged_prefill_kernel, scale=scale, page=page, n_pp=n_pp, G=G,
-        quantized=quantized, packed=packed,
-    )
-    # pages wholly past the last visible position clamp their fetch to
-    # scratch page 0: the pipeline skips copies when the mapped block
-    # repeats, so HBM traffic follows the chunk's live span (start + C),
-    # not the slot's capacity
-    def page_idx(h, i, bt, st, p=page, c=C):
-        return (jnp.where(i * p <= st[0] + c - 1, bt[0, i], 0), h, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, C * G, hd), lambda h, i, bt, st: (h, 0, 0)),
-        pl.BlockSpec((1, 1, page, hdk), page_idx),
-        pl.BlockSpec((1, 1, page, hdk), page_idx),
-    ]
-    args = [qg, k_pages, v_pages]
-    if quantized:
-        # int8 pages ride with their per-(position, head) scales — same
-        # physical page index, dequant fused in-kernel at the VMEM read
-        in_specs += [_scale_spec(Hkv, page, page_idx)] * 2
-        args += [k_scale, v_scale]
-    out = pl.pallas_call(
-        kernel,
-        name="paged_prefill_attention",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(Hkv, n_pp),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, C * G, hd), lambda h, i, bt, st: (h, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((C * G, 1), jnp.float32),
-                pltpu.VMEM((C * G, 1), jnp.float32),
-                pltpu.VMEM((C * G, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((Hkv, C * G, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        bt_row.reshape(1, n_pp),
-        jnp.asarray(start, jnp.int32).reshape(1),
-        *args,
-    )
-    return (
-        out.reshape(Hkv, C, G, hd)
-        .transpose(1, 0, 2, 3)
-        .reshape(C, Hq, hd)
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -1103,7 +895,6 @@ __all__ = [
     "flash_attention",
     "paged_attention",
     "paged_attention_ref",
-    "paged_prefill_attention",
     "paged_prefill_attention_ref",
     "ragged_paged_attention",
     "ragged_paged_attention_ref",

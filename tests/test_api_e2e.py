@@ -61,6 +61,12 @@ def api_cluster(tmp_path_factory):
             break
         time.sleep(0.2)
     validator.test_workers = [worker, worker2]  # for capacity-shrink tests
+    # every test counts on MODEL, whichever a process of xdist draws first
+    status, body = _req(
+        validator.api, "POST", "/request-model",
+        {"hf_name": MODEL, "config": tiny_cfg_json(), "seq_len": 256},
+    )
+    assert status == 200 and body["status"] == "ready", body
     yield validator
     worker.stop()
     worker2.stop()
@@ -641,6 +647,12 @@ def test_stats_and_node_info(api_cluster):
     assert hosted[MODEL].get("stages") == 1
     status, body = _req(api, "GET", "/node-info")
     assert body["role"] == "validator" and MODEL in body["hosted_models"]
+    status, body = _req(
+        api, "POST", "/v1/generate",
+        {"hf_name": MODEL, "message": "hi", "max_new_tokens": 2,
+         "do_sample": False},
+    )
+    assert status == 200, body
     status, body = _req(api, "GET", "/model-demand")
     assert body["demand"].get(MODEL, 0) >= 1
     status, body = _req(api, "GET", "/network-history")

@@ -128,7 +128,7 @@ def test_per_row_room_no_cross_truncation():
 def test_batch_bucket_smallest_fit_for_1_to_8_pending():
     """The serving batch shape for n pending requests is the SMALLEST
     compiled bucket ≥ n — 2 live requests must never pad to B=8 (4× the
-    decode FLOPs for dead rows, the BENCH_r05 0.56×-per-row regression)."""
+    decode FLOPs for dead rows)."""
     from tensorlink_tpu.engine.generate import GenerationEngine
     from tensorlink_tpu.models import ModelConfig, init_params
 

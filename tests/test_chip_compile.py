@@ -150,17 +150,6 @@ def test_walk_compiles_at_other_head_shapes(v5e, heads, kernel, mode):
         )
 
 
-@pytest.mark.parametrize("mode", MODES)
-def test_prefill_kernel_compiles_for_v5e(v5e, mode):
-    """The one-slot offset-chunk kernel (same scale operands)."""
-    hq, hkv = FULL
-    kv, scales = _pages(v5e, mode, hkv)
-    _compiles_with_kernel(
-        A.paged_prefill_attention, _q(v5e, 128, hq, HD), kv, kv,
-        _i32(v5e, N_PP), _i32(v5e), **scales,
-    )
-
-
 def test_flash_kernel_compiles_for_v5e(v5e):
     """The dense engine's fresh-cache prefill kernel (no pages)."""
     hq, hkv = FULL

@@ -58,8 +58,7 @@ def smoke(monkeypatch):
     )
     # the kernels, interpreted — both where the smoke calls them and where
     # the step program picked them up at import
-    for name in ("ragged_paged_attention", "paged_attention",
-                 "paged_prefill_attention"):
+    for name in ("ragged_paged_attention", "paged_attention"):
         interp = functools.partial(getattr(attention, name), interpret=True)
         monkeypatch.setattr(attention, name, interp)
         if hasattr(paged, name):

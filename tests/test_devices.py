@@ -76,8 +76,8 @@ def test_programs_lower_without_stack_frames(env_limit):
 
 def test_no_other_compile_cache_directory_in_the_tree():
     """The rule lives in ONE function: no other file names the config knob
-    (a second setter is how bench.py and the profiler script each grew
-    their own /tmp path)."""
+    (a second setter is how two retired scripts each grew their own /tmp
+    path)."""
     here = Path(__file__).resolve()
     rule = REPO / "tensorlink_tpu" / "core" / "devices.py"
     sources = [

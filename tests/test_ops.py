@@ -19,7 +19,6 @@ from tensorlink_tpu.ops.attention import (
     flash_attention,
     paged_attention,
     paged_attention_ref,
-    paged_prefill_attention,
     paged_prefill_attention_ref,
     ragged_paged_attention,
     ragged_paged_attention_ref,
@@ -87,9 +86,16 @@ def test_flash_rejects_indivisible_seq():
                         interpret=True)
 
 
+@pytest.fixture
+def flash_off_tpu(monkeypatch):
+    """Off the TPU the model takes the flash kernel (interpreted) only
+    when asked (models/transformer.py)."""
+    monkeypatch.setenv("TLTPU_FLASH_INTERPRET", "1")
+
+
 @pytest.mark.slow  # engine-level compile-heavy; CI engine job runs these
 # unfiltered — the tier-1 'not slow' pass keeps the kernel parity tests only
-def test_engine_flash_windowed_prefill_matches_dense():
+def test_engine_flash_windowed_prefill_matches_dense(flash_off_tpu):
     """A sliding-window (mistral-style) config takes the flash path too."""
     from tensorlink_tpu.engine.generate import GenerationEngine
     from tensorlink_tpu.engine.sampling import SamplingParams
@@ -112,7 +118,7 @@ def test_engine_flash_windowed_prefill_matches_dense():
 
 
 @pytest.mark.slow  # see above
-def test_engine_flash_prefill_matches_dense():
+def test_engine_flash_prefill_matches_dense(flash_off_tpu):
     """cfg.flash_attention routes the engine's fresh-cache prefill through
     the kernel; generated tokens must match the einsum engine exactly
     (same math, same greedy argmax), including right-padded batch rows."""
@@ -225,39 +231,6 @@ def test_paged_ref_matches_dense_attention():
 # ---------------------------------------------------------------------------
 # offset-carrying paged PREFILL attention (chunked prefill / prefix cache)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize(
-    "C,Hq,Hkv,hd,page,n_pp,start",
-    [
-        (8, 8, 2, 32, 8, 4, 0),  # GQA, offset 0 (fresh admission)
-        (8, 8, 2, 32, 8, 4, 13),  # GQA, mid-page offset (COW landing)
-        # extra head layouts ride the CI engine job (tier-1 wall-time)
-        pytest.param(16, 4, 4, 16, 16, 3, 16, marks=pytest.mark.slow),
-        pytest.param(4, 8, 1, 64, 4, 8, 27, marks=pytest.mark.slow),
-    ],
-)
-def test_paged_prefill_kernel_matches_ref(C, Hq, Hkv, hd, page, n_pp, start):
-    """The offset-carrying Pallas prefill kernel (queries at absolute
-    positions start+j over scalar-prefetched pages) matches the pure-jnp
-    reference — the restriction the monolithic flash kernel had
-    (offset-0-only fresh caches) is what this lifts."""
-    rng = np.random.default_rng(4)
-    P = 1 + n_pp + 2
-    q = jnp.asarray(rng.normal(size=(C, Hq, hd)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(P, Hkv, page, hd)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(P, Hkv, page, hd)).astype(np.float32))
-    bt = jnp.asarray(rng.permutation(np.arange(1, P))[:n_pp].astype(np.int32))
-    scale = hd**-0.5
-    ref = paged_prefill_attention_ref(
-        q, kp, vp, bt, jnp.int32(start), scale=scale
-    )
-    got = paged_prefill_attention(
-        q, kp, vp, bt, jnp.int32(start), scale=scale, interpret=True
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
-    )
-
-
 def test_paged_prefill_ref_matches_dense_causal():
     """A chunk at offset ``start`` over contiguously-paged KV computes
     exactly dense causal attention restricted to the chunk's rows: query
@@ -327,13 +300,21 @@ def _ragged_case(rng, S, C, Hq, Hkv, hd, page, n_pp, starts, nv):
         # all-padding block (idle engine shape: all-zero output, no NaN)
         pytest.param(2, 8, 4, 2, 16, 8, 2, [0, 0], [0, 0],
                      marks=pytest.mark.slow),
+        # one slot, a whole chunk at an offset: the prefill path
+        (1, 8, 8, 2, 32, 8, 4, [0], [8]),  # GQA, fresh admission
+        (1, 8, 8, 2, 32, 8, 4, [13], [8]),  # GQA, mid-page (COW landing)
+        pytest.param(1, 16, 4, 4, 16, 16, 3, [16], [16],
+                     marks=pytest.mark.slow),
+        pytest.param(1, 4, 8, 1, 64, 4, 8, [27], [4],
+                     marks=pytest.mark.slow),
     ],
 )
 def test_ragged_kernel_matches_ref(S, C, Hq, Hkv, hd, page, n_pp, starts, nv):
     """The ragged Pallas kernel (decode grid + whole-chunk query blocks,
     per-slot (start, n_valid) via scalar prefetch) matches the pure-jnp
     reference across decode-only / prefill-only / mixed / all-padding
-    slot configurations — the one-kernel claim of the unified step."""
+    slot configurations — the one-kernel claim of the unified step — and
+    each slot's valid rows the one-slot offset-prefill reference."""
     rng = np.random.default_rng(8)
     q, kp, vp, bt, st, nvj = _ragged_case(
         rng, S, C, Hq, Hkv, hd, page, n_pp, starts, nv
@@ -350,6 +331,13 @@ def test_ragged_kernel_matches_ref(S, C, Hq, Hkv, hd, page, n_pp, starts, nv):
     for s in range(S):
         assert np.abs(np.asarray(ref)[s, nv[s]:]).max(initial=0) == 0
         assert np.abs(np.asarray(got)[s, nv[s]:]).max(initial=0) == 0
+        pf = paged_prefill_attention_ref(
+            q[s], kp, vp, bt[s], st[s], scale=scale
+        )
+        np.testing.assert_allclose(
+            np.asarray(got)[s, : nv[s]], np.asarray(pf)[: nv[s]],
+            rtol=2e-5, atol=2e-5,
+        )
 
 
 def test_ragged_ref_matches_decode_and_prefill_refs_bitwise():
@@ -836,8 +824,9 @@ def test_quantized_ragged_kernel_matches_ref(
 
 @pytest.mark.slow  # see above — CI's engine job runs it on every push
 def test_quantized_decode_and_prefill_kernels_match_refs():
-    """The decode and offset-prefill entry points carry int8 pages too:
-    kernel (interpret) vs quantized reference parity for both."""
+    """The decode entry point and a one-slot offset chunk through the
+    ragged one carry int8 pages too: kernel (interpret) vs quantized
+    reference parity for both."""
     rng = np.random.default_rng(22)
     S, Hq, Hkv, hd, page, n_pp = 4, 8, 2, 32, 8, 4
     P = 1 + S * n_pp
@@ -865,10 +854,11 @@ def test_quantized_decode_and_prefill_kernels_match_refs():
         qp, k8, v8, bt[0], jnp.int32(13), scale=scale,
         k_scale=ks, v_scale=vs,
     )
-    got = paged_prefill_attention(
-        qp, k8, v8, bt[0], jnp.int32(13), scale=scale, interpret=True,
+    got = ragged_paged_attention(
+        qp[None], k8, v8, bt[:1], jnp.asarray([13], jnp.int32),
+        jnp.asarray([C], jnp.int32), scale=scale, interpret=True,
         k_scale=ks, v_scale=vs,
-    )
+    )[0]
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
     )
@@ -911,7 +901,7 @@ def test_quantized_kv_divergence_bounded():
 
 
 @pytest.mark.slow  # see above
-def test_engine_flash_sharded_mesh_matches_dense(cpu_devices):
+def test_engine_flash_sharded_mesh_matches_dense(flash_off_tpu, cpu_devices):
     """Flash prefill composes with a tensor/data mesh (r3 weak: it was
     silently ignored on sharded stages): the kernel runs inside shard_map
     over data/tensor, and the sharded flash engine's tokens match the
@@ -972,8 +962,8 @@ def _int4_pages(rng, P, Hkv, page, hd):
 
 @pytest.mark.slow  # interpret-mode kernel compiles — CI engine job
 def test_int4_kernels_match_refs():
-    """Packed int4 pages through all THREE paged entry points: the
-    Pallas kernels' in-VMEM nibble unpack + dequant matches the pure-jnp
+    """Packed int4 pages through both paged entry points: the Pallas
+    kernels' in-VMEM nibble unpack + dequant matches the pure-jnp
     references' gather-time dequant across mixed/decode/prefill shapes —
     the same parity bar the int8 pages hold."""
     rng = np.random.default_rng(31)
@@ -1014,16 +1004,17 @@ def test_int4_kernels_match_refs():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
     )
-    # offset-prefill entry point
+    # a one-slot offset chunk against the one-slot prefill reference
     qp = jnp.asarray(rng.normal(size=(C, Hq, hd)).astype(np.float32))
     ref = paged_prefill_attention_ref(
         qp, k4, v4, bt[0], jnp.int32(13), scale=scale,
         k_scale=ks, v_scale=vs,
     )
-    got = paged_prefill_attention(
-        qp, k4, v4, bt[0], jnp.int32(13), scale=scale, interpret=True,
+    got = ragged_paged_attention(
+        qp[None], k4, v4, bt[:1], jnp.asarray([13], jnp.int32),
+        jnp.asarray([C], jnp.int32), scale=scale, interpret=True,
         k_scale=ks, v_scale=vs,
-    )
+    )[0]
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
     )

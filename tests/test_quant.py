@@ -374,7 +374,7 @@ def test_paged_cache_int4_layout_and_capacity():
     c4 = PagedKVCache.init(cfg, 2, page_size=8, max_len=32,
                            kv_quant="int4")
     assert c4.k.shape[-1] == cfg.head_dim // 2 and c4.k.dtype == jnp.int8
-    assert b8 / b4 >= 1.8, (b8, b4)  # the bench's slots-ratio bar
+    assert b8 / b4 >= 1.8, (b8, b4)
     # odd head_dim cannot pack: loud, never a silent mis-layout
     with pytest.raises(ValueError, match="even"):
         odd = ModelConfig(

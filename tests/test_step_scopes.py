@@ -226,10 +226,46 @@ def test_kernel_names_are_the_entry_points():
 
     src = inspect.getsource(attention)
     named = re.findall(r'pl\.pallas_call\(\s*kernel,\s*name=("?\w+"?)', src)
-    assert named == ['"flash_attention"', '"paged_prefill_attention"',
-                     "name"]
+    assert named == ['"flash_attention"', "name"]
     assert src.count("pl.pallas_call(") == len(named)
     # the live-span walk is one pallas_call behind two entry points, and
     # each hands it its own name as a literal
     assert re.findall(r'_paged_walk\(\s*"(\w+)"', src) == [
         "ragged_paged_attention", "paged_attention"]
+
+
+def test_every_kernel_entry_point_has_a_caller_in_the_package():
+    """A public function of ``ops/attention.py`` that reaches a
+    ``pl.pallas_call`` is imported by the package outside ``ops/``: a
+    kernel whose caller is retired goes with it."""
+    import ast
+    from pathlib import Path
+
+    from tensorlink_tpu.ops import attention
+
+    src = Path(attention.__file__)
+    funcs = {n.name: n for n in ast.parse(src.read_text()).body
+             if isinstance(n, ast.FunctionDef)}
+    calls = {
+        name: {c.func.id for c in ast.walk(fn)
+               if isinstance(c, ast.Call) and isinstance(c.func, ast.Name)}
+        for name, fn in funcs.items()
+    }
+    reaches = {n for n, fn in funcs.items()
+               if "pl.pallas_call(" in ast.unparse(fn)}
+    while more := {n for n, cs in calls.items() if cs & reaches} - reaches:
+        reaches |= more  # callers of what reaches a kernel reach it too
+    entry_points = {n for n in reaches if not n.startswith("_")}
+    # found through the helper that holds the walk's ``pallas_call``
+    assert {"ragged_paged_attention", "paged_attention"} <= entry_points
+
+    pkg = src.parents[1]
+    imported = {
+        alias.name
+        for path in pkg.rglob("*.py") if pkg / "ops" not in path.parents
+        for node in ast.walk(ast.parse(path.read_text()))
+        if isinstance(node, ast.ImportFrom)
+        and (node.module or "").endswith("ops.attention")
+        for alias in node.names
+    }
+    assert entry_points <= imported, entry_points - imported

@@ -852,7 +852,6 @@ def test_tree_is_clean_and_baseline_fresh():
             REPO_ROOT / "tensorlink_tpu",
             REPO_ROOT / "tests",
             REPO_ROOT / "tools",
-            REPO_ROOT / "bench.py",
         ],
         baseline_path=DEFAULT_BASELINE,
     )
@@ -1010,7 +1009,7 @@ def test_meta_rules_with_deliberate_catches_are_baselined():
 def test_meta_tl103_tree_is_disciplined_and_the_near_miss_fires():
     """TL103's sweep of the pre-PR tree found ZERO live violations: all
     26 resolved donor call sites (paged/generate/training donors, across
-    engine, tests, bench, soak) rebind the donated name in the same
+    engine, tests, soak) rebind the donated name in the same
     statement, so there was nothing to fix or baseline — the donation
     discipline genuinely held. What the rule buys is enforcement: this
     pins it against the near-miss every one of those sites individually
@@ -1046,7 +1045,6 @@ def test_meta_tl103_tree_is_disciplined_and_the_near_miss_fires():
             REPO_ROOT / "tensorlink_tpu",
             REPO_ROOT / "tests",
             REPO_ROOT / "tools",
-            REPO_ROOT / "bench.py",
         ],
         baseline_path=None,
         rules={"TL103": RULES["TL103"]},

@@ -166,7 +166,6 @@ def test_host_gap_span_recorded(tiny):
     recs = ce.recorder.records()
     assert recs and recs[-1]["host_ms"] == pytest.approx(
         recs[-1]["admit_ms"] + recs[-1]["pack_ms"])
-    assert recs[-1]["host_ms"] == pytest.approx(ce._host_gap_ms)
     snap = ce.serving_snapshot()
     host_us = snap["chunk_us_admit"] + snap["chunk_us_pack"]
     assert host_us == round(sum(r["host_ms"] for r in recs) * 1e3) > 0

@@ -330,7 +330,6 @@ def test_chunk_record_holds_every_phase_and_they_add_up(tiny_engine):
         parts = sum(a[f"{p}_ms"] for p in PHASES) + b["between_ms"]
         assert parts == pytest.approx(wall_ms, abs=1.0)
         assert b["between_ms"] > 0.0
-    assert recs[-1]["host_ms"] == ce._host_gap_ms
     ce.close()
 
 

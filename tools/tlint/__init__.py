@@ -32,7 +32,7 @@ JAX trace rules (TL1xx):
 - TL106 adhoc-counters: ``self.stats`` dict counters belong in the
   core.metrics registry.
 
-Run: ``python -m tools.tlint tensorlink_tpu tests tools bench.py``
+Run: ``python -m tools.tlint tensorlink_tpu tests tools``
 (blocking in CI; ``--format github`` for inline PR annotations).
 """
 

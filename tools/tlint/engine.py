@@ -299,7 +299,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument(
         "paths",
         nargs="*",
-        default=["tensorlink_tpu", "tests", "tools", "bench.py"],
+        default=["tensorlink_tpu", "tests", "tools"],
     )
     ap.add_argument(
         "--baseline",
