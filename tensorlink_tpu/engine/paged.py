@@ -20,8 +20,9 @@ cross-slot contamination.
 
 Attention routes through ops/attention.py: the Pallas
 :func:`~tensorlink_tpu.ops.attention.ragged_paged_attention` kernel on
-TPU (whole mixed prefill+decode block, KV gathered page-by-page via a
-scalar-prefetched block table) with
+TPU (whole mixed prefill+decode block, KV copied page-by-page out of the
+layer-stacked pool via a scalar-prefetched block table and layer index)
+with
 :func:`~tensorlink_tpu.ops.attention.ragged_paged_attention_ref` on CPU
 and in parity tests; the decode continuation inside the step runs the
 :func:`~tensorlink_tpu.ops.attention.paged_attention` kernel per token.
@@ -89,9 +90,12 @@ class PagedKVCache:
     """Paged decode cache: ``k``/``v`` are ``[L, P, n_kv, page, hd]``,
     ``block_tables`` maps each serving slot to its pages ``[S, n_pp]``
     (0 = the reserved scratch page), ``lengths`` counts valid positions
-    per slot ``[S]``. Stacked over layers like the dense cache so the
-    decode ``lax.scan`` indexes its layer slice; donated into the step so
-    XLA updates pages in place.
+    per slot ``[S]``. Stacked over layers like the dense cache, and
+    never taken apart: the step's layer loops CARRY the stacks
+    (:func:`_scan_layers`), rows are written at ``(layer, page, head,
+    offset)`` and the attention kernel is handed the stack and a layer
+    index, so no step slices a layer's pool out, stacks one back or
+    copies a pool. Donated into the step, so XLA updates pages in place.
 
     **int8 mode** (``quantized=True``): ``k``/``v`` hold int8 and
     ``k_scale``/``v_scale`` ``[L, P, n_kv, page]`` carry the
@@ -906,7 +910,8 @@ def _ragged_write_indices(block_tables, starts, n_valid, page, n_pp, C):
     token is just the ``C = 1`` / ``n_valid = 1`` case (the clamp is
     belt-and-braces; the host evicts a slot before it reaches capacity).
     Also returns the uncapped absolute positions (the rope offsets) and
-    the validity mask."""
+    the validity mask. :func:`_page_write_plan` names the same targets
+    page by page, for the blocks whose payload is written that way."""
     idx = jnp.arange(C)[None, :]
     pos = starts[:, None] + idx  # [S, C]
     valid = idx < n_valid[:, None]
@@ -917,18 +922,64 @@ def _ragged_write_indices(block_tables, starts, n_valid, page, n_pp, C):
     return write_pg, write_off, pos, valid
 
 
+def _page_write_plan(block_tables, starts, n_valid, page, n_pp, C):
+    """The same writes as :func:`_ragged_write_indices` names row by row,
+    as WHOLE PAGES: a slot's block of ``C`` consecutive positions lies in
+    at most ``n_pg = ceil((C + page - 1) / page)`` consecutive pages of
+    its table, from page ``starts // page`` on. Returns ``(target [S,
+    n_pg], shift [S], written [S, n_pg, 1, page, 1])``: the physical page
+    each of those holds (the scratch page where no valid position falls
+    into it), the offset of the block's first position in the first
+    page, and which positions of the ``n_pg`` pages the block writes (a
+    mask over ``[S, n_pg, Hkv, page, hd]``)."""
+    n_pg = -(-(C + page - 1) // page)
+    shift = starts % page
+    r = jnp.arange(n_pg * page)[None, :]
+    lp = (starts // page)[:, None] + jnp.arange(n_pg)[None, :]  # [S, n_pg]
+    rows = (
+        (r >= shift[:, None]) & (r < (shift + n_valid)[:, None])
+        & jnp.repeat(lp < n_pp, page, axis=1)
+    )
+    pg = jnp.take_along_axis(
+        block_tables, jnp.minimum(lp, n_pp - 1), axis=1
+    )
+    written = rows.reshape(-1, n_pg, page)
+    target = jnp.where(written.any(-1), pg, 0)
+    return target, shift, written[:, :, None, :, None]
+
+
+def _merge_pages(pool, layer, plan, rows):
+    """``pool`` ``[L, P, Hkv, page, hd]`` with the block's ``rows`` ``[S,
+    C, Hkv, hd]`` written into layer ``layer`` page by page: each page
+    the block touches is read, its written positions replaced, and put
+    back whole — ``S·n_pg`` contiguous updates where a row scatter makes
+    ``S·C·Hkv``."""
+    target, shift, written = plan
+    S, C, Hkv, hd = rows.shape
+    n_pg, page = target.shape[1], pool.shape[3]
+    R = n_pg * page
+    # row j of the block to position shift + j of the page run
+    padded = jnp.pad(rows, ((0, 0), (page - 1, R - C), (0, 0), (0, 0)))
+    run = jax.vmap(
+        lambda x, o: jax.lax.dynamic_slice_in_dim(x, page - 1 - o, R, 0)
+    )(padded, shift)
+    new = run.reshape(S, n_pg, page, Hkv, hd).transpose(0, 1, 3, 2, 4)
+    old = pool[layer, target]  # [S, n_pg, Hkv, page, hd]
+    return pool.at[layer, target].set(jnp.where(written, new, old))
+
+
 def _cache_kv(cache: PagedKVCache) -> tuple:
-    """One layer-stacked KV tuple for the decode scan — ``(k, v)`` plain,
-    ``(k, v, k_scale, v_scale)`` in int8 mode; the blocks branch on the
-    tuple arity (a trace-time constant)."""
+    """The layer-stacked KV tuple the layer loops carry — ``(k, v)``
+    plain, ``(k, v, k_scale, v_scale)`` in int8 mode; the blocks branch
+    on the tuple arity (a trace-time constant)."""
     if cache.k_scale is None:
         return (cache.k, cache.v)
     return (cache.k, cache.v, cache.k_scale, cache.v_scale)
 
 
 def _with_kv(cache: PagedKVCache, kv: tuple, **kw) -> PagedKVCache:
-    """Rebuild the cache from a scan's stacked KV output (inverse of
-    :func:`_cache_kv`)."""
+    """Rebuild the cache from the KV tuple a layer loop carried (inverse
+    of :func:`_cache_kv`)."""
     if len(kv) == 4:
         return replace(
             cache, k=kv[0], v=kv[1], k_scale=kv[2], v_scale=kv[3], **kw
@@ -937,42 +988,96 @@ def _with_kv(cache: PagedKVCache, kv: tuple, **kw) -> PagedKVCache:
 
 
 # tlint: hot-path
-def _scatter_kv(cache_kv: tuple, write_pg, write_off, k, v) -> tuple:
+def _scatter_kv(cache_kv: tuple, layer, write_pg, write_off, k, v,
+                plan=None) -> tuple:
     """THE one page-write path's scatter: land this block's KV rows at
-    their ``(page, offset)`` targets across every program. In quantized
+    their ``(layer, page, offset)`` targets in the layer-stacked pools
+    ``[L, P, Hkv, page, hd]``, across every program. In quantized
     mode this is the single quantize site — each position's row quantizes
     independently (per-(position, head) scale over ``head_dim``,
     models/quant.py::quantize_kv — or ``quantize_kv4`` when the pages are
     PACKED int4, detected by the page dim being half the row's), which is
     exactly what keeps chunk framing, COW and promotion byte-exact under
     quantization. ``k``/``v`` are ``[..., Hkv, hd]`` with leading dims
-    matching ``write_pg``."""
-    # every kv head is named in the index, so each scattered update is one
-    # contiguous ``[hd]`` row of the kernel's kv-head-major page layout.
-    # Slicing the head axis instead (``.at[pg, :, off]``) makes the TPU
-    # compiler re-lay the whole page pool position-major for the scatter
-    # and back for the Pallas call — a second copy of the pool per step.
+    matching ``write_pg``.
+
+    The pools are the ones the layer loop carries, written in place: no
+    layer's pool is cut out of the stack or put back. A block of single
+    positions (the decode step: ``S·Hkv`` rows) is a row scatter. A block
+    of ``C`` positions a slot comes with its ``plan``
+    (:func:`_page_write_plan`) and is written page by page
+    (:func:`_merge_pages`): the ``S·C·Hkv`` = 8,192 row updates of 128
+    bytes into HBM took 40 ms of a 91-ms ragged pass on a v5e; as the 72
+    pages they lie in, the pass takes 52 ms (PERF.md section 6, PR 29)."""
+    # the layer and every kv head are named in the index, so each
+    # scattered update is one contiguous ``[hd]`` row of the kernel's
+    # kv-head-major page layout. Slicing the head axis instead
+    # (``.at[pg, :, off]``) makes the TPU compiler re-lay the whole page
+    # pool position-major for the scatter and back for the Pallas call —
+    # a second copy of the pool per step.
     idx = (
-        write_pg[..., None], jnp.arange(cache_kv[0].shape[1]),
+        write_pg[..., None], jnp.arange(cache_kv[0].shape[2]),
         write_off[..., None],
     )
+
+    def payload(pool, rows):
+        if plan is None:
+            return pool.at[(layer,) + idx].set(rows)
+        return _merge_pages(pool, layer, plan, rows)
+
     if len(cache_kv) == 4:
         ck, cv, cks, cvs = cache_kv
         quant = _quant_kv4 if ck.shape[-1] != k.shape[-1] else _quant_kv
         k8, ks = quant(k)
         v8, vs = quant(v)
+
+        # a block's scales go through the layer's own plane (1 MB where
+        # a pool is 34): scattered straight into the stacked planes, the
+        # TPU compiler moved all L layers of them to fast memory and back
+        # a layer-call of the ragged pass (cross-compile, PERF.md, PR 29)
+        def planes(pool, s):
+            if plan is None:
+                return pool.at[(layer,) + idx].set(s)
+            plane = jax.lax.dynamic_index_in_dim(pool, layer, 0, False)
+            return jax.lax.dynamic_update_index_in_dim(
+                pool, plane.at[idx].set(s), layer, 0
+            )
+
         return (
-            ck.at[idx].set(k8), cv.at[idx].set(v8),
-            cks.at[idx].set(ks), cvs.at[idx].set(vs),
+            payload(ck, k8), payload(cv, v8), planes(cks, ks), planes(cvs, vs)
         )
     ck, cv = cache_kv
-    return (
-        ck.at[idx].set(k.astype(ck.dtype)), cv.at[idx].set(v.astype(cv.dtype))
+    return payload(ck, k.astype(ck.dtype)), payload(cv, v.astype(cv.dtype))
+
+
+def _paged_attend(kernel_fn, ref_fn, kernel: bool, q, kv: tuple, layer,
+                  *ctl, scale: float):
+    """Attention of ``q`` over layer ``layer`` of the carried pools
+    ``kv``, through ``kernel_fn`` or the reference ``ref_fn`` (``ctl``:
+    the block tables and what places the queries). The kernel takes the
+    stack and the layer's index and copies the live pages out of it
+    itself; the reference gets the layer's pools indexed out, as does
+    the kernel for plain pages stored in another dtype than the queries'
+    (the cast would otherwise be of every layer)."""
+    scales = {}
+    if len(kv) == 4:
+        scales = {"k_scale": kv[2], "v_scale": kv[3]}
+    if kernel and (scales or kv[0].dtype == q.dtype):
+        return kernel_fn(
+            q, kv[0], kv[1], *ctl, scale=scale, layer=layer, **scales
+        )
+    k, v = kv[0][layer], kv[1][layer]
+    if scales:
+        scales = {n: a[layer] for n, a in scales.items()}
+    else:
+        k, v = k.astype(q.dtype), v.astype(q.dtype)
+    return (kernel_fn if kernel else ref_fn)(
+        q, k, v, *ctl, scale=scale, **scales
     )
 
 
-def _paged_block(x, lp, cfg: ModelConfig, cos, sin, cache_kv, write_pg,
-                 write_off, att_len, block_tables, kernel: bool,
+def _paged_block(x, lp, layer, cfg: ModelConfig, cos, sin, cache_kv,
+                 write_pg, write_off, att_len, block_tables, kernel: bool,
                  tp_axis: str | None = None, tp_quant: bool = False):
     """One transformer block over a slot batch of single tokens (T=1),
     reading/writing KV through pages. Mirrors transformer.py::_block's
@@ -985,22 +1090,38 @@ def _paged_block(x, lp, cfg: ModelConfig, cos, sin, cache_kv, write_pg,
         q, k, v = _paged_qkv(h, lp, cfg, cos, sin)  # [S, 1, H, hd]
 
     # per-slot scatter of the new token's KV through THE one write path
-    # (quantizes in int8 mode); cache_kv is this layer's pages
+    # (quantizes in int8 mode); cache_kv is every layer's pages, written
+    # and read at ``layer``
     with jax.named_scope("kv_write"):
-        kv = _scatter_kv(cache_kv, write_pg, write_off, k[:, 0], v[:, 0])
-    attn = paged_attention if kernel else paged_attention_ref
+        kv = _scatter_kv(
+            cache_kv, layer, write_pg, write_off, k[:, 0], v[:, 0]
+        )
     with jax.named_scope("attn"):
-        if len(kv) == 4:
-            attn_raw = attn(
-                q[:, 0], kv[0], kv[1], block_tables, att_len,
-                scale=_attn_scale(cfg), k_scale=kv[2], v_scale=kv[3],
-            )[:, None]
-        else:
-            attn_raw = attn(
-                q[:, 0], kv[0].astype(q.dtype), kv[1].astype(q.dtype),
-                block_tables, att_len, scale=_attn_scale(cfg),
-            )[:, None]  # [S, 1, Hq, hd]
+        attn_raw = _paged_attend(
+            paged_attention, paged_attention_ref, kernel, q[:, 0], kv,
+            layer, block_tables, att_len, scale=_attn_scale(cfg),
+        )[:, None]  # [S, 1, Hq, hd]
     return _paged_residual(x, attn_raw, lp, cfg, tp_axis, tp_quant), kv
+
+
+def _scan_layers(params, x, cache: PagedKVCache, block):
+    """The layer loop of both passes: ``block(x, lp, layer, kv) -> (x,
+    kv)`` over the stacked layer parameters, the page pools CARRIED whole
+    beside the activations. The pools are not the scan's ``xs``/``ys``: a
+    layer's pool is never sliced out of the stack, the updated one never
+    stacked back, and the loop's result is the buffer it was given, so an
+    enclosing loop (the decode continuation) carries it without a copy.
+    Returns ``(x, kv)``."""
+    def scan_fn(carry, xs):
+        lp, layer = xs
+        return block(carry[0], lp, layer, carry[1]), None
+
+    n_layers = cache.k.shape[0]
+    (x, kv), _ = jax.lax.scan(
+        scan_fn, (x, _cache_kv(cache)),
+        (params["layers"], jnp.arange(n_layers)),
+    )
+    return x, kv
 
 
 # tlint: hot-path
@@ -1040,16 +1161,12 @@ def _decode_step_impl(
     if cfg.pos == "rope":
         cos, sin = rope_tables(positions, _rope_dim(cfg), cfg.rope_theta)
 
-    def scan_fn(carry, xs):
-        lp, ckv = xs[0], xs[1:]
-        y, ckv = _paged_block(
-            carry, lp, cfg, cos, sin, ckv, write_pg, write_off,
+    x, kv_new = _scan_layers(
+        params, x, cache,
+        lambda x, lp, layer, kv: _paged_block(
+            x, lp, layer, cfg, cos, sin, kv, write_pg, write_off,
             att_len, cache.block_tables, kernel, tp_axis, tp_quant,
-        )
-        return y, ckv
-
-    x, kv_new = jax.lax.scan(
-        scan_fn, x, (params["layers"], *_cache_kv(cache))
+        ),
     )
     x = _norm(x, params["final_norm"], cfg)
     logits = _logits(params, x, cfg, tp_axis, tp_quant)[:, 0]
@@ -1220,9 +1337,10 @@ def _verify_emit(blk, logits_v, base, n_spec, emit, seeds, steps, temp,
     return toks, last, m, ended, counts, steps, remaining
 
 
-def _ragged_block(x, lp, cfg: ModelConfig, cos, sin, cache_kv, write_pg,
-                  write_off, block_tables, starts, n_valid, kernel: bool,
-                  tp_axis: str | None = None, tp_quant: bool = False):
+def _ragged_block(x, lp, layer, cfg: ModelConfig, cos, sin, cache_kv, plan,
+                  write_pg, write_off, block_tables, starts, n_valid,
+                  kernel: bool, tp_axis: str | None = None,
+                  tp_quant: bool = False):
     """One transformer block over the ragged ``[S, C]`` token block,
     reading/writing KV through every slot's pages at once. Shares
     ``_paged_block``'s prologue/epilogue (scatter-then-attend order
@@ -1239,19 +1357,13 @@ def _ragged_block(x, lp, cfg: ModelConfig, cos, sin, cache_kv, write_pg,
     # rows and idle slots land on scratch page 0, unreachable from any
     # block table
     with jax.named_scope("kv_write"):
-        kv = _scatter_kv(cache_kv, write_pg, write_off, k, v)
-    attn = ragged_paged_attention if kernel else ragged_paged_attention_ref
+        kv = _scatter_kv(cache_kv, layer, write_pg, write_off, k, v, plan)
     with jax.named_scope("attn"):
-        if len(kv) == 4:
-            attn_raw = attn(
-                q, kv[0], kv[1], block_tables, starts, n_valid,
-                scale=_attn_scale(cfg), k_scale=kv[2], v_scale=kv[3],
-            )
-        else:
-            attn_raw = attn(
-                q, kv[0].astype(q.dtype), kv[1].astype(q.dtype),
-                block_tables, starts, n_valid, scale=_attn_scale(cfg),
-            )  # [S, C, Hq, hd]
+        attn_raw = _paged_attend(
+            ragged_paged_attention, ragged_paged_attention_ref, kernel, q,
+            kv, layer, block_tables, starts, n_valid,
+            scale=_attn_scale(cfg),
+        )  # [S, C, Hq, hd]
     return _paged_residual(x, attn_raw, lp, cfg, tp_axis, tp_quant), kv
 
 
@@ -1273,6 +1385,7 @@ def _ragged_pass(
         write_pg, write_off, pos, _valid = _ragged_write_indices(
             bt, starts, n_valid, page, n_pp, C
         )
+        plan = _page_write_plan(bt, starts, n_valid, page, n_pp, C)
 
         x = _embed_tokens(params, blk, cfg)  # [S, C, d]
         positions = pos
@@ -1282,16 +1395,12 @@ def _ragged_pass(
         if cfg.pos == "rope":
             cos, sin = rope_tables(positions, _rope_dim(cfg), cfg.rope_theta)
 
-        def scan_fn(carry, xs):
-            lp, ckv = xs[0], xs[1:]
-            y, ckv = _ragged_block(
-                carry, lp, cfg, cos, sin, ckv, write_pg, write_off,
+        x, kv_new = _scan_layers(
+            params, x, cache,
+            lambda x, lp, layer, kv: _ragged_block(
+                x, lp, layer, cfg, cos, sin, kv, plan, write_pg, write_off,
                 bt, starts, n_valid, kernel, tp_axis, tp_quant,
-            )
-            return y, ckv
-
-        x, kv_new = jax.lax.scan(
-            scan_fn, x, (params["layers"], *_cache_kv(cache))
+            ),
         )
         x = _norm(x, params["final_norm"], cfg)
     # verification rows: the last spec_width rows of each slot's valid

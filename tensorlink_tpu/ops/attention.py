@@ -510,8 +510,9 @@ def _lane_pad(x: jax.Array) -> jax.Array:
     """``x`` with its minor dim padded to whole lane rows: a manual copy
     cannot slice an HBM operand whose minor dim is not a multiple of 128
     (Mosaic refuses the slice). int8 and bf16 pages of 128-wide heads
-    pass through untouched; packed int4 pages and pages of a head_dim
-    under 128 pay a padded copy of the layer's pool a call, on top of the
+    pass through untouched (and, stacked over the layers, are handed to
+    the kernel whole); packed int4 pages and pages of a head_dim under
+    128 pay a padded copy of the layer's pool a call, on top of the
     relayout XLA makes of such a pool for any kernel (it stores a minor
     dim under a lane row page-minor). That cost follows the pool's
     capacity, not the live span: ~0.4 ms of a 0.6 ms call at int4 and
@@ -521,6 +522,13 @@ def _lane_pad(x: jax.Array) -> jax.Array:
     if not short:
         return x
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, short)])
+
+
+def _one_layer(pool: jax.Array, layer: jax.Array) -> jax.Array:
+    """Layer ``layer`` of a stacked pool ``[L, P, ...]``, cut out as
+    ``[P, ...]``: a copy of one layer's capacity, for the operands the
+    kernel cannot address inside the stack (see :func:`_paged_walk`)."""
+    return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
 
 
 def _scale_rows(scales: jax.Array, hb: int) -> jax.Array:
@@ -557,8 +565,9 @@ def _paged_walk_kernel(
     bt_ref,  # scalar-prefetch: block tables [S, n_pp]
     start_ref,  # scalar-prefetch: absolute position of each slot's row 0
     nv_ref,  # scalar-prefetch: valid query positions per slot
+    layer_ref,  # scalar-prefetch [1]: the layer of the stack to walk
     q_ref,  # [1, hb, C·G, hd] (VMEM): this step's block of kv heads
-    k_hbm,  # [P, Hkv, page, hdk] — the whole pool, left in HBM
+    k_hbm,  # [L, P, Hkv, page, hdk] — every layer's pool, left in HBM
     v_hbm,
     *rest,  # quantized: ks_hbm, vs_hbm [P, Hkv/hb, 1, lanes]; out + scratch
     scale: float,
@@ -585,7 +594,7 @@ def _paged_walk_kernel(
     Hkv, CG, hd = q_ref.shape[1:]  # Hkv: the heads of this block
     # the block's heads of a page: all of it where one block holds them
     # all, and contiguous in the pool either way
-    heads = () if Hkv == k_hbm.shape[1] else (pl.ds(hblk * Hkv, Hkv),)
+    heads = () if Hkv == k_hbm.shape[2] else (pl.ds(hblk * Hkv, Hkv),)
     hdk = hd // 2 if packed else hd  # bytes of a packed int4 row
     R = cb * G  # query rows a row block holds
     T = ppb * page  # key positions a KV block holds
@@ -597,11 +606,12 @@ def _paged_walk_kernel(
         # page, hdk] is contiguous in the pool. A wait needs the copy's
         # shape only, so it names page 0 and never reads the block table
         pg = (0 if wait else bt_ref[s, kb * ppb + p],)
+        at = (0 if wait else layer_ref[0],) + pg + heads
         copies = [
             pltpu.make_async_copy(
-                k_hbm.at[pg + heads], kbuf.at[buf, :, p], sem.at[buf, 0]),
+                k_hbm.at[at], kbuf.at[buf, :, p], sem.at[buf, 0]),
             pltpu.make_async_copy(
-                v_hbm.at[pg + heads], vbuf.at[buf, :, p], sem.at[buf, 1]),
+                v_hbm.at[at], vbuf.at[buf, :, p], sem.at[buf, 1]),
         ]
         if quantized:
             copies += [
@@ -728,13 +738,14 @@ def _paged_walk_kernel(
 def _paged_walk(
     name: str,
     qg: jax.Array,  # [S, Hkv, C·G, hd] — kv-head-major query rows
-    k_pages: jax.Array,  # [P, Hkv, page, hdk]
+    k_pages: jax.Array,  # [L, P, Hkv, page, hdk], or one layer's [P, ...]
     v_pages: jax.Array,
     block_tables: jax.Array,  # int32 [S, n_pp]
     starts: jax.Array,  # int32 [S]
     n_valid: jax.Array,  # int32 [S]
-    k_scale: jax.Array | None,
+    k_scale: jax.Array | None,  # [L, P, Hkv, page], or [P, ...]
     v_scale: jax.Array | None,
+    layer: jax.Array | None,  # int32 scalar: the layer of a stack to walk
     *,
     G: int,
     scale: float,
@@ -744,9 +755,28 @@ def _paged_walk(
     ``[S, Hkv, C·G, hd]``. Block sizes come from the shapes: KV blocks of
     :func:`_pages_per_block` pages, row blocks of
     :func:`_positions_per_row_block` chunk positions, grid steps of
-    :func:`_heads_per_block` kv heads."""
+    :func:`_heads_per_block` kv heads.
+
+    The pools are addressed, not loaded: the kernel copies page
+    ``(layer, page)`` out of the stack ``[L, P, ...]`` itself, so a layer
+    loop that carries the stack hands it over whole and nothing cuts a
+    layer's pool out of it. One layer's ``[P, ...]`` pool is the stack of
+    one (a free reshape). Two operands are still cut out a layer-call,
+    each a copy of ONE layer's capacity: a pool :func:`_lane_pad` has to
+    pad (padding the stack would cost ``L`` layers a call), and the scale
+    planes, which :func:`_scale_rows` re-lays."""
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    else:
+        if k_scale is not None:
+            k_scale = _one_layer(k_scale, layer)
+            v_scale = _one_layer(v_scale, layer)
+        if k_pages.shape[-1] % _MIN_TILE:
+            k_pages = _one_layer(k_pages, layer)[None]
+            v_pages = _one_layer(v_pages, layer)[None]
+            layer = 0
     S, Hkv, CG, hd = qg.shape
-    _, _, page, hdk = k_pages.shape  # hdk = hd // 2 for packed int4
+    page, hdk = k_pages.shape[3:]  # hdk = hd // 2 for packed int4
     n_pp = block_tables.shape[1]
     ppb = _pages_per_block(page, n_pp)
     cb = _positions_per_row_block(CG // G, G)
@@ -766,7 +796,7 @@ def _paged_walk(
     # block lands at [:, p]: the same (page, hdk) trailing tile as the
     # pool, and a leading-dim merge away from [Hkv, T, hd]
     scratch = [
-        pltpu.VMEM((2, hb, ppb) + a.shape[2:], a.dtype) for a in args[1:]
+        pltpu.VMEM((2, hb, ppb) + a.shape[3:], a.dtype) for a in args[1:]
     ]
     if quantized:
         args += [_scale_rows(k_scale, hb), _scale_rows(v_scale, hb)]
@@ -783,7 +813,7 @@ def _paged_walk(
         kernel,
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(S, Hkv // hb),
             in_specs=[q_spec] + [in_hbm] * (len(args) - 1),
             out_specs=q_spec,
@@ -798,6 +828,7 @@ def _paged_walk(
         block_tables,
         jnp.asarray(starts, jnp.int32),
         jnp.asarray(n_valid, jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
         *args,
     )
 
@@ -816,8 +847,15 @@ def ragged_paged_attention(
     interpret: bool = False,
     k_scale: jax.Array | None = None,  # f32 [P, Hkv, page] — int8 pages
     v_scale: jax.Array | None = None,
+    layer: jax.Array | None = None,  # int32 scalar — see below
 ) -> jax.Array:
     """Ragged paged attention (TPU); returns ``[S, C, Hq, hd]``.
+
+    With ``layer``, the pools (and scale planes) are every layer's,
+    stacked ``[L, P, ...]`` as the engine's ``PagedKVCache`` holds them,
+    and the walk reads layer ``layer`` of the stack in place
+    (:func:`_paged_walk`); the result is bitwise that of the same call on
+    that layer's ``[P, ...]`` slice.
 
     The live-span walk (:func:`_paged_walk_kernel`) over the whole-chunk
     query block: grid ``(slot, kv-head block)``, block tables, per-slot
@@ -834,7 +872,7 @@ def ragged_paged_attention(
     current start) ride the same causal ``q_pos`` masking — see the
     reference's "Verify mode" note."""
     S, C, Hq, hd = q.shape
-    Hkv = k_pages.shape[1]
+    Hkv = k_pages.shape[-3]
     G = Hq // Hkv
     # [S, C, Hq, hd] -> [S, Hkv, C·G, hd]: kv-head-major, so the rows of
     # one kv head are one matmul operand against that head's pages
@@ -845,7 +883,7 @@ def ragged_paged_attention(
     )
     out = _paged_walk(
         "ragged_paged_attention", qg, k_pages, v_pages, block_tables,
-        starts, n_valid, k_scale, v_scale, G=G, scale=scale,
+        starts, n_valid, k_scale, v_scale, layer, G=G, scale=scale,
         interpret=interpret,
     )
     return (
@@ -867,6 +905,7 @@ def paged_attention(
     interpret: bool = False,
     k_scale: jax.Array | None = None,  # f32 [P, Hkv, page] — int8 pages
     v_scale: jax.Array | None = None,
+    layer: jax.Array | None = None,  # int32 scalar: pools are [L, P, ...]
 ) -> jax.Array:
     """Paged decode attention; returns ``[S, Hq, hd]``.
 
@@ -880,13 +919,14 @@ def paged_attention(
     compiled program serves every (length mix, page assignment) — the
     block table and lengths are data, not shape."""
     S, Hq, hd = q.shape
-    Hkv = k_pages.shape[1]
+    Hkv = k_pages.shape[-3]
     lengths = jnp.asarray(lengths, jnp.int32)
     out = _paged_walk(
         "paged_attention", q.reshape(S, Hkv, Hq // Hkv, hd), k_pages,
         v_pages, block_tables, jnp.maximum(lengths - 1, 0),
         jnp.minimum(lengths, 1),
-        k_scale, v_scale, G=Hq // Hkv, scale=scale, interpret=interpret,
+        k_scale, v_scale, layer, G=Hq // Hkv, scale=scale,
+        interpret=interpret,
     )
     return out.reshape(S, Hq, hd)
 

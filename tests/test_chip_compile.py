@@ -30,6 +30,9 @@ S, PAGE, N_PP, HD = 8, 16, 256, 128
 P = 1 + S * N_PP
 SCALE = HD**-0.5
 MODES = ("bf16", "int8", "int4")
+# the pools a kernel is handed: one layer's [P, ...], or every layer's
+# [L, P, ...] with a layer index, as the step's layer loop carries them
+pools = pytest.mark.parametrize("layers", [None, 36], ids=["layer", "stack"])
 
 
 @pytest.fixture(scope="module")
@@ -54,16 +57,22 @@ def v5e(v5e_chips):
     return SingleDeviceSharding(v5e_chips[0])
 
 
-def _pages(dev, mode: str, hkv: int, hd: int = HD):
-    """(k/v page spec, {k_scale, v_scale} specs) for a page storage mode."""
-    def sds(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=dev)
+def _pages(dev, mode: str, hkv: int, hd: int = HD, layers: int | None = None):
+    """(k/v page spec, {k_scale, v_scale[, layer]} specs) for a page
+    storage mode; with ``layers`` the pools are stacked and a traced
+    layer index goes with them."""
+    stack = () if layers is None else (layers,)
 
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(stack + shape, dt, sharding=dev)
+
+    kw = {} if layers is None else {"layer": _i32(dev)}
     if mode == "bf16":
-        return sds((P, hkv, PAGE, hd), jnp.bfloat16), {}
+        return sds((P, hkv, PAGE, hd), jnp.bfloat16), kw
     hdk = hd // 2 if mode == "int4" else hd  # int4: two values per byte
     sc = sds((P, hkv, PAGE), jnp.float32)
-    return sds((P, hkv, PAGE, hdk), jnp.int8), {"k_scale": sc, "v_scale": sc}
+    return sds((P, hkv, PAGE, hdk), jnp.int8), {
+        "k_scale": sc, "v_scale": sc, **kw}
 
 
 def _compiles_with_kernel(fn, *args, **kw) -> None:
@@ -83,29 +92,34 @@ def _q(dev, *shape):
 FULL, TP4_SHARD = (32, 8), (8, 2)
 
 
+@pools
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize(
     "heads,C",
     [(FULL, 128), (FULL, 8), (TP4_SHARD, 128)],
     ids=["full-C128", "full-C8", "tp4shard-C128"],
 )
-def test_ragged_kernel_compiles_for_v5e(v5e, mode, heads, C):
+def test_ragged_kernel_compiles_for_v5e(v5e, mode, heads, C, layers):
     """THE step program's kernel: the packed ``[slots, chunk]`` block
-    (chunk 128 as served; 8 = a decode/verify-only width)."""
+    (chunk 128 as served; 8 = a decode/verify-only width), on one layer's
+    pool and on the stack with a layer index (what Mosaic makes of a copy
+    out of ``.at[layer, page]`` shows here, not in interpret mode)."""
     hq, hkv = heads
-    kv, scales = _pages(v5e, mode, hkv)
+    kv, scales = _pages(v5e, mode, hkv, layers=layers)
     _compiles_with_kernel(
         A.ragged_paged_attention, _q(v5e, S, C, hq, HD), kv, kv,
         _i32(v5e, S, N_PP), _i32(v5e, S), _i32(v5e, S), **scales,
     )
 
 
+@pools
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("heads", [FULL, TP4_SHARD], ids=["full", "tp4shard"])
-def test_decode_kernel_compiles_for_v5e(v5e, mode, heads):
-    """The decode continuation's per-token kernel."""
+def test_decode_kernel_compiles_for_v5e(v5e, mode, heads, layers):
+    """The decode continuation's per-token kernel, on one layer's pool
+    and on the stack with a layer index."""
     hq, hkv = heads
-    kv, scales = _pages(v5e, mode, hkv)
+    kv, scales = _pages(v5e, mode, hkv, layers=layers)
     _compiles_with_kernel(
         A.paged_attention, _q(v5e, S, hq, HD), kv, kv,
         _i32(v5e, S, N_PP), _i32(v5e, S), **scales,
@@ -165,9 +179,14 @@ def test_flash_kernel_compiles_for_v5e(v5e):
 V5E_HBM = 16 * 1024**3  # one v5e chip
 
 
-def _step_operands(cfg, place, place_cache):
+def _nbytes(tree) -> int:
+    """Logical bytes of a tree of shapes."""
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def _step_operands(cfg, place, place_cache, kv_quant: str = "int8"):
     """Abstract operands of the ragged step at a default MLConfig worker's
-    shapes for ``cfg``: (operands, logical bytes of weights + page pool)."""
+    shapes for ``cfg`` (weights first, the cache third)."""
     from tensorlink_tpu.engine.paged import PagedKVCache
     from tensorlink_tpu.models.transformer import init_params
 
@@ -176,13 +195,9 @@ def _step_operands(cfg, place, place_cache):
     )
     cache = jax.eval_shape(
         lambda: PagedKVCache.init(
-            cfg, S, page_size=PAGE, max_len=N_PP * PAGE, kv_quant="int8"
+            cfg, S, page_size=PAGE, max_len=N_PP * PAGE, kv_quant=kv_quant
         )
     )
-    resident = sum(
-        x.size * x.dtype.itemsize for x in jax.tree.leaves((params, cache))
-    )
-
     def ctl(dt, *shape):
         return place(jax.ShapeDtypeStruct(shape, dt))
 
@@ -193,7 +208,82 @@ def _step_operands(cfg, place, place_cache):
         ctl(i32, S), ctl(f32, S), ctl(i32, S), ctl(f32, S), ctl(f32, S),
         ctl(f32, S), ctl(i32, S, cfg.vocab_size), ctl(i32, S),
         ctl(i32, S, 8),
-    ), resident
+    )
+
+
+def _on(sharding):
+    """Place every leaf of a tree of shapes under ``sharding``."""
+    return lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _assert_pools_stay_put(compiled, cache, params, shards: int = 1) -> None:
+    """The compiled step holds the kernel and moves no pool: no ``copy``
+    whose result has a page pool's shape (the parent's step copied both
+    pools once a continuation step, a layer's slice of each in and out a
+    layer-call), and temporaries under a quarter of the pool beside the
+    one thing the compiler does re-lay once an execution, outside the
+    loops: the q/k/v projection weights (at the parent as well; 1.13 GB
+    at full depth). ``shards``: devices the kv heads and those weights
+    are split over (``memory_analysis`` is one device's)."""
+    import re
+
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    L, n_pages, hkv, page, hdk = cache.k.shape
+    pool_shape = f"[{L},{n_pages},{hkv // shards},{page},{hdk}]"
+    copies = re.findall(
+        rf"^\s*\S+ = \w+{re.escape(pool_shape)}\S* copy\(.*$", text, re.M
+    )
+    assert not copies, copies[:2]
+    if cache.k_scale is not None:
+        # the stacked scale planes keep the layer as their major-most
+        # dimension wherever they appear: with the layer minor (seen in
+        # the tp decode loop when a block's scale write was used for
+        # single positions too) writing one layer's plane touches every
+        # tile of the stack, 32 us a call on the chip
+        shape = (L, n_pages, hkv // shards, page)
+        planes = "f32[" + ",".join(map(str, shape)) + "]"
+        layouts = set(re.findall(re.escape(planes) + r"\{([\d,]+)", text))
+        majors = {  # minor to major, dimensions of one left out
+            lay: [d for d in map(int, lay.split(",")) if shape[d] > 1][-1]
+            for lay in layouts
+        }
+        assert majors and set(majors.values()) == {0}, majors
+    qkv = _nbytes([params["layers"]["attn"][w] for w in ("wq", "wk", "wv")])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    pool = _nbytes((cache.k, cache.v, cache.k_scale, cache.v_scale))
+    assert temp < (pool // 4 + qkv) // shards, (temp, pool, qkv)
+
+
+@pytest.mark.parametrize(
+    "kv_quant,tp", [("int8", 1), ("none", 1), ("int8", 4)],
+    ids=["int8", "bf16", "int8-tp4"],
+)
+def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp):
+    """qwen3-4b's widths and page pool (8 slots x 4096, 2,049 pages a
+    layer) with the depth cut to twelve layers (a third: what the step
+    keeps beside the pools, ~35 MB of logits and control state a chip,
+    does not shrink with the depth): the layer loops carry the
+    pools and the walk takes a layer index, so neither loop of the
+    compiled step copies a pool, slices a layer's out or stacks one
+    back. int8 and bf16 pages on one chip; the tensor-parallel step over
+    the described 2x2, where a chip's pool holds two kv heads."""
+    import dataclasses
+
+    from tensorlink_tpu.engine.paged import paged_ragged_step
+    from tensorlink_tpu.models.registry import config_presets
+
+    cfg = dataclasses.replace(config_presets()["qwen3-4b"], n_layers=12)
+    if tp == 1:
+        place = _on(SingleDeviceSharding(v5e_chips[0]))
+        ops = _step_operands(cfg, place, place, kv_quant)
+        compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
+    else:
+        compiled, ops = _tp4_step_compiled(v5e_chips, cfg)
+    _assert_pools_stay_put(compiled, ops[2], ops[0], tp)
 
 
 @pytest.mark.slow
@@ -201,34 +291,27 @@ def test_ragged_step_fits_one_v5e_beside_the_weights(v5e):
     """qwen3-4b, all 36 layers, int8 pages, spec width 9: the step program
     compiles for one chip with the kernel in it, stores weights + pages at
     their logical size (the scale planes are not lane-padded in HBM), and
-    its temporaries stay under two page pools — the position-major scatter
-    it replaced made the compiler keep a re-laid copy of the whole pool
-    per layout (7.2 GB of temporaries, past the chip)."""
+    moves no pool: 1.17 GB of temporaries, of which 1.13 GB the re-laid
+    q/k/v projections (3.80 GB when the pools were the scan's ``xs`` and
+    ``ys``; 7.2 GB, past the chip, under the position-major scatter before
+    that)."""
     from tensorlink_tpu.engine.paged import paged_ragged_step
     from tensorlink_tpu.models.registry import config_presets
 
     cfg = config_presets()["qwen3-4b"]
-
-    def place(tree):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
-            tree,
-        )
-
-    ops, resident = _step_operands(cfg, place, place)
+    ops = _step_operands(cfg, _on(v5e), _on(v5e))
     compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    _assert_pools_stay_put(compiled, ops[2], ops[0])
     ma = compiled.memory_analysis()
-    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(ops[2]))
+    resident = _nbytes((ops[0], ops[2]))
     assert ma.argument_size_in_bytes <= 1.01 * resident + 8 * 2**20, ma
-    assert ma.temp_size_in_bytes < 2 * pool, (ma, pool)
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM, ma
 
 
 def _tp4_step_compiled(v5e_chips, cfg):
     """The tensor-parallel step for ``cfg`` compiled over the four
     described chips at a default MLConfig worker's shapes: (compiled,
-    logical bytes of weights + page pool)."""
+    its abstract operands)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -253,9 +336,9 @@ def _tp4_step_compiled(v5e_chips, cfg):
             sharding=NamedSharding(mesh, PartitionSpec()),
         )
 
-    ops, resident = _step_operands(cfg, place, on(tp_cache_specs(True)))
+    ops = _step_operands(cfg, place, on(tp_cache_specs(True)))
     step = make_tp_ragged_step(mesh, cfg, n_steps=8, spec_width=9, kernel=True)
-    return step.lower(*ops).compile(), resident
+    return step.lower(*ops).compile(), ops
 
 
 @pytest.mark.slow
@@ -264,8 +347,9 @@ def test_tp4_ragged_step_compiles_for_a_v5e_2x2_mesh(v5e_chips):
     all-gathers present, each device holding about a quarter."""
     from tensorlink_tpu.models.registry import config_presets
 
-    compiled, resident = _tp4_step_compiled(
+    compiled, ops = _tp4_step_compiled(
         v5e_chips, config_presets()["qwen3-4b"])
+    resident = _nbytes((ops[0], ops[2]))
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-gather" in text
     ma = compiled.memory_analysis()
@@ -286,9 +370,11 @@ def test_qwen2p5_7b_tp4_step_compiles_at_its_published_shapes(v5e_chips):
     from tensorlink_tpu.models.registry import config_presets
 
     cfg = dataclasses.replace(config_presets()["qwen2p5-7b"], max_seq_len=4096)
-    compiled, resident = _tp4_step_compiled(v5e_chips, cfg)
+    compiled, ops = _tp4_step_compiled(v5e_chips, cfg)
+    _assert_pools_stay_put(compiled, ops[2], ops[0], shards=4)
+    resident = _nbytes((ops[0], ops[2]))
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and "all-gather" in text
+    assert "all-gather" in text
     ma = compiled.memory_analysis()
     embed = cfg.vocab_size * cfg.d_model * 2
     share = (resident - embed) / 4 + embed

@@ -1088,3 +1088,64 @@ def test_int4_pack_layout_is_split_half():
     assert p[0] == np.int8((-4 & 0xF) | ((0 & 0xF) << 4))
     assert np.array_equal(np.asarray(unpack_int4(jnp.asarray(p[None]))),
                           np.asarray(v))
+
+
+# ---------------------------------------------------------------------------
+# the walk over a layer-stacked pool: [L, P, Hkv, page, hd] + a layer index
+# ---------------------------------------------------------------------------
+def _stacked_pools(rng, fmt, L, P, Hkv, page, hd):
+    """``L`` layers of pages in one storage format, stacked as the cache
+    holds them: ``(k, v, {k_scale, v_scale})``."""
+    if fmt == "bf16":
+        k, v = (
+            jnp.asarray(rng.normal(size=(L, P, Hkv, page, hd)), jnp.bfloat16)
+            for _ in range(2)
+        )
+        return k, v, {}
+    make = _int4_pages if fmt == "int4" else _quantized_pages
+    layers = [make(rng, P, Hkv, page, hd)[2:] for _ in range(L)]
+    k, ks, v, vs = (jnp.stack(x) for x in zip(*layers))
+    return k, v, {"k_scale": ks, "v_scale": vs}
+
+
+@pytest.mark.parametrize("G", [1, 4, 7])
+@pytest.mark.parametrize("fmt", ["bf16", "int8", "int4"])
+def test_walk_over_a_stacked_pool_equals_the_layers_slice(fmt, G):
+    """Both entry points on the layer-stacked pools with a layer index
+    equal, bitwise, the same call on that layer's ``[P, ...]`` slice: the
+    kernel addresses ``(layer, page)`` where it addressed ``page``, and
+    nothing else changes. hd 128: bf16 and int8 pages go to the kernel as
+    the whole stack, packed int4 (64 bytes a row, under a lane row) has
+    its layer cut out and padded first. The layer is traced, as under the
+    step's scan."""
+    rng = np.random.default_rng(29)
+    L, S, C, Hkv, hd, page, n_pp = 3, 3, 8, 2, 128, 8, 4
+    Hq, P = Hkv * G, 1 + S * n_pp
+    k, v, scales = _stacked_pools(rng, fmt, L, P, Hkv, page, hd)
+    bt = jnp.asarray(
+        rng.permutation(np.arange(1, P))[: S * n_pp]
+        .reshape(S, n_pp).astype(np.int32)
+    )
+    dt = jnp.bfloat16 if fmt == "bf16" else jnp.float32
+    q = jnp.asarray(rng.normal(size=(S, C, Hq, hd)), dt)
+    st = jnp.asarray([13, 0, 11], jnp.int32)
+    nv = jnp.asarray([1, 8, 0], jnp.int32)
+    lens = jnp.asarray([0, 9, 27], jnp.int32)
+    kw = dict(scale=hd**-0.5, interpret=True)
+    ragged = jax.jit(lambda layer: ragged_paged_attention(
+        q, k, v, bt, st, nv, layer=layer, **scales, **kw))
+    decode = jax.jit(lambda layer: paged_attention(
+        q[:, 0], k, v, bt, lens, layer=layer, **scales, **kw))
+    for layer in (0, L - 1):
+        sl = {n: a[layer] for n, a in scales.items()}
+        np.testing.assert_array_equal(
+            np.asarray(ragged(jnp.int32(layer)), np.float32),
+            np.asarray(ragged_paged_attention(
+                q, k[layer], v[layer], bt, st, nv, **sl, **kw), np.float32),
+        )
+        np.testing.assert_array_equal(
+            np.asarray(decode(jnp.int32(layer)), np.float32),
+            np.asarray(paged_attention(
+                q[:, 0], k[layer], v[layer], bt, lens, **sl, **kw),
+                np.float32),
+        )
