@@ -85,7 +85,15 @@ class TensorlinkAPI:
         self.host = host
         self.port = port
         self.log = get_logger("api")
-        self._pool = ThreadPoolExecutor(max_workers=8, thread_name_prefix="api-ml")
+        # one thread a request in flight (a stream holds its thread to its
+        # last token): as many as the slot engine has slots, so that a
+        # deployment of more than eight slots can be filled through the
+        # API at all (eight, the default slot count, was the fixed size)
+        ml = getattr(getattr(node, "config", None), "ml", None)
+        slots = int(getattr(ml, "cont_max_slots", 8) or 8)
+        self._pool = ThreadPoolExecutor(
+            max_workers=max(8, slots), thread_name_prefix="api-ml"
+        )
         self._loop: asyncio.AbstractEventLoop | None = None
         self._thread: threading.Thread | None = None
         self._server: asyncio.AbstractServer | None = None

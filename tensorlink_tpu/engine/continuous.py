@@ -80,6 +80,7 @@ from .generate import GenerationEngine
 from .kvtier import HostPagePool
 from .paged import (
     PageAllocator,
+    LatentPagedCache,
     PagedKVCache,
     PrefixCache,
     SharedPagePool,
@@ -123,6 +124,12 @@ def paged_unsupported(cfg) -> str | None:
     deliberately NOT a reason anymore — the paged cache stores int8
     pages natively (``kv_quant``), so ``quant="int8+kv"`` model specs
     serve continuous (regression-pinned in tests/test_quant.py)."""
+    if getattr(cfg, "patterned", False):
+        # layers of more than one kind: windows live in the page cache
+        # there, per kind (engine/latent.py)
+        from .latent import unsupported
+
+        return unsupported(cfg)
     if getattr(cfg, "sliding_window", None) is not None:
         return "sliding-window attention"
     return None
@@ -355,6 +362,30 @@ _ENGINE_COUNTERS = (
      "bytes each chip received in the tp step's all-gathers"),
     ("tp_gather_calls", "tlink_engine_tp_gather_calls_total",
      "all-gathers the tp step executed"),
+    # a patterned model's step (engine/latent.py): what the routing and
+    # the selection of a chunk came to, counted by the program itself
+    # (the cache's ``stats``, read with the chunk's one sync), each summed
+    # over the chunk's expert / full layer executions; 0 for other models
+    ("moe_rows_routed_local", "tlink_engine_moe_rows_routed_local_total",
+     "(row, expert) pairs that fell on an expert this program holds"),
+    ("moe_rows_computed", "tlink_engine_moe_rows_computed_total",
+     "rows the expert loop computed (whole tiles of one expert's rows)"),
+    ("moe_rows_busiest_expert", "tlink_engine_moe_rows_busiest_expert_total",
+     "rows of the held expert with most rows, a layer execution"),
+    ("moe_experts_touched", "tlink_engine_moe_experts_touched_total",
+     "held experts that got a row, a layer execution"),
+    ("moe_experts_held", "tlink_engine_moe_experts_held_total",
+     "held experts, a layer execution"),
+    ("sparse_positions_kept", "tlink_engine_sparse_positions_kept_total",
+     "cached positions the full layers attended after selection"),
+    ("sparse_positions_scored", "tlink_engine_sparse_positions_scored_total",
+     "cached positions in those queries' causal spans"),
+    # from the contexts as packed, like attn_pages_live: pages a sliding
+    # layer's pass reads against the pages the slot's context holds
+    ("window_pages_walked", "tlink_engine_window_pages_walked_total",
+     "pages the sliding layers' window spans reach"),
+    ("window_pages_context", "tlink_engine_window_pages_context_total",
+     "pages of context under those passes"),
     # the sampling epilogue (ROADMAP S1): what the packed slots asked of
     # it, per dispatched chunk from the host's own arrays. A sampler call
     # is one _sample_rows over [slots, vocabulary]: each verify row
@@ -546,6 +577,26 @@ class ContinuousEngine:
                 "continuous batching does not support sliding-window "
                 "attention yet — serve through the static batcher"
             )
+        # a patterned model's cache is latent pages per layer kind
+        # (engine/latent.py): the parts of the system that move or share
+        # K/V pages by name do not know them yet, and say so
+        self._latent = bool(engine.cfg.patterned)
+        if self._latent:
+            asked = str(kv_quant or "none")
+            refusals = (
+                (paged_unsupported(engine.cfg), None),
+                (asked != "none" or engine.cache_quant,
+                 f"latent pages are stored in the model dtype (kv_quant "
+                 f"{asked!r} asked)"),
+                (pool is not None, "latent pages in a shared page pool"),
+                (int(host_tier_pages) > 0,
+                 "latent pages in the host-RAM tier"),
+                (handoff_after_prefill,
+                 "latent pages do not hand off between workers"),
+            )
+            for hit, why in refusals:
+                if hit:
+                    raise PagedUnsupported(f"patterned model: {why or hit}")
         if int(prefill_chunk) <= 0:
             raise ValueError(
                 "prefill_chunk must be >= 1 — the monolithic dense-prefill "
@@ -607,6 +658,11 @@ class ContinuousEngine:
             self.alloc = None
         else:
             def new_cache():
+                if self._latent:
+                    return LatentPagedCache.init(
+                        self.cfg, self.max_slots, page_size=self.page_size,
+                        max_len=self.max_seq_len, dtype=engine.cache_dtype,
+                    )
                 return PagedKVCache.init(
                     self.cfg, self.max_slots, page_size=self.page_size,
                     max_len=self.max_seq_len, dtype=engine.cache_dtype,
@@ -1480,6 +1536,8 @@ class ContinuousEngine:
         corrupt the source. Returns None when nothing useful is
         resident (the prefix lost the race to eviction since the digest
         was published): the puller degrades to its next rung."""
+        if self._latent:  # latent pages do not travel yet (ROADMAP R3)
+            return None
         if self.prefix is None:
             return None
         chain = [int(t) for t in chain]
@@ -1540,6 +1598,8 @@ class ContinuousEngine:
         (0 = refused — the puller falls through to local prefill).
         Partial success is success: an allocator that dries up mid-blob
         keeps what it staged."""
+        if self._latent:  # latent pages do not travel yet (ROADMAP R3)
+            return 0
         if self.prefix is None:
             return 0
         ours = self.migration_mode()
@@ -1964,6 +2024,11 @@ class ContinuousEngine:
         reported resident — the PR-3 trie short-circuit). The gather is
         one fixed-shape dispatch per page (``gather_page``), so exports
         never grow the compiled-program set."""
+        if self._latent:
+            raise PagedUnsupported(
+                "a patterned model's latent pages do not migrate yet: the "
+                "stream falls back to re-prefill on its destination"
+            )
         req = self._slots[slot]
         if req is None or slot not in self._frozen:
             raise ValueError(f"slot {slot} is not frozen for export")
@@ -2330,10 +2395,8 @@ class ContinuousEngine:
         staged bytes to be meaningful on this engine (int4 and int8
         pools share the int8 byte dtype; page layouts differ per
         page_size; payload bytes differ per dtype)."""
-        return (
-            self.kv_quant, self.page_size,
-            str(np.dtype(self.cache.k.dtype)),
-        )
+        pools = self.cache.full if self._latent else self.cache.k
+        return (self.kv_quant, self.page_size, str(np.dtype(pools.dtype)))
 
     def resident_prefix_pages(self, chain, limit: int) -> int:
         """The probe: how many leading FULL pages of ``chain`` are
@@ -2353,6 +2416,8 @@ class ContinuousEngine:
         probe, allocator dry): the source then takes the re-prefill rung.
         Pages stay IN TRANSIT (conservation-tracked) until the stream's
         resume request adopts them, or the TTL/close GC frees them."""
+        if self._latent:  # the source takes the re-prefill rung
+            return False
         if mig_id in self._migrations:
             return True
         if self.drain_state != "serving":
@@ -2598,7 +2663,10 @@ class ContinuousEngine:
         # KV storage mode + occupancy: the capacity math operators size
         # slots-per-chip with (kv_quant="int8" halves kv_page_bytes)
         c = self.cache
-        page_bytes = (c.k.nbytes + c.v.nbytes) // c.n_pages
+        if self._latent:
+            page_bytes = c.pool_bytes // c.n_pages
+        else:
+            page_bytes = (c.k.nbytes + c.v.nbytes) // c.n_pages
         if c.quantized:
             page_bytes += (c.k_scale.nbytes + c.v_scale.nbytes) // c.n_pages
         # speculative decoding: enablement + the aggregate amortization
@@ -2650,6 +2718,9 @@ class ContinuousEngine:
             # hot path (1 = single device) — a router treats the whole
             # mesh as one placement unit
             "tensor_parallel": self.tensor_parallel,
+            "latent_pool_bytes": (
+                self.cache.pool_bytes if self._latent else 0
+            ),
             "weights_bytes_device_max": max(self.weights_bytes_device),
             "weights_bytes_device_min": min(self.weights_bytes_device),
         })
@@ -2959,6 +3030,31 @@ class ContinuousEngine:
             jnp.asarray(remaining), jnp.asarray(eos_arr),
         )
 
+    def _count_latent(self, step_stats, starts, n_valid, emit, n_exec):
+        """A patterned model's counters of one chunk: the step's own
+        (``STEP_STATS``, already summed over its layers and steps) and,
+        from the contexts as packed, the pages the sliding layers' window
+        spans reach against the pages of context under them: every slot
+        with a row in the ragged pass, the emitting ones each further
+        step."""
+        from ..models.latent import STEP_STATS, kind_counts
+
+        for name, v in zip(STEP_STATS, step_stats):
+            self._count(name, int(v))
+        la = self.cfg.latent_of("sliding")
+        ctx = starts + n_valid
+        pages = -(-ctx // self.page_size)
+        first = np.maximum(starts - (la.window - 1), 0) // self.page_size
+        rows = n_valid > 0
+        layers = kind_counts(self.cfg)["sliding"]
+        self._count("window_pages_walked", layers * int(
+            (pages - first)[rows].sum()
+            + (n_exec - 1) * (pages - first)[emit].sum()
+        ))
+        self._count("window_pages_context", layers * int(
+            pages[rows].sum() + (n_exec - 1) * pages[emit].sum()
+        ))
+
     def lower_step(self):
         """The step program lowered at this engine's own shapes and
         placement, not run: what ``chip_smoke.py`` reads to prove the
@@ -3047,6 +3143,10 @@ class ContinuousEngine:
                     toks_host = np.asarray(tokens)
                     n_tok_host = np.asarray(n_tok)
                     spec_m_host = np.asarray(spec_m)
+                    # the step's own counts ride the same sync
+                    step_stats = (
+                        np.asarray(self.cache.stats) if self._latent else ()
+                    )
                 # the chunk's host-visible wall time — measured at the
                 # ONE existing boundary sync, so span recording adds no
                 # device round trips of its own
@@ -3083,6 +3183,10 @@ class ContinuousEngine:
                         self._count("sampler_calls_sampled", n_calls)
                     self._count("verify_rows_walked", walked)
                     self._count("verify_rows_capacity", self.spec_width)
+                    if self._latent:
+                        self._count_latent(
+                            step_stats, starts, n_valid, emit, n_exec
+                        )
                     if self._tp_step is not None:
                         # the ragged pass gathers every block row through
                         # the layers and the verify rows through the head,

@@ -60,6 +60,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..models.base import ModelConfig
+from ..models.latent import Pattern
 from ..models.transformer import (
     _embed_tokens,
     _logits,
@@ -81,6 +82,15 @@ from ..ops.attention import (
     paged_attention_ref,
     ragged_paged_attention,
     ragged_paged_attention_ref,
+)
+from .latent import (
+    LatentPagedCache,
+    attention_only,
+    cache_pools,
+    decode_layers,
+    layer_loop,
+    ragged_layers,
+    with_pools,
 )
 
 
@@ -971,7 +981,10 @@ def _merge_pages(pool, layer, plan, rows):
 def _cache_kv(cache: PagedKVCache) -> tuple:
     """The layer-stacked KV tuple the layer loops carry — ``(k, v)``
     plain, ``(k, v, k_scale, v_scale)`` in int8 mode; the blocks branch
-    on the tuple arity (a trace-time constant)."""
+    on the tuple arity (a trace-time constant). A patterned model's
+    cache carries its pools per kind (engine/latent.py)."""
+    if isinstance(cache, LatentPagedCache):
+        return cache_pools(cache)
     if cache.k_scale is None:
         return (cache.k, cache.v)
     return (cache.k, cache.v, cache.k_scale, cache.v_scale)
@@ -980,6 +993,8 @@ def _cache_kv(cache: PagedKVCache) -> tuple:
 def _with_kv(cache: PagedKVCache, kv: tuple, **kw) -> PagedKVCache:
     """Rebuild the cache from the KV tuple a layer loop carried (inverse
     of :func:`_cache_kv`)."""
+    if isinstance(cache, LatentPagedCache):
+        return with_pools(cache, kv, **kw)
     if len(kv) == 4:
         return replace(
             cache, k=kv[0], v=kv[1], k_scale=kv[2], v_scale=kv[3], **kw
@@ -1105,23 +1120,16 @@ def _paged_block(x, lp, layer, cfg: ModelConfig, cos, sin, cache_kv,
 
 
 def _scan_layers(params, x, cache: PagedKVCache, block):
-    """The layer loop of both passes: ``block(x, lp, layer, kv) -> (x,
-    kv)`` over the stacked layer parameters, the page pools CARRIED whole
-    beside the activations. The pools are not the scan's ``xs``/``ys``: a
-    layer's pool is never sliced out of the stack, the updated one never
-    stacked back, and the loop's result is the buffer it was given, so an
-    enclosing loop (the decode continuation) carries it without a copy.
-    Returns ``(x, kv)``."""
-    def scan_fn(carry, xs):
-        lp, layer = xs
-        return block(carry[0], lp, layer, carry[1]), None
-
-    n_layers = cache.k.shape[0]
-    (x, kv), _ = jax.lax.scan(
-        scan_fn, (x, _cache_kv(cache)),
-        (params["layers"], jnp.arange(n_layers)),
+    """The layer loop of both passes of a dense GQA model: ``block(x, lp,
+    layer, kv) -> (x, kv)`` over the stacked layer parameters, the page
+    pools CARRIED whole beside the activations. The one-kind case of the
+    loop every model runs (``engine/latent.py::layer_loop``: no lead
+    layers, a period of one layer, no tail). Returns ``(x, kv)``."""
+    return layer_loop(
+        (), (params["layers"],), (),
+        Pattern((), ("gqa",), cache.k.shape[0], ()), x, _cache_kv(cache),
+        lambda x, lp, _kind, layer, kv: block(x, lp, layer, kv),
     )
-    return x, kv
 
 
 # tlint: hot-path
@@ -1161,13 +1169,20 @@ def _decode_step_impl(
     if cfg.pos == "rope":
         cos, sin = rope_tables(positions, _rope_dim(cfg), cfg.rope_theta)
 
-    x, kv_new = _scan_layers(
-        params, x, cache,
-        lambda x, lp, layer, kv: _paged_block(
-            x, lp, layer, cfg, cos, sin, kv, write_pg, write_off,
-            att_len, cache.block_tables, kernel, tp_axis, tp_quant,
-        ),
-    )
+    if cfg.patterned:
+        x, kv_new = decode_layers(
+            params, x, cache, cfg, kernel, positions=positions,
+            active=active, write_pg=write_pg, write_off=write_off,
+            att_len=att_len,
+        )
+    else:
+        x, kv_new = _scan_layers(
+            params, x, cache,
+            lambda x, lp, layer, kv: _paged_block(
+                x, lp, layer, cfg, cos, sin, kv, write_pg, write_off,
+                att_len, cache.block_tables, kernel, tp_axis, tp_quant,
+            ),
+        )
     x = _norm(x, params["final_norm"], cfg)
     logits = _logits(params, x, cfg, tp_axis, tp_quant)[:, 0]
     new_cache = _with_kv(
@@ -1382,7 +1397,7 @@ def _ragged_pass(
     n_pp = cache.pages_per_slot
     bt = cache.block_tables
     with jax.named_scope(RAGGED_PASS):
-        write_pg, write_off, pos, _valid = _ragged_write_indices(
+        write_pg, write_off, pos, valid = _ragged_write_indices(
             bt, starts, n_valid, page, n_pp, C
         )
         plan = _page_write_plan(bt, starts, n_valid, page, n_pp, C)
@@ -1395,13 +1410,20 @@ def _ragged_pass(
         if cfg.pos == "rope":
             cos, sin = rope_tables(positions, _rope_dim(cfg), cfg.rope_theta)
 
-        x, kv_new = _scan_layers(
-            params, x, cache,
-            lambda x, lp, layer, kv: _ragged_block(
-                x, lp, layer, cfg, cos, sin, kv, plan, write_pg, write_off,
-                bt, starts, n_valid, kernel, tp_axis, tp_quant,
-            ),
-        )
+        if cfg.patterned:
+            x, kv_new = ragged_layers(
+                params, x, cache, cfg, kernel, positions=pos, valid=valid,
+                plan=plan, n_valid=n_valid,
+            )
+        else:
+            x, kv_new = _scan_layers(
+                params, x, cache,
+                lambda x, lp, layer, kv: _ragged_block(
+                    x, lp, layer, cfg, cos, sin, kv, plan, write_pg,
+                    write_off, bt, starts, n_valid, kernel, tp_axis,
+                    tp_quant,
+                ),
+            )
         x = _norm(x, params["final_norm"], cfg)
     # verification rows: the last spec_width rows of each slot's valid
     # span — base = n_valid - 1 - n_spec, so a non-speculating slot
@@ -1774,6 +1796,68 @@ def make_logits_probe(mesh, cfg: ModelConfig, *, kernel: bool = False,
     return sharded(ragged, 2), sharded(decode, 1)
 
 
+def make_layer_probe(cfg: ModelConfig, kind: str, *, kernel: bool = False):
+    """ONE layer's attention of a patterned model through the pages, on
+    hidden states given from outside: ``(ragged, decode)``, placed exactly
+    as :func:`_ragged_pass` and :func:`_decode_step_impl` place the step's
+    rows. What compares a layer's cached rows and its attention with a
+    reference ON THE SAME INPUT, so that nothing an earlier layer's
+    discrete choices did (which positions, which experts) is in the
+    difference (``benchmarks/reference/dots3_note.py``); nothing serves
+    through it.
+
+    ``ragged(lp, x [S, C, d], cache, li, starts, n_valid)`` and
+    ``decode(lp, x [S, 1, d], cache, li, active)`` return ``(what the
+    attention adds to x, cache)`` with the rows written to layer ``li`` of
+    ``kind``'s pools and the lengths advanced; ``lp`` holds the layer's
+    ``ln1`` and ``attn``. The addition and not the sum: rounded into a
+    bf16 residual stream ten times its size, the sum loses most of what
+    the comparison is after."""
+
+    def ragged(lp, x, cache, li, starts, n_valid):
+        page, n_pp = cache.page_size, cache.pages_per_slot
+        bt = cache.block_tables
+        _, _, pos, valid = _ragged_write_indices(
+            bt, starts, n_valid, page, n_pp, x.shape[1]
+        )
+        added, pools = attention_only(
+            lp, x, cache, cfg, kernel, kind, li, positions=pos, valid=valid,
+            plan=_page_write_plan(bt, starts, n_valid, page, n_pp, x.shape[1]),
+            n_valid=n_valid,
+        )
+        lengths = jnp.where(n_valid > 0, starts + n_valid, cache.lengths)
+        return added, _with_kv(cache, pools, lengths=lengths)
+
+    def decode(lp, x, cache, li, active):
+        lengths = cache.lengths
+        write_pg, write_off, _, _ = _ragged_write_indices(
+            cache.block_tables, lengths, active.astype(jnp.int32),
+            cache.page_size, cache.pages_per_slot, 1,
+        )
+        added, pools = attention_only(
+            lp, x, cache, cfg, kernel, kind, li, positions=lengths[:, None],
+            active=active, write_pg=write_pg[:, 0], write_off=write_off[:, 0],
+            att_len=jnp.where(active, lengths + 1, 0),
+        )
+        return added, _with_kv(
+            cache, pools, lengths=jnp.where(active, lengths + 1, lengths)
+        )
+
+    return (jax.jit(ragged, donate_argnums=(2,)),
+            jax.jit(decode, donate_argnums=(2,)))
+
+
+def _page_pools(cache) -> dict:
+    """A cache's page pools by field name, page axis 1: what a page
+    operation moves. ``k`` / ``v`` and, quantized, their scale planes; a
+    patterned model's pools per kind (engine/latent.py)."""
+    if isinstance(cache, LatentPagedCache):
+        return {n: getattr(cache, n) for n in cache.POOLS}
+    names = ("k", "v") if cache.k_scale is None else (
+        "k", "v", "k_scale", "v_scale")
+    return {n: getattr(cache, n) for n in names}
+
+
 # tlint: hot-path  # tlint: one-program
 @partial(jax.jit, donate_argnames=("cache",))
 def copy_page(
@@ -1784,18 +1868,9 @@ def copy_page(
     without touching the shared original. In int8 mode the scale rows
     move with the payload — the copy is byte-exact, so a COW'd quantized
     page dequantizes to exactly what the original does."""
-    out = replace(
-        cache,
-        k=cache.k.at[:, dst].set(cache.k[:, src]),
-        v=cache.v.at[:, dst].set(cache.v[:, src]),
-    )
-    if cache.k_scale is not None:
-        out = replace(
-            out,
-            k_scale=cache.k_scale.at[:, dst].set(cache.k_scale[:, src]),
-            v_scale=cache.v_scale.at[:, dst].set(cache.v_scale[:, src]),
-        )
-    return out
+    return replace(cache, **{
+        n: a.at[:, dst].set(a[:, src]) for n, a in _page_pools(cache).items()
+    })
 
 
 # tlint: hot-path  # tlint: one-program
@@ -1807,12 +1882,7 @@ def gather_page(cache: PagedKVCache, page: jax.Array) -> tuple:
     value itself (no dequantize, no cast), which is what makes a shipped
     page byte-exact on the destination: an adopted quantized page
     dequantizes to exactly what the source's kernels read."""
-    if cache.k_scale is None:
-        return cache.k[:, page], cache.v[:, page]
-    return (
-        cache.k[:, page], cache.v[:, page],
-        cache.k_scale[:, page], cache.v_scale[:, page],
-    )
+    return tuple(a[:, page] for a in _page_pools(cache).values())
 
 
 # tlint: hot-path  # tlint: one-program
@@ -1828,19 +1898,13 @@ def scatter_page(
     """Write one shipped page's KV into a destination-owned physical page —
     the migration IMPORT device path (inverse of :func:`gather_page`,
     byte-exact; page shape is fixed, so any migration compiles this ONCE
-    per engine mode regardless of how many pages move)."""
-    out = replace(
-        cache,
-        k=cache.k.at[:, page].set(k),
-        v=cache.v.at[:, page].set(v),
-    )
-    if k_scale is not None:
-        out = replace(
-            out,
-            k_scale=cache.k_scale.at[:, page].set(k_scale),
-            v_scale=cache.v_scale.at[:, page].set(v_scale),
-        )
-    return out
+    per engine mode regardless of how many pages move). The arrays given
+    are the cache's pools in :func:`gather_page`'s order."""
+    given = [x for x in (k, v, k_scale, v_scale) if x is not None]
+    return replace(cache, **{
+        n: a.at[:, page].set(x)
+        for (n, a), x in zip(_page_pools(cache).items(), given)
+    })
 
 
 # tlint: hot-path  # tlint: one-program
@@ -1879,6 +1943,7 @@ def pages_needed(total_len: int, page_size: int) -> int:
 
 
 __all__ = [
+    "LatentPagedCache",
     "PagedKVCache",
     "PageAllocator",
     "PoolTenant",
@@ -1888,6 +1953,7 @@ __all__ = [
     "paged_ragged_step",
     "make_tp_ragged_step",
     "make_logits_probe",
+    "make_layer_probe",
     "tp_cache_specs",
     "tp_gather_costs",
     "copy_page",

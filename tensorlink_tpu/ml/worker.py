@@ -473,7 +473,10 @@ class DistributedWorker:
             del full
 
         if serve_tp == 1:
-            mesh = self._build_stage_mesh(cfg, stage)
+            # a patterned model (models/latent.py) is served whole on one
+            # device: its tree has no partition specs yet (ROADMAP R1: an
+            # expert axis over the host's chips)
+            mesh = None if cfg.patterned else self._build_stage_mesh(cfg, stage)
             if mesh is not None:
                 params = self._shard_params(params, cfg, stage, mesh)
         if self.node.config.ml.collective_quant and not training:

@@ -20,6 +20,69 @@ from ..core import serialization
 
 
 @dataclass(frozen=True)
+class LatentAttn:
+    """Sizes of one kind of latent (low-rank) attention layer: queries
+    and keys/values are projected down to ``q_rank`` / ``kv_rank``,
+    normalised, and up again per head; a head's key is ``nope_dim``
+    values from the latent and ``rope_dim`` rotated values shared by all
+    heads. What a position caches is the latent and the rotated key
+    (``row_dim`` values), whatever the head count.
+
+    ``window``: keys ``t - window < s <= t`` (the token itself counts);
+    None = causal. ``index_heads > 0``: a learned selector scores every
+    earlier position with ``index_heads`` small heads of ``index_dim``
+    (rope on the first ``index_rope_dim``) and attention reads the
+    ``index_topk`` best; it caches one ``index_dim`` key a position."""
+
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float
+    window: int | None = None
+    q_scale: float = 1.0  # on the normalised query latent
+    kv_scale: float = 1.0  # on the normalised key/value latent
+    index_heads: int = 0
+    index_dim: int = 0
+    index_rope_dim: int = 0
+    index_topk: int = 0
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def row_dim(self) -> int:
+        """Values cached a position: the latent and the rotated key."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def pool_dim(self) -> int:
+        """``row_dim`` in whole 128-lane rows, as the page pool stores it
+        (a kernel's page copy cannot slice a narrower minor dim)."""
+        return -(-self.row_dim // 128) * 128
+
+    def param_count(self, d: int) -> int:
+        n = (
+            d * self.q_rank + self.q_rank
+            + self.q_rank * self.n_heads * self.qk_dim
+            + d * self.row_dim + self.kv_rank
+            + self.kv_rank * self.n_heads * (self.nope_dim + self.v_dim)
+            + d * self.n_heads  # the headwise output gate
+            + self.n_heads * self.v_dim * d
+        )
+        if self.index_heads:
+            n += (
+                self.q_rank * self.index_heads * self.index_dim
+                + d * self.index_dim + 2 * self.index_dim
+                + d * self.index_heads
+            )
+        return n
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for the unified decoder-only core.
 
@@ -96,6 +159,39 @@ class ModelConfig:
     # hop bytes at a bounded, test-pinned divergence. Opt-in
     # (MLConfig.collective_quant applies it at stage load).
     collective_quant: bool = False
+    # -- layers of more than one kind (models/latent.py) ------------------
+    # ``layer_kinds`` names each layer's attention kind ("full" /
+    # "sliding"), ``latent`` the sizes of each kind; () = every layer the
+    # one GQA kind above. The first ``n_dense_layers`` keep the dense MLP
+    # (``d_ff``); the others route over ``n_experts`` experts of
+    # ``moe_d_ff`` beside ``n_shared_experts`` always-on ones.
+    layer_kinds: tuple = ()
+    latent: tuple = ()  # ((kind, LatentAttn), ...)
+    n_dense_layers: int = 0
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    # "softmax": top-k of the logits, softmax over the k (Mixtral).
+    # "sigmoid": sigmoid scores, top-k of score + selection bias, the k
+    # scores normalised to sum 1 (``moe_norm_topk``), times ``moe_scale``
+    moe_router: str = "softmax"
+    moe_norm_topk: bool = True
+    moe_scale: float = 1.0
+    # a chip's share of an expert group: the router scores all
+    # ``n_experts``, this program holds and computes experts
+    # ``experts_first .. experts_first + experts_held - 1`` (0 = all)
+    experts_first: int = 0
+    experts_held: int = 0
+
+    @property
+    def patterned(self) -> bool:
+        return bool(self.layer_kinds)
+
+    def latent_of(self, kind: str) -> LatentAttn:
+        return dict(self.latent)[kind]
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def q_dim(self) -> int:
@@ -126,12 +222,25 @@ class ModelConfig:
         d = dict(d)
         if isinstance(d.get("dtype"), str):
             d["dtype"] = jnp.dtype(d["dtype"]).type
+        # JSON has no tuples and no dataclasses: a config is a static
+        # (hashed) argument of the compiled programs
+        if "layer_kinds" in d:
+            d["layer_kinds"] = tuple(d["layer_kinds"])
+        if "latent" in d:
+            d["latent"] = tuple(
+                (k, v if isinstance(v, LatentAttn) else LatentAttn(**v))
+                for k, v in d["latent"]
+            )
         return cls(**d)
 
     def param_count(self) -> int:
         """Analytic parameter count (used by the sharding planner's memory
         estimator — TPU analogue of reference ml/utils.py:36-124)."""
         d, f, v, L = self.d_model, self.d_ff, self.vocab_size, self.n_layers
+        if self.patterned:
+            # ``total`` counts every published expert; what one chip of an
+            # expert group holds is ``held_param_count``
+            return self._patterned_count(self.n_experts)
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
         if self.moe:
             mlp = self.n_experts * 3 * d * f + d * self.n_experts
@@ -143,6 +252,26 @@ class ModelConfig:
         emb = v * d + (0 if self.tie_embeddings else v * d)
         pos = self.max_seq_len * d if self.pos == "learned" else 0
         return L * (attn + mlp + norms) + emb + pos + d
+
+    def _patterned_count(self, n_experts: int) -> int:
+        d, v = self.d_model, self.vocab_size
+        expert = 3 * d * self.moe_d_ff
+        moe = (
+            d * self.n_experts + self.n_experts  # router + selection bias
+            + (n_experts + self.n_shared_experts) * expert
+        )
+        n = 2 * v * d + d  # embedding, untied head, final norm
+        for i, kind in enumerate(self.layer_kinds):
+            n += self.latent_of(kind).param_count(d) + 2 * d
+            n += 3 * d * self.d_ff if i < self.n_dense_layers else moe
+        return n
+
+    def held_param_count(self) -> int:
+        """Parameters this program holds: ``param_count`` with the
+        experts held in place of the experts published."""
+        if not self.patterned:
+            return self.param_count()
+        return self._patterned_count(self.n_held)
 
 
 @jax.tree_util.register_dataclass

@@ -14,7 +14,7 @@ from typing import Any, Callable
 
 import jax.numpy as jnp
 
-from .base import ModelConfig
+from .base import LatentAttn, ModelConfig
 
 # tlint: disable=TL006(family registry — populated at import, read-only after)
 _FAMILY_BUILDERS: dict[str, Callable[[dict], ModelConfig]] = {}
@@ -121,6 +121,92 @@ def _mixtral(d: dict) -> ModelConfig:
         n_experts=d["num_local_experts"],
         n_experts_per_tok=d["num_experts_per_tok"],
         sliding_window=d.get("sliding_window"),
+    )
+
+
+@register_family("dots3_note")
+def _dots3_note(d: dict) -> ModelConfig:
+    """dots3-note: latent attention of two kinds by ``layer_types`` (full
+    layers with a learned top-k selector, sliding layers with sizes of
+    their own, ``swa_*``), a leading dense layer, then sigmoid-routed
+    experts beside a shared one. The keys say what the layers are; four
+    conventions they do not settle are this reader's (docs/MODELS.md
+    "dots3_note", and ``assumed`` in the benchmark's configuration file):
+    the latent rescale ``apply_mla_qkv_lora_rescale`` is sqrt(hidden /
+    rank) on each normalised latent; the headwise gate is a sigmoid of the
+    layer's normed input times each head's output; the window counts the
+    token itself; rope is on the LAST ``qk_rope_head_dim`` dims of q/k.
+
+    A chip's share of an expert group: ``n_routed_experts`` is what this
+    program holds, ``published.n_routed_experts`` what the router scores,
+    ``expert_group.first_expert`` where the held ones start."""
+    hidden = d["hidden_size"]
+    rescale = bool(d.get("apply_mla_qkv_lora_rescale", False))
+
+    def scale(rank: int) -> float:
+        return (hidden / rank) ** 0.5 if rescale else 1.0
+
+    full = LatentAttn(
+        n_heads=d["num_attention_heads"],
+        q_rank=d["q_lora_rank"], kv_rank=d["kv_lora_rank"],
+        nope_dim=d["qk_nope_head_dim"], rope_dim=d["qk_rope_head_dim"],
+        v_dim=d["v_head_dim"], rope_theta=float(d["rope_theta"]),
+        q_scale=scale(d["q_lora_rank"]), kv_scale=scale(d["kv_lora_rank"]),
+        index_heads=d.get("index_n_heads", 0),
+        index_dim=d.get("index_head_dim", 0),
+        index_rope_dim=d["qk_rope_head_dim"] if d.get("index_n_heads") else 0,
+        index_topk=d.get("index_topk", 0),
+    )
+    sliding = LatentAttn(
+        n_heads=d["swa_num_attention_heads"],
+        q_rank=d["swa_q_lora_rank"], kv_rank=d["swa_kv_lora_rank"],
+        nope_dim=d["swa_qk_nope_head_dim"], rope_dim=d["swa_qk_rope_head_dim"],
+        v_dim=d["swa_v_head_dim"], rope_theta=float(d["swa_rope_theta"]),
+        window=d["sliding_window_size"],
+        q_scale=scale(d["swa_q_lora_rank"]),
+        kv_scale=scale(d["swa_kv_lora_rank"]),
+    )
+    kinds = tuple(
+        {"full_attention": "full", "sliding_attention": "sliding"}[t]
+        for t in d["layer_types"][: d["num_hidden_layers"]]
+    )
+    if len(kinds) != d["num_hidden_layers"]:
+        raise ValueError(
+            f"layer_types names {len(kinds)} layers, num_hidden_layers "
+            f"{d['num_hidden_layers']}"
+        )
+    if d.get("scoring_func", "sigmoid") != "sigmoid" or d.get("n_group"):
+        raise ValueError(
+            "dots3_note: only the sigmoid router without expert groups "
+            "is implemented"
+        )
+    held = d["n_routed_experts"]
+    published = (d.get("published") or {}).get("n_routed_experts", held)
+    return ModelConfig(
+        family="dots3_note",
+        vocab_size=d["vocab_size"],
+        d_model=hidden,
+        n_layers=d["num_hidden_layers"],
+        # the GQA fields name the full layers' heads: nothing of the
+        # patterned path reads them
+        n_heads=full.n_heads, n_kv_heads=1, head_dim=full.qk_dim,
+        d_ff=d["intermediate_size"],
+        max_seq_len=d.get("max_position_embeddings", 4096),
+        norm_eps=d.get("rms_norm_eps", 1e-6),
+        rope_theta=full.rope_theta,
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        layer_kinds=kinds,
+        latent=(("full", full), ("sliding", sliding)),
+        n_dense_layers=d.get("first_k_dense_replace", 0),
+        n_experts=published,
+        n_experts_per_tok=d["num_experts_per_tok"],
+        moe_d_ff=d["moe_intermediate_size"],
+        n_shared_experts=d.get("n_shared_experts", 0),
+        moe_router="sigmoid",
+        moe_norm_topk=bool(d.get("norm_topk_prob", True)),
+        moe_scale=float(d.get("routed_scaling_factor", 1.0)),
+        experts_first=(d.get("expert_group") or {}).get("first_expert", 0),
+        experts_held=held if held != published else 0,
     )
 
 

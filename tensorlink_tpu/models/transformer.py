@@ -58,6 +58,16 @@ def init_params(
     result (``tp_partition_specs`` on the serving mesh): every leaf is then
     made under its sharding, no leaf whole on one device, with the values
     the same seed gives unsharded (tests/test_tp_load.py pins both)."""
+    if cfg.patterned:
+        # layers of more than one kind have a tree of their own (lead,
+        # periods, tail) and one placement: whole, on one device
+        from . import latent
+
+        if shardings is not None:
+            raise NotImplementedError(
+                "a patterned model's weights are not made under shardings"
+            )
+        return latent.init_params(cfg, key, dtype)
     dt = dtype or cfg.dtype
     d, f, hd = cfg.d_model, cfg.d_ff, cfg.head_dim
     L, V = cfg.n_layers, cfg.vocab_size
@@ -598,6 +608,13 @@ def _stage_impl(
     flash_prefill: bool = False,
     flash_mesh=None,
 ):
+    if cfg.patterned:
+        raise NotImplementedError(
+            "a model with layers of more than one kind (latent attention, "
+            "windows, routed experts: models/latent.py) runs on the slot "
+            "engine's step only (engine/paged.py): the dense-cache forward, "
+            "stage chains and training do not know its layers"
+        )
     attn_fn = None
     T_in = tokens.shape[1] if tokens is not None else (
         hidden.shape[1] if hidden is not None else 1
@@ -783,6 +800,13 @@ def slice_stage_params(
     """Cut a full parameter tree down to one stage's tree (host-side; used by
     tests and by single-host multi-stage simulations — real workers load only
     their slice from the checkpoint, engine/loader.py)."""
+    if "layers" not in params:
+        # a patterned model (models/latent.py) is one stage, whole
+        if not (first and holds_head and lo == 0):
+            raise NotImplementedError(
+                "a patterned model is served as one whole stage"
+            )
+        return dict(params)
     out: dict = {}
     if first:
         out["embed"] = params["embed"]

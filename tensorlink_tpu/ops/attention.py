@@ -279,6 +279,7 @@ def paged_attention_ref(
     scale: float,
     k_scale: jax.Array | None = None,  # f32 [P, Hkv, page] — int8 pages
     v_scale: jax.Array | None = None,
+    window: int | None = None,  # keys length - window <= s < length
 ) -> jax.Array:
     """Pure-jnp paged attention — the CPU serving path and the ground truth
     the Pallas kernel is pinned against.
@@ -311,6 +312,8 @@ def paged_attention_ref(
         * scale
     )
     valid = jnp.arange(K)[None, :] < lengths[:, None]  # [S, K]
+    if window is not None:  # the query sits at lengths - 1 and counts
+        valid &= jnp.arange(K)[None, :] >= lengths[:, None] - window
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     w = jnp.where(lengths[:, None, None, None] > 0, w, 0.0)
@@ -561,6 +564,17 @@ def _walk_trips(start, n_valid, rb, *, cb: int, page: int, ppb: int):
     return n_rb, kv_len, n_pages, n_kb
 
 
+def _walk_start(start, rb, *, cb: int, page: int, ppb: int, window: int):
+    """Where row block ``rb`` of a windowed walk begins: ``(first key
+    position any of its queries may attend, the KV block holding it)``.
+    A query at position ``t`` attends ``t - window < s <= t`` (the token
+    itself counts), and the block's first query sits at ``start + rb·cb``:
+    the blocks before that key's are never copied. Integer arithmetic
+    only, like :func:`_walk_trips`."""
+    lo = jnp.maximum(start + rb * cb - (window - 1), 0)
+    return lo, lo // page // ppb
+
+
 def _paged_walk_kernel(
     bt_ref,  # scalar-prefetch: block tables [S, n_pp]
     start_ref,  # scalar-prefetch: absolute position of each slot's row 0
@@ -577,12 +591,25 @@ def _paged_walk_kernel(
     cb: int,
     quantized: bool,
     packed: bool,
+    window: int | None = None,
+    shared_kv: bool = False,
 ):
     """One slot and one block of ``hb`` kv heads of the walk (grid
     ``(slot, head block)``): row blocks of ``cb`` chunk positions up to
     the slot's last valid query, each against KV blocks of ``ppb`` pages
-    up to its own causal limit, double-buffered."""
-    if quantized:
+    up to its own causal limit, double-buffered.
+
+    ``window``: a query at ``t`` attends ``t - window < s <= t`` and the
+    walk starts at the KV block that holds its row block's first such
+    key (:func:`_walk_start`). ``shared_kv``: the value of a position is
+    its key row (a latent cache: one row serves both sides, the caller
+    keeps the value's columns of the output), so ``v_hbm`` is not an
+    operand and a page is copied once."""
+    if shared_kv:
+        v_hbm, rest = None, (v_hbm,) + rest
+        o_ref, kbuf, sem, m_ref, l_ref, acc_ref = rest
+        vbuf = None
+    elif quantized:
         ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem, \
             m_ref, l_ref, acc_ref = rest
     else:
@@ -610,9 +637,10 @@ def _paged_walk_kernel(
         copies = [
             pltpu.make_async_copy(
                 k_hbm.at[at], kbuf.at[buf, :, p], sem.at[buf, 0]),
-            pltpu.make_async_copy(
-                v_hbm.at[at], vbuf.at[buf, :, p], sem.at[buf, 1]),
         ]
+        if not shared_kv:
+            copies.append(pltpu.make_async_copy(
+                v_hbm.at[at], vbuf.at[buf, :, p], sem.at[buf, 1]))
         if quantized:
             copies += [
                 pltpu.make_async_copy(
@@ -666,7 +694,11 @@ def _paged_walk_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # query row r of the block is chunk position rb·cb + r // G
         c_pos = rb * cb + q_row // G
-        live_pages(0, 0, n_pages, wait=False)
+        kb0 = 0
+        if window is not None:
+            kb0 = _walk_start(start, rb, cb=cb, page=page, ppb=ppb,
+                              window=window)[1]
+        live_pages(kb0, kb0 % 2, n_pages, wait=False)
 
         def kv_block(kb, carry):
             buf = kb % 2
@@ -677,7 +709,7 @@ def _paged_walk_kernel(
 
             live_pages(kb, buf, n_pages, wait=True)
             k = kbuf[buf, :, :, :, pl.ds(0, hdk)]  # [Hkv, ppb, page, hdk]
-            v = vbuf[buf, :, :, :, pl.ds(0, hdk)]
+            v = k if shared_kv else vbuf[buf, :, :, :, pl.ds(0, hdk)]
             if packed:
                 k, v = _unpack4(k), _unpack4(v)
             else:
@@ -699,7 +731,10 @@ def _paged_walk_kernel(
                 ks, vs = scale_rows(ksbuf, buf), scale_rows(vsbuf, buf)
                 vs = jnp.where(k_lane < left, vs, 0.0)
                 sc = sc * ks[:, None, :]
-            ok = ((kb * T + k_col <= start + c_pos) & (c_pos < nv))[None]
+            ok = (kb * T + k_col <= start + c_pos) & (c_pos < nv)
+            if window is not None:
+                ok &= kb * T + k_col > start + c_pos - window
+            ok = ok[None]
             sc = jnp.where(ok, sc, NEG_INF)
             m_prev = m_ref[...]
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
@@ -718,7 +753,7 @@ def _paged_walk_kernel(
             m_ref[...] = m_new
             return carry
 
-        jax.lax.fori_loop(0, n_kb, kv_block, 0)
+        jax.lax.fori_loop(kb0, n_kb, kv_block, 0)
         # rows past the last valid query met no unmasked key: l == 0 and
         # the floor yields a zero row, like the references
         o_ref[0, :, rows, :] = (
@@ -750,6 +785,8 @@ def _paged_walk(
     G: int,
     scale: float,
     interpret: bool,
+    window: int | None = None,
+    shared_kv: bool = False,  # values are the key rows: v_pages unused
 ) -> jax.Array:
     """The ``pl.pallas_call`` of the walk, named ``name``; returns
     ``[S, Hkv, C·G, hd]``. Block sizes come from the shapes: KV blocks of
@@ -773,7 +810,8 @@ def _paged_walk(
             v_scale = _one_layer(v_scale, layer)
         if k_pages.shape[-1] % _MIN_TILE:
             k_pages = _one_layer(k_pages, layer)[None]
-            v_pages = _one_layer(v_pages, layer)[None]
+            if not shared_kv:
+                v_pages = _one_layer(v_pages, layer)[None]
             layer = 0
     S, Hkv, CG, hd = qg.shape
     page, hdk = k_pages.shape[3:]  # hdk = hd // 2 for packed int4
@@ -784,8 +822,12 @@ def _paged_walk(
     kernel = functools.partial(
         _paged_walk_kernel, scale=scale, page=page, ppb=ppb, G=G, cb=cb,
         quantized=quantized, packed=quantized and hdk * 2 == hd,
+        **({"window": window} if window is not None else {}),
+        **({"shared_kv": True} if shared_kv else {}),
     )
-    args = [qg, _lane_pad(k_pages), _lane_pad(v_pages)]
+    args = [qg, _lane_pad(k_pages)]
+    if not shared_kv:
+        args.append(_lane_pad(v_pages))
     hb = _heads_per_block(
         Hkv, CG, cb * G, ppb * page, hd, qg.dtype.itemsize,
         args[1].shape[-1] * args[1].dtype.itemsize,
@@ -804,7 +846,8 @@ def _paged_walk(
             pltpu.VMEM((2, ppb) + a.shape[2:], a.dtype) for a in args[3:]
         ]
     scratch += [
-        pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)),
+        pltpu.SemaphoreType.DMA(
+            (2, 4 if quantized else 1 if shared_kv else 2)),
         pltpu.VMEM((hb, cb * G, 1), jnp.float32),  # running max
         pltpu.VMEM((hb, cb * G, 1), jnp.float32),  # running denominator
         pltpu.VMEM((hb, cb * G, hd), jnp.float32),  # accumulator
@@ -893,11 +936,12 @@ def ragged_paged_attention(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("scale", "interpret", "window", "name"))
 def paged_attention(
     q: jax.Array,  # [S, Hq, hd]
     k_pages: jax.Array,  # [P, Hkv, page, hd]
-    v_pages: jax.Array,  # [P, Hkv, page, hd]
+    v_pages: jax.Array | None,  # [P, Hkv, page, hd]; None: the key rows
     block_tables: jax.Array,  # int32 [S, pages_per_slot]
     lengths: jax.Array,  # int32 [S]
     *,
@@ -906,6 +950,8 @@ def paged_attention(
     k_scale: jax.Array | None = None,  # f32 [P, Hkv, page] — int8 pages
     v_scale: jax.Array | None = None,
     layer: jax.Array | None = None,  # int32 scalar: pools are [L, P, ...]
+    window: int | None = None,  # keys length - window <= s < length
+    name: str | None = None,  # the pallas_call's, as a trace shows it
 ) -> jax.Array:
     """Paged decode attention; returns ``[S, Hq, hd]``.
 
@@ -917,16 +963,21 @@ def paged_attention(
     output. GQA queries group on the
     kv-head axis, so repeated KV heads are never materialized. One
     compiled program serves every (length mix, page assignment) — the
-    block table and lengths are data, not shape."""
+    block table and lengths are data, not shape.
+
+    ``window`` walks only the last ``window`` positions (the query's own
+    counts). ``v_pages=None`` is a latent cache: a position's value is
+    its key row, copied once (the caller keeps the value's columns)."""
     S, Hq, hd = q.shape
     Hkv = k_pages.shape[-3]
     lengths = jnp.asarray(lengths, jnp.int32)
     out = _paged_walk(
-        "paged_attention", q.reshape(S, Hkv, Hq // Hkv, hd), k_pages,
+        "paged_attention" if name is None else name,
+        q.reshape(S, Hkv, Hq // Hkv, hd), k_pages,
         v_pages, block_tables, jnp.maximum(lengths - 1, 0),
         jnp.minimum(lengths, 1),
         k_scale, v_scale, layer, G=Hq // Hkv, scale=scale,
-        interpret=interpret,
+        interpret=interpret, window=window, shared_kv=v_pages is None,
     )
     return out.reshape(S, Hq, hd)
 

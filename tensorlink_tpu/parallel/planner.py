@@ -79,6 +79,8 @@ class MemoryEstimate:
         optimizer: str = "adamw",
     ) -> "MemoryEstimate":
         pb = _dtype_bytes(cfg.dtype)
+        if cfg.patterned:
+            return cls._patterned(cfg, batch, seq_len, training, pb)
         n = cfg.param_count()
         params = n * pb
         grads = n * pb if training else 0
@@ -111,6 +113,34 @@ class MemoryEstimate:
         )
         total = int((params + grads + opt + act + kv) * 1.1)
         return cls(params, grads, opt, int(act), int(kv), total)
+
+
+    @classmethod
+    def _patterned(cls, cfg, batch, seq_len, training, pb):
+        """A model with layers of more than one kind (models/latent.py),
+        as the slot engine serves it: the experts this program HOLDS, not
+        the experts published; a cached position is one latent row a
+        layer (and a selector key on the full layers), whatever the head
+        count; no pass holds scores of a whole context against itself
+        (full layers attend ``index_topk`` selected rows, sliding layers
+        their window), so activations are the residual stream and one
+        layer's projections. This is what lets a 16k context be planned:
+        the dense-cache estimate above reads 68 GB for its scores alone."""
+        from ..models.latent import kind_counts
+
+        if training:
+            raise NotImplementedError(
+                "a patterned model is served, not trained (models/latent.py)"
+            )
+        params = cfg.held_param_count() * pb
+        per_position = sum(
+            n * (cfg.latent_of(kind).pool_dim + cfg.latent_of(kind).index_dim)
+            for kind, n in kind_counts(cfg).items()
+        )
+        kv = per_position * batch * seq_len * pb
+        act = batch * seq_len * (8 * cfg.d_model + 2 * cfg.d_ff) * pb
+        total = int((params + act + kv) * 1.1)
+        return cls(params, 0, 0, int(act), int(kv), total)
 
 
 @dataclass

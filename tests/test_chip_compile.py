@@ -385,3 +385,65 @@ def test_qwen2p5_7b_tp4_step_compiles_at_its_published_shapes(v5e_chips):
           f"{text.count('tpu_custom_call')} kernel calls")
     assert 0.98 * share < ma.argument_size_in_bytes < 1.02 * share + 2**26, ma
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 0.6 * V5E_HBM, ma
+
+
+def test_dots3_note_step_fits_one_v5e_with_its_window_kernel(v5e):
+    """The benchmark's ``dots3-note-prev-ep8`` at its published widths (5
+    layers, 32 of 256 experts held, 16 slots x 16,384 positions of bf16
+    latent pages): the step compiles for one described v5e with the
+    windowed latent kernel in it (one call a sliding layer of the
+    continuation step: rows of 1,152 values, 64 query heads on one row),
+    weights + pools + temporaries fit the chip, no operation copies a
+    latent pool, and the entry computation holds the three phase loops in
+    order (the ragged pass's layers run as ONE loop). ~60 s: the one
+    program the new cell serves from, compiled nowhere else in tier-1."""
+    import json
+    import re
+    from pathlib import Path
+
+    from tensorlink_tpu.engine.latent import WINDOW_KERNEL, LatentPagedCache
+    from tensorlink_tpu.engine.paged import STEP_PHASES, paged_ragged_step
+    from tensorlink_tpu.models.registry import config_from_hf
+    from tensorlink_tpu.models.transformer import init_params
+
+    hf = json.loads((Path(__file__).parent.parent / "benchmarks" / "configs"
+                     / "dots3-note-prev-ep8.json").read_text())
+    cfg = config_from_hf(hf)
+    slots = hf["deployment"]["ml"]["cont_max_slots"]
+    params = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: LatentPagedCache.init(
+        cfg, slots, page_size=PAGE, max_len=hf["deployment"]["seq_len"]))
+    place = _on(v5e)
+
+    def ctl(dt, *shape):
+        return place(jax.ShapeDtypeStruct(shape, dt))
+
+    i32, f32 = jnp.int32, jnp.float32
+    ops = (
+        place(params), ctl(i32, slots, 128), place(cache), ctl(i32, slots),
+        ctl(i32, slots), ctl(i32, slots), ctl(jnp.bool_, slots),
+        ctl(i32, slots), ctl(i32, slots), ctl(f32, slots), ctl(i32, slots),
+        ctl(f32, slots), ctl(f32, slots), ctl(f32, slots),
+        ctl(i32, slots, cfg.vocab_size), ctl(i32, slots), ctl(i32, slots, 8),
+    )
+    compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3 and WINDOW_KERNEL in text
+    ma = compiled.memory_analysis()
+    weights = _nbytes(ops[0])
+    # bf16 but the float32 selection bias: 2 more bytes x 256 x 4 layers
+    assert weights == 2 * cfg.held_param_count() + 2 * 256 * 4
+    assert 8.1e9 < weights < 8.3e9
+    pools = _nbytes((cache.full, cache.index, cache.slide))
+    assert 2.5e9 < pools < 2.7e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM, ma
+    assert ma.temp_size_in_bytes < pools, ma
+    for pool in (cache.full, cache.index, cache.slide):
+        shape = "[" + ",".join(map(str, pool.shape)) + "]"
+        copies = re.findall(
+            rf"^\s*\S+ = \w+{re.escape(shape)}\S* copy\(.*$", text, re.M)
+        assert not copies, copies[:2]
+    whiles = [ln for ln in text[text.index("ENTRY"):].splitlines()
+              if " while(" in ln]
+    assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)
+            for ln in whiles] == list(STEP_PHASES)

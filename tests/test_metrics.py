@@ -231,6 +231,13 @@ LEGACY_ENGINE_KEYS = (
     "attn_pages_live", "attn_pages_capacity",
     # the tensor-parallel step's activation gathers (0 at tp = 1)
     "tp_gather_bytes", "tp_gather_calls",
+    # a patterned model's step (engine/latent.py): routing and selection
+    # as the program counted them, window pages from the packed contexts
+    # (0 for every other model)
+    "moe_rows_routed_local", "moe_rows_computed", "moe_rows_busiest_expert",
+    "moe_experts_touched", "moe_experts_held",
+    "sparse_positions_kept", "sparse_positions_scored",
+    "window_pages_walked", "window_pages_context",
     # the sampling epilogue (ROADMAP S1): calls, those that sorted, and
     # the verify walk's length against the rows the program holds
     "sampler_calls", "sampler_calls_sampled",

@@ -1149,3 +1149,63 @@ def test_walk_over_a_stacked_pool_equals_the_layers_slice(fmt, G):
                 q[:, 0], k[layer], v[layer], bt, lens, **sl, **kw),
                 np.float32),
         )
+
+
+# ---------------------------------------------------------------------------
+# the walk with a start (a window) and one row as key and value (a latent
+# cache): ops/attention.py::_walk_start, paged_attention(window=, v_pages=None)
+# ---------------------------------------------------------------------------
+def test_walk_starts_at_the_windows_first_block():
+    from tensorlink_tpu.ops.attention import _walk_start, _walk_trips
+
+    def start(s, rb, window, cb=1, page=16, ppb=8):
+        return tuple(int(x) for x in _walk_start(
+            s, rb, cb=cb, page=page, ppb=ppb, window=window))
+
+    # (first key position, its KV block): a decode row at 12,799 with the
+    # published window 513 (the token itself counts) walks from 12,287
+    assert start(12799, 0, 513) == (12287, 95)
+    assert start(100, 0, 513) == (0, 0)  # the window is not full yet
+    assert start(512, 0, 513) == (0, 0)
+    assert start(513, 0, 513) == (1, 0)
+    assert start(1000, 2, 129, cb=32) == (936, 7)  # row block 2 of a chunk
+    # and it never starts past the walk's end
+    for s in (0, 1, 127, 128, 4095):
+        lo, kb0 = start(s, 0, 17)
+        n_kb = int(_walk_trips(s, 1, 0, cb=1, page=16, ppb=8)[3])
+        assert lo == max(s - 16, 0) and kb0 < n_kb
+
+
+@pytest.mark.parametrize("window", [None, 1, 17, 33, 200])
+def test_windowed_latent_walk_matches_the_reference(window):
+    """``paged_attention(window=, v_pages=None)`` (interpreted) against
+    ``paged_attention_ref`` with the pool as keys and values both: a
+    stacked pool of one "kv head" with a wide row, lengths from 0 (a free
+    slot) to past several KV blocks; pages outside the window hold NaN
+    where the window leaves whole blocks out, and are never read."""
+    from tensorlink_tpu.ops.attention import paged_attention, paged_attention_ref
+
+    rng = np.random.default_rng(11)
+    L, P, page, W = 3, 60, 16, 256
+    S, H, n_pp = 4, 8, 14
+    pool = rng.normal(size=(L, P, 1, page, W)).astype(np.float32)
+    bt = rng.permutation(np.arange(1, P))[:S * n_pp].reshape(S, n_pp).astype(np.int32)
+    lengths = np.asarray([0, 5, 131, 220], np.int32)
+    q = jnp.asarray(rng.normal(size=(S, H, W)), jnp.float32)
+    ref = paged_attention_ref(
+        q, jnp.asarray(pool[1]), jnp.asarray(pool[1]), jnp.asarray(bt),
+        jnp.asarray(lengths), scale=0.1, window=window)
+    poisoned = pool.copy()
+    if window is not None:
+        for s, n in enumerate(lengths):
+            first_block = max(n - window, 0) // page // 8  # ppb 8
+            for pg in bt[s, :first_block * 8]:
+                poisoned[:, pg] = np.nan
+    out = paged_attention(
+        q, jnp.asarray(poisoned), None, jnp.asarray(bt), jnp.asarray(lengths),
+        scale=0.1, interpret=True, layer=jnp.int32(1), window=window,
+        name="latent_window_attention")
+    assert not np.isnan(np.asarray(out)).any()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    assert np.abs(np.asarray(out[0])).max() == 0.0  # the free slot
