@@ -405,6 +405,17 @@ _ENGINE_COUNTERS = (
     (f"chunk_us_{ph}", f"tlink_engine_chunk_us_{ph}_total",
      f"host microseconds in a chunk's {ph} phase")
     for ph in ("between",) + CHUNK_PHASES
+) + (
+    # the stream stage (docs/SERVING.md "Observability"): a chunk's tokens
+    # leave for their callbacks behind the NEXT chunk's dispatch, so its
+    # microseconds lie inside that chunk's wait; with no step in flight
+    # they lie inside deliver (or wherever a flush was asked for)
+    ("chunk_us_stream", "tlink_engine_chunk_us_stream_total",
+     "host microseconds in the stream stage (a sub-span of wait or deliver)"),
+    ("stream_tokens_overlapped", "tlink_engine_stream_tokens_overlapped_total",
+     "tokens streamed while the device ran the next chunk"),
+    ("stream_tokens_flushed", "tlink_engine_stream_tokens_flushed_total",
+     "tokens streamed with no step in flight"),
 )
 
 
@@ -434,6 +445,10 @@ class ContinuousRequest:
     prefill_tokens: list[int] = field(default_factory=list)
     prefill_target: int = 0
     error: BaseException | None = None
+    # stream_cb asked for a stop while the next chunk was already running
+    # with this slot in it: that chunk's settle evicts the slot and drops
+    # its tokens (docs/SERVING.md "Continuous batching", the cancel)
+    cancelled: bool = False
     done: threading.Event = field(default_factory=threading.Event)
     # -- live migration (docs/FAILURE_MODEL.md "Migration & drain") ------
     # staged-adoption ticket id: admission binds the shipped KV pages
@@ -891,6 +906,11 @@ class ContinuousEngine:
         self._chunk_exit_t: float | None = None
         # the flight-recorder step of the chunk in progress
         self._chunk_step = 0
+        # what the settle stage left for the stream stage: rid -> (request,
+        # its last n tokens not yet handed to stream_cb, whether they hold
+        # its first token ever, whether on_finish follows them, the chunk
+        # that made them). At most one entry a request; driver-thread only
+        self._unstreamed: dict[int, tuple] = {}
         if pool is not None:
             # per-tenant pool occupancy: these render under the model's
             # label at /metrics (the registry-per-model grouping), which
@@ -1177,34 +1197,91 @@ class ContinuousEngine:
         if cb is not None:
             cb(req)
 
-    def _emit(self, req: ContinuousRequest, tok: int) -> bool:
-        """Deliver one token; returns True when the request is done
-        (EOS / budget / downstream cancel)."""
-        if not req.tokens:
-            # first token EVER for this request (a resumed-after-preempt
-            # request already has tokens, so TTFT is recorded once).
-            # Under the lock: serving_snapshot() iterates the TTFT
-            # sample deque from other threads (/stats), and a deque
-            # append racing that iteration raises.
-            now = time.monotonic()
-            with self._lock:
-                self.sched.note_first_token(req, now - req.submit_t)
-            if req.trace_id:
-                # the TTFT decomposition's last leg: prefill completed →
-                # first token delivered (contiguous with the queue_wait
-                # and prefill spans by construction, so the three parts
-                # sum to the first_token span's TTFT)
-                base = req.prefill_done_t or req.admit_t or req.submit_t
-                self._trace(req, "first_decode", dur_s=now - base)
-                self._trace(
-                    req, "first_token", dur_s=now - req.submit_t,
-                    chunk=self._chunk_step,
-                )
-        req.tokens.append(tok)
-        cancel = False
-        if req.stream_cb is not None:
-            cancel = bool(req.stream_cb(tok))
-        return cancel or tok in req.eos or len(req.tokens) >= req.budget
+    # tlint: hot-path
+    def flush_stream(self, req: ContinuousRequest | None = None, *,
+                     in_flight: bool = False) -> None:
+        """The stream stage: hand what the settle stage left pending to
+        the requests' callbacks, in order: the ``first_token`` spans where
+        a first token leaves, ``stream_cb`` a token, ``on_finish`` after a
+        finished request's last one. ``step_chunk`` runs it behind its
+        dispatch (``in_flight``: the device executes the next chunk
+        meanwhile), or at once when no step follows. Anything else that
+        answers for a request calls it first, for that request (``req``)
+        or for all: ``close``, ``begin_drain``, ``freeze_slot`` and every
+        teardown that is not a finish (``_teardown_slot``: preemption,
+        shed, handoff commit). Driver-thread only."""
+        pend = self._unstreamed
+        if not pend or (req is not None and req.rid not in pend):
+            return
+        t0 = time.monotonic()
+        n = 0
+        try:
+            with jax.profiler.TraceAnnotation("tlink:stream"):
+                for rid in (req.rid,) if req is not None else tuple(pend):
+                    entry = pend.pop(rid, None)
+                    if entry is not None:
+                        n += self._stream_one(*entry, in_flight)
+        finally:
+            self._count(
+                "chunk_us_stream", int((time.monotonic() - t0) * 1e6 + 0.5)
+            )
+            self._count(
+                "stream_tokens_overlapped" if in_flight
+                else "stream_tokens_flushed", n,
+            )
+
+    def _stream_one(self, req: ContinuousRequest, n: int, first: bool,
+                    finish: bool, step: int, in_flight: bool) -> int:
+        """One request's pending tokens (the last ``n`` of ``req.tokens``)
+        to its callbacks; returns how many left. A truthy ``stream_cb``
+        return (a confirmed stop) ends the stream there: ``req.tokens`` is
+        cut back to what was streamed, so at ``on_finish`` it is exactly
+        the sequence the callback was given."""
+        base = len(req.tokens) - n
+        sent, cancel = n, False
+        try:
+            if first:
+                # first token EVER for this request (a resumed-after-
+                # preempt request already has tokens, so TTFT is recorded
+                # once), stamped when it really leaves. Under the lock:
+                # serving_snapshot() iterates the TTFT sample deque from
+                # other threads (/stats), and a deque append racing that
+                # iteration raises.
+                now = time.monotonic()
+                with self._lock:
+                    self.sched.note_first_token(req, now - req.submit_t)
+                if req.trace_id:
+                    # the TTFT decomposition's last leg: prefill completed
+                    # → first token delivered (contiguous with the
+                    # queue_wait and prefill spans by construction, so the
+                    # three parts sum to the first_token span's TTFT)
+                    t_pf = req.prefill_done_t or req.admit_t or req.submit_t
+                    self._trace(req, "first_decode", dur_s=now - t_pf)
+                    self._trace(
+                        req, "first_token", dur_s=now - req.submit_t,
+                        chunk=step,
+                    )
+            cb = req.stream_cb
+            if cb is not None:
+                for i in range(n):
+                    if cb(req.tokens[base + i]):
+                        sent, cancel = i + 1, True
+                        del req.tokens[base + sent:]
+                        break
+            if finish:
+                self._finish(req, finished=True)
+            elif cancel:
+                req.cancelled = True
+                if not in_flight:
+                    self._evict(req.slot)
+                # else the running chunk holds the slot: its settle evicts
+        except BaseException as e:
+            if finish and not req.done.is_set():
+                # its slot is gone: nobody else would answer for it
+                req.error = e
+                self._finish(req, finished=False)
+            raise
+        return sent
 
     def _admit_one(self, req: ContinuousRequest, slot: int) -> bool:
         """Place ``req`` into ``slot``. Returns False when no pages are
@@ -1846,10 +1923,18 @@ class ContinuousEngine:
         return bool(np.any(np.asarray(v)))
 
     def _evict(self, slot: int) -> None:
+        """Retire a slot and answer for its request at once (``close``, a
+        stop asked for with no step in flight); a chunk's settle stage
+        retires and leaves ``on_finish`` to the stream stage."""
+        req = self._retire(slot)
+        if req is not None:
+            self._finish(req, finished=True)
+
+    def _retire(self, slot: int) -> ContinuousRequest | None:
         """Free a finished slot at a step boundary: shared prefix pages
         drop their refcount, promotable private pages move INTO the
         prefix cache, the rest return to the free-list; table row →
-        scratch, slot → admission pool."""
+        scratch, slot → admission pool. No callback runs here."""
         req = self._teardown_slot(slot)
         if req is not None:
             self._count("evicted")
@@ -1884,13 +1969,18 @@ class ContinuousEngine:
                     self.sched.note_finished(
                         req, time.monotonic() - req.admit_t
                     )
-            self._finish(req, finished=True)
+        return req
 
     def _teardown_slot(self, slot: int) -> ContinuousRequest | None:
         """Shared slot teardown for eviction AND preemption: device row →
         scratch, pages released (promotable prefill-written pages enter
         the prefix cache), host mirrors cleared. Returns the request that
-        held the slot, its transient slot state reset."""
+        held the slot, its transient slot state reset. Tokens of its
+        request that wait for the stream stage leave first: whoever tears
+        a slot down answers for the request next (a requeue, a redirect
+        to another worker), and a stop they bring frees the slot itself."""
+        if self._slots[slot] is not None:
+            self.flush_stream(self._slots[slot])
         req = self._slots[slot]
         self._slots[slot] = None
         self._prefilling.pop(slot, None)
@@ -1994,6 +2084,10 @@ class ContinuousEngine:
         (their cheap exit is the re-prefill fallback; they have no
         decode-written KV worth shipping). Driver-thread only, at a chunk
         boundary."""
+        if self._slots[slot] is not None:
+            # the export's chain is prompt + tokens and the destination
+            # goes on from there: what this side made leaves this side
+            self.flush_stream(self._slots[slot])
         req = self._slots[slot]
         if req is None or not self._active[slot] or slot in self._prefilling:
             raise ValueError(
@@ -2164,7 +2258,10 @@ class ContinuousEngine:
     def begin_drain(self) -> None:
         """Admission fence: stop taking new work (submit fails fast,
         admission_check rejects) so the drain loop can shed every live
-        slot without racing fresh arrivals."""
+        slot without racing fresh arrivals. What the last chunk left for
+        the stream stage leaves first, so the manifest the drain reads is
+        of requests whose clients hold every token made here."""
+        self.flush_stream()
         self.drain_state = "draining"
         with self._lock:
             self.sched.set_draining(True)
@@ -3085,10 +3182,13 @@ class ContinuousEngine:
         (no separate prefill dispatches to wait behind), and a
         completing prefill samples its first token in the same dispatch
         that finishes its prompt. Runs ``chunk_steps`` fixed-shape slot
-        steps per host round trip, delivers each slot's tokens up to its
-        own done-point, and evicts finished slots at the boundary.
-        Returns True while any work (live slots or queued requests)
-        remains — the driver's requeue signal."""
+        steps per host round trip, settles each slot's tokens up to its
+        own done-point, and retires finished slots at the boundary. The
+        tokens leave for their callbacks behind the NEXT call's dispatch,
+        while the device runs that chunk, or at once when no step follows
+        (nothing dispatched, or no work left). Returns True while any
+        work (live slots or queued requests) remains — the driver's
+        requeue signal."""
         # the anatomy of a chunk (docs/SERVING.md "Observability"): the
         # phases below are marked where the work happens, on the
         # profiler's host line (tlink:<phase> inside one tlink:chunk that
@@ -3101,6 +3201,7 @@ class ContinuousEngine:
             t0 - self._chunk_exit_t if self._chunk_exit_t is not None else 0.0
         )
         step = self._chunk_step = self.recorder.next_step
+        stream_us0 = self._stat["chunk_us_stream"].value
         ph: dict = {}
         fields = None
         with jax.profiler.TraceAnnotation("tlink:chunk", chunk=step):
@@ -3137,6 +3238,10 @@ class ContinuousEngine:
                                 )
                             )
                 with _Phase(ph, "wait"):
+                    # the device runs this chunk: the one before it leaves
+                    # for its callbacks meanwhile, nothing below reads
+                    # what they return but a stop (settled next)
+                    self.flush_stream(in_flight=True)
                     # the first value that blocks: the device is done here
                     n_exec = int(n_exec)
                 with _Phase(ph, "drain"):
@@ -3152,11 +3257,13 @@ class ContinuousEngine:
                 # device round trips of its own
                 chunk_dur = ph["dispatch"] + ph["wait"] + ph["drain"]
                 with _Phase(ph, "deliver"):
-                    delivered_total = self._deliver(
+                    delivered_total = self._settle(
                         grants, completing, handoff_done, emit, n_spec,
                         n_exec, toks_host, n_tok_host, spec_m_host,
                         chunk_dur,
                     )
+                    if not self.has_work():
+                        self.flush_stream()  # no step follows to hide it
                 with _Phase(ph, "post"):
                     self._count("ragged_rows_valid", int(n_valid.sum()))
                     self._count("ragged_rows_computed", blk.size)
@@ -3215,6 +3322,9 @@ class ContinuousEngine:
                         preemptions=int(self._stat["preemptions"].value),
                     )
                     self._refresh_prefix_digest()
+            else:
+                with _Phase(ph, "deliver"):
+                    self.flush_stream()  # nothing was dispatched
         more = self.has_work()
         if fields is None:
             # nothing was dispatched (admission only, or nothing live):
@@ -3237,18 +3347,22 @@ class ContinuousEngine:
             host_ms=(us["admit"] + us["pack"]) / 1e3,
             t0=t0,
             **{f"{k}_ms": v / 1e3 for k, v in us.items()},
+            # a sub-span (of wait, or of deliver with no step in flight)
+            stream_ms=(self._stat["chunk_us_stream"].value - stream_us0) / 1e3,
         )
         self._chunk_exit_t = ph["end"] if more else None
         return more
 
     # tlint: hot-path
-    def _deliver(self, grants, completing, handoff_done, emit, n_spec,
-                 n_exec, toks_host, n_tok_host, spec_m_host,
-                 chunk_dur) -> int:
-        """The deliver phase of a chunk, after the drain: prefill
-        bookkeeping, each emitting slot's tokens up to its own
-        done-point through ``_emit``, and eviction of finished slots.
-        Returns the tokens delivered."""
+    def _settle(self, grants, completing, handoff_done, emit, n_spec,
+                n_exec, toks_host, n_tok_host, spec_m_host,
+                chunk_dur) -> int:
+        """The settle stage of a chunk, after the drain: everything the
+        next admission and pack read. Prefill bookkeeping, each emitting
+        slot's tokens onto ``req.tokens`` up to its own done-point (EOS,
+        budget: decided from the token array itself) and the teardown of
+        finished slots. It makes no callback: what it settled waits in
+        ``_unstreamed`` for the stream stage. Returns the tokens settled."""
         S = self.max_slots
         step = self._chunk_step
         # prefill bookkeeping: the grants landed on device; completed
@@ -3305,12 +3419,17 @@ class ContinuousEngine:
             # prefill-only steps decode nothing — don't count them
             self._count("decode_steps", n_exec)
             self._count("slot_steps_total", n_exec * S)
-        deliver = emit
         delivered_total = 0
         for s in range(S):
-            if not deliver[s]:
+            if not emit[s]:
                 continue
             req = self._slots[s]
+            if req.cancelled:
+                # its stream stopped while this chunk ran: the chunk's
+                # tokens of this slot are dropped, none went anywhere
+                self._retire(s)
+                self._unstreamed[req.rid] = (req, 0, False, True, step)
+                continue
             if n_spec[s] > 0 and req.spec_state is not None:
                 # verify-pass accounting feeds the per-request kill
                 # switch (engine/spec.py): spec_m is the pass's emitted
@@ -3323,6 +3442,7 @@ class ContinuousEngine:
                     self._count("spec_killed")
             finished = False
             emitted = 0
+            first = not req.tokens
             for i in range(int(n_tok_host[s])):
                 tok = int(toks_host[s, i])
                 if req.spec_state is not None:
@@ -3335,7 +3455,8 @@ class ContinuousEngine:
                     req.spec_state.note_pair(prev, tok)
                 self._tok[s] = tok
                 emitted += 1
-                if self._emit(req, tok):
+                req.tokens.append(tok)
+                if tok in req.eos or len(req.tokens) >= req.budget:
                     finished = True
                     break
             # the chunk's frozen slots stopped their key chain exactly
@@ -3346,7 +3467,11 @@ class ContinuousEngine:
             self._count("slot_steps_live", emitted)
             delivered_total += emitted
             if finished:
-                self._evict(s)
+                self._retire(s)
+            if emitted:
+                self._unstreamed[req.rid] = (
+                    req, emitted, first, finished, step,
+                )
         return delivered_total
 
     def _refresh_prefix_digest(self) -> None:
@@ -3375,17 +3500,26 @@ class ContinuousEngine:
         engine teardown). A real error dumps the flight recorder — the
         last N chunks of slot/page state ride ``recorder.last_dump`` so a
         chaos postmortem reads data, not prints."""
+        from ..core.logging import get_logger
+
         err = error or RuntimeError("continuous engine closed")
         if error is not None:
             dump = self.recorder.dump(error)
-            from ..core.logging import get_logger
-
             get_logger("engine.flight").warning(
                 "engine error — flight recorder dumped %d step records "
                 "(last: %s)",
                 dump["n_records"],
                 dump["records"][-1] if dump["records"] else None,
             )
+        while self._unstreamed:
+            # what was settled leaves before anything is failed; a
+            # callback that raises loses its own stream and no other
+            try:
+                self.flush_stream()
+            except Exception:
+                get_logger("engine.flight").exception(
+                    "a stream callback raised while the engine closed"
+                )
         with self._lock:
             pending = self.sched.pending()
             for req in pending:

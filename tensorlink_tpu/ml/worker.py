@@ -2053,6 +2053,9 @@ class DistributedWorker:
         reconciliation (its token counts are authoritative; the journal's
         high-water marks are only a floor)."""
         out = []
+        if rt.cont is not None:
+            # the counts below are of tokens that left for their relays
+            rt.cont.flush_stream()
         for jrid, req in rt.jstreams.items():
             out.append({
                 "jrid": jrid,
@@ -2080,6 +2083,12 @@ class DistributedWorker:
         tid = str(p.get("trace") or "")
         hwm = int(p.get("hwm", 0))
         req = rt.jstreams.get(jrid)
+        if req is not None:
+            # what the slot settled last chunk leaves through the OLD
+            # callbacks first (it may finish the request, or stop it):
+            # the backlog below is cut from req.tokens, and a token still
+            # pending would reach the new relay twice
+            cont.flush_stream(req)
         if req is not None and not req.finished:
             base = int(req.start_step)
             stream_cb, on_finish = self._cont_channels(

@@ -356,10 +356,12 @@ def test_new_request_joins_within_one_chunk(tiny_engine):
         late_first_at.setdefault("long_progress", len(long_req.tokens))
         return False
 
-    ce.submit([9, 9], max_new_tokens=4, seed=1, stream_cb=late_cb)
+    late = ce.submit([9, 9], max_new_tokens=4, seed=1, stream_cb=late_cb)
     ce.step_chunk()
-    assert "long_progress" in late_first_at, "late request not admitted"
-    # the late request's first token arrived while the long one was still
+    assert late.tokens, "late request not admitted"
+    assert not late_first_at  # settled; it leaves behind the next dispatch
+    ce.step_chunk()
+    # the late request's first token left while the long one was still
     # well short of done, within one chunk of its submission
     assert late_first_at["long_progress"] <= emitted_before_late + ce.chunk_steps
     assert not long_req.finished

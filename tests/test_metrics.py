@@ -246,6 +246,9 @@ LEGACY_ENGINE_KEYS = (
     "chunk_us_between", "chunk_us_admit", "chunk_us_pack",
     "chunk_us_dispatch", "chunk_us_wait", "chunk_us_drain",
     "chunk_us_deliver", "chunk_us_post",
+    # the stream stage: its microseconds (inside wait or deliver) and the
+    # tokens that left under a dispatched step / with none in flight
+    "chunk_us_stream", "stream_tokens_overlapped", "stream_tokens_flushed",
 )
 PHASES = ("between", "admit", "pack", "dispatch", "wait", "drain",
           "deliver", "post")

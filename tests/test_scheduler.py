@@ -322,7 +322,7 @@ def test_preempted_request_tokens_stream_exactly_once(tiny_engine):
         for i in range(3)
     ]
     ce.step_chunk()
-    assert len(seen) > 0  # victim is decoding
+    assert victim.tokens and not seen  # decoding; its tokens wait to leave
     pre = ce.submit([9, 9], max_new_tokens=4, seed=30,
                     priority="interactive")
     ce.run_until_idle()

@@ -394,8 +394,17 @@ def test_profiler_trace_holds_chunks_joined_to_records_by_id(
     for _name, a, b, _stats in chunks:
         inside = [e for e in events
                   if e[0] != "tlink:chunk" and a <= e[1] and e[2] <= b]
+        streams = [e for e in inside if e[0] == "tlink:stream"]
+        inside = [e for e in inside if e[0] != "tlink:stream"]
         assert [e[0] for e in inside] == [f"tlink:{p}" for p in PHASES]
         assert all(x[2] <= y[1] for x, y in zip(inside, inside[1:]))
+        # the stream stage is a sub-span: of wait while a step is in
+        # flight, of deliver when none follows
+        for _n, sa, sb, _s in streams:
+            assert any(e[0] in ("tlink:wait", "tlink:deliver")
+                       and e[1] <= sa and sb <= e[2] for e in inside)
+    # chunk 2's wait holds chunk 1's stream, its deliver its own (no work left)
+    assert len([e for e in events if e[0] == "tlink:stream"]) == 2
     ce.close()
 
 
