@@ -376,6 +376,10 @@ _ENGINE_COUNTERS = (
      "held experts that got a row, a layer execution"),
     ("moe_experts_held", "tlink_engine_moe_experts_held_total",
      "held experts, a layer execution"),
+    ("moe_rows_in_group", "tlink_engine_moe_rows_in_group_total",
+     "rows whose routing groups reach an expert this program holds"),
+    ("moe_rows_valid", "tlink_engine_moe_rows_valid_total",
+     "rows that carry a token, an expert layer execution"),
     ("sparse_positions_kept", "tlink_engine_sparse_positions_kept_total",
      "cached positions the full layers attended after selection"),
     ("sparse_positions_scored", "tlink_engine_sparse_positions_scored_total",
@@ -386,6 +390,12 @@ _ENGINE_COUNTERS = (
      "pages the sliding layers' window spans reach"),
     ("window_pages_context", "tlink_engine_window_pages_context_total",
      "pages of context under those passes"),
+    # ... and cached rows the walk of a full layer without a selector
+    # reads against the rows the slots of those passes could hold
+    ("latent_rows_read", "tlink_engine_latent_rows_read_total",
+     "cached rows the full layers' walk reads (each slot's live span)"),
+    ("latent_rows_capacity", "tlink_engine_latent_rows_capacity_total",
+     "rows the slots of those passes could hold"),
     # the sampling epilogue (ROADMAP S1): what the packed slots asked of
     # it, per dispatched chunk from the host's own arrays. A sampler call
     # is one _sample_rows over [slots, vocabulary]: each verify row
@@ -2492,7 +2502,7 @@ class ContinuousEngine:
         staged bytes to be meaningful on this engine (int4 and int8
         pools share the int8 byte dtype; page layouts differ per
         page_size; payload bytes differ per dtype)."""
-        pools = self.cache.full if self._latent else self.cache.k
+        pools = self.cache.rows if self._latent else self.cache.k
         return (self.kv_quant, self.page_size, str(np.dtype(pools.dtype)))
 
     def resident_prefix_pages(self, chain, limit: int) -> int:
@@ -3130,27 +3140,36 @@ class ContinuousEngine:
     def _count_latent(self, step_stats, starts, n_valid, emit, n_exec):
         """A patterned model's counters of one chunk: the step's own
         (``STEP_STATS``, already summed over its layers and steps) and,
-        from the contexts as packed, the pages the sliding layers' window
-        spans reach against the pages of context under them: every slot
-        with a row in the ragged pass, the emitting ones each further
-        step."""
+        from the contexts as packed (every slot with a row in the ragged
+        pass, the emitting ones each further step): the pages the sliding
+        layers' window spans reach against the pages of context under
+        them, and the rows the walk of the full layers that select
+        nothing reads against the rows those slots could hold."""
         from ..models.latent import STEP_STATS, kind_counts
 
         for name, v in zip(STEP_STATS, step_stats):
             self._count(name, int(v))
-        la = self.cfg.latent_of("sliding")
         ctx = starts + n_valid
-        pages = -(-ctx // self.page_size)
-        first = np.maximum(starts - (la.window - 1), 0) // self.page_size
         rows = n_valid > 0
-        layers = kind_counts(self.cfg)["sliding"]
-        self._count("window_pages_walked", layers * int(
-            (pages - first)[rows].sum()
-            + (n_exec - 1) * (pages - first)[emit].sum()
-        ))
-        self._count("window_pages_context", layers * int(
-            pages[rows].sum() + (n_exec - 1) * pages[emit].sum()
-        ))
+        layers = kind_counts(self.cfg)
+        sizes = dict(self.cfg.latent)
+
+        def over_passes(x):  # summed over the slots of each pass
+            return int(x[rows].sum() + (n_exec - 1) * x[emit].sum())
+
+        if layers.get("sliding"):
+            la = sizes["sliding"]
+            pages = -(-ctx // self.page_size)
+            first = np.maximum(starts - (la.window - 1), 0) // self.page_size
+            self._count("window_pages_walked",
+                        layers["sliding"] * over_passes(pages - first))
+            self._count("window_pages_context",
+                        layers["sliding"] * over_passes(pages))
+        if layers.get("full") and not sizes["full"].index_heads:
+            self._count("latent_rows_read", layers["full"] * over_passes(ctx))
+            self._count("latent_rows_capacity", layers["full"] * over_passes(
+                np.full_like(ctx, self.cache.pages_per_slot * self.page_size)
+            ))
 
     def lower_step(self):
         """The step program lowered at this engine's own shapes and

@@ -3,8 +3,9 @@ than one kind, models/latent.py) under the one serving step
 (engine/paged.py::paged_ragged_step).
 
 **Pools per kind, one page table.** A slot's block-table row names the
-same physical pages in every pool. A full layer caches a latent row and a
-selector key a position, a sliding layer a (wider) latent row:
+same physical pages in every pool. A full layer caches a latent row and,
+where it has a selector, a selector key a position, a sliding layer a
+(wider) latent row; a pool exists only for what the model has:
 
     full   [Lf, P, 1, page, pool_dim(full)]      latent | rotated key | pad
     index  [Lf, P, 1, page, index_dim]           the selector's keys
@@ -30,12 +31,19 @@ kind's pool.
 * continuation step, sliding layer: absorbed, through the paged kernel with
   a window start and the latent row as key and value both
   (``latent_window_attention``);
-* full layer, either pass: the selector scores the live span, the
-  ``index_topk`` best rows are gathered and attended absorbed (each query
-  has rows of its own). The ragged pass does this for every slot's first
-  row in one batch and, slot by slot, for the whole block of a slot that
-  holds more than one valid row (a prefill, a verify) — so a decode row in
-  the block costs a decode row. A context that holds no more than
+* full layer without a selector, either pass: absorbed, over the slot's
+  LIVE span through the paged kernel with the latent row as key and value
+  both (``latent_full_attention``): a continuation step and every slot's
+  first row of the ragged pass as one query position a slot, a slot's
+  block of more rows (a prefill, a verify) slot by slot as a ragged walk
+  with each row's causal limit. Nothing is gathered and no score array
+  spans a context;
+* full layer with a selector, either pass: the selector scores the live
+  span, the ``index_topk`` best rows are gathered and attended absorbed
+  (each query has rows of its own). The ragged pass does this for every
+  slot's first row in one batch and, slot by slot, for the whole block
+  of a slot that holds more than one valid row (a prefill, a verify) —
+  so a decode row in the block costs a decode row. A context that holds no more than
   ``index_topk`` positions is attended whole and nothing is scored.
 
 First support keeps every page of a slot for the sliding layers and reads
@@ -53,9 +61,11 @@ from jax import lax
 from ..models.base import LatentAttn, ModelConfig
 from ..models.latent import (
     _rms,
+    EXPERT_STACKS,
     INDEX_SELECT,
     LATENT_ATTN,
     MOE,
+    N_MOE_STATS,
     NEG_INF,
     STEP_STATS,
     WINDOW_ATTN,
@@ -74,9 +84,21 @@ from ..models.latent import (
     top_k_positions,
 )
 from ..models.quant import matmul as _mm
-from ..ops.attention import paged_attention, paged_attention_ref
+from ..ops.attention import (
+    paged_attention,
+    paged_attention_ref,
+    ragged_paged_attention,
+    ragged_paged_attention_ref,
+)
 
 WINDOW_KERNEL = "latent_window_attention"  # the pallas_call's name
+FULL_KERNEL = "latent_full_attention"  # ... the full layers' walk, both passes
+# the full layers' walk (ops/attention.py::_paged_walk, ``latent``): key
+# positions a KV block spans, query rows a row block holds, query rows a
+# grid step holds. One row serves every head, so a 128 x 128 tile is on
+# the chip's ridge with nothing to spare for the loop around it; sized on
+# the chip (PERF.md section 6, PR 34)
+FULL_KV_TILE, FULL_ROWS, FULL_GROUP_ROWS = 512, 512, 2048
 
 
 @jax.tree_util.register_dataclass
@@ -86,9 +108,9 @@ class LatentPagedCache:
     state as :class:`~tensorlink_tpu.engine.paged.PagedKVCache`
     (``block_tables``, ``lengths``), pools per kind in its layout."""
 
-    full: jax.Array
-    index: jax.Array
-    slide: jax.Array
+    full: jax.Array | None  # None: the model has no such layers
+    index: jax.Array | None  # ... no selector
+    slide: jax.Array | None
     block_tables: jax.Array  # int32 [S, pages_per_slot]
     lengths: jax.Array  # int32 [S]
     stats: jax.Array  # int32 [len(STEP_STATS)]: this step's counts
@@ -104,19 +126,35 @@ class LatentPagedCache:
         P = n_pages if n_pages is not None else 1 + max_slots * n_pp
         dt = dtype or cfg.dtype
         n = kind_counts(cfg)
-        full, slide = cfg.latent_of("full"), cfg.latent_of("sliding")
+        sizes = dict(cfg.latent)
+        full, slide = sizes.get("full"), sizes.get("sliding")
 
-        def pool(layers, width):
-            return jnp.zeros((layers, P, 1, page_size, width), dt)
+        def pool(kind, width):
+            # no pool for a kind, or a selector, the model has not: a
+            # placeholder one value wide is padded to whole lanes on the
+            # chip (0.4 GB of nothing at 6 layers x 16 slots x 16,384)
+            if not n.get(kind) or not width:
+                return None
+            return jnp.zeros((n[kind], P, 1, page_size, width), dt)
 
         return cls(
-            full=pool(n["full"], full.pool_dim),
-            index=pool(n["full"], max(full.index_dim, 1)),
-            slide=pool(n["sliding"], slide.pool_dim),
+            full=pool("full", full and full.pool_dim),
+            index=pool("full", full and full.index_heads and full.index_dim),
+            slide=pool("sliding", slide and slide.pool_dim),
             block_tables=jnp.zeros((max_slots, n_pp), jnp.int32),
             lengths=jnp.zeros((max_slots,), jnp.int32),
             stats=jnp.zeros((len(STEP_STATS),), jnp.int32),
         )
+
+    def pools(self) -> dict:
+        """The pools there are, by field name."""
+        return {n: getattr(self, n) for n in self.POOLS
+                if getattr(self, n) is not None}
+
+    @property
+    def rows(self) -> jax.Array:
+        """A pool of latent rows (any: the control state is shared)."""
+        return self.full if self.full is not None else self.slide
 
     @property
     def quantized(self) -> bool:
@@ -124,11 +162,11 @@ class LatentPagedCache:
 
     @property
     def page_size(self) -> int:
-        return self.full.shape[3]
+        return self.rows.shape[3]
 
     @property
     def n_pages(self) -> int:
-        return self.full.shape[1]
+        return self.rows.shape[1]
 
     @property
     def max_slots(self) -> int:
@@ -140,23 +178,31 @@ class LatentPagedCache:
 
     @property
     def pool_bytes(self) -> int:
-        return sum(
-            getattr(self, n).size * getattr(self, n).dtype.itemsize
-            for n in self.POOLS
-        )
+        return sum(a.size * a.dtype.itemsize for a in self.pools().values())
 
 
 def unsupported(cfg: ModelConfig) -> str | None:
     """Why the slot engine cannot serve a patterned config; None when it
-    can: the two kinds this module implements, each with sizes."""
+    can: layers of the two kinds this module implements, either or both,
+    each kind in use with its sizes."""
     kinds = set(cfg.layer_kinds)
-    have = {k for k, _ in cfg.latent}
-    if not kinds <= {"full", "sliding"} or have != {"full", "sliding"}:
-        return f"layer kinds {sorted(kinds)} (served: full, sliding)"
-    if cfg.latent_of("sliding").window is None:
-        return "a sliding layer without a window"
-    if cfg.latent_of("full").window is not None:
+    sizes = dict(cfg.latent)
+    if not kinds <= {"full", "sliding"} or not kinds <= set(sizes):
+        return (f"layer kinds {sorted(kinds)} with sizes for "
+                f"{sorted(sizes)} (served: full, sliding)")
+    if "sliding" in kinds:
+        if sizes["sliding"].window is None:
+            return "a sliding layer without a window"
+        if sizes["sliding"].index_heads:
+            return "a selector on the sliding layers"
+    if "full" in kinds and sizes["full"].window is not None:
         return "a window on the full layers"
+    if cfg.moe_n_group and (
+        cfg.n_experts % cfg.moe_n_group
+        or not 0 < cfg.moe_topk_group <= cfg.moe_n_group
+    ):
+        return (f"{cfg.n_experts} experts in {cfg.moe_n_group} groups, "
+                f"{cfg.moe_topk_group} a token")
     return None
 
 
@@ -257,7 +303,7 @@ def _select_attend(q_n, q_r, qi, wi, q_pos, row_ok, bt_row, full, index, li,
     Kc = lat.shape[0]
     causal = jnp.arange(Kc)[None, :] <= q_pos[:, None]  # [R, Kc]
     span = jnp.where(row_ok, q_pos + 1, 0).sum()
-    if not la.index_heads or Kc <= la.index_topk:
+    if Kc <= la.index_topk:
         # nothing to drop: every live position is attended
         mask = causal & row_ok[:, None]
         rows = jnp.broadcast_to(lat[None], (q_pos.shape[0],) + lat.shape)
@@ -275,9 +321,60 @@ def _select_attend(q_n, q_r, qi, wi, q_pos, row_ok, bt_row, full, index, li,
     return o, mask.sum(), span
 
 
+def _walk_attend(q, pool, li, ap, la: LatentAttn, ctx: _Ctx):
+    """A full layer's attention with nothing selected: absorbed, over each
+    slot's live span through the page walk, the latent row as key and
+    value both; ``[S, T, H, v]``."""
+    T = ctx.positions.shape[1]
+    bt, scale = ctx.block_tables, la.softmax_scale
+    qa = absorbed_query(q["q_n"], q["q_r"], ap, la)  # [S, T, H, W]
+    shape = (la.kv_rank, FULL_KV_TILE, FULL_ROWS, FULL_GROUP_ROWS)
+    kernel = ctx.kernel and pool.dtype == qa.dtype
+
+    def walk(fn, ref, qs, *place):  # either entry point, or its reference
+        if kernel:
+            return fn(qs, pool, None, *place, scale=scale, layer=li,
+                      name=FULL_KERNEL, latent=shape)
+        rows = pool[li].astype(qs.dtype)
+        return ref(qs, rows, rows, *place, scale=scale)[..., :la.kv_rank]
+
+    def first_rows(lengths):  # one query position a slot, at lengths - 1
+        out = walk(paged_attention, paged_attention_ref, qa[:, 0], bt, lengths)
+        return absorbed_output(out, ap, la)[:, None]  # [S, 1, H, v]
+
+    if ctx.plan is None:  # a continuation step
+        return first_rows(ctx.att_len)
+    # every slot's first row in one call: all there is of a decode slot
+    o1 = first_rows(jnp.where(ctx.n_valid > 0, ctx.positions[:, 0] + 1, 0))
+    if T == 1:
+        return o1
+    many = ctx.n_valid > 1  # a prefill's or a verify's block, slot by slot
+
+    def block(a):
+        ok, qs, bt_row, start, nv = a
+        return lax.cond(
+            ok,
+            lambda: absorbed_output(walk(
+                ragged_paged_attention, ragged_paged_attention_ref, qs[None],
+                bt_row[None], start[None], nv[None])[0], ap, la),
+            lambda: jnp.zeros((T, la.n_heads, la.v_dim), o1.dtype),
+        )
+
+    oT = lax.map(block, (many, qa, bt, ctx.positions[:, 0], ctx.n_valid))
+    first = jnp.pad(o1, ((0, 0), (0, T - 1), (0, 0), (0, 0)))
+    return jnp.where(many[:, None, None, None], oT, first)
+
+
 def _full_attend(q, full, index, li, ap, la: LatentAttn, ctx: _Ctx):
-    """A full layer's attention; ``([S, T, H, v], kept, scored)``."""
+    """A full layer's attention; ``([S, T, H, v], kept, scored)``:
+    positions attended and positions the causal spans held, summed over
+    the valid queries."""
     S, T = ctx.positions.shape
+    if not la.index_heads:
+        with jax.named_scope(LATENT_ATTN):
+            o = _walk_attend(q, full, li, ap, la, ctx)
+        span = jnp.where(ctx.row_ok, ctx.positions + 1, 0).sum()
+        return o, span, span
 
     def slot(args, rows=slice(None)):
         q_n, q_r, qi, wi, pos, ok, bt_row = args
@@ -286,9 +383,8 @@ def _full_attend(q, full, index, li, ap, la: LatentAttn, ctx: _Ctx):
             bt_row, full, index, li, ap, la,
         )
 
-    zi = jnp.zeros((S, T, 1, 1), q["q_n"].dtype)
     args = (
-        q["q_n"], q["q_r"], q.get("qi", zi), q.get("wi", zi[..., 0]),
+        q["q_n"], q["q_r"], q["qi"], q["wi"],
         ctx.positions, ctx.row_ok, ctx.block_tables,
     )
     # every slot's first row in one batch: all there is of a decode slot
@@ -340,9 +436,11 @@ def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
             o = _sliding_attend(q, slide, li, ap, la, ctx)
     else:
         o, kept, scored = _full_attend(q, full, index, li, ap, la, ctx)
-        stats = stats.at[5:7].add(jnp.stack([kept, scored]).astype(jnp.int32))
+        stats = stats.at[N_MOE_STATS:].add(
+            jnp.stack([kept, scored]).astype(jnp.int32))
     with jax.named_scope("attn"):
-        o = (o.astype(jnp.float32) * q["gate"][..., None]).astype(x.dtype)
+        if "gate" in q:
+            o = (o.astype(jnp.float32) * q["gate"][..., None]).astype(x.dtype)
         added = _mm(o.reshape(S, T, -1), ap["wo"])
     return added, (full, index, slide, stats)
 
@@ -364,7 +462,8 @@ def _layer(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         y, ms = moe_mlp(
             h.reshape(S * T, d), lp["moe"], cfg, ctx.row_ok.reshape(-1)
         )
-        stats = stats.at[:5].add(ms)  # each adds up over layers and steps
+        # each adds up over layers and steps
+        stats = stats.at[:N_MOE_STATS].add(ms)
     return x + y.reshape(S, T, d), (full, index, slide, stats)
 
 
@@ -384,7 +483,8 @@ def with_pools(cache: LatentPagedCache, pools: tuple, **kw):
     )
 
 
-def layer_loop(lead, periods, tail, pat: Pattern, x, carry, layer):
+def layer_loop(lead, periods, tail, pat: Pattern, x, carry, layer,
+               whole=None):
     """THE layer loop of the serving step, for every model: ``layer(x, lp,
     kind, li, carry) -> (x, carry)`` over the lead layers (unrolled), a
     scan over the periods (one traced period whatever the depth) and the
@@ -395,7 +495,15 @@ def layer_loop(lead, periods, tail, pat: Pattern, x, carry, layer):
     is the buffer it was given (an enclosing loop, the decode
     continuation, carries it without a copy). A dense GQA model is the
     one-kind case: no lead, a period of one layer, ``li`` the scan's own
-    index (``paged._scan_layers``). Returns ``(x, carry)``."""
+    index (``paged._scan_layers``). ``whole``: for each place of a
+    period, the leaves of ``lp["moe"]`` that the scan must NOT slice a
+    period at a time (None: none): they reach ``layer`` stacked over the
+    periods with ``"stacked"``, the period's index, beside them. The
+    scan's slice of an ``xs`` leaf is a copy where its reader cannot fuse
+    it, and the expert loop reads one expert of a layer's stack by a
+    dynamic index: five periods of 20 experts copied 0.94 GB of expert
+    weights a layer and step (PERF.md section 6, PR 34). Returns ``(x,
+    carry)``."""
     seen = dict.fromkeys(pat.lead + pat.period + pat.tail, 0)
 
     def single(x, carry, lp, kind):
@@ -412,6 +520,8 @@ def layer_loop(lead, periods, tail, pat: Pattern, x, carry, layer):
             x, carry = c
             lps, i = xs
             for j, (lp, kind) in enumerate(zip(lps, pat.period)):
+                if whole is not None and whole[j] is not None:
+                    lp = {**lp, "moe": {**lp["moe"], **whole[j], "stacked": i}}
                 base, per, offs = index_of[kind]
                 li = i if (base, per, offs[j]) == (0, 1, 0) else (
                     base + i * per + offs[j])
@@ -430,11 +540,19 @@ def layer_loop(lead, periods, tail, pat: Pattern, x, carry, layer):
 
 def run_layers(params, x, cache: LatentPagedCache, ctx: _Ctx):
     """Every layer of a patterned model over ``x``: :func:`layer_loop`
-    with the pools per kind as its carry. Returns ``(x, pools)``."""
+    with the pools per kind as its carry and the periods' expert stacks
+    kept whole. Returns ``(x, pools)``."""
+    sliced, whole = [], []
+    for lp in params["periods"]:
+        moe = lp.get("moe", {})
+        whole.append({k: moe[k] for k in EXPERT_STACKS if k in moe} or None)
+        sliced.append(lp if whole[-1] is None else {**lp, "moe": {
+            k: v for k, v in moe.items() if k not in EXPERT_STACKS}})
     return layer_loop(
-        params["lead"], params["periods"], params["tail"],
+        params["lead"], tuple(sliced), params["tail"],
         pattern_of(ctx.cfg), x, cache_pools(cache),
         lambda x, lp, kind, li, pools: _layer(x, lp, kind, li, pools, ctx),
+        whole=tuple(whole),
     )
 
 
@@ -505,7 +623,8 @@ def attention_only(lp, x, cache, cfg: ModelConfig, kernel: bool, kind: str,
 
 
 __all__ = [
-    "LatentPagedCache", "WINDOW_KERNEL", "attention_only", "cache_pools",
+    "FULL_KERNEL", "LatentPagedCache", "WINDOW_KERNEL", "attention_only",
+    "cache_pools",
     "decode_layers", "layer_loop", "ragged_layers", "run_layers",
     "unsupported", "with_pools",
 ]

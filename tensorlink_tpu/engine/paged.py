@@ -1852,7 +1852,7 @@ def _page_pools(cache) -> dict:
     operation moves. ``k`` / ``v`` and, quantized, their scale planes; a
     patterned model's pools per kind (engine/latent.py)."""
     if isinstance(cache, LatentPagedCache):
-        return {n: getattr(cache, n) for n in cache.POOLS}
+        return cache.pools()
     names = ("k", "v") if cache.k_scale is None else (
         "k", "v", "k_scale", "v_scale")
     return {n: getattr(cache, n) for n in names}

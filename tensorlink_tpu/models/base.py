@@ -9,6 +9,7 @@ serializes over the wire (ml/utils.py:569-660).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from typing import Any
 
@@ -19,6 +20,13 @@ import numpy as np
 from ..core import serialization
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 mscale ln(factor) + 1``."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
 @dataclass(frozen=True)
 class LatentAttn:
     """Sizes of one kind of latent (low-rank) attention layer: queries
@@ -27,6 +35,13 @@ class LatentAttn:
     values from the latent and ``rope_dim`` rotated values shared by all
     heads. What a position caches is the latent and the rotated key
     (``row_dim`` values), whatever the head count.
+
+    ``rope_scaling``: None, or YaRN's ``(factor, original length,
+    beta_fast, beta_slow, mscale, mscale_all_dim)``: the rotary
+    frequencies are interpolated by dimension
+    (models/transformer.py::yarn_inv_freq) and the softmax scale carries
+    ``yarn_mscale(factor, mscale_all_dim) ** 2`` (:attr:`softmax_scale`).
+    ``gate``: a sigmoid gate a head on the attention output.
 
     ``window``: keys ``t - window < s <= t`` (the token itself counts);
     None = causal. ``index_heads > 0``: a learned selector scores every
@@ -48,10 +63,27 @@ class LatentAttn:
     index_dim: int = 0
     index_rope_dim: int = 0
     index_topk: int = 0
+    rope_scaling: tuple | None = None
+    gate: bool = True
 
     @property
     def qk_dim(self) -> int:
         return self.nope_dim + self.rope_dim
+
+    @property
+    def temperature(self) -> float:
+        """The square of YaRN's ``mscale`` where the positions are scaled
+        (what the softmax scale carries beside ``qk_dim ** -0.5``), else
+        1."""
+        if self.rope_scaling is None:
+            return 1.0
+        factor, _, _, _, _, all_dim = self.rope_scaling
+        return yarn_mscale(factor, all_dim) ** 2
+
+    @property
+    def softmax_scale(self) -> float:
+        """What multiplies a head's scores."""
+        return self.qk_dim**-0.5 * self.temperature
 
     @property
     def row_dim(self) -> int:
@@ -70,7 +102,7 @@ class LatentAttn:
             + self.q_rank * self.n_heads * self.qk_dim
             + d * self.row_dim + self.kv_rank
             + self.kv_rank * self.n_heads * (self.nope_dim + self.v_dim)
-            + d * self.n_heads  # the headwise output gate
+            + (d * self.n_heads if self.gate else 0)  # the headwise gate
             + self.n_heads * self.v_dim * d
         )
         if self.index_heads:
@@ -172,10 +204,19 @@ class ModelConfig:
     n_shared_experts: int = 0
     # "softmax": top-k of the logits, softmax over the k (Mixtral).
     # "sigmoid": sigmoid scores, top-k of score + selection bias, the k
-    # scores normalised to sum 1 (``moe_norm_topk``), times ``moe_scale``
+    # scores normalised to sum 1 (``moe_norm_topk``), times ``moe_scale``.
+    # "softmax_all": softmax over every published expert, top-k of the
+    # scores (no bias), the k scores as weights (normalised only with
+    # ``moe_norm_topk``), times ``moe_scale``
     moe_router: str = "softmax"
     moe_norm_topk: bool = True
     moe_scale: float = 1.0
+    # group-limited routing: the published experts lie in ``moe_n_group``
+    # groups of consecutive experts, a group scores as its best expert,
+    # and a token picks its experts inside its ``moe_topk_group`` best
+    # groups only (0 = no limit)
+    moe_n_group: int = 0
+    moe_topk_group: int = 0
     # a chip's share of an expert group: the router scores all
     # ``n_experts``, this program holds and computes experts
     # ``experts_first .. experts_first + experts_held - 1`` (0 = all)
@@ -228,7 +269,7 @@ class ModelConfig:
             d["layer_kinds"] = tuple(d["layer_kinds"])
         if "latent" in d:
             d["latent"] = tuple(
-                (k, v if isinstance(v, LatentAttn) else LatentAttn(**v))
+                (k, v if isinstance(v, LatentAttn) else _latent_attn(v))
                 for k, v in d["latent"]
             )
         return cls(**d)
@@ -256,8 +297,9 @@ class ModelConfig:
     def _patterned_count(self, n_experts: int) -> int:
         d, v = self.d_model, self.vocab_size
         expert = 3 * d * self.moe_d_ff
+        bias = self.n_experts if self.moe_router == "sigmoid" else 0
         moe = (
-            d * self.n_experts + self.n_experts  # router + selection bias
+            d * self.n_experts + bias  # router + selection bias
             + (n_experts + self.n_shared_experts) * expert
         )
         n = 2 * v * d + d  # embedding, untied head, final norm
@@ -272,6 +314,14 @@ class ModelConfig:
         if not self.patterned:
             return self.param_count()
         return self._patterned_count(self.n_held)
+
+
+def _latent_attn(d: dict) -> LatentAttn:
+    # JSON has no tuples: a LatentAttn is hashed with its config
+    d = dict(d)
+    if d.get("rope_scaling") is not None:
+        d["rope_scaling"] = tuple(d["rope_scaling"])
+    return LatentAttn(**d)
 
 
 @jax.tree_util.register_dataclass

@@ -62,9 +62,14 @@ MOE = "tlink.moe"
 # one sync by engine/continuous.py)
 STEP_STATS = (
     "moe_rows_routed_local", "moe_rows_computed", "moe_rows_busiest_expert",
-    "moe_experts_touched", "moe_experts_held",
+    "moe_experts_touched", "moe_experts_held", "moe_rows_in_group",
+    "moe_rows_valid",
     "sparse_positions_kept", "sparse_positions_scored",
 )
+N_MOE_STATS = 7  # the expert layer's counts (:func:`moe_mlp`) lead
+# a layer's routed experts, stacked ``[E, ...]`` (``[periods, E, ...]``
+# where ``moe["stacked"]`` names the period: engine/latent.py::layer_loop)
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
 class Pattern(NamedTuple):
@@ -151,14 +156,16 @@ def _init_tree(key, cfg: ModelConfig, dt) -> dict:
             # seed scale: attention logits of order one, as trained
             # weights give, where the plain fan-in scale would give a
             # softmax of a handful of positions (std ~6 at the published
-            # sizes) that turns with every rounding
+            # sizes) that turns with every rounding; likewise what scaled
+            # positions put on the softmax scale
             "w_uq": dense(stack, la.q_rank, H * la.qk_dim,
-                          scale=la.q_rank**-0.5 / la.q_scale),
+                          scale=la.q_rank**-0.5 / la.q_scale
+                          / la.temperature),
             "w_dkv": dense(stack, d, la.row_dim),
             "kv_norm": ones(stack, la.kv_rank),
             "w_ukv": dense(stack, la.kv_rank, H * (la.nope_dim + la.v_dim),
                            scale=la.kv_rank**-0.5 / la.kv_scale),
-            "w_g": dense(stack, d, H),
+            **({"w_g": dense(stack, d, H)} if la.gate else {}),
             "wo": dense(stack, H * la.v_dim, d),
         }
         if la.index_heads:
@@ -194,7 +201,9 @@ def _init_tree(key, cfg: ModelConfig, dt) -> dict:
             "router": dense(stack, d, cfg.n_experts),
             # the selection bias moves which experts are chosen, never a
             # weight; seeded small so that it is not a no-op under test
-            "bias": draw(tuple(stack) + (cfg.n_experts,), 0.02, jnp.float32),
+            **({"bias": draw(tuple(stack) + (cfg.n_experts,), 0.02,
+                             jnp.float32)}
+               if cfg.moe_router == "sigmoid" else {}),
             "w_gate": dense(stack, E, d, f),
             "w_up": dense(stack, E, d, f),
             "w_down": dense(stack, E, f, d, scale=f**-0.5),
@@ -244,9 +253,10 @@ def _rope_prefix(x, cos, sin, n: int):
 
 
 def rope_by_kind(cfg: ModelConfig, positions: jax.Array) -> dict:
-    """cos/sin tables of each kind's own theta at ``positions`` [B, T]."""
+    """cos/sin tables of each kind's own theta (and scaling) at
+    ``positions`` [B, T]."""
     return {
-        k: rope_tables(positions, la.rope_dim, la.rope_theta)
+        k: rope_tables(positions, la.rope_dim, la.rope_theta, la.rope_scaling)
         for k, la in cfg.latent
     }
 
@@ -255,9 +265,9 @@ def latent_qkv(h, ap: dict, la: LatentAttn, eps: float, cos, sin) -> dict:
     """The projections of one latent attention layer over ``h`` ``[B, T,
     d]``: ``q_n`` / ``q_r`` ``[B, T, H, nope | rope]`` (rotated), ``row``
     ``[B, T, pool_dim]`` (what the position caches: latent, rotated key,
-    zero lanes up to a whole lane row), ``gate`` ``[B, T, H]`` (float32)
-    and, with an indexer, ``qi`` ``[B, T, Hi, Di]``, ``ki`` ``[B, T, Di]``
-    and ``wi`` ``[B, T, Hi]`` (float32)."""
+    zero lanes up to a whole lane row), with a gate ``gate`` ``[B, T, H]``
+    (float32) and, with an indexer, ``qi`` ``[B, T, Hi, Di]``, ``ki`` ``[B,
+    T, Di]`` and ``wi`` ``[B, T, Hi]`` (float32)."""
     B, T = h.shape[:2]
     H = la.n_heads
     cq = _rms(_mm(h, ap["w_dq"]), ap["q_norm"], eps, la.q_scale)
@@ -270,8 +280,9 @@ def latent_qkv(h, ap: dict, la: LatentAttn, eps: float, cos, sin) -> dict:
         "q_n": q[..., :la.nope_dim],
         "q_r": apply_rope(q[..., la.nope_dim:], cos, sin),
         "row": jnp.concatenate([c, k_r, pad], axis=-1),
-        "gate": jax.nn.sigmoid(_mm(h, ap["w_g"]).astype(jnp.float32)),
     }
+    if "w_g" in ap:
+        out["gate"] = jax.nn.sigmoid(_mm(h, ap["w_g"]).astype(jnp.float32))
     if la.index_heads:
         qi = _mm(cq, ap["w_iq"]).reshape(B, T, la.index_heads, la.index_dim)
         ki = _layernorm(_mm(h, ap["w_ik"]), ap["ik_norm"], eps)
@@ -316,7 +327,7 @@ def attend_materialised(q_n, q_r, rows, mask, ap, la: LatentAttn):
     ) + jnp.einsum(
         "brhe,bke->bhrk", q_r, k_r, preferred_element_type=jnp.float32
     )
-    p = _softmax_rows(sc * la.qk_dim**-0.5, mask[:, None])
+    p = _softmax_rows(sc * la.softmax_scale, mask[:, None])
     return jnp.einsum("bhrk,bkhv->brhv", p.astype(v.dtype), v)
 
 
@@ -345,7 +356,7 @@ def attend_absorbed(q_n, q_r, rows, mask, ap, la: LatentAttn):
     sc = jnp.einsum(
         "rhw,rkw->rhk", qa, rows, preferred_element_type=jnp.float32
     )
-    p = _softmax_rows(sc * la.qk_dim**-0.5, mask[:, None])
+    p = _softmax_rows(sc * la.softmax_scale, mask[:, None])
     ctx = jnp.einsum("rhk,rkw->rhw", p.astype(rows.dtype), rows)
     return absorbed_output(ctx, ap, la)
 
@@ -481,37 +492,63 @@ def gated_mlp(h, p: dict):
                p["w_down"])
 
 
+def _group_limit(sc, n_group: int, topk_group: int):
+    """``sc`` ``[N, E]`` with the scores outside each row's ``topk_group``
+    best groups set to 0, and the kept groups ``[N, n_group]`` (bool). A
+    group is ``E / n_group`` consecutive experts and scores as its best
+    one."""
+    N, E = sc.shape
+    best = sc.reshape(N, n_group, E // n_group).max(-1)
+    _, gi = top_k_few(best, topk_group)
+    kept = (gi[..., None] == jnp.arange(n_group)).any(1)  # [N, n_group]
+    return jnp.where(jnp.repeat(kept, E // n_group, axis=1), sc, 0.0), kept
+
+
 def route(h, mp: dict, cfg: ModelConfig):
     """Which experts each row of ``h`` ``[N, d]`` goes to and with what
     weight: ``(experts [N, K] int32 over the published experts, weights
     [N, K] float32)``."""
+    return _route(h, mp, cfg)[:2]
+
+
+def _route(h, mp: dict, cfg: ModelConfig):
+    """:func:`route` and ``kept`` ``[N, n_group]``, the groups a row may
+    pick from (None where routing has no group limit)."""
     # float32 scores: a product rounded to the activations' dtype first
     # ties experts that a float32 router tells apart
     logits = jnp.matmul(
         h, mp["router"], preferred_element_type=jnp.float32
     )
     K = cfg.n_experts_per_tok
+    if cfg.moe_router == "softmax":
+        topw, topi = top_k_few(logits, K)
+        return topi, jax.nn.softmax(topw, axis=-1), None
+    kept = None
     if cfg.moe_router == "sigmoid":
         sc = jax.nn.sigmoid(logits)
         _, topi = top_k_few(sc + mp["bias"].astype(jnp.float32), K)
         topw = jnp.take_along_axis(sc, topi, axis=-1)
-        if cfg.moe_norm_topk:
-            topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
-        return topi, topw * cfg.moe_scale
-    topw, topi = top_k_few(logits, K)
-    return topi, jax.nn.softmax(topw, axis=-1)
+    else:  # "softmax_all"
+        sc = jax.nn.softmax(logits, axis=-1)
+        if cfg.moe_n_group:
+            sc, kept = _group_limit(sc, cfg.moe_n_group, cfg.moe_topk_group)
+        topw, topi = top_k_few(sc, K)
+    if cfg.moe_norm_topk:
+        topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
+    return topi, topw * cfg.moe_scale, kept
 
 
 def moe_mlp(h, mp: dict, cfg: ModelConfig, valid):
     """The expert layer over rows ``h`` ``[N, d]`` (``valid`` ``[N]``:
     rows that carry a token; the others are routed nowhere): the shared
     expert plus this program's share of the routed ones. Returns ``(y [N,
-    d], stats)`` with ``stats`` the first five of :data:`STEP_STATS`."""
+    d], stats)`` with ``stats`` the first :data:`N_MOE_STATS` of
+    :data:`STEP_STATS`."""
     N, d = h.shape
     K = cfg.n_experts_per_tok
     E = cfg.n_held
     T = min(MOE_TILE, N)
-    topi, topw = route(h, mp, cfg)
+    topi, topw, kept = _route(h, mp, cfg)
     local = (
         (topi >= cfg.experts_first) & (topi < cfg.experts_first + E)
         & valid[:, None]
@@ -544,9 +581,10 @@ def moe_mlp(h, mp: dict, cfg: ModelConfig, valid):
         jnp.searchsorted(tile_end, jnp.arange(max_tiles), side="right"), E - 1
     )
     hp = jnp.concatenate([h, jnp.zeros((T, d), h.dtype)])
+    period = (mp["stacked"],) if "stacked" in mp else ()
 
     def tile(t, out):
-        e = tile_expert[t]
+        e = period + (tile_expert[t],)
         rows = lax.dynamic_slice_in_dim(slot_row, t * T, T)
         w = lax.dynamic_slice_in_dim(slot_w, t * T, T)
         x = hp[rows]
@@ -563,15 +601,26 @@ def moe_mlp(h, mp: dict, cfg: ModelConfig, valid):
     )[:N]
     if "shared" in mp:
         out = out + gated_mlp(h, mp["shared"]).astype(jnp.float32)
+    reach = valid
+    if kept is not None:
+        # a held expert's group is one this row kept: without a group
+        # limit every row can reach this program's experts
+        per = cfg.n_experts // cfg.moe_n_group
+        mine = jnp.arange(cfg.moe_n_group)
+        mine = (mine >= cfg.experts_first // per) & (
+            mine <= (cfg.experts_first + E - 1) // per)
+        reach = valid & (kept & mine).any(-1)
     stats = jnp.stack([
         counts.sum(), n_tiles * T, counts.max(), (counts > 0).sum(),
-        jnp.asarray(E, jnp.int32),
+        jnp.asarray(E, jnp.int32), reach.sum(), valid.sum(),
     ]).astype(jnp.int32)
     return out.astype(h.dtype), stats
 
 
 __all__ = [
-    "INDEX_SELECT", "LATENT_ATTN", "MOE", "STEP_STATS", "WINDOW_ATTN",
+    "EXPERT_STACKS", "INDEX_SELECT", "LATENT_ATTN", "MOE", "N_MOE_STATS",
+    "STEP_STATS",
+    "WINDOW_ATTN",
     "Pattern", "absorbed_output", "absorbed_query", "attend_absorbed",
     "attend_materialised", "gated_mlp", "index_scores", "init_params",
     "kind_counts", "latent_qkv", "moe_mlp", "pattern_of", "rope_by_kind",

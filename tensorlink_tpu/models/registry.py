@@ -137,9 +137,7 @@ def _dots3_note(d: dict) -> ModelConfig:
     layer's normed input times each head's output; the window counts the
     token itself; rope is on the LAST ``qk_rope_head_dim`` dims of q/k.
 
-    A chip's share of an expert group: ``n_routed_experts`` is what this
-    program holds, ``published.n_routed_experts`` what the router scores,
-    ``expert_group.first_expert`` where the held ones start."""
+    A chip's share of an expert group: :func:`_expert_share`."""
     hidden = d["hidden_size"]
     rescale = bool(d.get("apply_mla_qkv_lora_rescale", False))
 
@@ -180,8 +178,6 @@ def _dots3_note(d: dict) -> ModelConfig:
             "dots3_note: only the sigmoid router without expert groups "
             "is implemented"
         )
-    held = d["n_routed_experts"]
-    published = (d.get("published") or {}).get("n_routed_experts", held)
     return ModelConfig(
         family="dots3_note",
         vocab_size=d["vocab_size"],
@@ -198,15 +194,108 @@ def _dots3_note(d: dict) -> ModelConfig:
         layer_kinds=kinds,
         latent=(("full", full), ("sliding", sliding)),
         n_dense_layers=d.get("first_k_dense_replace", 0),
-        n_experts=published,
         n_experts_per_tok=d["num_experts_per_tok"],
         moe_d_ff=d["moe_intermediate_size"],
         n_shared_experts=d.get("n_shared_experts", 0),
         moe_router="sigmoid",
         moe_norm_topk=bool(d.get("norm_topk_prob", True)),
         moe_scale=float(d.get("routed_scaling_factor", 1.0)),
+        **_expert_share(d),
+    )
+
+
+def _expert_share(d: dict) -> dict:
+    """A chip's share of an expert group, as a configuration states it:
+    ``n_routed_experts`` is what this program holds,
+    ``published.n_routed_experts`` what the router scores,
+    ``expert_group.first_expert`` where the held ones start."""
+    held = d["n_routed_experts"]
+    published = (d.get("published") or {}).get("n_routed_experts", held)
+    return dict(
+        n_experts=published,
         experts_first=(d.get("expert_group") or {}).get("first_expert", 0),
         experts_held=held if held != published else 0,
+    )
+
+
+@register_family("deepseek_v2")
+def _deepseek_v2(d: dict) -> ModelConfig:
+    """DeepSeek-V2: latent attention over the whole context in every layer
+    (no window, no selector, no gate, no rescale of the latents), YaRN
+    positions with the ``mscale`` softmax scale, leading dense layers,
+    then softmax-scored experts with group-limited greedy routing beside
+    the shared ones. The published checkpoint stores each head's rotary
+    dims interleaved; this reader rotates halves of the stored order (a
+    permutation of the columns of the two projections that feed them;
+    docs/SERVING.md "Layers of more than one kind"). A chip's share of an
+    expert group:
+    :func:`_expert_share`."""
+    if d.get("scoring_func", "softmax") != "softmax":
+        raise ValueError(
+            f"deepseek_v2: scoring_func {d.get('scoring_func')!r} is not "
+            "built (softmax over the published experts is)"
+        )
+    method = d.get("topk_method", "greedy")
+    if method not in ("greedy", "group_limited_greedy"):
+        raise ValueError(
+            f"deepseek_v2: topk_method {method!r} is not built (greedy and "
+            "group_limited_greedy are)"
+        )
+    if d.get("moe_layer_freq", 1) != 1:
+        raise ValueError(
+            "deepseek_v2: moe_layer_freq other than 1 is not built (every "
+            "layer after the leading dense ones routes)"
+        )
+    if not d.get("q_lora_rank"):
+        raise ValueError(
+            "deepseek_v2: queries without a latent (q_lora_rank null, the "
+            "Lite models) are not built"
+        )
+    rs = d.get("rope_scaling")
+    scaling = None
+    if rs:
+        if rs.get("type", rs.get("rope_type")) != "yarn":
+            raise ValueError(
+                f"deepseek_v2: rope_scaling {rs!r} is not built (yarn is)"
+            )
+        scaling = (
+            float(rs["factor"]),
+            float(rs["original_max_position_embeddings"]),
+            float(rs.get("beta_fast", 32)), float(rs.get("beta_slow", 1)),
+            float(rs.get("mscale", 1)), float(rs.get("mscale_all_dim", 0)),
+        )
+    hidden = d["hidden_size"]
+    full = LatentAttn(
+        n_heads=d["num_attention_heads"],
+        q_rank=d["q_lora_rank"], kv_rank=d["kv_lora_rank"],
+        nope_dim=d["qk_nope_head_dim"], rope_dim=d["qk_rope_head_dim"],
+        v_dim=d["v_head_dim"], rope_theta=float(d.get("rope_theta", 1e4)),
+        rope_scaling=scaling, gate=False,
+    )
+    grouped = method == "group_limited_greedy"
+    return ModelConfig(
+        family="deepseek_v2",
+        vocab_size=d["vocab_size"],
+        d_model=hidden,
+        n_layers=d["num_hidden_layers"],
+        n_heads=full.n_heads, n_kv_heads=1, head_dim=full.qk_dim,
+        d_ff=d["intermediate_size"],
+        max_seq_len=d.get("max_position_embeddings", 4096),
+        norm_eps=d.get("rms_norm_eps", 1e-6),
+        rope_theta=full.rope_theta,
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        layer_kinds=("full",) * d["num_hidden_layers"],
+        latent=(("full", full),),
+        n_dense_layers=d.get("first_k_dense_replace", 0),
+        n_experts_per_tok=d["num_experts_per_tok"],
+        moe_d_ff=d["moe_intermediate_size"],
+        n_shared_experts=d.get("n_shared_experts") or 0,
+        moe_router="softmax_all",
+        moe_norm_topk=bool(d.get("norm_topk_prob", False)),
+        moe_scale=float(d.get("routed_scaling_factor", 1.0)),
+        moe_n_group=int(d.get("n_group") or 0) if grouped else 0,
+        moe_topk_group=int(d.get("topk_group") or 0) if grouped else 0,
+        **_expert_share(d),
     )
 
 

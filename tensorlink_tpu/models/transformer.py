@@ -18,10 +18,13 @@ ml/worker.py:297-357):
 
 from __future__ import annotations
 
+import math
 import os as _os
 from functools import partial
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .base import KVCache, ModelConfig
@@ -192,13 +195,51 @@ def _rms_head_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (out * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_tables(positions: jax.Array, head_dim: int, theta: float):
-    """cos/sin tables ``[B, T, head_dim]`` in the HF half-split convention
-    (rotate_half): frequencies repeat over the two halves."""
+def yarn_inv_freq(head_dim: int, theta: float, scaling: tuple):
+    """YaRN's rotary frequencies and amplitude for ``scaling`` =
+    ``(factor, original length, beta_fast, beta_slow, mscale,
+    mscale_all_dim)``: ``(inv_freq [head_dim / 2] float32, amplitude)``.
+    A dimension that turns more than ``beta_fast`` times over the
+    original length keeps its frequency, one that turns less than
+    ``beta_slow`` times is interpolated (divided by ``factor``), and a
+    linear ramp lies between; cos and sin are multiplied by
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``.
+    Shapes are static: numpy in float64, rounded once."""
+    from .base import yarn_mscale
+
+    factor, orig, beta_fast, beta_slow, mscale, all_dim = scaling
     half = head_dim // 2
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freq = theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def corr(turns):  # the dimension that turns ``turns`` times in ``orig``
+        return head_dim * math.log(orig / (2 * math.pi * turns)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), head_dim - 1)
+    ramp = np.clip(
+        (np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    inv_freq = freq / factor * ramp + freq * (1.0 - ramp)
+    amp = yarn_mscale(factor, mscale) / yarn_mscale(factor, all_dim)
+    return inv_freq.astype(np.float32), float(amp)
+
+
+def rope_tables(positions: jax.Array, head_dim: int, theta: float,
+                scaling: tuple | None = None):
+    """cos/sin tables ``[B, T, head_dim]`` in the HF half-split convention
+    (rotate_half): frequencies repeat over the two halves. ``scaling``:
+    YaRN's numbers (:func:`yarn_inv_freq`)."""
+    half = head_dim // 2
+    amp = 1.0
+    if scaling is None:
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    else:
+        inv_freq, amp = yarn_inv_freq(head_dim, theta, scaling)
     ang = positions.astype(jnp.float32)[..., None] * inv_freq  # [B, T, half]
     ang = jnp.concatenate([ang, ang], axis=-1)
+    if amp != 1.0:
+        return jnp.cos(ang) * amp, jnp.sin(ang) * amp
     return jnp.cos(ang), jnp.sin(ang)
 
 

@@ -56,8 +56,15 @@ pages lane-padded (:func:`_lane_pad`: a quarter of the bytes in HBM,
 half on the way into the kernel). The ``_ref`` twins dequantize at the
 same gather, pinned against the kernels in tests/test_ops.py.
 
+A latent cache (engine/latent.py) is walked by the same body: one "kv
+head" whose row is key and value both (``v_pages=None``), read by every
+query head of the model. ``window=`` starts the walk at a window's first
+block; ``latent=`` is the walk of 128 heads over a whole context, where
+a slot's prefill block is 16,384 query rows: larger tiles, bf16 operands,
+groups of query rows over a third grid axis (:func:`_paged_walk`).
+
 Every ``pl.pallas_call`` names its kernel (``name=``) after its entry
-point: a profiler trace's reduction finds the kernels by these names, so
+point (a caller of a latent cache passes its own): a profiler trace's reduction finds the kernels by these names, so
 renaming a Python function must not rename them
 (tests/test_step_scopes.py).
 """
@@ -463,28 +470,29 @@ _ROW_TILE = 16
 # What one grid step's blocks, buffers and f32 tiles may take of VMEM by
 # _heads_per_block's estimate (the scoped default is 16 MiB on a v5e).
 _VMEM_BUDGET = 12 * 2**20
+_LATENT_VMEM = 64 * 2**20  # the limit a latent walk asks for (_paged_walk)
 
 
-def _pages_per_block(page: int, n_pp: int) -> int:
+def _pages_per_block(page: int, n_pp: int, tile: int = _MIN_TILE) -> int:
     """Pages a KV block of the walk holds: enough that a score tile spans
-    ``_MIN_TILE`` key positions (8 pages of 16), never more than a slot
-    has."""
-    return max(1, min(-(-_MIN_TILE // page), n_pp))
+    ``tile`` key positions (8 pages of 16 at ``_MIN_TILE``), never more
+    than a slot has."""
+    return max(1, min(-(-tile // page), n_pp))
 
 
-def _positions_per_row_block(C: int, G: int) -> int:
+def _positions_per_row_block(C: int, G: int, rows: int = _MIN_TILE) -> int:
     """Chunk positions a row block holds: whole positions (``G`` query
     rows each) dividing the chunk, so that every row block is whole. The
-    whole chunk if it is at most ``_MIN_TILE`` rows (one block, sliced
+    whole chunk if it is at most ``rows`` rows (one block, sliced
     statically: any height will do). Else the largest divisor whose
-    ``cb·G`` rows are whole ``_ROW_TILE`` tiles within ``_MIN_TILE`` rows,
+    ``cb·G`` rows are whole ``_ROW_TILE`` tiles within ``rows`` rows,
     or failing that (a group size like 9) the smallest such divisor
     above it."""
-    if C * G <= _MIN_TILE:
+    if C * G <= rows:
         return C
     whole = [cb for cb in range(1, C + 1)
              if C % cb == 0 and cb * G % _ROW_TILE == 0]
-    within = [cb for cb in whole if cb * G <= _MIN_TILE]
+    within = [cb for cb in whole if cb * G <= rows]
     return max(within) if within else min(whole, default=C)
 
 
@@ -593,11 +601,22 @@ def _paged_walk_kernel(
     packed: bool,
     window: int | None = None,
     shared_kv: bool = False,
+    v_width: int | None = None,
+    row_groups: bool = False,
 ):
     """One slot and one block of ``hb`` kv heads of the walk (grid
     ``(slot, head block)``): row blocks of ``cb`` chunk positions up to
     the slot's last valid query, each against KV blocks of ``ppb`` pages
     up to its own causal limit, double-buffered.
+
+    ``row_groups``: the grid has a third axis over groups of whole row
+    blocks, and this step holds one group's query rows (a slot's rows do
+    not fit VMEM at once where 128 heads share a row: 16,384 rows of 640).
+    ``v_width`` (with ``shared_kv``; a latent cache's walk): the value is
+    the key row's first ``v_width`` columns, and so wide is the output;
+    the operands go to the MXU as they are stored (bf16), products summed
+    in float32 and the scale applied to the scores, as the XLA fallback's
+    einsums do. Else everything is float32.
 
     ``window``: a query at ``t`` attends ``t - window < s <= t`` and the
     walk starts at the KV block that holds its row block's first such
@@ -618,7 +637,9 @@ def _paged_walk_kernel(
     hblk = pl.program_id(1)
     start = start_ref[s]
     nv = nv_ref[s]
-    Hkv, CG, hd = q_ref.shape[1:]  # Hkv: the heads of this block
+    Hkv, CG, hd = q_ref.shape[1:]  # Hkv: the heads, CG: the rows, here
+    vw = hd if v_width is None else v_width
+    native_dot = v_width is not None
     # the block's heads of a page: all of it where one block holds them
     # all, and contiguous in the pool either way
     heads = () if Hkv == k_hbm.shape[2] else (pl.ds(hblk * Hkv, Hkv),)
@@ -675,20 +696,26 @@ def _paged_walk_kernel(
 
     q_row = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
     k_col = jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
-    k_row = jax.lax.broadcasted_iota(jnp.int32, (T, hd), 0)
+    k_row = jax.lax.broadcasted_iota(jnp.int32, (T, vw), 0)
     k_lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
 
-    def rows_of(rb):
+    # the first row block of this step's rows among the slot's
+    rb0 = pl.program_id(2) * (CG // R) if row_groups else None
+
+    def rows_of(lb):
         # one row block is the whole query block, whatever its height; a
         # dynamic offset lands on a tile edge (_positions_per_row_block)
         if CG == R:
             return slice(None)
-        return pl.ds(pl.multiple_of(rb * R, R), R)
+        return pl.ds(pl.multiple_of(lb * R, R), R)
 
-    def row_block(rb, carry):
+    def row_block(lb, carry):
+        rb = lb if rb0 is None else rb0 + lb
         _, kv_len, n_pages, n_kb = trips(rb)
-        rows = rows_of(rb)
-        q = q_ref[0, :, rows, :].astype(jnp.float32) * scale  # [Hkv, R, hd]
+        rows = rows_of(lb)
+        q = q_ref[0, :, rows, :]  # [Hkv, R, hd]
+        if not native_dot:
+            q = q.astype(jnp.float32) * scale
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -712,19 +739,29 @@ def _paged_walk_kernel(
             v = k if shared_kv else vbuf[buf, :, :, :, pl.ds(0, hdk)]
             if packed:
                 k, v = _unpack4(k), _unpack4(v)
-            else:
+            elif not native_dot:
                 k, v = k.astype(jnp.float32), v.astype(jnp.float32)
             k = k.reshape(Hkv, T, hd)
-            v = v.reshape(Hkv, T, hd)
+            v = v.reshape(Hkv, T, hd)[..., :vw]
             # positions of the buffer past kv_len were not copied, or
             # lie past the span inside a copied page: whatever they hold
             # (NaN included) must not reach p @ v as 0 × NaN
             left = kv_len - kb * T  # live key positions of this block
-            v = jnp.where((k_row < left)[None], v, 0.0)
+            if native_dot:  # only a row block's last KV block has any
+                v = jax.lax.cond(
+                    left < T,
+                    lambda v: jnp.where((k_row < left)[None], v,
+                                        jnp.zeros_like(v)),
+                    lambda v: v, v,
+                )
+            else:
+                v = jnp.where((k_row < left)[None], v, 0.0)
             sc = jax.lax.dot_general(
                 q, k, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )  # [Hkv, R, T]
+            if native_dot:
+                sc = sc * scale
             if quantized:
                 # the dequant rides the score columns and the softmax
                 # weights: per-position scales stay on the lane axis
@@ -746,7 +783,8 @@ def _paged_walk_kernel(
                 p, axis=2, keepdims=True
             )
             acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p * vs[:, None, :] if quantized else p, v,
+                p * vs[:, None, :] if quantized
+                else p.astype(v.dtype) if native_dot else p, v,
                 (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
@@ -761,11 +799,13 @@ def _paged_walk_kernel(
         ).astype(o_ref.dtype)
         return carry
 
-    def dead_row_block(rb, carry):
-        o_ref[0, :, rows_of(rb), :] = jnp.zeros((Hkv, R, hd), o_ref.dtype)
+    def dead_row_block(lb, carry):
+        o_ref[0, :, rows_of(lb), :] = jnp.zeros((Hkv, R, vw), o_ref.dtype)
         return carry
 
     n_rb = trips(0)[0]
+    if rb0 is not None:  # the slot's live row blocks that lie in this group
+        n_rb = jnp.clip(n_rb - rb0, 0, CG // R)
     jax.lax.fori_loop(0, n_rb, row_block, 0)
     jax.lax.fori_loop(n_rb, CG // R, dead_row_block, 0)
 
@@ -787,12 +827,23 @@ def _paged_walk(
     interpret: bool,
     window: int | None = None,
     shared_kv: bool = False,  # values are the key rows: v_pages unused
+    latent: tuple | None = None,
 ) -> jax.Array:
     """The ``pl.pallas_call`` of the walk, named ``name``; returns
     ``[S, Hkv, C·G, hd]``. Block sizes come from the shapes: KV blocks of
     :func:`_pages_per_block` pages, row blocks of
     :func:`_positions_per_row_block` chunk positions, grid steps of
     :func:`_heads_per_block` kv heads.
+
+    ``latent`` = ``(v_width, kv_tile, rows, group_rows)`` is the walk of a
+    latent cache that one row serves many heads of (``shared_kv``): the
+    output is the softmax-weighted first ``v_width`` columns of the rows
+    (``[S, 1, C·G, v_width]``), the operands reach the MXU in the stored
+    dtype, a KV block spans ``kv_tile`` positions and a row block ``rows``
+    query rows (at 128 x 128 a walk of 16 slots x 12,800 positions took
+    1.59 ms on a v5e, at 512 x 512 1.10, a full prefill block 12.4 against
+    4.3: PERF.md section 6, PR 34), and the grid gets a third axis over
+    groups of ``group_rows`` query rows where a slot's rows are more.
 
     The pools are addressed, not loaded: the kernel copies page
     ``(layer, page)`` out of the stack ``[L, P, ...]`` itself, so a layer
@@ -816,24 +867,42 @@ def _paged_walk(
     S, Hkv, CG, hd = qg.shape
     page, hdk = k_pages.shape[3:]  # hdk = hd // 2 for packed int4
     n_pp = block_tables.shape[1]
-    ppb = _pages_per_block(page, n_pp)
-    cb = _positions_per_row_block(CG // G, G)
+    vw, kv_tile, max_rows, group_rows = latent or (
+        hd, _MIN_TILE, _MIN_TILE, CG)
+    ppb = _pages_per_block(page, n_pp, kv_tile)
+    cb = _positions_per_row_block(CG // G, G, max_rows)
+    # rows of a grid step: the slot's, or one group of whole row blocks
+    QR = CG if CG <= group_rows else group_rows // (cb * G) * (cb * G)
+    if CG % QR:
+        raise ValueError(f"{CG} query rows in groups of {QR}")
     quantized = k_scale is not None
     kernel = functools.partial(
         _paged_walk_kernel, scale=scale, page=page, ppb=ppb, G=G, cb=cb,
         quantized=quantized, packed=quantized and hdk * 2 == hd,
         **({"window": window} if window is not None else {}),
         **({"shared_kv": True} if shared_kv else {}),
+        **({"v_width": vw} if latent else {}),
+        **({"row_groups": True} if QR < CG else {}),
     )
     args = [qg, _lane_pad(k_pages)]
     if not shared_kv:
         args.append(_lane_pad(v_pages))
     hb = _heads_per_block(
-        Hkv, CG, cb * G, ppb * page, hd, qg.dtype.itemsize,
+        Hkv, QR, cb * G, ppb * page, hd, qg.dtype.itemsize,
         args[1].shape[-1] * args[1].dtype.itemsize,
     )
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    q_spec = pl.BlockSpec((1, hb, CG, hd), lambda s, h, *_: (s, h, 0, 0))
+    if QR < CG:
+        grid = (S, Hkv // hb, CG // QR)
+        q_spec = pl.BlockSpec(
+            (1, hb, QR, hd), lambda s, h, g, *_: (s, h, g, 0))
+        o_spec = pl.BlockSpec(
+            (1, hb, QR, vw), lambda s, h, g, *_: (s, h, g, 0))
+    else:
+        grid = (S, Hkv // hb)
+        q_spec = pl.BlockSpec((1, hb, CG, hd), lambda s, h, *_: (s, h, 0, 0))
+        o_spec = q_spec if vw == hd else pl.BlockSpec(
+            (1, hb, CG, vw), lambda s, h, *_: (s, h, 0, 0))
     # two buffers of one KV block each, laid out so that page p of a
     # block lands at [:, p]: the same (page, hdk) trailing tile as the
     # pool, and a leading-dim merge away from [Hkv, T, hd]
@@ -850,21 +919,25 @@ def _paged_walk(
             (2, 4 if quantized else 1 if shared_kv else 2)),
         pltpu.VMEM((hb, cb * G, 1), jnp.float32),  # running max
         pltpu.VMEM((hb, cb * G, 1), jnp.float32),  # running denominator
-        pltpu.VMEM((hb, cb * G, hd), jnp.float32),  # accumulator
+        pltpu.VMEM((hb, cb * G, vw), jnp.float32),  # accumulator
     ]
     return pl.pallas_call(
         kernel,
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(S, Hkv // hb),
+            grid=grid,
             in_specs=[q_spec] + [in_hbm] * (len(args) - 1),
-            out_specs=q_spec,
+            out_specs=o_spec,
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape[:-1] + (vw,), qg.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("parallel",) * len(grid),
+            # a latent walk's tiles outgrow the scoped default (16 MiB of
+            # a v5e's 128): [512, 512] float32 scores three times over
+            # beside 2,048 query rows of 640, in and out, twice buffered
+            **({"vmem_limit_bytes": _LATENT_VMEM} if latent else {}),
         ),
         interpret=interpret,
     )(
@@ -877,11 +950,12 @@ def _paged_walk(
 
 
 # tlint: hot-path
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("scale", "interpret", "name", "latent"))
 def ragged_paged_attention(
     q: jax.Array,  # [S, C, Hq, hd]
     k_pages: jax.Array,  # [P, Hkv, page, hd]
-    v_pages: jax.Array,  # [P, Hkv, page, hd]
+    v_pages: jax.Array | None,  # [P, Hkv, page, hd]; None: the key rows
     block_tables: jax.Array,  # int32 [S, pages_per_slot]
     starts: jax.Array,  # int32 [S]
     n_valid: jax.Array,  # int32 [S]
@@ -891,8 +965,11 @@ def ragged_paged_attention(
     k_scale: jax.Array | None = None,  # f32 [P, Hkv, page] — int8 pages
     v_scale: jax.Array | None = None,
     layer: jax.Array | None = None,  # int32 scalar — see below
+    name: str | None = None,  # the pallas_call's, as a trace shows it
+    latent: tuple | None = None,  # a latent cache's walk (_paged_walk)
 ) -> jax.Array:
-    """Ragged paged attention (TPU); returns ``[S, C, Hq, hd]``.
+    """Ragged paged attention (TPU); returns ``[S, C, Hq, hd]``
+    (``latent``: ``[S, C, Hq, v_width]``).
 
     With ``layer``, the pools (and scale planes) are every layer's,
     stacked ``[L, P, ...]`` as the engine's ``PagedKVCache`` holds them,
@@ -925,19 +1002,22 @@ def ragged_paged_attention(
         .reshape(S, Hkv, C * G, hd)
     )
     out = _paged_walk(
-        "ragged_paged_attention", qg, k_pages, v_pages, block_tables,
-        starts, n_valid, k_scale, v_scale, layer, G=G, scale=scale,
-        interpret=interpret,
+        "ragged_paged_attention" if name is None else name, qg, k_pages,
+        v_pages, block_tables, starts, n_valid, k_scale, v_scale, layer,
+        G=G, scale=scale, interpret=interpret,
+        **({"shared_kv": True} if v_pages is None else {}),
+        **({"latent": latent} if latent else {}),
     )
     return (
-        out.reshape(S, Hkv, C, G, hd)
+        out.reshape(S, Hkv, C, G, out.shape[-1])
         .transpose(0, 2, 1, 3, 4)
-        .reshape(S, C, Hq, hd)
+        .reshape(S, C, Hq, out.shape[-1])
     )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "interpret", "window", "name"))
+    jax.jit,
+    static_argnames=("scale", "interpret", "window", "name", "latent"))
 def paged_attention(
     q: jax.Array,  # [S, Hq, hd]
     k_pages: jax.Array,  # [P, Hkv, page, hd]
@@ -952,8 +1032,10 @@ def paged_attention(
     layer: jax.Array | None = None,  # int32 scalar: pools are [L, P, ...]
     window: int | None = None,  # keys length - window <= s < length
     name: str | None = None,  # the pallas_call's, as a trace shows it
+    latent: tuple | None = None,  # a latent cache's walk (_paged_walk)
 ) -> jax.Array:
-    """Paged decode attention; returns ``[S, Hq, hd]``.
+    """Paged decode attention; returns ``[S, Hq, hd]`` (``latent``: ``[S,
+    Hq, v_width]``).
 
     The same live-span walk as :func:`ragged_paged_attention` with one
     query position a slot, at ``lengths - 1``: grid ``(slot, kv-head
@@ -978,8 +1060,9 @@ def paged_attention(
         jnp.minimum(lengths, 1),
         k_scale, v_scale, layer, G=Hq // Hkv, scale=scale,
         interpret=interpret, window=window, shared_kv=v_pages is None,
+        **({"latent": latent} if latent else {}),
     )
-    return out.reshape(S, Hq, hd)
+    return out.reshape(S, Hq, out.shape[-1])
 
 
 __all__ = [

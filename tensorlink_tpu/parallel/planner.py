@@ -32,6 +32,7 @@ from ..models.base import ModelConfig
 from ..models.transformer import cache_specs, partition_specs
 
 MAX_STAGES = 6  # reference ml/validator.py:427-430
+PREFILL_BLOCK = 128  # rows a slot's prefill block holds (MLConfig.prefill_chunk)
 # tlint: disable=TL006(read-only constant table — never mutated at runtime)
 _DTYPE_BYTES = {"bfloat16": 2, "float32": 4, "float16": 2, "float8_e4m3fn": 1}
 
@@ -117,15 +118,20 @@ class MemoryEstimate:
 
     @classmethod
     def _patterned(cls, cfg, batch, seq_len, training, pb):
-        """A model with layers of more than one kind (models/latent.py),
-        as the slot engine serves it: the experts this program HOLDS, not
-        the experts published; a cached position is one latent row a
-        layer (and a selector key on the full layers), whatever the head
-        count; no pass holds scores of a whole context against itself
-        (full layers attend ``index_topk`` selected rows, sliding layers
-        their window), so activations are the residual stream and one
-        layer's projections. This is what lets a 16k context be planned:
-        the dense-cache estimate above reads 68 GB for its scores alone."""
+        """A model whose layers are named by kind (models/latent.py), as
+        the slot engine serves it: the experts this program HOLDS, not the
+        experts published; a cached position is one latent row a layer
+        (and a selector key on the full layers that have a selector),
+        whatever the head count. No pass holds scores of a whole context
+        against itself: sliding layers attend their window, full layers
+        with a selector ``index_topk`` selected rows, and full layers
+        without one walk the live span a block of scores at a time inside
+        the page walk (engine/latent.py::_walk_attend), for which a pass
+        holds the absorbed queries and the walk's output of one prefill
+        block: ``[slots, chunk, heads, pool_dim + kv_rank]``. So
+        activations are the residual stream, one layer's projections and
+        that block. This is what lets a 16k context be planned: the
+        dense-cache estimate above reads 68 GB for its scores alone."""
         from ..models.latent import kind_counts
 
         if training:
@@ -133,12 +139,20 @@ class MemoryEstimate:
                 "a patterned model is served, not trained (models/latent.py)"
             )
         params = cfg.held_param_count() * pb
+        sizes = dict(cfg.latent)
         per_position = sum(
-            n * (cfg.latent_of(kind).pool_dim + cfg.latent_of(kind).index_dim)
+            n * (sizes[kind].pool_dim
+                 + (sizes[kind].index_dim if sizes[kind].index_heads else 0))
             for kind, n in kind_counts(cfg).items()
         )
         kv = per_position * batch * seq_len * pb
         act = batch * seq_len * (8 * cfg.d_model + 2 * cfg.d_ff) * pb
+        full = sizes.get("full")
+        if full is not None and not full.index_heads:
+            act += (
+                batch * min(seq_len, PREFILL_BLOCK) * full.n_heads
+                * (full.pool_dim + full.kv_rank) * pb
+            )
         total = int((params + act + kv) * 1.1)
         return cls(params, 0, 0, int(act), int(kv), total)
 

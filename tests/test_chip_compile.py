@@ -447,3 +447,88 @@ def test_dots3_note_step_fits_one_v5e_with_its_window_kernel(v5e):
               if " while(" in ln]
     assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)
             for ln in whiles] == list(STEP_PHASES)
+
+
+def test_deepseek_v2_step_fits_one_v5e_with_its_walk_in_both_passes(v5e):
+    """The benchmark's ``deepseek-v2-ep8`` at its published widths (6
+    layers, routing group 0 = 20 of 160 experts held, 16 slots x 16,384
+    positions of bf16 latent pages): the latent walk compiles at 128
+    heads on one 640-wide row, one query position a slot and a slot's
+    block of 128 x 128 = 16,384 query rows (groups of rows over a third
+    grid axis: the block does not fit VMEM whole, ROADMAP R2), and the
+    whole step compiles for one described v5e with that kernel in BOTH
+    passes (three calls a traced layer: the first rows and the blocks of
+    the ragged pass, the continuation step), no pool for a selector or a
+    sliding kind, weights + pool + temporaries inside the chip, no
+    operation copying the pool, no ``[rows, heads, capacity]`` score array,
+    and the three phase loops in order. ~45 s."""
+    import json
+    import re
+    from pathlib import Path
+
+    from tensorlink_tpu.engine.latent import (
+        FULL_GROUP_ROWS, FULL_KERNEL, FULL_KV_TILE, FULL_ROWS,
+        LatentPagedCache)
+    from tensorlink_tpu.engine.paged import STEP_PHASES, paged_ragged_step
+    from tensorlink_tpu.models.registry import config_from_hf
+    from tensorlink_tpu.models.transformer import init_params
+
+    hf = json.loads((Path(__file__).parent.parent / "benchmarks" / "configs"
+                     / "deepseek-v2-ep8.json").read_text())
+    cfg = config_from_hf(hf)
+    la = cfg.latent_of("full")
+    slots = hf["deployment"]["ml"]["cont_max_slots"]
+    params = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: LatentPagedCache.init(
+        cfg, slots, page_size=PAGE, max_len=hf["deployment"]["seq_len"]))
+    assert cache.index is None and cache.slide is None
+    place = _on(v5e)
+    pool, n_pp = place(cache.full), cache.pages_per_slot
+    shape = (la.kv_rank, FULL_KV_TILE, FULL_ROWS, FULL_GROUP_ROWS)
+    kw = dict(scale=la.softmax_scale, layer=_i32(v5e), name=FULL_KERNEL,
+              latent=shape)
+    for fn, args in (
+        (A.paged_attention, (_q(v5e, slots, la.n_heads, la.pool_dim), pool,
+                             None, _i32(v5e, slots, n_pp), _i32(v5e, slots))),
+        (A.ragged_paged_attention, (
+            _q(v5e, 1, 128, la.n_heads, la.pool_dim), pool, None,
+            _i32(v5e, 1, n_pp), _i32(v5e, 1), _i32(v5e, 1))),
+    ):
+        text = fn.lower(*args, **kw).compile().as_text()
+        assert "tpu_custom_call" in text and FULL_KERNEL in text
+
+    def ctl(dt, *shape):
+        return place(jax.ShapeDtypeStruct(shape, dt))
+
+    i32, f32 = jnp.int32, jnp.float32
+    ops = (
+        place(params), ctl(i32, slots, 128), place(cache), ctl(i32, slots),
+        ctl(i32, slots), ctl(i32, slots), ctl(jnp.bool_, slots),
+        ctl(i32, slots), ctl(i32, slots), ctl(f32, slots), ctl(i32, slots),
+        ctl(f32, slots), ctl(f32, slots), ctl(f32, slots),
+        ctl(i32, slots, cfg.vocab_size), ctl(i32, slots), ctl(i32, slots, 8),
+    )
+    compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
+    text = compiled.as_text()
+    # lead layer + traced period: (first rows, blocks) + a continuation step
+    assert text.count("tpu_custom_call") == 6
+    assert len(re.findall(rf'custom-call\([^\n]*{FULL_KERNEL}', text)) == 6 \
+        or text.count(f"/{FULL_KERNEL}/pallas_call") >= 6
+    ma = compiled.memory_analysis()
+    weights = _nbytes(ops[0])
+    assert weights == 2 * cfg.held_param_count()
+    assert 7.6e9 < weights < 7.7e9
+    pools = _nbytes(cache.full)
+    assert 2.0e9 < pools < 2.02e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 0.75 * V5E_HBM, ma
+    assert ma.temp_size_in_bytes < pools, ma
+    dims = "[" + ",".join(map(str, cache.full.shape)) + "]"
+    copies = re.findall(
+        rf"^\s*\S+ = \w+{re.escape(dims)}\S* copy\(.*$", text, re.M)
+    assert not copies, copies[:2]
+    # no score array over a slot's capacity: [.., 16384] float32 of rows x heads
+    assert not re.findall(r"f32\[(?:\d+,)*128,128,16384\]", text)
+    whiles = [ln for ln in text[text.index("ENTRY"):].splitlines()
+              if " while(" in ln]
+    assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)
+            for ln in whiles] == list(STEP_PHASES)

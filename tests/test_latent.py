@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.reference import deepseek_v2 as ref_ds
 from benchmarks.reference import dots3_note as ref
 from tensorlink_tpu.engine import paged
 from tensorlink_tpu.engine.continuous import (
@@ -48,6 +49,27 @@ TINY = dict(
 )
 
 
+# a tiny ``deepseek_v2``: one kind of layer (full, nothing selected), YaRN
+# over an original length shorter than the contexts, 16 experts in 4
+# routing groups of which this chip holds group 1, two shared experts
+# tlint: disable=TL006(read-only table: every test copies it)
+TINY_DS = dict(
+    model_type="deepseek_v2", hidden_size=64, num_hidden_layers=3,
+    num_attention_heads=4, q_lora_rank=32, kv_lora_rank=16,
+    qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8, rope_theta=1e4,
+    rope_scaling=dict(
+        type="yarn", factor=40, original_max_position_embeddings=8,
+        beta_fast=32, beta_slow=1, mscale=0.707, mscale_all_dim=0.707),
+    first_k_dense_replace=1, intermediate_size=96, moe_intermediate_size=32,
+    n_routed_experts=4, n_shared_experts=2, num_experts_per_tok=3,
+    norm_topk_prob=False, routed_scaling_factor=16, scoring_func="softmax",
+    topk_method="group_limited_greedy", n_group=4, topk_group=2,
+    rms_norm_eps=1e-6, vocab_size=64, max_position_embeddings=64,
+    tie_word_embeddings=False,
+    published={"n_routed_experts": 16}, expert_group={"first_expert": 4},
+)
+
+
 def tiny_hf(**over) -> dict:
     return {**TINY, **over}
 
@@ -66,7 +88,8 @@ def _engine(cfg, params, **kw):
     return ContinuousEngine(eng, **kw)
 
 
-def _teacher_forced(params, cfg, seqs, lens, n_decode, *, C=8, page=4, S=3):
+def _teacher_forced(params, cfg, seqs, lens, n_decode, *, C=8, page=4, S=3,
+                    kernel=False):
     """Each sequence's logits at its last prompt position and at
     ``n_decode`` teacher-forced decode steps, through the pages: chunked
     prefill in blocks of ``C`` (the step's ragged pass), then continuation
@@ -92,7 +115,7 @@ def _teacher_forced(params, cfg, seqs, lens, n_decode, *, C=8, page=4, S=3):
                 starts[s], nv[s] = pos[s], n
         lv, _base, kv = paged._ragged_pass(
             params, jnp.asarray(blk), cache, jnp.asarray(starts),
-            jnp.asarray(nv), jnp.zeros(S, jnp.int32), cfg, 1, False,
+            jnp.asarray(nv), jnp.zeros(S, jnp.int32), cfg, 1, kernel,
         )
         cache = paged._with_kv(cache, kv, lengths=jnp.where(
             jnp.asarray(nv) > 0, jnp.asarray(starts + nv), cache.lengths))
@@ -105,7 +128,7 @@ def _teacher_forced(params, cfg, seqs, lens, n_decode, *, C=8, page=4, S=3):
         for s, seq in enumerate(seqs):
             tok[s], active[s] = seq[lens[s] + i], True
         lg, cache = paged._decode_step_impl(
-            params, jnp.asarray(tok), cache, jnp.asarray(active), cfg, False
+            params, jnp.asarray(tok), cache, jnp.asarray(active), cfg, kernel
         )
         for s in range(len(seqs)):
             got[s].append(np.asarray(lg[s]))
@@ -289,13 +312,16 @@ def test_short_context_is_latent_attention_without_the_indexer(tiny):
     assert np.abs(got[0] - want).max() > 1e-3
 
 
-def test_expert_shares_add_up_to_the_uncut_layer(tiny):
+@pytest.mark.parametrize("family", ["dots3_note", "deepseek_v2"])
+def test_expert_shares_add_up_to_the_uncut_layer(family):
     """The share test: the routed parts of all four shares of the expert
-    group plus the shared expert once are the uncut layer's output."""
-    cfg, _ = tiny
-    whole = config_from_hf(
-        tiny_hf(n_routed_experts=16, published=None, expert_group=None),
-        dtype=jnp.float32)
+    group plus the shared expert(s) once are the uncut layer's output
+    (dots3_note: sigmoid scores, normalised; deepseek_v2: softmax scores,
+    the group limit with one routing group a share, unnormalised x 16)."""
+    hf = {**(TINY if family == "dots3_note" else TINY_DS),
+          "n_routed_experts": 16, "published": None, "expert_group": None}
+    reference = ref if family == "dots3_note" else ref_ds
+    whole = config_from_hf(hf, dtype=jnp.float32)
     wp = init_params(whole, jax.random.PRNGKey(5))
     mp = jax.tree.map(lambda a: a[0], wp["periods"][0]["moe"])
     rng = np.random.default_rng(5)
@@ -304,21 +330,26 @@ def test_expert_shares_add_up_to_the_uncut_layer(tiny):
     want, _ = ml.moe_mlp(h, mp, whole, valid)
     shared = ml.gated_mlp(h, mp["shared"])
     total = shared
+    reach = 0
     for first in range(0, 16, 4):
         share_cfg = whole.with_(experts_first=first, experts_held=4)
         share_mp = {**mp, **{n: mp[n][first:first + 4]
                              for n in ("w_gate", "w_up", "w_down")}}
         y, st = ml.moe_mlp(h, share_mp, share_cfg, valid)
         total = total + (y - shared)
+        st = dict(zip(ml.STEP_STATS, np.asarray(st)))
+        assert st["moe_rows_valid"] == 12
+        reach += st["moe_rows_in_group"]
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
+    # a row reaches topk_group of the n_group shares; all without a limit
+    assert reach == 12 * (2 if family == "deepseek_v2" else 4)
     # and the uncut layer is the reference's
-    arch = ref.arch_of(tiny_hf(n_routed_experts=16, published=None,
-                               expert_group=None))
+    arch = reference.arch_of(hf)
     lt = {"ln2": {"scale": jnp.ones(64)}, "moe": mp}
     normed = h  # ln2 with unit scale is applied by the reference itself
-    out = ref.mlp_layer(normed, lt, arch) - normed
-    a = ref._rmsnorm(normed, jnp.ones(64), arch["eps"])
+    out = reference.mlp_layer(normed, lt, arch) - normed
+    a = reference._rmsnorm(normed, jnp.ones(64), arch["eps"])
     mine, _ = ml.moe_mlp(a, mp, whole, valid)
     np.testing.assert_allclose(np.asarray(mine), np.asarray(out),
                                rtol=1e-4, atol=1e-5)

@@ -236,8 +236,10 @@ LEGACY_ENGINE_KEYS = (
     # (0 for every other model)
     "moe_rows_routed_local", "moe_rows_computed", "moe_rows_busiest_expert",
     "moe_experts_touched", "moe_experts_held",
+    "moe_rows_in_group", "moe_rows_valid",
     "sparse_positions_kept", "sparse_positions_scored",
     "window_pages_walked", "window_pages_context",
+    "latent_rows_read", "latent_rows_capacity",
     # the sampling epilogue (ROADMAP S1): calls, those that sorted, and
     # the verify walk's length against the rows the program holds
     "sampler_calls", "sampler_calls_sampled",

@@ -1209,3 +1209,101 @@ def test_windowed_latent_walk_matches_the_reference(window):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     assert np.abs(np.asarray(out[0])).max() == 0.0  # the free slot
+
+
+# one latent row for 128 heads (DeepSeek-V2's shape in small widths): the
+# walk's ``latent`` form = (v_width, kv_tile, rows a row block, rows a grid
+# step), with tiles small enough that every loop runs more than once
+LATENT_SHAPE = (128, 64, 256, 512)
+
+
+def _latent_case(rng, S, n_pp, page=16, W=256, L=2):
+    P = 1 + S * n_pp
+    pool = rng.normal(size=(L, P, 1, page, W)).astype(np.float32)
+    bt = rng.permutation(np.arange(1, P)).reshape(S, n_pp).astype(np.int32)
+    return pool, bt
+
+
+def _poison_latent(pool, bt, live):
+    """``pool`` with NaN in every page behind a dead block-table entry and
+    past the live span inside the last live page (layer 1)."""
+    pool, bt = pool.copy(), bt.copy()
+    page = pool.shape[3]
+    nan_page = pool.shape[1]
+    pool = np.concatenate(
+        [pool, np.full((pool.shape[0], 1) + pool.shape[2:], np.nan,
+                       np.float32)], axis=1)
+    for s, n in enumerate(live):
+        n_live = -(-int(n) // page)
+        bt[s, n_live:] = nan_page
+        if n % page:
+            pool[1, bt[s, n_live - 1], :, n % page:] = np.nan
+    return pool, bt
+
+
+def test_latent_walk_at_128_heads_decode_rows():
+    """``paged_attention(..., v_pages=None, latent=...)``: one query
+    position a slot, 128 heads on one cached row, the value the row's
+    first ``v_width`` columns, operands to the MXU as stored and the
+    scale on the scores: ``paged_attention_ref`` with the pool as keys and
+    values both, at lengths from 0 to past several KV blocks, reading
+    nothing past the live span."""
+    from tensorlink_tpu.ops.attention import paged_attention, paged_attention_ref
+
+    rng = np.random.default_rng(41)
+    S, H, n_pp, W = 4, 128, 14, 256
+    pool, bt = _latent_case(rng, S, n_pp, W=W)
+    lengths = np.asarray([0, 5, 131, 220], np.int32)
+    q = jnp.asarray(rng.normal(size=(S, H, W)), jnp.float32)
+    ref = paged_attention_ref(
+        q, jnp.asarray(pool[1]), jnp.asarray(pool[1]), jnp.asarray(bt),
+        jnp.asarray(lengths), scale=0.07)[..., :LATENT_SHAPE[0]]
+    poisoned, pbt = _poison_latent(pool, bt, lengths)
+    out = paged_attention(
+        q, jnp.asarray(poisoned), None, jnp.asarray(pbt),
+        jnp.asarray(lengths), scale=0.07, interpret=True, layer=jnp.int32(1),
+        name="latent_full_attention", latent=LATENT_SHAPE)
+    assert out.shape == (S, H, LATENT_SHAPE[0])
+    assert not np.isnan(np.asarray(out)).any()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    assert np.abs(np.asarray(out[0])).max() == 0.0  # the free slot
+
+
+@pytest.mark.parametrize(
+    "C,starts,nv",
+    [(16, [70, 0, 201], [16, 0, 9]),  # a mid-prefill block, a pad, a partial
+     (8, [213, 130, 0], [5, 1, 8])],  # a verify block, a decode row, a fresh
+    ids=["mid-prefill", "verify"],
+)
+def test_latent_walk_at_128_heads_blocks_of_rows(C, starts, nv):
+    """``ragged_paged_attention(..., v_pages=None, latent=...)``: blocks of
+    up to ``C`` positions x 128 heads with each row's causal limit, the
+    query rows in groups over a third grid axis (2,048 or 1,024 rows in
+    groups of 512, row blocks of 256 = two positions): exact against
+    ``ragged_paged_attention_ref``, zero rows past ``n_valid``, nothing
+    read past the live span."""
+    from tensorlink_tpu.ops.attention import (
+        ragged_paged_attention, ragged_paged_attention_ref)
+
+    rng = np.random.default_rng(42)
+    S, H, n_pp, W = 3, 128, 14, 256
+    pool, bt = _latent_case(rng, S, n_pp, W=W)
+    q = jnp.asarray(rng.normal(size=(S, C, H, W)), jnp.float32)
+    st, nvj = jnp.asarray(starts, jnp.int32), jnp.asarray(nv, jnp.int32)
+    rows = jnp.asarray(pool[1])
+    ref = ragged_paged_attention_ref(
+        q, rows, rows, jnp.asarray(bt), st, nvj, scale=0.07,
+    )[..., :LATENT_SHAPE[0]]
+    live = [a + b if b else 0 for a, b in zip(starts, nv)]
+    poisoned, pbt = _poison_latent(pool, bt, live)
+    out = ragged_paged_attention(
+        q, jnp.asarray(poisoned), None, jnp.asarray(pbt), st, nvj, scale=0.07,
+        interpret=True, layer=jnp.int32(1), name="latent_full_attention",
+        latent=LATENT_SHAPE)
+    assert out.shape == (S, C, H, LATENT_SHAPE[0])
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for s in range(S):
+        assert np.abs(np.asarray(out[s, nv[s]:])).max(initial=0) == 0
