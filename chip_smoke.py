@@ -22,9 +22,9 @@ any phase fails, or outside the repo. Phases:
   two other presets (seven query rows a kv head; 32 kv heads of width 96).
 * ``serve`` (default, one chip): the traffic above, then the checks — the
   worker advertises the accelerator; every request went through the
-  ``ContinuousEngine`` with the Pallas kernel in its lowered step program
-  (``tpu_custom_call``); the repeated greedy request reproduced its token
-  stream; every stream has the requested length; page conservation is clean;
+  ``ContinuousEngine`` with the Pallas kernel in its lowered step programs
+  (``tpu_custom_call``; one program a width of the packed block); the
+  repeated greedy request reproduced its token stream; every stream has the requested length; page conservation is clean;
   no program was built after warm-up and ``jit_cache_sizes()`` did not move;
   ``/stats`` shows int8 pages and prefix-cache hits.
 * ``--chips 4``: only the tensor-parallel path and what it is compared
@@ -316,8 +316,10 @@ def engine_of(worker):
 
 
 def kernel_in_program(cont) -> bool:
-    """The Pallas kernel is in the step program this engine dispatches."""
-    return "tpu_custom_call" in cont.lower_step().as_text()
+    """The Pallas kernel is in the step programs this engine dispatches
+    (one a width of the packed block)."""
+    return all("tpu_custom_call" in cont.lower_step(w).as_text()
+               for w in cont.block_widths)
 
 
 def conservation_error(cont) -> str:
@@ -352,7 +354,8 @@ def common_checks(port: int, worker, cont, taps: list, built: list,
     check(isinstance(cont, ContinuousEngine) and cont.use_kernel,
           f"served by {type(cont).__name__}, use_kernel={cont.use_kernel}")
     check(kernel_in_program(cont),
-          "lowered step program contains the Pallas kernel (tpu_custom_call)")
+          f"lowered step programs (block widths {cont.block_widths}) each "
+          "contain the Pallas kernel (tpu_custom_call)")
     n_http = 3 + len(CONCURRENT) + 1
     check(len(taps) == n_http,
           f"{len(taps)} slot-engine admissions for {n_http} HTTP requests")
@@ -643,10 +646,12 @@ def tp_phase(ml, tmp: str, built: list, degree: int) -> None:
         check(cont.tensor_parallel == degree and cont._tp_step is not None,
               f"engine runs tensor_parallel={cont.tensor_parallel}")
         common_checks(port, worker, cont, taps, built, res, warm)
-        text = cont.lower_step().as_text()
-        check("all_gather" in text,
-              f"lowered tp step: {text.count('tpu_custom_call')} kernel "
-              f"call(s), {text.count('all_gather')} all_gather op(s)")
+        for width in cont.block_widths:
+            text = cont.lower_step(width).as_text()
+            check("all_gather" in text,
+                  f"lowered tp step, block {width} wide: "
+                  f"{text.count('tpu_custom_call')} kernel call(s), "
+                  f"{text.count('all_gather')} all_gather op(s)")
         # one copy of the weights a device, each holding its share: the
         # engine's own arrays beside what the runtime reports in use
         devs = list(cont._tp_mesh.devices.flat)

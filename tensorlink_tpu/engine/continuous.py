@@ -18,7 +18,10 @@ suffix rides the packed ``[slots, chunk]`` block of the one step
 program — each mid-prefill slot's next prompt piece (its grant from
 :func:`pack_prefill_budgets`) and each decode slot's next token in the
 SAME ragged dispatch, so a long admission never stalls co-resident
-decodes at all. (The legacy two-program schedule — ≤1 prefill chunk per
+decodes at all. The block is packed at the narrowest of at most two
+widths that holds the chunk's longest grant (``block_widths``: a page or
+two when nobody prefills, else ``prefill_chunk``), and the step is
+compiled once a width. (The legacy two-program schedule — ≤1 prefill chunk per
 mid-prefill slot before a separate decode chunk — and the monolithic
 dense-prefill admission were retired after their one-release fallback
 window; ``prefill_chunk`` must be ≥ 1.) Finished slots promote their
@@ -63,6 +66,7 @@ import itertools
 import math
 import threading
 import time
+from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable
 
@@ -344,12 +348,23 @@ _ENGINE_COUNTERS = (
      "admissions that attempted a cross-replica prefix pull"),
     ("fleet_pull_fallbacks", "tlink_engine_fleet_pull_fallbacks_total",
      "fleet pulls that degraded to the next rung (local prefill)"),
-    # flat token packing (ROADMAP S3): rows of the packed [S, C] block
+    # flat token packing (ROADMAP S5): rows of the packed [S, C] block
     # that carried a token against rows the ragged pass computed
     ("ragged_rows_valid", "tlink_engine_ragged_rows_valid_total",
      "rows of the packed block that carried a token"),
     ("ragged_rows_computed", "tlink_engine_ragged_rows_computed_total",
-     "rows of the packed block the ragged pass computed (slots x chunk)"),
+     "rows of the packed block the ragged pass computed (slots x the "
+     "width that ran)"),
+    # the width ladder (ROADMAP S5): a chunk whose longest grant fits the
+    # narrow width packs a block that wide, every other one prefill_chunk
+    ("ragged_blocks", "tlink_engine_ragged_blocks_total",
+     "packed blocks dispatched (one a chunk)"),
+    ("ragged_blocks_narrow", "tlink_engine_ragged_blocks_narrow_total",
+     "of those, blocks packed at the narrow width of the ladder"),
+    ("ragged_blocks_narrow_unbuilt",
+     "tlink_engine_ragged_blocks_narrow_unbuilt_total",
+     "blocks that fitted the narrow width and ran wide: its program was "
+     "still being built"),
     # the paged kernels' live-span walk (ROADMAP S7): pages the walk
     # reads against the page slots a capacity-wide walk would visit
     ("attn_pages_live", "tlink_engine_attn_pages_live_total",
@@ -754,14 +769,39 @@ class ContinuousEngine:
         # tighter inter-token bound
         self.prefill_budget = int(prefill_budget)
         # -- speculative decoding (docs/SERVING.md) ----------------------
-        # spec_width is the step program's STATIC verify-row count: ONE
-        # compiled ragged_step per engine whether speculation is on or
-        # off (per-slot draft lengths are data — spec/non-spec request
-        # mixes never recompile). Draft rows ride the packed block's
+        # spec_width is the step program's STATIC verify-row count: the
+        # same compiled ragged_step (one a width of the ladder below)
+        # whether speculation is on or off (per-slot draft lengths are
+        # data — spec/non-spec request mixes never recompile). Draft
+        # rows ride the packed block's
         # columns, so the width caps at the chunk row (prefill_chunk).
         self.spec_decode = bool(spec_decode)
         self.spec_draft = max(0, min(int(spec_draft), self.prefill_chunk - 1))
         self.spec_width = 1 + (self.spec_draft if self.spec_decode else 0)
+        # the packed block's width follows the chunk's longest grant, from
+        # a ladder of at most two fixed here: the smallest whole number of
+        # pages that holds the verify rows (a chunk in which nobody
+        # prefills: a row a slot and its drafts), and prefill_chunk. A
+        # width is a shape of the step program, so the ladder is the
+        # whole compile set: one program a width. It collapses to one
+        # where the narrow width would not be the smaller, and for a
+        # patterned model: its step is a program of several layer bodies
+        # a pass (dots3's: 1.9 + 1.8 + 5.5 s to trace, lower and fetch a
+        # second one), and with the ladder the dots3 cell's set-up read
+        # 4.8 s (10%) more where a tenth of its chunks packed narrow
+        # and no end-to-end number moved (PERF.md section 6, PR 35)
+        narrow = -(-self.spec_width // self.page_size) * self.page_size
+        self.block_widths = (
+            (narrow, self.prefill_chunk)
+            if narrow < self.prefill_chunk and not self._latent
+            else (self.prefill_chunk,)
+        )
+        # a width is packed only once its program is built. build_steps
+        # (what a server calls before traffic) leaves the narrow program's
+        # compile running on a thread, here, while the wide one serves;
+        # an engine nobody called it on builds each width at its first
+        # call, like any jitted function
+        self._build: Future | None = None
         # optional TOTAL draft tokens per step shared across speculating
         # slots (0 = each gets a full draft): bounds the extra verify
         # compute like prefill_budget bounds prefill compute — and since
@@ -1171,10 +1211,12 @@ class ContinuousEngine:
         """Compiled-program counts of the slot-batched hot loop — the
         "no unbounded compile set" guarantee, asserted by the engine
         tests: these stay fixed no matter the request mix. The entire
-        serving hot loop is ONE top-level step program (``ragged_step``;
+        serving hot loop is ONE top-level step function (``ragged_step``;
         prompt length, cache-hit offset, prefill/decode mix, budget
         split AND the kv_quant storage mode are all DATA or trace-time
-        constants to it) plus the COW ``copy_page``. ``decode_step`` /
+        constants to it), compiled once a width of ``block_widths`` (at
+        most two: the packed block's shape keys the jit cache), plus the
+        COW ``copy_page``. ``decode_step`` /
         ``sample_rows`` / ``row_keys`` are traced INSIDE the step
         program — never dispatched from the host loop. (The legacy
         two-program pair ``decode_chunk``/``prefill_chunk`` was retired
@@ -1184,9 +1226,9 @@ class ContinuousEngine:
             "sample_rows": _sample_rows._cache_size(),
             "row_keys": _row_keys._cache_size(),
             "ragged_step": paged_ragged_step._cache_size(),
-            # the sharded analogue: ONE ragged program per shard degree
-            # (the factory builds a plain/quant-cache pair, only the
-            # arity matching this engine's cache ever compiles)
+            # the sharded analogue: one ragged program per shard degree
+            # and width (the factory builds a plain/quant-cache pair,
+            # only the arity matching this engine's cache ever compiles)
             "tp_ragged_step": (
                 self._tp_step._cache_size()
                 if self._tp_step is not None else 0
@@ -1213,7 +1255,19 @@ class ContinuousEngine:
         """The stream stage: hand what the settle stage left pending to
         the requests' callbacks, in order: the ``first_token`` spans where
         a first token leaves, ``stream_cb`` a token, ``on_finish`` after a
-        finished request's last one. ``step_chunk`` runs it behind its
+        finished request's last one. Entries that end a request go first,
+        then every first token, then the rest in slot order: a client that
+        waits for its answer to send the next request is freed at the
+        start of the stage and not behind every other slot's tokens (at a
+        chunk of 80 ms and a stage of 40 that decided in which chunk the
+        next request was admitted), and a first token does not queue
+        behind tokens whose readers already have a stream going. Only the
+        first token itself goes ahead: the tokens that came with it keep
+        their slot's place, because an entry that changes place between
+        two stages moves every reader behind it by an entry and its own
+        reader's next gap by the way back (the longest gap a reader sees;
+        PERF.md section 6, PR 36). A request's own tokens keep their
+        order. ``step_chunk`` runs it behind its
         dispatch (``in_flight``: the device executes the next chunk
         meanwhile), or at once when no step follows. Anything else that
         answers for a request calls it first, for that request (``req``)
@@ -1227,7 +1281,26 @@ class ContinuousEngine:
         n = 0
         try:
             with jax.profiler.TraceAnnotation("tlink:stream"):
-                for rid in (req.rid,) if req is not None else tuple(pend):
+                order = (req.rid,) if req is not None else tuple(pend)
+                if req is None:
+                    for rid in order:  # what ends a request
+                        entry = pend.get(rid)
+                        if entry is not None and entry[3]:
+                            del pend[rid]
+                            n += self._stream_one(*entry, in_flight)
+                    for rid in order:  # first tokens
+                        entry = pend.get(rid)
+                        if entry is None or not entry[2]:
+                            continue
+                        r, k, _first, _finish, step = entry
+                        if k > 1:  # the rest stays pending, in its place
+                            pend[rid] = (r, k - 1, False, False, step)
+                        else:
+                            del pend[rid]
+                        n += self._stream_one(
+                            r, k, True, False, step, in_flight, head=True,
+                        )
+                for rid in order:
                     entry = pend.pop(rid, None)
                     if entry is not None:
                         n += self._stream_one(*entry, in_flight)
@@ -1241,14 +1314,16 @@ class ContinuousEngine:
             )
 
     def _stream_one(self, req: ContinuousRequest, n: int, first: bool,
-                    finish: bool, step: int, in_flight: bool) -> int:
+                    finish: bool, step: int, in_flight: bool, *,
+                    head: bool = False) -> int:
         """One request's pending tokens (the last ``n`` of ``req.tokens``)
-        to its callbacks; returns how many left. A truthy ``stream_cb``
-        return (a confirmed stop) ends the stream there: ``req.tokens`` is
-        cut back to what was streamed, so at ``on_finish`` it is exactly
-        the sequence the callback was given."""
+        to its callbacks, or with ``head`` the first of them alone (the
+        caller keeps the rest pending); returns how many left. A truthy
+        ``stream_cb`` return (a confirmed stop) ends the stream there:
+        ``req.tokens`` is cut back to what was streamed, so at
+        ``on_finish`` it is exactly the sequence the callback was given."""
         base = len(req.tokens) - n
-        sent, cancel = n, False
+        sent, cancel = 1 if head else n, False
         try:
             if first:
                 # first token EVER for this request (a resumed-after-
@@ -1273,7 +1348,7 @@ class ContinuousEngine:
                     )
             cb = req.stream_cb
             if cb is not None:
-                for i in range(n):
+                for i in range(sent):
                     if cb(req.tokens[base + i]):
                         sent, cancel = i + 1, True
                         del req.tokens[base + sent:]
@@ -1281,11 +1356,16 @@ class ContinuousEngine:
             if finish:
                 self._finish(req, finished=True)
             elif cancel:
+                # what a first token left pending went with the cut
+                self._unstreamed.pop(req.rid, None)
                 req.cancelled = True
                 if not in_flight:
                     self._evict(req.slot)
                 # else the running chunk holds the slot: its settle evicts
         except BaseException as e:
+            if head:
+                # a callback that raises loses its own stream
+                self._unstreamed.pop(req.rid, None)
             if finish and not req.done.is_set():
                 # its slot is gone: nobody else would answer for it
                 req.error = e
@@ -2986,7 +3066,11 @@ class ContinuousEngine:
         and each decoding slot's current token ride ONE block, with
         per-slot ``(start, n_valid)`` as data. ``emit`` marks the slots
         that sample this step (decoders, and prefills whose prompt
-        completes in this block). Returns None when nothing is live."""
+        completes in this block). The block is cut to the narrowest width
+        of ``block_widths`` that holds the chunk's longest grant: a chunk
+        in which nobody prefills (or only a short prompt tail does) has
+        the dense layers compute a page of rows a slot, not
+        ``prefill_chunk``. Returns None when nothing is live."""
         if not self._prefilling and not self._active.any():
             return None
         S, C = self.max_slots, self.prefill_chunk
@@ -3056,10 +3140,31 @@ class ContinuousEngine:
                 ids = sorted(req.eos)[: self._EOS_WIDTH]
                 eos_arr[s, : len(ids)] = ids
         n_spec = self._pack_drafts(blk, n_valid, remaining)
+        blk = np.ascontiguousarray(blk[:, :self._block_width(n_valid)])
         return (blk, starts, n_valid, n_spec, emit, remaining, eos_arr,
                 completing, handoff_done, grants)
 
     # tlint: hot-path
+    def _block_width(self, n_valid) -> int:
+        """The narrowest width of ``block_widths`` that holds the chunk's
+        longest grant and whose program is built: while ``build_steps``'
+        thread is at the narrow one every block is ``prefill_chunk`` wide
+        (the same rows carry the same tokens; nothing waits)."""
+        if self._build is not None:
+            if not self._build.done():
+                return self.block_widths[-1]
+            self._join_build()  # what the build raised is raised here
+        longest = int(n_valid.max())
+        return next(w for w in self.block_widths if longest <= w)
+
+    def _join_build(self) -> None:
+        """Wait for ``build_steps``' thread (bounded by one compile, or
+        one fetch from the persistent cache, once an engine) and raise
+        what it raised; from here on every width is packed."""
+        build, self._build = self._build, None
+        if build is not None:
+            build.result()
+
     def _pack_drafts(self, blk, n_valid, remaining):
         """Draft-budget packing, the speculative half of the packed
         block: each opted-in DECODING slot proposes a prompt-lookup draft
@@ -3171,12 +3276,19 @@ class ContinuousEngine:
                 np.full_like(ctx, self.cache.pages_per_slot * self.page_size)
             ))
 
-    def lower_step(self):
+    def lower_step(self, width: int | None = None):
         """The step program lowered at this engine's own shapes and
         placement, not run: what ``chip_smoke.py`` reads to prove the
         Pallas kernel (``tpu_custom_call``) and, sharded, the collectives
-        are in the program that serves. Call it on an idle engine."""
-        S, C = self.max_slots, self.prefill_chunk
+        are in the program that serves. One program a width of
+        ``block_widths``: ``width`` names which (the widest when not
+        given). Call it on an idle engine."""
+        S = self.max_slots
+        C = self.block_widths[-1] if width is None else int(width)
+        if C not in self.block_widths:
+            raise ValueError(
+                f"width {C} is not one of this engine's {self.block_widths}"
+            )
         zi = np.zeros(S, np.int32)
         ops = self._step_operands(
             np.zeros((S, C), np.int32), zi, zi, zi, np.zeros(S, bool),
@@ -3189,9 +3301,54 @@ class ContinuousEngine:
             self.use_kernel,
         )
 
+    def build_steps(self) -> None:
+        """Build the step program of every width of ``block_widths``
+        (compiled, or fetched from the persistent cache) without running
+        one: what a server calls once, before traffic
+        (``ml/worker.py::_ensure_cont``). It returns when the WIDEST is
+        built, which serves every chunk; the narrow one's compile goes on
+        behind the first requests, on a thread. Until it is through,
+        ``_pack_ragged`` packs every block wide (``_block_width``;
+        counted ``ragged_blocks_narrow_unbuilt``), and the engine joins
+        it the first time it runs out of work (``step_chunk``), so no
+        request waits for the narrow program while the wide one can
+        serve it, and none is served while a program is built after the
+        engine's first idle moment. What the thread raised is raised
+        there, on the serving path. The call at a width then finds its
+        program built: the jitted function's own lowering is what is
+        compiled here.
+
+        The order (cached, qwen3-4b on a v5e; PERF.md section 7): the
+        widest is traced and lowered (1.4-1.6 + 1.45 s) and handed to a
+        thread (a fetch of 1.75 s, 2.1 beside this thread's work) while
+        this thread traces and lowers the narrow one (0.5 + 1.3 s); the
+        narrow one's own fetch (1.0 s), behind the widest's on that
+        thread, is the part no request waits for.
+        An engine of one width has nothing to build ahead: its first
+        chunk builds its program, as ever (built here, ahead of the
+        call, dots3's one program cost set-up 5 s more). Call it on an
+        idle engine."""
+        if len(self.block_widths) == 1 or self._build is not None:
+            return
+        pool = ThreadPoolExecutor(1, thread_name_prefix="build-step")
+        try:
+            widest = pool.submit(self.lower_step().compile)
+            # one worker: the narrow program's fetch follows the widest's
+            # on the thread. On a thread of its own it started 0.2 s
+            # sooner and took 1.7-1.8 s for 1.0, beside the widest's end
+            # and the first chunk (PERF.md section 7)
+            self._build = pool.submit(
+                self.lower_step(self.block_widths[0]).compile
+            )
+            widest.result()  # what it raised is raised here
+        finally:
+            pool.shutdown(wait=False)  # the thread ends with its queue
+
     # tlint: hot-path
     def step_chunk(self, *, admit_only: bool = False) -> bool:
-        """Admit queued requests, then run ONE compiled step program.
+        """Admit queued requests, then run ONE compiled step program
+        (the step at the width this chunk's block was packed at: one
+        program a width of ``block_widths``, at most two).
 
         The packed ragged block — every mid-prefill slot's next prompt
         piece AND every decode slot's next token in one dispatch —
@@ -3286,6 +3443,15 @@ class ContinuousEngine:
                 with _Phase(ph, "post"):
                     self._count("ragged_rows_valid", int(n_valid.sum()))
                     self._count("ragged_rows_computed", blk.size)
+                    self._count("ragged_blocks")
+                    if blk.shape[1] < self.prefill_chunk:
+                        self._count("ragged_blocks_narrow")
+                    elif int(n_valid.max()) <= self.block_widths[0] < (
+                        blk.shape[1]
+                    ):
+                        # it fitted the narrow width, whose program was
+                        # still being built
+                        self._count("ragged_blocks_narrow_unbuilt")
                     # what the kernels' walk follows, from the contexts
                     # as packed: every slot with a row rides the ragged
                     # pass, the emitting ones each further step (at the
@@ -3334,6 +3500,7 @@ class ContinuousEngine:
                         prefilling=len(self._prefilling),
                         decode_steps=n_exec if bool(emit.any()) else 0,
                         prefill_granted=int(sum(grants.values())),
+                        block_rows=blk.shape[1],  # the width that ran
                         spec_drafted=int(n_spec.sum()),
                         tokens_emitted=delivered_total,
                         pages_free=self.alloc.n_free,
@@ -3345,6 +3512,10 @@ class ContinuousEngine:
                 with _Phase(ph, "deliver"):
                     self.flush_stream()  # nothing was dispatched
         more = self.has_work()
+        if not more:
+            # out of work for now: nobody waits for this engine, so the
+            # narrow program's build (build_steps) is joined here, once
+            self._join_build()
         if fields is None:
             # nothing was dispatched (admission only, or nothing live):
             # no record; the round stays part of what lies between two
@@ -3522,6 +3693,11 @@ class ContinuousEngine:
         from ..core.logging import get_logger
 
         err = error or RuntimeError("continuous engine closed")
+        build, self._build = self._build, None
+        if build is not None:
+            # a closed engine packs no block: a compile still queued is
+            # dropped, one under way ends on its thread, unread
+            build.cancel()
         if error is not None:
             dump = self.recorder.dump(error)
             get_logger("engine.flight").warning(

@@ -1049,13 +1049,22 @@ def _scatter_kv(cache_kv: tuple, layer, write_pg, write_off, k, v,
         # a block's scales go through the layer's own plane (1 MB where
         # a pool is 34): scattered straight into the stacked planes, the
         # TPU compiler moved all L layers of them to fast memory and back
-        # a layer-call of the ragged pass (cross-compile, PERF.md, PR 29)
+        # a layer-call of the ragged pass (cross-compile, PERF.md, PR 29).
+        # The plane is scattered FLAT, one index a scale: by (page, head,
+        # offset) the scatter's own layout reached the loop's carry, and
+        # at a block 16 rows wide with one kv head a chip the compiler
+        # carried the stack layer-minor, every layer's plane cut out of
+        # and put back into every tile of it (tests/test_chip_compile.py,
+        # PERF.md section 6, PR 35)
         def planes(pool, s):
             if plan is None:
                 return pool.at[(layer,) + idx].set(s)
             plane = jax.lax.dynamic_index_in_dim(pool, layer, 0, False)
+            _, n_kv, page = plane.shape
+            flat = (idx[0] * n_kv + idx[1]) * page + idx[2]  # [S, C, Hkv]
+            new = plane.reshape(-1).at[flat.reshape(-1)].set(s.reshape(-1))
             return jax.lax.dynamic_update_index_in_dim(
-                pool, plane.at[idx].set(s), layer, 0
+                pool, new.reshape(plane.shape), layer, 0
             )
 
         return (
@@ -1542,11 +1551,16 @@ def paged_ragged_step(
     spec_width: int = 1,
     kernel: bool = False,
 ):
-    """THE serving hot loop's single compiled program: one ragged
-    prefill+decode forward over the packed ``[S, C]`` token block, then
-    up to ``n_steps - 1`` decode continuation steps in the same
-    on-device while_loop — one host round trip per chunk, zero
-    scheduling seams between prefilling and decoding slots.
+    """THE serving hot loop's single step function, compiled once a
+    width of the packed block: one ragged prefill+decode forward over
+    the packed ``[S, C]`` token block, then up to ``n_steps - 1`` decode
+    continuation steps in the same on-device while_loop — one host
+    round trip per chunk, zero scheduling seams between prefilling and
+    decoding slots. ``C`` is one of the engine's ``block_widths`` (the
+    narrow one when no slot's grant is longer, else ``prefill_chunk``):
+    one program a width of the ladder, at most two, fixed at
+    construction; no argument's VALUE picks a program (what ``# tlint:
+    one-program`` holds callers to).
 
     The packed block (assembled by the host-side
     ``engine/continuous.py::pack_prefill_budgets`` packing) carries every
@@ -1557,9 +1571,10 @@ def paged_ragged_step(
     token from their last valid row's logits with the request's own key
     chain and continue through the decode loop; mid-prefill slots
     that didn't finish stay frozen for the rest of the chunk and get
-    their next grant at the next step boundary. One compiled program
-    serves every (prefill/decode mix, prompt length, offset, budget
-    split) — asserted in tests/test_continuous.py. With a quantized
+    their next grant at the next step boundary. One compiled program a
+    width serves every (prefill/decode mix, prompt length, offset,
+    budget split) — asserted in tests/test_continuous.py and
+    tests/test_block_width.py. With a quantized
     cache the same program stores int8 pages: the scatter quantizes,
     the kernels dequantize at the fetch.
 
@@ -1642,9 +1657,9 @@ def tp_gather_costs(cfg: ModelConfig, tp: int, quant: bool = False):
 
 # Compiled tensor-parallel ragged-step programs, keyed by every static
 # that shapes the trace. Engines sharing (mesh, model, chunk geometry)
-# share ONE program — churn in slots/requests/spec mixes never adds
-# entries, which is what the per-shard-degree jit-cache guard in
-# tests/test_tp.py pins.
+# share ONE step (compiled once a width of the packed block, at most
+# twice) — churn in slots/requests/spec mixes never adds entries, which
+# is what the per-shard-degree jit-cache guard in tests/test_tp.py pins.
 # tlint: disable=TL006(append-only compiled-program registry, the TP analogue of a @jax.jit function's cache, bounded by hosted configs)
 _TP_RAGGED_CACHE: dict = {}
 
@@ -1722,14 +1737,18 @@ def make_tp_ragged_step(
         # outputs — and the jit cache keys on the spelling, not the
         # placement. Pin ONE canonical form (the rank-expanded one the
         # step's own outputs carry, so steady-state decode chunks pass
-        # through untouched) to keep the hot loop at one program.
+        # through untouched) to keep the hot loop at one program a width.
         want = NamedSharding(mesh, P(*([None] * x.ndim)))
         sh = getattr(x, "sharding", None)
         if isinstance(sh, NamedSharding) and sh == want:
             return x
+        if isinstance(x, jax.ShapeDtypeStruct):  # lowered from shapes
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=want)
         return jax.device_put(x, want)
 
-    def step(params, blk, cache, *rest):
+    def placed(params, blk, cache, *rest):
+        """The program for this cache's arity and its operands as it is
+        called with them."""
         fn = plain if cache.k_scale is None else quant
         bt = _canon(cache.block_tables)
         ln = _canon(cache.lengths)
@@ -1737,16 +1756,24 @@ def make_tp_ragged_step(
             cache = replace(cache, block_tables=bt, lengths=ln)
         rest = list(rest)
         rest[11] = _canon(rest[11])  # counts (donated, like the cache)
-        return fn(params, blk, cache, *rest)
+        return fn, (params, blk, cache, *rest)
+
+    def step(*ops):
+        fn, ops = placed(*ops)
+        return fn(*ops)
+
+    def lower(*ops):
+        # the jitted program itself, lowered without running, at the
+        # placements a call gives it: what it compiles to is the program
+        # the call then finds (chip_smoke.py reads the kernel and the
+        # collectives off it; ContinuousEngine.build_steps builds it)
+        fn, ops = placed(*ops)
+        return fn.lower(*ops)
 
     step._cache_size = lambda: (  # compile-count guard hook, summed
         plain._cache_size() + quant._cache_size()
     )
-    # the jitted program itself, lowered without running (chip_smoke.py
-    # reads the kernel and the collectives off it)
-    step.lower = lambda params, blk, cache, *rest: (
-        plain if cache.k_scale is None else quant
-    ).lower(params, blk, cache, *rest)
+    step.lower = lower
     _TP_RAGGED_CACHE[key] = step
     return step
 

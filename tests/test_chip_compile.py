@@ -184,9 +184,15 @@ def _nbytes(tree) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
-def _step_operands(cfg, place, place_cache, kv_quant: str = "int8"):
+NARROW = 16  # the ladder's other width at pages of 16 and 9 verify rows
+
+
+def _step_operands(cfg, place, place_cache, kv_quant: str = "int8",
+                   width: int = 128):
     """Abstract operands of the ragged step at a default MLConfig worker's
-    shapes for ``cfg`` (weights first, the cache third)."""
+    shapes for ``cfg`` (weights first, the cache third); ``width``: the
+    packed block's (``prefill_chunk``, or the narrow one a chunk in which
+    nobody prefills packs: ``ContinuousEngine.block_widths``)."""
     from tensorlink_tpu.engine.paged import PagedKVCache
     from tensorlink_tpu.models.transformer import init_params
 
@@ -203,7 +209,7 @@ def _step_operands(cfg, place, place_cache, kv_quant: str = "int8"):
 
     i32, f32 = jnp.int32, jnp.float32
     return (
-        place(params), ctl(i32, S, 128), place_cache(cache), ctl(i32, S),
+        place(params), ctl(i32, S, width), place_cache(cache), ctl(i32, S),
         ctl(i32, S), ctl(i32, S), ctl(jnp.bool_, S), ctl(i32, S),
         ctl(i32, S), ctl(f32, S), ctl(i32, S), ctl(f32, S), ctl(f32, S),
         ctl(f32, S), ctl(i32, S, cfg.vocab_size), ctl(i32, S),
@@ -259,10 +265,12 @@ def _assert_pools_stay_put(compiled, cache, params, shards: int = 1) -> None:
 
 
 @pytest.mark.parametrize(
-    "kv_quant,tp", [("int8", 1), ("none", 1), ("int8", 4)],
-    ids=["int8", "bf16", "int8-tp4"],
+    "kv_quant,tp,width",
+    [("int8", 1, 128), ("none", 1, 128), ("int8", 4, 128),
+     ("int8", 1, NARROW), ("int8", 4, NARROW)],
+    ids=["int8", "bf16", "int8-tp4", "int8-narrow", "int8-tp4-narrow"],
 )
-def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp):
+def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp, width):
     """qwen3-4b's widths and page pool (8 slots x 4096, 2,049 pages a
     layer) with the depth cut to twelve layers (a third: what the step
     keeps beside the pools, ~35 MB of logits and control state a chip,
@@ -270,7 +278,9 @@ def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp):
     pools and the walk takes a layer index, so neither loop of the
     compiled step copies a pool, slices a layer's out or stacks one
     back. int8 and bf16 pages on one chip; the tensor-parallel step over
-    the described 2x2, where a chip's pool holds two kv heads."""
+    the described 2x2, where a chip's pool holds two kv heads. The same
+    at the narrow width of the block (the second program of the step: two
+    page merges a slot and layer for nine), on one chip and over the 2x2."""
     import dataclasses
 
     from tensorlink_tpu.engine.paged import paged_ragged_step
@@ -279,10 +289,11 @@ def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp):
     cfg = dataclasses.replace(config_presets()["qwen3-4b"], n_layers=12)
     if tp == 1:
         place = _on(SingleDeviceSharding(v5e_chips[0]))
-        ops = _step_operands(cfg, place, place, kv_quant)
+        ops = _step_operands(cfg, place, place, kv_quant, width)
         compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
     else:
-        compiled, ops = _tp4_step_compiled(v5e_chips, cfg)
+        compiled, ops = _tp4_step_compiled(v5e_chips, cfg, width)
+    assert ops[1].shape == (S, width)
     _assert_pools_stay_put(compiled, ops[2], ops[0], tp)
 
 
@@ -308,7 +319,7 @@ def test_ragged_step_fits_one_v5e_beside_the_weights(v5e):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM, ma
 
 
-def _tp4_step_compiled(v5e_chips, cfg):
+def _tp4_step_compiled(v5e_chips, cfg, width: int = 128):
     """The tensor-parallel step for ``cfg`` compiled over the four
     described chips at a default MLConfig worker's shapes: (compiled,
     its abstract operands)."""
@@ -336,7 +347,7 @@ def _tp4_step_compiled(v5e_chips, cfg):
             sharding=NamedSharding(mesh, PartitionSpec()),
         )
 
-    ops = _step_operands(cfg, place, on(tp_cache_specs(True)))
+    ops = _step_operands(cfg, place, on(tp_cache_specs(True)), width=width)
     step = make_tp_ragged_step(mesh, cfg, n_steps=8, spec_width=9, kernel=True)
     return step.lower(*ops).compile(), ops
 
@@ -357,20 +368,24 @@ def test_tp4_ragged_step_compiles_for_a_v5e_2x2_mesh(v5e_chips):
     assert ma.argument_size_in_bytes < 0.35 * resident, (ma, resident)
 
 
-def test_qwen2p5_7b_tp4_step_compiles_at_its_published_shapes(v5e_chips):
+@pytest.mark.parametrize("width", [128, NARROW], ids=["wide", "narrow"])
+def test_qwen2p5_7b_tp4_step_compiles_at_its_published_shapes(v5e_chips,
+                                                              width):
     """Qwen2.5-7B as ``qwen2p5-7b-tp4.decode-closed`` serves it: 28/4 heads
     of 128 (one kv head and seven query heads a chip), d_ff 18944 / 4, an
     untied vocabulary head of 152064 / 4 columns, q/k/v biases, int8 pages,
     8 slots x 4096. Not slow-marked: it is the one guard, off the chip, of
     the only four-chip cell. The step holds the Pallas walk and the
     gathers, and a chip's arguments are its share: a quarter of the layers
-    and the head, all of the embedding table, a quarter of the pages."""
+    and the head, all of the embedding table, a quarter of the pages. Both
+    programs of the step: the block at ``prefill_chunk`` and at the narrow
+    width a chunk in which nobody prefills packs."""
     import dataclasses
 
     from tensorlink_tpu.models.registry import config_presets
 
     cfg = dataclasses.replace(config_presets()["qwen2p5-7b"], max_seq_len=4096)
-    compiled, ops = _tp4_step_compiled(v5e_chips, cfg)
+    compiled, ops = _tp4_step_compiled(v5e_chips, cfg, width)
     _assert_pools_stay_put(compiled, ops[2], ops[0], shards=4)
     resident = _nbytes((ops[0], ops[2]))
     text = compiled.as_text()
@@ -378,7 +393,7 @@ def test_qwen2p5_7b_tp4_step_compiles_at_its_published_shapes(v5e_chips):
     ma = compiled.memory_analysis()
     embed = cfg.vocab_size * cfg.d_model * 2
     share = (resident - embed) / 4 + embed
-    print(f"qwen2p5-7b tp=4 on a described v5e 2x2: arguments a chip "
+    print(f"qwen2p5-7b tp=4, block {width} wide, on a described v5e 2x2: arguments a chip "
           f"{ma.argument_size_in_bytes / 1e9:.3f} GB (share {share / 1e9:.3f}), "
           f"temp {ma.temp_size_in_bytes / 1e9:.3f} GB, "
           f"{text.count('all-gather(')} all-gathers, "
@@ -396,7 +411,9 @@ def test_dots3_note_step_fits_one_v5e_with_its_window_kernel(v5e):
     weights + pools + temporaries fit the chip, no operation copies a
     latent pool, and the entry computation holds the three phase loops in
     order (the ragged pass's layers run as ONE loop). ~60 s: the one
-    program the new cell serves from, compiled nowhere else in tier-1."""
+    program the new cell serves from (a patterned model's block has one
+    width, ``ContinuousEngine.block_widths``), compiled nowhere else in
+    tier-1."""
     import json
     import re
     from pathlib import Path

@@ -51,10 +51,11 @@ def smoke(monkeypatch):
     monkeypatch.setattr(chip_smoke, "SEQ_LEN", 256)
     monkeypatch.setattr(chip_smoke, "PLATFORM", "cpu")
     # interpret mode lowers the kernel to plain HLO: there is no custom call
-    # to find, so the rehearsal only proves the step program lowers
+    # to find, so the rehearsal only proves the step programs lower
     monkeypatch.setattr(
         chip_smoke, "kernel_in_program",
-        lambda cont: bool(cont.lower_step().as_text()),
+        lambda cont: all(cont.lower_step(w).as_text()
+                         for w in cont.block_widths),
     )
     # the kernels, interpreted — both where the smoke calls them and where
     # the step program picked them up at import

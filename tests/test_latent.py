@@ -417,7 +417,9 @@ def test_the_step_keeps_its_three_phase_loops_and_names_its_scopes(tiny):
     from test_step_scopes import top_level_loops
 
     cfg, params = tiny
-    ce = _engine(cfg, params, spec_decode=True, spec_draft=4)
+    ce = _engine(cfg, params, spec_decode=True, spec_draft=3)
+    # a patterned model's block has one width (no second step program)
+    assert ce.block_widths == (ce.prefill_chunk,) == (8,)
     text = ce.lower_step().as_text(debug_info=True)
     loops = top_level_loops(text)
     assert len(loops) == 3, loops

@@ -226,6 +226,10 @@ LEGACY_ENGINE_KEYS = (
     # flat packing's count (ROADMAP S3): rows of the packed block that
     # carried a token / rows the ragged pass computed
     "ragged_rows_valid", "ragged_rows_computed",
+    # the width ladder (ROADMAP S5): packed blocks dispatched / those
+    # packed at the narrow width / those that fitted it and ran wide while
+    # its program was still being built
+    "ragged_blocks", "ragged_blocks_narrow", "ragged_blocks_narrow_unbuilt",
     # the paged kernels' live-span walk (ROADMAP S7): pages walked /
     # page slots of the same passes
     "attn_pages_live", "attn_pages_capacity",
@@ -373,10 +377,12 @@ def test_page_counters_follow_the_slot_contexts(tiny_engine):
 
 
 def test_row_counters_count_valid_and_computed_rows(tiny_engine):
-    """ROADMAP S3's count: a chunk adds slots x chunk rows to
-    ``ragged_rows_computed`` whatever is live, and only the rows that
-    carried a token to ``ragged_rows_valid``: the prompt's tokens in the
-    prefill chunk, one row per live slot in a decode-only chunk."""
+    """ROADMAP S5's count: a chunk adds slots x the width its block was
+    packed at to ``ragged_rows_computed`` whatever is live (a page where
+    no grant is longer, ``prefill_chunk`` otherwise), and only the rows
+    that carried a token to ``ragged_rows_valid``: the prompt's tokens in
+    the prefill chunk, one row per live slot in a decode-only chunk.
+    ``ragged_blocks`` / ``ragged_blocks_narrow`` count the blocks."""
     from tensorlink_tpu.engine.continuous import ContinuousEngine
 
     S, C = 3, 16
@@ -384,21 +390,65 @@ def test_row_counters_count_valid_and_computed_rows(tiny_engine):
         tiny_engine, max_slots=S, page_size=8, chunk_steps=2,
         prefill_chunk=C,
     )
-    ce.submit([1, 2, 3, 4, 5], max_new_tokens=8, seed=1)
+    N = ce.block_widths[0]
+    assert ce.block_widths == (8, C)
+    ce.submit(list(range(1, 10)), max_new_tokens=8, seed=1)
     ce.submit([6, 7, 8], max_new_tokens=8, seed=2)
-    ce.step_chunk()  # both prompts prefill in one block
+    ce.step_chunk()  # both prompts prefill in one block: 9 rows > a page
     s = ce.stats
     assert s["ragged_rows_computed"] == S * C
-    assert s["ragged_rows_valid"] == 5 + 3
-    ce.step_chunk()  # decode only: one row per live slot
+    assert s["ragged_rows_valid"] == 9 + 3
+    assert (s["ragged_blocks"], s["ragged_blocks_narrow"]) == (1, 0)
+    ce.step_chunk()  # decode only: one row per live slot, a page wide
     assert ce.live_slots == 2
     d = {k: ce.stats[k] - s[k] for k in s}
-    assert d["ragged_rows_computed"] == S * C
+    assert d["ragged_rows_computed"] == S * N
     assert d["ragged_rows_valid"] == 2
+    assert (d["ragged_blocks"], d["ragged_blocks_narrow"]) == (1, 1)
     ce.step_chunk(admit_only=True)  # dispatches nothing: counts nothing
-    assert ce.stats["ragged_rows_computed"] == 2 * S * C
+    assert ce.stats["ragged_rows_computed"] == S * (C + N)
+    assert ce.stats["ragged_blocks"] == 2
+    assert [r["block_rows"] for r in ce.recorder.records()] == [C, N]
     ce.run_until_idle()
+    fams = parse_exposition(ce.metrics.render({"model": "tiny"}))
+    assert ce.stats["ragged_blocks_narrow_unbuilt"] == 0  # nothing is built behind
+    for name in ("ragged_blocks", "ragged_blocks_narrow",
+                 "ragged_blocks_narrow_unbuilt"):
+        sample = fams[f"tlink_engine_{name}_total"]["samples"][0]
+        assert float(sample.rsplit(" ", 1)[1]) == ce.stats[name]
     ce.close()
+
+
+def _counter_metric_files() -> list[Path]:
+    import json
+
+    files = sorted((REPO / "benchmarks" / "layer_metrics").glob("*.json"))
+    return [f for f in files
+            if json.loads(f.read_text())["kind"].startswith("stats_")]
+
+
+@pytest.mark.parametrize(
+    "path", _counter_metric_files(), ids=lambda p: p.name[:-len(".json")])
+def test_a_counter_metric_reads_keys_the_engine_reports(path):
+    """Each of the benchmark's counter metrics (``layer_metrics/*.json`` of
+    a ``stats_*`` kind) names counters of ``_ENGINE_COUNTERS`` or gauges
+    of ``serving_snapshot()``: a counter renamed here would leave its
+    metric silent there, and the benchmark's files are not this repo's
+    tests' to see. ``narrow_block_share(.sessions)`` reads the width
+    ladder's ``ragged_blocks_narrow`` over ``ragged_blocks``."""
+    import json
+
+    from tensorlink_tpu.engine import continuous
+
+    spec = json.loads(path.read_text())
+    keys = [spec["key"]] if "key" in spec else spec["num"] + spec["den"]
+    counters = {c[0] for c in continuous._ENGINE_COUNTERS}
+    gauges = {"latent_pool_bytes", "weights_bytes_device_max",
+              "prefix_evictions"}  # serving_snapshot()'s own
+    assert keys and set(keys) <= counters | gauges, set(keys) - counters
+    if path.name.startswith("narrow_block_share"):
+        assert (spec["num"], spec["den"], spec["scale"]) == (
+            ["ragged_blocks_narrow"], ["ragged_blocks"], 100)
 
 
 def test_adhoc_counter_guard_is_tl106(tmp_path):

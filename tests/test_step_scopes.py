@@ -88,11 +88,17 @@ def top_level_loops(text: str) -> list[str]:
     return out
 
 
+@pytest.mark.parametrize("rung", [-1, 0], ids=["wide", "narrow"])
 @pytest.mark.parametrize("spec_width", [1, 9])
-def test_three_top_level_loops_in_phase_order(tiny_engine, spec_width):
+def test_three_top_level_loops_in_phase_order(tiny_engine, spec_width, rung):
+    """At each width of the ladder (one program a width): what reads the
+    phases by loop order reads a narrow chunk's as a wide one's."""
     ce = _cont(tiny_engine, spec_width)
     assert ce.spec_width == spec_width
-    text = ce.lower_step().as_text(debug_info=True)
+    assert ce.block_widths == (8 if spec_width == 1 else 16, 64)
+    width = ce.block_widths[rung]
+    text = ce.lower_step(width).as_text(debug_info=True)
+    assert f"tensor<4x{width}xi32>" in text  # the packed block's shape
     loops = top_level_loops(text)
     assert len(loops) == 3, loops
     for path, phase in zip(loops, STEP_PHASES):
@@ -103,7 +109,7 @@ def test_three_top_level_loops_in_phase_order(tiny_engine, spec_width):
     # that order: the layer scan with its trip count known, the verify
     # walk with none (its bound is data: the longest emitting draft + 1),
     # so no pass can inline it even at spec_width 1
-    compiled = ce.lower_step().compile().as_text()
+    compiled = ce.lower_step(width).compile().as_text()
     whiles = [ln for ln in compiled[compiled.index("ENTRY"):].splitlines()
               if " while(" in ln]
     assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)

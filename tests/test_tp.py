@@ -133,10 +133,16 @@ def test_tp_kv_shards_and_page_conservation(tiny):
     """KV pages shard by kv head — every device holds ALL pages over
     n_kv/tp local heads — the sharding survives chunk donation, the
     host-side conservation equation holds, and the hot loop stays ONE
-    compiled ragged program for the shard degree."""
+    compiled ragged program a width of the packed block (at most two) for
+    the shard degree."""
     cfg, params = tiny
     ce = _cont(cfg, params, tensor_parallel=2)
     _serve(ce)
+    # MIXES' prompts fit a page: a longer one packs the wide block too
+    ce.submit([3, 1, 4, 1, 5, 9, 2, 6, 5, 3], max_new_tokens=6, seed=2)
+    ce.run_until_idle()
+    assert {r["block_rows"] for r in ce.recorder.records()} == set(
+        ce.block_widths)
     k = ce.cache.k  # [L, n_pages, n_kv, page, hd]
     assert k.sharding.spec == jax.sharding.PartitionSpec(None, None, "tp")
     for shard in k.addressable_shards:
@@ -144,7 +150,9 @@ def test_tp_kv_shards_and_page_conservation(tiny):
         assert shard.data.shape[2] == cfg.n_kv_heads // 2  # heads split
     ce.check_page_conservation()
     sizes = ce.jit_cache_sizes()
-    assert sizes["tp_ragged_step"] == 1
+    assert sizes["tp_ragged_step"] == len(ce.block_widths) == 2
+    _serve(ce)  # and churn over both adds none
+    assert ce.jit_cache_sizes()["tp_ragged_step"] == 2
     # control state stays host-replicated: block tables shard nowhere
     assert ce.cache.block_tables.sharding.spec == jax.sharding.PartitionSpec()
     snap = ce.serving_snapshot()

@@ -182,6 +182,11 @@ def test_a_tp_load_keeps_one_copy_of_the_weights_a_device(tp):
     # the served stream is the unsharded engine's, so the layout is the
     # step's own (same seed, tp=1)
     prompt = [int(t) for t in np.random.default_rng(1).integers(0, 512, 40)]
+    # _ensure_cont returned with the wide program built and the narrow one
+    # on its thread (build_steps): joined here, so that the widths below
+    # do not depend on how long this machine compiles
+    assert cont._build is not None
+    cont._join_build()
     req = cont.submit(prompt, max_new_tokens=8)
     cont.run_until_idle()
     one = ContinuousEngine(
@@ -203,12 +208,15 @@ def test_a_tp_load_keeps_one_copy_of_the_weights_a_device(tp):
     assert head == cfg.vocab_size * item * got
     # three chunks: 32 prompt tokens alone (one pass, no decode step), then
     # the last 8 with the first token and three continuation steps, then
-    # four steps more
+    # four steps more: the last two pack the narrow block (no grant longer
+    # than its 16 rows), and the gathers follow the width
     assert cont.stats["decode_steps"] == 8
     assert cont.stats["tp_gather_calls"] == calls * 9
     S, C, W = SLOTS, CHUNK, cont.spec_width
+    assert cont.block_widths == (16, C)
     assert cont.stats["tp_gather_bytes"] == int(rows * S * C + head * S * W) + 2 * int(
-        rows * (S * C + 3 * S) + head * S * (W + 3))
+        rows * (S * 16 + 3 * S) + head * S * (W + 3))
+    assert (cont.stats["ragged_blocks"], cont.stats["ragged_blocks_narrow"]) == (3, 2)
     span = [s for s in get_tracer().collect("j1") if s["name"] == "load_stage"][-1]
     assert span["tp"] == tp and span["weights_bytes_device_max"] == share
     assert span["weights_bytes_device_min"] == share
