@@ -33,7 +33,15 @@ from tensorlink_tpu.api.schemas import (
 )
 from tensorlink_tpu.core.logging import get_logger
 from tensorlink_tpu.core.metrics import MetricsRegistry, render_prometheus
-from tensorlink_tpu.core.trace import get_tracer, mint_trace_id
+from tensorlink_tpu.core.trace import (
+    API_IN,
+    HTTP_FIRST_BYTE,
+    TOKEN_OUT,
+    current_span,
+    first_token_stamp,
+    get_tracer,
+    mint_trace_id,
+)
 
 MAX_BODY = 8 << 20
 MAX_CONCURRENT = 100  # reference api/node.py:537
@@ -562,6 +570,7 @@ class TensorlinkAPI:
         self._inflight += n
         try:
             if not gen.stream:
+                t_entry = time.monotonic()
                 # return_exceptions: every sibling dispatch completes before
                 # an error propagates — otherwise one failed choice would
                 # orphan n-1 running generations while _inflight is already
@@ -570,8 +579,10 @@ class TensorlinkAPI:
                 results = await asyncio.wait_for(
                     asyncio.gather(
                         *(self._ml(
-                            lambda: self.executor.generate_api(
-                                gen, trace_id=rid
+                            lambda: self._traced_in(
+                                rid, t_entry, "",
+                                self.executor.generate_api, gen,
+                                trace_id=rid,
                             )
                         ) for _ in range(n)),
                         return_exceptions=True,
@@ -630,6 +641,25 @@ class TensorlinkAPI:
         finally:
             self._inflight -= n
 
+    @staticmethod
+    def _traced_in(rid: str, t_entry: float, root: str, fn, *args, **kw):
+        """Run ``fn`` (the executor's ``generate_api``) on this pool
+        thread as the request's ``api_in`` span ends: the handler's entry
+        to here is the wait for the event loop and for a pool thread.
+        The span is the cause of what the thread records next
+        (``current_span``); a request without an id skips all of it."""
+        if not rid:
+            return fn(*args, **kw)
+        sid = get_tracer().record(
+            rid, API_IN, site="api", t0=t_entry,
+            dur_s=time.monotonic() - t_entry, parent=root,
+        )
+        tok = current_span.set(sid)
+        try:
+            return fn(*args, **kw)
+        finally:
+            current_span.reset(tok)  # the thread serves other requests
+
     async def _stream_generate(self, gen, fmt, writer, rid: str = "") -> None:
         """SSE: ML thread pushes deltas through call_soon_threadsafe.
 
@@ -637,13 +667,24 @@ class TensorlinkAPI:
         entry to the first delta written, on the API's own monotonic
         clock. Minus the engine's ``first_token`` span (submit to first
         emit, on the engine's clock) it is everything outside the engine
-        on both legs, with no clock shared between hosts."""
+        on both legs, with no clock shared between hosts. Inside it, at
+        its two ends: ``api_in`` (entry to ``generate_api`` on its pool
+        thread) and ``token_out`` (from the stamp the engine took as it
+        handed the first token on, which rode the stream's first frame,
+        to the first delta written and drained)."""
         t_entry = time.monotonic()
         first_byte = False
         loop = asyncio.get_running_loop()
         q: asyncio.Queue = asyncio.Queue()
+        tracer = get_tracer()
+        root = tracer.new_sid() if rid else ""  # http_first_byte's, ahead
+        first: dict = {}  # the engine's stamp, as the first delta found it
 
         def on_delta(piece: str) -> None:
+            if rid and not first:
+                # read on the thread that runs the stream callbacks,
+                # where ml/module.py put it; once a stream
+                first["stamp"] = first_token_stamp.get()
             loop.call_soon_threadsafe(q.put_nowait, ("delta", piece))
 
         def on_meta(meta: dict) -> None:
@@ -653,8 +694,9 @@ class TensorlinkAPI:
 
         def work():
             try:
-                res = self.executor.generate_api(
-                    gen, on_delta=on_delta, trace_id=rid, meta_cb=on_meta
+                res = self._traced_in(
+                    rid, t_entry, root, self.executor.generate_api,
+                    gen, on_delta=on_delta, trace_id=rid, meta_cb=on_meta,
                 )
                 loop.call_soon_threadsafe(q.put_nowait, ("done", res))
             except Exception as e:
@@ -681,9 +723,14 @@ class TensorlinkAPI:
                 await writer.drain()
                 if not first_byte:
                     first_byte = True
-                    get_tracer().record(
-                        rid, "http_first_byte", site="api",
-                        dur_s=time.monotonic() - t_entry,
+                    now = time.monotonic()
+                    tracer.record(
+                        rid, HTTP_FIRST_BYTE, site="api", t0=t_entry,
+                        dur_s=now - t_entry, sid=root,
+                    )
+                    tracer.record_since(
+                        rid, TOKEN_OUT, first.get("stamp"), end=now,
+                        site="api",
                     )
             elif kind == "meta":
                 writer.write(sse_event(fmt.stream_prelude(item)))

@@ -19,9 +19,24 @@ append under a short lock — no device sync, no compiled programs, and
 with no trace id on a request the engine skips the calls entirely.
 
 Span timestamps: ``dur_ms`` comes from ``time.monotonic`` pairs on one
-host (drift-free). ``ts`` is a wall-clock epoch anchor recorded ONCE per
-span for cross-worker ordering/joining only — it is never subtracted or
-compared for durations (tlint TL004 discipline).
+host (drift-free). ``t0`` is the span's START on the same clock
+(``time.monotonic()`` seconds: CLOCK_MONOTONIC, one clock for every
+process of a host, the clock of the flight recorder's ``t0``), ``host``
+a short tag of the machine's boot: two ``t0`` are compared, and spans
+laid end to end, only where their ``host`` agrees. ``parent`` is the
+``sid`` of the span that caused this one (empty at the root). ``ts`` is
+a wall-clock epoch anchor recorded ONCE per span for cross-worker
+ordering/joining only — it is never subtracted or compared for
+durations (tlint TL004 discipline).
+
+**The request path.** From the API's handler to the first SSE delta a
+request crosses four processes; a span is recorded at every boundary,
+where the work or the wait happens (``PATH_SPANS``; docs/SERVING.md
+"Telemetry" has the table). A start that was taken in another process
+rides the frame as a :func:`stamp` ``{t, host}``; the receiver records
+``dur_ms`` only where the stamp's ``host`` is its own
+(:meth:`Tracer.record_since`). Only the FIRST ``send_token`` of a
+stream carries one: nothing is stamped per token, step or chunk.
 
 **Flight recorder.** A bounded per-engine ring of per-step records
 (occupied slots, prefill grants, tokens emitted, page occupancy,
@@ -42,6 +57,78 @@ import secrets
 import threading
 import time
 from collections import OrderedDict, deque
+
+
+def _boot_tag() -> str:
+    """A short tag of this machine's boot: every process of the host
+    reads the same one, a reboot or another machine reads another.
+    Where the kernel offers none, a tag of this process alone — its
+    stamps then compare with nobody else's, which is the safe side."""
+    try:
+        with open("/proc/sys/kernel/random/boot_id", encoding="ascii") as f:
+            return f.read().strip().replace("-", "")[:12]
+    except OSError:
+        return "p" + secrets.token_hex(5)
+
+
+#: the clock ``time.monotonic()`` reads here, by name (span field ``host``)
+HOST = _boot_tag()
+
+# the request path's spans, in the order a request crosses them; the
+# recording sites use these names and benchmarks/layer_metrics/*.json
+# select them (tests/test_metrics.py holds the two together)
+HTTP_FIRST_BYTE = "http_first_byte"  # api: handler entry -> first delta out
+API_IN = "api_in"  # api: handler entry -> generate_api on its pool thread
+PREPARE = "prepare"  # validator: chat template, encode -> handed on
+HOP_IN = "hop_in"  # module's bridge -> the worker's work queue
+WORK_WAIT = "work_wait"  # in the worker's work queue -> handler starts
+SUBMIT = "submit"  # worker: handler start -> the engine stamped the request
+TOKEN_OUT = "token_out"  # engine hands on the first token -> delta drained
+PATH_SPANS = (
+    HTTP_FIRST_BYTE, API_IN, PREPARE, HOP_IN, WORK_WAIT, SUBMIT,
+    # the engine's own (engine/continuous.py), contiguous from submit:
+    # queue_wait + prefill + first_decode == first_token
+    "queue_wait", "admission", "prefill_chunk", "prefill", "first_decode",
+    "first_token", TOKEN_OUT,
+)
+# which span caused which, among one request's spans on the engine; the
+# two that end AFTER what they caused get their sid ahead of themselves
+SPANS_NAMED_AHEAD = ("first_token", "prefill")
+# tlint: disable=TL006(which span caused which — read-only table)
+ENGINE_SPAN_PARENT = {
+    "first_token": SUBMIT,
+    "queue_wait": "first_token",
+    "admission": "queue_wait",
+    "prefill": "first_token",
+    "prefill_chunk": "prefill",
+    "first_decode": "first_token",
+}
+
+
+def stamp() -> dict:
+    """A moment on this host's monotonic clock, fit to cross a process
+    boundary in a frame: the receiver measures from it only where
+    ``host`` is its own (:meth:`Tracer.record_since`)."""
+    return {"t": time.monotonic(), "host": HOST}
+
+
+# the stamp the engine took as it handed a stream's first token on
+# (where ``first_token`` ends, with that span's sid as ``parent``), on
+# the thread that runs the stream callbacks: the engine sets it around
+# the first token's callback, the worker's callback sends it with the
+# stream's first frame, ml/module.py sets it as the relay delivers that
+# frame, and the API's delta callback, further down the same call,
+# reads it: ``token_out`` starts there
+first_token_stamp: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+    "tlink_first_token_stamp", default=None
+)
+
+# the sid of the span that causes what the CURRENT thread does next for
+# its request: set as api_in and prepare are recorded, read where the
+# next hop's stamp is made (``parent`` of the span recorded from it)
+current_span: contextvars.ContextVar[str] = contextvars.ContextVar(
+    "tlink_span", default=""
+)
 
 # active trace id for log joining (core/logging.py json mode): set by the
 # code driving a request on the CURRENT thread (generate_api entry, the
@@ -77,6 +164,12 @@ class Tracer:
         self._tag = secrets.token_hex(4)
 
     # -- recording -------------------------------------------------------
+    def new_sid(self) -> str:
+        """A span id ahead of its span, for a span that others name as
+        their ``parent`` before it is recorded (a span is recorded at
+        its END; what it caused may end first)."""
+        return f"{self._tag}:{next(self._sid)}"
+
     def record(
         self,
         trace_id: str,
@@ -84,19 +177,33 @@ class Tracer:
         *,
         site: str = "",
         dur_s: float | None = None,
+        t0: float | None = None,
+        parent: str = "",
+        sid: str = "",
+        host: str = "",
         **attrs,
-    ) -> None:
-        """Append one span. ``dur_s`` is a monotonic-pair duration
-        measured by the caller (None = instantaneous event)."""
+    ) -> str:
+        """Append one span; returns its ``sid`` ("" when ``trace_id`` is
+        empty and nothing was stored). ``dur_s`` is a monotonic-pair
+        duration measured by the caller (None = an instantaneous event,
+        or a span whose start was stamped on another host's clock).
+        ``t0`` is its start on the monotonic clock named by ``host``
+        (this process's unless given); left out, the span is taken to
+        end now: ``t0`` = now - ``dur_s``."""
         if not trace_id:
-            return
+            return ""
+        if t0 is None:
+            t0 = time.monotonic() - (dur_s or 0.0)
         span = {
-            "sid": f"{self._tag}:{next(self._sid)}",
+            "sid": sid or self.new_sid(),
             "name": str(name),
             "site": str(site),
             # wall anchor for cross-worker ordering/log joining ONLY —
             # durations always come from the monotonic pair in dur_ms
             "ts": time.time(),
+            "t0": float(t0),
+            "host": host or HOST,
+            "parent": str(parent or ""),
         }
         if dur_s is not None:
             span["dur_ms"] = round(float(dur_s) * 1e3, 4)
@@ -111,32 +218,43 @@ class Tracer:
                     self._traces.popitem(last=False)  # LRU-ish: oldest out
             if len(spans) < self.max_spans:
                 spans.append(span)
+        return span["sid"]
 
-    class _SpanCtx:
-        __slots__ = ("tracer", "trace_id", "name", "site", "attrs", "_t0")
-
-        def __init__(self, tracer, trace_id, name, site, attrs):
-            self.tracer = tracer
-            self.trace_id = trace_id
-            self.name = name
-            self.site = site
-            self.attrs = attrs
-
-        def __enter__(self):
-            self._t0 = time.monotonic()
-            return self
-
-        def __exit__(self, *exc):
-            self.tracer.record(
-                self.trace_id, self.name, site=self.site,
-                dur_s=time.monotonic() - self._t0, **self.attrs,
-            )
-            return False
-
-    def span(self, trace_id: str, name: str, *, site: str = "", **attrs):
-        """Context manager measuring a monotonic duration around a block
-        (records nothing when ``trace_id`` is empty — record() gates)."""
-        return Tracer._SpanCtx(self, trace_id, name, site, attrs)
+    def record_since(
+        self,
+        trace_id: str,
+        name: str,
+        start: dict | None,
+        *,
+        end: dict | float | None = None,
+        site: str = "",
+        parent: str = "",
+        **attrs,
+    ) -> str:
+        """A span that began at a :func:`stamp` ``start`` (taken in any
+        process, maybe on another machine) and ends at ``end``: a stamp,
+        a reading of this process's ``time.monotonic()``, or now. It
+        gets a ``dur_ms`` only where both ends lie on one host's clock;
+        a start stamped on a foreign host gives a span at that host's
+        ``t0`` with no duration. A frame without a stamp (an old peer)
+        records nothing."""
+        if not trace_id or not isinstance(start, dict) or "t" not in start:
+            return ""
+        host = str(start.get("host") or "")
+        if isinstance(end, dict):
+            end_t, end_host = end.get("t"), str(end.get("host") or "")
+        else:
+            end_t = time.monotonic() if end is None else end
+            end_host = HOST
+        t0 = float(start["t"])
+        dur = None
+        if host and host == end_host and end_t is not None:
+            dur = max(float(end_t) - t0, 0.0)
+        return self.record(
+            trace_id, name, site=site, dur_s=dur, t0=t0,
+            parent=parent or str(start.get("parent") or ""),
+            host=host or "?", **attrs,
+        )
 
     # -- merge / query ---------------------------------------------------
     def ingest(self, trace_id: str, spans: list[dict]) -> int:
@@ -165,10 +283,29 @@ class Tracer:
         return added
 
     def collect(self, trace_id: str) -> list[dict]:
-        """All spans recorded/ingested for a trace (ts-ordered copy)."""
+        """All spans recorded/ingested for a trace, in start order: by
+        ``t0`` among the spans of one ``host`` (one clock, whichever
+        process stamped them: two processes of a host may read the wall
+        clock in the wrong order within a millisecond), by the wall
+        anchor ``ts`` across hosts and for spans of a peer that sends no
+        ``t0``. A copy."""
         with self._lock:
             spans = list(self._traces.get(trace_id, ()))
-        return sorted(spans, key=lambda s: s.get("ts", 0.0))
+        spans.sort(key=lambda s: s.get("ts", 0.0))
+        # each host's spans keep the places ts gave that host, and take
+        # them in the order of their own clock
+        places: dict[str, list[int]] = {}
+        for i, s in enumerate(spans):
+            if "t0" in s and s.get("host"):
+                places.setdefault(s["host"], []).append(i)
+        for idx in places.values():
+            for i, s in zip(idx, sorted(
+                (spans[i] for i in idx),
+                # of two that start together the longer one holds the other
+                key=lambda s: (s["t0"], -s.get("dur_ms", 0.0)),
+            )):
+                spans[i] = s
+        return spans
 
     def known(self, trace_id: str) -> bool:
         with self._lock:
@@ -241,9 +378,16 @@ class FlightRecorder:
 
 
 __all__ = [
+    "ENGINE_SPAN_PARENT",
     "FlightRecorder",
+    "HOST",
+    "PATH_SPANS",
+    "SPANS_NAMED_AHEAD",
     "Tracer",
+    "current_span",
     "current_trace",
+    "first_token_stamp",
     "get_tracer",
     "mint_trace_id",
+    "stamp",
 ]

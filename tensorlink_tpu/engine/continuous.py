@@ -77,7 +77,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import faults
 from ..core.metrics import MetricsRegistry
-from ..core.trace import FlightRecorder, get_tracer
+from ..core.trace import (
+    ENGINE_SPAN_PARENT,
+    HOST,
+    SPANS_NAMED_AHEAD,
+    FlightRecorder,
+    first_token_stamp,
+    get_tracer,
+)
 from ..models.transformer import tp_partition_specs, tp_shardable
 from ..parallel.mesh import serving_mesh
 from .generate import GenerationEngine
@@ -242,6 +249,13 @@ CHUNK_PHASES = (
 
 FLIGHT_CAPACITY = 1024  # chunks the flight recorder keeps
 MIGRATION_TTL_S = 120.0  # an engine's ``migration_ttl_s`` starts here
+
+
+def _timed(fn) -> float:
+    """Run ``fn``; the seconds it took on this thread's wall clock."""
+    t0 = time.monotonic()
+    fn()
+    return time.monotonic() - t0
 
 
 class _Phase:
@@ -505,6 +519,9 @@ class ContinuousRequest:
     # distributed-trace id minted by the API server (empty = untraced:
     # the engine skips every span-recording call for this request)
     trace_id: str = ""
+    # name -> sid of the spans this request's other spans name as their
+    # ``parent`` before they are recorded (a span is recorded at its end)
+    span_sids: dict | None = None
     prefill_done_t: float = 0.0  # when the slot left the prefilling set
     # deepest cache tier that contributed to this admission's hit region
     # ("none" | "hbm" | "host" | "fleet") — rides the admission span so
@@ -802,6 +819,18 @@ class ContinuousEngine:
         # an engine nobody called it on builds each width at its first
         # call, like any jitted function
         self._build: Future | None = None
+        # the step programs' build, counted where it happens (the gauges
+        # ``step_build_ms`` / ``step_build_waited_ms`` of
+        # ``serving_snapshot``): seconds spent tracing, lowering and
+        # compiling or fetching every step program this engine built, on
+        # whatever thread, and seconds a serving path spent inside that
+        # work or waiting for it. Written only where a program is built
+        # or waited for (``build_steps``, ``_join_build``, the first call
+        # at a width: ``_note_first_call``); ``_unbuilt`` empties there
+        # and a chunk's path reads nothing else of this
+        self._step_build_s = 0.0
+        self._step_build_waited_s = 0.0
+        self._unbuilt = set(self.block_widths)
         # optional TOTAL draft tokens per step shared across speculating
         # slots (0 = each gets a full draft): bounds the extra verify
         # compute like prefill_budget bounds prefill compute — and since
@@ -1067,14 +1096,31 @@ class ContinuousEngine:
         self._stat[key].inc(n)
 
     def _trace(self, req, name: str, dur_s: float | None = None,
-               **attrs) -> None:
+               t0: float | None = None, **attrs) -> str:
         """Record one span for a traced request (no-op when the request
-        carries no trace id — the disabled-mode fast path)."""
-        if req is not None and req.trace_id:
-            self.tracer.record(
-                req.trace_id, name, site=self.trace_site, dur_s=dur_s,
-                **attrs,
-            )
+        carries no trace id — the disabled-mode fast path). ``t0`` is
+        its start on the monotonic clock (left out: it ends now);
+        ``parent`` follows ``ENGINE_SPAN_PARENT``, by ids minted ahead
+        of the spans that end after what they caused. Returns the sid."""
+        if req is None or not req.trace_id:
+            return ""
+        sids = req.span_sids
+        if sids is None:
+            sids = req.span_sids = {}
+        cause = ENGINE_SPAN_PARENT.get(name, "")
+        if cause in SPANS_NAMED_AHEAD and cause not in sids:
+            sids[cause] = self.tracer.new_sid()
+        ahead = name in SPANS_NAMED_AHEAD
+        sid = self.tracer.record(
+            req.trace_id, name, site=self.trace_site, dur_s=dur_s, t0=t0,
+            parent=sids.get(cause, ""),
+            # an id named ahead is used once: a second round (a
+            # preempted request's) names a new one
+            sid=sids.pop(name, "") if ahead else "", **attrs,
+        )
+        if not ahead:
+            sids[name] = sid  # what it causes ends later and finds it here
+        return sid
 
     # -- client side -----------------------------------------------------
     def submit(
@@ -1091,6 +1137,7 @@ class ContinuousEngine:
         on_finish: Callable[[ContinuousRequest], None] | None = None,
         adopt: str | None = None,
         trace_id: str | None = None,
+        trace_parent: str = "",
         speculative: bool = False,
         handoff: bool = False,
     ) -> ContinuousRequest:
@@ -1130,6 +1177,7 @@ class ContinuousEngine:
             on_finish=on_finish,
             adopt=adopt,
             trace_id=str(trace_id or ""),
+            span_sids={"submit": trace_parent} if trace_parent else None,
             speculative=bool(speculative) and self.spec_decode,
             handoff=(
                 bool(handoff) and self.handoff_after_prefill
@@ -1341,18 +1389,28 @@ class ContinuousEngine:
                     # queue_wait and prefill spans by construction, so the
                     # three parts sum to the first_token span's TTFT)
                     t_pf = req.prefill_done_t or req.admit_t or req.submit_t
-                    self._trace(req, "first_decode", dur_s=now - t_pf)
-                    self._trace(
+                    self._trace(req, "first_decode", dur_s=now - t_pf, t0=t_pf)
+                    sid = self._trace(
                         req, "first_token", dur_s=now - req.submit_t,
-                        chunk=step,
+                        t0=req.submit_t, chunk=step,
+                    )
+                    # where first_token ends the way out starts: left on
+                    # this thread for the stream callback, which sends it
+                    # with the stream's first frame (``token_out``)
+                    first_token_stamp.set(
+                        {"t": now, "host": HOST, "parent": sid}
                     )
             cb = req.stream_cb
-            if cb is not None:
-                for i in range(sent):
-                    if cb(req.tokens[base + i]):
-                        sent, cancel = i + 1, True
-                        del req.tokens[base + sent:]
-                        break
+            try:
+                if cb is not None:
+                    for i in range(sent):
+                        if cb(req.tokens[base + i]):
+                            sent, cancel = i + 1, True
+                            del req.tokens[base + sent:]
+                            break
+            finally:
+                if first and req.trace_id:
+                    first_token_stamp.set(None)
             if finish:
                 self._finish(req, finished=True)
             elif cancel:
@@ -2910,6 +2968,10 @@ class ContinuousEngine:
             ),
             "weights_bytes_device_max": max(self.weights_bytes_device),
             "weights_bytes_device_min": min(self.weights_bytes_device),
+            # what building the step programs cost, and what of it a
+            # serving path waited for (set at build time only)
+            "step_build_ms": round(self._step_build_s * 1e3, 3),
+            "step_build_waited_ms": round(self._step_build_waited_s * 1e3, 3),
         })
         if self.pool is not None:
             # co-hosting: the shared pool's occupancy plus THIS tenant's
@@ -3032,11 +3094,11 @@ class ContinuousEngine:
                 # prefix-cache walk, COW, any preemption teardown)
                 self._trace(
                     req, "queue_wait", dur_s=req.admit_t - req.submit_t,
-                    priority=req.priority,
+                    t0=req.submit_t, priority=req.priority,
                 )
                 self._trace(
                     req, "admission", dur_s=req.admit_t - t_adm,
-                    slot=req.slot, cache_hit_tokens=req.prefill_pos,
+                    t0=t_adm, slot=req.slot, cache_hit_tokens=req.prefill_pos,
                     # deepest tier that fed the hit region — "hbm",
                     # "host", "fleet", or "none" (adopted migrations
                     # keep their own "adopt" span instead)
@@ -3163,7 +3225,9 @@ class ContinuousEngine:
         what it raised; from here on every width is packed."""
         build, self._build = self._build, None
         if build is not None:
-            build.result()
+            t0 = time.monotonic()
+            self._step_build_s += build.result()  # the thread's own seconds
+            self._step_build_waited_s += time.monotonic() - t0
 
     def _pack_drafts(self, blk, n_valid, remaining):
         """Draft-budget packing, the speculative half of the packed
@@ -3327,22 +3391,48 @@ class ContinuousEngine:
         An engine of one width has nothing to build ahead: its first
         chunk builds its program, as ever (built here, ahead of the
         call, dots3's one program cost set-up 5 s more). Call it on an
-        idle engine."""
+        idle engine.
+
+        What this costs is counted here and where the thread is joined:
+        ``step_build_ms`` takes both lowerings and each fetch's own
+        seconds (what overlaps counts twice), ``step_build_waited_ms``
+        this call from entry to return, then the join's wait."""
         if len(self.block_widths) == 1 or self._build is not None:
             return
+        t0 = time.monotonic()
         pool = ThreadPoolExecutor(1, thread_name_prefix="build-step")
         try:
-            widest = pool.submit(self.lower_step().compile)
+            widest = pool.submit(_timed, self.lower_step().compile)
             # one worker: the narrow program's fetch follows the widest's
             # on the thread. On a thread of its own it started 0.2 s
             # sooner and took 1.7-1.8 s for 1.0, beside the widest's end
             # and the first chunk (PERF.md section 7)
             self._build = pool.submit(
-                self.lower_step(self.block_widths[0]).compile
+                _timed, self.lower_step(self.block_widths[0]).compile
             )
-            widest.result()  # what it raised is raised here
+            lowered = time.monotonic() - t0  # both, traced too: this thread
+            built = widest.result()  # what it raised is raised here
         finally:
             pool.shutdown(wait=False)  # the thread ends with its queue
+        # the narrow program's seconds follow where it is joined
+        self._step_build_s += lowered + built
+        self._step_build_waited_s += time.monotonic() - t0
+        self._unbuilt.clear()  # no call of this engine builds a program
+
+    def _step_programs(self) -> int:
+        """Step programs in the jit cache this engine's calls fill."""
+        step = paged_ragged_step if self._tp_step is None else self._tp_step
+        return step._cache_size()
+
+    def _note_first_call(self, width: int, programs: int, dur_s: float):
+        """After this engine's first call at ``width`` (no
+        ``build_steps`` came before it): where the call grew the jit
+        cache from ``programs`` it built its program, and the dispatch
+        phase's ``dur_s`` is build time that a serving path waited for."""
+        self._unbuilt.discard(width)
+        if self._step_programs() > programs:
+            self._step_build_s += dur_s
+            self._step_build_waited_s += dur_s
 
     # tlint: hot-path
     def step_chunk(self, *, admit_only: bool = False) -> bool:
@@ -3390,6 +3480,7 @@ class ContinuousEngine:
             if pack is not None:
                 blk, starts, n_valid, n_spec, emit, remaining, eos_arr, \
                     completing, handoff_done, grants = pack
+                programs = self._step_programs() if self._unbuilt else 0
                 with _Phase(ph, "dispatch"):
                     # read before delivery releases a finished slot
                     any_sampled = bool((self._temp > 0).any())
@@ -3413,6 +3504,10 @@ class ContinuousEngine:
                                     self.spec_width, self.use_kernel,
                                 )
                             )
+                if self._unbuilt:
+                    self._note_first_call(
+                        blk.shape[1], programs, ph["dispatch"]
+                    )
                 with _Phase(ph, "wait"):
                     # the device runs this chunk: the one before it leaves
                     # for its callbacks meanwhile, nothing below reads
@@ -3580,6 +3675,7 @@ class ContinuousEngine:
                 self._trace(
                     req, "prefill",
                     dur_s=(now - req.admit_t) if req.admit_t else None,
+                    t0=req.admit_t or None,
                     tokens=req.prefill_pos, chunk=step,
                 )
             del self._prefilling[s]
@@ -3598,6 +3694,7 @@ class ContinuousEngine:
             self._trace(
                 req, "prefill",
                 dur_s=(now - req.admit_t) if req.admit_t else None,
+                t0=req.admit_t or None,
                 tokens=req.prefill_pos, chunk=step,
             )
             self._tok[s] = int(req.prefill_tokens[-1])

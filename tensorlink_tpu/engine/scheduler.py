@@ -75,7 +75,11 @@ def normalize_priority(priority) -> str:
 
 class SchedulerOverloaded(RuntimeError):
     """A class queue is at its cap (the engine-side backstop behind the
-    API layer's 429 gate). Carries what the 429 body needs."""
+    API layer's 429 gate). Carries what the 429 body needs.
+    ``retry_after`` (the ``rejected`` span's attribute too) is
+    ``estimate_wait``: requests ahead over slots, times the service
+    EWMA, from the engine's ``submit`` on. What a request waits before
+    it reaches the engine (``work_wait``, core/trace.py) is not in it."""
 
     def __init__(self, priority: str, depth: int, cap: int, retry_after: float):
         super().__init__(
@@ -325,6 +329,13 @@ class RequestScheduler:
 
     # tlint: holds-lock(the engine lock)
     def note_first_token(self, req, ttft_s: float) -> None:
+        """One first-token sample of ``req``'s class. The engine's clock
+        starts at ITS ``submit`` (``now - req.submit_t``), not at the
+        request's arrival: the API, the validator, the bridges and the
+        wait on the worker's work queue (``work_wait``, behind a running
+        chunk; core/trace.py ``PATH_SPANS``) lie before it, so the class
+        percentiles of ``/stats`` understate what a client sees by that
+        much."""
         st = self.by_class[req.priority]
         ttft = max(float(ttft_s), 0.0)
         st.ttfts.append(ttft)

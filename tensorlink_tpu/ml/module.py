@@ -1271,6 +1271,13 @@ class DistributedModel:
                 # engine records its spans under it and ships them back on
                 # the response (docs/SERVING.md "Telemetry")
                 body["trace"] = trace_id
+                # the way in's third span starts as this frame is handed
+                # to the bridge (_issue_generate stamps it: ``hop_in`` on
+                # the worker, core/trace.py); its cause is what this
+                # thread recorded last for the request
+                from tensorlink_tpu.core.trace import current_span
+
+                body["stamp"] = {"parent": current_span.get()}
             if adopt:
                 # resume-after-migration: the destination staged our KV
                 # pages under this ticket — admission binds them instead
@@ -1278,9 +1285,7 @@ class DistributedModel:
                 body["adopt"] = adopt
             try:
                 if stream_cb is None:
-                    resp = self._request(
-                        wid, proto.GENERATE, body, _repaired=True
-                    )
+                    resp = self._issue_generate(wid, body)
                     self._note_serving(resp)
                     mig = resp.get("migrated")
                     if mig is not None:
@@ -1385,6 +1390,17 @@ class DistributedModel:
                 )
                 self._repair(wid)
 
+    def _issue_generate(self, wid: str, body: dict):
+        """One continuous GENERATE to ``wid``. A traced request's frame
+        is stamped ``{t, host}`` here, as it is handed to the bridge:
+        where the worker's ``hop_in`` span starts (an old worker ignores
+        the key; an untraced request carries none)."""
+        if "stamp" in body:
+            from tensorlink_tpu.core.trace import stamp
+
+            body["stamp"].update(stamp())
+        return self._request(wid, proto.GENERATE, body, _repaired=True)
+
     def _drain_continuous_stream(
         self, wid: str, body: dict, delivered: list[int], stream_cb
     ) -> tuple[list[int], bool, dict | None]:
@@ -1403,9 +1419,7 @@ class DistributedModel:
 
         def issue():
             try:
-                result["resp"] = self._request(
-                    wid, proto.GENERATE, body, _repaired=True
-                )
+                result["resp"] = self._issue_generate(wid, body)
             except Exception as e:
                 result["err"] = e
 
@@ -1418,6 +1432,14 @@ class DistributedModel:
                 "next_tokens", {"stream": stream_id, "timeout": 5.0},
                 timeout=10.0,
             )
+            if tk.get("stamp"):
+                # the stream's FIRST frame carried the moment the engine
+                # handed the first token on: left on this thread for the
+                # API's delta callback, which the stream_cb below reaches
+                # (``token_out``; generate_api clears it)
+                from tensorlink_tpu.core.trace import first_token_stamp
+
+                first_token_stamp.set(tk["stamp"])
             for _row, tok in tk.get("tokens") or ():
                 toks.append(int(tok))
                 cancel = stream_cb([int(tok)])

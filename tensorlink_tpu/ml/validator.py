@@ -1294,15 +1294,23 @@ class DistributedValidator:
         batcher to the engine so every hop's spans land under it, and is
         installed as the ACTIVE trace on this worker thread so json-mode
         log lines join the trace too (core/logging.py)."""
-        from tensorlink_tpu.core.trace import current_trace
+        from tensorlink_tpu.core.trace import (
+            current_span,
+            current_trace,
+            first_token_stamp,
+        )
 
         tid = str(trace_id or "")
         token = current_trace.set(tid)
         try:
             return self._generate_api(req, on_delta, tid, meta_cb)
         finally:
-            # the pool thread serves many requests — never leak the id
+            # the pool thread serves many requests — never leak the id,
+            # nor what this request's hops left for each other
             current_trace.reset(token)
+            if tid:
+                current_span.set("")
+                first_token_stamp.set(None)
 
     def _generate_api(self, req, on_delta, trace_id: str,
                       meta_cb=None) -> dict:
@@ -1314,6 +1322,7 @@ class DistributedValidator:
             normalize_generate_args,
         )
 
+        t_entry = time.monotonic()
         job = self.hosted.get(req.hf_name)
         if job is None or job.status != "ready":
             raise ModelNotReady(req.hf_name, job.status if job else "absent")
@@ -1460,6 +1469,21 @@ class DistributedValidator:
         spec = bool(getattr(req, "lookahead", False)) and args["temperature"] == 0.0
         spec_cont = bool(getattr(req, "speculative", False))
         beams_used = None
+        if trace_id:
+            # the request path's second span (core/trace.py PATH_SPANS):
+            # this entry to the tokenised prompt handed on: chat template,
+            # encode, the journal's admit record, the stream closures
+            from tensorlink_tpu.core.trace import (
+                PREPARE,
+                current_span,
+                get_tracer,
+            )
+
+            current_span.set(get_tracer().record(
+                trace_id, PREPARE, site="validator", t0=t_entry,
+                dur_s=time.monotonic() - t_entry,
+                parent=current_span.get(), prompt_tokens=len(ids),
+            ))
         if (
             rjid
             and n_beams == 1

@@ -1670,6 +1670,8 @@ class DistributedWorker:
         from tensorlink_tpu.engine.sampling import SamplingParams
 
         rt = self._runtime(p["job_id"])
+        if p.get("trace") and "stamp" in p:
+            self._trace_way_in(rt, p)
         if rt.engine is None:
             raise ValueError("generate requires a whole-model stage")
         prompts = [list(map(int, row)) for row in p["prompts"]]
@@ -1829,6 +1831,36 @@ class DistributedWorker:
         )
 
     # -- continuous batching (engine/continuous.py) ----------------------
+    def _trace_way_in(self, rt: "StageRuntime", p: dict) -> None:
+        """A traced GENERATE as the ML loop takes it off the work queue:
+        ``hop_in`` (the validator's stamp as ml/module.py handed the
+        frame to its bridge, to ``NetBridge.post_work``'s stamp) and
+        ``work_wait`` (from there to now: FIFO behind whatever the loop
+        was running, which for a request that arrives during a chunk is
+        what is left of ``step_chunk``). ``chunk`` is the step of the
+        last chunk the engine finished before this moment
+        (``recorder.next_step - 1``: the id of its record and of its
+        ``tlink:chunk``), the one waited behind where the wait is long.
+        Durations only where the stamps share this host's clock; leaves
+        the handler's start and its cause in ``p["_way_in"]`` for the
+        ``submit`` span."""
+        from tensorlink_tpu.core.trace import HOP_IN, WORK_WAIT, get_tracer
+
+        t_in = time.monotonic()
+        tracer, tid = get_tracer(), str(p["trace"])
+        site = str(self.node.node_id or "")
+        queued = p.get("stamp_q")
+        sid = tracer.record_since(
+            tid, HOP_IN, p["stamp"], end=queued or t_in, site=site,
+        )
+        if queued:
+            sid = tracer.record_since(
+                tid, WORK_WAIT, queued, end=t_in, site=site, parent=sid,
+                **({"chunk": rt.cont.recorder.next_step - 1}
+                   if rt.cont is not None else {}),
+            )
+        p["_way_in"] = (t_in, sid)
+
     def _generate_continuous(self, rt: "StageRuntime", p: dict,
                              prompts: list[list[int]]) -> bool:
         """Admit a GENERATE flagged ``continuous`` into the job's slot
@@ -1862,6 +1894,7 @@ class DistributedWorker:
                 self.draining, None, [],
             )
             return True
+        built = rt.cont is None or rt.cont.engine is not rt.engine
         cont = self._ensure_cont(rt)
         if cont is None:
             return False
@@ -1886,6 +1919,9 @@ class DistributedWorker:
             rt, cont, peer=peer, rid=p["rid"], stream_id=stream_id,
             tid=tid, jrid=jrid,
         )
+        # the ``submit`` span's id ahead of it: the engine's first_token
+        # names it as its cause (core/trace.py)
+        sid_submit = cont.tracer.new_sid() if tid else ""
         req = cont.submit(
             prompts[0],
             max_new_tokens=int(p.get("max_new_tokens", 128)),
@@ -1900,6 +1936,7 @@ class DistributedWorker:
             # re-prefilling (engine falls back when the ticket is stale)
             adopt=p.get("adopt") or None,
             trace_id=tid,
+            trace_parent=sid_submit,
             # draft/verify opt-in (no-op unless this engine's spec_decode
             # is on; streams bit-identical either way)
             speculative=bool(p.get("speculative", False)),
@@ -1921,6 +1958,18 @@ class DistributedWorker:
             "peer": peer, "rid": p["rid"], "stream": stream_id,
             "trace": tid, "jrid": jrid,
         }
+        if tid and "_way_in" in p:
+            # the handler's start to here: sampling knobs, the channels,
+            # the engine's own submit, and the slot engine where this
+            # request built it
+            from tensorlink_tpu.core.trace import SUBMIT
+
+            t_in, cause = p["_way_in"]
+            cont.tracer.record(
+                tid, SUBMIT, site=str(self.node.node_id or ""), t0=t_in,
+                dur_s=req.submit_t - t_in, parent=cause, sid=sid_submit,
+                **({"slot_engine_built": True} if built else {}),
+            )
         if jrid:
             rt.jstreams[jrid] = req
         self._schedule_cont(rt)
@@ -1941,10 +1990,18 @@ class DistributedWorker:
             # fire-and-forget per token; cancel frames (confirmed stop
             # matches) poll once per chunk — overrun bounded like the
             # compiled chunked stream
-            self.bridge.notify(
-                "send_token",
-                {"peer": peer, "stream": stream_id, "tokens": [[0, int(tok)]]},
-            )
+            msg = {"peer": peer, "stream": stream_id,
+                   "tokens": [[0, int(tok)]]}
+            if tid and not state["n"]:
+                # a traced stream's FIRST frame alone carries the moment
+                # the engine handed this token on (it left it on this
+                # thread: the API's ``token_out`` starts there)
+                from tensorlink_tpu.core.trace import first_token_stamp
+
+                first = first_token_stamp.get()
+                if first is not None:
+                    msg["stamp"] = first
+            self.bridge.notify("send_token", msg)
             state["n"] += 1
             if state["n"] % cont.chunk_steps == 0:
                 try:

@@ -31,6 +31,8 @@ import traceback
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+from tensorlink_tpu.core.trace import stamp
+
 
 class RemoteError(RuntimeError):
     """A command failed in the network process; carries its traceback."""
@@ -122,6 +124,12 @@ class NetBridge:
         self._task: asyncio.Task | None = None
 
     def post_work(self, kind: str, item: Any) -> None:
+        if isinstance(item, dict) and "stamp" in item:
+            # a traced request's frame (ml/module.py stamped it as it
+            # left): the moment it goes onto the work queue ends its
+            # ``hop_in`` and starts its ``work_wait`` (core/trace.py);
+            # any other item is put as it came
+            item["stamp_q"] = stamp()
         try:
             loop = asyncio.get_running_loop()
         except RuntimeError:

@@ -245,7 +245,7 @@ class RoleServer(TensorNode):
     async def cmd_send_token(self, p) -> bool:
         await self.send_token(
             self._conn(p["peer"]), p["stream"], p.get("tokens", []),
-            done=p.get("done", False),
+            done=p.get("done", False), stamp=p.get("stamp"),
         )
         return True
 
@@ -254,9 +254,15 @@ class RoleServer(TensorNode):
             tokens, done = await self.next_tokens(
                 p["stream"], timeout=p.get("timeout", 30.0)
             )
+            out = {"tokens": tokens, "done": done}
+            # the stamp a traced stream's FIRST frame carried leaves with
+            # the first drain (core/trace.py ``token_out``)
+            st = self.stream_stamps.pop(p["stream"], None)
+            if st is not None:
+                out["stamp"] = st
             if done:
                 self.drop_stream(p["stream"])
-            return {"tokens": tokens, "done": done}
+            return out
         except asyncio.TimeoutError:
             return {"tokens": [], "done": False, "timeout": True}
 

@@ -40,6 +40,9 @@ class TensorNode(P2PNode):
         # queue cannot grow unboundedly
         self.forward_tokens_to_ml = True
         self.stream_buffers: dict[str, asyncio.Queue] = {}  # stream_id -> tokens
+        # stream_id -> the stamp its first frame carried, until the first
+        # drain takes it (traced streams only; dropped with the buffer)
+        self.stream_stamps: dict[str, dict] = {}
         self.register(proto.TOKEN, self._handle_token)
         self.register(proto.STREAM_END, self._handle_token)
 
@@ -103,13 +106,21 @@ class TensorNode(P2PNode):
     # validator_thread.py:211-265)
     # ------------------------------------------------------------------
     async def send_token(
-        self, conn: Connection, stream_id: str, token_ids: list[int], done: bool = False
+        self, conn: Connection, stream_id: str, token_ids: list[int],
+        done: bool = False, stamp: dict | None = None,
     ) -> None:
         tag = proto.STREAM_END if done else proto.TOKEN
-        await conn.send_control(tag, {"stream": stream_id, "tokens": token_ids})
+        body = {"stream": stream_id, "tokens": token_ids}
+        if stamp:
+            # a traced stream's first frame alone: when the engine handed
+            # the token on (core/trace.py; an old peer ignores the key)
+            body["stamp"] = stamp
+        await conn.send_control(tag, body)
 
     async def _handle_token(self, conn, kind, tag, body) -> None:
         q = self.stream_buffers.setdefault(body["stream"], asyncio.Queue())
+        if body.get("stamp"):
+            self.stream_stamps[body["stream"]] = body["stamp"]
         await q.put((body.get("tokens", []), tag == proto.STREAM_END))
         if self.work is not None and self.forward_tokens_to_ml:
             self.post_work("token", {
@@ -127,6 +138,7 @@ class TensorNode(P2PNode):
 
     def drop_stream(self, stream_id: str) -> None:
         self.stream_buffers.pop(stream_id, None)
+        self.stream_stamps.pop(stream_id, None)
 
     # ------------------------------------------------------------------
     # ML-process handoff

@@ -442,6 +442,80 @@ def test_close_drops_a_build_under_way(tiny):
 
 
 # ---------------------------------------------------------------------------
+# the build is counted where it happens (``step_build_ms``,
+# ``step_build_waited_ms`` of ``serving_snapshot``)
+# ---------------------------------------------------------------------------
+def _build_gauges(ce) -> tuple[float, float]:
+    snap = ce.serving_snapshot()
+    return snap["step_build_ms"], snap["step_build_waited_ms"]
+
+
+@pytest.mark.parametrize(
+    "d_ff,kw,ahead",
+    [
+        (104, dict(), True),  # what a server does: build_steps, then the join
+        (112, dict(prefill_chunk=8), False),  # one width: its first chunk
+        (120, dict(), False),  # two widths nobody built ahead: a call each
+    ],
+    ids=["build-steps-and-join", "one-width-first-chunk", "a-first-call-each"],
+)
+def test_the_build_is_counted_where_it_happens_and_nowhere_else(
+        d_ff, kw, ahead):
+    """``step_build_ms`` holds the seconds of tracing, lowering and
+    compiling every step program this engine built, on whatever thread;
+    ``step_build_waited_ms`` what of it a serving path spent in the work
+    or waiting for it: the ``build_steps`` call and the join, or the
+    first call at a width where nothing was built ahead. Both are written
+    there only: no chunk after that moves either."""
+    cfg = _cfg(d_ff=d_ff)  # of its own: nothing here was built before
+    ce = _cont((cfg, init_params(cfg, jax.random.PRNGKey(3))), **kw)
+    assert _build_gauges(ce) == (0.0, 0.0)
+    if ahead:
+        ce.build_steps()
+        built, waited = _build_gauges(ce)
+        # both lowerings and the widest's compile, against the call itself
+        assert built >= waited > 0 and ce._unbuilt == set()
+    else:
+        built = waited = 0.0
+    ce.submit([100] * 9, max_new_tokens=6)
+    ce.run_until_idle()  # a wide chunk, narrow ones, and the join
+    assert ce._build is None and not ce._unbuilt
+    after = _build_gauges(ce)
+    if ahead:
+        # the narrow program's own seconds came in where it was joined
+        assert after[0] > built and after[1] >= waited
+    assert after[0] >= after[1] > 0
+    if not ahead:
+        # the calls that built a program, and nothing of the other chunks
+        assert sorted(set(_widths(ce))) == list(ce.block_widths)
+        recs = ce.recorder.records()
+        first = {r["block_rows"]: r["dispatch_ms"] for r in reversed(recs)}
+        assert after[0] == after[1] == pytest.approx(
+            sum(first.values()), abs=0.01 * len(first))
+    _serve(ce)  # a churn of every mix: neither gauge moves again
+    ce.submit([101] * 40, max_new_tokens=5, seed=1)
+    ce.run_until_idle()
+    assert _build_gauges(ce) == after
+    ce.close()
+
+
+def test_a_program_built_before_the_engine_is_not_counted(tiny):
+    """The jit cache is the process's: an engine whose calls find their
+    programs there (another engine of the same shapes built them) built
+    nothing, and says so."""
+    first = _cont(tiny)
+    first.submit([100] * 9, max_new_tokens=6)
+    first.run_until_idle()
+    ce = _cont(tiny)
+    ce.submit([100] * 9, max_new_tokens=6)
+    ce.run_until_idle()
+    assert sorted(set(_widths(ce))) == list(ce.block_widths)
+    assert _build_gauges(ce) == (0.0, 0.0) and not ce._unbuilt
+    first.close()
+    ce.close()
+
+
+# ---------------------------------------------------------------------------
 # the stream stage's order
 # ---------------------------------------------------------------------------
 def test_ending_entries_and_first_tokens_leave_first(tiny):

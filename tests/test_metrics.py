@@ -444,11 +444,73 @@ def test_a_counter_metric_reads_keys_the_engine_reports(path):
     keys = [spec["key"]] if "key" in spec else spec["num"] + spec["den"]
     counters = {c[0] for c in continuous._ENGINE_COUNTERS}
     gauges = {"latent_pool_bytes", "weights_bytes_device_max",
+              "step_build_ms", "step_build_waited_ms",
               "prefix_evictions"}  # serving_snapshot()'s own
     assert keys and set(keys) <= counters | gauges, set(keys) - counters
     if path.name.startswith("narrow_block_share"):
         assert (spec["num"], spec["den"], spec["scale"]) == (
             ["ragged_blocks_narrow"], ["ragged_blocks"], 100)
+
+
+def _span_metric_files() -> list[Path]:
+    import json
+
+    files = sorted((REPO / "benchmarks" / "layer_metrics").glob("*.json"))
+    kinds = {f: json.loads(f.read_text())["kind"] for f in files}
+    return [f for f, kind in kinds.items()
+            if kind.startswith("span_") or kind.endswith("_by_spans")]
+
+
+@pytest.mark.parametrize(
+    "path", _span_metric_files(), ids=lambda p: p.name[:-len(".json")])
+def test_a_span_metric_selects_spans_the_program_records(path):
+    """Each of the benchmark's span metrics (``layer_metrics/*.json`` of a
+    ``span_*`` kind, and the idle share read against spans) selects names
+    of ``core/trace.py::PATH_SPANS``, the request path's spans as the
+    recording sites name them (tests/test_trace.py holds that one
+    streamed request yields every one): a span renamed here would leave
+    its metric silent there."""
+    import json
+
+    from tensorlink_tpu.core import trace
+
+    spec = json.loads(path.read_text())
+    minus = spec.get("minus", [])
+    names = [spec[k] for k in ("span", "from", "to") if k in spec]
+    names += [minus] if isinstance(minus, str) else list(minus)
+    assert names and set(names) <= set(trace.PATH_SPANS), (
+        set(names) - set(trace.PATH_SPANS))
+    if spec["kind"] == "span_residual_quantile":
+        # the seven that lie end to end inside http_first_byte
+        assert spec["span"] == trace.HTTP_FIRST_BYTE
+        assert minus == [trace.API_IN, trace.PREPARE, trace.HOP_IN,
+                         trace.WORK_WAIT, trace.SUBMIT, "first_token",
+                         trace.TOKEN_OUT]
+
+
+def test_the_recording_sites_use_the_path_spans_names():
+    """The new spans are recorded under the constants of
+    ``core/trace.py``, each in the module the table of docs/SERVING.md
+    "Telemetry" names, and the engine's literals are names of the tuple."""
+    import re
+
+    from tensorlink_tpu.core import trace
+
+    pkg = REPO / "tensorlink_tpu"
+    for const, mod in (("API_IN", "api/server.py"),
+                       ("HTTP_FIRST_BYTE", "api/server.py"),
+                       ("TOKEN_OUT", "api/server.py"),
+                       ("PREPARE", "ml/validator.py"),
+                       ("HOP_IN", "ml/worker.py"),
+                       ("WORK_WAIT", "ml/worker.py"),
+                       ("SUBMIT", "ml/worker.py")):
+        assert getattr(trace, const) in trace.PATH_SPANS
+        assert re.search(rf"\b{const}\b", (pkg / mod).read_text()), (const, mod)
+    engine = (pkg / "engine" / "continuous.py").read_text()
+    recorded = set(re.findall(r'self\._trace\(\s*\w+, "(\w+)"', engine))
+    assert set(trace.ENGINE_SPAN_PARENT) <= recorded
+    assert set(trace.ENGINE_SPAN_PARENT) <= set(trace.PATH_SPANS)
+    assert set(trace.SPANS_NAMED_AHEAD) <= set(trace.ENGINE_SPAN_PARENT.values())
 
 
 def test_adhoc_counter_guard_is_tl106(tmp_path):

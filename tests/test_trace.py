@@ -4,7 +4,19 @@
 Hard contracts pinned here:
 
 - the tracer's span store is bounded (traces AND spans per trace), ingest
-  dedups wire-echoed spans, and ``collect`` returns ts-ordered copies;
+  dedups wire-echoed spans, and ``collect`` returns copies in start
+  order: by ``t0`` where the spans' ``host`` agrees, by ``ts`` across;
+- every span holds ``t0`` (its start on ``time.monotonic()``), ``host``
+  (whose clock that is) and ``parent``; a start stamped in another
+  process gives a duration only where the stamp's host is the
+  recorder's own;
+- a streamed request through a validator + worker cluster leaves a span
+  at every boundary from the API's handler to the first delta
+  (``PATH_SPANS``), which lie end to end on one clock and add up to
+  ``http_first_byte``; a request that arrives while ``step_chunk`` runs
+  waits for it in ``work_wait``; only a stream's FIRST ``send_token``
+  carries a stamp; an untraced request and a frame from a peer without
+  the new keys are served as before;
 - a traced request's engine spans decompose its TTFT contiguously:
   queue_wait + prefill + first_decode == first_token (to float rounding);
 - tracing is OBSERVATION ONLY: a traced stream is bit-identical to the
@@ -28,11 +40,15 @@ import jax.numpy as jnp
 import pytest
 
 from tensorlink_tpu.core.trace import (
+    HOST,
+    PATH_SPANS,
     FlightRecorder,
     Tracer,
     current_trace,
+    first_token_stamp,
     get_tracer,
     mint_trace_id,
+    stamp,
 )
 from tensorlink_tpu.engine.continuous import CHUNK_PHASES, ContinuousEngine
 from tensorlink_tpu.engine.generate import GenerationEngine
@@ -86,6 +102,96 @@ def test_tracer_bounds_and_ingest_dedup():
     assert t3.ingest("x", spans) == 1  # fresh store -> merged
     assert t3.collect("x")[0]["site"] == "w1"
     assert t3.collect("x")[0]["dur_ms"] == pytest.approx(500.0)
+
+
+def test_span_holds_start_host_and_parent():
+    import time
+
+    t = Tracer()
+    before = time.monotonic()
+    root = t.new_sid()  # named ahead: a span is recorded at its end
+    child = t.record("x", "child", dur_s=0.25, parent=root)
+    assert t.record("x", "root", t0=before, dur_s=0.5, sid=root) == root
+    assert t.record("", "nothing") == ""  # no id: nothing stored, no sid
+    by = {s["name"]: s for s in t.collect("x")}
+    assert by["child"]["parent"] == root and by["child"]["sid"] == child
+    assert by["root"]["parent"] == "" and by["root"]["t0"] == before
+    # no start given: the span ends now
+    assert by["child"]["t0"] == pytest.approx(
+        time.monotonic() - 0.25, abs=0.05)
+    assert by["child"]["host"] == by["root"]["host"] == HOST
+    assert len(HOST) >= 8 and stamp()["host"] == HOST
+    assert not hasattr(t, "span")  # the context manager nobody called
+
+
+def test_collect_orders_by_start_where_the_hosts_agree():
+    """Two processes of one host may read the wall clock in the wrong
+    order within a millisecond: their spans are ordered by ``t0``, one
+    clock for both; across hosts only ``ts`` can order."""
+    t = Tracer()
+    mine = [
+        {"sid": "a:1", "name": "second", "ts": 100.0001, "t0": 7.002,
+         "host": "h1"},
+        {"sid": "b:1", "name": "first", "ts": 100.0002, "t0": 7.001,
+         "host": "h1"},
+        {"sid": "c:1", "name": "elsewhere", "ts": 100.00015, "t0": 99999.0,
+         "host": "h2"},
+        {"sid": "d:1", "name": "old_peer", "ts": 99.0},  # no t0, no host
+        {"sid": "e:1", "name": "inside_first", "ts": 100.0003, "t0": 7.001,
+         "host": "h1", "dur_ms": 1.0},
+        {"sid": "f:1", "name": "holds_it", "ts": 100.0004, "t0": 7.001,
+         "host": "h1", "dur_ms": 5.0},
+    ]
+    assert t.ingest("x", mine) == 6
+    assert [s["name"] for s in t.collect("x")] == [
+        "old_peer", "holds_it", "elsewhere", "inside_first", "first",
+        "second",
+    ]
+
+
+def test_a_foreign_hosts_stamp_gives_a_span_without_a_duration():
+    t = Tracer()
+    far = {"t": 12.5, "host": "another-boot", "parent": "z:9"}
+    sid = t.record_since("x", "hop_in", far, site="w")
+    (sp,) = t.collect("x")
+    assert sp["sid"] == sid and "dur_ms" not in sp
+    assert (sp["t0"], sp["host"], sp["parent"]) == (12.5, "another-boot", "z:9")
+    # this host's own stamp: a duration, between two stamps or up to now
+    a = stamp()
+    b = {"t": a["t"] + 0.004, "host": HOST}
+    t.record_since("y", "hop_in", a, end=b)
+    t.record_since("y", "work_wait", b, end=b["t"] + 0.1, parent="p:1")
+    hop, wait = t.collect("y")
+    assert hop["dur_ms"] == pytest.approx(4.0, abs=1e-3)
+    assert wait["dur_ms"] == pytest.approx(100.0, abs=1e-3)
+    assert wait["t0"] == b["t"] and wait["parent"] == "p:1"
+    # a peer that sends no stamp (or half of one): nothing is recorded
+    assert t.record_since("z", "hop_in", None) == ""
+    assert t.record_since("z", "hop_in", {"host": HOST}) == ""
+    assert not t.known("z")
+
+
+def _child_stamp(q):
+    from tensorlink_tpu.core.trace import stamp as child_stamp
+
+    q.put(child_stamp())
+
+
+def test_a_stamp_taken_in_a_child_process_is_ordered_with_the_parents():
+    """``time.monotonic()`` is one clock for every process of a host, and
+    ``host`` says so: what the spans across the bridge rest on."""
+    import multiprocessing as mp
+
+    ctx = mp.get_context("spawn")
+    q = ctx.Queue()
+    before = stamp()
+    proc = ctx.Process(target=_child_stamp, args=(q,))
+    proc.start()
+    theirs = q.get(timeout=60)
+    proc.join(timeout=60)
+    after = stamp()
+    assert theirs["host"] == before["host"] == HOST
+    assert before["t"] < theirs["t"] < after["t"]
 
 
 def test_mint_and_contextvar():
@@ -471,3 +577,416 @@ def test_streamed_request_spans_name_its_chunks(tiny_engine):
     assert len(rode) == 3 and rode == sorted(rode) and set(rode) <= steps
     assert by["prefill"][0]["chunk"] == rode[-1] == first_token["chunk"]
     ce.close()
+
+
+def test_an_engine_in_the_apis_own_process_has_its_way_out_too(tiny_engine):
+    """No worker, no frame: the engine leaves the first token's stamp on
+    the thread that runs the stream callback, the API's delta callback
+    finds it there, and ``token_out`` starts where ``first_token`` ends."""
+    import asyncio
+    from concurrent.futures import ThreadPoolExecutor
+
+    from tensorlink_tpu.api.formatter import ResponseFormatter
+    from tensorlink_tpu.api.schemas import GenerationRequest
+    from tensorlink_tpu.api.server import TensorlinkAPI
+
+    ce = _cont(tiny_engine, chunk_steps=2)
+
+    class _Exec:
+        def generate_api(self, gen, on_delta, trace_id, meta_cb):
+            ce.submit([1, 2, 3], max_new_tokens=4, seed=1, trace_id=trace_id,
+                      stream_cb=lambda tok: on_delta(f"{tok} ") or False)
+            ce.run_until_idle()
+            return {"prompt_tokens": 3, "finish_reason": "length",
+                    "completion_tokens": 4}
+
+    api = TensorlinkAPI.__new__(TensorlinkAPI)
+    api.executor = _Exec()
+    api._pool = ThreadPoolExecutor(1)
+    api._req_ids = {}
+    gen = GenerationRequest.parse({"hf_name": "m", "stream": True})
+    rid = mint_trace_id()
+    try:
+        asyncio.run(api._stream_generate(
+            gen, ResponseFormatter("m", "simple"), _Writer(), rid))
+        # and with no id nothing is looked up or stored
+        asyncio.run(api._stream_generate(
+            gen, ResponseFormatter("m", "simple"), _Writer(), ""))
+    finally:
+        api._pool.shutdown()
+    by = {sp["name"]: sp for sp in get_tracer().collect(rid)}
+    whole, first, out = (
+        by[n] for n in ("http_first_byte", "first_token", "token_out"))
+    assert by["api_in"]["parent"] == whole["sid"]
+    assert out["parent"] == first["sid"] and out["site"] == "api"
+    assert out["t0"] == pytest.approx(
+        first["t0"] + first["dur_ms"] / 1e3, abs=1e-6)
+    assert out["t0"] + out["dur_ms"] / 1e3 == pytest.approx(
+        whole["t0"] + whole["dur_ms"] / 1e3, abs=1e-6)
+    ce.close()
+
+
+# ---------------------------------------------------------------------------
+# the request path, hop by hop: the worker's side without nodes
+# ---------------------------------------------------------------------------
+
+
+def _fake_worker(ce):
+    """A ``DistributedWorker`` over a recording bridge (as
+    tests/test_stream_stage.py builds one), hosting ``ce`` as job "j"."""
+    import logging
+    import queue
+    import types
+
+    from tensorlink_tpu.ml.worker import DistributedWorker
+
+    sent: list = []  # every send_token payload, in order
+
+    class _Bridge:
+        q = types.SimpleNamespace(work=queue.Queue())
+
+        def notify(self, verb, p):
+            if verb == "send_token":
+                sent.append(p)
+
+        def request(self, verb, p, timeout=None):
+            if verb == "send_token":
+                sent.append(p)
+            return [] if verb == "poll_cancel" else True
+
+    w = DistributedWorker.__new__(DistributedWorker)
+    w.bridge = _Bridge()
+    w.node = types.SimpleNamespace(
+        node_id="f" * 64,
+        config=types.SimpleNamespace(ml=types.SimpleNamespace()),
+    )
+    w.log = logging.getLogger("test.trace")
+    w.draining = None
+    w._handoff_pools = {}
+    w._respond = lambda *a, **kw: None
+    rt = types.SimpleNamespace(
+        job_id="j", jstreams={}, orphans={}, cont_scheduled=False,
+        engine=ce.engine, cont=ce)
+    w.jobs = {"j": rt}
+    return w, rt, sent
+
+
+def _frame(tid, **extra):
+    return {
+        "job_id": "j", "prompts": [[1, 2, 3]], "max_new_tokens": 6,
+        "continuous": True, "seed": 1, "peer": "p0", "rid": "r0",
+        "stream": "s0", "trace": tid, **extra,
+    }
+
+
+def test_only_a_streams_first_send_token_carries_a_stamp(tiny_engine):
+    """One stamp a stream, none a token: the first frame holds the moment
+    the engine handed the first token on (where ``first_token`` ends),
+    and the engine leaves nothing behind on its thread."""
+    ce = _cont(tiny_engine, trace_site="wS")
+    w, rt, sent = _fake_worker(ce)
+    tid = mint_trace_id()
+    w._generate(_frame(tid, stamp=stamp()))
+    ce.run_until_idle()
+    toks = [p for p in sent if p["tokens"]]
+    assert len(toks) == 6
+    assert "stamp" in toks[0] and not any("stamp" in p for p in toks[1:])
+    first = {s["name"]: s for s in get_tracer().collect(tid)}["first_token"]
+    st = toks[0]["stamp"]
+    assert st["host"] == HOST and st["parent"] == first["sid"]
+    assert st["t"] == pytest.approx(
+        first["t0"] + first["dur_ms"] / 1e3, abs=1e-6)
+    assert first_token_stamp.get() is None
+    ce.close()
+
+
+def test_an_untraced_stream_is_stamped_and_recorded_nowhere(tiny_engine):
+    """No trace id: the frame carries no stamp, no message does, nothing
+    is stored, and ``NetBridge.post_work`` leaves the item as it came."""
+    from tensorlink_tpu.nodes.ipc import BridgeQueues, NetBridge
+
+    ce = _cont(tiny_engine)
+    w, rt, sent = _fake_worker(ce)
+    n_before = int(get_tracer().new_sid().split(":")[1])
+    frame = _frame("")
+    nb = NetBridge(BridgeQueues())
+    nb.post_work("generate", frame)
+    kind, item = nb.q.work.get(timeout=10)
+    assert "stamp_q" not in item and "stamp" not in item
+    w._generate(item)
+    ce.run_until_idle()
+    assert len([p for p in sent if p["tokens"]]) == 6
+    assert not any("stamp" in p for p in sent)
+    assert "_way_in" not in item
+    # no span id was minted on the way: the next one follows the last
+    assert int(get_tracer().new_sid().split(":")[1]) == n_before + 1
+    ce.close()
+
+
+def test_a_frame_from_a_peer_without_the_new_keys_is_served_as_before(
+        tiny_engine):
+    """An old validator sends ``trace`` and no ``stamp``: the request is
+    served, the engine's spans are there, and the way in has none."""
+    ce = _cont(tiny_engine, trace_site="wO")
+    w, rt, sent = _fake_worker(ce)
+    tid = mint_trace_id()
+    w._generate(_frame(tid))
+    ce.run_until_idle()
+    assert len([p for p in sent if p["tokens"]]) == 6
+    names = {s["name"] for s in get_tracer().collect(tid)}
+    assert {"queue_wait", "prefill", "first_token"} <= names
+    assert not names & {"hop_in", "work_wait", "submit"}
+    ce.close()
+
+
+def test_the_work_queue_stamps_a_traced_frame_and_the_worker_reads_it(
+        tiny_engine):
+    """``hop_in`` ends and ``work_wait`` starts where ``post_work`` puts
+    the item on the queue; ``submit`` runs from the handler's start to
+    the engine's own stamp, where ``first_token`` starts."""
+    import time
+
+    from tensorlink_tpu.nodes.ipc import BridgeQueues, NetBridge
+
+    ce = _cont(tiny_engine, trace_site="wQ")
+    w, rt, sent = _fake_worker(ce)
+    tid = mint_trace_id()
+    nb = NetBridge(BridgeQueues())
+    nb.post_work("generate", _frame(tid, stamp={**stamp(), "parent": "v:7"}))
+    time.sleep(0.05)  # the loop was busy: the item waits in the queue
+    kind, item = nb.q.work.get(timeout=10)
+    assert item["stamp_q"]["host"] == HOST
+    w._generate(item)
+    ce.run_until_idle()
+    by = {s["name"]: s for s in get_tracer().collect(tid)}
+    hop, wait, sub, first = (
+        by[n] for n in ("hop_in", "work_wait", "submit", "first_token"))
+    assert hop["parent"] == "v:7" and wait["parent"] == hop["sid"]
+    assert sub["parent"] == wait["sid"] and first["parent"] == sub["sid"]
+    assert wait["dur_ms"] >= 50.0 and hop["dur_ms"] < 50.0
+    end = lambda sp: sp["t0"] + sp["dur_ms"] / 1e3  # noqa: E731
+    assert end(hop) == pytest.approx(wait["t0"], abs=1e-6)
+    assert end(wait) == pytest.approx(sub["t0"], abs=1e-6)
+    assert end(sub) == pytest.approx(first["t0"], abs=1e-6)
+    assert "slot_engine_built" not in sub  # the engine was there
+    assert wait["chunk"] == ce.recorder.records()[0]["step"] - 1
+    ce.close()
+
+
+# ---------------------------------------------------------------------------
+# the request path through a validator + worker cluster (the API's
+# handler, the validator, the bridge, TCP, the worker's work queue, the
+# engine, and back)
+# ---------------------------------------------------------------------------
+
+PATH_MODEL = "tiny-path"
+
+
+@pytest.fixture(scope="module")
+def path_cluster(tmp_path_factory):
+    import time
+
+    from test_api_e2e import _req, tiny_cfg_json
+
+    from tensorlink_tpu.core.config import ValidatorConfig, WorkerConfig
+    from tensorlink_tpu.nodes.runners import ValidatorNode, WorkerNode
+
+    tmp = tmp_path_factory.mktemp("path_cluster")
+    common = dict(
+        local_test=True, key_dir=str(tmp / "keys"),
+        log_dir=str(tmp / "logs"), env_file=str(tmp / ".env"),
+    )
+    validator = ValidatorNode(
+        ValidatorConfig(endpoint=True, endpoint_port=0, **common)
+    ).start()
+    worker = WorkerNode(
+        WorkerConfig(seed_validators=[["127.0.0.1", validator.port]], **common)
+    ).start()
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline and not validator.status()["peers"]:
+        time.sleep(0.2)
+    status, body = _req(
+        validator.api, "POST", "/request-model",
+        {"hf_name": PATH_MODEL, "config": tiny_cfg_json(), "seq_len": 256},
+    )
+    assert status == 200 and body["status"] == "ready", body
+    # the first request builds the slot engine and its programs
+    _stream(validator.api, mint_trace_id(), "warm the engine up")
+    validator.test_worker = worker
+    yield validator
+    worker.stop()
+    validator.stop()
+
+
+def _stream(api, rid, message, new_tokens=6, stream=True):
+    """POST /v1/generate under ``X-Request-Id: rid``; the raw reply."""
+    import json
+    import socket
+
+    payload = json.dumps({
+        "hf_name": PATH_MODEL, "message": message, "stream": stream,
+        "max_new_tokens": new_tokens, "do_sample": False,
+    }).encode()
+    s = socket.create_connection(("127.0.0.1", api.port), timeout=200)
+    s.sendall(
+        f"POST /v1/generate HTTP/1.1\r\nHost: x\r\nX-Request-Id: {rid}\r\n"
+        "Content-Type: application/json\r\n"
+        f"Content-Length: {len(payload)}\r\n\r\n".encode() + payload
+    )
+    buf = b""
+    while True:
+        chunk = s.recv(65536)
+        if not chunk:
+            break
+        buf += chunk
+    s.close()
+    return buf
+
+
+def _trace_of(api, rid):
+    from test_api_e2e import _req
+
+    status, body = _req(api, "GET", f"/trace/{rid}")
+    assert status == 200, body
+    return body["spans"]
+
+
+WAY = ("api_in", "prepare", "hop_in", "work_wait", "submit", "first_token",
+       "token_out")
+
+
+@pytest.mark.e2e
+def test_a_streamed_request_leaves_a_span_at_every_boundary(path_cluster):
+    rid = mint_trace_id()
+    assert b"[DONE]" in _stream(path_cluster.api, rid, "hello there")
+    spans = _trace_of(path_cluster.api, rid)
+    by = {}
+    for sp in spans:
+        by.setdefault(sp["name"], []).append(sp)
+    assert set(PATH_SPANS) <= set(by), set(PATH_SPANS) - set(by)
+    for name in PATH_SPANS:
+        for sp in by[name]:
+            assert isinstance(sp["t0"], float) and sp["host"] == HOST, sp
+            assert "dur_ms" in sp and isinstance(sp["parent"], str), sp
+    # which span caused which: one chain from the handler to the delta
+    one = {n: by[n][0] for n in PATH_SPANS}
+    chain = ("http_first_byte",) + WAY
+    for cause, name in zip(chain, chain[1:]):
+        if name == "token_out":
+            cause = "first_token"
+        assert one[name]["parent"] == one[cause]["sid"], (name, cause)
+    assert one["http_first_byte"]["parent"] == ""
+    for name, cause in (("queue_wait", "first_token"),
+                        ("admission", "queue_wait"),
+                        ("prefill", "first_token"),
+                        ("prefill_chunk", "prefill"),
+                        ("first_decode", "first_token")):
+        assert one[name]["parent"] == one[cause]["sid"], (name, cause)
+    assert one["prepare"]["prompt_tokens"] > 0
+    assert one["api_in"]["site"] == one["token_out"]["site"] == "api"
+    assert one["hop_in"]["site"] == one["first_token"]["site"] != "api"
+    assert isinstance(one["work_wait"]["chunk"], int)
+
+
+@pytest.mark.e2e
+def test_the_way_to_the_first_byte_lies_end_to_end_and_adds_up(path_cluster):
+    """Laid end to end by ``t0`` the seven spans of the way do not
+    overlap, start where ``http_first_byte`` starts, end where it ends,
+    and leave less than 5 ms of it unnamed."""
+    for attempt in range(3):  # a starved thread is not a hole in the way
+        rid = mint_trace_id()
+        _stream(path_cluster.api, rid, f"how long is the way {attempt}")
+        by = {sp["name"]: sp for sp in _trace_of(path_cluster.api, rid)}
+        whole = by["http_first_byte"]
+        way = [by[n] for n in WAY]
+        if whole["dur_ms"] - sum(sp["dur_ms"] for sp in way) < 5.0:
+            break
+    assert [sp["name"] for sp in sorted(way, key=lambda sp: sp["t0"])] \
+        == list(WAY)
+    end = lambda sp: sp["t0"] + sp["dur_ms"] / 1e3  # noqa: E731
+    for a, b in zip(way, way[1:]):
+        assert end(a) <= b["t0"] + 1e-6, (a["name"], b["name"])
+    assert way[0]["t0"] == whole["t0"]
+    assert end(way[-1]) == pytest.approx(end(whole), abs=1e-6)
+    named = sum(sp["dur_ms"] for sp in way)
+    assert 0.0 <= whole["dur_ms"] - named < 5.0, (whole["dur_ms"], named)
+    # the engine's own three still add up to its first_token
+    parts = sum(by[n]["dur_ms"]
+                for n in ("queue_wait", "prefill", "first_decode"))
+    assert parts == pytest.approx(by["first_token"]["dur_ms"], abs=0.1)
+
+
+@pytest.mark.e2e
+def test_the_trace_endpoint_gives_spans_in_start_order(path_cluster):
+    rid = mint_trace_id()
+    _stream(path_cluster.api, rid, "in what order")
+    spans = _trace_of(path_cluster.api, rid)
+    assert all(sp["host"] == HOST for sp in spans)
+    starts = [sp["t0"] for sp in spans]
+    assert starts == sorted(starts)
+    assert spans[0]["name"] == "http_first_byte"  # it holds the others
+    assert [sp["name"] for sp in spans[1:4]] == ["api_in", "prepare", "hop_in"]
+    sids = {sp["sid"] for sp in spans}
+    for sp in spans:
+        if sp["name"] in PATH_SPANS and sp["name"] != "http_first_byte":
+            assert sp["parent"] in sids, sp
+
+
+@pytest.mark.e2e
+def test_an_unstreamed_request_has_the_way_in_and_no_way_out(path_cluster):
+    rid = mint_trace_id()
+    reply = _stream(path_cluster.api, rid, "all at once", stream=False)
+    assert b"200 OK" in reply
+    by = {sp["name"]: sp for sp in _trace_of(path_cluster.api, rid)}
+    for name in ("api_in", "prepare", "hop_in", "work_wait", "submit",
+                 "first_token"):
+        assert name in by and "dur_ms" in by[name], name
+    assert by["api_in"]["parent"] == ""
+    assert by["prepare"]["parent"] == by["api_in"]["sid"]
+    assert "token_out" not in by and "http_first_byte" not in by
+
+
+@pytest.mark.e2e
+def test_a_request_behind_a_running_chunk_waits_for_it_and_names_it(
+        path_cluster):
+    """The worker's loop takes a GENERATE when ``step_chunk`` returns: a
+    request that arrives meanwhile waits in ``work_wait``, outside the
+    engine's ``queue_wait``, for what is left of the chunk, and the span
+    names that chunk's record."""
+    import threading
+    import time
+
+    worker = path_cluster.test_worker
+    (rt,) = [r for r in worker.executor.jobs.values() if r.cont is not None]
+    cont = rt.cont
+    pack = cont._pack_ragged
+
+    def slow_pack():
+        time.sleep(0.25)  # inside the chunk, in its pack phase
+        return pack()
+
+    cont._pack_ragged = slow_pack
+    try:
+        long_rid, rid = mint_trace_id(), mint_trace_id()
+        first = threading.Thread(
+            target=_stream, args=(path_cluster.api, long_rid, "go on"),
+            kwargs={"new_tokens": 40})
+        first.start()
+        time.sleep(0.6)  # chunks of 0.25 s and more follow one another
+        _stream(path_cluster.api, rid, "behind a chunk")
+        first.join(timeout=120)
+    finally:
+        cont._pack_ragged = pack
+    by = {sp["name"]: sp for sp in _trace_of(path_cluster.api, rid)}
+    wait = by["work_wait"]
+    rec = next(r for r in cont.recorder.records() if r["step"] == wait["chunk"])
+    rec_end = rec["t0"] + sum(
+        rec[f"{p}_ms"] for p in CHUNK_PHASES) / 1e3
+    wait_end = wait["t0"] + wait["dur_ms"] / 1e3
+    # it was put on the queue before that chunk ended, and taken after
+    assert wait["t0"] < rec_end <= wait_end + 1e-3
+    left_ms = (rec_end - max(wait["t0"], rec["t0"])) * 1e3
+    assert wait["dur_ms"] >= left_ms - 1.0 and left_ms > 0.0
+    # the wait the engine cannot see: queue_wait starts after it
+    assert by["queue_wait"]["t0"] >= wait_end - 1e-6
+    assert by["queue_wait"]["dur_ms"] < wait["dur_ms"] + 250.0
