@@ -90,6 +90,7 @@ from ..parallel.mesh import serving_mesh
 from .generate import GenerationEngine
 from .kvtier import HostPagePool
 from .paged import (
+    EOS_WIDTH,
     PageAllocator,
     LatentPagedCache,
     PagedKVCache,
@@ -100,12 +101,14 @@ from .paged import (
     copy_page,
     gather_page,
     make_tp_ragged_step,
+    pack_control,
     paged_decode_step,
     paged_ragged_step,
     pages_needed,
     scatter_page,
     tp_cache_specs,
     tp_gather_costs,
+    unpack_results,
 )
 from .sampling import SamplingParams, penalized, sample
 from .spec import SpecController
@@ -455,6 +458,10 @@ _ENGINE_COUNTERS = (
      "tokens streamed while the device ran the next chunk"),
     ("stream_tokens_flushed", "tlink_engine_stream_tokens_flushed_total",
      "tokens streamed with no step in flight"),
+    # the host-device boundary of a chunk (docs/SERVING.md "The anatomy of
+    # a chunk"): the packed control buffer in, the packed results out
+    ("chunk_host_arrays", "tlink_engine_chunk_host_arrays_total",
+     "arrays step_chunk placed on or fetched from the device (2 a chunk)"),
 )
 
 
@@ -1303,25 +1310,34 @@ class ContinuousEngine:
         """The stream stage: hand what the settle stage left pending to
         the requests' callbacks, in order: the ``first_token`` spans where
         a first token leaves, ``stream_cb`` a token, ``on_finish`` after a
-        finished request's last one. Entries that end a request go first,
-        then every first token, then the rest in slot order: a client that
-        waits for its answer to send the next request is freed at the
-        start of the stage and not behind every other slot's tokens (at a
-        chunk of 80 ms and a stage of 40 that decided in which chunk the
-        next request was admitted), and a first token does not queue
-        behind tokens whose readers already have a stream going. Only the
-        first token itself goes ahead: the tokens that came with it keep
-        their slot's place, because an entry that changes place between
-        two stages moves every reader behind it by an entry and its own
-        reader's next gap by the way back (the longest gap a reader sees;
-        PERF.md section 6, PR 36). A request's own tokens keep their
-        order. ``step_chunk`` runs it behind its
-        dispatch (``in_flight``: the device executes the next chunk
-        meanwhile), or at once when no step follows. Anything else that
-        answers for a request calls it first, for that request (``req``)
-        or for all: ``close``, ``begin_drain``, ``freeze_slot`` and every
-        teardown that is not a finish (``_teardown_slot``: preemption,
-        shed, handoff commit). Driver-thread only."""
+        finished request's last one. Every first token goes first, then
+        the entries that go on in slot order, then the entries that end a
+        request. A first token does not queue behind tokens whose readers
+        already have a stream going. Only the first token itself goes
+        ahead (an answer that ends with its first chunk leaves whole):
+        the tokens that came with it keep their slot's place, because an
+        entry that changes place between two stages moves every reader
+        behind it by an entry and its own reader's next gap by the way
+        back (the longest gap a reader sees; PERF.md section 6, PR 36).
+        What ends a request goes last: its ``on_finish`` is the one
+        callback that blocks for long (on a worker the done-marker's
+        round trip and ``GENERATE_RESP``, 15-20 ms), so there it holds
+        back nobody's tokens; and a client that waits for its answer to
+        send the next request gets it where the engine goes on to its
+        sync. Answered at the START of the stage, that client's next
+        request came back about a narrow chunk later on four chips once
+        such a chunk was 60 ms long, made the next admission or missed
+        it by a millisecond, and eight clients asking equal answers
+        drifted into step, a different way every run; answered at the
+        end it misses that admission every time and meets the one after
+        (PERF.md section 6, PR 40). A request's own tokens keep their
+        order. ``step_chunk`` runs it behind its dispatch (``in_flight``:
+        the device executes the next chunk meanwhile), or at once when
+        no step follows. Anything else that answers for a request calls
+        it first, for that request (``req``) or for all: ``close``,
+        ``begin_drain``, ``freeze_slot`` and every teardown that is not a
+        finish (``_teardown_slot``: preemption, shed, handoff commit).
+        Driver-thread only."""
         pend = self._unstreamed
         if not pend or (req is not None and req.rid not in pend):
             return
@@ -1331,24 +1347,25 @@ class ContinuousEngine:
             with jax.profiler.TraceAnnotation("tlink:stream"):
                 order = (req.rid,) if req is not None else tuple(pend)
                 if req is None:
-                    for rid in order:  # what ends a request
-                        entry = pend.get(rid)
-                        if entry is not None and entry[3]:
-                            del pend[rid]
-                            n += self._stream_one(*entry, in_flight)
                     for rid in order:  # first tokens
                         entry = pend.get(rid)
                         if entry is None or not entry[2]:
                             continue
-                        r, k, _first, _finish, step = entry
-                        if k > 1:  # the rest stays pending, in its place
+                        r, k, _first, finish, step = entry
+                        head = k > 1 and not finish
+                        if head:  # the rest stays pending, in its place
                             pend[rid] = (r, k - 1, False, False, step)
-                        else:
+                        else:  # one token, or a whole answer: all of it
                             del pend[rid]
                         n += self._stream_one(
-                            r, k, True, False, step, in_flight, head=True,
+                            r, k, True, finish, step, in_flight, head=head,
                         )
-                for rid in order:
+                    for rid in order:  # what goes on, in slot order
+                        entry = pend.get(rid)
+                        if entry is not None and not entry[3]:
+                            del pend[rid]
+                            n += self._stream_one(*entry, in_flight)
+                for rid in order:  # what ends a request
                     entry = pend.pop(rid, None)
                     if entry is not None:
                         n += self._stream_one(*entry, in_flight)
@@ -3118,7 +3135,7 @@ class ContinuousEngine:
     # per-slot EOS ids carried INTO the compiled chunk (freeze
     # optimization); the host's delivery loop checks the full set, so an
     # overflowing set only costs wasted in-chunk steps, never correctness
-    _EOS_WIDTH = 8
+    _EOS_WIDTH = EOS_WIDTH
 
     # tlint: hot-path
     def _pack_ragged(self):
@@ -3290,21 +3307,20 @@ class ContinuousEngine:
             self._slots[s].spec_state.drafted += len(d)
         return n_spec
 
+    # tlint: hot-path
     def _step_operands(self, blk, starts, n_valid, n_spec, emit, remaining,
                        eos_arr) -> tuple:
         """The step program's positional operands: this chunk's packed
-        block and per-slot control rows beside the engine's resident
-        state (weights, page cache, sampling knobs, histograms)."""
-        return (
-            self.engine.params, jnp.asarray(blk), self.cache,
-            jnp.asarray(starts), jnp.asarray(n_valid),
-            jnp.asarray(n_spec), jnp.asarray(emit),
-            jnp.asarray(self._seeds), jnp.asarray(self._steps),
-            jnp.asarray(self._temp), jnp.asarray(self._topk),
-            jnp.asarray(self._topp), jnp.asarray(self._pres),
-            jnp.asarray(self._freq), self._counts,
-            jnp.asarray(remaining), jnp.asarray(eos_arr),
+        block, per-slot control rows and sampling knobs in ONE host
+        buffer (``paged.pack_control``: the call places it, nothing is
+        placed here) beside the engine's resident state (weights, page
+        cache, histograms)."""
+        ctl = pack_control(
+            blk, starts, n_valid, n_spec, emit, self._seeds, self._steps,
+            self._temp, self._topk, self._topp, self._pres, self._freq,
+            remaining, eos_arr,
         )
+        return (self.engine.params, ctl, self.cache, self._counts)
 
     def _count_latent(self, step_stats, starts, n_valid, emit, n_exec):
         """A patterned model's counters of one chunk: the step's own
@@ -3360,9 +3376,9 @@ class ContinuousEngine:
         )
         if self._tp_step is not None:
             return self._tp_step.lower(*ops)
-        return paged_ragged_step.lower(
-            *ops, self.cfg, self.chunk_steps, self.spec_width,
-            self.use_kernel,
+        return paged_ragged_step.lower(  # spelled as step_chunk's call
+            *ops, cfg=self.cfg, n_steps=self.chunk_steps,
+            spec_width=self.spec_width, kernel=self.use_kernel,
         )
 
     def build_steps(self) -> None:
@@ -3460,8 +3476,8 @@ class ContinuousEngine:
         # profiler's host line (tlink:<phase> inside one tlink:chunk that
         # carries this chunk's flight-recorder step) and as monotonic
         # pairs in the chunk's record and the chunk_us_* counters. No
-        # sync is added: the device is waited for at int(n_exec), as
-        # before.
+        # sync is added: the device is waited for at np.asarray(out),
+        # the chunk's one fetch.
         t0 = time.monotonic()
         between = (
             t0 - self._chunk_exit_t if self._chunk_exit_t is not None else 0.0
@@ -3490,20 +3506,17 @@ class ContinuousEngine:
                     )
                     if self._tp_step is not None:
                         # sharded hot path: same program semantics,
-                        # weights/KV are device-local shards; control
-                        # arrays stay host-replicated
-                        tokens, n_tok, spec_m, n_exec, self.cache, _done, \
-                            _steps_dev, self._counts, _rem = (
-                                self._tp_step(*ops)
-                            )
+                        # weights/KV are device-local shards; the control
+                        # buffer is replicated by the call
+                        out, self.cache, self._counts = self._tp_step(*ops)
                     else:
-                        tokens, n_tok, spec_m, n_exec, self.cache, _done, \
-                            _steps_dev, self._counts, _rem = (
-                                paged_ragged_step(
-                                    *ops, self.cfg, self.chunk_steps,
-                                    self.spec_width, self.use_kernel,
-                                )
-                            )
+                        out, self.cache, self._counts = paged_ragged_step(
+                            *ops, cfg=self.cfg, n_steps=self.chunk_steps,
+                            spec_width=self.spec_width,
+                            kernel=self.use_kernel,
+                        )
+                    # host arrays the call placed on the device(s)
+                    placed = sum(isinstance(x, np.ndarray) for x in ops)
                 if self._unbuilt:
                     self._note_first_call(
                         blk.shape[1], programs, ph["dispatch"]
@@ -3513,16 +3526,16 @@ class ContinuousEngine:
                     # for its callbacks meanwhile, nothing below reads
                     # what they return but a stop (settled next)
                     self.flush_stream(in_flight=True)
-                    # the first value that blocks: the device is done here
-                    n_exec = int(n_exec)
+                    # the chunk's one fetch, and the one value that
+                    # blocks: the device is done here
+                    out = np.asarray(out)
                 with _Phase(ph, "drain"):
-                    toks_host = np.asarray(tokens)
-                    n_tok_host = np.asarray(n_tok)
-                    spec_m_host = np.asarray(spec_m)
-                    # the step's own counts ride the same sync
-                    step_stats = (
-                        np.asarray(self.cache.stats) if self._latent else ()
-                    )
+                    # the host's copy split (the step's own counts of a
+                    # patterned model rode it too)
+                    toks_host, n_tok_host, spec_m_host, n_exec, \
+                        step_stats = unpack_results(
+                            out, self.chunk_steps, self.spec_width
+                        )
                 # the chunk's host-visible wall time — measured at the
                 # ONE existing boundary sync, so span recording adds no
                 # device round trips of its own
@@ -3539,6 +3552,7 @@ class ContinuousEngine:
                     self._count("ragged_rows_valid", int(n_valid.sum()))
                     self._count("ragged_rows_computed", blk.size)
                     self._count("ragged_blocks")
+                    self._count("chunk_host_arrays", placed + 1)  # + out
                     if blk.shape[1] < self.prefill_chunk:
                         self._count("ragged_blocks_narrow")
                     elif int(n_valid.max()) <= self.block_widths[0] < (

@@ -53,9 +53,11 @@ import hashlib
 import heapq
 from dataclasses import dataclass, replace
 from functools import partial
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -1453,10 +1455,115 @@ def _ragged_pass(
     return logits_v, base, kv_new
 
 
+# A chunk crosses the host-device boundary as ONE array each way
+# (docs/SERVING.md "The anatomy of a chunk"). In: ``int32 [S, C +
+# CTL_COLS]``, the packed token block's ``C`` columns, then one column
+# for each of ``CTL_INTS`` (``emit`` as 0 / 1), the ``EOS_WIDTH`` EOS ids
+# and the bits of the four float32 knobs ``CTL_FLOATS``. Out: ``int32 [S,
+# n_steps + spec_width - 1 + 3 (+ the step's own counts)]``, see
+# :func:`pack_results`. :class:`Control` names the rows in the order both
+# packers take them.
+CTL_INTS = (
+    "starts", "n_valid", "n_spec", "emit", "seeds", "steps", "top_k",
+    "remaining",
+)
+CTL_FLOATS = ("temp", "top_p", "pres", "freq")
+EOS_WIDTH = 8  # EOS ids a slot carries INTO the program (pad with -1)
+CTL_COLS = len(CTL_INTS) + EOS_WIDTH + len(CTL_FLOATS)
+
+
+class Control(NamedTuple):
+    """A chunk's control rows, as the step's phases read them."""
+
+    blk: Any  # int32 [S, C]: packed ragged token block (0-padded)
+    starts: Any  # int32 [S]: absolute position of blk[s, 0]
+    n_valid: Any  # int32 [S]: valid tokens per slot (0 = idle)
+    n_spec: Any  # int32 [S]: draft tokens per slot (rows 1..n_spec)
+    emit: Any  # bool [S]: slot samples from its last valid row
+    seeds: Any  # int32 [S]: per-slot RNG seeds
+    steps: Any  # int32 [S]: per-slot next draw index
+    temp: Any  # f32 [S] sampling knobs ...
+    top_k: Any  # int32 [S]
+    top_p: Any  # f32 [S]
+    pres: Any  # f32 [S]
+    freq: Any  # f32 [S]
+    remaining: Any  # int32 [S]: tokens still wanted per slot
+    eos: Any  # int32 [S, <= EOS_WIDTH]: per-slot EOS ids (pad with -1)
+
+
+# tlint: hot-path
+def pack_control(blk, starts, n_valid, n_spec, emit, seeds, steps, temp,
+                 top_k, top_p, pres, freq, remaining, eos) -> np.ndarray:
+    """The step program's one control operand from a chunk's rows
+    (:class:`Control`'s fields, in order), on the host: the float knobs
+    ride as their float32 bits, so every value comes out of
+    :func:`unpack_control` as it went in."""
+    rows = Control(blk, starts, n_valid, n_spec, emit, seeds, steps, temp,
+                   top_k, top_p, pres, freq, remaining, eos)
+    (S, C), n_eos = np.shape(blk), np.shape(eos)[1]
+    if n_eos > EOS_WIDTH:
+        raise ValueError(f"{n_eos} EOS ids a slot, over {EOS_WIDTH}")
+    ctl = np.empty((S, C + CTL_COLS), np.int32)
+    ctl[:, :C] = blk
+    for i, name in enumerate(CTL_INTS):
+        ctl[:, C + i] = getattr(rows, name)
+    col = C + len(CTL_INTS)
+    ctl[:, col + n_eos : col + EOS_WIDTH] = -1
+    ctl[:, col : col + n_eos] = eos
+    knobs = np.empty((S, len(CTL_FLOATS)), np.float32)
+    for i, name in enumerate(CTL_FLOATS):
+        knobs[:, i] = getattr(rows, name)
+    ctl[:, col + EOS_WIDTH :] = knobs.view(np.int32)
+    return ctl
+
+
+# tlint: hot-path
+def unpack_control(ctl: jax.Array) -> Control:
+    """:func:`pack_control`'s inverse inside the program: slices and four
+    bitcasts, no loop."""
+    C = ctl.shape[1] - CTL_COLS
+    ints = {n: ctl[:, C + i] for i, n in enumerate(CTL_INTS)}
+    ints["emit"] = ints["emit"] != 0
+    col = C + len(CTL_INTS)
+    floats = {
+        n: jax.lax.bitcast_convert_type(
+            ctl[:, col + EOS_WIDTH + i], jnp.float32
+        )
+        for i, n in enumerate(CTL_FLOATS)
+    }
+    return Control(
+        blk=ctl[:, :C], eos=ctl[:, col : col + EOS_WIDTH], **ints, **floats
+    )
+
+
+# tlint: hot-path
+def pack_results(tokens, n_tok, spec_m, n_exec, stats=None) -> jax.Array:
+    """The step program's one result besides its resident state:
+    ``int32 [S, T + 3 (+ len(stats))]`` of ``tokens [S, T]``, ``n_tok``,
+    ``spec_m``, ``n_exec`` broadcast down a column and, for a patterned
+    model, the step's own counts (``cache.stats``) broadcast down one
+    column each."""
+    S = tokens.shape[0]
+    cols = [tokens, n_tok[:, None], spec_m[:, None],
+            jnp.broadcast_to(n_exec, (S, 1))]
+    if stats is not None:
+        cols.append(jnp.broadcast_to(stats[None, :], (S, stats.shape[0])))
+    return jnp.concatenate(cols, axis=1)
+
+
+# tlint: hot-path
+def unpack_results(out: np.ndarray, n_steps: int, spec_width: int):
+    """:func:`pack_results`' inverse on the host's copy: ``(tokens,
+    n_tok, spec_m, n_exec, stats)``; ``stats`` is empty where the
+    program packed none."""
+    T = int(n_steps) + int(spec_width) - 1
+    return (out[:, :T], out[:, T], out[:, T + 1], int(out[0, T + 2]),
+            out[0, T + 3 :])
+
+
 # tlint: hot-path
 def _ragged_step_impl(
-    params, blk, cache, starts, n_valid, n_spec, emit, seeds, steps,
-    temp, top_k, top_p, pres, freq, counts, remaining, eos,
+    params, ctl, cache, counts,
     cfg: ModelConfig, n_steps: int, spec_width: int, kernel: bool,
     tp_axis: str | None = None, tp_quant: bool = False,
 ):
@@ -1464,10 +1571,11 @@ def _ragged_step_impl(
     the tensor-parallel shard_map (:func:`make_tp_ragged_step`). There
     ``params`` holds head-major column slices, the per-layer KV pages
     hold the LOCAL kv heads (axis 2 of ``[L, P, n_kv, page, hd]``), and
-    every control-state array (block tables, starts/n_valid, sampling
-    knobs, histograms) is replicated — so the sampling epilogue sees
-    gathered full-width logits and draws the SAME token on every
-    shard."""
+    every control-state array (block tables, the packed control rows,
+    histograms) is replicated — so the sampling epilogue sees gathered
+    full-width logits and draws the SAME token on every shard."""
+    (blk, starts, n_valid, n_spec, emit, seeds, steps, temp, top_k, top_p,
+     pres, freq, remaining, eos) = unpack_control(ctl)
     S = blk.shape[0]
     W = int(spec_width)
     # the program's three phases carry names of their own (STEP_PHASES):
@@ -1513,13 +1621,14 @@ def _ragged_step_impl(
         spec_m, tokens,
     )
     with jax.named_scope(DECODE_CONT):
-        n_exec, _tok, cache, done, steps, counts, remaining, n_tok, tokens = (
+        n_exec, _tok, cache, _done, _steps, counts, _rem, n_tok, tokens = (
             jax.lax.while_loop(cond, body, init)
         )
-    return (
-        tokens, n_tok, spec_m, n_exec, cache, done, steps, counts,
-        remaining,
+    out = pack_results(
+        tokens, n_tok, spec_m, n_exec,
+        cache.stats if cfg.patterned else None,
     )
+    return out, cache, counts
 
 
 # tlint: hot-path  # tlint: one-program
@@ -1530,22 +1639,9 @@ def _ragged_step_impl(
 )
 def paged_ragged_step(
     params,
-    blk: jax.Array,  # int32 [S, C] — packed ragged token block (0-padded)
+    ctl: jax.Array,  # int32 [S, C + CTL_COLS]: pack_control's buffer
     cache: PagedKVCache,
-    starts: jax.Array,  # int32 [S] — absolute position of blk[s, 0]
-    n_valid: jax.Array,  # int32 [S] — valid tokens per slot (0 = idle)
-    n_spec: jax.Array,  # int32 [S] — draft tokens per slot (rows 1..n_spec)
-    emit: jax.Array,  # bool [S] — slot samples from its last valid row
-    seeds: jax.Array,  # int32 [S] — per-slot RNG seeds
-    steps: jax.Array,  # int32 [S] — per-slot next draw index
-    temp: jax.Array,  # f32 [S] sampling knobs …
-    top_k: jax.Array,  # int32 [S]
-    top_p: jax.Array,  # f32 [S]
-    pres: jax.Array,  # f32 [S]
-    freq: jax.Array,  # f32 [S]
     counts: jax.Array,  # int32 [S, V] context histograms (penalties)
-    remaining: jax.Array,  # int32 [S] — tokens still wanted per slot
-    eos: jax.Array,  # int32 [S, E] per-slot EOS ids (pad with -1)
     cfg: ModelConfig,
     n_steps: int,
     spec_width: int = 1,
@@ -1596,16 +1692,18 @@ def paged_ragged_step(
     because the slot already owns every page it wrote), and the next
     pass overwrites the garbage before any mask can reach it.
 
-    Returns ``(tokens [S, n_steps + spec_width - 1], n_tok [S], spec_m
-    [S], n_exec, cache, done, steps, counts, remaining)``: per-slot
+    The chunk's control rows arrive as ONE operand, ``ctl``
+    (:func:`pack_control` on the host, :func:`unpack_control` here), and
+    what the host reads of a chunk leaves as ONE result: returns ``(out,
+    cache, counts)`` with ``out`` = :func:`pack_results` of ``tokens [S,
+    n_steps + spec_width - 1]``, ``n_tok [S]``, ``spec_m [S]`` and
+    ``n_exec`` (:func:`unpack_results` on the host's copy): per-slot
     token counts ``n_tok`` replace the old shared column convention
     (column 0..n_tok[s]-1 hold slot ``s``'s draws), and ``spec_m`` is
     the ragged pass's emitted count (the tokens-per-verify-pass signal
     the engine's kill switch consumes)."""
     return _ragged_step_impl(
-        params, blk, cache, starts, n_valid, n_spec, emit, seeds, steps,
-        temp, top_k, top_p, pres, freq, counts, remaining, eos,
-        cfg, n_steps, spec_width, kernel,
+        params, ctl, cache, counts, cfg, n_steps, spec_width, kernel,
     )
 
 
@@ -1681,7 +1779,7 @@ def make_tp_ragged_step(
     Weights enter as head-major column slices (tp_partition_specs), KV
     pages as kv-head slices (:func:`tp_cache_specs`), everything else
     replicated; outputs mirror that layout, so the donated cache keeps
-    its sharding across chunks. Call with the SAME positional arrays as
+    its sharding across chunks. Call with the SAME positional operands as
     ``paged_ragged_step`` minus the trailing statics (closed over
     here). ``tp_quant`` routes the per-chunk activation gathers through
     the int8 quantized collective (bounded divergence, opt-in via
@@ -1696,23 +1794,26 @@ def make_tp_ragged_step(
 
     # the name is the compiled module's (``jit_tp_ragged_step``): what a
     # profiler trace calls this program's executions
-    def tp_ragged_step(params, blk, cache, starts, n_valid, n_spec, emit,
-                       seeds, steps, temp, top_k, top_p, pres, freq, counts,
-                       remaining, eos):
+    def tp_ragged_step(params, ctl, cache, counts):
         return _ragged_step_impl(
-            params, blk, cache, starts, n_valid, n_spec, emit, seeds,
-            steps, temp, top_k, top_p, pres, freq, counts, remaining,
-            eos, cfg, n_steps, spec_width, kernel, axis, tp_quant,
+            params, ctl, cache, counts, cfg, n_steps, spec_width, kernel,
+            axis, tp_quant,
         )
 
     def specs_for(quantized: bool):
         cspecs = tp_cache_specs(quantized, axis)
-        in_specs = (pspecs, rep, cspecs) + (rep,) * 14
-        out_specs = (rep, rep, rep, rep, cspecs, rep, rep, rep, rep)
-        return in_specs, out_specs
+        # replicated results spelled rank-expanded: the jit's
+        # out_shardings are made of these (see _canon)
+        rep1, rep2 = P(None), P(None, None)
+        out_cache = replace(cspecs, block_tables=rep2, lengths=rep1)
+        return (pspecs, rep, cspecs, rep), (rep2, out_cache, rep2)
 
     def build(quantized: bool):
         in_specs, out_specs = specs_for(quantized)
+        out_shardings = jax.tree.map(
+            lambda s: NamedSharding(mesh, s), out_specs,
+            is_leaf=lambda s: isinstance(s, P),
+        )
         return jax.jit(
             # check_vma off: a pallas_call's out_shape carries no
             # varying-axis type, and all_gather's result is typed varying
@@ -1723,7 +1824,8 @@ def make_tp_ragged_step(
                 tp_ragged_step, mesh=mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False,
             ),
-            donate_argnums=(2, 14),  # cache, counts — as the 1-dev step
+            donate_argnums=(2, 3),  # cache, counts — as the 1-dev step
+            out_shardings=out_shardings,
         )
 
     # int8-cache engines carry scale planes (a different cache pytree),
@@ -1735,9 +1837,12 @@ def make_tp_ragged_step(
         # spellings of the same placement — P() from host-side
         # device_puts and rank-expanded P(None, ...) from jit/shard_map
         # outputs — and the jit cache keys on the spelling, not the
-        # placement. Pin ONE canonical form (the rank-expanded one the
-        # step's own outputs carry, so steady-state decode chunks pass
-        # through untouched) to keep the hot loop at one program a width.
+        # placement. Pin ONE canonical form (the rank-expanded one, which
+        # the step's own outputs are given above whatever their rank, so
+        # steady-state decode chunks pass through untouched: left to
+        # itself the rank-1 ``lengths`` came back as ``P()`` and was
+        # placed again every chunk) to keep the hot loop at one program
+        # a width.
         want = NamedSharding(mesh, P(*([None] * x.ndim)))
         sh = getattr(x, "sharding", None)
         if isinstance(sh, NamedSharding) and sh == want:
@@ -1746,17 +1851,17 @@ def make_tp_ragged_step(
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=want)
         return jax.device_put(x, want)
 
-    def placed(params, blk, cache, *rest):
+    def placed(params, ctl, cache, counts):
         """The program for this cache's arity and its operands as it is
-        called with them."""
+        called with them: ``ctl`` as the host made it (the call places it
+        on every chip of the mesh, once)."""
         fn = plain if cache.k_scale is None else quant
         bt = _canon(cache.block_tables)
         ln = _canon(cache.lengths)
         if bt is not cache.block_tables or ln is not cache.lengths:
             cache = replace(cache, block_tables=bt, lengths=ln)
-        rest = list(rest)
-        rest[11] = _canon(rest[11])  # counts (donated, like the cache)
-        return fn, (params, blk, cache, *rest)
+        # counts is donated, like the cache
+        return fn, (params, ctl, cache, _canon(counts))
 
     def step(*ops):
         fn, ops = placed(*ops)
@@ -1979,6 +2084,9 @@ __all__ = [
     "paged_decode_step",
     "paged_ragged_step",
     "make_tp_ragged_step",
+    "pack_control",
+    "unpack_control",
+    "unpack_results",
     "make_logits_probe",
     "make_layer_probe",
     "tp_cache_specs",

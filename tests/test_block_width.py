@@ -518,7 +518,7 @@ def test_a_program_built_before_the_engine_is_not_counted(tiny):
 # ---------------------------------------------------------------------------
 # the stream stage's order
 # ---------------------------------------------------------------------------
-def test_ending_entries_and_first_tokens_leave_first(tiny):
+def test_first_tokens_leave_first_and_ending_entries_last(tiny):
     ce = _cont(tiny, spec_decode=False)
     log: list = []
 
@@ -536,12 +536,12 @@ def test_ending_entries_and_first_tokens_leave_first(tiny):
     del log[:]
     ce.flush_stream()
     assert end.finished and not ce._unstreamed
-    # what ends a request, then the first token alone, then slot order
+    # the first token alone, what goes on in slot order, what ends last
     assert [name for name, _ in log] == (
-        ["end"] * 3 + ["new"] + ["mid"] * 4 + ["new"] * 3)
+        ["new"] + ["mid"] * 4 + ["new"] * 3 + ["end"] * 3)
     # a request's own tokens in their order, the end behind its last
-    assert log[:3] == [("end", end.tokens[4]), ("end", end.tokens[5]),
-                       ("end", "end")]
+    assert log[-3:] == [("end", end.tokens[4]), ("end", end.tokens[5]),
+                        ("end", "end")]
     assert [t for name, t in log if name == "new"] == new.tokens
     assert [t for name, t in log if name == "mid"] == mid.tokens[4:]
     ce.run_until_idle()
@@ -601,3 +601,54 @@ def test_tp2_serves_the_same_streams_from_two_programs(tiny):
     assert one.stats["tp_gather_bytes"] == 0
     one.close()
     tp.close()
+
+
+@pytest.mark.parametrize("n_new", [1, 3])
+def test_a_request_that_ends_with_its_first_chunk_is_finished_once(tiny, n_new):
+    """An answer that ends with its first chunk leaves whole with the
+    first tokens, and is ended once, behind its last token."""
+    ce = _cont(tiny, spec_decode=False)
+    log: list = []
+    other = ce.submit([1, 2, 3], max_new_tokens=12,
+                      stream_cb=lambda tok: log.append(("other", tok)) and None)
+    req = ce.submit(
+        [4, 5, 6], max_new_tokens=n_new, seed=1,
+        stream_cb=lambda tok: log.append(("req", tok)) and None,
+        on_finish=lambda r: log.append(("req", "end")),
+    )
+    ce.step_chunk()
+    assert ce._unstreamed[req.rid][1:4] == (n_new, True, True)
+    ce.flush_stream()
+    names = [name for name, _ in log]
+    assert [t for name, t in log if name == "req"] == req.tokens + ["end"]
+    # the other's first token, the whole answer and its end, the rest
+    assert names == ["other"] + ["req"] * (n_new + 1) + ["other"] * 3
+    assert req.finished and req.done.is_set() and not ce._unstreamed
+    ce.run_until_idle()
+    assert other.finished
+    ce.close()
+
+
+def test_an_end_that_raises_leaves_the_rest_for_the_next_stage(tiny):
+    ce = _cont(tiny, spec_decode=False)
+    heard: list = []
+
+    def bad(req):
+        raise RuntimeError("requester gone")
+
+    a = ce.submit([1, 2, 3], max_new_tokens=3, on_finish=bad)
+    b = ce.submit([4, 5, 6], max_new_tokens=3, seed=1,
+                  on_finish=lambda r: heard.append(r.rid))
+    on = ce.submit([7, 8, 9], max_new_tokens=12, seed=2)  # a step follows
+    ce.step_chunk()
+    with pytest.raises(RuntimeError, match="requester gone"):
+        ce.flush_stream()
+    # the stage stopped at the end that raised: what was behind it stays
+    # pending and leaves with the next stage
+    assert a.done.is_set() and a.finished and not b.done.is_set()
+    assert list(ce._unstreamed) == [b.rid, on.rid]
+    ce.run_until_idle()
+    assert b.finished and heard == [b.rid] and not ce._unstreamed
+    assert on.finished and len(on.tokens) == 12
+    ce.check_page_conservation()
+    ce.close()

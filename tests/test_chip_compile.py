@@ -204,16 +204,23 @@ def _step_operands(cfg, place, place_cache, kv_quant: str = "int8",
             cfg, S, page_size=PAGE, max_len=N_PP * PAGE, kv_quant=kv_quant
         )
     )
-    def ctl(dt, *shape):
-        return place(jax.ShapeDtypeStruct(shape, dt))
+    return _packed_operands(cfg, params, cache, place, place_cache, width)
 
-    i32, f32 = jnp.int32, jnp.float32
+
+def _packed_operands(cfg, params, cache, place, place_cache, width: int = 128):
+    """``(params, ctl, cache, counts)`` as shapes: the step program's
+    operands for a cache of any kind, the slots counted off its block
+    tables."""
+    from tensorlink_tpu.engine.paged import CTL_COLS
+
+    slots = cache.block_tables.shape[0]
+
+    def ctl(*shape):
+        return place(jax.ShapeDtypeStruct(shape, jnp.int32))
+
     return (
-        place(params), ctl(i32, S, width), place_cache(cache), ctl(i32, S),
-        ctl(i32, S), ctl(i32, S), ctl(jnp.bool_, S), ctl(i32, S),
-        ctl(i32, S), ctl(f32, S), ctl(i32, S), ctl(f32, S), ctl(f32, S),
-        ctl(f32, S), ctl(i32, S, cfg.vocab_size), ctl(i32, S),
-        ctl(i32, S, 8),
+        place(params), ctl(slots, width + CTL_COLS), place_cache(cache),
+        ctl(slots, cfg.vocab_size),
     )
 
 
@@ -283,7 +290,7 @@ def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp, width):
     page merges a slot and layer for nine), on one chip and over the 2x2."""
     import dataclasses
 
-    from tensorlink_tpu.engine.paged import paged_ragged_step
+    from tensorlink_tpu.engine.paged import CTL_COLS, paged_ragged_step
     from tensorlink_tpu.models.registry import config_presets
 
     cfg = dataclasses.replace(config_presets()["qwen3-4b"], n_layers=12)
@@ -293,7 +300,7 @@ def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp, width):
         compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
     else:
         compiled, ops = _tp4_step_compiled(v5e_chips, cfg, width)
-    assert ops[1].shape == (S, width)
+    assert ops[1].shape == (S, width + CTL_COLS)
     _assert_pools_stay_put(compiled, ops[2], ops[0], tp)
 
 
@@ -431,18 +438,7 @@ def test_dots3_note_step_fits_one_v5e_with_its_window_kernel(v5e):
     cache = jax.eval_shape(lambda: LatentPagedCache.init(
         cfg, slots, page_size=PAGE, max_len=hf["deployment"]["seq_len"]))
     place = _on(v5e)
-
-    def ctl(dt, *shape):
-        return place(jax.ShapeDtypeStruct(shape, dt))
-
-    i32, f32 = jnp.int32, jnp.float32
-    ops = (
-        place(params), ctl(i32, slots, 128), place(cache), ctl(i32, slots),
-        ctl(i32, slots), ctl(i32, slots), ctl(jnp.bool_, slots),
-        ctl(i32, slots), ctl(i32, slots), ctl(f32, slots), ctl(i32, slots),
-        ctl(f32, slots), ctl(f32, slots), ctl(f32, slots),
-        ctl(i32, slots, cfg.vocab_size), ctl(i32, slots), ctl(i32, slots, 8),
-    )
+    ops = _packed_operands(cfg, params, cache, place, place)
     compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3 and WINDOW_KERNEL in text
@@ -513,18 +509,7 @@ def test_deepseek_v2_step_fits_one_v5e_with_its_walk_in_both_passes(v5e):
     ):
         text = fn.lower(*args, **kw).compile().as_text()
         assert "tpu_custom_call" in text and FULL_KERNEL in text
-
-    def ctl(dt, *shape):
-        return place(jax.ShapeDtypeStruct(shape, dt))
-
-    i32, f32 = jnp.int32, jnp.float32
-    ops = (
-        place(params), ctl(i32, slots, 128), place(cache), ctl(i32, slots),
-        ctl(i32, slots), ctl(i32, slots), ctl(jnp.bool_, slots),
-        ctl(i32, slots), ctl(i32, slots), ctl(f32, slots), ctl(i32, slots),
-        ctl(f32, slots), ctl(f32, slots), ctl(f32, slots),
-        ctl(i32, slots, cfg.vocab_size), ctl(i32, slots), ctl(i32, slots, 8),
-    )
+    ops = _packed_operands(cfg, params, cache, place, place)
     compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
     text = compiled.as_text()
     # lead layer + traced period: (first rows, blocks) + a continuation step

@@ -59,19 +59,19 @@ def _inputs(cfg, kv_quant: str, spec: bool):
     cache = paged._with_kv(
         cache, kv, block_tables=jnp.asarray(bt), lengths=starts + 0
     )
-    n_spec = jnp.asarray([0, 0, 3 if spec else 0, 0], jnp.int32)
-    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
-    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
-    return (
-        i32(rng.integers(1, cfg.vocab_size, (S, C))), cache, starts,
+    n_spec = np.asarray([0, 0, 3 if spec else 0, 0], np.int32)
+    i32 = functools.partial(np.asarray, dtype=np.int32)
+    f32 = functools.partial(np.asarray, dtype=np.float32)
+    ctl = paged.pack_control(
+        rng.integers(1, cfg.vocab_size, (S, C)), np.asarray(starts),
         i32([1, C, 1, 0]) + n_spec, n_spec,
-        jnp.asarray([True, True, True, False]),
+        np.asarray([True, True, True, False]),
         i32([3, 4, 5, 6]), i32([0, 0, 2, 0]),  # seeds, steps
         f32([0, 0.8, 0, 0]), i32([0, 5, 0, 0]), f32([1, 0.9, 1, 1]),
         f32([0, 0.1, 0, 0]), f32([0, 0.1, 0, 0]),
-        jnp.zeros((S, cfg.vocab_size), jnp.int32), i32([9, 9, 9, 9]),
-        jnp.full((S, 2), -1, jnp.int32),
+        i32([9, 9, 9, 9]), np.full((S, 2), -1, np.int32),
     )
+    return ctl, cache, jnp.zeros((S, cfg.vocab_size), jnp.int32)
 
 
 def _step(monkeypatch, cfg, params, tp: int, kernel: bool, W: int):
@@ -93,8 +93,8 @@ def _step(monkeypatch, cfg, params, tp: int, kernel: bool, W: int):
         x, jax.sharding.NamedSharding(mesh, s)))
     specs = paged.tp_cache_specs
 
-    def sharded(params, blk, cache, *rest):
-        return step(params, blk, put(cache, specs(cache.quantized)), *rest)
+    def sharded(params, ctl, cache, counts):
+        return step(params, ctl, put(cache, specs(cache.quantized)), counts)
 
     return sharded, put(params, tp_partition_specs(cfg))
 
@@ -138,7 +138,8 @@ def test_carried_pools_equal_the_sliced_loop(
         np.testing.assert_array_equal(g, w)
     # the block did something to compare: every emitting slot drew
     # tokens, and the pools changed in every layer
-    tokens, n_tok, cache = got[0], got[1], got[4]
+    (_tokens, n_tok, _m, _n_exec, _stats), cache = paged.unpack_results(
+        got[0], N_STEPS, W), got[1]
     assert (n_tok[:3] >= 1).all() and n_tok[3] == 0, n_tok
     assert all((cache.k[i] != before[i]).any() for i in range(cfg.n_layers))
 

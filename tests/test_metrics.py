@@ -255,6 +255,8 @@ LEGACY_ENGINE_KEYS = (
     # the stream stage: its microseconds (inside wait or deliver) and the
     # tokens that left under a dispatched step / with none in flight
     "chunk_us_stream", "stream_tokens_overlapped", "stream_tokens_flushed",
+    # the host-device boundary: arrays a chunk placed and fetched (2)
+    "chunk_host_arrays",
 )
 PHASES = ("between", "admit", "pack", "dispatch", "wait", "drain",
           "deliver", "post")

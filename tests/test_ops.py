@@ -684,7 +684,8 @@ def test_ragged_packing_framing_is_bitwise_invariant():
     packing function hand out any grant schedule (fair-share, budget-
     capped, full-chunk) without moving a bit of any stream."""
     from tensorlink_tpu.engine.paged import (
-        PagedKVCache, bind_slot, paged_ragged_step,
+        PagedKVCache, bind_slot, pack_control, paged_ragged_step,
+        unpack_results,
     )
     from tensorlink_tpu.models import ModelConfig, init_params
 
@@ -710,10 +711,10 @@ def test_ragged_packing_framing_is_bitwise_invariant():
         cache = bind_slot(
             cache, jnp.int32(1), jnp.asarray(bt1), jnp.int32(0)
         )
-        zeros_i = jnp.zeros(S, jnp.int32)
-        zeros_f = jnp.zeros(S, jnp.float32)
+        zeros_i = np.zeros(S, np.int32)
+        zeros_f = np.zeros(S, np.float32)
         counts = jnp.zeros((S, cfg.vocab_size), jnp.int32)
-        eos = jnp.full((S, 2), -1, jnp.int32)
+        eos = np.full((S, 2), -1, np.int32)
         pos = 0
         first_draw = None
         for step_i, g in enumerate(schedule):
@@ -730,16 +731,16 @@ def test_ragged_packing_framing_is_bitwise_invariant():
             starts[1], nv[1] = step_i, 1
             done_prefill = pos + g >= T
             emit[0] = done_prefill  # final chunk: greedy first draw
-            tokens, _nt, _m, n_exec, cache, _d, _s, counts, _r = \
-                paged_ragged_step(
-                    params, jnp.asarray(blk), cache, jnp.asarray(starts),
-                    jnp.asarray(nv), zeros_i, jnp.asarray(emit),
-                    zeros_i, zeros_i, zeros_f, zeros_i,
-                    jnp.ones(S, jnp.float32), zeros_f, zeros_f, counts,
-                    jnp.ones(S, jnp.int32), eos, cfg, 1, 1, False,
-                )
+            ctl = pack_control(
+                blk, starts, nv, zeros_i, emit, zeros_i, zeros_i, zeros_f,
+                zeros_i, np.ones(S, np.float32), zeros_f, zeros_f,
+                np.ones(S, np.int32), eos,
+            )
+            out, cache, counts = paged_ragged_step(
+                params, ctl, cache, counts, cfg, 1, 1, False,
+            )
             if done_prefill:
-                first_draw = int(np.asarray(tokens)[0, 0])
+                first_draw = int(unpack_results(np.asarray(out), 1, 1)[0][0, 0])
             pos += g
         k = np.asarray(cache.k)
         real = np.stack(
