@@ -85,6 +85,7 @@ from ..core.trace import (
     first_token_stamp,
     get_tracer,
 )
+from ..models.sala import check_sparse
 from ..models.transformer import tp_partition_specs, tp_shardable
 from ..parallel.mesh import serving_mesh
 from .generate import GenerationEngine
@@ -110,6 +111,7 @@ from .paged import (
     tp_gather_costs,
     unpack_results,
 )
+from .sala import restore_snapshot, take_snapshot, zero_state
 from .sampling import SamplingParams, penalized, sample
 from .spec import SpecController
 from .scheduler import (
@@ -428,6 +430,29 @@ _ENGINE_COUNTERS = (
      "cached rows the full layers' walk reads (each slot's live span)"),
     ("latent_rows_capacity", "tlink_engine_latent_rows_capacity_total",
      "rows the slots of those passes could hold"),
+    # block-sparse GQA and lightning layers (engine/sala.py): the step's
+    # own counts, summed like the ones above over a chunk's layer
+    # executions of the kind ...
+    ("sparse_blocks_kept", "tlink_engine_sparse_blocks_kept_total",
+     "blocks the sparse layers attended, a query row and kv group"),
+    ("sparse_blocks_visible", "tlink_engine_sparse_blocks_visible_total",
+     "blocks those queries could see (their causal spans)"),
+    ("sparse_rows_dense", "tlink_engine_sparse_rows_dense_total",
+     "query rows under dense_len (nothing selected), a sparse layer"),
+    ("lightning_rows", "tlink_engine_lightning_rows_total",
+     "rows the recurrence took, a lightning layer"),
+    # ... and what admission did about the slots' states (host side)
+    ("state_admissions", "tlink_engine_state_admissions_total",
+     "admissions of a model with recurrent layers"),
+    ("state_snapshots_taken", "tlink_engine_state_snapshots_taken_total",
+     "state snapshots taken where a prefill chunk ended"),
+    ("state_snapshots_restored",
+     "tlink_engine_state_snapshots_restored_total",
+     "admissions that restored a snapshot under their prefix hit"),
+    ("state_snapshots_skipped", "tlink_engine_state_snapshots_skipped_total",
+     "snapshot points passed with no place free in the snapshot pool"),
+    ("state_rows_replayed", "tlink_engine_state_rows_replayed_total",
+     "cached positions prefilled again between a snapshot and the match"),
     # the sampling epilogue (ROADMAP S1): what the packed slots asked of
     # it, per dispatched chunk from the host's own arrays. A sampler call
     # is one _sample_rows over [slots, vocabulary]: each verify row
@@ -544,6 +569,13 @@ class ContinuousRequest:
     # the request opted in ({"speculative": true}); only effective on an
     # engine with MLConfig.spec_decode enabled
     speculative: bool = False
+    # -- a model with recurrent layers (engine/sala.py) -------------------
+    # snapshots of the slot's state this admission's prefill took, by
+    # position (places in the engine's snapshot pool): they join the trie
+    # with the pages at release. ``state_restored_at``: the position of
+    # the snapshot the admission restored (-1: none, the state began at 0)
+    snaps: dict = field(default_factory=dict)
+    state_restored_at: int = -1
     # per-request drafting state machine (created lazily at the first
     # decode pack; survives preemption/requeue so the permanent kill
     # switch never re-probes; NOT shipped by migration — a migrated
@@ -635,6 +667,8 @@ class ContinuousEngine:
         model_id: str = "",
         page_quota: int = 0,
         tensor_parallel: int = 1,
+        state_snapshot_stride: int = 0,
+        state_snapshots: int = 0,
     ):
         if engine.cfg.sliding_window is not None:
             raise PagedUnsupported(
@@ -645,22 +679,41 @@ class ContinuousEngine:
         # (engine/latent.py): the parts of the system that move or share
         # K/V pages by name do not know them yet, and say so
         self._latent = bool(engine.cfg.patterned)
+        # ... and a model with recurrent layers holds a state a slot that
+        # no page chain describes: what reuses or moves a slot restores a
+        # snapshot and replays, or refuses (``stateful_refusals``)
+        self._stateful = bool(engine.cfg.recurrent)
+        what = "pages and recurrent states" if self._stateful else (
+            "latent pages")
         if self._latent:
             asked = str(kv_quant or "none")
             refusals = (
                 (paged_unsupported(engine.cfg), None),
                 (asked != "none" or engine.cache_quant,
-                 f"latent pages are stored in the model dtype (kv_quant "
+                 f"{what} are stored in the model dtype (kv_quant "
                  f"{asked!r} asked)"),
-                (pool is not None, "latent pages in a shared page pool"),
-                (int(host_tier_pages) > 0,
-                 "latent pages in the host-RAM tier"),
+                (pool is not None, f"{what} in a shared page pool"),
+                (int(host_tier_pages) > 0, f"{what} in the host-RAM tier"),
                 (handoff_after_prefill,
-                 "latent pages do not hand off between workers"),
+                 f"{what} do not hand off between workers"),
+                (self._stateful and int(tensor_parallel or 1) > 1,
+                 "recurrent states have no partition specs "
+                 f"(tensor_parallel={tensor_parallel} asked)"),
+                ("sparse" in engine.cfg.layer_kinds and check_sparse(
+                    engine.cfg.latent_of("sparse"), int(page_size)), None),
             )
             for hit, why in refusals:
                 if hit:
                     raise PagedUnsupported(f"patterned model: {why or hit}")
+        # requests that ask to draft are served without drafts: a rejected
+        # draft row would have advanced the state (said in
+        # ``serving_snapshot()["spec_refusal"]``)
+        self.spec_refusal = ""
+        if self._stateful and spec_decode:
+            spec_decode = False
+            self.spec_refusal = (
+                "a model with recurrent layers does not draft: a rejected "
+                "draft row would have advanced the slot's state")
         if int(prefill_chunk) <= 0:
             raise ValueError(
                 "prefill_chunk must be >= 1 — the monolithic dense-prefill "
@@ -750,6 +803,40 @@ class ContinuousEngine:
         # long admission never stalls running slots at all
         self.prefill_chunk = min(int(prefill_chunk), self.max_seq_len)
         self.prefix = PrefixCache(self.page_size) if prefix_cache else None
+        # -- a model with recurrent layers: the snapshot pool -------------
+        # A prefix hit is only as good as the nearest state snapshot at or
+        # below it: the slot restores that and prefills the rest again. A
+        # state exists only where a chunk of the ragged pass ended, so a
+        # prefill's grants stop at every multiple of ``snap_stride`` (the
+        # constructor's ``state_snapshot_stride``, sized from the context
+        # when 0; ``state_snapshots`` places, 0 = one a stride plus two a
+        # slot: both are sizes a test sets, not deployment options) and at
+        # the last page edge under its prompt's end (what the trie inserts
+        # at release), and a snapshot is taken at each stop. Snapshots
+        # belong to trie nodes and leave with them; when the pool is full
+        # the one whose NODE was matched longest ago goes: every admission
+        # walks the shared document's nodes, so its snapshots stay while
+        # those of sessions that ended go (by the snapshots' own last use
+        # the document's went first, restored only by first turns: eight
+        # new sessions then prefilled 32,768 tokens each, PERF.md PR 42).
+        self.snap_stride = 0
+        self._snaps = None
+        self._snap_free: list[int] = []
+        self._snap_nodes: dict[int, object] = {}  # place -> trie node
+        if self._stateful and self.prefix is not None:
+            # 32 prefill chunks, or an eighth of the context where that
+            # is less (a short context still gets its eight)
+            chunk = self.prefill_chunk
+            stride = int(state_snapshot_stride) or min(
+                32 * chunk, max(self.max_seq_len // 8 // chunk, 1) * chunk)
+            self.snap_stride = -(-stride // self.page_size) * self.page_size
+            n = int(state_snapshots) or (
+                self.max_seq_len // self.snap_stride + 2 * self.max_slots)
+            st = self.cache.state
+            self._snaps = jnp.zeros((n,) + st.shape[:1] + st.shape[2:],
+                                    st.dtype)
+            self._snap_free = list(range(n))
+            self.prefix.on_drop = self._drop_snapshot
         # -- tiered prefix cache (docs/SERVING.md "Tiered prefix cache") -
         # host_tier_pages > 0 arms the host-RAM tier: refcount-0 pages
         # the trie evicts DEMOTE there (PrefixCache.spill) instead of
@@ -1557,7 +1644,19 @@ class ContinuousEngine:
                 hit_nodes = self._pull_chain(seq, limit, hit_nodes)
                 if len(hit_nodes) > n0:
                     req.cache_tier = "fleet"
-            cow = self.prefix.partial_match(hit_nodes, seq, limit)
+            replayed = 0
+            if self._stateful:
+                # the hit ends at the nearest snapshot at or below the
+                # match; what lies between is prefilled again (through
+                # every layer: a recurrent layer's input is the output of
+                # the layers before it), into pages of the slot's own
+                keep = max((i + 1 for i, n in enumerate(hit_nodes)
+                            if n.snap is not None), default=0)
+                replayed = (len(hit_nodes) - keep) * self.page_size
+                self.prefix.release(hit_nodes[keep:])
+                hit_nodes = hit_nodes[:keep]
+            else:
+                cow = self.prefix.partial_match(hit_nodes, seq, limit)
             if cow is not None:
                 self.prefix.acquire([cow[0]])
         n_hit = len(hit_nodes)
@@ -1589,6 +1688,8 @@ class ContinuousEngine:
                 self.cache, jnp.int32(slot), jnp.asarray(bt_row),
                 jnp.int32(hit_len),
             )
+            if self._stateful:
+                self._admit_state(req, slot, hit_nodes, hit_len)
         except BaseException:
             # a failed admission must not leak: return the private pages
             # and drop the pinned refs so close()'s conservation check
@@ -1610,6 +1711,10 @@ class ContinuousEngine:
         self._arm_slot(req, slot)
         self._count("admitted")
         self._count("prefill_tokens_skipped", hit_len)
+        if self._stateful:
+            self._count("state_admissions")
+            if self.prefix is not None:
+                self._count("state_rows_replayed", replayed)
         if self.prefix is not None:
             # counted HERE, not in match(): one lookup per admission, so
             # head-of-line page-wait retries don't skew the hit rate
@@ -1618,6 +1723,72 @@ class ContinuousEngine:
                 self.prefix.stats["hits"] += 1
             self.prefix.stats["hit_tokens"] += hit_len
         return True
+
+    # -- recurrent state: snapshots (engine/sala.py) ----------------------
+    def _admit_state(self, req, slot: int, hit_nodes: list,
+                     hit_len: int) -> None:
+        """``slot``'s state as ``hit_len`` positions left it: the snapshot
+        of the hit's last node, or zero where nothing was hit."""
+        req.snaps = {}
+        if hit_nodes:
+            idx = hit_nodes[-1].snap
+            self.cache = restore_snapshot(
+                self.cache, self._snaps, jnp.int32(slot), jnp.int32(idx))
+            req.state_restored_at = hit_len
+            self._count("state_snapshots_restored")
+        else:
+            self.cache = zero_state(self.cache, jnp.int32(slot))
+            req.state_restored_at = -1
+
+    def _next_stop(self, req: ContinuousRequest) -> int:
+        """Where ``req``'s next prefill grant has to end at the latest: the
+        next multiple of ``snap_stride``, the last page edge under the
+        prompt's last token (the length the trie inserts at release), or
+        the prompt's end."""
+        T, pos = len(req.prefill_tokens), req.prefill_pos
+        if not self.snap_stride:
+            return T
+        final = (T - 1) // self.page_size * self.page_size
+        stop = (pos // self.snap_stride + 1) * self.snap_stride
+        if pos < final:
+            stop = min(stop, final)
+        return min(stop, T)
+
+    def _snapshot_slot(self, req: ContinuousRequest, slot: int) -> None:
+        """Keep ``slot``'s state at ``req.prefill_pos``, where its chunk
+        just ended, if that is a snapshot point."""
+        T, pos = len(req.prefill_tokens), req.prefill_pos
+        final = (T - 1) // self.page_size * self.page_size
+        if not self.snap_stride or not 0 < pos < T or pos in req.snaps or (
+            pos % self.snap_stride and pos != final
+        ):
+            return
+        if not self._snap_free and self._snap_nodes:
+            # the pool is full: the snapshot of the node matched longest
+            # ago goes (the node stays; a hit there restores further down)
+            self._drop_snapshot(
+                min(self._snap_nodes.values(), key=lambda n: n.tick))
+        if not self._snap_free:
+            self._count("state_snapshots_skipped")
+            return
+        idx = self._snap_free.pop()
+        self._snaps = take_snapshot(
+            self._snaps, self.cache.state, jnp.int32(slot), jnp.int32(idx))
+        req.snaps[pos] = idx
+        self._count("state_snapshots_taken")
+
+    def _drop_snapshot(self, node) -> None:
+        """``node``'s snapshot, if it has one, back to the free places
+        (``PrefixCache.on_drop``: the node leaves the trie)."""
+        idx, node.snap = node.snap, None
+        if idx is not None:
+            del self._snap_nodes[idx]
+            self._snap_free.append(idx)
+
+    def _free_snapshots(self, req: ContinuousRequest) -> None:
+        """The snapshots ``req`` took that no trie node took over."""
+        self._snap_free.extend(req.snaps.values())
+        req.snaps = {}
 
     # -- tiered prefix cache (docs/SERVING.md "Tiered prefix cache") -----
     # tlint: hot-path
@@ -2160,6 +2331,7 @@ class ContinuousEngine:
                 self._release_pages(req)
             else:
                 self.alloc.free(req.pages)
+                self._free_snapshots(req)
             req.pages = []
             req.shared_nodes = []
         return req
@@ -2226,12 +2398,18 @@ class ContinuousEngine:
                     # an identical chain landed first (e.g. a co-batched
                     # twin finished earlier): keep theirs, free ours
                     free_list.append(pid)
+                if hi in req.snaps and node.snap is None:
+                    # the state after this page's last position goes
+                    # where the page goes
+                    node.snap = req.snaps.pop(hi)
+                    self._snap_nodes[node.snap] = node
             else:
                 # the chain must stay contiguous from position 0 — once a
                 # page can't be promoted, nothing after it can attach
                 promoting = False
                 free_list.append(pid)
         self.alloc.free(free_list)
+        self._free_snapshots(req)
 
     # -- live slot migration (export side) + drain -----------------------
     # Protocol (docs/FAILURE_MODEL.md "Migration & drain"): the DRIVER
@@ -2285,8 +2463,11 @@ class ContinuousEngine:
         never grow the compiled-program set."""
         if self._latent:
             raise PagedUnsupported(
-                "a patterned model's latent pages do not migrate yet: the "
-                "stream falls back to re-prefill on its destination"
+                "a patterned model's "
+                + ("pages and recurrent states" if self._stateful
+                   else "latent pages")
+                + " do not migrate yet: the stream falls back to "
+                "re-prefill on its destination"
             )
         req = self._slots[slot]
         if req is None or slot not in self._frozen:
@@ -2901,6 +3082,30 @@ class ContinuousEngine:
             )
         if self.host_tier is not None:
             self.host_tier.check_conservation()
+        if self._snaps is not None:
+            self._check_snapshot_conservation()
+
+    def _check_snapshot_conservation(self) -> None:
+        """Every place of the snapshot pool is free, a resident trie
+        node's, or a live request's, and only one of them."""
+        held = [i for r in self._slots if r is not None
+                for i in r.snaps.values()]
+        owned = self._snap_free + list(self._snap_nodes) + held
+        problems = []
+        if len(owned) != len(set(owned)):
+            problems.append("a snapshot place has two owners")
+        if len(owned) != self._snaps.shape[0]:
+            problems.append("leak: the owners do not sum to the pool")
+        for idx, node in self._snap_nodes.items():
+            if node.snap != idx or self.prefix._by_page.get(node.page) is not node:
+                problems.append(f"place {idx} belongs to no resident node")
+        if problems:
+            raise AssertionError(
+                "snapshot conservation violated: " + "; ".join(problems)
+                + f" [free={len(self._snap_free)} "
+                f"trie={len(self._snap_nodes)} slots={len(held)} vs "
+                f"total={self._snaps.shape[0]}]"
+            )
 
     def _pages_in_transit(self) -> int:
         """Pages currently held by an in-flight migration on either side:
@@ -2922,6 +3127,9 @@ class ContinuousEngine:
         registry but stay byte-compatible with the pre-registry dicts
         (test-pinned; see docs/SERVING.md "Telemetry")."""
         out = dict(self.stats)
+        state_bytes = self.cache.state_bytes if self._stateful else 0
+        snap_bytes = 0 if self._snaps is None else (
+            self._snaps.size * self._snaps.dtype.itemsize)
         # KV storage mode + occupancy: the capacity math operators size
         # slots-per-chip with (kv_quant="int8" halves kv_page_bytes)
         c = self.cache
@@ -2983,6 +3191,13 @@ class ContinuousEngine:
             "latent_pool_bytes": (
                 self.cache.pool_bytes if self._latent else 0
             ),
+            # a model with recurrent layers: the slots' states, and the
+            # states and snapshots together (0 for other models)
+            "lightning_state_bytes": state_bytes,
+            "state_snapshot_bytes": snap_bytes,
+            "state_pool_bytes": state_bytes + snap_bytes,
+            "state_snapshots_resident": len(self._snap_nodes),
+            "spec_refusal": self.spec_refusal,
             "weights_bytes_device_max": max(self.weights_bytes_device),
             "weights_bytes_device_min": min(self.weights_bytes_device),
             # what building the step programs cost, and what of it a
@@ -3116,6 +3331,8 @@ class ContinuousEngine:
                 self._trace(
                     req, "admission", dur_s=req.admit_t - t_adm,
                     t0=t_adm, slot=req.slot, cache_hit_tokens=req.prefill_pos,
+                    **({"state_restored_at": req.state_restored_at}
+                       if self._stateful else {}),
                     # deepest tier that fed the hit region — "hbm",
                     # "host", "fleet", or "none" (adopted migrations
                     # keep their own "adopt" span instead)
@@ -3175,6 +3392,13 @@ class ContinuousEngine:
             - (1 if self._prefilling[s].handoff else 0)
             for s in pf_slots
         ]
+        if self._stateful:
+            # a grant ends where a snapshot is due (``_next_stop``)
+            pf_rem = [
+                min(r, self._next_stop(self._prefilling[s])
+                    - self._prefilling[s].prefill_pos)
+                for s, r in zip(pf_slots, pf_rem)
+            ]
         budgets = pack_prefill_budgets(
             pf_rem, C,
             self.prefill_budget if self.prefill_budget > 0 else None,
@@ -3330,9 +3554,10 @@ class ContinuousEngine:
         layers' window spans reach against the pages of context under
         them, and the rows the walk of the full layers that select
         nothing reads against the rows those slots could hold."""
-        from ..models.latent import STEP_STATS, kind_counts
+        from ..models.latent import kind_counts
+        from ..models.sala import step_stats as names_of
 
-        for name, v in zip(STEP_STATS, step_stats):
+        for name, v in zip(names_of(self.cfg), step_stats):
             self._count(name, int(v))
         ctx = starts + n_valid
         rows = n_valid > 0
@@ -3670,6 +3895,8 @@ class ContinuousEngine:
         for s, g in grants.items():
             req = self._prefilling[s]
             req.prefill_pos += g
+            if self._stateful:
+                self._snapshot_slot(req, s)
             self._count("prefill_chunks")
             self._count("prefill_tokens", g)
             self._trace(

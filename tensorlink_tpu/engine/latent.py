@@ -53,12 +53,13 @@ the window's span only; freeing pages behind the window is ROADMAP R2.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..models.base import LatentAttn, ModelConfig
+from ..models.base import SALA_KINDS, LatentAttn, ModelConfig
 from ..models.latent import (
     _rms,
     EXPERT_STACKS,
@@ -84,6 +85,7 @@ from ..models.latent import (
     top_k_positions,
 )
 from ..models.quant import matmul as _mm
+from ..models.sala import is_sala, step_stats
 from ..ops.attention import (
     paged_attention,
     paged_attention_ref,
@@ -113,9 +115,16 @@ class LatentPagedCache:
     slide: jax.Array | None
     block_tables: jax.Array  # int32 [S, pages_per_slot]
     lengths: jax.Array  # int32 [S]
-    stats: jax.Array  # int32 [len(STEP_STATS)]: this step's counts
+    stats: jax.Array  # int32 [len(step_stats(cfg))]: this step's counts
+    # block-sparse GQA layers (engine/sala.py): keys, values, and one
+    # float32 sum of keys a page; lightning layers: a state a slot, which
+    # no page describes
+    k: jax.Array | None = None
+    v: jax.Array | None = None
+    ksum: jax.Array | None = None
+    state: jax.Array | None = None  # float32 [Ll, S, H, hd, hd]
 
-    POOLS = ("full", "index", "slide")  # pages in the model dtype
+    POOLS = ("full", "index", "slide", "k", "v", "ksum")  # page axis 1
 
     @classmethod
     def init(cls, cfg: ModelConfig, max_slots: int, *, page_size: int = 16,
@@ -128,6 +137,11 @@ class LatentPagedCache:
         n = kind_counts(cfg)
         sizes = dict(cfg.latent)
         full, slide = sizes.get("full"), sizes.get("sliding")
+        extra = {}
+        if is_sala(cfg):
+            from .sala import init_pools
+
+            extra = init_pools(cfg, max_slots, P, page_size, dt)
 
         def pool(kind, width):
             # no pool for a kind, or a selector, the model has not: a
@@ -143,7 +157,8 @@ class LatentPagedCache:
             slide=pool("sliding", slide and slide.pool_dim),
             block_tables=jnp.zeros((max_slots, n_pp), jnp.int32),
             lengths=jnp.zeros((max_slots,), jnp.int32),
-            stats=jnp.zeros((len(STEP_STATS),), jnp.int32),
+            stats=jnp.zeros((len(step_stats(cfg)),), jnp.int32),
+            **extra,
         )
 
     def pools(self) -> dict:
@@ -153,8 +168,8 @@ class LatentPagedCache:
 
     @property
     def rows(self) -> jax.Array:
-        """A pool of latent rows (any: the control state is shared)."""
-        return self.full if self.full is not None else self.slide
+        """A page pool (any: the control state is shared)."""
+        return next(iter(self.pools().values()))
 
     @property
     def quantized(self) -> bool:
@@ -163,6 +178,12 @@ class LatentPagedCache:
     @property
     def page_size(self) -> int:
         return self.rows.shape[3]
+
+    @property
+    def state_bytes(self) -> int:
+        """The lightning layers' states of every slot."""
+        return 0 if self.state is None else (
+            self.state.size * self.state.dtype.itemsize)
 
     @property
     def n_pages(self) -> int:
@@ -187,9 +208,17 @@ def unsupported(cfg: ModelConfig) -> str | None:
     each kind in use with its sizes."""
     kinds = set(cfg.layer_kinds)
     sizes = dict(cfg.latent)
-    if not kinds <= {"full", "sliding"} or not kinds <= set(sizes):
+    served = {"full", "sliding"} | set(SALA_KINDS)
+    if not kinds <= served or not kinds <= set(sizes):
         return (f"layer kinds {sorted(kinds)} with sizes for "
-                f"{sorted(sizes)} (served: full, sliding)")
+                f"{sorted(sizes)} (served: full, sliding, sparse, "
+                "lightning)")
+    if kinds & set(SALA_KINDS):
+        if kinds - set(SALA_KINDS):
+            return "sparse / lightning layers beside latent layers"
+        if cfg.n_experts:
+            return "routed experts beside sparse / lightning layers"
+        return None
     if "sliding" in kinds:
         if sizes["sliding"].window is None:
             return "a sliding layer without a window"
@@ -416,8 +445,12 @@ def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     pools first; returns ``(added, pools)`` with ``pools`` = ``(full,
     index, slide, stats)``."""
     cfg = ctx.cfg
+    if kind in SALA_KINDS:
+        from . import sala
+
+        return sala.attention(x, lp, kind, li, pools, ctx)
     la = cfg.latent_of(kind)
-    full, index, slide, stats = pools
+    full, index, slide, stats = pools[:4]
     S, T, d = x.shape
     ap = lp["attn"]
     cos, sin = ctx.rope[kind]
@@ -442,29 +475,28 @@ def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         if "gate" in q:
             o = (o.astype(jnp.float32) * q["gate"][..., None]).astype(x.dtype)
         added = _mm(o.reshape(S, T, -1), ap["wo"])
-    return added, (full, index, slide, stats)
+    return added, pools._replace(
+        full=full, index=index, slide=slide, stats=stats)
 
 
 def _layer(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     """One layer: :func:`_attention`, then its MLP or its experts."""
     cfg = ctx.cfg
     S, T, d = x.shape
-    added, (full, index, slide, stats) = _attention(
-        x, lp, kind, li, pools, ctx
-    )
+    added, pools = _attention(x, lp, kind, li, pools, ctx)
     with jax.named_scope("attn"):
         x = x + added
     h = _rms(x, lp["ln2"]["scale"], cfg.norm_eps)
     if "mlp" in lp:
         with jax.named_scope("mlp"):
-            return x + gated_mlp(h, lp["mlp"]), (full, index, slide, stats)
+            return x + gated_mlp(h, lp["mlp"]), pools
     with jax.named_scope(MOE):
         y, ms = moe_mlp(
             h.reshape(S * T, d), lp["moe"], cfg, ctx.row_ok.reshape(-1)
         )
         # each adds up over layers and steps
-        stats = stats.at[:N_MOE_STATS].add(ms)
-    return x + y.reshape(S, T, d), (full, index, slide, stats)
+        pools = pools._replace(stats=pools.stats.at[:N_MOE_STATS].add(ms))
+    return x + y.reshape(S, T, d), pools
 
 
 # ---------------------------------------------------------------------------
@@ -472,15 +504,27 @@ def _layer(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
 # ---------------------------------------------------------------------------
 
 
-def cache_pools(cache: LatentPagedCache) -> tuple:
-    return (cache.full, cache.index, cache.slide, cache.stats)
+class Pools(NamedTuple):
+    """What the layer loop carries of a patterned model's cache: the page
+    pools there are, the step's counts and, for lightning layers, the
+    slots' states. A field the model has not is None (no leaf)."""
+
+    full: jax.Array | None
+    index: jax.Array | None
+    slide: jax.Array | None
+    stats: jax.Array
+    k: jax.Array | None = None
+    v: jax.Array | None = None
+    ksum: jax.Array | None = None
+    state: jax.Array | None = None
 
 
-def with_pools(cache: LatentPagedCache, pools: tuple, **kw):
-    full, index, slide, stats = pools
-    return replace(
-        cache, full=full, index=index, slide=slide, stats=stats, **kw
-    )
+def cache_pools(cache: LatentPagedCache) -> Pools:
+    return Pools(*(getattr(cache, n) for n in Pools._fields))
+
+
+def with_pools(cache: LatentPagedCache, pools: Pools, **kw):
+    return replace(cache, **pools._asdict(), **kw)
 
 
 def layer_loop(lead, periods, tail, pat: Pattern, x, carry, layer,
@@ -541,7 +585,13 @@ def layer_loop(lead, periods, tail, pat: Pattern, x, carry, layer,
 def run_layers(params, x, cache: LatentPagedCache, ctx: _Ctx):
     """Every layer of a patterned model over ``x``: :func:`layer_loop`
     with the pools per kind as its carry and the periods' expert stacks
-    kept whole. Returns ``(x, pools)``."""
+    kept whole; a model of sparse / lightning layers, whose order has no
+    period, loops over runs of one kind (engine/sala.py). Returns ``(x,
+    pools)``."""
+    if is_sala(ctx.cfg):
+        from . import sala
+
+        return sala.run_layers(params, x, cache_pools(cache), ctx)
     sliced, whole = [], []
     for lp in params["periods"]:
         moe = lp.get("moe", {})
@@ -623,7 +673,8 @@ def attention_only(lp, x, cache, cfg: ModelConfig, kernel: bool, kind: str,
 
 
 __all__ = [
-    "FULL_KERNEL", "LatentPagedCache", "WINDOW_KERNEL", "attention_only",
+    "FULL_KERNEL", "LatentPagedCache", "Pools", "WINDOW_KERNEL",
+    "attention_only",
     "cache_pools",
     "decode_layers", "layer_loop", "ragged_layers", "run_layers",
     "unsupported", "with_pools",

@@ -533,7 +533,7 @@ class _TrieNode:
 
     __slots__ = (
         "block", "page", "parent", "children", "refs", "tick",
-        "depth", "key_hash", "weights_version",
+        "depth", "key_hash", "weights_version", "snap",
     )
 
     def __init__(self, block: tuple, page: int, parent: "_TrieNode | None"):
@@ -547,6 +547,10 @@ class _TrieNode:
         # (PrefixCache.insert stamps it): the match fence for live weight
         # publishes — see ContinuousEngine.publish_weights
         self.weights_version = 1
+        # a model with recurrent layers: the engine's snapshot of the
+        # slot's state after this page's last position (its place in the
+        # snapshot pool), or None. Dropped with the node (``on_drop``)
+        self.snap: int | None = None
         # chain identity for the fleet digest: pages-from-root count and
         # the rolling chain hash (root carries depth 0 / hash "")
         if parent is None:
@@ -599,6 +603,9 @@ class PrefixCache:
         # fails to demote is simply destroyed, the pre-tier behavior),
         # so eviction itself can never be blocked by the tier below.
         self.spill = None
+        # called with every node that leaves the trie, after ``spill``:
+        # the engine frees what it keeps by node (a state snapshot)
+        self.on_drop = None
         self.stats = {
             "lookups": 0,
             "hits": 0,
@@ -740,6 +747,8 @@ class PrefixCache:
         ):
             del parent.children[block]
             del self._by_page[existing.page]
+            if self.on_drop is not None:
+                self.on_drop(existing)
             self.stats["evictions"] += 1
             self.version += 1
             if freed is not None:
@@ -792,6 +801,8 @@ class PrefixCache:
                 # HBM (its page id hasn't been reused yet) — offer them
                 # to the tier below before the trie forgets the chain
                 self.spill(victim)
+            if self.on_drop is not None:
+                self.on_drop(victim)
             del victim.parent.children[victim.block]
             del self._by_page[victim.page]
             self.stats["evictions"] += 1
@@ -1143,6 +1154,15 @@ def _scan_layers(params, x, cache: PagedKVCache, block):
     )
 
 
+def _final_norm(x, params, cfg: ModelConfig):
+    """The final norm and, where the model has one, the divisor of what
+    goes to the head (MiniCPM: ``hidden_size / dim_model_base``)."""
+    x = _norm(x, params["final_norm"], cfg)
+    if cfg.logit_div != 1.0:
+        x = x / jnp.asarray(cfg.logit_div, x.dtype)
+    return x
+
+
 # tlint: hot-path
 def _decode_step_impl(
     params,
@@ -1194,7 +1214,7 @@ def _decode_step_impl(
                 att_len, cache.block_tables, kernel, tp_axis, tp_quant,
             ),
         )
-    x = _norm(x, params["final_norm"], cfg)
+    x = _final_norm(x, params, cfg)
     logits = _logits(params, x, cfg, tp_axis, tp_quant)[:, 0]
     new_cache = _with_kv(
         cache, kv_new, lengths=jnp.where(active, lengths + 1, lengths)
@@ -1435,7 +1455,7 @@ def _ragged_pass(
                     tp_quant,
                 ),
             )
-        x = _norm(x, params["final_norm"], cfg)
+        x = _final_norm(x, params, cfg)
     # verification rows: the last spec_width rows of each slot's valid
     # span — base = n_valid - 1 - n_spec, so a non-speculating slot
     # (n_spec 0: plain decode, completing prefill, idle) gathers exactly

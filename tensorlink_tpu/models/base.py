@@ -115,6 +115,84 @@ class LatentAttn:
 
 
 @dataclass(frozen=True)
+class SparseAttn:
+    """Sizes of a block-sparse GQA layer (layer kind ``"sparse"``: keys and
+    values of ``n_kv_heads`` heads in pages, per-head RMSNorm on ``q`` and
+    ``k``, no rotary positions, a sigmoid gate a channel on the output).
+    A query at position ``t >= dense_len`` attends ``topk`` blocks of
+    ``block`` positions a kv group, chosen by its own heads' scores against
+    pooled keys (the mean of ``pool`` keys every ``stride`` positions), the
+    first ``init_blocks`` blocks and the blocks of the last ``window``
+    positions always among them; below ``dense_len`` every ``s <= t``."""
+
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    pool: int = 32  # positions under one pooled key
+    stride: int = 16  # ... and between two pooled keys' first positions
+    block: int = 64
+    init_blocks: int = 1
+    window: int = 2048
+    topk: int = 64
+    dense_len: int = 8192
+    rope_dim: int = 0  # no positional rotation (rope_by_kind skips it)
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.head_dim**-0.5
+
+    @property
+    def max_kept(self) -> int:
+        """Blocks a query can attend: the selection's, every block under
+        ``dense_len``, or the forced ones where they alone are more."""
+        forced = self.init_blocks + (self.window - 1) // self.block + 2
+        return max(self.topk, -(-self.dense_len // self.block), forced)
+
+    def param_count(self, d: int) -> int:
+        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+        return d * q + 2 * d * kv + 2 * self.head_dim + d * q + q * d
+
+
+@dataclass(frozen=True)
+class LinearAttn:
+    """Sizes of a lightning (linear-attention) layer (kind
+    ``"lightning"``): ``n_heads`` heads of ``head_dim``, per-head RMSNorm
+    on ``q`` and ``k``, rotate-half rotary positions on all of both, a
+    float32 state ``[n_heads, head_dim, head_dim]`` a slot that decays by
+    ``exp(-slope_a)`` a position, an RMSNorm over the concatenated heads
+    and a sigmoid gate a channel on the output. ``slope_a = 2 ** (-8 (a +
+    1) / n_heads)`` (Lightning Attention's head slopes)."""
+
+    n_heads: int
+    head_dim: int
+    rope_theta: float = 10000.0
+    rope_scaling: tuple | None = None
+
+    @property
+    def rope_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def state_bytes(self) -> int:
+        """One slot's state of one layer, float32."""
+        return self.n_heads * self.head_dim * self.head_dim * 4
+
+    def slopes(self) -> tuple:
+        return tuple(
+            2.0 ** (-8.0 * (a + 1) / self.n_heads)
+            for a in range(self.n_heads)
+        )
+
+    def param_count(self, d: int) -> int:
+        q = self.n_heads * self.head_dim
+        return 3 * d * q + 2 * self.head_dim + d * q + q + q * d
+
+
+# layer kinds whose cache is not a latent row: engine/sala.py serves them
+SALA_KINDS = ("sparse", "lightning")
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for the unified decoder-only core.
 
@@ -222,10 +300,22 @@ class ModelConfig:
     # ``experts_first .. experts_first + experts_held - 1`` (0 = all)
     experts_first: int = 0
     experts_held: int = 0
+    # MiniCPM's scaling keys (1.0 = none): on the embeddings
+    # (``scale_emb``), on what each sublayer adds to the residual stream
+    # (``scale_depth / sqrt(published depth)``), and the divisor of the
+    # final norm's output before the head (``hidden / dim_model_base``)
+    embed_mult: float = 1.0
+    residual_mult: float = 1.0
+    logit_div: float = 1.0
 
     @property
     def patterned(self) -> bool:
         return bool(self.layer_kinds)
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer carries a state that no page chain describes."""
+        return "lightning" in self.layer_kinds
 
     def latent_of(self, kind: str) -> LatentAttn:
         return dict(self.latent)[kind]
@@ -269,7 +359,7 @@ class ModelConfig:
             d["layer_kinds"] = tuple(d["layer_kinds"])
         if "latent" in d:
             d["latent"] = tuple(
-                (k, v if isinstance(v, LatentAttn) else _latent_attn(v))
+                (k, _latent_attn(v) if isinstance(v, dict) else v)
                 for k, v in d["latent"]
             )
         return cls(**d)
@@ -316,11 +406,17 @@ class ModelConfig:
         return self._patterned_count(self.n_held)
 
 
-def _latent_attn(d: dict) -> LatentAttn:
-    # JSON has no tuples: a LatentAttn is hashed with its config
+def _latent_attn(d: dict):
+    """A layer kind's sizes from their JSON form: the class whose fields
+    the keys are. JSON has no tuples: the sizes are hashed with the
+    config."""
     d = dict(d)
     if d.get("rope_scaling") is not None:
         d["rope_scaling"] = tuple(d["rope_scaling"])
+    if "dense_len" in d:
+        return SparseAttn(**d)
+    if "q_rank" not in d:
+        return LinearAttn(**d)
     return LatentAttn(**d)
 
 

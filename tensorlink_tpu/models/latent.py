@@ -257,7 +257,7 @@ def rope_by_kind(cfg: ModelConfig, positions: jax.Array) -> dict:
     ``positions`` [B, T]."""
     return {
         k: rope_tables(positions, la.rope_dim, la.rope_theta, la.rope_scaling)
-        for k, la in cfg.latent
+        for k, la in cfg.latent if la.rope_dim  # 0: no positional rotation
     }
 
 

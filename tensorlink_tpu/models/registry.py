@@ -14,7 +14,7 @@ from typing import Any, Callable
 
 import jax.numpy as jnp
 
-from .base import LatentAttn, ModelConfig
+from .base import LatentAttn, LinearAttn, ModelConfig, SparseAttn
 
 # tlint: disable=TL006(family registry — populated at import, read-only after)
 _FAMILY_BUILDERS: dict[str, Callable[[dict], ModelConfig]] = {}
@@ -201,6 +201,84 @@ def _dots3_note(d: dict) -> ModelConfig:
         moe_norm_topk=bool(d.get("norm_topk_prob", True)),
         moe_scale=float(d.get("routed_scaling_factor", 1.0)),
         **_expert_share(d),
+    )
+
+
+# MiniCPM4's ``sparse_config`` (the family's published values): what a
+# ``minicpm_sala`` config that lacks the block takes
+# tlint: disable=TL006(read-only table: merged into a copy)
+SALA_SPARSE_DEFAULTS = dict(
+    kernel_size=32, kernel_stride=16, block_size=64, init_blocks=1,
+    window_size=2048, topk=64, dense_len=8192,
+)
+# tlint: disable=TL006(read-only table)
+_SALA_MIXERS = {"minicpm4": "sparse", "lightning-attn": "lightning"}
+
+
+@register_family("minicpm_sala")
+def _minicpm_sala(d: dict) -> ModelConfig:
+    """MiniCPM-SALA: block-sparse GQA layers (``minicpm4``: per-head q/k
+    RMSNorm, no rotary positions, a sigmoid gate a channel) and lightning
+    linear-attention layers (``lightning-attn``: per-head q/k RMSNorm,
+    rotary positions, head-wise decay, output norm and gate) in the order
+    ``mixer_types`` gives, every layer with the dense SwiGLU; MiniCPM's
+    scaling keys: ``scale_emb`` on the embeddings, ``scale_depth /
+    sqrt(published depth)`` on what a sublayer adds, ``hidden_size /
+    dim_model_base`` under the head. A cut config keeps the residual
+    scale of the published depth (``published.num_hidden_layers``)."""
+    mixers = list(d["mixer_types"])
+    unknown = sorted(set(mixers) - set(_SALA_MIXERS))
+    if unknown or len(mixers) != d["num_hidden_layers"]:
+        raise ValueError(
+            f"minicpm_sala: mixer_types {unknown or len(mixers)} for "
+            f"{d['num_hidden_layers']} layers (built: {sorted(_SALA_MIXERS)})"
+        )
+    flags = ("qk_norm", "use_output_gate", "use_output_norm",
+             "attn_use_output_gate", "lightning_use_rope")
+    off = [k for k in flags if not d.get(k, True)]
+    if off or d.get("attn_use_rope", False) or d.get("attention_bias"):
+        raise ValueError(
+            f"minicpm_sala: only the published mixers are built (off: {off}, "
+            f"attn_use_rope {d.get('attn_use_rope')}, attention_bias "
+            f"{d.get('attention_bias')})"
+        )
+    sp = {**SALA_SPARSE_DEFAULTS, **(d.get("sparse_config") or {})}
+    hd = d.get("head_dim") or d["hidden_size"] // d["num_attention_heads"]
+    sparse = SparseAttn(
+        n_heads=d["num_attention_heads"],
+        n_kv_heads=d["num_key_value_heads"], head_dim=hd,
+        pool=sp["kernel_size"], stride=sp["kernel_stride"],
+        block=sp["block_size"], init_blocks=sp["init_blocks"],
+        window=sp["window_size"], topk=sp["topk"], dense_len=sp["dense_len"],
+    )
+    if d.get("lightning_nkv", d["lightning_nh"]) != d["lightning_nh"]:
+        raise ValueError("minicpm_sala: lightning_nkv != lightning_nh")
+    lightning = LinearAttn(
+        n_heads=d["lightning_nh"],
+        head_dim=d.get("lightning_head_dim", hd),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+    )
+    depth = (d.get("published") or {}).get(
+        "num_hidden_layers", d["num_hidden_layers"])
+    return ModelConfig(
+        family="minicpm_sala",
+        vocab_size=d["vocab_size"],
+        d_model=d["hidden_size"],
+        n_layers=d["num_hidden_layers"],
+        n_heads=d["num_attention_heads"],
+        n_kv_heads=d["num_key_value_heads"],
+        head_dim=hd,
+        d_ff=d["intermediate_size"],
+        max_seq_len=d.get("max_position_embeddings", 4096),
+        norm_eps=d.get("rms_norm_eps", 1e-6),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        layer_kinds=tuple(_SALA_MIXERS[m] for m in mixers),
+        latent=(("sparse", sparse), ("lightning", lightning)),
+        n_dense_layers=d["num_hidden_layers"],
+        embed_mult=float(d.get("scale_emb", 1.0)),
+        residual_mult=float(d.get("scale_depth", 1.0)) / depth**0.5,
+        logit_div=d["hidden_size"] / d.get("dim_model_base", d["hidden_size"]),
     )
 
 

@@ -64,12 +64,14 @@ def init_params(
     if cfg.patterned:
         # layers of more than one kind have a tree of their own (lead,
         # periods, tail) and one placement: whole, on one device
-        from . import latent
+        from . import latent, sala
 
         if shardings is not None:
             raise NotImplementedError(
                 "a patterned model's weights are not made under shardings"
             )
+        if sala.is_sala(cfg):
+            return sala.init_params(cfg, key, dtype)
         return latent.init_params(cfg, key, dtype)
     dt = dtype or cfg.dtype
     d, f, hd = cfg.d_model, cfg.d_ff, cfg.head_dim
@@ -263,6 +265,8 @@ def _embed_tokens(params: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Arra
     x = params["embed"]["tok"][tokens].astype(cfg.dtype)
     if cfg.embed_scale:  # Gemma normalizer, cast to activation dtype like HF
         x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
+    if cfg.embed_mult != 1.0:  # MiniCPM's scale_emb
+        x = x * jnp.asarray(cfg.embed_mult, cfg.dtype)
     return x
 
 
@@ -969,6 +973,9 @@ def tp_shardable(cfg: ModelConfig, tp: int) -> str | None:
         return "MoE routing is not tensor-shardable on the serving path"
     if cfg.qk_norm_full:
         return "qk_norm_full normalizes over the full projection dim"
+    if cfg.patterned:
+        return ("a model with layers of more than one kind is served whole "
+                "on one chip: its pools and states have no partition specs")
     for name in ("n_heads", "n_kv_heads", "d_ff", "d_model"):
         val = int(getattr(cfg, name))
         if val % tp:

@@ -603,6 +603,7 @@ def _paged_walk_kernel(
     shared_kv: bool = False,
     v_width: int | None = None,
     row_groups: bool = False,
+    per_head: bool = False,
 ):
     """One slot and one block of ``hb`` kv heads of the walk (grid
     ``(slot, head block)``): row blocks of ``cb`` chunk positions up to
@@ -623,7 +624,10 @@ def _paged_walk_kernel(
     key (:func:`_walk_start`). ``shared_kv``: the value of a position is
     its key row (a latent cache: one row serves both sides, the caller
     keeps the value's columns of the output), so ``v_hbm`` is not an
-    operand and a page is copied once."""
+    operand and a page is copied once. ``per_head``: every (slot, head
+    block) has a block-table row, a start and a count of its own (row
+    ``slot · head blocks + head block`` of each): a table of kept blocks
+    a kv head (:func:`block_sparse_attention`)."""
     if shared_kv:
         v_hbm, rest = None, (v_hbm,) + rest
         o_ref, kbuf, sem, m_ref, l_ref, acc_ref = rest
@@ -635,6 +639,8 @@ def _paged_walk_kernel(
         o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = rest
     s = pl.program_id(0)
     hblk = pl.program_id(1)
+    if per_head:  # the walk's row of the tables, not the query's slot
+        s = s * pl.num_programs(1) + hblk
     start = start_ref[s]
     nv = nv_ref[s]
     Hkv, CG, hd = q_ref.shape[1:]  # Hkv: the heads, CG: the rows, here
@@ -828,6 +834,7 @@ def _paged_walk(
     window: int | None = None,
     shared_kv: bool = False,  # values are the key rows: v_pages unused
     latent: tuple | None = None,
+    per_head: bool = False,  # tables, starts, counts: [S · Hkv, ...]
 ) -> jax.Array:
     """The ``pl.pallas_call`` of the walk, named ``name``; returns
     ``[S, Hkv, C·G, hd]``. Block sizes come from the shapes: KV blocks of
@@ -883,6 +890,7 @@ def _paged_walk(
         **({"shared_kv": True} if shared_kv else {}),
         **({"v_width": vw} if latent else {}),
         **({"row_groups": True} if QR < CG else {}),
+        **({"per_head": True} if per_head else {}),
     )
     args = [qg, _lane_pad(k_pages)]
     if not shared_kv:
@@ -891,6 +899,8 @@ def _paged_walk(
         Hkv, QR, cb * G, ppb * page, hd, qg.dtype.itemsize,
         args[1].shape[-1] * args[1].dtype.itemsize,
     )
+    if per_head:
+        hb = 1  # a table a kv head: a grid step walks one head's
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     if QR < CG:
         grid = (S, Hkv // hb, CG // QR)
@@ -1065,7 +1075,73 @@ def paged_attention(
     return out.reshape(S, Hq, out.shape[-1])
 
 
+def block_sparse_attention_ref(
+    q: jax.Array,  # [S, Hq, hd]
+    k_pages: jax.Array,  # [P, Hkv, page, hd]
+    v_pages: jax.Array,
+    tables: jax.Array,  # int32 [S, Hkv, n_v]: pages of the kept blocks
+    lengths: jax.Array,  # int32 [S, Hkv]: positions of the table attended
+    *,
+    scale: float,
+) -> jax.Array:
+    """Pure-jnp :func:`block_sparse_attention`: each (slot, kv head)'s
+    queries over the first ``lengths`` positions of the pages its table
+    names, in table order. A slot with length 0 reads zero."""
+    S, Hq, hd = q.shape
+    _, Hkv, page, _ = k_pages.shape
+    G = Hq // Hkv
+    heads = jnp.arange(Hkv)[None, :, None]
+    k = k_pages[tables, heads].reshape(S, Hkv, -1, hd)  # [S, Hkv, K, hd]
+    v = v_pages[tables, heads].reshape(S, Hkv, -1, hd)
+    sc = jnp.einsum(
+        "sgad,sgkd->sgak", q.reshape(S, Hkv, G, hd).astype(jnp.float32),
+        k.astype(jnp.float32), preferred_element_type=jnp.float32,
+    ) * scale
+    ok = (jnp.arange(k.shape[2]) < lengths[..., None])[:, :, None, :]
+    w = jax.nn.softmax(jnp.where(ok, sc, NEG_INF), axis=-1)
+    w = jnp.where(ok.any(-1, keepdims=True), w, 0.0)
+    out = jnp.einsum("sgak,sgkd->sgad", w, v.astype(jnp.float32))
+    return out.reshape(S, Hq, hd).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "name"))
+def block_sparse_attention(
+    q: jax.Array,  # [S, Hq, hd]: one query position a slot
+    k_pages: jax.Array,  # [L, P, Hkv, page, hd] with ``layer``, or [P, ...]
+    v_pages: jax.Array,
+    tables: jax.Array,  # int32 [S, Hkv, n_v]
+    lengths: jax.Array,  # int32 [S, Hkv]
+    *,
+    scale: float,
+    interpret: bool = False,
+    layer: jax.Array | None = None,
+    name: str = "block_sparse_attention",
+) -> jax.Array:
+    """The page walk over a table of kept blocks a slot and kv head:
+    grid ``(slot, kv head)``, the head's ``Hq / Hkv`` query heads on each
+    block's keys. The live-span walk (:func:`_paged_walk_kernel`) with a
+    block-table row, a start and a count a (slot, kv head): the table
+    names the pages of the blocks a selection kept, in position order,
+    the query sits at the table's last attended position (the walk's
+    causal limit is the table's length), and only the kept blocks' pages
+    are copied. Positions carry no rotation in such a layer, so a key's
+    place in the table is as good as its place in the context. Returns
+    ``[S, Hq, hd]``."""
+    S, Hq, hd = q.shape
+    Hkv = k_pages.shape[-3]
+    lengths = jnp.asarray(lengths, jnp.int32).reshape(S * Hkv)
+    out = _paged_walk(
+        name, q.reshape(S, Hkv, Hq // Hkv, hd), k_pages, v_pages,
+        tables.reshape(S * Hkv, -1), jnp.maximum(lengths - 1, 0),
+        jnp.minimum(lengths, 1), None, None, layer, G=Hq // Hkv,
+        scale=scale, interpret=interpret, per_head=True,
+    )
+    return out.reshape(S, Hq, hd)
+
+
 __all__ = [
+    "block_sparse_attention",
+    "block_sparse_attention_ref",
     "flash_attention",
     "paged_attention",
     "paged_attention_ref",

@@ -140,6 +140,13 @@ class MemoryEstimate:
             )
         params = cfg.held_param_count() * pb
         sizes = dict(cfg.latent)
+        if cfg.recurrent or "sparse" in cfg.layer_kinds:
+            parts = cls.state_parts(cfg, batch, seq_len)
+            kv = sum(parts.values())
+            act = batch * min(seq_len, PREFILL_BLOCK) * (
+                8 * cfg.d_model + 2 * cfg.d_ff) * pb
+            total = int((params + act + kv) * 1.1)
+            return cls(params, 0, 0, int(act), int(kv), total)
         per_position = sum(
             n * (sizes[kind].pool_dim
                  + (sizes[kind].index_dim if sizes[kind].index_heads else 0))
@@ -155,6 +162,33 @@ class MemoryEstimate:
             )
         total = int((params + act + kv) * 1.1)
         return cls(params, 0, 0, int(act), int(kv), total)
+
+
+    @staticmethod
+    def state_parts(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
+        """What the slots of a model of block-sparse GQA and lightning
+        layers hold (engine/sala.py), in bytes: ``pages`` (keys, values and
+        a float32 key sum a page, the SPARSE layers only: a lightning layer
+        caches no position), ``states`` (a float32 state a slot and
+        lightning layer) and ``snapshots`` (the engine's default pool: one
+        place a ``32 x PREFILL_BLOCK`` positions of the context plus two
+        a slot). Not pages x layers."""
+        pb = _dtype_bytes(cfg.dtype)
+        sizes = dict(cfg.latent)
+        n_sparse = cfg.layer_kinds.count("sparse")
+        n_light = cfg.layer_kinds.count("lightning")
+        pages = states = snaps = 0
+        if n_sparse:
+            sa = sizes["sparse"]
+            row = sa.n_kv_heads * sa.head_dim
+            pages = n_sparse * batch * seq_len * (
+                2 * row * pb + row * 4 // sa.stride)
+        if n_light:
+            one = n_light * sizes["lightning"].state_bytes
+            states = batch * one
+            snaps = (seq_len // (32 * PREFILL_BLOCK) + 2 * batch) * one
+        return {"pages": int(pages), "states": int(states),
+                "snapshots": int(snaps)}
 
 
 @dataclass
@@ -533,6 +567,21 @@ def plan_sharding(
                 estimate=est,
                 update_mode=training_update_mode(axes, training),
             )
+
+    if cfg.recurrent:
+        # the slots' states have no stage to follow a layer to: such a
+        # model is served whole on one worker, or not here
+        parts = MemoryEstimate.state_parts(cfg, batch, seq_len)
+        gb = lambda n: f"{n / 1e9:.2f} GB"  # noqa: E731
+        raise AssignmentError(
+            f"{model_name or cfg.family} does not fit one worker: it needs "
+            f"{gb(est.total)} (weights {gb(est.params)}, pages of the paged "
+            f"layers {gb(parts['pages'])}, recurrent states "
+            f"{gb(parts['states'])}, state snapshots {gb(parts['snapshots'])}, "
+            f"activations {gb(est.activations)}, a tenth of headroom) at "
+            f"{batch} x {seq_len} positions; the largest worker has "
+            f"{gb(best.hbm_bytes)}"
+        )
 
     # 2) pipeline split: per-layer cost + embedding/head overheads
     pb = _dtype_bytes(cfg.dtype)

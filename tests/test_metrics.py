@@ -244,6 +244,12 @@ LEGACY_ENGINE_KEYS = (
     "sparse_positions_kept", "sparse_positions_scored",
     "window_pages_walked", "window_pages_context",
     "latent_rows_read", "latent_rows_capacity",
+    # block-sparse GQA and lightning layers (engine/sala.py): the step's
+    # own counts, and what admission did about the slots' states
+    "sparse_blocks_kept", "sparse_blocks_visible", "sparse_rows_dense",
+    "lightning_rows",
+    "state_admissions", "state_snapshots_taken", "state_snapshots_restored",
+    "state_snapshots_skipped", "state_rows_replayed",
     # the sampling epilogue (ROADMAP S1): calls, those that sorted, and
     # the verify walk's length against the rows the program holds
     "sampler_calls", "sampler_calls_sampled",
@@ -446,7 +452,7 @@ def test_a_counter_metric_reads_keys_the_engine_reports(path):
     keys = [spec["key"]] if "key" in spec else spec["num"] + spec["den"]
     counters = {c[0] for c in continuous._ENGINE_COUNTERS}
     gauges = {"latent_pool_bytes", "weights_bytes_device_max",
-              "step_build_ms", "step_build_waited_ms",
+              "step_build_ms", "step_build_waited_ms", "state_pool_bytes",
               "prefix_evictions"}  # serving_snapshot()'s own
     assert keys and set(keys) <= counters | gauges, set(keys) - counters
     if path.name.startswith("narrow_block_share"):
