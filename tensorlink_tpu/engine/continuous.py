@@ -97,8 +97,6 @@ from .paged import (
     PagedKVCache,
     PrefixCache,
     SharedPagePool,
-    bind_slot,
-    clear_slot,
     copy_page,
     gather_page,
     make_tp_ragged_step,
@@ -107,6 +105,7 @@ from .paged import (
     paged_ragged_step,
     pages_needed,
     scatter_page,
+    set_counts_row,
     tp_cache_specs,
     tp_gather_costs,
     unpack_results,
@@ -487,6 +486,13 @@ _ENGINE_COUNTERS = (
     # a chunk"): the packed control buffer in, the packed results out
     ("chunk_host_arrays", "tlink_engine_chunk_host_arrays_total",
      "arrays step_chunk placed on or fetched from the device (2 a chunk)"),
+    # what an admission or a retirement changes on the device rides the
+    # next chunk's control buffer; what cannot ride stays a call
+    ("slot_binds_packed", "tlink_engine_slot_binds_packed_total",
+     "slot binds and clears handed to the next chunk's control buffer"),
+    ("admit_device_calls", "tlink_engine_admit_device_calls_total",
+     "device calls of the admission and retirement path (copy-on-write "
+     "copies, state restores, penalty histograms, promoted pages)"),
 )
 
 
@@ -1139,6 +1145,18 @@ class ContinuousEngine:
                     self._tp_mesh, P(*([None] * self._counts.ndim))
                 ),
             )
+        # What an admission and a retirement change on the device: the
+        # host's copy of the block table and each slot's start length, and
+        # which slots' rows (``_bind``) and histograms (``_reset``) the
+        # next dispatched step program takes from its control buffer
+        # before its ragged pass (``paged.pack_control``'s bind columns).
+        # Driver-thread only
+        self._bt_host = np.zeros(
+            (self.max_slots, self.cache.pages_per_slot), np.int32
+        )
+        self._len0 = np.zeros(self.max_slots, np.int32)
+        self._bind = np.zeros(self.max_slots, bool)
+        self._reset = np.zeros(self.max_slots, bool)
         if pool is not None:
             # nothing fallible may follow: a registered-but-dead tenant
             # is unrecoverable without a worker restart (see above)
@@ -1358,7 +1376,8 @@ class ContinuousEngine:
         split AND the kv_quant storage mode are all DATA or trace-time
         constants to it), compiled once a width of ``block_widths`` (at
         most two: the packed block's shape keys the jit cache), plus the
-        COW ``copy_page``. ``decode_step`` /
+        COW ``copy_page`` (a slot's bind and clear ride the step's
+        control buffer and are no program). ``decode_step`` /
         ``sample_rows`` / ``row_keys`` are traced INSIDE the step
         program — never dispatched from the host loop. (The legacy
         two-program pair ``decode_chunk``/``prefill_chunk`` was retired
@@ -1398,32 +1417,44 @@ class ContinuousEngine:
         the requests' callbacks, in order: the ``first_token`` spans where
         a first token leaves, ``stream_cb`` a token, ``on_finish`` after a
         finished request's last one. Every first token goes first, then
-        the entries that go on in slot order, then the entries that end a
-        request. A first token does not queue behind tokens whose readers
-        already have a stream going. Only the first token itself goes
-        ahead (an answer that ends with its first chunk leaves whole):
-        the tokens that came with it keep their slot's place, because an
-        entry that changes place between two stages moves every reader
-        behind it by an entry and its own reader's next gap by the way
-        back (the longest gap a reader sees; PERF.md section 6, PR 36).
-        What ends a request goes last: its ``on_finish`` is the one
-        callback that blocks for long (on a worker the done-marker's
-        round trip and ``GENERATE_RESP``, 15-20 ms), so there it holds
-        back nobody's tokens; and a client that waits for its answer to
-        send the next request gets it where the engine goes on to its
-        sync. Answered at the START of the stage, that client's next
-        request came back about a narrow chunk later on four chips once
-        such a chunk was 60 ms long, made the next admission or missed
-        it by a millisecond, and eight clients asking equal answers
-        drifted into step, a different way every run; answered at the
-        end it misses that admission every time and meets the one after
-        (PERF.md section 6, PR 40). A request's own tokens keep their
-        order. ``step_chunk`` runs it behind its dispatch (``in_flight``:
-        the device executes the next chunk meanwhile), or at once when
-        no step follows. Anything else that answers for a request calls
-        it first, for that request (``req``) or for all: ``close``,
-        ``begin_drain``, ``freeze_slot`` and every teardown that is not a
-        finish (``_teardown_slot``: preemption, shed, handoff commit).
+        the next token of every other stream (one that ends with this
+        chunk too), then the rest of the entries that go on in slot
+        order, then the rest of the entries that end a request, each
+        with its ``on_finish``. A first token does not queue behind
+        tokens whose readers already have a stream going, and no
+        reader's stall lasts while another reader's whole chunk is
+        handed on ahead of it: handed on entry by entry, the gap that
+        ends with a stream's next token was 4-5 ms longer a slot of slot
+        order (eight ``stream_cb`` calls an entry), 27 to 50 ms behind a
+        wide chunk on four chips and longest of all for the entry that
+        ended a request; eight clients that each keep their slot then
+        read a longest gap by their slot, and the median over requests
+        fell between two slots' values, another way every run (PERF.md
+        section 6, PR 43). Only ONE token of an entry goes ahead (an
+        answer that ends with its first chunk leaves whole): the tokens
+        that came with it keep their slot's place, because a whole entry
+        that changes place between two stages moves every reader behind
+        it by an entry and its own reader's next gap by the way back
+        (the longest gap a reader sees; PERF.md section 6, PR 36).
+        What is left of an entry that ends a request goes last: its
+        ``on_finish`` is the one callback that blocks for long (on a
+        worker the done-marker's round trip and ``GENERATE_RESP``, 15-20
+        ms), so there it holds back nobody's tokens; and a client that
+        waits for its answer to send the next request gets it where the
+        engine goes on to its sync. Answered at the START of the stage,
+        that client's next request came back about a narrow chunk later
+        on four chips once such a chunk was 60 ms long, made the next
+        admission or missed it by a millisecond, and eight clients
+        asking equal answers drifted into step, a different way every
+        run; answered at the end it misses that admission every time and
+        meets the one after (PERF.md section 6, PR 40). A request's own
+        tokens keep their order. ``step_chunk`` runs it behind its
+        dispatch (``in_flight``: the device executes the next chunk
+        meanwhile), or at once when no step follows. Anything else that
+        answers for a request calls it first, for that request (``req``:
+        its entry leaves whole) or for all: ``close``, ``begin_drain``,
+        ``freeze_slot`` and every teardown that is not a finish
+        (``_teardown_slot``: preemption, shed, handoff commit).
         Driver-thread only."""
         pend = self._unstreamed
         if not pend or (req is not None and req.rid not in pend):
@@ -1434,6 +1465,7 @@ class ContinuousEngine:
             with jax.profiler.TraceAnnotation("tlink:stream"):
                 order = (req.rid,) if req is not None else tuple(pend)
                 if req is None:
+                    led = set()
                     for rid in order:  # first tokens
                         entry = pend.get(rid)
                         if entry is None or not entry[2]:
@@ -1444,8 +1476,18 @@ class ContinuousEngine:
                             pend[rid] = (r, k - 1, False, False, step)
                         else:  # one token, or a whole answer: all of it
                             del pend[rid]
+                        led.add(rid)
                         n += self._stream_one(
                             r, k, True, finish, step, in_flight, head=head,
+                        )
+                    for rid in order:  # every other stream's next token
+                        entry = pend.get(rid)
+                        if entry is None or entry[1] < 2 or rid in led:
+                            continue
+                        r, k, _first, finish, step = entry
+                        pend[rid] = (r, k - 1, False, finish, step)
+                        n += self._stream_one(
+                            r, k, False, finish, step, in_flight, head=True,
                         )
                     for rid in order:  # what goes on, in slot order
                         entry = pend.get(rid)
@@ -1473,7 +1515,9 @@ class ContinuousEngine:
         caller keeps the rest pending); returns how many left. A truthy
         ``stream_cb`` return (a confirmed stop) ends the stream there:
         ``req.tokens`` is cut back to what was streamed, so at
-        ``on_finish`` it is exactly the sequence the callback was given."""
+        ``on_finish`` it is exactly the sequence the callback was given.
+        ``finish`` with ``head``: the entry ends its request, which is
+        finished here only if its reader stops at this token."""
         base = len(req.tokens) - n
         sent, cancel = 1 if head else n, False
         try:
@@ -1516,7 +1560,12 @@ class ContinuousEngine:
                 if first and req.trace_id:
                     first_token_stamp.set(None)
             if finish:
-                self._finish(req, finished=True)
+                # an ending entry's token sent ahead ends nothing, unless
+                # its reader stops there: the rest goes with the cut
+                if cancel and head:
+                    self._unstreamed.pop(req.rid, None)
+                if cancel or not head:
+                    self._finish(req, finished=True)
             elif cancel:
                 # what a first token left pending went with the cut
                 self._unstreamed.pop(req.rid, None)
@@ -1675,21 +1724,20 @@ class ContinuousEngine:
             bt_row[n_hit : n_hit + len(pages)] = pages
             if cow is not None:
                 # the divergent page: duplicate the cached page into the
-                # slot's first private page and credit the matched positions
+                # slot's first private page and credit the matched
+                # positions (the call places its own scalars)
                 src, n_match = cow
                 self.cache = copy_page(
-                    self.cache, jnp.int32(src.page), jnp.int32(pages[0])
+                    self.cache, np.int32(src.page), np.int32(pages[0])
                 )
+                self._count("admit_device_calls")
                 hit_len += n_match
                 self.prefix.stats["cow_copies"] += 1
                 self.prefix.release([src])
                 cow_released = True
-            self.cache = bind_slot(
-                self.cache, jnp.int32(slot), jnp.asarray(bt_row),
-                jnp.int32(hit_len),
-            )
             if self._stateful:
                 self._admit_state(req, slot, hit_nodes, hit_len)
+            self._bind_slot(slot, bt_row, hit_len)
         except BaseException:
             # a failed admission must not leak: return the private pages
             # and drop the pinned refs so close()'s conservation check
@@ -1733,12 +1781,13 @@ class ContinuousEngine:
         if hit_nodes:
             idx = hit_nodes[-1].snap
             self.cache = restore_snapshot(
-                self.cache, self._snaps, jnp.int32(slot), jnp.int32(idx))
+                self.cache, self._snaps, np.int32(slot), np.int32(idx))
             req.state_restored_at = hit_len
             self._count("state_snapshots_restored")
         else:
-            self.cache = zero_state(self.cache, jnp.int32(slot))
+            self.cache = zero_state(self.cache, np.int32(slot))
             req.state_restored_at = -1
+        self._count("admit_device_calls")
 
     def _next_stop(self, req: ContinuousRequest) -> int:
         """Where ``req``'s next prefill grant has to end at the latest: the
@@ -1773,7 +1822,7 @@ class ContinuousEngine:
             return
         idx = self._snap_free.pop()
         self._snaps = take_snapshot(
-            self._snaps, self.cache.state, jnp.int32(slot), jnp.int32(idx))
+            self._snaps, self.cache.state, np.int32(slot), np.int32(idx))
         req.snaps[pos] = idx
         self._count("state_snapshots_taken")
 
@@ -1806,7 +1855,7 @@ class ContinuousEngine:
         try:
             if faults.ENABLED:
                 faults.inject("kvtier.demote", "demote:" + node.key_hash)
-            got = gather_page(self.cache, jnp.int32(node.page))
+            got = gather_page(self.cache, np.int32(node.page))
         except faults.FaultInjected:
             return  # destroyed instead — exactly the pre-tier behavior
         blocks: list[tuple] = []
@@ -1822,6 +1871,19 @@ class ContinuousEngine:
             weights_version=node.weights_version,
         )
         self._count("prefix_demotions")
+
+    # tlint: hot-path
+    def _scatter_page(self, pid: int, k, v, k_scale=None,
+                      v_scale=None) -> None:
+        """A promoted, pulled or shipped page's bytes into page ``pid``:
+        ONE call, which places the page id and the payload itself (a host
+        value wrapped in ``jnp`` first is a placement of its own)."""
+        if k_scale is None:
+            self.cache = scatter_page(self.cache, np.int32(pid), k, v)
+        else:
+            self.cache = scatter_page(
+                self.cache, np.int32(pid), k, v, k_scale, v_scale
+            )
 
     # tlint: hot-path
     def _promote_chain(self, seq, limit: int, hit_nodes: list) -> list:
@@ -1858,18 +1920,10 @@ class ContinuousEngine:
                     faults.inject(
                         "kvtier.fetch", "promote:" + entry.key_hash
                     )
-                if entry.k_scale is not None:
-                    self.cache = scatter_page(
-                        self.cache, jnp.int32(pid),
-                        jnp.asarray(entry.k), jnp.asarray(entry.v),
-                        jnp.asarray(entry.k_scale),
-                        jnp.asarray(entry.v_scale),
-                    )
-                else:
-                    self.cache = scatter_page(
-                        self.cache, jnp.int32(pid),
-                        jnp.asarray(entry.k), jnp.asarray(entry.v),
-                    )
+                self._scatter_page(
+                    pid, entry.k, entry.v, entry.k_scale, entry.v_scale
+                )
+                self._count("admit_device_calls")
             except faults.FaultInjected:
                 # failed promotion fails SAFE: the page returns to the
                 # free list and the suffix takes the next rung
@@ -1966,7 +2020,7 @@ class ContinuousEngine:
                 faults.inject("kvtier.fetch", f"export:{len(nodes)}")
             payload: dict[str, list] = {"k": [], "v": [], "ks": [], "vs": []}
             for n in nodes[n_skip:]:
-                got = gather_page(self.cache, jnp.int32(n.page))
+                got = gather_page(self.cache, np.int32(n.page))
                 payload["k"].append(np.asarray(got[0]))
                 payload["v"].append(np.asarray(got[1]))
                 if len(got) == 4:
@@ -2070,18 +2124,12 @@ class ContinuousEngine:
                 self._tier_pinned.append(pid)
                 try:
                     j = i - n_skip  # index into the shipped payload
-                    if self.cache.quantized:
-                        self.cache = scatter_page(
-                            self.cache, jnp.int32(pid),
-                            jnp.asarray(k[j]), jnp.asarray(v[j]),
-                            jnp.asarray(blob["k_scale"][j]),
-                            jnp.asarray(blob["v_scale"][j]),
-                        )
-                    else:
-                        self.cache = scatter_page(
-                            self.cache, jnp.int32(pid),
-                            jnp.asarray(k[j]), jnp.asarray(v[j]),
-                        )
+                    scales = (
+                        (blob["k_scale"][j], blob["v_scale"][j])
+                        if self.cache.quantized else ()
+                    )
+                    self._scatter_page(pid, k[j], v[j], *scales)
+                    self._count("admit_device_calls")
                 except BaseException:
                     # failed staging must not leak mid-pull: the pinned
                     # page returns before the error surfaces, so the
@@ -2168,10 +2216,7 @@ class ContinuousEngine:
         bt_row[:n_skip] = [n.page for n in ticket["nodes"]]
         bt_row[n_skip:n_have] = ticket["pages"]
         bt_row[n_have : n_have + len(grow)] = grow
-        self.cache = bind_slot(
-            self.cache, jnp.int32(slot), jnp.asarray(bt_row),
-            jnp.int32(length),
-        )
+        self._bind_slot(slot, bt_row, length)
         req.slot = slot
         req.pages = list(ticket["pages"]) + grow
         req.shared_nodes = list(ticket["nodes"])
@@ -2240,19 +2285,43 @@ class ContinuousEngine:
         self._set_knob_mirrors(slot, req.sampling)
         if ctx is None:
             ctx = req.prefill_tokens or req.prompt
-        self._counts = self._counts.at[slot].set(self._ctx_counts(req, ctx))
+        hist = self._ctx_counts(req, ctx)
+        # a histogram of zeros rides the next chunk's control buffer as
+        # one flag; a context's own is written now, whole, and stands
+        self._reset[slot] = hist is None
+        if hist is not None:
+            self._counts = set_counts_row(self._counts, np.int32(slot), hist)
+            self._count("admit_device_calls")
 
-    def _ctx_counts(self, req: ContinuousRequest, ctx) -> jax.Array:
+    def _ctx_counts(self, req: ContinuousRequest, ctx) -> np.ndarray | None:
         """Histogram of ``ctx`` when the request's penalties need one
-        (zeros otherwise). An adopted (migrated-in) slot passes the full
-        chain — prompt + every emitted token — which equals the
-        uninterrupted run's integer counts at the same step."""
+        (None otherwise: it starts at zero). An adopted (migrated-in)
+        slot passes the full chain — prompt + every emitted token — which
+        equals the uninterrupted run's integer counts at the same step."""
         if not (self._any(req.sampling.presence_penalty)
                 or self._any(req.sampling.frequency_penalty)):
-            return jnp.zeros((self.cfg.vocab_size,), jnp.int32)
+            return None
         c = np.zeros(self.cfg.vocab_size, np.int32)
         np.add.at(c, np.asarray(ctx, np.int64), 1)
-        return jnp.asarray(c)
+        return c
+
+    def _bind_slot(self, slot: int, bt_row, length: int) -> None:
+        """Point ``slot`` at its pages, ``length`` positions in: on the
+        host's table, for the next dispatched chunk's control buffer to
+        carry (the step program replaces the row and the length before
+        its ragged pass; ``_step_operands``)."""
+        self._bt_host[slot] = bt_row
+        self._len0[slot] = length
+        self._bind[slot] = True
+        self._count("slot_binds_packed")
+
+    def _slot_length(self, slot: int) -> int:
+        """``slot``'s length on the device, as the next step program will
+        find it: what rides the next control buffer, where something
+        does, stands for the device's own."""
+        if self._bind[slot]:
+            return int(self._len0[slot])
+        return int(np.asarray(self.cache.lengths)[slot])
 
     @staticmethod
     def _any(v) -> bool:
@@ -2324,8 +2393,11 @@ class ContinuousEngine:
         self._active[slot] = False
         self._tok[slot] = 0
         self._temp[slot] = 0.0
-        self.cache = clear_slot(self.cache, jnp.int32(slot))
-        self._counts = self._counts.at[slot].set(0)
+        # table row -> scratch page, length and histogram -> 0: the next
+        # dispatched chunk carries it (no step reads a retired slot's row
+        # before that, and a page freed here is written by nobody sooner)
+        self._bind_slot(slot, 0, 0)
+        self._reset[slot] = True
         if req is not None:
             if self.prefix is not None:
                 self._release_pages(req)
@@ -2451,7 +2523,7 @@ class ContinuousEngine:
         decode-written positions are only byte-exact as shipped bytes)."""
         req = self._slots[slot]
         assert req is not None and slot in self._frozen
-        length = int(np.asarray(self.cache.lengths)[slot])
+        length = self._slot_length(slot)
         return req.prompt + req.tokens, min(length, req.prefill_target)
 
     def export_slot(self, slot: int, *, n_skip: int = 0) -> dict:
@@ -2473,7 +2545,7 @@ class ContinuousEngine:
         if req is None or slot not in self._frozen:
             raise ValueError(f"slot {slot} is not frozen for export")
         t_export = time.monotonic()
-        length = int(np.asarray(self.cache.lengths)[slot])
+        length = self._slot_length(slot)
         chain, limit = self.migration_chain(slot)
         n_valid_pages = pages_needed(length, self.page_size)
         n_skip = max(0, min(int(n_skip), limit // self.page_size,
@@ -2482,7 +2554,7 @@ class ContinuousEngine:
         ship = row[n_skip:n_valid_pages]
         payload: dict[str, list] = {"k": [], "v": [], "ks": [], "vs": []}
         for pid in ship:
-            got = gather_page(self.cache, jnp.int32(pid))
+            got = gather_page(self.cache, np.int32(pid))
             payload["k"].append(np.asarray(got[0]))
             payload["v"].append(np.asarray(got[1]))
             if len(got) == 4:
@@ -2924,18 +2996,11 @@ class ContinuousEngine:
             self.prefix.acquire(nodes)
         try:
             for i, pid in enumerate(pages):
-                if self.cache.quantized:
-                    self.cache = scatter_page(
-                        self.cache, jnp.int32(pid),
-                        jnp.asarray(k[i]), jnp.asarray(v[i]),
-                        jnp.asarray(blob["k_scale"][i]),
-                        jnp.asarray(blob["v_scale"][i]),
-                    )
-                else:
-                    self.cache = scatter_page(
-                        self.cache, jnp.int32(pid),
-                        jnp.asarray(k[i]), jnp.asarray(v[i]),
-                    )
+                scales = (
+                    (blob["k_scale"][i], blob["v_scale"][i])
+                    if self.cache.quantized else ()
+                )
+                self._scatter_page(pid, k[i], v[i], *scales)
         except BaseException:
             # a failed staging must not leak: pages back to the free-list,
             # pinned refs dropped, so conservation holds on the error path
@@ -3542,7 +3607,8 @@ class ContinuousEngine:
         ctl = pack_control(
             blk, starts, n_valid, n_spec, emit, self._seeds, self._steps,
             self._temp, self._topk, self._topp, self._pres, self._freq,
-            remaining, eos_arr,
+            remaining, eos_arr, self._bind, self._len0, self._reset,
+            self._bt_host,
         )
         return (self.engine.params, ctl, self.cache, self._counts)
 
@@ -3740,6 +3806,9 @@ class ContinuousEngine:
                             spec_width=self.spec_width,
                             kernel=self.use_kernel,
                         )
+                    # the program took the binds and resets it carried
+                    self._bind[:] = False
+                    self._reset[:] = False
                     # host arrays the call placed on the device(s)
                     placed = sum(isinstance(x, np.ndarray) for x in ops)
                 if self._unbuilt:

@@ -1477,19 +1477,23 @@ def _ragged_pass(
 
 # A chunk crosses the host-device boundary as ONE array each way
 # (docs/SERVING.md "The anatomy of a chunk"). In: ``int32 [S, C +
-# CTL_COLS]``, the packed token block's ``C`` columns, then one column
-# for each of ``CTL_INTS`` (``emit`` as 0 / 1), the ``EOS_WIDTH`` EOS ids
-# and the bits of the four float32 knobs ``CTL_FLOATS``. Out: ``int32 [S,
-# n_steps + spec_width - 1 + 3 (+ the step's own counts)]``, see
+# CTL_COLS + n_pp]``, the packed token block's ``C`` columns, then one
+# column for each of ``CTL_INTS`` (``emit``, ``bind`` and ``reset`` as 0 /
+# 1), the ``EOS_WIDTH`` EOS ids, the bits of the four float32 knobs
+# ``CTL_FLOATS`` and last the ``n_pp`` columns of the table rows that
+# ``bind`` marks (``pages_per_slot`` of the cache the program is called
+# with; none where a caller packs no rows). Out: ``int32 [S, n_steps +
+# spec_width - 1 + 3 (+ the step's own counts)]``, see
 # :func:`pack_results`. :class:`Control` names the rows in the order both
 # packers take them.
 CTL_INTS = (
     "starts", "n_valid", "n_spec", "emit", "seeds", "steps", "top_k",
-    "remaining",
+    "remaining", "bind", "bind_len", "reset",
 )
 CTL_FLOATS = ("temp", "top_p", "pres", "freq")
 EOS_WIDTH = 8  # EOS ids a slot carries INTO the program (pad with -1)
 CTL_COLS = len(CTL_INTS) + EOS_WIDTH + len(CTL_FLOATS)
+_CTL_FLAGS = ("emit", "bind", "reset")  # bool in the program, 0 / 1 packed
 
 
 class Control(NamedTuple):
@@ -1509,21 +1513,38 @@ class Control(NamedTuple):
     freq: Any  # f32 [S]
     remaining: Any  # int32 [S]: tokens still wanted per slot
     eos: Any  # int32 [S, <= EOS_WIDTH]: per-slot EOS ids (pad with -1)
+    # what admissions and retirements since the last chunk changed, applied
+    # by the program before its ragged pass (left out: nothing changed)
+    bind: Any = None  # bool [S]: the slot takes bind_rows[s] and bind_len[s]
+    bind_len: Any = None  # int32 [S]: the length a bound slot starts at
+    reset: Any = None  # bool [S]: the slot's histogram starts at zero
+    bind_rows: Any = None  # int32 [S, n_pp]: table rows (0 = scratch page)
 
 
 # tlint: hot-path
 def pack_control(blk, starts, n_valid, n_spec, emit, seeds, steps, temp,
-                 top_k, top_p, pres, freq, remaining, eos) -> np.ndarray:
+                 top_k, top_p, pres, freq, remaining, eos, bind=None,
+                 bind_len=None, reset=None, bind_rows=None, *,
+                 pages_per_slot: int = 0) -> np.ndarray:
     """The step program's one control operand from a chunk's rows
     (:class:`Control`'s fields, in order), on the host: the float knobs
     ride as their float32 bits, so every value comes out of
-    :func:`unpack_control` as it went in."""
-    rows = Control(blk, starts, n_valid, n_spec, emit, seeds, steps, temp,
-                   top_k, top_p, pres, freq, remaining, eos)
-    (S, C), n_eos = np.shape(blk), np.shape(eos)[1]
+    :func:`unpack_control` as it went in. A caller with no slot to bind
+    leaves the last four out and names the table's width
+    (``pages_per_slot``: the program splits the buffer by its cache's)."""
+    S, C = np.shape(blk)
+    n_pp = int(pages_per_slot) if bind_rows is None else np.shape(bind_rows)[1]
+    off = np.zeros(S, np.int32)
+    rows = Control(
+        blk, starts, n_valid, n_spec, emit, seeds, steps, temp, top_k,
+        top_p, pres, freq, remaining, eos,
+        off if bind is None else bind, off if bind_len is None else bind_len,
+        off if reset is None else reset, bind_rows,
+    )
+    n_eos = np.shape(eos)[1]
     if n_eos > EOS_WIDTH:
         raise ValueError(f"{n_eos} EOS ids a slot, over {EOS_WIDTH}")
-    ctl = np.empty((S, C + CTL_COLS), np.int32)
+    ctl = np.empty((S, C + CTL_COLS + n_pp), np.int32)
     ctl[:, :C] = blk
     for i, name in enumerate(CTL_INTS):
         ctl[:, C + i] = getattr(rows, name)
@@ -1533,17 +1554,20 @@ def pack_control(blk, starts, n_valid, n_spec, emit, seeds, steps, temp,
     knobs = np.empty((S, len(CTL_FLOATS)), np.float32)
     for i, name in enumerate(CTL_FLOATS):
         knobs[:, i] = getattr(rows, name)
-    ctl[:, col + EOS_WIDTH :] = knobs.view(np.int32)
+    ctl[:, col + EOS_WIDTH : C + CTL_COLS] = knobs.view(np.int32)
+    ctl[:, C + CTL_COLS :] = 0 if bind_rows is None else bind_rows
     return ctl
 
 
 # tlint: hot-path
-def unpack_control(ctl: jax.Array) -> Control:
+def unpack_control(ctl: jax.Array, pages_per_slot: int = 0) -> Control:
     """:func:`pack_control`'s inverse inside the program: slices and four
-    bitcasts, no loop."""
-    C = ctl.shape[1] - CTL_COLS
+    bitcasts, no loop. ``pages_per_slot``: the width of the table rows at
+    the buffer's end."""
+    C = ctl.shape[1] - CTL_COLS - int(pages_per_slot)
     ints = {n: ctl[:, C + i] for i, n in enumerate(CTL_INTS)}
-    ints["emit"] = ints["emit"] != 0
+    for n in _CTL_FLAGS:
+        ints[n] = ints[n] != 0
     col = C + len(CTL_INTS)
     floats = {
         n: jax.lax.bitcast_convert_type(
@@ -1552,7 +1576,8 @@ def unpack_control(ctl: jax.Array) -> Control:
         for i, n in enumerate(CTL_FLOATS)
     }
     return Control(
-        blk=ctl[:, :C], eos=ctl[:, col : col + EOS_WIDTH], **ints, **floats
+        blk=ctl[:, :C], eos=ctl[:, col : col + EOS_WIDTH],
+        bind_rows=ctl[:, C + CTL_COLS :], **ints, **floats
     )
 
 
@@ -1595,9 +1620,20 @@ def _ragged_step_impl(
     histograms) is replicated — so the sampling epilogue sees gathered
     full-width logits and draws the SAME token on every shard."""
     (blk, starts, n_valid, n_spec, emit, seeds, steps, temp, top_k, top_p,
-     pres, freq, remaining, eos) = unpack_control(ctl)
+     pres, freq, remaining, eos, bind, bind_len, reset,
+     bind_rows) = unpack_control(ctl, cache.pages_per_slot)
     S = blk.shape[0]
     W = int(spec_width)
+    # admissions and retirements since the last chunk, first: a bound
+    # slot's table row and start length, a retired slot's row to the
+    # scratch page and its length to 0, a histogram that starts at zero
+    # (the host holds all three; engine/continuous.py ``_bind_slot``)
+    cache = replace(
+        cache,
+        block_tables=jnp.where(bind[:, None], bind_rows, cache.block_tables),
+        lengths=jnp.where(bind, bind_len, cache.lengths),
+    )
+    counts = jnp.where(reset[:, None], 0, counts)
     # the program's three phases carry names of their own (STEP_PHASES):
     # each is one top-level loop, in this order, which is how a profiler
     # trace whose events keep no scope still tells them apart
@@ -1659,7 +1695,7 @@ def _ragged_step_impl(
 )
 def paged_ragged_step(
     params,
-    ctl: jax.Array,  # int32 [S, C + CTL_COLS]: pack_control's buffer
+    ctl: jax.Array,  # int32 [S, C + CTL_COLS + n_pp]: pack_control's
     cache: PagedKVCache,
     counts: jax.Array,  # int32 [S, V] context histograms (penalties)
     cfg: ModelConfig,
@@ -1713,8 +1749,12 @@ def paged_ragged_step(
     pass overwrites the garbage before any mask can reach it.
 
     The chunk's control rows arrive as ONE operand, ``ctl``
-    (:func:`pack_control` on the host, :func:`unpack_control` here), and
-    what the host reads of a chunk leaves as ONE result: returns ``(out,
+    (:func:`pack_control` on the host, :func:`unpack_control` here). With
+    them ride the slots bound and cleared since the last chunk: where
+    ``bind`` is set the slot's table row and length are replaced before
+    the ragged pass, where ``reset`` is set its histogram starts at zero
+    (an admission and a retirement call no program of their own). What
+    the host reads of a chunk leaves as ONE result: returns ``(out,
     cache, counts)`` with ``out`` = :func:`pack_results` of ``tokens [S,
     n_steps + spec_width - 1]``, ``n_tok [S]``, ``spec_m [S]`` and
     ``n_exec`` (:func:`unpack_results` on the host's copy): per-slot
@@ -2060,32 +2100,16 @@ def scatter_page(
 
 
 # tlint: hot-path  # tlint: one-program
-@partial(jax.jit, donate_argnames=("cache",))
-def bind_slot(
-    cache: PagedKVCache, slot: jax.Array, bt_row: jax.Array, length: jax.Array
-) -> PagedKVCache:
-    """Point a slot at its allocated pages (admission)."""
-    return replace(
-        cache,
-        block_tables=cache.block_tables.at[slot].set(bt_row),
-        lengths=cache.lengths.at[slot].set(length),
-    )
-
-
-# tlint: hot-path  # tlint: one-program
-@partial(jax.jit, donate_argnames=("cache",))
-def clear_slot(cache: PagedKVCache, slot: jax.Array) -> PagedKVCache:
-    """Detach an evicted slot: zero its table row (→ scratch page) and its
-    length, so the fixed-shape step treats it as free. The pages
-    themselves go back to the host free-list — their stale contents are
-    unreachable once no table row names them."""
-    return replace(
-        cache,
-        block_tables=cache.block_tables.at[slot].set(
-            jnp.zeros((cache.pages_per_slot,), jnp.int32)
-        ),
-        lengths=cache.lengths.at[slot].set(0),
-    )
+@partial(jax.jit, donate_argnames=("counts",))
+def set_counts_row(
+    counts: jax.Array, slot: jax.Array, row: jax.Array
+) -> jax.Array:
+    """A slot's context histogram, whole: what a request with a presence
+    or frequency penalty brings at admission. A slot without one starts
+    at zero through the step's own ``reset`` column (:class:`Control`),
+    as a slot's table row and length ride ``bind``: nothing else binds or
+    clears a slot on the device."""
+    return counts.at[slot].set(row)
 
 
 def pages_needed(total_len: int, page_size: int) -> int:
@@ -2114,7 +2138,6 @@ __all__ = [
     "copy_page",
     "gather_page",
     "scatter_page",
-    "bind_slot",
-    "clear_slot",
+    "set_counts_row",
     "pages_needed",
 ]

@@ -12,6 +12,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @jax.tree_util.register_dataclass
@@ -38,21 +39,26 @@ class SamplingParams:
 
     def __post_init__(self):
         if self.presence_penalty is None:
-            object.__setattr__(self, "presence_penalty", jnp.float32(0.0))
+            object.__setattr__(self, "presence_penalty", np.float32(0.0))
         if self.frequency_penalty is None:
-            object.__setattr__(self, "frequency_penalty", jnp.float32(0.0))
+            object.__setattr__(self, "frequency_penalty", np.float32(0.0))
 
     @classmethod
     def make(
         cls, temperature=0.0, top_k=0, top_p=1.0,
         presence_penalty=0.0, frequency_penalty=0.0,
     ) -> "SamplingParams":
+        """One request's knobs as HOST scalars of the leaves' dtypes: a
+        jitted call places them like any array (same program), and the
+        slot engine, which reads them back at admission, reads host
+        memory (as ``jnp`` scalars each was a placement here and a fetch
+        there, with the device idle: PERF.md section 5, PR 43)."""
         return cls(
-            temperature=jnp.float32(temperature),
-            top_k=jnp.int32(top_k),
-            top_p=jnp.float32(top_p),
-            presence_penalty=jnp.float32(presence_penalty),
-            frequency_penalty=jnp.float32(frequency_penalty),
+            temperature=np.float32(temperature),
+            top_k=np.int32(top_k),
+            top_p=np.float32(top_p),
+            presence_penalty=np.float32(presence_penalty),
+            frequency_penalty=np.float32(frequency_penalty),
         )
 
     def pad_rows(self, batch: int) -> "SamplingParams":

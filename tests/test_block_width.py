@@ -536,15 +536,106 @@ def test_first_tokens_leave_first_and_ending_entries_last(tiny):
     del log[:]
     ce.flush_stream()
     assert end.finished and not ce._unstreamed
-    # the first token alone, what goes on in slot order, what ends last
+    # the first token alone, every other stream's next token, what goes
+    # on in slot order, what is left of an ending entry and its end last
     assert [name for name, _ in log] == (
-        ["new"] + ["mid"] * 4 + ["new"] * 3 + ["end"] * 3)
+        ["new", "mid", "end"] + ["mid"] * 3 + ["new"] * 3 + ["end"] * 2)
     # a request's own tokens in their order, the end behind its last
-    assert log[-3:] == [("end", end.tokens[4]), ("end", end.tokens[5]),
-                        ("end", "end")]
+    assert log[2] == ("end", end.tokens[4])
+    assert log[-2:] == [("end", end.tokens[5]), ("end", "end")]
     assert [t for name, t in log if name == "new"] == new.tokens
     assert [t for name, t in log if name == "mid"] == mid.tokens[4:]
     ce.run_until_idle()
+    ce.close()
+
+
+def test_every_stream_s_next_token_leaves_before_anyone_s_second(tiny):
+    """Behind the first tokens every stream that goes on hands on ONE
+    token, in slot order, and only then the rest of its chunk: no reader's
+    stall lasts while another reader's whole chunk goes out ahead of it.
+    What is left of an entry that ends a request leaves last, with its
+    end; a stop on a token sent ahead takes the rest of its entry
+    along."""
+    ce = _cont(tiny, spec_decode=False)
+    log: list = []
+    stop_at: dict = {}
+
+    def taps(name):
+        def cb(tok):
+            log.append((name, tok))
+            return stop_at.get(name) == sum(1 for n, _ in log if n == name)
+        return dict(stream_cb=cb,
+                    on_finish=lambda req: log.append((name, "end")))
+
+    a = ce.submit([1, 2, 3], max_new_tokens=40, **taps("a"))
+    b = ce.submit([4, 5, 6], max_new_tokens=40, seed=1, **taps("b"))
+    end = ce.submit([7, 8, 9], max_new_tokens=6, seed=2, **taps("end"))
+    ce.step_chunk()  # four tokens each (chunk_steps)
+    new = ce.submit([2, 4, 6], max_new_tokens=40, seed=3, **taps("new"))
+    ce.step_chunk()  # `end` ends, `new` gets its first token, a and b go on
+    assert list(ce._unstreamed) == [a.rid, b.rid, end.rid, new.rid]
+    del log[:]
+    ce.flush_stream()
+    assert [name for name, _ in log] == (
+        ["new"] + ["a", "b", "end"] + ["a"] * 3 + ["b"] * 3 + ["new"] * 3
+        + ["end"] * 2)
+    assert log[-1] == ("end", "end") and end.finished
+    for name, req, since in (("a", a, 4), ("b", b, 4), ("new", new, 0)):
+        assert [t for n, t in log if n == name] == req.tokens[since:]
+    # a reader that stops at the token sent ahead loses the rest of the
+    # chunk with it, and nobody else's
+    ce.step_chunk()
+    del log[:]
+    stop_at["a"] = 1
+    ce.flush_stream()
+    assert a.cancelled and log[:2] == [("a", a.tokens[-1]), ("a", "end")]
+    assert [n for n, _ in log].count("a") == 2
+    assert a.rid not in ce._unstreamed
+    assert [t for n, t in log if n == "b"] == b.tokens[-4:]
+    ce.run_until_idle()
+    assert b.finished and new.finished and len(b.tokens) == 40
+    ce.check_page_conservation()
+    ce.close()
+
+
+@pytest.mark.parametrize("how", ["stops", "raises"])
+def test_an_ending_entry_s_token_sent_ahead_can_end_it_once(tiny, how):
+    """The entry that ends a request hands its next token on with the
+    others' and the rest last. A reader that stops (or raises) at that
+    token is answered once, there; the rest of the entry goes with the
+    cut, and the slot, which another request holds by then, is left
+    alone."""
+    ce = _cont(tiny, spec_decode=False)
+    got, ended = [], []
+
+    def cb(tok):
+        got.append(tok)
+        if len(got) == 5:  # the second chunk's token sent ahead
+            if how == "raises":
+                raise RuntimeError("reader gone")
+            return True
+
+    other = ce.submit([1, 2, 3], max_new_tokens=12)
+    req = ce.submit([4, 5, 6], max_new_tokens=7, seed=1, stream_cb=cb,
+                    on_finish=lambda r: ended.append(r.rid))
+    ce.step_chunk()
+    ce.step_chunk()  # req's last three tokens are settled, its slot freed
+    assert len(got) == 4 and ce._unstreamed[req.rid][1:4] == (3, False, True)
+    nxt = ce.submit([7, 8, 9], max_new_tokens=12, seed=2)
+    if how == "raises":
+        with pytest.raises(RuntimeError, match="reader gone"):
+            ce.step_chunk(admit_only=True)
+        assert not req.finished and isinstance(req.error, RuntimeError)
+    else:
+        ce.step_chunk(admit_only=True)  # admits nxt, then streams
+        assert req.finished and req.tokens == got
+    assert nxt.slot == 1 and ce._slots[1] is nxt  # req's old slot
+    assert ended == [req.rid] and req.done.is_set() and len(got) == 5
+    assert req.rid not in ce._unstreamed
+    ce.run_until_idle()
+    assert other.finished and nxt.finished and len(nxt.tokens) == 12
+    assert ended == [req.rid]
+    ce.check_page_conservation()
     ce.close()
 
 

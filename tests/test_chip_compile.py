@@ -219,7 +219,9 @@ def _packed_operands(cfg, params, cache, place, place_cache, width: int = 128):
         return place(jax.ShapeDtypeStruct(shape, jnp.int32))
 
     return (
-        place(params), ctl(slots, width + CTL_COLS), place_cache(cache),
+        place(params),
+        ctl(slots, width + CTL_COLS + cache.block_tables.shape[1]),
+        place_cache(cache),
         ctl(slots, cfg.vocab_size),
     )
 
@@ -300,7 +302,7 @@ def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp, width):
         compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
     else:
         compiled, ops = _tp4_step_compiled(v5e_chips, cfg, width)
-    assert ops[1].shape == (S, width + CTL_COLS)
+    assert ops[1].shape == (S, width + CTL_COLS + N_PP)
     _assert_pools_stay_put(compiled, ops[2], ops[0], tp)
 
 

@@ -328,7 +328,21 @@ def test_eviction_returns_pages_and_isolates_slots(tiny_engine):
         seen_tables.append(len(pages))
     assert all(r.finished for r in reqs)
     assert ce.alloc.n_free == free0  # every page came back
-    assert np.asarray(ce.cache.lengths).sum() == 0  # all slots cleared
+    # all slots cleared: on the host's table at once, and a row the device
+    # still holds is marked to go with the next chunk's control buffer
+    assert ce._bt_host.sum() == 0 and ce._len0.sum() == 0
+    stale = np.asarray(ce.cache.lengths) > 0
+    assert stale.any() and ce._bind[stale].all() and ce._reset[stale].all()
+    # ... which the next dispatched chunk's program applies before its pass
+    ce.submit([9, 8], max_new_tokens=40, seed=9)
+    ce.step_chunk()
+    (live,) = [s for s in range(ce.max_slots) if ce._slots[s] is not None]
+    lengths = np.asarray(ce.cache.lengths)
+    bt = np.asarray(ce.cache.block_tables)
+    assert lengths[live] > 0 and lengths.sum() == lengths[live]
+    assert bt[live].any() and bt.sum() == bt[live].sum()
+    assert not ce._bind.any() and not ce._reset.any()
+    ce.run_until_idle()
 
 
 def test_admission_queues_when_slots_exhausted(tiny_engine):

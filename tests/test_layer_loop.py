@@ -70,6 +70,7 @@ def _inputs(cfg, kv_quant: str, spec: bool):
         f32([0, 0.8, 0, 0]), i32([0, 5, 0, 0]), f32([1, 0.9, 1, 1]),
         f32([0, 0.1, 0, 0]), f32([0, 0.1, 0, 0]),
         i32([9, 9, 9, 9]), np.full((S, 2), -1, np.int32),
+        pages_per_slot=N_PP,
     )
     return ctl, cache, jnp.zeros((S, cfg.vocab_size), jnp.int32)
 

@@ -263,6 +263,9 @@ LEGACY_ENGINE_KEYS = (
     "chunk_us_stream", "stream_tokens_overlapped", "stream_tokens_flushed",
     # the host-device boundary: arrays a chunk placed and fetched (2)
     "chunk_host_arrays",
+    # slot binds and clears that rode a chunk's control buffer / device
+    # calls the admission and retirement path still made
+    "slot_binds_packed", "admit_device_calls",
 )
 PHASES = ("between", "admit", "pack", "dispatch", "wait", "drain",
           "deliver", "post")

@@ -684,8 +684,7 @@ def test_ragged_packing_framing_is_bitwise_invariant():
     packing function hand out any grant schedule (fair-share, budget-
     capped, full-chunk) without moving a bit of any stream."""
     from tensorlink_tpu.engine.paged import (
-        PagedKVCache, bind_slot, pack_control, paged_ragged_step,
-        unpack_results,
+        PagedKVCache, pack_control, paged_ragged_step, unpack_results,
     )
     from tensorlink_tpu.models import ModelConfig, init_params
 
@@ -705,12 +704,10 @@ def test_ragged_packing_framing_is_bitwise_invariant():
 
     def run(schedule):
         cache = PagedKVCache.init(cfg, S, page_size=page, max_len=64)
-        cache = bind_slot(
-            cache, jnp.int32(0), jnp.asarray(bt0), jnp.int32(0)
-        )
-        cache = bind_slot(
-            cache, jnp.int32(1), jnp.asarray(bt1), jnp.int32(0)
-        )
+        # both slots are bound by the first block's control buffer, as
+        # the engine binds an admitted slot (``Control.bind``)
+        rows = np.zeros((S, 8), np.int32)
+        rows[0], rows[1] = bt0, bt1
         zeros_i = np.zeros(S, np.int32)
         zeros_f = np.zeros(S, np.float32)
         counts = jnp.zeros((S, cfg.vocab_size), jnp.int32)
@@ -735,6 +732,8 @@ def test_ragged_packing_framing_is_bitwise_invariant():
                 blk, starts, nv, zeros_i, emit, zeros_i, zeros_i, zeros_f,
                 zeros_i, np.ones(S, np.float32), zeros_f, zeros_f,
                 np.ones(S, np.int32), eos,
+                np.arange(S) < (2 if step_i == 0 else 0), zeros_i,
+                np.zeros(S, bool), rows,
             )
             out, cache, counts = paged_ragged_step(
                 params, ctl, cache, counts, cfg, 1, 1, False,

@@ -638,11 +638,12 @@ def test_scopes_and_kernel_names_are_what_the_metrics_match(tiny):
 
 # -- (i) the other families' programs ----------------------------------------
 
-# sha256 (first 16 hex) of ``ContinuousEngine.lower_step(width).as_text()``
-# at the parent commit ba0bac1, tiny float32 presets with zero weights, on
-# the CPU: this PR leaves the dense GQA step and both latent steps as they
-# were. A PR that changes one of these programs on purpose puts the new
-# digest here.
+# sha256 (first 16 hex) of ``ContinuousEngine.lower_step(width).as_text()``,
+# tiny float32 presets with zero weights, on the CPU: PR 42 left the dense
+# GQA step and both latent steps as they were at ba0bac1. A PR that
+# changes one of these programs on purpose puts the new digest here: PR 43
+# did (every step program binds and resets slots from its control buffer
+# before the ragged pass).
 # tlint: disable=TL006(read-only table)
 _QWEN = dict(model_type="qwen3", vocab_size=258, hidden_size=64,
              num_hidden_layers=3, num_attention_heads=4,
@@ -650,8 +651,8 @@ _QWEN = dict(model_type="qwen3", vocab_size=258, hidden_size=64,
              max_position_embeddings=64, rms_norm_eps=1e-6)
 # tlint: disable=TL006(read-only table)
 PARENT_PROGRAMS = {
-    ("qwen3", 4): "6f9749c319a06bd4", ("qwen3", 8): "77a39815d381df9b",
-    ("dots3", 8): "7311c2456e07689d", ("deepseek_v2", 8): "6215893334399c97",
+    ("qwen3", 4): "b48e70bef57f47e8", ("qwen3", 8): "0ddc9d9103af0c85",
+    ("dots3", 8): "fa34db53568c4f63", ("deepseek_v2", 8): "85c5626002e7ab0c",
 }
 
 

@@ -35,11 +35,13 @@ SLOTS, PAGE, CHUNK, MAX_LEN = 4, 8, 32, 96
 # ---------------------------------------------------------------------------
 # (a) the packers are inverses
 # ---------------------------------------------------------------------------
-def _random_rows(rng, S: int, C: int, eos_width: int = EOS_WIDTH) -> Control:
+def _random_rows(rng, S: int, C: int, eos_width: int = EOS_WIDTH,
+                 n_pp: int = 0) -> Control:
     """Every field at values that would show a wrong column or a lossy
     float: random float32 BITS (NaNs left out: they compare unequal to
     themselves, not to their bits), negative seeds, mixed ``emit``, an
-    EOS row of -1s."""
+    EOS row of -1s, table rows ``n_pp`` pages wide under ``bind`` and
+    ``reset`` flags that differ from ``emit`` and from each other."""
     bits = rng.integers(-2**31, 2**31, (4, S), dtype=np.int64).astype(np.int32)
     floats = bits.view(np.float32)
     floats = np.where(np.isnan(floats), np.float32(-0.0), floats)
@@ -53,6 +55,9 @@ def _random_rows(rng, S: int, C: int, eos_width: int = EOS_WIDTH) -> Control:
         steps=i32(0, 2**20), temp=floats[0], top_k=i32(0, 200),
         top_p=floats[1], pres=floats[2], freq=floats[3],
         remaining=i32(-3, 4096), eos=eos,
+        bind=np.arange(S) % 3 == 0, bind_len=i32(0, 2**20),
+        reset=np.arange(S) % 3 == 1,
+        bind_rows=rng.integers(0, 2**31 - 1, (S, n_pp)).astype(np.int32),
     )
 
 
@@ -62,13 +67,15 @@ def _bits(x) -> np.ndarray:
 
 
 @pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
-@pytest.mark.parametrize("S,C", [(8, 16), (8, 128), (16, 128), (3, 5)])
-def test_unpack_is_the_inverse_of_pack_for_every_field(S, C, jitted):
-    rows = _random_rows(np.random.default_rng(S * 1000 + C), S, C)
+@pytest.mark.parametrize("S,C,n_pp", [
+    (8, 16, 256), (8, 128, 256), (16, 128, 1024), (3, 5, 0), (3, 5, 7)])
+def test_unpack_is_the_inverse_of_pack_for_every_field(S, C, n_pp, jitted):
+    rows = _random_rows(np.random.default_rng(S * 1000 + C), S, C, n_pp=n_pp)
     ctl = pack_control(*rows)
-    assert ctl.dtype == np.int32 and ctl.shape == (S, C + CTL_COLS)
-    unpack = jax.jit(unpack_control) if jitted else unpack_control
-    got = unpack(jnp.asarray(ctl))
+    assert ctl.dtype == np.int32 and ctl.shape == (S, C + CTL_COLS + n_pp)
+    unpack = (jax.jit(unpack_control, static_argnums=1) if jitted
+              else unpack_control)
+    got = unpack(jnp.asarray(ctl), n_pp)
     for name, want, have in zip(Control._fields, rows, got):
         assert have.dtype == want.dtype, name
         np.testing.assert_array_equal(_bits(have), _bits(want), err_msg=name)
@@ -206,14 +213,15 @@ def test_packed_program_returns_what_the_phases_return(tiny, mode):
         static_argnames=("cfg", "n_steps", "spec_width"),
     )(params, *(jnp.asarray(x) for x in rows[:1]), cache,
       *(jnp.asarray(x) for x in rows[1:12]), jnp.asarray(counts),
-      *(jnp.asarray(x) for x in rows[12:]), cfg=cfg, n_steps=n_steps,
+      *(jnp.asarray(x) for x in rows[12:14]), cfg=cfg, n_steps=n_steps,
       spec_width=W)
     tokens, n_tok, spec_m, n_exec, cache_w, _d, _s, counts_w, _r = want
     # on operands of its own: the program donates cache and counts
     rows, cache, counts = _block(cfg, mode)
     out, cache_g, counts_g = paged.paged_ragged_step(
-        params, pack_control(*rows), cache, jnp.asarray(counts), cfg,
-        n_steps, W, False)
+        params,
+        pack_control(*rows[:14], pages_per_slot=cache.pages_per_slot),
+        cache, jnp.asarray(counts), cfg, n_steps, W, False)
     got = unpack_results(np.asarray(out), n_steps, W)
     for have, expect in zip(got[:4], (tokens, n_tok, spec_m, n_exec)):
         np.testing.assert_array_equal(have, np.asarray(expect))
@@ -249,7 +257,7 @@ def test_a_patterned_model_s_counts_ride_the_result():
     ctl = pack_control(
         blk, z, np.asarray([C, 3, 0], np.int32), z,
         np.asarray([True, False, False]), z, z, zf, z, zf + 1, zf, zf,
-        z + 5, np.full((S, 1), -1, np.int32))
+        z + 5, np.full((S, 1), -1, np.int32), pages_per_slot=n_pp)
     out, cache, _counts = paged.paged_ragged_step(
         params, ctl, cache, jnp.zeros((S, cfg.vocab_size), jnp.int32), cfg,
         n_steps, 1, False)
@@ -355,8 +363,9 @@ def test_a_chunk_places_one_array_and_fetches_one(monkeypatch, kind, tp):
     ops = ce._step_operands(*ce._pack_ragged()[:7])
     assert len(ops) == 4 and ops[3] is ce._counts
     assert isinstance(ops[1], np.ndarray) and ops[1].dtype == np.int32
-    assert ops[1].shape == (
-        ce.max_slots, ce.block_widths[0] + CTL_COLS)  # pure decode: narrow
+    assert ops[1].shape == (  # pure decode: narrow; the table rides too
+        ce.max_slots,
+        ce.block_widths[0] + CTL_COLS + ce.cache.pages_per_slot)
     # the steady state, watched: nothing is placed by hand, the call takes
     # the one host buffer, and one device array is read back
     fetches, places = _watch(monkeypatch)

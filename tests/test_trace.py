@@ -960,6 +960,12 @@ def test_a_request_behind_a_running_chunk_waits_for_it_and_names_it(
     (rt,) = [r for r in worker.executor.jobs.values() if r.cont is not None]
     cont = rt.cont
     pack = cont._pack_ragged
+    # the cluster's warm request is answered before its step_chunk
+    # returns, and where this test is the cluster's first the loop then
+    # joins the narrow program's build (seconds on a loaded CPU): both
+    # requests below would wait behind that and not behind a chunk. One
+    # more request is taken only once the join is through
+    _stream(path_cluster.api, mint_trace_id(), "past the build")
 
     def slow_pack():
         time.sleep(0.25)  # inside the chunk, in its pack phase
