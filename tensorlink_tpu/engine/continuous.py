@@ -110,6 +110,7 @@ from .paged import (
     tp_gather_costs,
     unpack_results,
 )
+from .latent import restore_window, take_window, window_snapshot_pool
 from .sala import restore_snapshot, take_snapshot, zero_state
 from .sampling import SamplingParams, penalized, sample
 from .spec import SpecController
@@ -452,6 +453,19 @@ _ENGINE_COUNTERS = (
      "snapshot points passed with no place free in the snapshot pool"),
     ("state_rows_replayed", "tlink_engine_state_rows_replayed_total",
      "cached positions prefilled again between a snapshot and the match"),
+    # ... and the same of a model whose window layers hold a ring a slot
+    ("window_admissions", "tlink_engine_window_admissions_total",
+     "admissions of a model with window layers held as rings"),
+    ("window_snapshots_taken", "tlink_engine_window_snapshots_taken_total",
+     "window snapshots taken where a prefill chunk ended"),
+    ("window_snapshots_restored",
+     "tlink_engine_window_snapshots_restored_total",
+     "admissions that restored a window snapshot under their prefix hit"),
+    ("window_snapshots_skipped",
+     "tlink_engine_window_snapshots_skipped_total",
+     "snapshot points passed with no place free in the snapshot pool"),
+    ("window_rows_replayed", "tlink_engine_window_rows_replayed_total",
+     "cached positions prefilled again between a snapshot and the match"),
     # the sampling epilogue (ROADMAP S1): what the packed slots asked of
     # it, per dispatched chunk from the host's own arrays. A sampler call
     # is one _sample_rows over [slots, vocabulary]: each verify row
@@ -688,8 +702,13 @@ class ContinuousEngine:
         # ... and a model with recurrent layers holds a state a slot that
         # no page chain describes: what reuses or moves a slot restores a
         # snapshot and replays, or refuses (``stateful_refusals``)
-        self._stateful = bool(engine.cfg.recurrent)
-        what = "pages and recurrent states" if self._stateful else (
+        # ... as does a model whose window layers hold a ring a slot
+        # (engine/latent.py): the same rule, counted under ``window_*``
+        self._ring = engine.cfg.ring_window is not None
+        self._stateful = bool(engine.cfg.recurrent) or self._ring
+        self._snap_counts = "window" if self._ring else "state"
+        what = "pages and window rings" if self._ring else (
+            "pages and recurrent states" if self._stateful else
             "latent pages")
         if self._latent:
             asked = str(kv_quant or "none")
@@ -703,7 +722,8 @@ class ContinuousEngine:
                 (handoff_after_prefill,
                  f"{what} do not hand off between workers"),
                 (self._stateful and int(tensor_parallel or 1) > 1,
-                 "recurrent states have no partition specs "
+                 ("window rings" if self._ring else "recurrent states")
+                 + " have no partition specs "
                  f"(tensor_parallel={tensor_parallel} asked)"),
                 ("sparse" in engine.cfg.layer_kinds and check_sparse(
                     engine.cfg.latent_of("sparse"), int(page_size)), None),
@@ -718,6 +738,9 @@ class ContinuousEngine:
         if self._stateful and spec_decode:
             spec_decode = False
             self.spec_refusal = (
+                "a model whose window layers hold a ring does not draft: a "
+                "rejected draft row would have overwritten the ring's oldest "
+                "page" if self._ring else
                 "a model with recurrent layers does not draft: a rejected "
                 "draft row would have advanced the slot's state")
         if int(prefill_chunk) <= 0:
@@ -785,6 +808,7 @@ class ContinuousEngine:
                     return LatentPagedCache.init(
                         self.cfg, self.max_slots, page_size=self.page_size,
                         max_len=self.max_seq_len, dtype=engine.cache_dtype,
+                        prefill_chunk=int(prefill_chunk),
                     )
                 return PagedKVCache.init(
                     self.cfg, self.max_slots, page_size=self.page_size,
@@ -836,11 +860,19 @@ class ContinuousEngine:
             stride = int(state_snapshot_stride) or min(
                 32 * chunk, max(self.max_seq_len // 8 // chunk, 1) * chunk)
             self.snap_stride = -(-stride // self.page_size) * self.page_size
+            # ... a window snapshot is 12.6 MB at the published sizes
+            # where a state is 2: one a stride and three for two slots
             n = int(state_snapshots) or (
-                self.max_seq_len // self.snap_stride + 2 * self.max_slots)
-            st = self.cache.state
-            self._snaps = jnp.zeros((n,) + st.shape[:1] + st.shape[2:],
-                                    st.dtype)
+                self.max_seq_len // self.snap_stride + (
+                    3 * self.max_slots // 2 if self._ring
+                    else 2 * self.max_slots))
+            if self._ring:
+                self._snaps = window_snapshot_pool(
+                    self.cache, n, self.cfg.ring_window)
+            else:
+                st = self.cache.state
+                self._snaps = jnp.zeros((n,) + st.shape[:1] + st.shape[2:],
+                                        st.dtype)
             self._snap_free = list(range(n))
             self.prefix.on_drop = self._drop_snapshot
         # -- tiered prefix cache (docs/SERVING.md "Tiered prefix cache") -
@@ -1760,9 +1792,9 @@ class ContinuousEngine:
         self._count("admitted")
         self._count("prefill_tokens_skipped", hit_len)
         if self._stateful:
-            self._count("state_admissions")
+            self._count(f"{self._snap_counts}_admissions")
             if self.prefix is not None:
-                self._count("state_rows_replayed", replayed)
+                self._count(f"{self._snap_counts}_rows_replayed", replayed)
         if self.prefix is not None:
             # counted HERE, not in match(): one lookup per admission, so
             # head-of-line page-wait retries don't skew the hit rate
@@ -1776,17 +1808,23 @@ class ContinuousEngine:
     def _admit_state(self, req, slot: int, hit_nodes: list,
                      hit_len: int) -> None:
         """``slot``'s state as ``hit_len`` positions left it: the snapshot
-        of the hit's last node, or zero where nothing was hit."""
+        of the hit's last node, or zero where nothing was hit (a ring
+        needs no zero: no query reads a position before its slot's
+        first)."""
         req.snaps = {}
+        req.state_restored_at = hit_len if hit_nodes else -1
         if hit_nodes:
-            idx = hit_nodes[-1].snap
-            self.cache = restore_snapshot(
-                self.cache, self._snaps, np.int32(slot), np.int32(idx))
-            req.state_restored_at = hit_len
-            self._count("state_snapshots_restored")
+            place = (np.int32(slot), np.int32(hit_nodes[-1].snap))
+            if self._ring:
+                self.cache = restore_window(
+                    self.cache, self._snaps, *place, np.int32(hit_len))
+            else:
+                self.cache = restore_snapshot(self.cache, self._snaps, *place)
+            self._count(f"{self._snap_counts}_snapshots_restored")
+        elif self._ring:
+            return
         else:
             self.cache = zero_state(self.cache, np.int32(slot))
-            req.state_restored_at = -1
         self._count("admit_device_calls")
 
     def _next_stop(self, req: ContinuousRequest) -> int:
@@ -1818,13 +1856,18 @@ class ContinuousEngine:
             self._drop_snapshot(
                 min(self._snap_nodes.values(), key=lambda n: n.tick))
         if not self._snap_free:
-            self._count("state_snapshots_skipped")
+            self._count(f"{self._snap_counts}_snapshots_skipped")
             return
         idx = self._snap_free.pop()
-        self._snaps = take_snapshot(
-            self._snaps, self.cache.state, np.int32(slot), np.int32(idx))
+        if self._ring:
+            self._snaps = take_window(
+                self._snaps, self.cache, np.int32(slot), np.int32(idx),
+                np.int32(pos))
+        else:
+            self._snaps = take_snapshot(
+                self._snaps, self.cache.state, np.int32(slot), np.int32(idx))
         req.snaps[pos] = idx
-        self._count("state_snapshots_taken")
+        self._count(f"{self._snap_counts}_snapshots_taken")
 
     def _drop_snapshot(self, node) -> None:
         """``node``'s snapshot, if it has one, back to the free places
@@ -2536,7 +2579,8 @@ class ContinuousEngine:
         if self._latent:
             raise PagedUnsupported(
                 "a patterned model's "
-                + ("pages and recurrent states" if self._stateful
+                + ("pages and window rings" if self._ring else
+                   "pages and recurrent states" if self._stateful
                    else "latent pages")
                 + " do not migrate yet: the stream falls back to "
                 "re-prefill on its destination"
@@ -3147,6 +3191,16 @@ class ContinuousEngine:
             )
         if self.host_tier is not None:
             self.host_tier.check_conservation()
+        if self._ring:
+            # a slot's ring pages are its own by their number: the pools
+            # hold the scratch page and ``ring_pages`` a slot, no more
+            c = self.cache
+            if c.wk.shape[1] != 1 + self.max_slots * c.ring_pages or (
+                c.wv.shape != c.wk.shape
+            ):
+                raise AssertionError(
+                    f"ring conservation violated: {c.wk.shape[1]} ring "
+                    f"pages for {self.max_slots} slots of {c.ring_pages}")
         if self._snaps is not None:
             self._check_snapshot_conservation()
 
@@ -3195,6 +3249,11 @@ class ContinuousEngine:
         state_bytes = self.cache.state_bytes if self._stateful else 0
         snap_bytes = 0 if self._snaps is None else (
             self._snaps.size * self._snaps.dtype.itemsize)
+        ring_bytes = self.cache.ring_bytes if self._ring else 0
+        # the snapshot pool is the states' or the windows'
+        pool = (snap_bytes, len(self._snap_nodes))  # bytes, places held
+        of_states, of_windows = (
+            ((0, 0), pool) if self._ring else (pool, (0, 0)))
         # KV storage mode + occupancy: the capacity math operators size
         # slots-per-chip with (kv_quant="int8" halves kv_page_bytes)
         c = self.cache
@@ -3259,9 +3318,14 @@ class ContinuousEngine:
             # a model with recurrent layers: the slots' states, and the
             # states and snapshots together (0 for other models)
             "lightning_state_bytes": state_bytes,
-            "state_snapshot_bytes": snap_bytes,
-            "state_pool_bytes": state_bytes + snap_bytes,
-            "state_snapshots_resident": len(self._snap_nodes),
+            "state_snapshot_bytes": of_states[0],
+            "state_pool_bytes": state_bytes + of_states[0],
+            "state_snapshots_resident": of_states[1],
+            # a model whose window layers hold a ring a slot: the rings,
+            # and the rings and their snapshots together
+            "window_ring_bytes": ring_bytes,
+            "window_pool_bytes": ring_bytes + of_windows[0],
+            "window_snapshots_resident": of_windows[1],
             "spec_refusal": self.spec_refusal,
             "weights_bytes_device_max": max(self.weights_bytes_device),
             "weights_bytes_device_min": min(self.weights_bytes_device),
@@ -3396,8 +3460,8 @@ class ContinuousEngine:
                 self._trace(
                     req, "admission", dur_s=req.admit_t - t_adm,
                     t0=t_adm, slot=req.slot, cache_hit_tokens=req.prefill_pos,
-                    **({"state_restored_at": req.state_restored_at}
-                       if self._stateful else {}),
+                    **({f"{self._snap_counts}_restored_at":
+                        req.state_restored_at} if self._stateful else {}),
                     # deepest tier that fed the hit region — "hbm",
                     # "host", "fleet", or "none" (adopted migrations
                     # keep their own "adopt" span instead)
@@ -3633,14 +3697,16 @@ class ContinuousEngine:
         def over_passes(x):  # summed over the slots of each pass
             return int(x[rows].sum() + (n_exec - 1) * x[emit].sum())
 
-        if layers.get("sliding"):
-            la = sizes["sliding"]
+        for kind in ("sliding", "gqa_window"):
+            if not layers.get(kind):
+                continue
             pages = -(-ctx // self.page_size)
-            first = np.maximum(starts - (la.window - 1), 0) // self.page_size
+            first = np.maximum(
+                starts - (sizes[kind].window - 1), 0) // self.page_size
             self._count("window_pages_walked",
-                        layers["sliding"] * over_passes(pages - first))
+                        layers[kind] * over_passes(pages - first))
             self._count("window_pages_context",
-                        layers["sliding"] * over_passes(pages))
+                        layers[kind] * over_passes(pages))
         if layers.get("full") and not sizes["full"].index_heads:
             self._count("latent_rows_read", layers["full"] * over_passes(ctx))
             self._count("latent_rows_capacity", layers["full"] * over_passes(

@@ -46,20 +46,38 @@ kind's pool.
   so a decode row in the block costs a decode row. A context that holds no more than
   ``index_topk`` positions is attended whole and nothing is scored.
 
-First support keeps every page of a slot for the sliding layers and reads
-the window's span only; freeing pages behind the window is ROADMAP R2.
+The latent sliding layers (``slide``) keep every page of a slot and read
+the window's span only (ROADMAP R2).
+
+**Grouped-query kinds** (``gqa_full`` / ``gqa_window``,
+models/base.py::GqaAttn). A ``gqa_full`` layer's keys and values are page
+pools under the slot's table (``k`` / ``v``), the trie's like any page. A
+``gqa_window`` layer's live in pools of their own (``wk`` / ``wv``) under
+a second table that no array holds: slot ``s`` owns ring pages ``1 + s R
+.. s R + R`` (page 0 is the scratch page) and its logical page ``j`` is
+ring page ``j mod R`` (:func:`ring_table`), ``R`` = :func:`ring_len`
+pages: the window, one block of the ragged pass and a page of slack, so
+that the page-by-page write and the page walk take the ring as they take
+any table and a block's write never lands on a page its first query still
+reads. Those pages are never the trie's: what a prefix hit reuses of them
+is a *snapshot* of the window at a page edge (the ``window - 1`` positions
+before it, :func:`take_window` / :func:`restore_window`, the engine's
+snapshot pool: engine/continuous.py), under the rule of the recurrent
+states. Both kinds, both passes, go through the page walk
+(``gqa_full_attention`` / ``gqa_window_attention``).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..models.base import SALA_KINDS, LatentAttn, ModelConfig
+from ..models.base import GQA_KINDS, SALA_KINDS, GqaAttn, LatentAttn, ModelConfig
 from ..models.latent import (
     _rms,
     EXPERT_STACKS,
@@ -75,6 +93,7 @@ from ..models.latent import (
     attend_absorbed,
     attend_materialised,
     gated_mlp,
+    gqa_qkv,
     index_scores,
     kind_counts,
     latent_qkv,
@@ -101,6 +120,29 @@ FULL_KERNEL = "latent_full_attention"  # ... the full layers' walk, both passes
 # the chip's ridge with nothing to spare for the loop around it; sized on
 # the chip (PERF.md section 6, PR 34)
 FULL_KV_TILE, FULL_ROWS, FULL_GROUP_ROWS = 512, 512, 2048
+# a grouped-query kind's walk (both passes) is the pallas_call
+# ``<kind>_attention`` under the scope ``tlink.<kind>``
+
+
+def ring_len(window: int, chunk: int, page: int) -> int:
+    """Pages of a slot's ring: the window and one block of the ragged pass
+    in whole pages, and one page more (a block that starts inside a page
+    ends inside another)."""
+    return -(-(window + chunk) // page) + 1
+
+
+def snapshot_pages(window: int, page: int) -> int:
+    """Pages a window snapshot holds: the ``window - 1`` positions before
+    a page edge."""
+    return -(-(window - 1) // page)
+
+
+def ring_table(max_slots: int, n_pp: int, R: int) -> jax.Array:
+    """The ring layers' block table ``[S, n_pp]``: logical page ``j`` of
+    slot ``s`` is ring page ``1 + s R + j mod R`` (a constant: no array of
+    the cache holds it)."""
+    return (1 + jnp.arange(max_slots, dtype=jnp.int32)[:, None] * R
+            + jnp.arange(n_pp, dtype=jnp.int32)[None, :] % R)
 
 
 @jax.tree_util.register_dataclass
@@ -123,13 +165,18 @@ class LatentPagedCache:
     v: jax.Array | None = None
     ksum: jax.Array | None = None
     state: jax.Array | None = None  # float32 [Ll, S, H, hd, hd]
+    # ``gqa_window`` layers: keys and values in rings, ``[Lw, 1 + S R,
+    # Hkv, page, hd]`` (module docstring); not page pools of the trie
+    wk: jax.Array | None = None
+    wv: jax.Array | None = None
 
     POOLS = ("full", "index", "slide", "k", "v", "ksum")  # page axis 1
 
     @classmethod
     def init(cls, cfg: ModelConfig, max_slots: int, *, page_size: int = 16,
              max_len: int | None = None, dtype=None,
-             n_pages: int | None = None) -> "LatentPagedCache":
+             n_pages: int | None = None,
+             prefill_chunk: int = 128) -> "LatentPagedCache":
         S_max = max_len or cfg.max_seq_len
         n_pp = -(-S_max // page_size)
         P = n_pages if n_pages is not None else 1 + max_slots * n_pp
@@ -142,6 +189,16 @@ class LatentPagedCache:
             from .sala import init_pools
 
             extra = init_pools(cfg, max_slots, P, page_size, dt)
+        for kind, names in (("gqa_full", ("k", "v")),
+                            ("gqa_window", ("wk", "wv"))):
+            if not n.get(kind):
+                continue
+            ga = sizes[kind]
+            # pages of the slot's table, or the scratch page and a ring a slot
+            pages = P if ga.window is None else 1 + max_slots * ring_len(
+                ga.window, min(prefill_chunk, S_max), page_size)
+            shape = (n[kind], pages, ga.n_kv_heads, page_size, ga.head_dim)
+            extra |= {name: jnp.zeros(shape, dt) for name in names}
 
         def pool(kind, width):
             # no pool for a kind, or a selector, the model has not: a
@@ -186,6 +243,18 @@ class LatentPagedCache:
             self.state.size * self.state.dtype.itemsize)
 
     @property
+    def ring_pages(self) -> int:
+        """Pages of one slot's ring (0: no ring layers)."""
+        return 0 if self.wk is None else (
+            (self.wk.shape[1] - 1) // self.max_slots)
+
+    @property
+    def ring_bytes(self) -> int:
+        """The ring layers' keys and values of every slot."""
+        return 0 if self.wk is None else 2 * (
+            self.wk.size * self.wk.dtype.itemsize)
+
+    @property
     def n_pages(self) -> int:
         return self.rows.shape[1]
 
@@ -202,17 +271,67 @@ class LatentPagedCache:
         return sum(a.size * a.dtype.itemsize for a in self.pools().values())
 
 
+# -- a slot's window and its snapshots (engine/continuous.py) ---------------
+# A snapshot is the ring layers' keys and values of the ``window - 1``
+# positions before a page edge ``pos``: ``[2, Lw, n, Hkv, page, hd]``, the
+# ``n`` = :func:`snapshot_pages` logical pages ``pos / page - n ..`` of a
+# slot's ring. A logical page lies at the same place of every slot's ring,
+# so a restore writes them where the taking slot read them.
+
+
+def window_snapshot_pool(cache: LatentPagedCache, n: int, window: int):
+    """``n`` empty places for ``cache``'s ring layers."""
+    Lw, _, Hkv, page, hd = cache.wk.shape
+    return jnp.zeros(
+        (n, 2, Lw, snapshot_pages(window, page), Hkv, page, hd),
+        cache.wk.dtype)
+
+
+def _snapshot_ring_pages(cache, n: int, slot, pos):
+    R, page = cache.ring_pages, cache.page_size
+    return 1 + slot * R + (pos // page - n + jnp.arange(n)) % R
+
+
+# tlint: one-program
+@partial(jax.jit, donate_argnames=("snaps",))
+def take_window(snaps, cache, slot, idx, pos):
+    """``slot``'s window before the page edge ``pos`` into place ``idx``
+    of the snapshot pool."""
+    pages = _snapshot_ring_pages(cache, snaps.shape[3], slot, pos)
+    return snaps.at[idx].set(
+        jnp.stack([cache.wk[:, pages], cache.wv[:, pages]]))
+
+
+# tlint: one-program
+@partial(jax.jit, donate_argnames=("cache",))
+def restore_window(cache, snaps, slot, idx, pos):
+    """Place ``idx`` of the snapshot pool, taken at ``pos``, as ``slot``'s
+    window there."""
+    pages = _snapshot_ring_pages(cache, snaps.shape[3], slot, pos)
+    return replace(cache, wk=cache.wk.at[:, pages].set(snaps[idx, 0]),
+                   wv=cache.wv.at[:, pages].set(snaps[idx, 1]))
+
+
 def unsupported(cfg: ModelConfig) -> str | None:
     """Why the slot engine cannot serve a patterned config; None when it
     can: layers of the two kinds this module implements, either or both,
     each kind in use with its sizes."""
     kinds = set(cfg.layer_kinds)
     sizes = dict(cfg.latent)
-    served = {"full", "sliding"} | set(SALA_KINDS)
+    served = {"full", "sliding"} | set(SALA_KINDS) | set(GQA_KINDS)
     if not kinds <= served or not kinds <= set(sizes):
         return (f"layer kinds {sorted(kinds)} with sizes for "
                 f"{sorted(sizes)} (served: full, sliding, sparse, "
-                "lightning)")
+                "lightning, gqa_full, gqa_window)")
+    if kinds & set(GQA_KINDS):
+        if kinds - set(GQA_KINDS):
+            return "grouped-query layers beside latent or sparse layers"
+        if "gqa_full" not in kinds:
+            return "window layers without a full layer (no page pool)"
+        if sizes["gqa_full"].window is not None:
+            return "a window on the gqa_full layers"
+        if "gqa_window" in kinds and sizes["gqa_window"].window is None:
+            return "a gqa_window layer without a window"
     if kinds & set(SALA_KINDS):
         if kinds - set(SALA_KINDS):
             return "sparse / lightning layers beside latent layers"
@@ -256,6 +375,10 @@ class _Ctx:
     write_off: jax.Array | None = None
     n_valid: jax.Array | None = None  # ragged pass [S]
     att_len: jax.Array | None = None  # decode [S]: positions attended
+    # ring layers: their table, and the write's plan / the row's page in it
+    ring_bt: jax.Array | None = None
+    ring_plan: tuple | None = None
+    ring_pg: jax.Array | None = None
 
 
 def _write(pool, li, rows, ctx: _Ctx):
@@ -439,6 +562,73 @@ def _full_attend(q, full, index, li, ap, la: LatentAttn, ctx: _Ctx):
     )
 
 
+def _gqa_write(pool, li, rows, plan, pg, off):
+    """``rows`` ``[S, T, Hkv, hd]`` into layer ``li`` of a key or value
+    pool: a block page by page under ``plan``, a single row at ``(pg,
+    off)``."""
+    from .paged import _merge_pages
+
+    if plan is not None:
+        return _merge_pages(pool, li, plan, rows.astype(pool.dtype))
+    at = (li, pg[:, None], jnp.arange(rows.shape[2])[None, :], off[:, None])
+    return pool.at[at].set(rows[:, 0].astype(pool.dtype))
+
+
+def _gqa_attend(q, kp, vp, li, bt, ga: GqaAttn, ctx: _Ctx, name: str):
+    """Queries ``q`` ``[S, T, H, hd]`` over layer ``li`` of ``kp`` / ``vp``
+    under the table ``bt`` through the page walk, from the window's first
+    key on where the kind has one: a continuation step as one query a
+    slot, the ragged pass as every slot's block in one call (a decode row
+    in it costs a row block); ``[S, T, H, hd]``."""
+    kw = dict(scale=ga.softmax_scale, window=ga.window)
+    kernel = ctx.kernel and kp.dtype == q.dtype
+    if ctx.plan is None:  # a continuation step
+        if kernel:
+            o = paged_attention(q[:, 0], kp, vp, bt, ctx.att_len, layer=li,
+                                name=name, **kw)
+        else:
+            o = paged_attention_ref(q[:, 0], kp[li].astype(q.dtype),
+                                    vp[li].astype(q.dtype), bt, ctx.att_len,
+                                    **kw)
+        return o[:, None]
+    starts = ctx.positions[:, 0]
+    if kernel:
+        return ragged_paged_attention(q, kp, vp, bt, starts, ctx.n_valid,
+                                      layer=li, name=name, **kw)
+    return ragged_paged_attention_ref(
+        q, kp[li].astype(q.dtype), vp[li].astype(q.dtype), bt, starts,
+        ctx.n_valid, **kw)
+
+
+def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
+    """:func:`_attention` for the grouped-query kinds: a ``gqa_full``
+    layer's rows go to the pages of the slot's table, a ``gqa_window``
+    layer's to the slot's ring."""
+    cfg = ctx.cfg
+    ga = cfg.latent_of(kind)
+    S, T, d = x.shape
+    ap = lp["attn"]
+    with jax.named_scope("attn"):
+        h = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
+        q = gqa_qkv(h, ap, ga, *ctx.rope[kind])
+    if kind == "gqa_window":
+        names, bt = ("wk", "wv"), ctx.ring_bt
+        place = (ctx.ring_plan, ctx.ring_pg, ctx.write_off)
+    else:
+        names, bt = ("k", "v"), ctx.block_tables
+        place = (ctx.plan, ctx.write_pg, ctx.write_off)
+    with jax.named_scope("kv_write"):
+        kp = _gqa_write(getattr(pools, names[0]), li, q["k"], *place)
+        vp = _gqa_write(getattr(pools, names[1]), li, q["v"], *place)
+    with jax.named_scope(f"tlink.{kind}"):
+        o = _gqa_attend(q["q"], kp, vp, li, bt, ga, ctx, f"{kind}_attention")
+    with jax.named_scope("attn"):
+        if "gate" in q:
+            o = (o.astype(jnp.float32) * q["gate"][..., None]).astype(x.dtype)
+        added = _mm(o.reshape(S, T, -1), ap["wo"])
+    return added, pools._replace(**{names[0]: kp, names[1]: vp})
+
+
 def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     """What the attention of one layer of ``kind`` (layer ``li`` of its
     kind's pools) adds to ``x`` ``[S, T, d]``, its rows written to the
@@ -449,6 +639,8 @@ def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         from . import sala
 
         return sala.attention(x, lp, kind, li, pools, ctx)
+    if kind in GQA_KINDS:
+        return _gqa_attention(x, lp, kind, li, pools, ctx)
     la = cfg.latent_of(kind)
     full, index, slide, stats = pools[:4]
     S, T, d = x.shape
@@ -517,6 +709,8 @@ class Pools(NamedTuple):
     v: jax.Array | None = None
     ksum: jax.Array | None = None
     state: jax.Array | None = None
+    wk: jax.Array | None = None
+    wv: jax.Array | None = None
 
 
 def cache_pools(cache: LatentPagedCache) -> Pools:
@@ -606,12 +800,31 @@ def run_layers(params, x, cache: LatentPagedCache, ctx: _Ctx):
     )
 
 
+def _ring_place(cache, positions, n_valid=None, active=None) -> dict:
+    """Where a pass writes and reads the ring layers (nothing for a model
+    without them): their table, and the ragged pass's write plan or the
+    continuation step's page in it."""
+    if cache.wk is None:
+        return {}
+    from .paged import _page_write_plan
+
+    page, n_pp = cache.page_size, cache.pages_per_slot
+    bt = ring_table(cache.max_slots, n_pp, cache.ring_pages)
+    start = positions[:, 0]
+    if n_valid is not None:
+        return dict(ring_bt=bt, ring_plan=_page_write_plan(
+            bt, start, n_valid, page, n_pp, positions.shape[1]))
+    pg = jnp.take_along_axis(
+        bt, jnp.minimum(start // page, n_pp - 1)[:, None], axis=1)[:, 0]
+    return dict(ring_bt=bt, ring_pg=jnp.where(active, pg, 0))
+
+
 def _ragged_ctx(cache, cfg: ModelConfig, kernel: bool, *, positions, valid,
                 plan, n_valid) -> _Ctx:
     return _Ctx(
         cfg=cfg, kernel=kernel, block_tables=cache.block_tables,
         positions=positions, row_ok=valid, rope=rope_by_kind(cfg, positions),
-        plan=plan, n_valid=n_valid,
+        plan=plan, n_valid=n_valid, **_ring_place(cache, positions, n_valid),
     )
 
 
@@ -622,6 +835,7 @@ def _decode_ctx(cache, cfg: ModelConfig, kernel: bool, *, positions, active,
         positions=positions, row_ok=active[:, None],
         rope=rope_by_kind(cfg, positions), write_pg=write_pg,
         write_off=write_off, att_len=att_len,
+        **_ring_place(cache, positions, active=active),
     )
 
 
@@ -673,9 +887,10 @@ def attention_only(lp, x, cache, cfg: ModelConfig, kernel: bool, kind: str,
 
 
 __all__ = [
-    "FULL_KERNEL", "LatentPagedCache", "Pools", "WINDOW_KERNEL",
-    "attention_only",
+    "FULL_KERNEL", "LatentPagedCache", "Pools",
+    "WINDOW_KERNEL", "attention_only",
     "cache_pools",
-    "decode_layers", "layer_loop", "ragged_layers", "run_layers",
-    "unsupported", "with_pools",
+    "decode_layers", "layer_loop", "restore_window", "ragged_layers",
+    "ring_len", "ring_table", "run_layers", "snapshot_pages", "take_window",
+    "unsupported", "window_snapshot_pool", "with_pools",
 ]

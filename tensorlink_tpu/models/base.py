@@ -188,8 +188,52 @@ class LinearAttn:
         return 3 * d * q + 2 * self.head_dim + d * q + q + q * d
 
 
+@dataclass(frozen=True)
+class GqaAttn:
+    """Sizes of one kind of grouped-query layer of a model whose layers
+    differ (kinds ``"gqa_full"`` / ``"gqa_window"``): ``n_heads`` query
+    heads over ``n_kv_heads`` heads of keys and values in pages, a head
+    count, a window and rotary positions of the kind's own. The first
+    ``rope_dim`` dims of each head rotate (rotate-half on the stored
+    order) with ``rope_theta``; ``rope_scaling``: None, or YaRN's
+    ``(factor, original length, beta_fast, beta_slow, mscale,
+    mscale_all_dim)``, whose amplitude ``yarn_mscale(factor, mscale) /
+    yarn_mscale(factor, mscale_all_dim)`` multiplies cos and sin (a
+    published ``attention_factor`` a: ``mscale = (a - 1) / (0.1 ln
+    factor)``, ``mscale_all_dim = 0``). ``window``: keys ``t - window < s
+    <= t`` (the token itself counts), and the slot engine then holds the
+    layer's keys and values as a ring a slot (engine/latent.py); None =
+    causal. ``gate``: a sigmoid gate a query head on the attention
+    output."""
+
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    rope_dim: int
+    rope_theta: float
+    window: int | None = None
+    rope_scaling: tuple | None = None
+    gate: bool = True
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.head_dim**-0.5
+
+    @property
+    def row_dim(self) -> int:
+        """Values a position caches a layer: keys and values."""
+        return 2 * self.n_kv_heads * self.head_dim
+
+    def param_count(self, d: int) -> int:
+        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+        return d * q + 2 * d * kv + (d * self.n_heads if self.gate else 0) + q * d
+
+
 # layer kinds whose cache is not a latent row: engine/sala.py serves them
 SALA_KINDS = ("sparse", "lightning")
+# ... and the grouped-query kinds of a model whose layers differ
+# (engine/latent.py): pages under the slot's table, a ring a slot
+GQA_KINDS = ("gqa_full", "gqa_window")
 
 
 @dataclass(frozen=True)
@@ -317,6 +361,16 @@ class ModelConfig:
         """Some layer carries a state that no page chain describes."""
         return "lightning" in self.layer_kinds
 
+    @property
+    def ring_window(self) -> int | None:
+        """The window of the layers whose keys and values the slot engine
+        holds as a ring a slot (kind ``"gqa_window"``); None: no such
+        layer. What reuses or moves a slot of such a model restores a
+        snapshot of the window, as for a recurrent state."""
+        if "gqa_window" not in self.layer_kinds:
+            return None
+        return self.latent_of("gqa_window").window
+
     def latent_of(self, kind: str) -> LatentAttn:
         return dict(self.latent)[kind]
 
@@ -415,9 +469,9 @@ def _latent_attn(d: dict):
         d["rope_scaling"] = tuple(d["rope_scaling"])
     if "dense_len" in d:
         return SparseAttn(**d)
-    if "q_rank" not in d:
-        return LinearAttn(**d)
-    return LatentAttn(**d)
+    if "q_rank" in d:
+        return LatentAttn(**d)
+    return (GqaAttn if "n_kv_heads" in d else LinearAttn)(**d)
 
 
 @jax.tree_util.register_dataclass

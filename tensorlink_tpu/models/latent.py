@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .base import LatentAttn, ModelConfig
+from .base import GqaAttn, LatentAttn, ModelConfig
 from .quant import matmul as _mm
 from .transformer import apply_rope, rope_tables
 
@@ -149,6 +149,16 @@ def _init_tree(key, cfg: ModelConfig, dt) -> dict:
     def attn(kind: str, stack):
         la = cfg.latent_of(kind)
         H = la.n_heads
+        if isinstance(la, GqaAttn):
+            # fan-in scale: unit-RMS inputs give q and k entries of order
+            # one and scores of order one under 1 / sqrt(head_dim)
+            q, kv = H * la.head_dim, la.n_kv_heads * la.head_dim
+            return {
+                "wq": dense(stack, d, q), "wk": dense(stack, d, kv),
+                "wv": dense(stack, d, kv),
+                **({"w_g": dense(stack, d, H)} if la.gate else {}),
+                "wo": dense(stack, q, d),
+            }
         p = {
             "w_dq": dense(stack, d, la.q_rank),
             "q_norm": ones(stack, la.q_rank),
@@ -247,6 +257,8 @@ def _layernorm(x, p, eps: float):
 
 def _rope_prefix(x, cos, sin, n: int):
     """Rope on the first ``n`` dims of ``x`` ``[B, T, H, hd]``."""
+    if n == x.shape[-1]:
+        return apply_rope(x, cos, sin)
     return jnp.concatenate(
         [apply_rope(x[..., :n], cos, sin), x[..., n:]], axis=-1
     )
@@ -259,6 +271,25 @@ def rope_by_kind(cfg: ModelConfig, positions: jax.Array) -> dict:
         k: rope_tables(positions, la.rope_dim, la.rope_theta, la.rope_scaling)
         for k, la in cfg.latent if la.rope_dim  # 0: no positional rotation
     }
+
+
+def gqa_qkv(h, ap: dict, ga: GqaAttn, cos, sin) -> dict:
+    """The projections of one grouped-query layer over ``h`` ``[B, T, d]``:
+    ``q`` ``[B, T, H, hd]`` and ``k`` ``[B, T, Hkv, hd]``, the first
+    ``rope_dim`` dims of each head rotated, ``v`` ``[B, T, Hkv, hd]`` and,
+    with a gate, ``gate`` ``[B, T, H]`` (float32)."""
+    B, T = h.shape[:2]
+    hd = ga.head_dim
+    q = _mm(h, ap["wq"]).reshape(B, T, ga.n_heads, hd)
+    k = _mm(h, ap["wk"]).reshape(B, T, ga.n_kv_heads, hd)
+    out = {
+        "q": _rope_prefix(q, cos, sin, ga.rope_dim),
+        "k": _rope_prefix(k, cos, sin, ga.rope_dim),
+        "v": _mm(h, ap["wv"]).reshape(B, T, ga.n_kv_heads, hd),
+    }
+    if "w_g" in ap:
+        out["gate"] = jax.nn.sigmoid(_mm(h, ap["w_g"]).astype(jnp.float32))
+    return out
 
 
 def latent_qkv(h, ap: dict, la: LatentAttn, eps: float, cos, sin) -> dict:
@@ -622,7 +653,8 @@ __all__ = [
     "STEP_STATS",
     "WINDOW_ATTN",
     "Pattern", "absorbed_output", "absorbed_query", "attend_absorbed",
-    "attend_materialised", "gated_mlp", "index_scores", "init_params",
+    "attend_materialised", "gated_mlp", "gqa_qkv", "index_scores",
+    "init_params",
     "kind_counts", "latent_qkv", "moe_mlp", "pattern_of", "rope_by_kind",
     "route",
 ]

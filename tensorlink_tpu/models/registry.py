@@ -10,11 +10,12 @@ reference's tests, docs, and BASELINE configs exercise: gpt2 / SmolLM (llama)
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable
 
 import jax.numpy as jnp
 
-from .base import LatentAttn, LinearAttn, ModelConfig, SparseAttn
+from .base import GqaAttn, LatentAttn, LinearAttn, ModelConfig, SparseAttn
 
 # tlint: disable=TL006(family registry — populated at import, read-only after)
 _FAMILY_BUILDERS: dict[str, Callable[[dict], ModelConfig]] = {}
@@ -279,6 +280,135 @@ def _minicpm_sala(d: dict) -> ModelConfig:
         embed_mult=float(d.get("scale_emb", 1.0)),
         residual_mult=float(d.get("scale_depth", 1.0)) / depth**0.5,
         logit_div=d["hidden_size"] / d.get("dim_model_base", d["hidden_size"]),
+    )
+
+# tlint: disable=TL006(read-only table)
+_LAGUNA_KINDS = {"full_attention": "gqa_full", "sliding_attention": "gqa_window"}
+
+
+def _laguna_rope(rp: dict, head_dim: int) -> dict:
+    """``rope_dim``, ``rope_theta`` and ``rope_scaling`` of one layer kind
+    from its block of ``rope_parameters``."""
+    rope_dim = int(head_dim * float(rp.get("partial_rotary_factor", 1.0)))
+    scaling = None
+    kind = rp.get("rope_type", "default")
+    if kind == "yarn":
+        factor = float(rp["factor"])
+        a = rp.get("attention_factor")
+        scaling = (
+            factor, float(rp["original_max_position_embeddings"]),
+            float(rp.get("beta_fast", 32)), float(rp.get("beta_slow", 1)),
+            # the amplitude on cos and sin as a YaRN mscale (GqaAttn)
+            1.0 if a is None else round(
+                (float(a) - 1.0) / (0.1 * math.log(factor)), 12),
+            0.0,
+        )
+    elif kind != "default":
+        raise ValueError(
+            f"laguna: rope_type {kind!r} is not built (default and yarn are)")
+    return dict(rope_dim=rope_dim - rope_dim % 2,
+                rope_theta=float(rp["rope_theta"]), rope_scaling=scaling)
+
+
+@register_family("laguna")
+def _laguna(d: dict) -> ModelConfig:
+    """Laguna: grouped-query layers of two kinds by ``layer_types`` (full
+    layers; sliding layers with ``sliding_window`` keys), each kind with
+    its own number of query heads (``num_attention_heads_per_layer``) and
+    its own rotary positions (``rope_parameters``: share of a head that
+    rotates, theta, YaRN with a given ``attention_factor``), a sigmoid
+    gate a query head on the attention output (``gating`` "per-head"),
+    leading dense layers and then routed experts beside one shared
+    expert (``mlp_layer_types``). The keys name no scoring function:
+    ``norm_topk_prob`` with a ``moe_routed_scaling_factor`` is read as
+    sigmoid scores, the best by score + selection bias, normalised, times
+    the factor (``assumed`` in the benchmark's configuration file, with
+    the other conventions the keys do not settle: no per-head q/k norm, no
+    gate on the shared expert, rotate-half on the stored order). The
+    per-layer lists are read at their first ``num_hidden_layers``
+    entries. A chip's share of an expert group: ``num_experts`` is what
+    this program holds, ``published.num_experts`` what the router scores,
+    ``expert_group.first_expert`` where the held ones start."""
+    L = d["num_hidden_layers"]
+
+    def per_layer(key: str) -> list:
+        got = list(d[key])[:L]
+        if len(got) != L:
+            raise ValueError(
+                f"laguna: {key} names {len(got)} layers, num_hidden_layers {L}")
+        return got
+
+    types = per_layer("layer_types")
+    unknown = sorted(set(types) - set(_LAGUNA_KINDS))
+    if unknown:
+        raise ValueError(
+            f"laguna: layer_types {unknown} (built: {sorted(_LAGUNA_KINDS)})")
+    gating = {d.get("gating", "per-head").replace("_", "-")} | {
+        g.replace("_", "-") for g in d.get("gating_types", [])[:L]}
+    if gating != {"per-head"}:
+        raise ValueError(
+            f"laguna: gating {sorted(gating)} is not built (per-head is: a "
+            "sigmoid gate a query head on the attention output)")
+    mlps = per_layer("mlp_layer_types")
+    n_dense = mlps.index("sparse") if "sparse" in mlps else L
+    if mlps != ["dense"] * n_dense + ["sparse"] * (L - n_dense):
+        raise ValueError(
+            f"laguna: mlp_layer_types {mlps} is not built (leading dense "
+            "layers, then sparse ones, is)")
+    if d.get("moe_apply_router_weight_on_input"):
+        raise ValueError(
+            "laguna: moe_apply_router_weight_on_input is not built (the "
+            "router's weight multiplies an expert's output)")
+    if d.get("moe_router_logit_softcapping"):
+        raise ValueError(
+            "laguna: moe_router_logit_softcapping "
+            f"{d['moe_router_logit_softcapping']} is not built (0 is)")
+    if d.get("attention_bias"):
+        raise ValueError("laguna: attention_bias is not built")
+    heads = per_layer("num_attention_heads_per_layer")
+    hd, kv = d["head_dim"], d["num_key_value_heads"]
+    sizes = []
+    for name, kind in _LAGUNA_KINDS.items():
+        n = {h for h, t in zip(heads, types) if t == name}
+        if len(n) > 1 or any(h % kv for h in n):
+            raise ValueError(
+                f"laguna: {sorted(n)} query heads on the {name} layers over "
+                f"{kv} kv heads (built: one count a kind, whole groups)")
+        if n:
+            sizes.append((kind, GqaAttn(
+                n_heads=n.pop(), n_kv_heads=kv, head_dim=hd,
+                window=(d["sliding_window"] if kind == "gqa_window" else None),
+                **_laguna_rope(d["rope_parameters"][name], hd),
+            )))
+    shared, f = d.get("shared_expert_intermediate_size", 0), d[
+        "moe_intermediate_size"]
+    if shared % f:
+        raise ValueError(
+            f"laguna: a shared expert of {shared} beside experts of {f}")
+    held = d["num_experts"]
+    published = (d.get("published") or {}).get("num_experts", held)
+    return ModelConfig(
+        family="laguna",
+        vocab_size=d["vocab_size"],
+        d_model=d["hidden_size"],
+        n_layers=L,
+        n_heads=d["num_attention_heads"], n_kv_heads=kv, head_dim=hd,
+        d_ff=d["intermediate_size"],
+        max_seq_len=d.get("max_position_embeddings", 4096),
+        norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        layer_kinds=tuple(_LAGUNA_KINDS[t] for t in types),
+        latent=tuple(sizes),
+        n_dense_layers=n_dense,
+        n_experts_per_tok=d["num_experts_per_tok"],
+        moe_d_ff=f,
+        n_shared_experts=shared // f,
+        moe_router="sigmoid",
+        moe_norm_topk=bool(d.get("norm_topk_prob", True)),
+        moe_scale=float(d.get("moe_routed_scaling_factor", 1.0)),
+        n_experts=published,
+        experts_first=(d.get("expert_group") or {}).get("first_expert", 0),
+        experts_held=held if held != published else 0,
     )
 
 

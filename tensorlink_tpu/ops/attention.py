@@ -394,6 +394,7 @@ def ragged_paged_attention_ref(
     scale: float,
     k_scale: jax.Array | None = None,  # f32 [P, Hkv, page] — int8 pages
     v_scale: jax.Array | None = None,
+    window: int | None = None,  # keys q_pos - window < s <= q_pos
 ) -> jax.Array:
     """Pure-jnp ragged paged attention — the CPU serving path of the
     unified prefill+decode step, and the ground truth the Pallas kernel is
@@ -442,6 +443,8 @@ def ragged_paged_attention_ref(
     q_pos = starts[:, None] + jnp.arange(C)[None, :]  # [S, C]
     k_pos = jnp.arange(K)[None, None, :]  # [1, 1, K]
     causal = k_pos <= q_pos[:, :, None]  # [S, C, K]
+    if window is not None:  # the query's own position counts
+        causal &= k_pos > q_pos[:, :, None] - window
     scores = jnp.where(causal[:, :, None, None, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     # invalid rows (j >= n_valid, including whole padding slots) masked
@@ -961,7 +964,8 @@ def _paged_walk(
 
 # tlint: hot-path
 @functools.partial(
-    jax.jit, static_argnames=("scale", "interpret", "name", "latent"))
+    jax.jit,
+    static_argnames=("scale", "interpret", "name", "latent", "window"))
 def ragged_paged_attention(
     q: jax.Array,  # [S, C, Hq, hd]
     k_pages: jax.Array,  # [P, Hkv, page, hd]
@@ -977,9 +981,12 @@ def ragged_paged_attention(
     layer: jax.Array | None = None,  # int32 scalar — see below
     name: str | None = None,  # the pallas_call's, as a trace shows it
     latent: tuple | None = None,  # a latent cache's walk (_paged_walk)
+    window: int | None = None,  # keys q_pos - window < s <= q_pos
 ) -> jax.Array:
     """Ragged paged attention (TPU); returns ``[S, C, Hq, hd]``
-    (``latent``: ``[S, C, Hq, v_width]``).
+    (``latent``: ``[S, C, Hq, v_width]``). ``window``: each row block's
+    walk starts at the KV block of its first query's oldest key
+    (:func:`_walk_start`).
 
     With ``layer``, the pools (and scale planes) are every layer's,
     stacked ``[L, P, ...]`` as the engine's ``PagedKVCache`` holds them,
@@ -1017,6 +1024,7 @@ def ragged_paged_attention(
         G=G, scale=scale, interpret=interpret,
         **({"shared_kv": True} if v_pages is None else {}),
         **({"latent": latent} if latent else {}),
+        **({"window": window} if window is not None else {}),
     )
     return (
         out.reshape(S, Hkv, C, G, out.shape[-1])

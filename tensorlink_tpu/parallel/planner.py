@@ -33,6 +33,7 @@ from ..models.transformer import cache_specs, partition_specs
 
 MAX_STAGES = 6  # reference ml/validator.py:427-430
 PREFILL_BLOCK = 128  # rows a slot's prefill block holds (MLConfig.prefill_chunk)
+PAGE = 16  # positions a page holds (MLConfig.cont_page_size)
 # tlint: disable=TL006(read-only constant table — never mutated at runtime)
 _DTYPE_BYTES = {"bfloat16": 2, "float32": 4, "float16": 2, "float8_e4m3fn": 1}
 
@@ -140,7 +141,9 @@ class MemoryEstimate:
             )
         params = cfg.held_param_count() * pb
         sizes = dict(cfg.latent)
-        if cfg.recurrent or "sparse" in cfg.layer_kinds:
+        if cfg.recurrent or "sparse" in cfg.layer_kinds or (
+            "gqa_full" in cfg.layer_kinds
+        ):
             parts = cls.state_parts(cfg, batch, seq_len)
             kv = sum(parts.values())
             act = batch * min(seq_len, PREFILL_BLOCK) * (
@@ -172,9 +175,30 @@ class MemoryEstimate:
         caches no position), ``states`` (a float32 state a slot and
         lightning layer) and ``snapshots`` (the engine's default pool: one
         place a ``32 x PREFILL_BLOCK`` positions of the context plus two
-        a slot). Not pages x layers."""
+        a slot). Not pages x layers. A model of grouped-query kinds
+        (engine/latent.py): ``pages`` the ``gqa_full`` layers' keys and
+        values, ``states`` the ``gqa_window`` layers' rings (the window
+        and one prefill block a slot, whatever the context) and
+        ``snapshots`` the engine's pool of window snapshots."""
         pb = _dtype_bytes(cfg.dtype)
         sizes = dict(cfg.latent)
+        if "gqa_full" in cfg.layer_kinds:
+            from ..engine.latent import ring_len, snapshot_pages
+            from ..models.latent import kind_counts
+
+            n = kind_counts(cfg)
+            full = sizes["gqa_full"]
+            pages = n["gqa_full"] * batch * seq_len * full.row_dim * pb
+            rings = snaps = 0
+            if n.get("gqa_window"):
+                win = sizes["gqa_window"]
+                page = n["gqa_window"] * PAGE * win.row_dim * pb
+                rings = batch * ring_len(
+                    win.window, min(PREFILL_BLOCK, seq_len), PAGE) * page
+                snaps = (seq_len // (16 * PREFILL_BLOCK) + 3 * batch // 2) * (
+                    snapshot_pages(win.window, PAGE) * page)
+            return {"pages": int(pages), "states": int(rings),
+                    "snapshots": int(snaps)}
         n_sparse = cfg.layer_kinds.count("sparse")
         n_light = cfg.layer_kinds.count("lightning")
         pages = states = snaps = 0
@@ -568,16 +592,20 @@ def plan_sharding(
                 update_mode=training_update_mode(axes, training),
             )
 
-    if cfg.recurrent:
-        # the slots' states have no stage to follow a layer to: such a
-        # model is served whole on one worker, or not here
+    if cfg.recurrent or cfg.ring_window is not None:
+        # the slots' states (a window layer's ring) have no stage to
+        # follow a layer to: such a model is served whole on one worker,
+        # or not here
         parts = MemoryEstimate.state_parts(cfg, batch, seq_len)
         gb = lambda n: f"{n / 1e9:.2f} GB"  # noqa: E731
+        states, snaps = ("window rings", "window snapshots") if (
+            cfg.ring_window is not None) else (
+            "recurrent states", "state snapshots")
         raise AssignmentError(
             f"{model_name or cfg.family} does not fit one worker: it needs "
             f"{gb(est.total)} (weights {gb(est.params)}, pages of the paged "
-            f"layers {gb(parts['pages'])}, recurrent states "
-            f"{gb(parts['states'])}, state snapshots {gb(parts['snapshots'])}, "
+            f"layers {gb(parts['pages'])}, {states} "
+            f"{gb(parts['states'])}, {snaps} {gb(parts['snapshots'])}, "
             f"activations {gb(est.activations)}, a tenth of headroom) at "
             f"{batch} x {seq_len} positions; the largest worker has "
             f"{gb(best.hbm_bytes)}"

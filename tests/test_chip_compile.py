@@ -138,6 +138,8 @@ OTHER_HEADS = (
     ("12x12-hd64", (12, 12, 64)),
     ("qwen2p5-7b", (28, 4, 128)),
     ("qwen2p5-7b-tp4shard", (7, 1, 128)),
+    ("laguna-full", (48, 8, 128)),
+    ("laguna-sliding", (72, 8, 128)),
 )
 
 
@@ -532,6 +534,63 @@ def test_deepseek_v2_step_fits_one_v5e_with_its_walk_in_both_passes(v5e):
     assert not copies, copies[:2]
     # no score array over a slot's capacity: [.., 16384] float32 of rows x heads
     assert not re.findall(r"f32\[(?:\d+,)*128,128,16384\]", text)
+    whiles = [ln for ln in text[text.index("ENTRY"):].splitlines()
+              if " while(" in ln]
+    assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)
+            for ln in whiles] == list(STEP_PHASES)
+
+
+def test_laguna_step_fits_one_v5e_with_both_walks_in_both_passes(v5e):
+    """The benchmark's ``laguna-s-2.1-ep8`` at its published widths (layers
+    0-8, 32 of 256 experts held, 16 slots x 16,384 positions of bf16 pages
+    for the 3 full layers, a ring of 41 pages a slot for the 6 sliding
+    ones): the step compiles for one described v5e with both grouped-query
+    walks in both passes (groups of 6 and 9 query heads a kv head, the
+    window's walk from its first key on), weights + pools + rings +
+    temporaries fit the chip, and no operation copies a pool or a ring.
+    ~80 s: the one program the cell serves from."""
+    import json
+    import re
+    from pathlib import Path
+
+    from tensorlink_tpu.engine.latent import LatentPagedCache
+    from tensorlink_tpu.engine.paged import STEP_PHASES, paged_ragged_step
+    from tensorlink_tpu.models.registry import config_from_hf
+    from tensorlink_tpu.models.transformer import init_params
+
+    hf = json.loads((Path(__file__).parent.parent / "benchmarks" / "configs"
+                     / "laguna-s-2.1-ep8.json").read_text())
+    cfg = config_from_hf(hf)
+    slots = hf["deployment"]["ml"]["cont_max_slots"]
+    params = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: LatentPagedCache.init(
+        cfg, slots, page_size=PAGE, max_len=hf["deployment"]["seq_len"]))
+    assert cache.wk.shape == (6, 1 + 16 * 41, 8, 16, 128)
+    assert cache.k.shape == (3, 1 + 16 * 1024, 8, 16, 128)
+    place = _on(v5e)
+    ops = _packed_operands(cfg, params, cache, place, place)
+    compiled = paged_ragged_step.lower(*ops, cfg, 8, 1, True).compile()
+    text = compiled.as_text()
+    for name in ("gqa_full_attention", "gqa_window_attention"):
+        assert text.count(name) >= 2, name  # a call a layer and pass
+    ma = compiled.memory_analysis()
+    weights = _nbytes(ops[0])
+    # bf16 but the float32 selection bias: 2 more bytes x 256 x 8 layers
+    assert weights == 2 * cfg.held_param_count() + 2 * 256 * 8
+    pools = _nbytes((cache.k, cache.v))
+    rings = _nbytes((cache.wk, cache.wv))
+    assert 3.2e9 < pools < 3.3e9 and 0.25e9 < rings < 0.27e9
+    print(f"laguna-s-2.1-ep8 on a described v5e: arguments "
+          f"{ma.argument_size_in_bytes / 1e9:.3f} GB, temp "
+          f"{ma.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"{text.count('tpu_custom_call')} kernel calls")
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM, ma
+    assert ma.temp_size_in_bytes < pools, ma
+    for pool in (cache.k, cache.wk):
+        shape = "[" + ",".join(map(str, pool.shape)) + "]"
+        copies = re.findall(
+            rf"^\s*\S+ = \w+{re.escape(shape)}\S* copy\(.*$", text, re.M)
+        assert not copies, copies[:2]
     whiles = [ln for ln in text[text.index("ENTRY"):].splitlines()
               if " while(" in ln]
     assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)

@@ -250,6 +250,10 @@ LEGACY_ENGINE_KEYS = (
     "lightning_rows",
     "state_admissions", "state_snapshots_taken", "state_snapshots_restored",
     "state_snapshots_skipped", "state_rows_replayed",
+    # ... and about the rings of a model whose window layers hold one
+    "window_admissions", "window_snapshots_taken",
+    "window_snapshots_restored", "window_snapshots_skipped",
+    "window_rows_replayed",
     # the sampling epilogue (ROADMAP S1): calls, those that sorted, and
     # the verify walk's length against the rows the program holds
     "sampler_calls", "sampler_calls_sampled",
@@ -456,7 +460,7 @@ def test_a_counter_metric_reads_keys_the_engine_reports(path):
     counters = {c[0] for c in continuous._ENGINE_COUNTERS}
     gauges = {"latent_pool_bytes", "weights_bytes_device_max",
               "step_build_ms", "step_build_waited_ms", "state_pool_bytes",
-              "prefix_evictions"}  # serving_snapshot()'s own
+              "window_pool_bytes", "prefix_evictions"}  # serving_snapshot()'s own
     assert keys and set(keys) <= counters | gauges, set(keys) - counters
     if path.name.startswith("narrow_block_share"):
         assert (spec["num"], spec["den"], spec["scale"]) == (
