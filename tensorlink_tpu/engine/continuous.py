@@ -20,8 +20,10 @@ program — each mid-prefill slot's next prompt piece (its grant from
 SAME ragged dispatch, so a long admission never stalls co-resident
 decodes at all. The block is packed at the narrowest of at most two
 widths that holds the chunk's longest grant (``block_widths``: a page or
-two when nobody prefills, else ``prefill_chunk``), and the step is
-compiled once a width. (The legacy two-program schedule — ≤1 prefill chunk per
+two when nobody prefills, else ``prefill_chunk``), a ``prefill_chunk``-wide
+block whose live rows fit ``flat_rows`` runs the flat rung (the ragged
+pass computes the rows that carry a token, ``_block_width``), and the step
+is compiled once a rung. (The legacy two-program schedule — ≤1 prefill chunk per
 mid-prefill slot before a separate decode chunk — and the monolithic
 dense-prefill admission were retired after their one-release fallback
 window; ``prefill_chunk`` must be ≥ 1.) Finished slots promote their
@@ -64,9 +66,11 @@ from __future__ import annotations
 
 import itertools
 import math
+import queue
 import threading
 import time
-from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor, wait
+from functools import partial
 from dataclasses import dataclass, field
 from typing import Callable
 
@@ -98,6 +102,7 @@ from .paged import (
     PrefixCache,
     SharedPagePool,
     copy_page,
+    flat_rung_rows,
     gather_page,
     make_tp_ragged_step,
     pack_control,
@@ -256,6 +261,16 @@ FLIGHT_CAPACITY = 1024  # chunks the flight recorder keeps
 MIGRATION_TTL_S = 120.0  # an engine's ``migration_ttl_s`` starts here
 
 
+# seconds an idle engine waits for its build thread at a time
+# (``ContinuousEngine._join_build``): a request that arrives meanwhile
+# waits behind it, and the server ends a stream after 30 s without an
+# event. Fetches from the persistent cache are through in a second or
+# two; this bounds a machine that has nothing cached and compiles a rung
+# for a minute (its chunks run the full program meanwhile, so it waits
+# as long as it safely can)
+BUILD_JOIN_MAX_S = 20.0
+
+
 def _timed(fn) -> float:
     """Run ``fn``; the seconds it took on this thread's wall clock."""
     t0 = time.monotonic()
@@ -372,8 +387,8 @@ _ENGINE_COUNTERS = (
     ("ragged_rows_valid", "tlink_engine_ragged_rows_valid_total",
      "rows of the packed block that carried a token"),
     ("ragged_rows_computed", "tlink_engine_ragged_rows_computed_total",
-     "rows of the packed block the ragged pass computed (slots x the "
-     "width that ran)"),
+     "rows the ragged pass computed position-wise (the flat rung's row "
+     "count, else slots x the width that ran)"),
     # the width ladder (ROADMAP S5): a chunk whose longest grant fits the
     # narrow width packs a block that wide, every other one prefill_chunk
     ("ragged_blocks", "tlink_engine_ragged_blocks_total",
@@ -384,6 +399,14 @@ _ENGINE_COUNTERS = (
      "tlink_engine_ragged_blocks_narrow_unbuilt_total",
      "blocks that fitted the narrow width and ran wide: its program was "
      "still being built"),
+    # the flat rung (ROADMAP S5): a prefill_chunk-wide block whose live
+    # rows fit flat_rows has the ragged pass compute that many rows
+    ("ragged_blocks_flat", "tlink_engine_ragged_blocks_flat_total",
+     "of those, blocks whose ragged pass ran over the flat row list"),
+    ("ragged_blocks_flat_unbuilt",
+     "tlink_engine_ragged_blocks_flat_unbuilt_total",
+     "blocks whose live rows fitted the flat rung and ran the full "
+     "program: the rung's was still being built"),
     # the paged kernels' live-span walk (ROADMAP S7): pages the walk
     # reads against the page slots a capacity-wide walk would visit
     ("attn_pages_live", "tlink_engine_attn_pages_live_total",
@@ -775,6 +798,7 @@ class ContinuousEngine:
         self.tensor_parallel = max(int(tensor_parallel or 1), 1)
         self._tp_mesh = None
         self._tp_step = None
+        self._tp_flat_step = None
         if self.tensor_parallel > 1:
             reason = tp_serving_refusal(
                 self.cfg, self.tensor_parallel,
@@ -931,8 +955,9 @@ class ContinuousEngine:
         # a ladder of at most two fixed here: the smallest whole number of
         # pages that holds the verify rows (a chunk in which nobody
         # prefills: a row a slot and its drafts), and prefill_chunk. A
-        # width is a shape of the step program, so the ladder is the
-        # whole compile set: one program a width. It collapses to one
+        # width is a shape of the step program: one program a width (and
+        # the flat rung below: ``rungs`` is the whole compile set). It
+        # collapses to one
         # where the narrow width would not be the smaller, and for a
         # patterned model: its step is a program of several layer bodies
         # a pass (dots3's: 1.9 + 1.8 + 5.5 s to trace, lower and fetch a
@@ -945,6 +970,25 @@ class ContinuousEngine:
             if narrow < self.prefill_chunk and not self._latent
             else (self.prefill_chunk,)
         )
+        # the prefill_chunk-wide geometry has two programs: the full one
+        # computes the block's rows where they lie, the flat rung the
+        # chunk's live rows as one list of flat_rows (paged.FlatRows; a
+        # function of the shapes alone: a full grant beside every slot's
+        # decode row and drafts, or a quarter of the block). A chunk takes
+        # it when its rows fit (``_block_width``). 0: no such rung: the
+        # block is no larger, or the model's pass holds layer bodies of
+        # more than one kind. A second wide program of those is a second
+        # set-up: built behind the first requests it cost dots3's set-up
+        # 7.5-10.9 s of 45 and laguna's 3.0-7.8 of 43 (their programs take
+        # 5-8 s to trace and lower and 5-6 s to fetch, and the document
+        # that could hide it fills in 5-7 s), and MiniCPM-SALA's takes 22
+        # s to lower and 1.4 GB beside 14.1 (my chip runs, PR 45; PERF.md
+        # section 6). The step serves the rung for every kind of layer
+        # all the same (tests/test_flat_rung.py), for when it costs less
+        one_body = len(set(self.cfg.layer_kinds)) <= 1
+        self.flat_rows = flat_rung_rows(
+            self.max_slots, self.prefill_chunk, self.spec_width
+        ) if one_body else 0
         # a width is packed only once its program is built. build_steps
         # (what a server calls before traffic) leaves the narrow program's
         # compile running on a thread, here, while the wide one serves;
@@ -962,7 +1006,7 @@ class ContinuousEngine:
         # and a chunk's path reads nothing else of this
         self._step_build_s = 0.0
         self._step_build_waited_s = 0.0
-        self._unbuilt = set(self.block_widths)
+        self._unbuilt = set(self.rungs)
         # optional TOTAL draft tokens per step shared across speculating
         # slots (0 = each gets a full draft): bounds the extra verify
         # compute like prefill_budget bounds prefill compute — and since
@@ -986,12 +1030,15 @@ class ContinuousEngine:
                 ),
                 engine.params, tp_partition_specs(self.cfg),
             )
-            self._tp_step = make_tp_ragged_step(
-                self._tp_mesh, self.cfg,
+            tp_step = partial(
+                make_tp_ragged_step, self._tp_mesh, self.cfg,
                 n_steps=self.chunk_steps, spec_width=self.spec_width,
                 kernel=self.use_kernel,
                 tp_quant=bool(self.cfg.collective_quant),
             )
+            self._tp_step = tp_step()
+            if self.flat_rows:  # the flat rung is a program of its own
+                self._tp_flat_step = tp_step(flat_rows=self.flat_rows)
             self._tp_gather = tp_gather_costs(
                 self.cfg, self.tensor_parallel,
                 bool(self.cfg.collective_quant),
@@ -1406,8 +1453,9 @@ class ContinuousEngine:
         serving hot loop is ONE top-level step function (``ragged_step``;
         prompt length, cache-hit offset, prefill/decode mix, budget
         split AND the kv_quant storage mode are all DATA or trace-time
-        constants to it), compiled once a width of ``block_widths`` (at
-        most two: the packed block's shape keys the jit cache), plus the
+        constants to it), compiled once a rung of ``rungs`` (at
+        most three: the packed block's shape and the flat rung's row count
+        key the jit cache), plus the
         COW ``copy_page`` (a slot's bind and clear ride the step's
         control buffer and are no program). ``decode_step`` /
         ``sample_rows`` / ``row_keys`` are traced INSIDE the step
@@ -1423,8 +1471,7 @@ class ContinuousEngine:
             # and width (the factory builds a plain/quant-cache pair,
             # only the arity matching this engine's cache ever compiles)
             "tp_ragged_step": (
-                self._tp_step._cache_size()
-                if self._tp_step is not None else 0
+                self._step_programs() if self._tp_step is not None else 0
             ),
             "copy_page": copy_page._cache_size(),
             # migration export/import move ONE page per dispatch (fixed
@@ -3495,7 +3542,13 @@ class ContinuousEngine:
         of ``block_widths`` that holds the chunk's longest grant: a chunk
         in which nobody prefills (or only a short prompt tail does) has
         the dense layers compute a page of rows a slot, not
-        ``prefill_chunk``. Returns None when nothing is live."""
+        ``prefill_chunk``. A ``prefill_chunk``-wide block goes out as it
+        is, per-slot ``starts`` / ``n_valid`` / ``n_spec`` beside it: the
+        flat rung's row map is computed IN the program from ``n_valid``
+        (``paged.FlatRows``), so the control buffer carries nothing more;
+        ``flat`` (the tuple's eighth) says whether the chunk takes that
+        rung (its row count, else 0: ``_block_width``). Returns None when
+        nothing is live."""
         if not self._prefilling and not self._active.any():
             return None
         S, C = self.max_slots, self.prefill_chunk
@@ -3572,32 +3625,62 @@ class ContinuousEngine:
                 ids = sorted(req.eos)[: self._EOS_WIDTH]
                 eos_arr[s, : len(ids)] = ids
         n_spec = self._pack_drafts(blk, n_valid, remaining)
-        blk = np.ascontiguousarray(blk[:, :self._block_width(n_valid)])
+        width, flat = self._block_width(n_valid)
+        blk = np.ascontiguousarray(blk[:, :width])
         return (blk, starts, n_valid, n_spec, emit, remaining, eos_arr,
-                completing, handoff_done, grants)
+                flat, completing, handoff_done, grants)
+
+    @property
+    def rungs(self) -> tuple:
+        """The step programs this engine runs, ``(width, flat_rows)``
+        each, by the rows their ragged pass computes: the narrow width
+        (where there is one), the flat rung of the ``prefill_chunk``-wide
+        geometry (where it is smaller than the block), the full program."""
+        C = self.block_widths[-1]
+        return (
+            tuple((w, 0) for w in self.block_widths[:-1])
+            + (((C, self.flat_rows),) if self.flat_rows else ())
+            + ((C, 0),)
+        )
 
     # tlint: hot-path
-    def _block_width(self, n_valid) -> int:
-        """The narrowest width of ``block_widths`` that holds the chunk's
-        longest grant and whose program is built: while ``build_steps``'
-        thread is at the narrow one every block is ``prefill_chunk`` wide
-        (the same rows carry the same tokens; nothing waits)."""
+    def _block_width(self, n_valid) -> tuple[int, int]:
+        """The chunk's rung ``(width, flat_rows)``, from its ``n_valid``
+        alone: the narrowest width of ``block_widths`` that holds the
+        longest grant; at ``prefill_chunk`` the flat rung when the chunk's
+        live rows fit it, else the full program. Only what is built is
+        picked: while ``build_steps``' thread is at work every block goes
+        out ``prefill_chunk`` wide to the full program (the same rows
+        carry the same tokens; nothing waits)."""
+        C = self.block_widths[-1]
         if self._build is not None:
             if not self._build.done():
-                return self.block_widths[-1]
+                return C, 0
             self._join_build()  # what the build raised is raised here
         longest = int(n_valid.max())
-        return next(w for w in self.block_widths if longest <= w)
+        width = next(w for w in self.block_widths if longest <= w)
+        if width == C and 0 < int(n_valid.sum()) <= self.flat_rows:
+            return C, self.flat_rows
+        return width, 0
 
-    def _join_build(self) -> None:
-        """Wait for ``build_steps``' thread (bounded by one compile, or
-        one fetch from the persistent cache, once an engine) and raise
-        what it raised; from here on every width is packed."""
-        build, self._build = self._build, None
-        if build is not None:
-            t0 = time.monotonic()
+    def _join_build(self, timeout: float | None = None) -> None:
+        """Wait for ``build_steps``' thread (fetches from the persistent
+        cache: seconds; compiles where nothing is cached: a minute) and
+        raise what it raised; from there on every rung is packed. An idle
+        engine waits ``timeout`` seconds and no longer (``step_chunk``):
+        the request that arrives meanwhile waits behind this, and the
+        server ends a stream after 30 s without an event. A thread that is
+        not through by then goes on behind the requests; the next idle
+        moment waits again."""
+        build = self._build
+        if build is None:
+            return
+        t0 = time.monotonic()
+        done, _ = wait((build,), timeout)
+        self._step_build_waited_s += time.monotonic() - t0
+        if done:
+            self._build = None  # what it raised is raised once
             self._step_build_s += build.result()  # the thread's own seconds
-            self._step_build_waited_s += time.monotonic() - t0
 
     def _pack_drafts(self, blk, n_valid, remaining):
         """Draft-budget packing, the speculative half of the packed
@@ -3713,96 +3796,120 @@ class ContinuousEngine:
                 np.full_like(ctx, self.cache.pages_per_slot * self.page_size)
             ))
 
-    def lower_step(self, width: int | None = None):
+    def lower_step(self, width: int | None = None, *, flat: bool = False):
         """The step program lowered at this engine's own shapes and
         placement, not run: what ``chip_smoke.py`` reads to prove the
         Pallas kernel (``tpu_custom_call``) and, sharded, the collectives
-        are in the program that serves. One program a width of
-        ``block_widths``: ``width`` names which (the widest when not
-        given). Call it on an idle engine."""
+        are in the program that serves. One program a rung of ``rungs``:
+        ``width`` names the block's (the widest when not given) and
+        ``flat`` the flat rung of the widest. Call it on an idle
+        engine."""
         S = self.max_slots
         C = self.block_widths[-1] if width is None else int(width)
         if C not in self.block_widths:
             raise ValueError(
                 f"width {C} is not one of this engine's {self.block_widths}"
             )
+        if flat and (not self.flat_rows or C != self.block_widths[-1]):
+            raise ValueError(f"no flat rung at width {C} ({self.rungs})")
         zi = np.zeros(S, np.int32)
         ops = self._step_operands(
             np.zeros((S, C), np.int32), zi, zi, zi, np.zeros(S, bool),
             zi, np.full((S, self._EOS_WIDTH), -1, np.int32),
         )
         if self._tp_step is not None:
-            return self._tp_step.lower(*ops)
+            return (self._tp_flat_step if flat else self._tp_step).lower(*ops)
         return paged_ragged_step.lower(  # spelled as step_chunk's call
             *ops, cfg=self.cfg, n_steps=self.chunk_steps,
             spec_width=self.spec_width, kernel=self.use_kernel,
+            flat_rows=self.flat_rows if flat else 0,
         )
 
     def build_steps(self) -> None:
-        """Build the step program of every width of ``block_widths``
-        (compiled, or fetched from the persistent cache) without running
-        one: what a server calls once, before traffic
-        (``ml/worker.py::_ensure_cont``). It returns when the WIDEST is
-        built, which serves every chunk; the narrow one's compile goes on
-        behind the first requests, on a thread. Until it is through,
-        ``_pack_ragged`` packs every block wide (``_block_width``;
-        counted ``ragged_blocks_narrow_unbuilt``), and the engine joins
-        it the first time it runs out of work (``step_chunk``), so no
-        request waits for the narrow program while the wide one can
-        serve it, and none is served while a program is built after the
-        engine's first idle moment. What the thread raised is raised
-        there, on the serving path. The call at a width then finds its
-        program built: the jitted function's own lowering is what is
-        compiled here.
+        """Build the step program of every rung of ``rungs`` (compiled,
+        or fetched from the persistent cache) without running one: what a
+        server calls once, before traffic
+        (``ml/worker.py::_ensure_cont``). It returns when the FULL
+        program is built, which serves every chunk; the other rungs'
+        compiles go on behind the first requests, on a thread. Until they
+        are through, ``_pack_ragged`` sends every block ``prefill_chunk``
+        wide to the full program (``_block_width``; counted
+        ``ragged_blocks_narrow_unbuilt`` / ``ragged_blocks_flat_unbuilt``),
+        and each time the engine runs out of work it waits for the thread,
+        ``BUILD_JOIN_MAX_S`` at a time (``step_chunk``), so no request
+        waits for a rung while the full program can serve it, and fetches
+        from the persistent cache are through by an engine's first or
+        second idle moment. What the thread raised is raised there, on
+        the serving path. The call at a rung
+        then finds its program built: the jitted function's own lowering
+        is what is compiled here.
 
         The order (cached, qwen3-4b on a v5e; PERF.md section 7): the
-        widest is traced and lowered (1.4-1.6 + 1.45 s) and handed to a
-        thread (a fetch of 1.75 s, 2.1 beside this thread's work) while
-        this thread traces and lowers the narrow one (0.5 + 1.3 s); the
-        narrow one's own fetch (1.0 s), behind the widest's on that
-        thread, is the part no request waits for.
-        An engine of one width has nothing to build ahead: its first
-        chunk builds its program, as ever (built here, ahead of the
-        call, dots3's one program cost set-up 5 s more). Call it on an
-        idle engine.
+        full program is traced and lowered (1.4-1.6 + 1.45 s) and handed
+        to a thread (a fetch of 1.75 s, 2.1 beside this thread's work)
+        while this thread traces and lowers the others, each handed to
+        the same thread as it is lowered (one worker: on a thread of its
+        own a fetch started 0.2 s sooner and took 1.7-1.8 s for 1.0).
+        DeepSeek-V2's engine (one width, two programs: 4.2 s to trace and
+        lower the full one, 2.5 to fetch it) lowers the flat rung beside
+        the full program's fetch: `warm requests` 6.9 -> 10.2 s, and the
+        document then fills in 4.7 s for 8.4 on the rung (my chip runs,
+        PR 45).
+
+        An engine of one program has nothing to build ahead: its first
+        chunk builds it, as ever. Call it on an idle engine.
 
         What this costs is counted here and where the thread is joined:
-        ``step_build_ms`` takes both lowerings and each fetch's own
+        ``step_build_ms`` takes every lowering and each fetch's own
         seconds (what overlaps counts twice), ``step_build_waited_ms``
         this call from entry to return, then the join's wait."""
-        if len(self.block_widths) == 1 or self._build is not None:
+        if len(self.rungs) == 1 or self._build is not None:
             return
         t0 = time.monotonic()
         pool = ThreadPoolExecutor(1, thread_name_prefix="build-step")
-        try:
-            widest = pool.submit(_timed, self.lower_step().compile)
-            # one worker: the narrow program's fetch follows the widest's
-            # on the thread. On a thread of its own it started 0.2 s
-            # sooner and took 1.7-1.8 s for 1.0, beside the widest's end
-            # and the first chunk (PERF.md section 7)
-            self._build = pool.submit(
-                _timed, self.lower_step(self.block_widths[0]).compile
+        lowered_rungs: queue.SimpleQueue = queue.SimpleQueue()
+
+        def behind() -> float:
+            """Each rung as this thread hands it over, lowered; the
+            thread's own seconds. One job, so what a rung's compile raises
+            ends it and is read where the thread is joined."""
+            return sum(
+                _timed(low.compile) for low in iter(lowered_rungs.get, None)
             )
-            lowered = time.monotonic() - t0  # both, traced too: this thread
-            built = widest.result()  # what it raised is raised here
+
+        try:
+            full = pool.submit(_timed, self.lower_step().compile)
+            # one worker: a rung's fetch follows the one before it
+            self._build = pool.submit(behind)
+            for width, flat_rows in self.rungs[:-1]:
+                lowered_rungs.put(
+                    self.lower_step(width, flat=True) if flat_rows
+                    else self.lower_step(width)
+                )
+            lowered = time.monotonic() - t0  # all, traced too: this thread
+            built = full.result()  # what it raised is raised here
         finally:
-            pool.shutdown(wait=False)  # the thread ends with its queue
-        # the narrow program's seconds follow where it is joined
+            lowered_rungs.put(None)  # the thread ends behind the last rung
+            pool.shutdown(wait=False)
+        # the other rungs' seconds follow where the thread is joined
         self._step_build_s += lowered + built
         self._step_build_waited_s += time.monotonic() - t0
         self._unbuilt.clear()  # no call of this engine builds a program
 
     def _step_programs(self) -> int:
         """Step programs in the jit cache this engine's calls fill."""
-        step = paged_ragged_step if self._tp_step is None else self._tp_step
-        return step._cache_size()
+        if self._tp_step is None:
+            return paged_ragged_step._cache_size()
+        return self._tp_step._cache_size() + (
+            self._tp_flat_step._cache_size() if self._tp_flat_step else 0
+        )
 
-    def _note_first_call(self, width: int, programs: int, dur_s: float):
-        """After this engine's first call at ``width`` (no
+    def _note_first_call(self, rung: tuple, programs: int, dur_s: float):
+        """After this engine's first call at ``rung`` (no
         ``build_steps`` came before it): where the call grew the jit
         cache from ``programs`` it built its program, and the dispatch
         phase's ``dur_s`` is build time that a serving path waited for."""
-        self._unbuilt.discard(width)
+        self._unbuilt.discard(rung)
         if self._step_programs() > programs:
             self._step_build_s += dur_s
             self._step_build_waited_s += dur_s
@@ -3810,8 +3917,8 @@ class ContinuousEngine:
     # tlint: hot-path
     def step_chunk(self, *, admit_only: bool = False) -> bool:
         """Admit queued requests, then run ONE compiled step program
-        (the step at the width this chunk's block was packed at: one
-        program a width of ``block_widths``, at most two).
+        (the step at the rung this chunk's rows picked: one program a
+        rung of ``rungs``, at most three).
 
         The packed ragged block — every mid-prefill slot's next prompt
         piece AND every decode slot's next token in one dispatch —
@@ -3852,7 +3959,7 @@ class ContinuousEngine:
                     pack = self._pack_ragged()
             if pack is not None:
                 blk, starts, n_valid, n_spec, emit, remaining, eos_arr, \
-                    completing, handoff_done, grants = pack
+                    flat, completing, handoff_done, grants = pack
                 programs = self._step_programs() if self._unbuilt else 0
                 with _Phase(ph, "dispatch"):
                     # read before delivery releases a finished slot
@@ -3865,12 +3972,13 @@ class ContinuousEngine:
                         # sharded hot path: same program semantics,
                         # weights/KV are device-local shards; the control
                         # buffer is replicated by the call
-                        out, self.cache, self._counts = self._tp_step(*ops)
+                        step = self._tp_flat_step if flat else self._tp_step
+                        out, self.cache, self._counts = step(*ops)
                     else:
                         out, self.cache, self._counts = paged_ragged_step(
                             *ops, cfg=self.cfg, n_steps=self.chunk_steps,
                             spec_width=self.spec_width,
-                            kernel=self.use_kernel,
+                            kernel=self.use_kernel, flat_rows=flat,
                         )
                     # the program took the binds and resets it carried
                     self._bind[:] = False
@@ -3879,7 +3987,7 @@ class ContinuousEngine:
                     placed = sum(isinstance(x, np.ndarray) for x in ops)
                 if self._unbuilt:
                     self._note_first_call(
-                        blk.shape[1], programs, ph["dispatch"]
+                        (blk.shape[1], flat), programs, ph["dispatch"]
                     )
                 with _Phase(ph, "wait"):
                     # the device runs this chunk: the one before it leaves
@@ -3909,18 +4017,27 @@ class ContinuousEngine:
                     if not self.has_work():
                         self.flush_stream()  # no step follows to hide it
                 with _Phase(ph, "post"):
-                    self._count("ragged_rows_valid", int(n_valid.sum()))
-                    self._count("ragged_rows_computed", blk.size)
+                    n_rows = int(n_valid.sum())
+                    # what the pass computed position-wise
+                    rows_computed = flat or blk.size
+                    self._count("ragged_rows_valid", n_rows)
+                    self._count("ragged_rows_computed", rows_computed)
                     self._count("ragged_blocks")
                     self._count("chunk_host_arrays", placed + 1)  # + out
                     if blk.shape[1] < self.prefill_chunk:
                         self._count("ragged_blocks_narrow")
+                    elif flat:
+                        self._count("ragged_blocks_flat")
                     elif int(n_valid.max()) <= self.block_widths[0] < (
                         blk.shape[1]
                     ):
                         # it fitted the narrow width, whose program was
                         # still being built
                         self._count("ragged_blocks_narrow_unbuilt")
+                    elif n_rows <= self.flat_rows:
+                        # it fitted the flat rung, whose program was
+                        # still being built
+                        self._count("ragged_blocks_flat_unbuilt")
                     # what the kernels' walk follows, from the contexts
                     # as packed: every slot with a row rides the ragged
                     # pass, the emitting ones each further step (at the
@@ -3955,7 +4072,7 @@ class ContinuousEngine:
                         S = blk.shape[0]
                         rows, head, calls = self._tp_gather
                         self._count("tp_gather_bytes", int(
-                            rows * (blk.size + (n_exec - 1) * S)
+                            rows * (rows_computed + (n_exec - 1) * S)
                             + head * S * (self.spec_width + n_exec - 1)
                         ))
                         self._count("tp_gather_calls", calls * n_exec)
@@ -3970,6 +4087,7 @@ class ContinuousEngine:
                         decode_steps=n_exec if bool(emit.any()) else 0,
                         prefill_granted=int(sum(grants.values())),
                         block_rows=blk.shape[1],  # the width that ran
+                        rows_computed=rows_computed,  # ... and the rung
                         spec_drafted=int(n_spec.sum()),
                         tokens_emitted=delivered_total,
                         pages_free=self.alloc.n_free,
@@ -3983,8 +4101,8 @@ class ContinuousEngine:
         more = self.has_work()
         if not more:
             # out of work for now: nobody waits for this engine, so the
-            # narrow program's build (build_steps) is joined here, once
-            self._join_build()
+            # build behind (build_steps) is waited for here
+            self._join_build(BUILD_JOIN_MAX_S)
         if fields is None:
             # nothing was dispatched (admission only, or nothing live):
             # no record; the round stays part of what lies between two

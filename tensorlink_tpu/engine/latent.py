@@ -379,6 +379,26 @@ class _Ctx:
     ring_bt: jax.Array | None = None
     ring_plan: tuple | None = None
     ring_pg: jax.Array | None = None
+    # the ragged pass's flat rung (paged.FlatRows): ``x`` is the live rows
+    # ``[1, R, d]``; ``positions`` / ``row_ok`` stay the block's ``[S, C]``
+    # for the seams that need a slot (the page write, the attention call)
+    rows: tuple | None = None
+
+    @property
+    def x_ok(self) -> jax.Array:
+        """``row_ok`` in ``x``'s own layout."""
+        return self.row_ok if self.rows is None else self.rows.live[None]
+
+
+def _expand(a, ctx: _Ctx):
+    """``a`` in ``x``'s layout to the block's ``[S, C, ...]`` (a gather
+    on the flat rung, nothing else)."""
+    return a if ctx.rows is None else ctx.rows.expand(a)
+
+
+def _collect(a, ctx: _Ctx):
+    """The block's ``[S, C, ...]`` back to ``x``'s layout."""
+    return a if ctx.rows is None else ctx.rows.collect(a)
 
 
 def _write(pool, li, rows, ctx: _Ctx):
@@ -441,7 +461,8 @@ def _sliding_attend(q, pool, li, ap, la: LatentAttn, ctx: _Ctx):
         (k_pos[:, None, :] <= q_pos) & (k_pos[:, None, :] > q_pos - la.window)
         & ctx.row_ok[:, :, None]
     )
-    return attend_materialised(q["q_n"], q["q_r"], rows, mask, ap, la)
+    return attend_materialised(
+        _expand(q["q_n"], ctx), _expand(q["q_r"], ctx), rows, mask, ap, la)
 
 
 def _select_attend(q_n, q_r, qi, wi, q_pos, row_ok, bt_row, full, index, li,
@@ -479,7 +500,14 @@ def _walk_attend(q, pool, li, ap, la: LatentAttn, ctx: _Ctx):
     value both; ``[S, T, H, v]``."""
     T = ctx.positions.shape[1]
     bt, scale = ctx.block_tables, la.softmax_scale
+    # the absorbed query is position-wise: over x's rows, flat or not
     qa = absorbed_query(q["q_n"], q["q_r"], ap, la)  # [S, T, H, W]
+    if ctx.rows is None:
+        first_q, per_slot, rows_of = (lambda: qa[:, 0]), qa, (lambda a: a)
+    else:  # a slot's rows are gathered where its block is walked
+        to_flat = per_slot = ctx.rows.to_flat
+        first_q = lambda: ctx.rows.take(qa, to_flat[:, 0])
+        rows_of = partial(ctx.rows.take, qa)
     shape = (la.kv_rank, FULL_KV_TILE, FULL_ROWS, FULL_GROUP_ROWS)
     kernel = ctx.kernel and pool.dtype == qa.dtype
 
@@ -491,7 +519,8 @@ def _walk_attend(q, pool, li, ap, la: LatentAttn, ctx: _Ctx):
         return ref(qs, rows, rows, *place, scale=scale)[..., :la.kv_rank]
 
     def first_rows(lengths):  # one query position a slot, at lengths - 1
-        out = walk(paged_attention, paged_attention_ref, qa[:, 0], bt, lengths)
+        out = walk(
+            paged_attention, paged_attention_ref, first_q(), bt, lengths)
         return absorbed_output(out, ap, la)[:, None]  # [S, 1, H, v]
 
     if ctx.plan is None:  # a continuation step
@@ -507,12 +536,14 @@ def _walk_attend(q, pool, li, ap, la: LatentAttn, ctx: _Ctx):
         return lax.cond(
             ok,
             lambda: absorbed_output(walk(
-                ragged_paged_attention, ragged_paged_attention_ref, qs[None],
-                bt_row[None], start[None], nv[None])[0], ap, la),
+                ragged_paged_attention, ragged_paged_attention_ref,
+                rows_of(qs)[None], bt_row[None], start[None], nv[None])[0],
+                ap, la),
             lambda: jnp.zeros((T, la.n_heads, la.v_dim), o1.dtype),
         )
 
-    oT = lax.map(block, (many, qa, bt, ctx.positions[:, 0], ctx.n_valid))
+    oT = lax.map(
+        block, (many, per_slot, bt, ctx.positions[:, 0], ctx.n_valid))
     first = jnp.pad(o1, ((0, 0), (0, T - 1), (0, 0), (0, 0)))
     return jnp.where(many[:, None, None, None], oT, first)
 
@@ -535,8 +566,7 @@ def _full_attend(q, full, index, li, ap, la: LatentAttn, ctx: _Ctx):
             bt_row, full, index, li, ap, la,
         )
 
-    args = (
-        q["q_n"], q["q_r"], q["qi"], q["wi"],
+    args = tuple(_expand(q[n], ctx) for n in ("q_n", "q_r", "qi", "wi")) + (
         ctx.positions, ctx.row_ok, ctx.block_tables,
     )
     # every slot's first row in one batch: all there is of a decode slot
@@ -611,6 +641,7 @@ def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     with jax.named_scope("attn"):
         h = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
         q = gqa_qkv(h, ap, ga, *ctx.rope[kind])
+        qs, k, v = (_expand(q[n], ctx) for n in ("q", "k", "v"))
     if kind == "gqa_window":
         names, bt = ("wk", "wv"), ctx.ring_bt
         place = (ctx.ring_plan, ctx.ring_pg, ctx.write_off)
@@ -618,11 +649,12 @@ def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         names, bt = ("k", "v"), ctx.block_tables
         place = (ctx.plan, ctx.write_pg, ctx.write_off)
     with jax.named_scope("kv_write"):
-        kp = _gqa_write(getattr(pools, names[0]), li, q["k"], *place)
-        vp = _gqa_write(getattr(pools, names[1]), li, q["v"], *place)
+        kp = _gqa_write(getattr(pools, names[0]), li, k, *place)
+        vp = _gqa_write(getattr(pools, names[1]), li, v, *place)
     with jax.named_scope(f"tlink.{kind}"):
-        o = _gqa_attend(q["q"], kp, vp, li, bt, ga, ctx, f"{kind}_attention")
+        o = _gqa_attend(qs, kp, vp, li, bt, ga, ctx, f"{kind}_attention")
     with jax.named_scope("attn"):
+        o = _collect(o, ctx)
         if "gate" in q:
             o = (o.astype(jnp.float32) * q["gate"][..., None]).astype(x.dtype)
         added = _mm(o.reshape(S, T, -1), ap["wo"])
@@ -651,11 +683,11 @@ def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         q = latent_qkv(h, ap, la, cfg.norm_eps, cos, sin)
     with jax.named_scope("kv_write"):
         if kind == "sliding":
-            slide = _write(slide, li, q["row"], ctx)
+            slide = _write(slide, li, _expand(q["row"], ctx), ctx)
         else:
-            full = _write(full, li, q["row"], ctx)
+            full = _write(full, li, _expand(q["row"], ctx), ctx)
             if la.index_heads:
-                index = _write(index, li, q["ki"], ctx)
+                index = _write(index, li, _expand(q["ki"], ctx), ctx)
     if kind == "sliding":
         with jax.named_scope(WINDOW_ATTN):
             o = _sliding_attend(q, slide, li, ap, la, ctx)
@@ -664,6 +696,7 @@ def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         stats = stats.at[N_MOE_STATS:].add(
             jnp.stack([kept, scored]).astype(jnp.int32))
     with jax.named_scope("attn"):
+        o = _collect(o, ctx)
         if "gate" in q:
             o = (o.astype(jnp.float32) * q["gate"][..., None]).astype(x.dtype)
         added = _mm(o.reshape(S, T, -1), ap["wo"])
@@ -684,7 +717,7 @@ def _layer(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
             return x + gated_mlp(h, lp["mlp"]), pools
     with jax.named_scope(MOE):
         y, ms = moe_mlp(
-            h.reshape(S * T, d), lp["moe"], cfg, ctx.row_ok.reshape(-1)
+            h.reshape(S * T, d), lp["moe"], cfg, ctx.x_ok.reshape(-1)
         )
         # each adds up over layers and steps
         pools = pools._replace(stats=pools.stats.at[:N_MOE_STATS].add(ms))
@@ -820,11 +853,16 @@ def _ring_place(cache, positions, n_valid=None, active=None) -> dict:
 
 
 def _ragged_ctx(cache, cfg: ModelConfig, kernel: bool, *, positions, valid,
-                plan, n_valid) -> _Ctx:
+                plan, n_valid, rows=None, rope_positions=None) -> _Ctx:
+    """``rows`` / ``rope_positions``: the flat rung's row map and its
+    rows' positions ``[1, R]`` (rope is applied where the rows are
+    projected: over ``x``'s layout)."""
     return _Ctx(
         cfg=cfg, kernel=kernel, block_tables=cache.block_tables,
-        positions=positions, row_ok=valid, rope=rope_by_kind(cfg, positions),
-        plan=plan, n_valid=n_valid, **_ring_place(cache, positions, n_valid),
+        positions=positions, row_ok=valid, rope=rope_by_kind(
+            cfg, positions if rope_positions is None else rope_positions),
+        plan=plan, n_valid=n_valid, rows=rows,
+        **_ring_place(cache, positions, n_valid),
     )
 
 
@@ -842,7 +880,8 @@ def _decode_ctx(cache, cfg: ModelConfig, kernel: bool, *, positions, active,
 def ragged_layers(params, x, cache, cfg: ModelConfig, kernel: bool, **place):
     """The ragged pass's layers over the packed block ``x`` ``[S, C, d]``
     (the step's first phase: the step's counts start here); ``place``:
-    ``positions``, ``valid``, ``plan``, ``n_valid``."""
+    ``positions``, ``valid``, ``plan``, ``n_valid`` and, on the flat rung
+    (``x`` ``[1, R, d]``), ``rows`` and ``rope_positions``."""
     cache = replace(cache, stats=jnp.zeros_like(cache.stats))
     ctx = _ragged_ctx(cache, cfg, kernel, **place)
     # the pass stays ONE top-level loop of the step program, as the dense

@@ -1383,20 +1383,82 @@ def _verify_emit(blk, logits_v, base, n_spec, emit, seeds, steps, temp,
     return toks, last, m, ended, counts, steps, remaining
 
 
+FLAT_ROW_TILE = 128  # the flat rung's row count is a multiple of this
+
+
+def flat_rung_rows(slots: int, chunk: int, spec_width: int = 1) -> int:
+    """Rows of the flat rung of a ``[slots, chunk]`` geometry, from the
+    shapes alone: the smallest multiple of ``FLAT_ROW_TILE`` that is at
+    least a quarter of the block and holds one full grant beside every
+    slot's decode row and drafts. 0: no such rung (it would compute as
+    many rows as the block holds)."""
+    need = max(-(-slots * chunk // 4), chunk + slots * spec_width)
+    rows = -(-need // FLAT_ROW_TILE) * FLAT_ROW_TILE
+    return rows if rows < slots * chunk else 0
+
+
+class FlatRows(NamedTuple):
+    """The ragged pass's live rows as one token-major list of ``R`` rows:
+    slot 0's grant (or its decode row and drafts), then slot 1's, back to
+    back; rows past the chunk's ``n_valid.sum()`` carry nothing. Computed
+    in the program from ``n_valid`` (a cumulative sum), so the control
+    buffer holds the ``[S, C]`` block as ever. Position-wise work runs
+    over ``[1, R, ...]``; the two seams that need a slot (the attention
+    call, the page write) take ``expand``'s ``[S, C, ...]`` and hand
+    their result to ``collect``."""
+
+    to_flat: jax.Array  # int32 [S, C]: block row -> flat row; R: no token
+    slot: jax.Array  # int32 [R]: flat row -> its slot ...
+    col: jax.Array  # int32 [R]: ... and its column in the block
+    live: jax.Array  # bool [R]: the flat row carries a token
+
+    @classmethod
+    def of(cls, n_valid: jax.Array, C: int, R: int) -> "FlatRows":
+        S = n_valid.shape[0]
+        end = jnp.cumsum(n_valid)
+        first = end - n_valid
+        j = jnp.arange(C)[None, :]
+        to_flat = jnp.where(j < n_valid[:, None], first[:, None] + j, R)
+        r = jnp.arange(R)
+        slot = jnp.minimum((r[:, None] >= end[None, :]).sum(-1), S - 1)
+        col = jnp.clip(r - first[slot], 0, C - 1)
+        return cls(to_flat, slot, col, r < end[-1])
+
+    def take(self, a: jax.Array, idx: jax.Array) -> jax.Array:
+        """Rows ``idx`` of the flat ``a`` ``[1, R, ...]``; zeros where
+        ``idx`` names no row (a block row without a token)."""
+        return a[0].at[idx].get(mode="fill", fill_value=0)
+
+    def expand(self, a: jax.Array) -> jax.Array:
+        """``[1, R, ...]`` -> ``[S, C, ...]``, one gather."""
+        return self.take(a, self.to_flat)
+
+    def collect(self, a: jax.Array) -> jax.Array:
+        """``[S, C, ...]`` -> ``[1, R, ...]``, one gather (a dead flat
+        row reads a live one's value; nothing reads it back)."""
+        return a[self.slot, self.col][None]
+
+
 def _ragged_block(x, lp, layer, cfg: ModelConfig, cos, sin, cache_kv, plan,
                   write_pg, write_off, block_tables, starts, n_valid,
                   kernel: bool, tp_axis: str | None = None,
-                  tp_quant: bool = False):
+                  tp_quant: bool = False, rows: FlatRows | None = None):
     """One transformer block over the ragged ``[S, C]`` token block,
     reading/writing KV through every slot's pages at once. Shares
     ``_paged_block``'s prologue/epilogue (scatter-then-attend order
     preserved) but carries the whole mixed prefill+decode block: a
     decode slot's single token and a mid-prefill slot's chunk go through
     the SAME projection, the SAME page scatter and the SAME ragged
-    attention — the kernel-level erasure of the prefill/decode split."""
+    attention — the kernel-level erasure of the prefill/decode split.
+    On the flat rung (``rows``) ``x`` is the live rows ``[1, R, d]``:
+    q/k/v are projected from them and go to ``[S, C, ...]`` for the page
+    write and the attention call, whose output comes back flat for
+    ``wo`` and the MLP."""
     with jax.named_scope("attn"):
         h = x if cfg.norm_position == "post" else _norm(x, lp["ln1"], cfg)
         q, k, v = _paged_qkv(h, lp, cfg, cos, sin)  # [S, C, H, hd]
+        if rows is not None:
+            q, k, v = rows.expand(q), rows.expand(k), rows.expand(v)
 
     # block scatter through the one write path (quantizes in int8 mode):
     # position (s, j) lands at (write_pg[s, j], write_off[s, j]); padding
@@ -1410,6 +1472,8 @@ def _ragged_block(x, lp, layer, cfg: ModelConfig, cos, sin, cache_kv, plan,
             kv, layer, block_tables, starts, n_valid,
             scale=_attn_scale(cfg),
         )  # [S, C, Hq, hd]
+        if rows is not None:
+            attn_raw = rows.collect(attn_raw)  # [1, R, Hq, hd]
     return _paged_residual(x, attn_raw, lp, cfg, tp_axis, tp_quant), kv
 
 
@@ -1418,11 +1482,19 @@ def _ragged_pass(
     params, blk, cache, starts, n_valid, n_spec,
     cfg: ModelConfig, spec_width: int, kernel: bool,
     tp_axis: str | None = None, tp_quant: bool = False,
+    flat_rows: int = 0,
 ):
     """The step's first phase: every layer over the packed ``[S, C]``
     block through the pages, then the vocabulary head over each slot's
     verification rows. Returns ``(logits_v [S, W, V], base, kv_new)``;
-    the caller advances the lengths."""
+    the caller advances the lengths.
+
+    ``flat_rows`` = ``R`` > 0 (the flat rung): the residual stream is the
+    chunk's live rows ``[1, R, d]`` (:class:`FlatRows`; the chunk holds no
+    more, which the host saw to), so embedding, norms, projections, rope,
+    MLP and experts cost ``R`` rows whatever ``S x C`` is; only the page
+    write and the attention call see ``[S, C, ...]``. 0: rows are
+    computed where they lie in the block."""
     S, C = blk.shape
     page = cache.page_size
     n_pp = cache.pages_per_slot
@@ -1433,8 +1505,12 @@ def _ragged_pass(
         )
         plan = _page_write_plan(bt, starts, n_valid, page, n_pp, C)
 
-        x = _embed_tokens(params, blk, cfg)  # [S, C, d]
-        positions = pos
+        rows, tok, positions = None, blk, pos
+        if flat_rows:
+            rows = FlatRows.of(n_valid, C, int(flat_rows))
+            tok = jnp.where(rows.live, blk[rows.slot, rows.col], 0)[None]
+            positions = (starts[rows.slot] + rows.col)[None]  # [1, R]
+        x = _embed_tokens(params, tok, cfg)  # [S, C, d], or [1, R, d]
         if cfg.pos == "learned":
             x = x + params["embed"]["pos"][positions].astype(cfg.dtype)
         cos = sin = None
@@ -1444,7 +1520,8 @@ def _ragged_pass(
         if cfg.patterned:
             x, kv_new = ragged_layers(
                 params, x, cache, cfg, kernel, positions=pos, valid=valid,
-                plan=plan, n_valid=n_valid,
+                plan=plan, n_valid=n_valid, rows=rows,
+                rope_positions=None if rows is None else positions,
             )
         else:
             x, kv_new = _scan_layers(
@@ -1452,7 +1529,7 @@ def _ragged_pass(
                 lambda x, lp, layer, kv: _ragged_block(
                     x, lp, layer, cfg, cos, sin, kv, plan, write_pg,
                     write_off, bt, starts, n_valid, kernel, tp_axis,
-                    tp_quant,
+                    tp_quant, rows,
                 ),
             )
         x = _final_norm(x, params, cfg)
@@ -1470,7 +1547,10 @@ def _ragged_pass(
         jnp.maximum(n_valid - 1, 0)[:, None],
     )  # [S, W]
     with jax.named_scope(RAGGED_PASS):
-        h_v = x[jnp.arange(S)[:, None], gather]  # [S, W, d]
+        if rows is None:
+            h_v = x[jnp.arange(S)[:, None], gather]  # [S, W, d]
+        else:  # the same block rows, where the flat list holds them
+            h_v = rows.take(x, jnp.take_along_axis(rows.to_flat, gather, 1))
         logits_v = _logits(params, h_v, cfg, tp_axis, tp_quant)  # [S, W, V]
     return logits_v, base, kv_new
 
@@ -1611,6 +1691,7 @@ def _ragged_step_impl(
     params, ctl, cache, counts,
     cfg: ModelConfig, n_steps: int, spec_width: int, kernel: bool,
     tp_axis: str | None = None, tp_quant: bool = False,
+    flat_rows: int = 0,
 ):
     """Unjitted body of :func:`paged_ragged_step` — also traced inside
     the tensor-parallel shard_map (:func:`make_tp_ragged_step`). There
@@ -1640,7 +1721,7 @@ def _ragged_step_impl(
     # (tests/test_step_scopes.py pins names and order)
     logits_v, base, kv_new = _ragged_pass(
         params, blk, cache, starts, n_valid, n_spec, cfg, W, kernel,
-        tp_axis, tp_quant,
+        tp_axis, tp_quant, flat_rows,
     )
 
     toks0, nxt, spec_m, ended, counts, steps, remaining = _verify_emit(
@@ -1690,7 +1771,7 @@ def _ragged_step_impl(
 # tlint: hot-path  # tlint: one-program
 @partial(
     jax.jit,
-    static_argnames=("cfg", "n_steps", "spec_width", "kernel"),
+    static_argnames=("cfg", "n_steps", "spec_width", "kernel", "flat_rows"),
     donate_argnames=("cache", "counts"),
 )
 def paged_ragged_step(
@@ -1702,17 +1783,26 @@ def paged_ragged_step(
     n_steps: int,
     spec_width: int = 1,
     kernel: bool = False,
+    flat_rows: int = 0,
 ):
     """THE serving hot loop's single step function, compiled once a
-    width of the packed block: one ragged prefill+decode forward over
+    rung of the packed block's ladder: one ragged prefill+decode forward over
     the packed ``[S, C]`` token block, then up to ``n_steps - 1`` decode
     continuation steps in the same on-device while_loop — one host
     round trip per chunk, zero scheduling seams between prefilling and
     decoding slots. ``C`` is one of the engine's ``block_widths`` (the
-    narrow one when no slot's grant is longer, else ``prefill_chunk``):
-    one program a width of the ladder, at most two, fixed at
-    construction; no argument's VALUE picks a program (what ``# tlint:
-    one-program`` holds callers to).
+    narrow one when no slot's grant is longer, else ``prefill_chunk``)
+    and ``flat_rows`` what the ragged pass computes position-wise: 0, the
+    ``S x C`` rows where they lie, or ``R`` (the flat rung of the
+    ``prefill_chunk``-wide geometry, :func:`flat_rung_rows`): the chunk's
+    live rows as one token-major list ``[1, R, d]`` through embedding,
+    norms, projections, rope, MLP, experts and the head's gather, with
+    ``[S, C, ...]`` only at the two seams that need a slot, the page
+    write and the attention call (:class:`FlatRows`; same kernels, same
+    write plan, same pages). One program a rung of the ladder, at most
+    three (narrow, flat, full), fixed at construction; the host picks the
+    rung from the chunk's ``n_valid`` alone, and no argument's VALUE
+    picks a program (what ``# tlint: one-program`` holds callers to).
 
     The packed block (assembled by the host-side
     ``engine/continuous.py::pack_prefill_budgets`` packing) carries every
@@ -1764,6 +1854,7 @@ def paged_ragged_step(
     the engine's kill switch consumes)."""
     return _ragged_step_impl(
         params, ctl, cache, counts, cfg, n_steps, spec_width, kernel,
+        flat_rows=flat_rows,
     )
 
 
@@ -1815,8 +1906,8 @@ def tp_gather_costs(cfg: ModelConfig, tp: int, quant: bool = False):
 
 # Compiled tensor-parallel ragged-step programs, keyed by every static
 # that shapes the trace. Engines sharing (mesh, model, chunk geometry)
-# share ONE step (compiled once a width of the packed block, at most
-# twice) — churn in slots/requests/spec mixes never adds entries, which
+# share ONE step a rung (compiled once a width of the packed block, at
+# most twice; the flat rung is an entry of its own) — churn in slots/requests/spec mixes never adds entries, which
 # is what the per-shard-degree jit-cache guard in tests/test_tp.py pins.
 # tlint: disable=TL006(append-only compiled-program registry, the TP analogue of a @jax.jit function's cache, bounded by hosted configs)
 _TP_RAGGED_CACHE: dict = {}
@@ -1831,6 +1922,7 @@ def make_tp_ragged_step(
     kernel: bool = False,
     tp_quant: bool = False,
     axis: str = "tp",
+    flat_rows: int = 0,
 ):
     """Build (or fetch) THE tensor-parallel serving program: the ragged
     step body shard_mapped over ``mesh[axis]`` and jitted with the same
@@ -1843,9 +1935,11 @@ def make_tp_ragged_step(
     ``paged_ragged_step`` minus the trailing statics (closed over
     here). ``tp_quant`` routes the per-chunk activation gathers through
     the int8 quantized collective (bounded divergence, opt-in via
-    ModelConfig.collective_quant)."""
+    ModelConfig.collective_quant). ``flat_rows``: the rung, as
+    :func:`paged_ragged_step` takes it (the flat rows are replicated
+    control like the block; a rung is a program of its own here too)."""
     key = (mesh, cfg, int(n_steps), int(spec_width), bool(kernel),
-           bool(tp_quant), axis)
+           bool(tp_quant), axis, int(flat_rows))
     hit = _TP_RAGGED_CACHE.get(key)
     if hit is not None:
         return hit
@@ -1857,7 +1951,7 @@ def make_tp_ragged_step(
     def tp_ragged_step(params, ctl, cache, counts):
         return _ragged_step_impl(
             params, ctl, cache, counts, cfg, n_steps, spec_width, kernel,
-            axis, tp_quant,
+            axis, tp_quant, flat_rows,
         )
 
     def specs_for(quantized: bool):
@@ -2128,6 +2222,8 @@ __all__ = [
     "paged_decode_step",
     "paged_ragged_step",
     "make_tp_ragged_step",
+    "FlatRows",
+    "flat_rung_rows",
     "pack_control",
     "unpack_control",
     "unpack_results",

@@ -59,6 +59,7 @@ from ..models.sala import (
 from ..models.transformer import apply_rope
 from ..ops.attention import block_sparse_attention, block_sparse_attention_ref
 from ..ops.lightning import lightning_attention_chunk, lightning_attention_step
+from .latent import _collect, _expand
 
 SPARSE_KERNEL = "block_sparse_attention"
 KEY_TILE = 2048  # key positions a trip of the masked dense walk attends
@@ -262,10 +263,11 @@ def _sparse(h, ap, li, pools, sa: SparseAttn, ctx):
     """A sparse layer's gated attention output ``[S, T, H hd]`` over the
     normed input ``h``, its rows written first; ``(o, pools)``."""
     cfg = ctx.cfg
-    S, T = ctx.positions.shape
+    T = ctx.positions.shape[1]
     H, hd = sa.n_heads, sa.head_dim
     with jax.named_scope("attn"):
         q, k, v, gate = qkv(h, ap, H, sa.n_kv_heads, hd, cfg.norm_eps, _mm)
+        q, k, v = (_expand(a, ctx) for a in (q, k, v))
     with jax.named_scope("kv_write"):
         pools = _write_kv(pools, li, k, v, ctx)
     t0 = ctx.positions[:, 0]
@@ -293,7 +295,8 @@ def _sparse(h, ap, li, pools, sa: SparseAttn, ctx):
             many[:, None], count(keptT, ctx.positions, ctx.row_ok), counts)
     stats = pools.stats.at[_N_BASE:_N_BASE + 3].add(counts.sum(0))
     with jax.named_scope("attn"):
-        o = (o.reshape(S, T, H * hd).astype(jnp.float32) * gate).astype(h.dtype)
+        o = _collect(o, ctx)
+        o = (o.reshape(gate.shape).astype(jnp.float32) * gate).astype(h.dtype)
     return o, pools._replace(stats=stats)
 
 
@@ -306,12 +309,12 @@ def _lightning(h, ap, li, pools, la: LinearAttn, ctx):
     """A lightning layer's normed, gated output ``[S, T, H hd]`` over the
     normed input ``h``, the slots' states advanced; ``(o, pools)``."""
     cfg = ctx.cfg
-    S, T = ctx.positions.shape
     H, hd = la.n_heads, la.head_dim
     with jax.named_scope("attn"):
         q, k, v, gate = qkv(h, ap, H, H, hd, cfg.norm_eps, _mm)
         cos, sin = ctx.rope["lightning"]
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        q, k, v = (_expand(a, ctx) for a in (q, k, v))
     slopes = jnp.asarray(la.slopes(), jnp.float32)
     state = pools.state
     with jax.named_scope(LIGHTNING):
@@ -339,7 +342,8 @@ def _lightning(h, ap, li, pools, la: LinearAttn, ctx):
             n_rows = ctx.n_valid.sum()
     stats = pools.stats.at[_N_BASE + 3].add(n_rows.astype(jnp.int32))
     with jax.named_scope("attn"):
-        o = _rms(o.reshape(S, T, H * hd), ap["o_norm"], cfg.norm_eps)
+        o = _collect(o, ctx)
+        o = _rms(o.reshape(gate.shape), ap["o_norm"], cfg.norm_eps)
         o = (o * gate).astype(h.dtype)
     return o, pools._replace(state=state, stats=stats)
 

@@ -2292,10 +2292,11 @@ class DistributedWorker:
         # given (no copy when _load_stage made them in its layout); the
         # stage must not keep another layout of them alive beside it
         rt.params = rt.engine.params
-        # one step program a width of the packed block: the wide one is
-        # built here (a program that does not compile or fit fails this
-        # request loudly, like any other lowering error), the narrow one
-        # behind the first requests, which the wide one serves meanwhile
+        # one step program a rung of the packed block's ladder: the full
+        # one is built here (a program that does not compile or fit fails
+        # this request loudly, like any other lowering error), the narrow
+        # and the flat one behind the first requests, which the full one
+        # serves meanwhile
         cont.build_steps()
         return cont
 

@@ -22,6 +22,17 @@ SLOTS, PAGE, CHUNK, MAX_LEN = 4, 8, 32, 96
 REP = [5, 9] * 4  # what prompt lookup drafts from
 
 
+@pytest.fixture(autouse=True)
+def _an_idle_engine_waits_for_its_build(monkeypatch):
+    """These tests compile on the CPU, seconds a program: an idle engine
+    waits for its build thread as long as that takes (a server's waits
+    ``BUILD_JOIN_MAX_S`` at a time: ``tests/test_flat_rung.py`` has that
+    case)."""
+    from tensorlink_tpu.engine import continuous
+
+    monkeypatch.setattr(continuous, "BUILD_JOIN_MAX_S", 120.0)
+
+
 def _cfg(**kw):
     # widths of its own: the jit caches are process-global, and the
     # compile counts below are of THIS module's programs

@@ -276,12 +276,14 @@ def _assert_pools_stay_put(compiled, cache, params, shards: int = 1) -> None:
 
 
 @pytest.mark.parametrize(
-    "kv_quant,tp,width",
-    [("int8", 1, 128), ("none", 1, 128), ("int8", 4, 128),
-     ("int8", 1, NARROW), ("int8", 4, NARROW)],
-    ids=["int8", "bf16", "int8-tp4", "int8-narrow", "int8-tp4-narrow"],
+    "kv_quant,tp,width,flat",
+    [("int8", 1, 128, 0), ("none", 1, 128, 0), ("int8", 4, 128, 0),
+     ("int8", 1, NARROW, 0), ("int8", 4, NARROW, 0),
+     ("int8", 1, 128, 256), ("int8", 4, 128, 256)],
+    ids=["int8", "bf16", "int8-tp4", "int8-narrow", "int8-tp4-narrow",
+         "int8-flat", "int8-tp4-flat"],
 )
-def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp, width):
+def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp, width, flat):
     """qwen3-4b's widths and page pool (8 slots x 4096, 2,049 pages a
     layer) with the depth cut to twelve layers (a third: what the step
     keeps beside the pools, ~35 MB of logits and control state a chip,
@@ -291,19 +293,27 @@ def test_ragged_step_moves_no_pool(v5e_chips, kv_quant, tp, width):
     back. int8 and bf16 pages on one chip; the tensor-parallel step over
     the described 2x2, where a chip's pool holds two kv heads. The same
     at the narrow width of the block (the second program of the step: two
-    page merges a slot and layer for nine), on one chip and over the 2x2."""
+    page merges a slot and layer for nine), on one chip and over the 2x2,
+    and on the flat rung (the third: 256 live rows through the layers,
+    ``[8, 128]`` only at the page write and the walk: the expand and
+    collect gathers move activations and no pool)."""
     import dataclasses
 
-    from tensorlink_tpu.engine.paged import CTL_COLS, paged_ragged_step
+    from tensorlink_tpu.engine.paged import (
+        CTL_COLS, flat_rung_rows, paged_ragged_step)
     from tensorlink_tpu.models.registry import config_presets
 
+    assert flat in (0, flat_rung_rows(S, 128, 9))
     cfg = dataclasses.replace(config_presets()["qwen3-4b"], n_layers=12)
     if tp == 1:
         place = _on(SingleDeviceSharding(v5e_chips[0]))
         ops = _step_operands(cfg, place, place, kv_quant, width)
-        compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
+        compiled = paged_ragged_step.lower(
+            *ops, cfg, 8, 9, True, flat).compile()
     else:
-        compiled, ops = _tp4_step_compiled(v5e_chips, cfg, width)
+        compiled, ops = _tp4_step_compiled(v5e_chips, cfg, width, flat)
+    if flat:  # the residual stream is the row list
+        assert f"bf16[1,{flat},{cfg.d_model}]" in compiled.as_text()
     assert ops[1].shape == (S, width + CTL_COLS + N_PP)
     _assert_pools_stay_put(compiled, ops[2], ops[0], tp)
 
@@ -330,7 +340,7 @@ def test_ragged_step_fits_one_v5e_beside_the_weights(v5e):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM, ma
 
 
-def _tp4_step_compiled(v5e_chips, cfg, width: int = 128):
+def _tp4_step_compiled(v5e_chips, cfg, width: int = 128, flat_rows: int = 0):
     """The tensor-parallel step for ``cfg`` compiled over the four
     described chips at a default MLConfig worker's shapes: (compiled,
     its abstract operands)."""
@@ -359,7 +369,8 @@ def _tp4_step_compiled(v5e_chips, cfg, width: int = 128):
         )
 
     ops = _step_operands(cfg, place, on(tp_cache_specs(True)), width=width)
-    step = make_tp_ragged_step(mesh, cfg, n_steps=8, spec_width=9, kernel=True)
+    step = make_tp_ragged_step(mesh, cfg, n_steps=8, spec_width=9, kernel=True,
+                               flat_rows=flat_rows)
     return step.lower(*ops).compile(), ops
 
 

@@ -223,13 +223,16 @@ LEGACY_ENGINE_KEYS = (
     # host-tier promotions, and cross-replica prefix pulls
     "prefix_demotions", "host_tier_hits",
     "fleet_pulls", "fleet_pull_fallbacks",
-    # flat packing's count (ROADMAP S3): rows of the packed block that
-    # carried a token / rows the ragged pass computed
+    # flat packing's count (ROADMAP S5): rows of the packed block that
+    # carried a token / rows the ragged pass computed position-wise
     "ragged_rows_valid", "ragged_rows_computed",
     # the width ladder (ROADMAP S5): packed blocks dispatched / those
     # packed at the narrow width / those that fitted it and ran wide while
     # its program was still being built
     "ragged_blocks", "ragged_blocks_narrow", "ragged_blocks_narrow_unbuilt",
+    # the flat rung (ROADMAP S5): blocks whose ragged pass ran over the
+    # flat row list / those that fitted it and ran the full program
+    "ragged_blocks_flat", "ragged_blocks_flat_unbuilt",
     # the paged kernels' live-span walk (ROADMAP S7): pages walked /
     # page slots of the same passes
     "attn_pages_live", "attn_pages_capacity",

@@ -11,6 +11,7 @@ points, which the reduction matches, and sit under an ``attn`` scope.
 """
 
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -88,17 +89,21 @@ def top_level_loops(text: str) -> list[str]:
     return out
 
 
-@pytest.mark.parametrize("rung", [-1, 0], ids=["wide", "narrow"])
+@pytest.mark.parametrize("rung", [-1, 0, 1], ids=["wide", "narrow", "flat"])
 @pytest.mark.parametrize("spec_width", [1, 9])
 def test_three_top_level_loops_in_phase_order(tiny_engine, spec_width, rung):
-    """At each width of the ladder (one program a width): what reads the
-    phases by loop order reads a narrow chunk's as a wide one's."""
+    """At each rung of the ladder (one program a rung): what reads the
+    phases by loop order reads a narrow or a flat chunk's as a wide
+    one's (the flat rung's gathers live inside ``tlink.ragged_pass``)."""
     ce = _cont(tiny_engine, spec_width)
     assert ce.spec_width == spec_width
     assert ce.block_widths == (8 if spec_width == 1 else 16, 64)
-    width = ce.block_widths[rung]
-    text = ce.lower_step(width).as_text(debug_info=True)
+    assert ce.rungs[1] == (64, 128)
+    width, flat_rows = ce.rungs[rung]
+    lower_step = partial(ce.lower_step, width, flat=flat_rows > 0)
+    text = lower_step().as_text(debug_info=True)
     assert f"tensor<4x{width}xi32>" in text  # the packed block's shape
+    assert (f"tensor<1x{flat_rows}x" in text) == (flat_rows > 0)
     loops = top_level_loops(text)
     assert len(loops) == 3, loops
     for path, phase in zip(loops, STEP_PHASES):
@@ -109,7 +114,7 @@ def test_three_top_level_loops_in_phase_order(tiny_engine, spec_width, rung):
     # that order: the layer scan with its trip count known, the verify
     # walk with none (its bound is data: the longest emitting draft + 1),
     # so no pass can inline it even at spec_width 1
-    compiled = ce.lower_step(width).compile().as_text()
+    compiled = lower_step().compile().as_text()
     whiles = [ln for ln in compiled[compiled.index("ENTRY"):].splitlines()
               if " while(" in ln]
     assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)
