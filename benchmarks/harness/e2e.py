@@ -17,6 +17,22 @@ read. The window is ``[t0, t1)``.
   (last token - first token) / (tokens - 1).
 * ``itl_max_p50_ms``: median over the same requests of the longest gap
   between two consecutive tokens.
+* ``stall8_p50_ms``: median over the same requests (those of more than
+  ``STALL_TOKENS`` tokens) of the longest time to receive ``STALL_TOKENS``
+  more tokens, ``max_i(stamps[i + 8] - stamps[i])``: the stall as a reader
+  feels it. Tokens leave the engine a chunk at a time and trickle through
+  the path to the client, so the longest gap between TWO tokens is a
+  chunk's start-to-start less the trickle and falls when the path gets
+  slower; a span of a chunk's worth of tokens always crosses one burst's
+  edge and reads the start-to-start plus what the trickle differs by from
+  one burst to the next: it does not fall when the path gets slower, and
+  still rises when a chunk grows. The 8 is this benchmark's (the chunk
+  every configuration has run since PR 23), not read from the program.
+
+The set-up clock (:func:`setup_clock`): ``setup_s`` runs from the moment
+``jax.devices()`` returned to the window's opening; what came before is the
+interpreter's and the machine's (``imports_s``, ``backend_up_s``) and is
+kept beside it, per layer.
 
 Completed means: every token asked for arrived, the last of them by the
 end of the wait (open loop) or inside the window (closed loop).
@@ -25,6 +41,8 @@ end of the wait (open loop) or inside the window (closed loop).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+
+STALL_TOKENS = 8  # the span of ``stall8_ms``, in tokens
 
 
 @dataclass
@@ -112,6 +130,23 @@ def itl_max_ms(r: Rec) -> float:
     return max(b - a for a, b in zip(r.stamps, r.stamps[1:])) * 1e3
 
 
+def stall_ms(r: Rec, k: int = STALL_TOKENS) -> float | None:
+    """The longest time to receive ``k`` more tokens; nothing for a request
+    of ``k`` tokens or fewer."""
+    if len(r.stamps) <= k:
+        return None
+    return max(b - a for a, b in zip(r.stamps, r.stamps[k:])) * 1e3
+
+
+def setup_clock(t_process: float, t_imported: float, t_backend: float,
+                t_open: float) -> dict:
+    """The stages of a set-up from four stamps of one clock: process start,
+    imports done, ``jax.devices()`` returned, window open."""
+    return {"imports_s": t_imported - t_process,
+            "backend_up_s": t_backend - t_imported,
+            "setup_s": t_open - t_backend}
+
+
 def summarize(recs: list[Rec], *, mode: str, t0: float, t1: float,
               grace: float) -> dict:
     """Every end-to-end number of one window, by metric name, with the
@@ -138,6 +173,8 @@ def summarize(recs: list[Rec], *, mode: str, t0: float, t1: float,
         "ttft_p50_ms": percentile(ttfts, 50),
         "tpot_p50_ms": percentile([tpot_ms(r) for r in done], 50),
         "itl_max_p50_ms": percentile([itl_max_ms(r) for r in done], 50),
+        f"stall{STALL_TOKENS}_p50_ms": percentile(
+            [s for s in map(stall_ms, done) if s is not None], 50),
         "out_tok_s": out["tokens_in_window"] / (t1 - t0),
     }
     out["metrics"] = {k: v for k, v in vals.items() if v is not None}

@@ -27,3 +27,4 @@ class Obs:
     model: dict = field(default_factory=dict)
     peaks: dict = field(default_factory=dict)
     client: dict = field(default_factory=dict)  # e2e.summarize's metrics
+    setup: dict = field(default_factory=dict)  # e2e.setup_clock's stages

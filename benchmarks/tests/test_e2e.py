@@ -3,7 +3,8 @@
 import pytest
 
 from benchmarks.harness.e2e import (Rec, completed, is_failed, percentile,
-                                    summarize, tokens_in_window)
+                                    setup_clock, stall_ms, summarize,
+                                    tokens_in_window)
 
 T0, T1, GRACE = 100.0, 110.0, 15.0
 
@@ -70,6 +71,61 @@ def test_tpot_and_longest_gap_over_completed_requests():
     # a: (102.9-102.0)/5 = 180 ms, longest gap 900; b: 1500 ms, gap 2000
     assert out["metrics"]["tpot_p50_ms"] == pytest.approx((180 + 1500) / 2)
     assert out["metrics"]["itl_max_p50_ms"] == pytest.approx((900 + 2000) / 2)
+
+
+def bursts(first, n_bursts, size, every, trickle):
+    """Tokens that leave in bursts of ``size`` every ``every`` seconds and
+    reach the client ``trickle`` seconds apart."""
+    return [first + b * every + i * trickle
+            for b in range(n_bursts) for i in range(size)]
+
+
+@pytest.mark.parametrize("size, every, trickle, stall, gap", [
+    # bursts of 8 (a chunk): a span of 8 tokens always crosses ONE edge and
+    # reads the start-to-start; the longest gap reads it LESS the trickle
+    (8, 0.120, 0.000, 120.0, 120.0),
+    (8, 0.120, 0.005, 120.0, 120.0 - 7 * 5.0),
+    (8, 0.120, 0.010, 120.0, 120.0 - 7 * 10.0),
+    # bursts of 16 (a chunk twice as long): the span crosses one edge of a
+    # period twice as long, less the eight tokens it does not need
+    (16, 0.240, 0.000, 240.0, 240.0),
+    (16, 0.240, 0.005, 240.0 - 8 * 5.0, 240.0 - 15 * 5.0),
+])
+def test_stall_over_a_chunks_worth_of_tokens(size, every, trickle, stall, gap):
+    r = rec(0, 101.0, bursts(102.0, 48 // size, size, every, trickle))
+    assert stall_ms(r) == pytest.approx(stall)
+    out = summarize([r], mode="closed", t0=T0, t1=T1, grace=GRACE)
+    assert out["metrics"]["stall8_p50_ms"] == pytest.approx(stall)
+    # a slower path LOWERS the longest gap and leaves the stall where it was
+    assert out["metrics"]["itl_max_p50_ms"] == pytest.approx(gap)
+
+
+def test_stall_needs_nine_tokens_and_is_a_median_over_those_that_have_them():
+    assert stall_ms(rec(0, 101.0, [102.0 + i for i in range(8)])) is None
+    assert stall_ms(rec(0, 101.0, [102.0 + i for i in range(9)])) \
+        == pytest.approx(8000.0)
+    short = rec(0, 101.0, [102.0, 102.5, 103.0])
+    out = summarize([short], mode="closed", t0=T0, t1=T1, grace=GRACE)
+    assert "stall8_p50_ms" not in out["metrics"]  # no sample: absent
+    assert out["metrics"]["itl_max_p50_ms"] == pytest.approx(500.0)
+    # one slow stretch in a long request decides its stall
+    long = rec(1, 101.0, [102.0 + 0.01 * i for i in range(20)]
+               + [103.0 + 0.01 * i for i in range(20)])
+    assert stall_ms(long) == pytest.approx((103.0 - 102.19 + 0.07) * 1e3)
+    out = summarize([short, long], mode="closed", t0=T0, t1=T1, grace=GRACE)
+    assert out["metrics"]["stall8_p50_ms"] == pytest.approx(stall_ms(long))
+
+
+def test_set_up_starts_where_the_backend_came_up():
+    # process start 10.0, imports done 12.8, jax.devices() back 21.9 (a slow
+    # machine: 9.1 s), window open 52.4
+    c = setup_clock(10.0, 12.8, 21.9, 52.4)
+    assert c == {"imports_s": pytest.approx(2.8),
+                 "backend_up_s": pytest.approx(9.1),
+                 "setup_s": pytest.approx(30.5)}
+    # a machine whose backend takes 20 s longer reads the same set-up
+    assert setup_clock(10.0, 12.8, 41.9, 72.4)["setup_s"] == pytest.approx(30.5)
+    assert sum(c.values()) == pytest.approx(52.4 - 10.0)
 
 
 def test_closed_loop_medians_take_requests_that_completed_inside():

@@ -80,14 +80,48 @@ def test_open_loop_sizes_are_the_quantiles_of_the_file():
     assert max(r.prompt_tokens for r in reqs) <= 512
 
 
+ANSWERS = [163, 190, 221, 244, 269, 291, 318, 352]
+
+
+def unstaggered(seed, **without):
+    """decode-closed's plan before the first round is scaled."""
+    traffic = {k: v for k, v in spec.load_traffic("decode-closed").items()
+               if k not in without} | {"stagger": False}
+    return spec.generator("closed").plan(traffic, {}, seed, 51, DEPLOY)
+
+
+def test_closed_loop_deals_eight_answer_lengths_the_same_way_at_every_seed():
+    t = spec.load_traffic("decode-closed")
+    assert [o for _, o in t["request_set"]] == ANSWERS
+    assert sum(ANSWERS) == 8 * 256  # the mean answer PR 23's file asked
+    # no two clients' answers take the same number of 8-step chunks
+    assert len({-(-a // 8) for a in ANSWERS}) == 8
+    plans = [make("decode-closed", {}, seed) for seed in (5, 7, 2**31 + 99)]
+    for p in plans:
+        assert len(p.clients) == 8 and all(len(c) == 64 for c in p.clients)
+        assert {r.prompt_tokens for c in p.clients for r in c} == {128}
+        assert all(r.count_template for c in p.clients for r in c)
+    assert sized(plans[0]) == sized(plans[1]) == sized(plans[2])
+    # without plan_seed the seed deals the same multiset another way
+    a, b = (unstaggered(s, plan_seed=None) for s in (1, 2**31 + 5))
+    assert a.sizes() == b.sizes() and sized(a) != sized(b)
+    # the multiset before the stagger: each length 64 times in 512 places
+    whole = unstaggered(5)
+    assert Counter(r.output_tokens for r in flat(whole)) == Counter(
+        {a: 64 for a in ANSWERS})
+    # no client is dealt long answers only: every client's first dozen
+    # requests (a window's worth) hold at least four different lengths
+    for c in whole.clients:
+        assert len({r.output_tokens for r in c[:12]}) >= 4
+
+
 def test_closed_loop_staggers_the_first_round_only():
     p = make("decode-closed", {}, 5)
-    assert len(p.clients) == 8
-    firsts = [c[0].output_tokens for c in p.clients]
-    assert firsts == [32, 64, 96, 128, 160, 192, 224, 256]
-    assert {r.output_tokens for c in p.clients for r in c[1:]} == {256}
-    assert {r.prompt_tokens for c in p.clients for r in c} == {128}
-    assert all(r.count_template for c in p.clients for r in c)
+    whole = unstaggered(5)
+    for i, (c, w) in enumerate(zip(p.clients, whole.clients)):
+        assert c[0].output_tokens == round(w[0].output_tokens * (i + 1) / 8)
+        assert [r.output_tokens for r in c[1:]] == [
+            r.output_tokens for r in w[1:]]
 
 
 def test_sessions_deal_one_fixed_set_of_turns():

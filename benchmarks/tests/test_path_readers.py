@@ -66,8 +66,9 @@ def test_the_parents_spans_give_no_metric_of_the_way_and_no_error():
     obs = obs_of(recs=recs, spans={"r0": old}, trace=hand_made_idle(),
                  chunks=[{"t0": 50.0}],
                  stats1={"weights_bytes_device_max": 8_000_000_000})
-    new = [m["name"] for m in load_benchmark()["per_layer"][-14:]]
-    assert new[0] == "api_in_p50_ms.sessions"
+    names = [m["name"] for m in load_benchmark()["per_layer"]]
+    first = names.index("api_in_p50_ms.sessions")
+    new = names[first:first + 14]  # PR 38's entries, wherever later ones go
     assert new[-3:] == ["idle_request_on_path_share.sessions",
                         "step_build_s", "step_build_waited_s"]
     theirs_too = {"prefill_p50_ms.sessions": 130.0,
@@ -86,7 +87,8 @@ def test_the_builds_gauges_read_in_seconds_and_move_set_up_in_every_cell():
     assert read("step_build_waited_s", obs) == pytest.approx(5.63025)
     bench = load_benchmark()
     cells = [w["name"] for w in bench["workloads"]]
-    for m in bench["per_layer"][-2:]:
+    by = {m["name"]: m for m in bench["per_layer"]}
+    for m in (by["step_build_s"], by["step_build_waited_s"]):
         assert (m["moves"], m["unit"], m["layer"], m["source"]) == (
             "setup_s", "s", "step program", "program_counter")
         assert sorted(m["workloads"]) == sorted(cells)
@@ -165,9 +167,9 @@ def test_the_new_entries_name_their_cells():
                  "first_byte_unaccounted_p50_ms"):
         m = by[name + ".open"]
         assert (m["workloads"], m["moves"]) == (
-            ["qwen3-4b.chat-steady"], "itl_max_p50_ms")
+            ["qwen3-4b.chat-steady"], "stall8_p50_ms")
     idle = by["idle_request_on_path_share.sessions"]
     assert idle["workloads"] == by["idle_in_host_phases_share.sessions"][
-        "workloads"] and len(idle["workloads"]) == 4
+        "workloads"] and len(idle["workloads"]) >= 4
     assert (idle["layer"], idle["source"], idle["moves"], idle["unit"]) == (
         "device", "device_trace", "tpot_p50_ms.sessions", "%")

@@ -53,6 +53,9 @@ def test_closed_loop_traced(run, monkeypatch):
     assert 0 <= m["device_idle_share.closed"]["value"] < 100
     assert out["device"]["busy_s"] > 0
     assert out["traced_chunks"] == 5
+    # the stages before the judged set-up clock, and the gap beside the stall
+    assert m["backend_up_s"]["value"] >= 0 and m["imports_s"]["value"] > 0
+    assert m["itl_max_p50_ms"]["value"] > 0
     assert 0 < out["device"]["window_s"] < 2.0
     assert {"device_ops", "idle_gaps"} == set(out["breakdown"])
     assert len(out["breakdown"]["device_ops"]) <= 10
@@ -64,7 +67,7 @@ def test_open_loop_end_to_end(run):
     check_line(out, cell, False)
     assert out["attempted"] == 8  # floor(2.0 requests/s x 4 s)
     assert {m["name"] for m in cell.end_to_end} == set(out["metrics"])
-    assert {"ttft_p50_ms", "tpot_p50_ms", "itl_max_p50_ms", "out_tok_s",
+    assert {"ttft_p50_ms", "tpot_p50_ms", "stall8_p50_ms", "out_tok_s",
             "out_tok_s.sessions", "setup_s"} <= set(out["metrics"])
     m = out["metrics"]
     assert m["out_tok_s"]["value"] == m["out_tok_s.sessions"]["value"]
