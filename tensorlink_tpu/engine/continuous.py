@@ -489,6 +489,17 @@ _ENGINE_COUNTERS = (
      "snapshot points passed with no place free in the snapshot pool"),
     ("window_rows_replayed", "tlink_engine_window_rows_replayed_total",
      "cached positions prefilled again between a snapshot and the match"),
+    # ... and of a model whose short-convolution layers hold a tail a slot
+    ("conv_admissions", "tlink_engine_conv_admissions_total",
+     "admissions of a model with short-convolution layers"),
+    ("conv_snapshots_taken", "tlink_engine_conv_snapshots_taken_total",
+     "tail snapshots taken where a prefill chunk ended"),
+    ("conv_snapshots_restored", "tlink_engine_conv_snapshots_restored_total",
+     "admissions that restored a tail snapshot under their prefix hit"),
+    ("conv_snapshots_skipped", "tlink_engine_conv_snapshots_skipped_total",
+     "snapshot points passed with no place free in the snapshot pool"),
+    ("conv_rows_replayed", "tlink_engine_conv_rows_replayed_total",
+     "cached positions prefilled again between a snapshot and the match"),
     # the sampling epilogue (ROADMAP S1): what the packed slots asked of
     # it, per dispatched chunk from the host's own arrays. A sampler call
     # is one _sample_rows over [slots, vocabulary]: each verify row
@@ -727,12 +738,23 @@ class ContinuousEngine:
         # snapshot and replays, or refuses (``stateful_refusals``)
         # ... as does a model whose window layers hold a ring a slot
         # (engine/latent.py): the same rule, counted under ``window_*``
-        self._ring = engine.cfg.ring_window is not None
-        self._stateful = bool(engine.cfg.recurrent) or self._ring
-        self._snap_counts = "window" if self._ring else "state"
-        what = "pages and window rings" if self._ring else (
-            "pages and recurrent states" if self._stateful else
-            "latent pages")
+        # ... and a model whose short-convolution layers hold a tail a
+        # slot: the same rule again, counted under ``conv_*``. One property
+        # says which (``ModelConfig.slot_state``)
+        held = engine.cfg.slot_state
+        self._ring = held == "gqa_window"
+        self._tail = held == "conv"
+        self._stateful = held is not None
+        self._snap_counts, what, states = {
+            None: ("state", "latent pages", ""),
+            "lightning": ("state", "pages and recurrent states",
+                          "recurrent states"),
+            "gqa_window": ("window", "pages and window rings",
+                           "window rings"),
+            "conv": ("conv", "pages and convolution tails",
+                     "convolution tails"),
+        }[held]
+        self._held_what = what
         if self._latent:
             asked = str(kv_quant or "none")
             refusals = (
@@ -745,8 +767,7 @@ class ContinuousEngine:
                 (handoff_after_prefill,
                  f"{what} do not hand off between workers"),
                 (self._stateful and int(tensor_parallel or 1) > 1,
-                 ("window rings" if self._ring else "recurrent states")
-                 + " have no partition specs "
+                 f"{states} have no partition specs "
                  f"(tensor_parallel={tensor_parallel} asked)"),
                 ("sparse" in engine.cfg.layer_kinds and check_sparse(
                     engine.cfg.latent_of("sparse"), int(page_size)), None),
@@ -764,6 +785,9 @@ class ContinuousEngine:
                 "a model whose window layers hold a ring does not draft: a "
                 "rejected draft row would have overwritten the ring's oldest "
                 "page" if self._ring else
+                "a model with short-convolution layers does not draft: a "
+                "rejected draft row would have moved the slot's tail"
+                if self._tail else
                 "a model with recurrent layers does not draft: a rejected "
                 "draft row would have advanced the slot's state")
         if int(prefill_chunk) <= 0:
@@ -880,9 +904,13 @@ class ContinuousEngine:
         if self._stateful and self.prefix is not None:
             # 32 prefill chunks, or an eighth of the context where that
             # is less (a short context still gets its eight)
+            # ... a tail is 74 KB at the published sizes where a state is
+            # 2 MB and a window 12.6: a snapshot wherever a chunk ends
             chunk = self.prefill_chunk
-            stride = int(state_snapshot_stride) or min(
-                32 * chunk, max(self.max_seq_len // 8 // chunk, 1) * chunk)
+            stride = int(state_snapshot_stride) or (
+                chunk if self._tail else min(
+                    32 * chunk,
+                    max(self.max_seq_len // 8 // chunk, 1) * chunk))
             self.snap_stride = -(-stride // self.page_size) * self.page_size
             # ... a window snapshot is 12.6 MB at the published sizes
             # where a state is 2: one a stride and three for two slots
@@ -1868,8 +1896,8 @@ class ContinuousEngine:
             else:
                 self.cache = restore_snapshot(self.cache, self._snaps, *place)
             self._count(f"{self._snap_counts}_snapshots_restored")
-        elif self._ring:
-            return
+        elif self._ring or self._tail:
+            return  # ... and the ragged pass reads zeros before position 0
         else:
             self.cache = zero_state(self.cache, np.int32(slot))
         self._count("admit_device_calls")
@@ -2625,10 +2653,7 @@ class ContinuousEngine:
         never grow the compiled-program set."""
         if self._latent:
             raise PagedUnsupported(
-                "a patterned model's "
-                + ("pages and window rings" if self._ring else
-                   "pages and recurrent states" if self._stateful
-                   else "latent pages")
+                "a patterned model's " + self._held_what
                 + " do not migrate yet: the stream falls back to "
                 "re-prefill on its destination"
             )
@@ -3248,6 +3273,15 @@ class ContinuousEngine:
                 raise AssertionError(
                     f"ring conservation violated: {c.wk.shape[1]} ring "
                     f"pages for {self.max_slots} slots of {c.ring_pages}")
+        if self._tail:
+            # one tail a conv layer and slot, no more
+            c, sc = self.cache, self.cfg.latent_of("conv")
+            want = (self.cfg.layer_kinds.count("conv"), self.max_slots,
+                    sc.tail, sc.width)
+            if c.state.shape != want:
+                raise AssertionError(
+                    f"tail conservation violated: {c.state.shape} for "
+                    f"{want}")
         if self._snaps is not None:
             self._check_snapshot_conservation()
 
@@ -3293,14 +3327,17 @@ class ContinuousEngine:
         registry but stay byte-compatible with the pre-registry dicts
         (test-pinned; see docs/SERVING.md "Telemetry")."""
         out = dict(self.stats)
-        state_bytes = self.cache.state_bytes if self._stateful else 0
+        # the slots' states, or the tails of a model with conv layers
+        held = self.cache.state_bytes if self._stateful else 0
+        state_bytes, tail_bytes = (0, held) if self._tail else (held, 0)
         snap_bytes = 0 if self._snaps is None else (
             self._snaps.size * self._snaps.dtype.itemsize)
         ring_bytes = self.cache.ring_bytes if self._ring else 0
-        # the snapshot pool is the states' or the windows'
-        pool = (snap_bytes, len(self._snap_nodes))  # bytes, places held
-        of_states, of_windows = (
-            ((0, 0), pool) if self._ring else (pool, (0, 0)))
+        # the snapshot pool is the states', the windows' or the tails':
+        # (bytes, places held) under the name its counters carry
+        of_states, of_windows, of_tails = (
+            (snap_bytes, len(self._snap_nodes)) if kind == self._snap_counts
+            else (0, 0) for kind in ("state", "window", "conv"))
         # KV storage mode + occupancy: the capacity math operators size
         # slots-per-chip with (kv_quant="int8" halves kv_page_bytes)
         c = self.cache
@@ -3373,6 +3410,11 @@ class ContinuousEngine:
             "window_ring_bytes": ring_bytes,
             "window_pool_bytes": ring_bytes + of_windows[0],
             "window_snapshots_resident": of_windows[1],
+            # a model whose short-convolution layers hold a tail a slot:
+            # the tails, and the tails and their snapshots together
+            "conv_tail_bytes": tail_bytes,
+            "conv_pool_bytes": tail_bytes + of_tails[0],
+            "conv_snapshots_resident": of_tails[1],
             "spec_refusal": self.spec_refusal,
             "weights_bytes_device_max": max(self.weights_bytes_device),
             "weights_bytes_device_min": min(self.weights_bytes_device),

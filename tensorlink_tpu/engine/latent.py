@@ -64,7 +64,25 @@ is a *snapshot* of the window at a page edge (the ``window - 1`` positions
 before it, :func:`take_window` / :func:`restore_window`, the engine's
 snapshot pool: engine/continuous.py), under the rule of the recurrent
 states. Both kinds, both passes, go through the page walk
-(``gqa_full_attention`` / ``gqa_window_attention``).
+(``gqa_full_attention`` / ``gqa_window_attention``). A ``gqa_full`` kind
+whose heads are half a lane row wide (``head_dim`` 64) keeps a position's
+keys BESIDE its values in one row of 128 (:func:`kv_beside`: the pool
+``k`` holds both, there is no ``v``): the walk then reads the row as key
+and value both, as it reads a latent row, against queries padded with
+zeros, and its output's second half is the attention. A pool 64 wide
+would be padded to whole lane rows a call, a copy that follows the pool's
+capacity (ops/attention.py::_lane_pad).
+
+**Short convolutions** (kind ``conv``, models/base.py::ShortConv) stand
+beside ``gqa_full`` layers. What a slot holds of them is the *tail*: the
+last ``kernel - 1`` positions of the gated stream ``z``, a layer and slot
+(``state`` ``[Lc, S, kernel - 1, width]``, in the activations' dtype). The
+ragged pass convolves a slot's rows behind its carried tail (zeros where
+the slot starts at position 0) and rewrites the tail at the slot's last
+live row; a continuation step is ``kernel`` taps. No page describes the
+tail: a prefix hit restores a snapshot of it (``engine/sala.py``'s
+``take_snapshot`` / ``restore_snapshot``, generic over a state's trailing
+dims) under the rule of the recurrent states.
 """
 
 from __future__ import annotations
@@ -77,7 +95,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..models.base import GQA_KINDS, SALA_KINDS, GqaAttn, LatentAttn, ModelConfig
+from ..models.base import (
+    CONV_KIND, GQA_KINDS, SALA_KINDS, GqaAttn, LatentAttn, ModelConfig,
+)
 from ..models.latent import (
     _rms,
     EXPERT_STACKS,
@@ -85,6 +105,7 @@ from ..models.latent import (
     LATENT_ATTN,
     MOE,
     N_MOE_STATS,
+    SHORT_CONV,
     NEG_INF,
     STEP_STATS,
     WINDOW_ATTN,
@@ -101,6 +122,8 @@ from ..models.latent import (
     Pattern,
     pattern_of,
     rope_by_kind,
+    short_conv_in,
+    short_conv_taps,
     top_k_positions,
 )
 from ..models.quant import matmul as _mm
@@ -122,6 +145,12 @@ FULL_KERNEL = "latent_full_attention"  # ... the full layers' walk, both passes
 FULL_KV_TILE, FULL_ROWS, FULL_GROUP_ROWS = 512, 512, 2048
 # a grouped-query kind's walk (both passes) is the pallas_call
 # ``<kind>_attention`` under the scope ``tlink.<kind>``
+
+
+def kv_beside(ga: GqaAttn) -> bool:
+    """A ``gqa_full`` kind keeps keys beside values in one lane row (module
+    docstring): heads of half a lane row, no window."""
+    return ga.window is None and 2 * ga.head_dim == 128
 
 
 def ring_len(window: int, chunk: int, page: int) -> int:
@@ -165,6 +194,8 @@ class LatentPagedCache:
     v: jax.Array | None = None
     ksum: jax.Array | None = None
     state: jax.Array | None = None  # float32 [Ll, S, H, hd, hd]
+    # ... or, of a model with ``conv`` layers, the slots' tails in the
+    # activations' dtype ``[Lc, S, kernel - 1, width]``
     # ``gqa_window`` layers: keys and values in rings, ``[Lw, 1 + S R,
     # Hkv, page, hd]`` (module docstring); not page pools of the trie
     wk: jax.Array | None = None
@@ -197,8 +228,15 @@ class LatentPagedCache:
             # pages of the slot's table, or the scratch page and a ring a slot
             pages = P if ga.window is None else 1 + max_slots * ring_len(
                 ga.window, min(prefill_chunk, S_max), page_size)
-            shape = (n[kind], pages, ga.n_kv_heads, page_size, ga.head_dim)
+            width = ga.head_dim
+            if kv_beside(ga):  # keys beside values: one pool, no ``v``
+                names, width = names[:1], 2 * width
+            shape = (n[kind], pages, ga.n_kv_heads, page_size, width)
             extra |= {name: jnp.zeros(shape, dt) for name in names}
+        if n.get(CONV_KIND):
+            sc = sizes[CONV_KIND]
+            extra["state"] = jnp.zeros(
+                (n[CONV_KIND], max_slots, sc.tail, sc.width), dt)
 
         def pool(kind, width):
             # no pool for a kind, or a selector, the model has not: a
@@ -238,7 +276,8 @@ class LatentPagedCache:
 
     @property
     def state_bytes(self) -> int:
-        """The lightning layers' states of every slot."""
+        """The lightning layers' states (the conv layers' tails) of every
+        slot."""
         return 0 if self.state is None else (
             self.state.size * self.state.dtype.itemsize)
 
@@ -318,16 +357,22 @@ def unsupported(cfg: ModelConfig) -> str | None:
     each kind in use with its sizes."""
     kinds = set(cfg.layer_kinds)
     sizes = dict(cfg.latent)
-    served = {"full", "sliding"} | set(SALA_KINDS) | set(GQA_KINDS)
+    served = {"full", "sliding"} | set(SALA_KINDS) | set(GQA_KINDS) | {
+        CONV_KIND}
     if not kinds <= served or not kinds <= set(sizes):
         return (f"layer kinds {sorted(kinds)} with sizes for "
                 f"{sorted(sizes)} (served: full, sliding, sparse, "
-                "lightning, gqa_full, gqa_window)")
-    if kinds & set(GQA_KINDS):
-        if kinds - set(GQA_KINDS):
-            return "grouped-query layers beside latent or sparse layers"
+                "lightning, gqa_full, gqa_window, conv)")
+    if kinds & (set(GQA_KINDS) | {CONV_KIND}):
+        if kinds - set(GQA_KINDS) - {CONV_KIND}:
+            return ("grouped-query or short-convolution layers beside "
+                    "latent or sparse layers")
         if "gqa_full" not in kinds:
-            return "window layers without a full layer (no page pool)"
+            return ("window or short-convolution layers without a full "
+                    "layer (no page pool)")
+        if CONV_KIND in kinds and "gqa_window" in kinds:
+            return ("short-convolution layers beside window layers (a "
+                    "slot's snapshot holds a ring or a tail, not both)")
         if sizes["gqa_full"].window is not None:
             return "a window on the gqa_full layers"
         if "gqa_window" in kinds and sizes["gqa_window"].window is None:
@@ -612,22 +657,24 @@ def _gqa_attend(q, kp, vp, li, bt, ga: GqaAttn, ctx: _Ctx, name: str):
     in it costs a row block); ``[S, T, H, hd]``."""
     kw = dict(scale=ga.softmax_scale, window=ga.window)
     kernel = ctx.kernel and kp.dtype == q.dtype
+
+    def rows():  # ``vp`` None: the key rows are the values too
+        kr = kp[li].astype(q.dtype)
+        return kr, (kr if vp is None else vp[li].astype(q.dtype))
+
     if ctx.plan is None:  # a continuation step
         if kernel:
             o = paged_attention(q[:, 0], kp, vp, bt, ctx.att_len, layer=li,
                                 name=name, **kw)
         else:
-            o = paged_attention_ref(q[:, 0], kp[li].astype(q.dtype),
-                                    vp[li].astype(q.dtype), bt, ctx.att_len,
-                                    **kw)
+            o = paged_attention_ref(q[:, 0], *rows(), bt, ctx.att_len, **kw)
         return o[:, None]
     starts = ctx.positions[:, 0]
     if kernel:
         return ragged_paged_attention(q, kp, vp, bt, starts, ctx.n_valid,
                                       layer=li, name=name, **kw)
     return ragged_paged_attention_ref(
-        q, kp[li].astype(q.dtype), vp[li].astype(q.dtype), bt, starts,
-        ctx.n_valid, **kw)
+        q, *rows(), bt, starts, ctx.n_valid, **kw)
 
 
 def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
@@ -640,7 +687,7 @@ def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     ap = lp["attn"]
     with jax.named_scope("attn"):
         h = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
-        q = gqa_qkv(h, ap, ga, *ctx.rope[kind])
+        q = gqa_qkv(h, ap, ga, *ctx.rope[kind], eps=cfg.norm_eps)
         qs, k, v = (_expand(q[n], ctx) for n in ("q", "k", "v"))
     if kind == "gqa_window":
         names, bt = ("wk", "wv"), ctx.ring_bt
@@ -648,11 +695,18 @@ def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     else:
         names, bt = ("k", "v"), ctx.block_tables
         place = (ctx.plan, ctx.write_pg, ctx.write_off)
+    beside = kind == "gqa_full" and kv_beside(ga)
+    if beside:  # one row a position: the key, then the value
+        k, v = jnp.concatenate([k, v], axis=-1), None
+        qs = jnp.pad(qs, ((0, 0),) * 3 + ((0, ga.head_dim),))
     with jax.named_scope("kv_write"):
         kp = _gqa_write(getattr(pools, names[0]), li, k, *place)
-        vp = _gqa_write(getattr(pools, names[1]), li, v, *place)
+        vp = None if beside else _gqa_write(
+            getattr(pools, names[1]), li, v, *place)
     with jax.named_scope(f"tlink.{kind}"):
         o = _gqa_attend(qs, kp, vp, li, bt, ga, ctx, f"{kind}_attention")
+        if beside:
+            o = o[..., ga.head_dim:]
     with jax.named_scope("attn"):
         o = _collect(o, ctx)
         if "gate" in q:
@@ -661,12 +715,46 @@ def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     return added, pools._replace(**{names[0]: kp, names[1]: vp})
 
 
+def _short_conv(x, lp, li, pools: tuple, ctx: _Ctx):
+    """:func:`_attention` for a ``conv`` layer (module docstring): what the
+    gated short convolution adds to ``x``, and the slots' tails as the
+    pass leaves them (layer ``li`` of ``pools.state``)."""
+    cfg = ctx.cfg
+    ap = lp["attn"]
+    tail = pools.state[li]  # [S, kernel - 1, width]
+    n_tail = tail.shape[1]
+    with jax.named_scope(SHORT_CONV):
+        h = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
+        z, gate = short_conv_in(h, ap)
+        if ctx.plan is None:  # a continuation step: ``kernel`` taps
+            zc = jnp.concatenate([tail, z.astype(tail.dtype)], axis=1)
+            c = short_conv_taps(zc, ap["taps"], 1)
+            new = jnp.where(ctx.row_ok[:, :, None], zc[:, 1:], tail)
+        else:
+            # a slot's rows behind its carried tail; no position lies
+            # before a sequence's first
+            begins = (ctx.positions[:, 0] == 0) & (ctx.n_valid > 0)
+            tail = jnp.where(begins[:, None, None], 0, tail)
+            zb = _expand(z, ctx).astype(tail.dtype)  # [S, C, width]
+            zc = jnp.concatenate([tail, zb], axis=1)
+            c = _collect(short_conv_taps(zc, ap["taps"], zb.shape[1]), ctx)
+            # the tail behind the slot's last live row (a slot without
+            # rows keeps its own)
+            at = ctx.n_valid[:, None] + jnp.arange(n_tail)[None, :]
+            new = jnp.take_along_axis(zc, at[:, :, None], axis=1)
+        y = (gate.astype(jnp.float32) * c).astype(x.dtype)
+        added = _mm(y, ap["w_out"])
+    return added, pools._replace(state=pools.state.at[li].set(new))
+
+
 def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     """What the attention of one layer of ``kind`` (layer ``li`` of its
     kind's pools) adds to ``x`` ``[S, T, d]``, its rows written to the
     pools first; returns ``(added, pools)`` with ``pools`` = ``(full,
     index, slide, stats)``."""
     cfg = ctx.cfg
+    if kind == CONV_KIND:
+        return _short_conv(x, lp, li, pools, ctx)
     if kind in SALA_KINDS:
         from . import sala
 
@@ -929,7 +1017,8 @@ __all__ = [
     "FULL_KERNEL", "LatentPagedCache", "Pools",
     "WINDOW_KERNEL", "attention_only",
     "cache_pools",
-    "decode_layers", "layer_loop", "restore_window", "ragged_layers",
+    "decode_layers", "kv_beside", "layer_loop", "restore_window",
+    "ragged_layers",
     "ring_len", "ring_table", "run_layers", "snapshot_pages", "take_window",
     "unsupported", "window_snapshot_pool", "with_pools",
 ]

@@ -214,6 +214,9 @@ class GqaAttn:
     window: int | None = None
     rope_scaling: tuple | None = None
     gate: bool = True
+    # an RMSNorm with a learned weight over the ``head_dim`` dims of every
+    # query and key head, before the rotation
+    qk_norm: bool = False
 
     @property
     def softmax_scale(self) -> float:
@@ -226,7 +229,34 @@ class GqaAttn:
 
     def param_count(self, d: int) -> int:
         q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
-        return d * q + 2 * d * kv + (d * self.n_heads if self.gate else 0) + q * d
+        return (d * q + 2 * d * kv + (d * self.n_heads if self.gate else 0)
+                + (2 * self.head_dim if self.qk_norm else 0) + q * d)
+
+
+@dataclass(frozen=True)
+class ShortConv:
+    """Sizes of a gated short-convolution layer (kind ``"conv"``): ``[B, C,
+    g] = split3(W_in u)``, ``z = B * g``, a depthwise causal convolution of
+    ``kernel`` taps over ``z`` (zeros before position 0), ``y = C * conv``,
+    ``W_out y``; every stream ``width`` wide. What a slot holds of such a
+    layer is the last ``kernel - 1`` positions of ``z``, its *tail*
+    (engine/latent.py): no page describes it."""
+
+    kernel: int
+    width: int
+
+    @property
+    def rope_dim(self) -> int:
+        """No positions: the convolution is causal by order."""
+        return 0
+
+    @property
+    def tail(self) -> int:
+        """Positions of ``z`` a slot carries from one pass to the next."""
+        return self.kernel - 1
+
+    def param_count(self, d: int) -> int:
+        return d * 3 * self.width + self.width * self.kernel + self.width * d
 
 
 # layer kinds whose cache is not a latent row: engine/sala.py serves them
@@ -234,6 +264,9 @@ SALA_KINDS = ("sparse", "lightning")
 # ... and the grouped-query kinds of a model whose layers differ
 # (engine/latent.py): pages under the slot's table, a ring a slot
 GQA_KINDS = ("gqa_full", "gqa_window")
+# ... beside which gated short-convolution layers may stand
+# (engine/latent.py): a tail a slot and layer
+CONV_KIND = "conv"
 
 
 @dataclass(frozen=True)
@@ -332,6 +365,8 @@ class ModelConfig:
     # ``moe_norm_topk``), times ``moe_scale``
     moe_router: str = "softmax"
     moe_norm_topk: bool = True
+    # what ``moe_norm_topk`` adds to the sum it divides by
+    moe_norm_eps: float = 1e-20
     moe_scale: float = 1.0
     # group-limited routing: the published experts lie in ``moe_n_group``
     # groups of consecutive experts, a group scores as its best expert,
@@ -357,9 +392,22 @@ class ModelConfig:
         return bool(self.layer_kinds)
 
     @property
+    def slot_state(self) -> str | None:
+        """What a slot of the engine holds of this model that no page
+        chain describes, by the kind of layer that carries it: the
+        ``"lightning"`` layers' float32 states, the ``"gqa_window"``
+        layers' rings of keys and values, the ``"conv"`` layers' tails;
+        None: pages alone. THE answer to "does a slot hold a state": what
+        reuses or moves a slot of such a model restores a snapshot and
+        replays, or refuses (engine/continuous.py, parallel/planner.py)."""
+        return next((k for k in ("lightning", "gqa_window", CONV_KIND)
+                     if k in self.layer_kinds), None)
+
+    @property
     def recurrent(self) -> bool:
-        """Some layer carries a state that no page chain describes."""
-        return "lightning" in self.layer_kinds
+        """A slot's state is one array a kind (states, tails), snapshotted
+        whole."""
+        return self.slot_state in ("lightning", CONV_KIND)
 
     @property
     def ring_window(self) -> int | None:
@@ -367,7 +415,7 @@ class ModelConfig:
         holds as a ring a slot (kind ``"gqa_window"``); None: no such
         layer. What reuses or moves a slot of such a model restores a
         snapshot of the window, as for a recurrent state."""
-        if "gqa_window" not in self.layer_kinds:
+        if self.slot_state != "gqa_window":
             return None
         return self.latent_of("gqa_window").window
 
@@ -446,7 +494,8 @@ class ModelConfig:
             d * self.n_experts + bias  # router + selection bias
             + (n_experts + self.n_shared_experts) * expert
         )
-        n = 2 * v * d + d  # embedding, untied head, final norm
+        # embedding, head (the embedding again where tied), final norm
+        n = (1 if self.tie_embeddings else 2) * v * d + d
         for i, kind in enumerate(self.layer_kinds):
             n += self.latent_of(kind).param_count(d) + 2 * d
             n += 3 * d * self.d_ff if i < self.n_dense_layers else moe
@@ -471,6 +520,8 @@ def _latent_attn(d: dict):
         return SparseAttn(**d)
     if "q_rank" in d:
         return LatentAttn(**d)
+    if "kernel" in d:
+        return ShortConv(**d)
     return (GqaAttn if "n_kv_heads" in d else LinearAttn)(**d)
 
 

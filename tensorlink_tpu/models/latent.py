@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .base import GqaAttn, LatentAttn, ModelConfig
+from .base import GqaAttn, LatentAttn, ModelConfig, ShortConv
 from .quant import matmul as _mm
 from .transformer import apply_rope, rope_tables
 
@@ -55,6 +55,7 @@ MOE_TILE = 128  # rows of one expert a trip of the expert loop computes
 LATENT_ATTN = "tlink.latent_attn"
 WINDOW_ATTN = "tlink.window_attn"
 INDEX_SELECT = "tlink.index_select"
+SHORT_CONV = "tlink.short_conv"
 MOE = "tlink.moe"
 
 # what a step counts of its own routing and selection, in this order, in
@@ -148,6 +149,17 @@ def _init_tree(key, cfg: ModelConfig, dt) -> dict:
 
     def attn(kind: str, stack):
         la = cfg.latent_of(kind)
+        if isinstance(la, ShortConv):
+            # the operator's projections in the place of an attention's:
+            # ``w_in`` to (B, C, g), the taps ``[kernel, width]`` (tap j
+            # weighs position t - (kernel - 1) + j; of order one over the
+            # kernel, so that z keeps its size), ``w_out``
+            return {
+                "w_in": dense(stack, d, 3 * la.width),
+                "taps": dense(stack, la.kernel, la.width,
+                              scale=la.kernel**-0.5),
+                "w_out": dense(stack, la.width, d),
+            }
         H = la.n_heads
         if isinstance(la, GqaAttn):
             # fan-in scale: unit-RMS inputs give q and k entries of order
@@ -157,6 +169,10 @@ def _init_tree(key, cfg: ModelConfig, dt) -> dict:
                 "wq": dense(stack, d, q), "wk": dense(stack, d, kv),
                 "wv": dense(stack, d, kv),
                 **({"w_g": dense(stack, d, H)} if la.gate else {}),
+                # seeded off one, so that a norm left out is not a no-op
+                **({"q_norm": 1 + dense(stack, la.head_dim, scale=0.1),
+                    "k_norm": 1 + dense(stack, la.head_dim, scale=0.1)}
+                   if la.qk_norm else {}),
                 "wo": dense(stack, q, d),
             }
         p = {
@@ -230,7 +246,9 @@ def _init_tree(key, cfg: ModelConfig, dt) -> dict:
         ),
         "tail": [layer(k, False) for k in pat.tail],
         "final_norm": {"scale": ones((), d)},
-        "lm_head": dense((), d, cfg.vocab_size),
+        # a tied head is the embedding (transformer._logits)
+        **({} if cfg.tie_embeddings
+           else {"lm_head": dense((), d, cfg.vocab_size)}),
     }
 
 
@@ -273,15 +291,18 @@ def rope_by_kind(cfg: ModelConfig, positions: jax.Array) -> dict:
     }
 
 
-def gqa_qkv(h, ap: dict, ga: GqaAttn, cos, sin) -> dict:
+def gqa_qkv(h, ap: dict, ga: GqaAttn, cos, sin, eps: float = 0.0) -> dict:
     """The projections of one grouped-query layer over ``h`` ``[B, T, d]``:
-    ``q`` ``[B, T, H, hd]`` and ``k`` ``[B, T, Hkv, hd]``, the first
-    ``rope_dim`` dims of each head rotated, ``v`` ``[B, T, Hkv, hd]`` and,
-    with a gate, ``gate`` ``[B, T, H]`` (float32)."""
+    ``q`` ``[B, T, H, hd]`` and ``k`` ``[B, T, Hkv, hd]``, each head
+    normalised first where the kind has ``qk_norm`` (``eps``: the
+    model's), the first ``rope_dim`` dims of each head rotated, ``v`` ``[B,
+    T, Hkv, hd]`` and, with a gate, ``gate`` ``[B, T, H]`` (float32)."""
     B, T = h.shape[:2]
     hd = ga.head_dim
     q = _mm(h, ap["wq"]).reshape(B, T, ga.n_heads, hd)
     k = _mm(h, ap["wk"]).reshape(B, T, ga.n_kv_heads, hd)
+    if "q_norm" in ap:
+        q, k = _rms(q, ap["q_norm"], eps), _rms(k, ap["k_norm"], eps)
     out = {
         "q": _rope_prefix(q, cos, sin, ga.rope_dim),
         "k": _rope_prefix(k, cos, sin, ga.rope_dim),
@@ -290,6 +311,23 @@ def gqa_qkv(h, ap: dict, ga: GqaAttn, cos, sin) -> dict:
     if "w_g" in ap:
         out["gate"] = jax.nn.sigmoid(_mm(h, ap["w_g"]).astype(jnp.float32))
     return out
+
+
+def short_conv_in(h, ap: dict):
+    """A short-convolution layer's input side over ``h`` ``[B, T, d]``:
+    ``(z, gate)`` = ``(B * g, C)`` of ``[B, C, g] = split3(h W_in)``, no
+    activation."""
+    b, c, g = jnp.split(_mm(h, ap["w_in"]), 3, axis=-1)
+    return b * g, c
+
+
+def short_conv_taps(zc, taps, n: int):
+    """The depthwise causal convolution over ``zc`` ``[B, tail + n, W]``
+    (a slot's carried tail, then its ``n`` new positions): ``c_t = sum_j
+    taps[j] * zc[t + j]`` as float32 ``[B, n, W]``."""
+    w = taps.astype(jnp.float32)
+    return sum(w[j] * zc[:, j:j + n].astype(jnp.float32)
+               for j in range(w.shape[0]))
 
 
 def latent_qkv(h, ap: dict, la: LatentAttn, eps: float, cos, sin) -> dict:
@@ -565,7 +603,7 @@ def _route(h, mp: dict, cfg: ModelConfig):
             sc, kept = _group_limit(sc, cfg.moe_n_group, cfg.moe_topk_group)
         topw, topi = top_k_few(sc, K)
     if cfg.moe_norm_topk:
-        topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
+        topw = topw / (topw.sum(-1, keepdims=True) + cfg.moe_norm_eps)
     return topi, topw * cfg.moe_scale, kept
 
 
@@ -652,9 +690,10 @@ __all__ = [
     "EXPERT_STACKS", "INDEX_SELECT", "LATENT_ATTN", "MOE", "N_MOE_STATS",
     "STEP_STATS",
     "WINDOW_ATTN",
+    "SHORT_CONV",
     "Pattern", "absorbed_output", "absorbed_query", "attend_absorbed",
     "attend_materialised", "gated_mlp", "gqa_qkv", "index_scores",
     "init_params",
     "kind_counts", "latent_qkv", "moe_mlp", "pattern_of", "rope_by_kind",
-    "route",
+    "route", "short_conv_in", "short_conv_taps",
 ]

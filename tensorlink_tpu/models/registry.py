@@ -15,7 +15,9 @@ from typing import Any, Callable
 
 import jax.numpy as jnp
 
-from .base import GqaAttn, LatentAttn, LinearAttn, ModelConfig, SparseAttn
+from .base import (
+    GqaAttn, LatentAttn, LinearAttn, ModelConfig, ShortConv, SparseAttn,
+)
 
 # tlint: disable=TL006(family registry — populated at import, read-only after)
 _FAMILY_BUILDERS: dict[str, Callable[[dict], ModelConfig]] = {}
@@ -409,6 +411,95 @@ def _laguna(d: dict) -> ModelConfig:
         n_experts=published,
         experts_first=(d.get("expert_group") or {}).get("first_expert", 0),
         experts_held=held if held != published else 0,
+    )
+
+
+# tlint: disable=TL006(read-only table)
+_LFM2_KINDS = {"conv": "conv", "full_attention": "gqa_full"}
+
+
+@register_family("lfm2_moe")
+def _lfm2_moe(d: dict) -> ModelConfig:
+    """LFM2-MoE: gated short-convolution layers (``conv_L_cache`` taps,
+    depthwise, causal) and grouped-query attention layers by
+    ``layer_types``, the attention with an RMSNorm a head on queries and
+    keys before the rotation and no gate; ``num_dense_layers`` leading
+    layers keep the dense MLP, the others route over ``num_experts``
+    sigmoid-scored experts, the best ``num_experts_per_tok`` by score +
+    selection bias (``use_expert_bias``), their scores normalised
+    (``norm_topk_prob``: over the sum + 1e-6) times
+    ``routed_scaling_factor``; no shared expert, every expert held; the
+    head is the embedding unless ``tie_word_embeddings`` says otherwise.
+    ``head_dim``: ``hidden_size / num_attention_heads`` where the keys do
+    not name it. The per-layer list is read at its first
+    ``num_hidden_layers`` entries (a stage of a pipeline holds a run of
+    layers)."""
+    L = d["num_hidden_layers"]
+    types = list(d["layer_types"])[:L]
+    if len(types) != L:
+        raise ValueError(
+            f"lfm2_moe: layer_types names {len(types)} layers, "
+            f"num_hidden_layers {L}")
+    unknown = sorted(set(types) - set(_LFM2_KINDS))
+    if unknown:
+        raise ValueError(
+            f"lfm2_moe: layer_types {unknown} (built: {sorted(_LFM2_KINDS)})")
+    if d.get("conv_bias"):
+        raise ValueError(
+            "lfm2_moe: conv_bias is not built (the operator's three "
+            "projections and its taps carry no bias)")
+    taps = int(d.get("conv_L_cache", 3))
+    if taps < 2:
+        raise ValueError(
+            f"lfm2_moe: conv_L_cache {taps} is not built (two taps or more: "
+            "a slot carries the last conv_L_cache - 1 positions)")
+    n_dense = int(d.get("num_dense_layers", 0))
+    if not 0 < n_dense <= L:
+        raise ValueError(
+            f"lfm2_moe: num_dense_layers {n_dense} of {L} layers is not "
+            "built (the leading layers keep the dense MLP, one or more)")
+    if not d.get("use_expert_bias", True):
+        raise ValueError(
+            "lfm2_moe: use_expert_bias false is not built (the router picks "
+            "by score + selection bias)")
+    if d.get("num_shared_experts") or d.get("n_shared_experts"):
+        raise ValueError("lfm2_moe: a shared expert is not built")
+    hidden, heads = d["hidden_size"], d["num_attention_heads"]
+    hd = int(d.get("head_dim") or hidden // heads)
+    kv = d["num_key_value_heads"]
+    if heads % kv:
+        raise ValueError(
+            f"lfm2_moe: {heads} query heads over {kv} kv heads (built: whole "
+            "groups)")
+    sizes = []
+    if "conv" in types:
+        sizes.append(("conv", ShortConv(kernel=taps, width=hidden)))
+    if "full_attention" in types:
+        sizes.append(("gqa_full", GqaAttn(
+            n_heads=heads, n_kv_heads=kv, head_dim=hd, rope_dim=hd,
+            rope_theta=float(d.get("rope_theta", 1000000.0)), gate=False,
+            qk_norm=True,
+        )))
+    return ModelConfig(
+        family="lfm2_moe",
+        vocab_size=d["vocab_size"],
+        d_model=hidden,
+        n_layers=L,
+        n_heads=heads, n_kv_heads=kv, head_dim=hd,
+        d_ff=d["intermediate_size"],
+        max_seq_len=d.get("max_position_embeddings", 4096),
+        norm_eps=d.get("norm_eps", 1e-5),
+        tie_embeddings=d.get("tie_word_embeddings", True),
+        layer_kinds=tuple(_LFM2_KINDS[t] for t in types),
+        latent=tuple(sizes),
+        n_dense_layers=n_dense,
+        n_experts=d["num_experts"],
+        n_experts_per_tok=d["num_experts_per_tok"],
+        moe_d_ff=d["moe_intermediate_size"],
+        moe_router="sigmoid",
+        moe_norm_topk=bool(d.get("norm_topk_prob", True)),
+        moe_norm_eps=1e-6,
+        moe_scale=float(d.get("routed_scaling_factor", 1.0)),
     )
 
 

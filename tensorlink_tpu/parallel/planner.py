@@ -197,6 +197,11 @@ class MemoryEstimate:
                     win.window, min(PREFILL_BLOCK, seq_len), PAGE) * page
                 snaps = (seq_len // (16 * PREFILL_BLOCK) + 3 * batch // 2) * (
                     snapshot_pages(win.window, PAGE) * page)
+            if n.get("conv"):
+                sc = sizes["conv"]
+                tail = n["conv"] * sc.tail * sc.width * pb
+                rings = batch * tail
+                snaps = (seq_len // PREFILL_BLOCK + 2 * batch) * tail
             return {"pages": int(pages), "states": int(rings),
                     "snapshots": int(snaps)}
         n_sparse = cfg.layer_kinds.count("sparse")
@@ -592,15 +597,17 @@ def plan_sharding(
                 update_mode=training_update_mode(axes, training),
             )
 
-    if cfg.recurrent or cfg.ring_window is not None:
-        # the slots' states (a window layer's ring) have no stage to
-        # follow a layer to: such a model is served whole on one worker,
-        # or not here
+    if cfg.slot_state is not None:
+        # the slots' states (a window layer's ring, a conv layer's tail)
+        # have no stage to follow a layer to: such a model is served whole
+        # on one worker, or not here
         parts = MemoryEstimate.state_parts(cfg, batch, seq_len)
         gb = lambda n: f"{n / 1e9:.2f} GB"  # noqa: E731
-        states, snaps = ("window rings", "window snapshots") if (
-            cfg.ring_window is not None) else (
-            "recurrent states", "state snapshots")
+        states, snaps = {
+            "gqa_window": ("window rings", "window snapshots"),
+            "conv": ("convolution tails", "tail snapshots"),
+            "lightning": ("recurrent states", "state snapshots"),
+        }[cfg.slot_state]
         raise AssignmentError(
             f"{model_name or cfg.family} does not fit one worker: it needs "
             f"{gb(est.total)} (weights {gb(est.params)}, pages of the paged "

@@ -362,6 +362,42 @@ def test_a_tiny_ring_engine():
     assert stats["slot_binds_packed"] == stats["admitted"] + stats["evicted"]
 
 
+def test_a_tiny_tail_engine():
+    """Short-convolution layers with a tail a slot: an admission under a
+    prefix hit restores ONE tail snapshot by a call, one that hits nothing
+    calls nothing (the ragged pass reads zeros before position 0), and
+    the pages bind through the control buffer."""
+    import test_lfm2
+
+    cfg = config_from_hf(test_lfm2.TINY, dtype=jnp.float32)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+
+    def run(cls):
+        eng = GenerationEngine(cfg, params, seq_buckets=(8, 32),
+                               batch_buckets=(1,), max_seq_len=256)
+        ce = cls(eng, max_slots=2, page_size=4, chunk_steps=4,
+                 prefill_chunk=8, state_snapshot_stride=32)
+        rng = np.random.default_rng(6)
+        doc = rng.integers(1, 97, 70).tolist()
+        r0 = ce.submit(doc, max_new_tokens=4, seed=0)
+        ce.run_until_idle()
+        reqs = [r0] + [
+            ce.submit(doc[:66] + rng.integers(1, 97, 5 + i).tolist(),
+                      max_new_tokens=5 + i, seed=1 + i)
+            for i in range(3)
+        ]
+        out, stats = _finish(ce, reqs)
+        return out, stats
+
+    got, stats = run(ContinuousEngine)
+    want, _ = run(_CallsTheDevice)
+    assert got == want and all(got)
+    assert stats["conv_admissions"] == 4
+    assert stats["admit_device_calls"] == (
+        stats["conv_snapshots_restored"]) == 3
+    assert stats["slot_binds_packed"] == stats["admitted"] + stats["evicted"]
+
+
 def test_a_migrated_stream_adopts_through_the_control_buffer(dense):
     """The destination binds the shipped pages on its host table; frozen
     again before any chunk ran there, the slot's length is still the

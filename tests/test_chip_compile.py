@@ -140,6 +140,8 @@ OTHER_HEADS = (
     ("qwen2p5-7b-tp4shard", (7, 1, 128)),
     ("laguna-full", (48, 8, 128)),
     ("laguna-sliding", (72, 8, 128)),
+    # lfm2's heads of 64 lie beside their values in rows of 128: the walk
+    # sees (32, 8, 128) with one pool (the shared_kv cases below)
 )
 
 
@@ -602,6 +604,64 @@ def test_laguna_step_fits_one_v5e_with_both_walks_in_both_passes(v5e):
         copies = re.findall(
             rf"^\s*\S+ = \w+{re.escape(shape)}\S* copy\(.*$", text, re.M)
         assert not copies, copies[:2]
+    whiles = [ln for ln in text[text.index("ENTRY"):].splitlines()
+              if " while(" in ln]
+    assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)
+            for ln in whiles] == list(STEP_PHASES)
+
+
+def test_lfm2_step_fits_one_v5e_and_pads_no_pool(v5e):
+    """The benchmark's ``lfm2-8b-a1b-l12`` at its published widths (layers
+    0-11, 32 experts held whole, 16 slots x 16,384 positions of bf16 pages
+    for the 3 attention layers, a tail of 2 positions a slot for the 9 conv
+    layers): the step compiles for one described v5e with the walk at a
+    head of 64 in both passes, keys beside values in rows of 128 lanes, so
+    that NOTHING pads or copies the pool (``ops/attention.py::_lane_pad``
+    would a pool 64 wide, a call and layer), and weights + pool +
+    temporaries fit the chip. ~90 s: the one program the cell serves
+    from."""
+    import json
+    import re
+    from pathlib import Path
+
+    from tensorlink_tpu.engine.latent import LatentPagedCache
+    from tensorlink_tpu.engine.paged import STEP_PHASES, paged_ragged_step
+    from tensorlink_tpu.models.registry import config_from_hf
+    from tensorlink_tpu.models.transformer import init_params
+
+    hf = json.loads((Path(__file__).parent.parent / "benchmarks" / "configs"
+                     / "lfm2-8b-a1b-l12.json").read_text())
+    cfg = config_from_hf(hf)
+    slots = hf["deployment"]["ml"]["cont_max_slots"]
+    params = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: LatentPagedCache.init(
+        cfg, slots, page_size=PAGE, max_len=hf["deployment"]["seq_len"]))
+    assert cache.k.shape == (3, 1 + 16 * 1024, 8, 16, 128) and cache.v is None
+    assert cache.state.shape == (9, 16, 2, 2048)
+    place = _on(v5e)
+    ops = _packed_operands(cfg, params, cache, place, place)
+    compiled = paged_ragged_step.lower(*ops, cfg, 8, 1, True).compile()
+    text = compiled.as_text()
+    assert text.count("gqa_full_attention") >= 2  # a call a layer and pass
+    ma = compiled.memory_analysis()
+    weights = _nbytes(ops[0])
+    # bf16 but the float32 selection bias: 2 more bytes x 32 x 10 layers
+    assert weights == 2 * cfg.held_param_count() + 2 * 32 * 10
+    pool = _nbytes(cache.k)
+    assert 1.6e9 < pool < 1.62e9 and _nbytes(cache.state) == 16 * 73_728
+    print(f"lfm2-8b-a1b-l12 on a described v5e: arguments "
+          f"{ma.argument_size_in_bytes / 1e9:.3f} GB, temp "
+          f"{ma.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"{text.count('tpu_custom_call')} kernel calls")
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM, ma
+    assert ma.temp_size_in_bytes < pool, ma
+    # one layer's pool or the stack, copied or padded
+    for shape in (cache.k.shape, cache.k.shape[1:], (1,) + cache.k.shape[1:]):
+        shape = "[" + ",".join(map(str, shape)) + "]"
+        moved = re.findall(
+            rf"^\s*\S+ = \w+{re.escape(shape)}\S* (?:copy|pad)\(.*$", text,
+            re.M)
+        assert not moved, moved[:2]
     whiles = [ln for ln in text[text.index("ENTRY"):].splitlines()
               if " while(" in ln]
     assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)

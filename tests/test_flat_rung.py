@@ -69,6 +69,9 @@ FAMILIES = {
                dict(page_size=4, state_snapshot_stride=32)),
     "sala": (lambda: _hf("test_sala"),
              dict(page_size=4, state_snapshot_stride=32)),
+    # a conv layer's rows leave the flat list for the block and come back
+    "lfm2": (lambda: _hf("test_lfm2"),
+             dict(page_size=4, state_snapshot_stride=32)),
 }
 
 
@@ -489,8 +492,9 @@ def test_build_steps_builds_every_rung_and_serving_builds_none():
 @pytest.mark.parametrize(
     "module,name,has",
     [("test_latent", "TINY_DS", True), ("test_latent", "TINY", False),
-     ("test_laguna", "TINY", False), ("test_sala", "TINY", False)],
-    ids=["deepseek", "dots3", "laguna", "sala"],
+     ("test_laguna", "TINY", False), ("test_sala", "TINY", False),
+     ("test_lfm2", "TINY", False)],
+    ids=["deepseek", "dots3", "laguna", "sala", "lfm2"],
 )
 def test_an_engine_builds_the_rung_for_a_pass_of_one_layer_body(
         module, name, has):

@@ -257,6 +257,9 @@ LEGACY_ENGINE_KEYS = (
     "window_admissions", "window_snapshots_taken",
     "window_snapshots_restored", "window_snapshots_skipped",
     "window_rows_replayed",
+    # ... and about the tails of a model with short-convolution layers
+    "conv_admissions", "conv_snapshots_taken", "conv_snapshots_restored",
+    "conv_snapshots_skipped", "conv_rows_replayed",
     # the sampling epilogue (ROADMAP S1): calls, those that sorted, and
     # the verify walk's length against the rows the program holds
     "sampler_calls", "sampler_calls_sampled",
@@ -463,11 +466,52 @@ def test_a_counter_metric_reads_keys_the_engine_reports(path):
     counters = {c[0] for c in continuous._ENGINE_COUNTERS}
     gauges = {"latent_pool_bytes", "weights_bytes_device_max",
               "step_build_ms", "step_build_waited_ms", "state_pool_bytes",
-              "window_pool_bytes", "prefix_evictions"}  # serving_snapshot()'s own
+              "window_pool_bytes", "conv_pool_bytes",
+              "prefix_evictions"}  # serving_snapshot()'s own
     assert keys and set(keys) <= counters | gauges, set(keys) - counters
     if path.name.startswith("narrow_block_share"):
         assert (spec["num"], spec["den"], spec["scale"]) == (
             ["ragged_blocks_narrow"], ["ragged_blocks"], 100)
+
+
+def _benchmark() -> dict:
+    import json
+
+    return json.loads((REPO / "BENCHMARK.json").read_text())
+
+
+def _reported_by(bench: dict) -> dict:
+    """``{cell: end-to-end metrics it reports}`` (an entry without
+    ``workloads`` is every cell's)."""
+    cells = [w["name"] for w in bench["workloads"]]
+    return {c: {m["name"] for m in bench["end_to_end"]
+                if c in m.get("workloads", cells)} for c in cells}
+
+
+def test_every_end_to_end_entry_names_cells_that_exist():
+    """A judged metric's ``workloads`` are cells of ``BENCHMARK.json``, and
+    every cell is judged by a latency or a rate besides ``setup_s``: a new
+    cell left out of the judged lists would be measured by set-up alone."""
+    bench = _benchmark()
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["end_to_end"]:
+        assert set(m.get("workloads", cells)) <= cells, m["name"]
+    for cell, names in _reported_by(bench).items():
+        assert "setup_s" in names and names - {"setup_s"}, cell
+
+
+@pytest.mark.parametrize(
+    "metric", _benchmark()["per_layer"], ids=lambda m: m["name"])
+def test_a_per_layer_metric_moves_what_its_cells_report(metric):
+    """``moves`` names an end-to-end metric, and each cell of the metric's
+    ``workloads`` (every cell, without the key) reports it: what refuses
+    a new cell wired into the wrong lists."""
+    bench = _benchmark()
+    reported = _reported_by(bench)
+    assert metric["moves"] in {m["name"] for m in bench["end_to_end"]}
+    for cell in metric.get("workloads", list(reported)):
+        assert cell in reported, (metric["name"], cell)
+        assert metric["moves"] in reported[cell], (metric["name"], cell)
 
 
 def _span_metric_files() -> list[Path]:
