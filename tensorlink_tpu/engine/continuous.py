@@ -22,8 +22,9 @@ decodes at all. The block is packed at the narrowest of at most two
 widths that holds the chunk's longest grant (``block_widths``: a page or
 two when nobody prefills, else ``prefill_chunk``), a ``prefill_chunk``-wide
 block whose live rows fit ``flat_rows`` runs the flat rung (the ragged
-pass computes the rows that carry a token, ``_block_width``), and the step
-is compiled once a rung. (The legacy two-program schedule — ≤1 prefill chunk per
+pass computes the rows that carry a token, ``_block_width``; a patterned
+model's one wide program instead tiles its row list by the chunk's live
+rows, ``tiled``), and the step is compiled once a rung. (The legacy two-program schedule — ≤1 prefill chunk per
 mid-prefill slot before a separate decode chunk — and the monolithic
 dense-prefill admission were retired after their one-release fallback
 window; ``prefill_chunk`` must be ≥ 1.) Finished slots promote their
@@ -89,7 +90,7 @@ from ..core.trace import (
     first_token_stamp,
     get_tracer,
 )
-from ..models.sala import check_sparse
+from ..models.sala import check_sparse, is_sala
 from ..models.transformer import tp_partition_specs, tp_shardable
 from ..parallel.mesh import serving_mesh
 from .generate import GenerationEngine
@@ -109,8 +110,10 @@ from .paged import (
     paged_decode_step,
     paged_ragged_step,
     pages_needed,
+    row_tile,
     scatter_page,
     set_counts_row,
+    tiled_rows,
     tp_cache_specs,
     tp_gather_costs,
     unpack_results,
@@ -388,7 +391,7 @@ _ENGINE_COUNTERS = (
      "rows of the packed block that carried a token"),
     ("ragged_rows_computed", "tlink_engine_ragged_rows_computed_total",
      "rows the ragged pass computed position-wise (the flat rung's row "
-     "count, else slots x the width that ran)"),
+     "count, the tiled pass's live tiles, else slots x the width that ran)"),
     # the width ladder (ROADMAP S5): a chunk whose longest grant fits the
     # narrow width packs a block that wide, every other one prefill_chunk
     ("ragged_blocks", "tlink_engine_ragged_blocks_total",
@@ -998,25 +1001,40 @@ class ContinuousEngine:
             if narrow < self.prefill_chunk and not self._latent
             else (self.prefill_chunk,)
         )
-        # the prefill_chunk-wide geometry has two programs: the full one
-        # computes the block's rows where they lie, the flat rung the
-        # chunk's live rows as one list of flat_rows (paged.FlatRows; a
-        # function of the shapes alone: a full grant beside every slot's
-        # decode row and drafts, or a quarter of the block). A chunk takes
-        # it when its rows fit (``_block_width``). 0: no such rung: the
-        # block is no larger, or the model's pass holds layer bodies of
-        # more than one kind. A second wide program of those is a second
-        # set-up: built behind the first requests it cost dots3's set-up
-        # 7.5-10.9 s of 45 and laguna's 3.0-7.8 of 43 (their programs take
-        # 5-8 s to trace and lower and 5-6 s to fetch, and the document
-        # that could hide it fills in 5-7 s), and MiniCPM-SALA's takes 22
-        # s to lower and 1.4 GB beside 14.1 (my chip runs, PR 45; PERF.md
-        # section 6). The step serves the rung for every kind of layer
-        # all the same (tests/test_flat_rung.py), for when it costs less
-        one_body = len(set(self.cfg.layer_kinds)) <= 1
-        self.flat_rows = flat_rung_rows(
-            self.max_slots, self.prefill_chunk, self.spec_width
-        ) if one_body else 0
+        # the prefill_chunk-wide pass has three shapes, and the engine
+        # picks by what it sees of the model's layers (``flat_rows``):
+        # - ladder and static rung (a pass of ONE layer body: dense,
+        #   DeepSeek-V2): two programs, the full one computes the block's
+        #   rows where they lie, the flat rung the chunk's live rows as
+        #   one list of flat_rows (paged.FlatRows; a function of the
+        #   shapes alone: a full grant beside every slot's decode row and
+        #   drafts, or a quarter of the block; 0 where the block is no
+        #   larger). A chunk takes the rung when its rows fit
+        #   (``_block_width``);
+        # - tiled (layer bodies of more than one kind, all of them
+        #   engine/latent.py's own: dots3, Laguna, LFM2): ONE program,
+        #   whose row list holds the whole block (``tiled_rows``) and
+        #   whose position-wise work runs over row tiles as far as the
+        #   chunk's live rows reach, a trip count that is data
+        #   (``FlatRows.by_tile``). A second wide program of several
+        #   bodies is a second set-up: built behind the first requests
+        #   it cost dots3's set-up 7.5-10.9 s of 45 and laguna's 3.0-7.8
+        #   of 43 (my chip runs, PR 45; PERF.md section 6);
+        # - full (a model of sparse / lightning layers, engine/sala.py's
+        #   loop over runs of one kind): one program over the block as it
+        #   lies: its second program takes 22 s to lower and 1.4 GB
+        #   beside 14.1, and its pass is given no bound yet (ROADMAP
+        #   S5(e)).
+        # The step serves the static rung for every kind of layer all
+        # the same (tests/test_flat_rung.py), for when it costs less
+        kinds = set(self.cfg.layer_kinds)
+        if len(kinds) <= 1:
+            self.flat_rows = flat_rung_rows(
+                self.max_slots, self.prefill_chunk, self.spec_width)
+        elif is_sala(self.cfg):
+            self.flat_rows = 0
+        else:
+            self.flat_rows = tiled_rows(self.max_slots, self.prefill_chunk)
         # a width is packed only once its program is built. build_steps
         # (what a server calls before traffic) leaves the narrow program's
         # compile running on a thread, here, while the wide one serves;
@@ -3682,8 +3700,14 @@ class ContinuousEngine:
         return (
             tuple((w, 0) for w in self.block_widths[:-1])
             + (((C, self.flat_rows),) if self.flat_rows else ())
-            + ((C, 0),)
+            + (() if self.tiled else ((C, 0),))
         )
+
+    @property
+    def tiled(self) -> bool:
+        """The wide pass is the tiled one: its row list holds the whole
+        block, so it is the one wide program and every chunk's."""
+        return self.flat_rows >= self.max_slots * self.prefill_chunk
 
     # tlint: hot-path
     def _block_width(self, n_valid) -> tuple[int, int]:
@@ -3701,7 +3725,9 @@ class ContinuousEngine:
             self._join_build()  # what the build raised is raised here
         longest = int(n_valid.max())
         width = next(w for w in self.block_widths if longest <= w)
-        if width == C and 0 < int(n_valid.sum()) <= self.flat_rows:
+        if width == C and (
+            self.tiled or 0 < int(n_valid.sum()) <= self.flat_rows
+        ):
             return C, self.flat_rows
         return width, 0
 
@@ -3838,16 +3864,21 @@ class ContinuousEngine:
                 np.full_like(ctx, self.cache.pages_per_slot * self.page_size)
             ))
 
-    def lower_step(self, width: int | None = None, *, flat: bool = False):
+    def lower_step(self, width: int | None = None, *,
+                   flat: bool | None = None):
         """The step program lowered at this engine's own shapes and
         placement, not run: what ``chip_smoke.py`` reads to prove the
         Pallas kernel (``tpu_custom_call``) and, sharded, the collectives
         are in the program that serves. One program a rung of ``rungs``:
         ``width`` names the block's (the widest when not given) and
-        ``flat`` the flat rung of the widest. Call it on an idle
-        engine."""
+        ``flat`` the flat rung of the widest (not given: what serves a
+        block with every row live, the tiled pass where the engine has
+        it, else the full program; False is the full program, which a
+        tiled engine never runs). Call it on an idle engine."""
         S = self.max_slots
         C = self.block_widths[-1] if width is None else int(width)
+        if flat is None:
+            flat = self.tiled and C == self.block_widths[-1]
         if C not in self.block_widths:
             raise ValueError(
                 f"width {C} is not one of this engine's {self.block_widths}"
@@ -4060,8 +4091,12 @@ class ContinuousEngine:
                         self.flush_stream()  # no step follows to hide it
                 with _Phase(ph, "post"):
                     n_rows = int(n_valid.sum())
-                    # what the pass computed position-wise
+                    # what the pass computed position-wise (the tiled
+                    # pass: the tiles its live rows reach into)
                     rows_computed = flat or blk.size
+                    if flat and self.tiled:
+                        tile = row_tile(*blk.shape)
+                        rows_computed = -(-n_rows // tile) * tile
                     self._count("ragged_rows_valid", n_rows)
                     self._count("ragged_rows_computed", rows_computed)
                     self._count("ragged_blocks")

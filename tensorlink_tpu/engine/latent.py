@@ -446,6 +446,25 @@ def _collect(a, ctx: _Ctx):
     return a if ctx.rows is None else ctx.rows.collect(a)
 
 
+def _by_tile(fn, ctx: _Ctx, *xs):
+    """Position-wise ``fn`` over ``xs`` in ``x``'s layout: at once, or,
+    where the ragged pass bounds its row list by the chunk's live rows,
+    tile by tile as far as they reach (``paged.FlatRows.by_tile``)."""
+    return fn(*xs) if ctx.rows is None else ctx.rows.by_tile(fn, *xs)
+
+
+def _attn_out(o, gate, ap, dtype, ctx: _Ctx):
+    """What a layer's attention adds: the heads' outputs ``o`` in ``x``'s
+    layout, gated where the kind has a gate, through ``wo``."""
+
+    def out(o, *gate):
+        if gate:
+            o = (o.astype(jnp.float32) * gate[0][..., None]).astype(dtype)
+        return _mm(o.reshape(o.shape[:2] + (-1,)), ap["wo"])
+
+    return _by_tile(out, ctx, o, *(() if gate is None else (gate,)))
+
+
 def _write(pool, li, rows, ctx: _Ctx):
     """``rows`` ``[S, T, W]`` into layer ``li`` of ``pool`` through the one
     write path's targets: a block page by page, a single row scattered."""
@@ -546,7 +565,9 @@ def _walk_attend(q, pool, li, ap, la: LatentAttn, ctx: _Ctx):
     T = ctx.positions.shape[1]
     bt, scale = ctx.block_tables, la.softmax_scale
     # the absorbed query is position-wise: over x's rows, flat or not
-    qa = absorbed_query(q["q_n"], q["q_r"], ap, la)  # [S, T, H, W]
+    qa = _by_tile(  # [S, T, H, W]
+        lambda q_n, q_r: absorbed_query(q_n, q_r, ap, la), ctx,
+        q["q_n"], q["q_r"])
     if ctx.rows is None:
         first_q, per_slot, rows_of = (lambda: qa[:, 0]), qa, (lambda a: a)
     else:  # a slot's rows are gathered where its block is walked
@@ -683,11 +704,14 @@ def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     layer's to the slot's ring."""
     cfg = ctx.cfg
     ga = cfg.latent_of(kind)
-    S, T, d = x.shape
     ap = lp["attn"]
-    with jax.named_scope("attn"):
+
+    def project(x, cos, sin):
         h = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
-        q = gqa_qkv(h, ap, ga, *ctx.rope[kind], eps=cfg.norm_eps)
+        return gqa_qkv(h, ap, ga, cos, sin, eps=cfg.norm_eps)
+
+    with jax.named_scope("attn"):
+        q = _by_tile(project, ctx, x, *ctx.rope[kind])
         qs, k, v = (_expand(q[n], ctx) for n in ("q", "k", "v"))
     if kind == "gqa_window":
         names, bt = ("wk", "wv"), ctx.ring_bt
@@ -708,10 +732,7 @@ def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         if beside:
             o = o[..., ga.head_dim:]
     with jax.named_scope("attn"):
-        o = _collect(o, ctx)
-        if "gate" in q:
-            o = (o.astype(jnp.float32) * q["gate"][..., None]).astype(x.dtype)
-        added = _mm(o.reshape(S, T, -1), ap["wo"])
+        added = _attn_out(_collect(o, ctx), q.get("gate"), ap, x.dtype, ctx)
     return added, pools._replace(**{names[0]: kp, names[1]: vp})
 
 
@@ -724,8 +745,8 @@ def _short_conv(x, lp, li, pools: tuple, ctx: _Ctx):
     tail = pools.state[li]  # [S, kernel - 1, width]
     n_tail = tail.shape[1]
     with jax.named_scope(SHORT_CONV):
-        h = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
-        z, gate = short_conv_in(h, ap)
+        z, gate = _by_tile(lambda x: short_conv_in(
+            _rms(x, lp["ln1"]["scale"], cfg.norm_eps), ap), ctx, x)
         if ctx.plan is None:  # a continuation step: ``kernel`` taps
             zc = jnp.concatenate([tail, z.astype(tail.dtype)], axis=1)
             c = short_conv_taps(zc, ap["taps"], 1)
@@ -742,8 +763,9 @@ def _short_conv(x, lp, li, pools: tuple, ctx: _Ctx):
             # rows keeps its own)
             at = ctx.n_valid[:, None] + jnp.arange(n_tail)[None, :]
             new = jnp.take_along_axis(zc, at[:, :, None], axis=1)
-        y = (gate.astype(jnp.float32) * c).astype(x.dtype)
-        added = _mm(y, ap["w_out"])
+        added = _by_tile(lambda gate, c: _mm(
+            (gate.astype(jnp.float32) * c).astype(x.dtype), ap["w_out"]),
+            ctx, gate, c)
     return added, pools._replace(state=pools.state.at[li].set(new))
 
 
@@ -763,12 +785,14 @@ def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         return _gqa_attention(x, lp, kind, li, pools, ctx)
     la = cfg.latent_of(kind)
     full, index, slide, stats = pools[:4]
-    S, T, d = x.shape
     ap = lp["attn"]
-    cos, sin = ctx.rope[kind]
-    with jax.named_scope("attn"):
+
+    def project(x, cos, sin):
         h = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
-        q = latent_qkv(h, ap, la, cfg.norm_eps, cos, sin)
+        return latent_qkv(h, ap, la, cfg.norm_eps, cos, sin)
+
+    with jax.named_scope("attn"):
+        q = _by_tile(project, ctx, x, *ctx.rope[kind])
     with jax.named_scope("kv_write"):
         if kind == "sliding":
             slide = _write(slide, li, _expand(q["row"], ctx), ctx)
@@ -784,10 +808,7 @@ def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         stats = stats.at[N_MOE_STATS:].add(
             jnp.stack([kept, scored]).astype(jnp.int32))
     with jax.named_scope("attn"):
-        o = _collect(o, ctx)
-        if "gate" in q:
-            o = (o.astype(jnp.float32) * q["gate"][..., None]).astype(x.dtype)
-        added = _mm(o.reshape(S, T, -1), ap["wo"])
+        added = _attn_out(_collect(o, ctx), q.get("gate"), ap, x.dtype, ctx)
     return added, pools._replace(
         full=full, index=index, slide=slide, stats=stats)
 
@@ -797,19 +818,30 @@ def _layer(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     cfg = ctx.cfg
     S, T, d = x.shape
     added, pools = _attention(x, lp, kind, li, pools, ctx)
-    with jax.named_scope("attn"):
-        x = x + added
-    h = _rms(x, lp["ln2"]["scale"], cfg.norm_eps)
-    if "mlp" in lp:
+
+    def normed(x, added):
+        with jax.named_scope("attn"):
+            x = x + added
+        return x, _rms(x, lp["ln2"]["scale"], cfg.norm_eps)
+
+    def dense(x, added):
+        x, h = normed(x, added)
         with jax.named_scope("mlp"):
-            return x + gated_mlp(h, lp["mlp"]), pools
+            return x + gated_mlp(h, lp["mlp"])
+
+    if "mlp" in lp:
+        return _by_tile(dense, ctx, x, added), pools
+    x, h = _by_tile(normed, ctx, x, added)
     with jax.named_scope(MOE):
+        # the router and the shared expert by tile; the routed experts'
+        # tiles follow the experts' rows, whatever the list holds
         y, ms = moe_mlp(
-            h.reshape(S * T, d), lp["moe"], cfg, ctx.x_ok.reshape(-1)
+            h.reshape(S * T, d), lp["moe"], cfg, ctx.x_ok.reshape(-1),
+            None if ctx.rows is None else partial(ctx.rows.by_tile, axis=0),
         )
         # each adds up over layers and steps
         pools = pools._replace(stats=pools.stats.at[:N_MOE_STATS].add(ms))
-    return x + y.reshape(S, T, d), pools
+    return _by_tile(jnp.add, ctx, x, y.reshape(S, T, d)), pools
 
 
 # ---------------------------------------------------------------------------

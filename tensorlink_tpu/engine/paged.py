@@ -1397,6 +1397,25 @@ def flat_rung_rows(slots: int, chunk: int, spec_width: int = 1) -> int:
     return rows if rows < slots * chunk else 0
 
 
+ROW_TILE = 512  # rows of one trip of the tiled pass, at most
+
+
+def row_tile(slots: int, chunk: int) -> int:
+    """Rows of one trip of the tiled pass over a ``[slots, chunk]``
+    geometry, from the shapes alone: a quarter of the block in whole
+    eights (a block with every row live streams a layer's position-wise
+    weights four times), at most ``ROW_TILE`` (where a trip's dots hide
+    the next stream of the weights on a v5e: PERF.md section 6, PR 53)."""
+    return min(ROW_TILE, max(slots * chunk // 32 * 8, 8))
+
+
+def tiled_rows(slots: int, chunk: int) -> int:
+    """Rows of the tiled pass's row list: what holds the whole block, in
+    whole tiles of :func:`row_tile`."""
+    tile = row_tile(slots, chunk)
+    return -(-slots * chunk // tile) * tile
+
+
 class FlatRows(NamedTuple):
     """The ragged pass's live rows as one token-major list of ``R`` rows:
     slot 0's grant (or its decode row and drafts), then slot 1's, back to
@@ -1405,15 +1424,20 @@ class FlatRows(NamedTuple):
     buffer holds the ``[S, C]`` block as ever. Position-wise work runs
     over ``[1, R, ...]``; the two seams that need a slot (the attention
     call, the page write) take ``expand``'s ``[S, C, ...]`` and hand
-    their result to ``collect``."""
+    their result to ``collect``. With a ``tile`` the list holds the whole
+    block and the row count is data: position-wise work goes through
+    :meth:`by_tile`, ``trips`` tiles of it."""
 
     to_flat: jax.Array  # int32 [S, C]: block row -> flat row; R: no token
     slot: jax.Array  # int32 [R]: flat row -> its slot ...
     col: jax.Array  # int32 [R]: ... and its column in the block
     live: jax.Array  # bool [R]: the flat row carries a token
+    trips: jax.Array | None = None  # int32: tiles that hold a live row
+    tile: int = 0  # rows of a tile (0: the list is computed whole)
 
     @classmethod
-    def of(cls, n_valid: jax.Array, C: int, R: int) -> "FlatRows":
+    def of(cls, n_valid: jax.Array, C: int, R: int,
+           tile: int = 0) -> "FlatRows":
         S = n_valid.shape[0]
         end = jnp.cumsum(n_valid)
         first = end - n_valid
@@ -1422,7 +1446,33 @@ class FlatRows(NamedTuple):
         r = jnp.arange(R)
         slot = jnp.minimum((r[:, None] >= end[None, :]).sum(-1), S - 1)
         col = jnp.clip(r - first[slot], 0, C - 1)
-        return cls(to_flat, slot, col, r < end[-1])
+        trips = (end[-1] + tile - 1) // tile if tile else None
+        return cls(to_flat, slot, col, r < end[-1], trips, tile)
+
+    def by_tile(self, fn, *xs, axis: int = 1):
+        """``fn(*xs)`` for a position-wise ``fn`` (row ``r`` of every
+        result reads row ``r`` of each of ``xs`` and nothing else of them;
+        rows lie along ``axis``: ``[1, R, ...]``; a tree of row lists
+        back): the list whole without a ``tile``, else tile by tile over
+        the ``trips`` tiles that hold a live row, a loop whose trip count
+        is data. The rows past them are not computed (zeros) and nothing
+        reads them."""
+        if not self.tile:
+            return fn(*xs)
+        T, R = self.tile, self.live.shape[0]
+        cut = partial(jax.lax.dynamic_slice_in_dim, slice_size=T, axis=axis)
+        rows = lambda a, n: a.shape[:axis] + (n,) + a.shape[axis + 1:]
+        like = jax.eval_shape(
+            fn, *(jax.ShapeDtypeStruct(rows(a, T), a.dtype) for a in xs))
+
+        def trip(i, out):
+            return jax.tree.map(
+                lambda o, y: jax.lax.dynamic_update_slice_in_dim(
+                    o, y, i * T, axis),
+                out, fn(*(cut(a, i * T) for a in xs)))
+
+        out = jax.tree.map(lambda s: jnp.zeros(rows(s, R), s.dtype), like)
+        return jax.lax.fori_loop(0, self.trips, trip, out)
 
     def take(self, a: jax.Array, idx: jax.Array) -> jax.Array:
         """Rows ``idx`` of the flat ``a`` ``[1, R, ...]``; zeros where
@@ -1507,9 +1557,18 @@ def _ragged_pass(
 
         rows, tok, positions = None, blk, pos
         if flat_rows:
-            rows = FlatRows.of(n_valid, C, int(flat_rows))
+            # a patterned model's list that holds the whole block is
+            # computed tile by tile, as far as the chunk's live rows reach
+            tile = row_tile(S, C) if (
+                cfg.patterned and flat_rows >= S * C) else 0
+            rows = FlatRows.of(n_valid, C, int(flat_rows), tile)
             tok = jnp.where(rows.live, blk[rows.slot, rows.col], 0)[None]
             positions = (starts[rows.slot] + rows.col)[None]  # [1, R]
+        tiled = rows is not None and rows.tile > 0
+        # (the tiled pass embeds its whole list too, a gather: a loop over
+        # the tokens alone is invariant of ``ragged_layers``' one loop, the
+        # compiler moves it in front of it, and a trace tells the phases
+        # apart by the order of the top-level loops)
         x = _embed_tokens(params, tok, cfg)  # [S, C, d], or [1, R, d]
         if cfg.pos == "learned":
             x = x + params["embed"]["pos"][positions].astype(cfg.dtype)
@@ -1532,7 +1591,8 @@ def _ragged_pass(
                     tp_quant, rows,
                 ),
             )
-        x = _final_norm(x, params, cfg)
+        if not tiled:  # (the tiled pass: over the rows the head reads)
+            x = _final_norm(x, params, cfg)
     # verification rows: the last spec_width rows of each slot's valid
     # span — base = n_valid - 1 - n_spec, so a non-speculating slot
     # (n_spec 0: plain decode, completing prefill, idle) gathers exactly
@@ -1551,6 +1611,8 @@ def _ragged_pass(
             h_v = x[jnp.arange(S)[:, None], gather]  # [S, W, d]
         else:  # the same block rows, where the flat list holds them
             h_v = rows.take(x, jnp.take_along_axis(rows.to_flat, gather, 1))
+        if tiled:  # position-wise: the norm of a row taken is the row's
+            h_v = _final_norm(h_v, params, cfg)
         logits_v = _logits(params, h_v, cfg, tp_axis, tp_quant)  # [S, W, V]
     return logits_v, base, kv_new
 
@@ -2224,6 +2286,8 @@ __all__ = [
     "make_tp_ragged_step",
     "FlatRows",
     "flat_rung_rows",
+    "row_tile",
+    "tiled_rows",
     "pack_control",
     "unpack_control",
     "unpack_results",

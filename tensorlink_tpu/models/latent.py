@@ -607,17 +607,21 @@ def _route(h, mp: dict, cfg: ModelConfig):
     return topi, topw * cfg.moe_scale, kept
 
 
-def moe_mlp(h, mp: dict, cfg: ModelConfig, valid):
+def moe_mlp(h, mp: dict, cfg: ModelConfig, valid, rowwise=None):
     """The expert layer over rows ``h`` ``[N, d]`` (``valid`` ``[N]``:
     rows that carry a token; the others are routed nowhere): the shared
-    expert plus this program's share of the routed ones. Returns ``(y [N,
-    d], stats)`` with ``stats`` the first :data:`N_MOE_STATS` of
-    :data:`STEP_STATS`."""
+    expert plus this program's share of the routed ones. ``rowwise(fn,
+    h)``: how the layer's position-wise pieces (the router, the shared
+    expert) run over ``h`` (None: at once; the ragged pass's row tiles,
+    engine/paged.py::FlatRows.by_tile). Returns ``(y [N, d], stats)`` with
+    ``stats`` the first :data:`N_MOE_STATS` of :data:`STEP_STATS`."""
     N, d = h.shape
     K = cfg.n_experts_per_tok
     E = cfg.n_held
     T = min(MOE_TILE, N)
-    topi, topw, kept = _route(h, mp, cfg)
+    if rowwise is None:
+        rowwise = lambda fn, h: fn(h)
+    topi, topw, kept = rowwise(lambda h: _route(h, mp, cfg), h)
     local = (
         (topi >= cfg.experts_first) & (topi < cfg.experts_first + E)
         & valid[:, None]
@@ -669,7 +673,8 @@ def moe_mlp(h, mp: dict, cfg: ModelConfig, valid):
         0, n_tiles, tile, jnp.zeros((N + T, d), jnp.float32)
     )[:N]
     if "shared" in mp:
-        out = out + gated_mlp(h, mp["shared"]).astype(jnp.float32)
+        out = out + rowwise(
+            lambda h: gated_mlp(h, mp["shared"]), h).astype(jnp.float32)
     reach = valid
     if kept is not None:
         # a held expert's group is one this row kept: without a group

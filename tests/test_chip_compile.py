@@ -443,7 +443,8 @@ def test_dots3_note_step_fits_one_v5e_with_its_window_kernel(v5e):
     from pathlib import Path
 
     from tensorlink_tpu.engine.latent import WINDOW_KERNEL, LatentPagedCache
-    from tensorlink_tpu.engine.paged import STEP_PHASES, paged_ragged_step
+    from tensorlink_tpu.engine.paged import (
+        STEP_PHASES, paged_ragged_step, tiled_rows)
     from tensorlink_tpu.models.registry import config_from_hf
     from tensorlink_tpu.models.transformer import init_params
 
@@ -456,7 +457,9 @@ def test_dots3_note_step_fits_one_v5e_with_its_window_kernel(v5e):
         cfg, slots, page_size=PAGE, max_len=hf["deployment"]["seq_len"]))
     place = _on(v5e)
     ops = _packed_operands(cfg, params, cache, place, place)
-    compiled = paged_ragged_step.lower(*ops, cfg, 8, 9, True).compile()
+    # the program that serves: the tiled pass, the one wide program
+    compiled = paged_ragged_step.lower(
+        *ops, cfg, 8, 9, True, tiled_rows(slots, 128)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3 and WINDOW_KERNEL in text
     ma = compiled.memory_analysis()
@@ -567,7 +570,8 @@ def test_laguna_step_fits_one_v5e_with_both_walks_in_both_passes(v5e):
     from pathlib import Path
 
     from tensorlink_tpu.engine.latent import LatentPagedCache
-    from tensorlink_tpu.engine.paged import STEP_PHASES, paged_ragged_step
+    from tensorlink_tpu.engine.paged import (
+        STEP_PHASES, paged_ragged_step, tiled_rows)
     from tensorlink_tpu.models.registry import config_from_hf
     from tensorlink_tpu.models.transformer import init_params
 
@@ -582,7 +586,9 @@ def test_laguna_step_fits_one_v5e_with_both_walks_in_both_passes(v5e):
     assert cache.k.shape == (3, 1 + 16 * 1024, 8, 16, 128)
     place = _on(v5e)
     ops = _packed_operands(cfg, params, cache, place, place)
-    compiled = paged_ragged_step.lower(*ops, cfg, 8, 1, True).compile()
+    # the program that serves: the tiled pass, the one wide program
+    compiled = paged_ragged_step.lower(
+        *ops, cfg, 8, 1, True, tiled_rows(slots, 128)).compile()
     text = compiled.as_text()
     for name in ("gqa_full_attention", "gqa_window_attention"):
         assert text.count(name) >= 2, name  # a call a layer and pass
@@ -625,7 +631,8 @@ def test_lfm2_step_fits_one_v5e_and_pads_no_pool(v5e):
     from pathlib import Path
 
     from tensorlink_tpu.engine.latent import LatentPagedCache
-    from tensorlink_tpu.engine.paged import STEP_PHASES, paged_ragged_step
+    from tensorlink_tpu.engine.paged import (
+        STEP_PHASES, paged_ragged_step, tiled_rows)
     from tensorlink_tpu.models.registry import config_from_hf
     from tensorlink_tpu.models.transformer import init_params
 
@@ -640,7 +647,9 @@ def test_lfm2_step_fits_one_v5e_and_pads_no_pool(v5e):
     assert cache.state.shape == (9, 16, 2, 2048)
     place = _on(v5e)
     ops = _packed_operands(cfg, params, cache, place, place)
-    compiled = paged_ragged_step.lower(*ops, cfg, 8, 1, True).compile()
+    # the program that serves: the tiled pass, the one wide program
+    compiled = paged_ragged_step.lower(
+        *ops, cfg, 8, 1, True, tiled_rows(slots, 128)).compile()
     text = compiled.as_text()
     assert text.count("gqa_full_attention") >= 2  # a call a layer and pass
     ma = compiled.memory_analysis()

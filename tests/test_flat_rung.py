@@ -9,6 +9,7 @@ family; which rung a chunk takes follows from its ``n_valid`` alone; one
 program a rung; an unbuilt rung packs full and is counted."""
 
 import threading
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -204,7 +205,7 @@ def test_a_flat_chunk_leaves_what_the_full_program_leaves(family):
 def test_the_row_map_is_each_slots_rows_back_to_back(n_valid):
     nv = np.asarray(n_valid, np.int32)
     rows = paged.FlatRows.of(jnp.asarray(nv), CHUNK, FLAT)
-    to_flat, slot, col, live = (np.asarray(a) for a in rows)
+    to_flat, slot, col, live = (np.asarray(a) for a in rows[:4])
     want = [(s, j) for s in range(SLOTS) for j in range(nv[s])]
     assert live.sum() == len(want) and live[: len(want)].all()
     assert list(zip(slot[live], col[live])) == want
@@ -489,34 +490,253 @@ def test_build_steps_builds_every_rung_and_serving_builds_none():
     ce.close()
 
 
+TILE = paged.row_tile(SLOTS, CHUNK)  # 48 rows a trip
+TILED = paged.tiled_rows(SLOTS, CHUNK)  # the whole block: 192
+# tlint: disable=TL006(read-only table)
+WIDE_PASS = {  # what an engine reads off the model's layer kinds
+    "static": (FLAT, ((CHUNK, FLAT), (CHUNK, 0))),
+    "tiled": (TILED, ((CHUNK, TILED),)),
+    "full": (0, ((CHUNK, 0),)),
+}
+
+
 @pytest.mark.parametrize(
-    "module,name,has",
-    [("test_latent", "TINY_DS", True), ("test_latent", "TINY", False),
-     ("test_laguna", "TINY", False), ("test_sala", "TINY", False),
-     ("test_lfm2", "TINY", False)],
+    "module,name,shape",
+    [("test_latent", "TINY_DS", "static"), ("test_latent", "TINY", "tiled"),
+     ("test_laguna", "TINY", "tiled"), ("test_sala", "TINY", "full"),
+     ("test_lfm2", "TINY", "tiled")],
     ids=["deepseek", "dots3", "laguna", "sala", "lfm2"],
 )
 def test_an_engine_builds_the_rung_for_a_pass_of_one_layer_body(
-        module, name, has):
-    """A second wide program of several layer bodies is a second set-up
-    (engine/continuous.py, where ``flat_rows`` is set): those engines keep
-    the full program; a pass of one body (dense, DeepSeek-V2) has the
-    rung, built by ``build_steps`` beside the full program."""
+        module, name, shape):
+    """The wide pass has three shapes (engine/continuous.py, where
+    ``flat_rows`` is set): a pass of one body (dense, DeepSeek-V2) has the
+    static rung, built by ``build_steps`` beside the full program; a
+    patterned model whose bodies are engine/latent.py's own has ONE wide
+    program, the tiled pass, and reports the rows its tiles computed; a
+    model of sparse / lightning layers keeps the full program."""
     cfg = _hf(module, name)
     ce = _engine(cfg, init_params(cfg, jax.random.PRNGKey(0)), page_size=4)
     assert ce.block_widths == (CHUNK,)
-    assert (len(set(cfg.layer_kinds)) == 1) == has
-    assert ce.flat_rows == (FLAT if has else 0)
-    assert ce.rungs == (((CHUNK, FLAT),) if has else ()) + ((CHUNK, 0),)
+    assert (len(set(cfg.layer_kinds)) == 1) == (shape == "static")
+    assert (ce.flat_rows, ce.rungs) == WIDE_PASS[shape]
+    assert ce.tiled == (shape == "tiled")
     ce.build_steps()
-    assert (ce._build is not None) == has
+    assert (ce._build is not None) == (shape == "static")
     ce.submit([1, 2, 3] * 5, max_new_tokens=8)
     ce.run_until_idle()
     ce.submit([3, 2, 1] * 5, max_new_tokens=8, seed=1)
     ce.run_until_idle()
     ran = {r["rows_computed"] for r in ce.recorder.records()}
-    # the first request ran the full program while the rung was built
-    assert ran - {SLOTS * CHUNK} == ({FLAT} if has else set())
+    if shape == "tiled":  # 15 prompt rows or a decode row: one tile
+        assert ran == {TILE}
+        assert ce.stats["ragged_blocks_flat"] == ce.stats["ragged_blocks"]
+        # one program, built by the first call: nobody waited beside it
+        snap = ce.serving_snapshot()
+        assert snap["step_build_ms"] == snap["step_build_waited_ms"]
+        # ``lower_step`` lowers what serves: the row list is the stream
+        d = cfg.d_model
+        assert f"tensor<1x{TILED}x{d}xf32>" in ce.lower_step().as_text()
+        assert f"tensor<1x{TILED}x{d}xf32>" not in ce.lower_step(
+            flat=False).as_text()
+    else:
+        # the first request ran the full program while the rung was built
+        assert ran - {SLOTS * CHUNK} == (
+            {FLAT} if shape == "static" else set())
     assert ce._build is None and not ce._unbuilt
     ce.check_page_conservation()
     ce.close()
+
+
+# ---------------------------------------------------------------------------
+# the tiled pass: position-wise work over row tiles, a live-row bound
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize(
+    "slots,chunk,tile,rows",
+    [(16, 128, 512, 2048), (8, 128, 256, 1024), (32, 128, 512, 4096),
+     (6, 32, 48, 192), (4, 32, 32, 128), (3, 8, 8, 24), (1, 8, 8, 8),
+     (5, 24, 24, 120)],
+)
+def test_the_tile_is_a_function_of_the_shapes(slots, chunk, tile, rows):
+    assert paged.row_tile(slots, chunk) == tile
+    assert paged.tiled_rows(slots, chunk) == rows >= slots * chunk
+    assert rows % tile == 0 and tile <= paged.ROW_TILE
+
+
+@pytest.mark.parametrize("n_live", [0, 1, TILE - 1, TILE, TILE + 1, TILED])
+def test_by_tile_computes_the_tiles_that_hold_a_live_row(n_live):
+    nv = jnp.asarray(_n_valid(n_live))
+    rows = paged.FlatRows.of(nv, CHUNK, TILED, TILE)
+    a = jnp.arange(1, 2 * TILED + 1, dtype=jnp.float32).reshape(1, TILED, 2)
+    b = jnp.arange(TILED, dtype=jnp.int32)[None]
+    got = jax.jit(lambda a, b: rows.by_tile(
+        lambda a, b: {"sum": a.sum(-1) + b, "twice": 2 * a}, a, b))(a, b)
+    done = -(-n_live // TILE) * TILE
+    assert int(rows.trips) * TILE == done
+    mask = (np.arange(TILED) < done)[None]
+    np.testing.assert_array_equal(
+        got["sum"], np.where(mask, np.asarray(a.sum(-1) + b), 0))
+    np.testing.assert_array_equal(
+        got["twice"], np.where(mask[..., None], 2 * np.asarray(a), 0))
+    # rows along the first axis (the expert layer's ``[N, d]`` lists)
+    got0 = jax.jit(lambda a: rows.by_tile(lambda a: a + 1, a, axis=0))(a[0])
+    np.testing.assert_array_equal(
+        got0, np.where(mask[0][:, None], np.asarray(a[0]) + 1, 0))
+    # without a tile the list is computed whole
+    whole = paged.FlatRows.of(nv, CHUNK, FLAT)
+    assert whole.tile == 0 and whole.trips is None
+    np.testing.assert_array_equal(
+        whole.by_tile(lambda a: 2 * a, a), 2 * np.asarray(a))
+
+
+def _n_valid(n_live: int) -> np.ndarray:
+    """``n_live`` rows over the slots: a decode row a slot from the last
+    slot on, the rest as grants from slot 0 on."""
+    nv = np.zeros(SLOTS, np.int32)
+    for s in range(SLOTS - 1, -1, -1):
+        if nv.sum() < n_live:
+            nv[s] = 1
+    for s in range(SLOTS):
+        nv[s] += min(CHUNK - nv[s], n_live - nv.sum())
+    assert nv.sum() == n_live
+    return nv
+
+
+TILED_FAMILIES = ("dots3", "laguna", "lfm2")
+_PASS = jax.jit(paged._ragged_pass, static_argnames=(
+    "cfg", "spec_width", "kernel", "flat_rows"))
+
+
+def _tiled_cfg(family: str, **kw):
+    return replace(FAMILIES[family][0](), **kw)
+
+
+def _a_block_both_ways(family: str, n_live: int):
+    """One block of ``n_live`` rows (decode rows and grants, each slot
+    16 positions into its sequence) through the ragged pass tiled and
+    untiled, on one cache: what each leaves, and the rows' logits."""
+    from tensorlink_tpu.engine.latent import LatentPagedCache
+
+    cfg = _tiled_cfg(family)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    cache = LatentPagedCache.init(cfg, SLOTS, page_size=4, max_len=64,
+                                  prefill_chunk=CHUNK)
+    n_pp = cache.pages_per_slot
+    perm = np.random.default_rng(0).permutation(np.arange(1, cache.n_pages))
+    cache = paged._with_kv(
+        cache, paged._cache_kv(cache),
+        block_tables=jnp.asarray(perm[: SLOTS * n_pp].reshape(SLOTS, n_pp)))
+    rng = np.random.default_rng(n_live)
+    zero = jnp.zeros(SLOTS, jnp.int32)
+
+    def block(cache, starts, nv, flat_rows):
+        blk = rng.integers(1, cfg.vocab_size - 1, (SLOTS, CHUNK))
+        blk = np.where(np.arange(CHUNK)[None] < nv[:, None], blk, 0)
+        return _PASS(params, jnp.asarray(blk, jnp.int32), cache,
+                     jnp.asarray(starts), jnp.asarray(nv), zero, cfg=cfg,
+                     spec_width=1, kernel=False, flat_rows=flat_rows)
+
+    # every slot holds 16 positions: pages, rings and tails to read
+    first = np.full(SLOTS, 16, np.int32)
+    _lv, _base, kv = block(cache, np.zeros(SLOTS, np.int32), first, 0)
+    cache = paged._with_kv(cache, kv, lengths=jnp.asarray(first))
+    nv = _n_valid(n_live)
+    state = rng.bit_generator.state
+    out = []
+    for flat_rows in (TILED, 0):
+        rng.bit_generator.state = state  # the same tokens both ways
+        lv, _base, kv = block(cache, np.where(nv > 0, first, 0), nv,
+                              flat_rows)
+        left = paged._with_kv(cache, kv)
+        out.append((_leaves(left), np.asarray(lv)[nv > 0]))
+    return out
+
+
+def _churn_both_ways(family: str):
+    """A churn of mixes through a tiled engine and one held to the
+    untiled program; the tiled one's chunks as packed (``n_valid``)."""
+    cfg = _tiled_cfg(family, norm_eps=3e-6)  # programs nobody built before
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    kw = FAMILIES[family][1]
+    tiled, full = _engine(cfg, params, **kw), _with_flat_rows(
+        _engine(cfg, params, **kw), 0)
+    packed, pack = [], tiled._pack_ragged
+
+    def watch():
+        out = pack()
+        if out is not None:
+            packed.append(out[2].copy())
+        return out
+
+    tiled._pack_ragged = watch
+    return tiled, full, packed
+
+
+@pytest.mark.parametrize("family", TILED_FAMILIES)
+@pytest.mark.parametrize(
+    "case", [0, 1, TILE - 1, TILE, TILE + 1, TILED - TILE, TILED - TILE + 1,
+             TILED, "churn", "counters"],
+    ids=lambda c: c if isinstance(c, str) else f"rows-{c}")
+def test_the_tiled_pass_is_the_untiled_program_over_fewer_rows(family, case):
+    """A patterned model's one wide program (``flat_rows`` = the whole
+    block, ``paged.tiled_rows``): at every live-row count around a tile's
+    edge a chunk leaves the pages, rings, conv tails, counts and logits
+    that the untiled program leaves (from ``TILED - TILE + 1`` rows on
+    every tile holds a live row and the program takes its untiled body);
+    a churn of mixes runs ONE program; the counters are the tiles
+    computed."""
+    if isinstance(case, int):
+        (a, lv_a), (b, lv_b) = _a_block_both_ways(family, case)
+        assert a.keys() == b.keys()
+        for name in a:
+            _same(a[name], b[name], name)
+        if case:  # (no row, no logits)
+            _same(lv_a, lv_b, "logits")
+        return
+    tiled, full, packed = _churn_both_ways(family)
+    pre = tiled.jit_cache_sizes()["ragged_step"]
+    assert tiled.tiled and tiled.rungs == ((CHUNK, TILED),)
+    if case == "churn":
+        reqs = [_submit_churn(ce) for ce in (tiled, full)]
+        base = tiled.jit_cache_sizes()["ragged_step"]
+        # the tiled engine's one program and the other engine's full one
+        assert base - pre == 2
+        for ce in (tiled, full):
+            for n in (1, 7, 8, 9, 31, 33, 60):
+                ce.submit([n] * n, max_new_tokens=3, seed=n)
+            ce.run_until_idle()
+        assert tiled.jit_cache_sizes()["ragged_step"] == base
+        for ra, rb in zip(*reqs):  # the same streams
+            assert ra.finished and ra.tokens == rb.tokens
+        trips = {r["rows_computed"] // TILE for r in tiled.recorder.records()}
+        assert {1, 2, 4} <= trips, trips  # decode alone .. every row live
+    else:
+        _submit_churn(tiled)
+        recs, s = tiled.recorder.records(), tiled.stats
+        assert len(packed) == len(recs) == s["ragged_blocks"]
+        assert s["ragged_blocks_flat"] == len(recs)
+        assert s["ragged_blocks_narrow"] == 0 == s["ragged_blocks_flat_unbuilt"]
+        by_hand = [-(-int(nv.sum()) // TILE) * TILE for nv in packed]
+        assert [r["rows_computed"] for r in recs] == by_hand
+        assert s["ragged_rows_computed"] == sum(by_hand)
+        assert s["ragged_rows_valid"] == sum(int(nv.sum()) for nv in packed)
+        assert max(by_hand) == TILED and min(by_hand) == TILE
+    for ce in (tiled, full):
+        ce.check_page_conservation()
+        ce.close()
+
+
+def _submit_churn(ce) -> list:
+    """:func:`_churn` within these families' 64 positions."""
+    reqs = [ce.submit([3 + i] * (3 + 5 * i), max_new_tokens=5 + i, seed=i)
+            for i in range(ce.max_slots + 2)]
+    ce.step_chunk()
+    reqs.append(ce.submit([(5 * i) % 90 + 1 for i in range(50)],
+                          max_new_tokens=5, seed=20))
+    ce.run_until_idle()
+    # every slot prefills a whole grant: every row of the block is live
+    reqs += [ce.submit([(7 * j + i) % 90 + 1 for j in range(40)],
+                       max_new_tokens=3, seed=30 + i)
+             for i in range(ce.max_slots)]
+    ce.run_until_idle()
+    return reqs

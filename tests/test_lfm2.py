@@ -564,7 +564,7 @@ def test_lagunas_step_program_is_the_parents():
                             jax.random.PRNGKey(0))
     params = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
     ce = tl._engine(cfg, params)
-    got = {w: hashlib.sha256(ce.lower_step(w).as_text().encode()
+    got = {w: hashlib.sha256(ce.lower_step(w, flat=False).as_text().encode()
                              ).hexdigest()[:16] for w in ce.block_widths}
     ce.close()
     assert got == {8: "0b009fec2d5ce770"}

@@ -667,7 +667,7 @@ def test_the_other_families_step_programs_are_the_parents(family):
     params = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
     ce = tl._engine(cfg, params)
     got = {(family, w): hashlib.sha256(
-        ce.lower_step(w).as_text().encode()).hexdigest()[:16]
+        ce.lower_step(w, flat=False).as_text().encode()).hexdigest()[:16]
         for w in ce.block_widths}
     ce.close()
     assert got == {k: v for k, v in PARENT_PROGRAMS.items() if k[0] == family}
