@@ -544,6 +544,18 @@ _ENGINE_COUNTERS = (
     ("admit_device_calls", "tlink_engine_admit_device_calls_total",
      "device calls of the admission and retirement path (copy-on-write "
      "copies, state restores, penalty histograms, promoted pages)"),
+    # intake ahead (docs/SERVING.md "The anatomy of a chunk"): what the
+    # host did for the next chunk while the device ran this one
+    ("submitted", "tlink_engine_submitted_total",
+     "requests handed to submit"),
+    ("submitted_ahead", "tlink_engine_submitted_ahead_total",
+     "of those, requests submitted inside a chunk's wait (the intake)"),
+    ("admitted_ahead", "tlink_engine_admitted_ahead_total",
+     "of the admitted, requests an ahead round prepared during a chunk's "
+     "wait and the next chunk's edge committed"),
+    ("chunk_us_intake", "tlink_engine_chunk_us_intake_total",
+     "host microseconds in the intake's frames and ahead rounds (a "
+     "sub-span of wait)"),
 )
 
 
@@ -726,6 +738,7 @@ class ContinuousEngine:
         tensor_parallel: int = 1,
         state_snapshot_stride: int = 0,
         state_snapshots: int = 0,
+        intake: Callable | None = None,
     ):
         if engine.cfg.sliding_window is not None:
             raise PagedUnsupported(
@@ -1215,6 +1228,22 @@ class ContinuousEngine:
         # its first token ever, whether on_finish follows them, the chunk
         # that made them). At most one entry a request; driver-thread only
         self._unstreamed: dict[int, tuple] = {}
+        # intake ahead (docs/SERVING.md "The anatomy of a chunk"): what a
+        # chunk's wait does for the next chunk while the device runs this
+        # one. ``intake(result)`` yields, one by one, the requests that
+        # arrive before ``result`` is ready, each as a callable that
+        # submits it (the worker's: ml/worker.py::_intake); it blocks
+        # between them and ends when ``result`` is ready or earlier. None:
+        # the wait is the fetch alone.
+        self.intake = intake
+        # admissions an ahead round prepared and the next ``_admit`` will
+        # publish: slot -> (request, rows replayed), in the order prepared.
+        # The request holds its pages and its hit chain, the host's table
+        # the slot's row; ``_slots`` / ``_prefilling`` do not know it yet
+        self._prepared: dict[int, tuple] = {}
+        # inside a chunk's intake: a request submitted now arrived while
+        # the chunk ``recorder.next_step`` names was in flight
+        self.taking_in = False
         if pool is not None:
             # per-tenant pool occupancy: these render under the model's
             # label at /metrics (the registry-per-model grouping), which
@@ -1424,6 +1453,10 @@ class ContinuousEngine:
         req.submit_t = time.monotonic()
         overload: SchedulerOverloaded | None = None
         with self._lock:
+            # under the lock: a client thread may submit beside the driver
+            self._count("submitted")
+            if self.taking_in:
+                self._count("submitted_ahead")
             try:
                 self.sched.push(req)
             except SchedulerOverloaded as e:
@@ -1466,7 +1499,7 @@ class ContinuousEngine:
             "draining": self.drain_state != "serving",
             "worker_role": self.worker_role,
             "max_slots": self.max_slots,
-            "slots_free": sum(1 for r in self._slots if r is None),
+            "slots_free": len(self._free_slots()),
             "kv_pages_free": self.alloc.n_free,
             "kv_pages_total": self.cache.n_pages - 1,
             "service_ewma_s": float(ewma),
@@ -1485,7 +1518,20 @@ class ContinuousEngine:
                 len(self.sched) > 0
                 or bool(self._active.any())
                 or bool(self._prefilling)
+                or bool(self._prepared)
             )
+
+    def _prepared_requests(self) -> list:
+        """The requests of the prepared admissions (a copy: other threads
+        read snapshots while the driver prepares and commits)."""
+        return [r for r, _ in list(self._prepared.values())]
+
+    def _free_slots(self) -> list[int]:
+        """Slots that hold no request and no prepared admission."""
+        return [
+            s for s, r in enumerate(self._slots)
+            if r is None and s not in self._prepared
+        ]
 
     @property
     def live_slots(self) -> int:
@@ -1709,8 +1755,10 @@ class ContinuousEngine:
             raise
         return sent
 
-    def _admit_one(self, req: ContinuousRequest, slot: int) -> bool:
-        """Place ``req`` into ``slot``. Returns False when no pages are
+    def _admit_one(self, req: ContinuousRequest, slot: int, *,
+                   ahead: bool = False) -> bool:
+        """Place ``req`` into ``slot`` (``ahead``: prepare it for the slot,
+        ``_admit_ahead``). Returns False when no pages are
         free (request stays queued). A preempted request re-admits here
         with ``req.tokens`` non-empty: the prefill sequence is prompt +
         emitted (the crash-recovery shape, so resumption is bit-exact)
@@ -1748,7 +1796,7 @@ class ContinuousEngine:
             self._drop_ticket(req)
         req.prefill_tokens = seq
         req.prefill_target = len(seq)
-        return self._admit_paged(req, slot, total)
+        return self._admit_paged(req, slot, total, ahead=ahead)
 
     def _alloc_pages(self, n: int) -> list[int] | None:
         """All-or-nothing page grab with eviction-on-demand: when the
@@ -1760,14 +1808,20 @@ class ContinuousEngine:
         OTHER tenants' cold resident prefixes reclaim to the shared
         free list (pool.reclaim_cache) — but only when this tenant's
         QUOTA has room, because a quota-dry tenant must pay with its
-        own pages, never a neighbor's."""
+        own pages, never a neighbor's. With a chunk in flight (an ahead
+        round: ``taking_in``) it evicts unreferenced leaves of its own
+        trie (the chunk reads only pages its slots reference) unless an
+        evicted page would be demoted, which fetches it; the other rungs
+        are the edge's."""
         pages = self.alloc.alloc(n)
+        if self.taking_in and self.host_tier is not None:
+            return pages
         if pages is None and self.prefix is not None:
             deficit = n - self.alloc.n_free
             if deficit > 0 and self.prefix.n_evictable() >= deficit:
                 self.alloc.free(self.prefix.evict(deficit))
                 pages = self.alloc.alloc(n)
-        if pages is None and self.pool is not None:
+        if pages is None and self.pool is not None and not self.taking_in:
             quota_room = self.alloc.quota - self.alloc.used
             deficit = n - self.pool.alloc.n_free
             if n <= quota_room and 0 < deficit <= self.pool.reclaim_cache(
@@ -1777,7 +1831,7 @@ class ContinuousEngine:
         return pages
 
     def _admit_paged(self, req: ContinuousRequest, slot: int,
-                     total: int) -> bool:
+                     total: int, *, ahead: bool = False) -> bool:
         """Chunked-prefill admission down the tiered-cache ladder
         (docs/SERVING.md "Tiered prefix cache"): (1) walk the HBM trie
         for the longest resident chain of full pages (zero prefill
@@ -1794,6 +1848,7 @@ class ContinuousEngine:
         T = len(seq)
         hit_nodes: list = []
         cow = None
+        replayed = 0
         if self.prefix is not None:
             # at least ONE real token must prefill so the final chunk
             # yields the last prompt position's logits for the first draw
@@ -1818,7 +1873,6 @@ class ContinuousEngine:
                 hit_nodes = self._pull_chain(seq, limit, hit_nodes)
                 if len(hit_nodes) > n0:
                     req.cache_tier = "fleet"
-            replayed = 0
             if self._stateful:
                 # the hit ends at the nearest snapshot at or below the
                 # match; what lies between is prefilled again (through
@@ -1834,7 +1888,11 @@ class ContinuousEngine:
             if cow is not None:
                 self.prefix.acquire([cow[0]])
         n_hit = len(hit_nodes)
-        pages = self._alloc_pages(pages_needed(total, self.page_size) - n_hit)
+        pages = None
+        if not (ahead and self._hit_may_grow(
+                seq, n_hit * self.page_size + (cow[1] if cow else 0))):
+            pages = self._alloc_pages(
+                pages_needed(total, self.page_size) - n_hit)
         if pages is None:
             if self.prefix is not None:
                 self.prefix.release(hit_nodes)
@@ -1873,16 +1931,65 @@ class ContinuousEngine:
                 if cow is not None and not cow_released:
                     self.prefix.release([cow[0]])
             raise
-        req.slot = slot
         req.pages = pages
         req.shared_nodes = hit_nodes
         req.prefill_pos = hit_len
+        if ahead:
+            # prepared: the chunk in flight was packed without this slot,
+            # and what reads the residents until it has settled (the
+            # settle stage, the flight recorder, the benchmark's taps)
+            # reads them as they were packed; the next ``_admit``
+            # publishes (``_commit_prepared``)
+            self._prepared[slot] = (req, replayed)
+        else:
+            self._publish(req, slot, replayed)
+        return True
+
+    def _hit_may_grow(self, seq: list, hit_len: int) -> bool:
+        """Whether the chunk in flight may leave ``seq`` a longer hit than
+        the ``hit_len`` positions the trie gives it now. A resident that
+        retires in this chunk promotes its prefill's pages when the chunk
+        settles (``_release_pages``), before the edge walks the trie: where
+        one's prefill goes on as ``seq`` does, past ``hit_len`` and as far
+        as the next position a hit could end at (any, by copy-on-write; a
+        stateful model's next snapshot point), an ahead round leaves the
+        request to the edge, which knows. Which resident retires is the
+        device's to say, so every one counts."""
+        if self.prefix is None:
+            return False
+        page, limit = self.page_size, len(seq) - 1
+        for r in self._slots:
+            if r is None:
+                continue
+            own = r.prefill_tokens
+            if not self._stateful:
+                h = hit_len + 1
+            elif self.snap_stride:
+                h = (hit_len // self.snap_stride + 1) * self.snap_stride
+                final = (len(own) - 1) // page * page
+                if final > hit_len:
+                    h = min(h, final)
+            else:
+                return False  # no snapshot, no hit
+            if (h <= min(limit, r.prefill_target // page * page)
+                    and own[h - 1] == seq[h - 1] and own[:h] == seq[:h]):
+                return True
+        return False
+
+    def _publish(self, req: ContinuousRequest, slot: int, replayed: int,
+                 *, ahead: bool = False) -> None:
+        """The end of an admission: ``slot`` holds ``req`` for everything
+        that enumerates residents from here on, and the counters say so."""
+        req.slot = slot
         self._slots[slot] = req
         self._prefilling[slot] = req
         # the completing step samples the first token IN-program, so the
         # slot's sampling state must be armed before its first packed block
         self._arm_slot(req, slot)
+        hit_len = req.prefill_pos
         self._count("admitted")
+        if ahead:
+            self._count("admitted_ahead")
         self._count("prefill_tokens_skipped", hit_len)
         if self._stateful:
             self._count(f"{self._snap_counts}_admissions")
@@ -1895,7 +2002,23 @@ class ContinuousEngine:
             if hit_len > 0:
                 self.prefix.stats["hits"] += 1
             self.prefix.stats["hit_tokens"] += hit_len
-        return True
+
+    def _commit_prepared(self) -> None:
+        """Publish what the ahead rounds prepared, in the order prepared:
+        the first thing an admission round does, and whatever else
+        enumerates residents between two chunks (a drain, a weight
+        publish)."""
+        for slot in list(self._prepared):
+            req, replayed = self._prepared.pop(slot)
+            self._publish(req, slot, replayed, ahead=True)
+
+    def _unprepare(self, slot: int) -> ContinuousRequest:
+        """Take a prepared admission back (``close``): its pages and
+        references return, the slot's row goes back to the scratch page,
+        the request is nobody's. Host work alone."""
+        req, _ = self._prepared.pop(slot)
+        self._give_back(slot, req)
+        return req
 
     # -- recurrent state: snapshots (engine/sala.py) ----------------------
     def _admit_state(self, req, slot: int, hit_nodes: list,
@@ -2529,6 +2652,12 @@ class ContinuousEngine:
         self._active[slot] = False
         self._tok[slot] = 0
         self._temp[slot] = 0.0
+        self._give_back(slot, req)
+        return req
+
+    def _give_back(self, slot: int, req: ContinuousRequest | None) -> None:
+        """``slot``'s row to the scratch page and ``req``'s pages to
+        whoever keeps them next (the trie, the free list)."""
         # table row -> scratch page, length and histogram -> 0: the next
         # dispatched chunk carries it (no step reads a retired slot's row
         # before that, and a page freed here is written by nobody sooner)
@@ -2542,7 +2671,6 @@ class ContinuousEngine:
                 self._free_snapshots(req)
             req.pages = []
             req.shared_nodes = []
-        return req
 
     def _preempt(self, slot: int) -> None:
         """Preempt a running (or mid-prefill) slot at an admission
@@ -2814,6 +2942,9 @@ class ContinuousEngine:
         the stream stage leaves first, so the manifest the drain reads is
         of requests whose clients hold every token made here."""
         self.flush_stream()
+        # a prepared admission is a resident to the drain: a mid-prefill
+        # slot of its manifest, shed down the re-prefill rung
+        self._commit_prepared()
         self.drain_state = "draining"
         with self._lock:
             self.sched.set_draining(True)
@@ -2905,6 +3036,9 @@ class ContinuousEngine:
         # tlint: disable=TL005(leaves that aren't arrays — exotic QTensor layouts — can't be re-placed; structure was validated above, so swapping the tree as given is the correct degradation)
         except (ValueError, TypeError):
             pass
+        # an admission prepared under the old weights is one of theirs
+        # (its hit chain is, and the version it is stamped with)
+        self._commit_prepared()
         eng.params = params_in
         self.weights_version = new_version
         if self.prefix is not None:
@@ -2948,7 +3082,7 @@ class ContinuousEngine:
                 for r in self.sched.pending()
             ):
                 return True
-        for req in self._slots:
+        for req in self._slots + self._prepared_requests():
             if req is not None and PRIORITY_RANK.get(req.priority, bar) < bar:
                 return True
         return False
@@ -2959,6 +3093,7 @@ class ContinuousEngine:
     def live_manifest(self) -> list[tuple[str, int, ContinuousRequest]]:
         """Snapshot of what a drain must move: ("decode"|"prefill", slot,
         request) for every live slot. Driver-thread only."""
+        self._commit_prepared()
         out: list[tuple[str, int, ContinuousRequest]] = []
         for s in range(self.max_slots):
             req = self._slots[s]
@@ -3207,6 +3342,8 @@ class ContinuousEngine:
                 (in_transit if s in self._frozen else slot_pages).extend(
                     req.pages
                 )
+        for req in self._prepared_requests():
+            slot_pages.extend(req.pages)  # a slot's, all but published
         for ticket in self._migrations.values():
             in_transit.extend(ticket["pages"])
         return {
@@ -3397,12 +3534,12 @@ class ContinuousEngine:
             "kv_pages_slots": sum(
                 len(r.pages) for s, r in enumerate(self._slots)
                 if r is not None and s not in self._frozen
-            ),
+            ) + sum(len(r.pages) for r in self._prepared_requests()),
             # fleet-router headroom (docs/SERVING.md "Fleet serving"):
             # slots no request holds — with kv_pages_free and the
             # per-class sched_classes depths below, the placement inputs
             # a router/LB needs without a second probe
-            "slots_free": sum(1 for r in self._slots if r is None),
+            "slots_free": len(self._free_slots()),
             # serve-and-train (docs/TRAINING.md): which model version
             # this engine serves (bumps per weight publish — the fleet
             # view of a rolling model update), plus the background
@@ -3483,7 +3620,8 @@ class ContinuousEngine:
         return out
 
     def _admit(self) -> None:
-        """One admission round (one scheduler tick): admit the scheduler's
+        """One admission round (one scheduler tick): commit what the ahead
+        rounds of the chunk before prepared, then admit the scheduler's
         best queued request into a free slot, preempting strictly-lower-
         priority residents when the candidate would otherwise miss
         admission — no free slot, or the allocator dry even after
@@ -3492,33 +3630,53 @@ class ContinuousEngine:
         client submit() calls never stack behind admission compute
         (single-driver discipline means nobody else pops the selection
         meanwhile)."""
+        self._commit_prepared()
         if self._migrations:
             # abandoned staged adoptions (their resume never arrived)
             # must not hold pages forever
             self._gc_staged_migrations()
         with self._lock:
             self.sched.tick()
+        self._admit_round()
+
+    def _admit_ahead(self) -> None:
+        """An ahead round, run from a chunk's wait after each request the
+        intake took in: ``_admit``'s round for the slots that hold no
+        request and no prepared admission, while the device runs the
+        chunk that was packed without them. It prepares and does not
+        publish (``_admit_paged``), never preempts, and leaves to the
+        chunk's edge what only the edge can do: a request with no slot or
+        no pages free, an adoption. Its device calls (a copy-on-write
+        page, a restored state) take ``self.cache``, the step's result,
+        and queue behind the step; none fetches."""
+        self._admit_round(ahead=True)
+
+    def _admit_round(self, *, ahead: bool = False) -> None:
+        """The body ``_admit`` and ``_admit_ahead`` share."""
         while True:
             with self._lock:
                 # a slot is free only when NO request holds it — active
-                # decode or mid-prefill both count as occupied
-                free = [
-                    s for s in range(self.max_slots)
-                    if self._slots[s] is None
-                ]
+                # decode or mid-prefill both count as occupied, and so
+                # does an admission prepared for it
+                free = self._free_slots()
                 req = self.sched.select()
                 victim = None
-                if req is not None and not free:
+                if req is not None and not free and not ahead:
                     victim = self.sched.victim(self._preemptable(), req)
             if req is None:
                 return
+            if ahead and (not free or req.adopt is not None):
+                return  # the edge's: a victim's slot, a staged adoption
             if not free:
                 if victim is None:
                     return  # every resident outranks the best candidate
                 self._preempt(victim.slot)
                 continue  # the victim's slot is free now
+            slot = free[0]
             t_adm = time.monotonic()
-            while not self._admit_one(req, free[0]):
+            while not self._admit_one(req, slot, ahead=ahead):
+                if ahead:
+                    return  # the edge finds the pages, or a victim
                 # allocator pressure the prefix cache couldn't cover:
                 # preempting a lower-priority resident frees its private
                 # pages (and promotes its prefill region, so ITS resume
@@ -3551,28 +3709,34 @@ class ContinuousEngine:
                         owner._count("preempted_cross_tenant")
                         continue
                 return  # head-of-line waits for pages
+            # in a slot, or prepared for one (else it ended at once: a
+            # prompt too long, no room left)
+            placed = req.slot >= 0 or slot in self._prepared
             with self._lock:
                 self.sched.remove(req)
-                if req.slot >= 0:
+                if placed:
                     self.sched.note_admitted(req)
                     req.admit_t = time.monotonic()
-            if req.slot >= 0 and req.trace_id:
+            if placed and req.trace_id:
                 # contiguous TTFT decomposition, part 1 and 2: time spent
                 # queued, then the admission work itself (page grab,
-                # prefix-cache walk, COW, any preemption teardown)
+                # prefix-cache walk, COW, any preemption teardown). An
+                # ahead round's end here: the request waits for the edge
+                # inside its ``prefill``
                 self._trace(
                     req, "queue_wait", dur_s=req.admit_t - req.submit_t,
                     t0=req.submit_t, priority=req.priority,
                 )
                 self._trace(
                     req, "admission", dur_s=req.admit_t - t_adm,
-                    t0=t_adm, slot=req.slot, cache_hit_tokens=req.prefill_pos,
+                    t0=t_adm, slot=slot, cache_hit_tokens=req.prefill_pos,
                     **({f"{self._snap_counts}_restored_at":
                         req.state_restored_at} if self._stateful else {}),
                     # deepest tier that fed the hit region — "hbm",
                     # "host", "fleet", or "none" (adopted migrations
                     # keep their own "adopt" span instead)
                     tier=req.cache_tier,
+                    **({"ahead": True} if ahead else {}),
                 )
 
     def _preemptable(self) -> list:
@@ -4021,6 +4185,7 @@ class ContinuousEngine:
         )
         step = self._chunk_step = self.recorder.next_step
         stream_us0 = self._stat["chunk_us_stream"].value
+        intake_us0 = self._stat["chunk_us_intake"].value
         ph: dict = {}
         fields = None
         with jax.profiler.TraceAnnotation("tlink:chunk", chunk=step):
@@ -4067,6 +4232,10 @@ class ContinuousEngine:
                     # for its callbacks meanwhile, nothing below reads
                     # what they return but a stop (settled next)
                     self.flush_stream(in_flight=True)
+                    if self.intake is not None:
+                        # ... and what arrives meanwhile is taken in and
+                        # prepared for the chunk after this one
+                        self._take_in(out)
                     # the chunk's one fetch, and the one value that
                     # blocks: the device is done here
                     out = np.asarray(out)
@@ -4203,9 +4372,35 @@ class ContinuousEngine:
             **{f"{k}_ms": v / 1e3 for k, v in us.items()},
             # a sub-span (of wait, or of deliver with no step in flight)
             stream_ms=(self._stat["chunk_us_stream"].value - stream_us0) / 1e3,
+            # ... and one of wait alone
+            intake_ms=(self._stat["chunk_us_intake"].value - intake_us0) / 1e3,
         )
         self._chunk_exit_t = ph["end"] if more else None
         return more
+
+    # tlint: hot-path
+    def _take_in(self, result) -> None:
+        """The intake of a chunk's wait: every request that arrives
+        before ``result`` (the step's, in flight) is ready is submitted
+        where it arrives, and an ahead round follows each, so that the
+        edge finds its admission prepared. ``self.intake`` blocks between
+        the requests and says when to stop; the time counted is the
+        host's work, not its waiting."""
+        busy = 0.0
+        # the fetch that follows finds the host's copy made: it is queued
+        # behind the step now and not when the intake has come back
+        result.copy_to_host_async()
+        self.taking_in = True
+        try:
+            for take in self.intake(result):
+                t0 = time.monotonic()
+                with jax.profiler.TraceAnnotation("tlink:intake"):
+                    take()
+                    self._admit_ahead()
+                busy += time.monotonic() - t0
+        finally:
+            self.taking_in = False
+            self._count("chunk_us_intake", int(busy * 1e6 + 0.5))
 
     # tlint: hot-path
     def _settle(self, grants, completing, handoff_done, emit, n_spec,
@@ -4387,6 +4582,10 @@ class ContinuousEngine:
             pending = self.sched.pending()
             for req in pending:
                 self.sched.remove(req)
+        # an admission prepared and never committed (the chunk it waited
+        # behind failed, or the engine goes before the next one): its
+        # pages and references return, its request fails like a queued one
+        pending += [self._unprepare(s) for s in list(self._prepared)]
         for s in range(self.max_slots):
             req = self._slots[s]
             if req is not None:

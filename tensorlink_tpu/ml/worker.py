@@ -22,6 +22,7 @@ ml/worker.py:473-476, deliberately dropped — SURVEY §7.4).
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
@@ -33,6 +34,7 @@ import numpy as np
 
 from tensorlink_tpu.core.faults import FaultCrash, FaultPlan
 from tensorlink_tpu.core.logging import get_logger
+from tensorlink_tpu.nodes.ipc import CHUNK_DONE
 from tensorlink_tpu.p2p import protocol as proto
 
 
@@ -175,6 +177,9 @@ class DistributedWorker:
         # only from the serial run loop (the pool's single-driver
         # contract holds because every job's engine steps there too).
         self._kv_pools: dict = {}
+        # the work item that ended a chunk's intake (``_intake``): the
+        # run loop handles it before it reads the queue again
+        self._held: tuple | None = None
         # per-node fault plan (core/faults.py) — an INSTANCE, not the module
         # global, so several worker nodes living in one test process never
         # share fault counters; None (the default) keeps the hot paths free
@@ -265,14 +270,20 @@ class DistributedWorker:
     # -- main loop ------------------------------------------------------
     def run(self) -> None:
         while True:
-            item = self.bridge.get_work(timeout=1.0)
+            # what ended a chunk's intake comes first: the queue's order
+            # is kept item for item (``_intake``)
+            item, self._held = self._held, None
+            if item is None:
+                item = self.bridge.get_work(timeout=1.0)
             if item is None:
                 continue
             kind, payload = item
             if kind == "_stop":
                 return
+            if kind == CHUNK_DONE:
+                continue  # of a chunk whose intake had ended before it
             try:
-                self._handle(kind, payload)
+                self._handle_guarded(kind, payload)
             except FaultCrash as e:
                 # injected node death: kill the network process abruptly so
                 # every peer sees a dropped connection (the repair paths'
@@ -281,44 +292,52 @@ class DistributedWorker:
                 self.log.warning("fault injection: %s — node going down", e)
                 self.node.crash()
                 return
-            except Exception as e:
-                self.log.exception("work %s failed", kind)
-                rid, peer = payload.get("rid"), payload.get("peer")
-                if rid and peer:
-                    resp_tag = {
-                        proto.FORWARD: proto.FORWARD_RESP,
-                        proto.BACKWARD: proto.BACKWARD_RESP,
-                        proto.GENERATE: proto.GENERATE_RESP,
-                        proto.OPTIMIZER: proto.OPTIMIZER_RESP,
-                        proto.PARAMS_REQ: proto.PARAMETERS,
-                        proto.CHECKPOINT: proto.CHECKPOINT_RESP,
-                        proto.PROOF_REQ: proto.PROOF_RESP,
-                        proto.MIGRATE: proto.MIGRATE_RESP,
-                        proto.DRAIN: proto.DRAIN_RESP,
-                        "load_stage": proto.MODULE_LOADED,
-                        "beam_continue": proto.GENERATE_RESP,
-                    }.get(kind, proto.FORWARD_RESP)
-                    # a chained hop's requester is the ORIGINATOR, not the
-                    # previous worker — route the error to it (it holds the
-                    # rid future) and name the failing worker for repair
-                    err_peer = payload.get("reply_to") or peer
-                    try:
-                        self._respond(
-                            err_peer, resp_tag, rid,
-                            {"error": f"{type(e).__name__}: {e}",
-                             "worker": self.node.node_id},
-                        )
-                    except Exception as e2:
-                        # the requester died too (the chaos suite's
-                        # validator kill lands here: the work item fails
-                        # BECAUSE the peer is gone, so the error reply
-                        # fails the same way) — an undeliverable reply
-                        # must never kill this loop; the worker keeps
-                        # serving and re-announces on the re-handshake
-                        self.log.warning(
-                            "error reply for %s to %s undeliverable: %s",
-                            kind, str(err_peer)[:8], e2,
-                        )
+
+    def _handle_guarded(self, kind: str, payload: dict) -> None:
+        """``_handle`` for one work item, a failure answered to whoever
+        asked (the run loop's items, and the GENERATE frames a chunk's
+        intake takes in ahead of it). An injected crash passes (a
+        ``BaseException``): the run loop takes the node down."""
+        try:
+            self._handle(kind, payload)
+        except Exception as e:
+            self.log.exception("work %s failed", kind)
+            rid, peer = payload.get("rid"), payload.get("peer")
+            if rid and peer:
+                resp_tag = {
+                    proto.FORWARD: proto.FORWARD_RESP,
+                    proto.BACKWARD: proto.BACKWARD_RESP,
+                    proto.GENERATE: proto.GENERATE_RESP,
+                    proto.OPTIMIZER: proto.OPTIMIZER_RESP,
+                    proto.PARAMS_REQ: proto.PARAMETERS,
+                    proto.CHECKPOINT: proto.CHECKPOINT_RESP,
+                    proto.PROOF_REQ: proto.PROOF_RESP,
+                    proto.MIGRATE: proto.MIGRATE_RESP,
+                    proto.DRAIN: proto.DRAIN_RESP,
+                    "load_stage": proto.MODULE_LOADED,
+                    "beam_continue": proto.GENERATE_RESP,
+                }.get(kind, proto.FORWARD_RESP)
+                # a chained hop's requester is the ORIGINATOR, not the
+                # previous worker — route the error to it (it holds the
+                # rid future) and name the failing worker for repair
+                err_peer = payload.get("reply_to") or peer
+                try:
+                    self._respond(
+                        err_peer, resp_tag, rid,
+                        {"error": f"{type(e).__name__}: {e}",
+                         "worker": self.node.node_id},
+                    )
+                except Exception as e2:
+                    # the requester died too (the chaos suite's
+                    # validator kill lands here: the work item fails
+                    # BECAUSE the peer is gone, so the error reply
+                    # fails the same way) — an undeliverable reply
+                    # must never kill this loop; the worker keeps
+                    # serving and re-announces on the re-handshake
+                    self.log.warning(
+                        "error reply for %s to %s undeliverable: %s",
+                        kind, str(err_peer)[:8], e2,
+                    )
 
     def _handle(self, kind: str, p: dict) -> None:
         if kind == "load_stage":
@@ -1836,11 +1855,14 @@ class DistributedWorker:
         ``hop_in`` (the validator's stamp as ml/module.py handed the
         frame to its bridge, to ``NetBridge.post_work``'s stamp) and
         ``work_wait`` (from there to now: FIFO behind whatever the loop
-        was running, which for a request that arrives during a chunk is
-        what is left of ``step_chunk``). ``chunk`` is the step of the
-        last chunk the engine finished before this moment
-        (``recorder.next_step - 1``: the id of its record and of its
-        ``tlink:chunk``), the one waited behind where the wait is long.
+        was running: for a request that arrives during a chunk's host
+        phases what is left of them, until the chunk's wait takes it in
+        (``_intake``), and what is left of ``step_chunk`` where it came
+        too late for that). ``chunk`` is the step of the chunk waited
+        behind (the id of its record and of its ``tlink:chunk``): the one
+        in flight for a frame its intake takes (``recorder.next_step``),
+        else the last the engine finished before this moment
+        (``recorder.next_step - 1``).
         Durations only where the stamps share this host's clock; leaves
         the handler's start and its cause in ``p["_way_in"]`` for the
         ``submit`` span."""
@@ -1856,10 +1878,32 @@ class DistributedWorker:
         if queued:
             sid = tracer.record_since(
                 tid, WORK_WAIT, queued, end=t_in, site=site, parent=sid,
-                **({"chunk": rt.cont.recorder.next_step - 1}
+                **({"chunk": rt.cont.recorder.next_step
+                    - (0 if rt.cont.taking_in else 1)}
                    if rt.cont is not None else {}),
             )
         p["_way_in"] = (t_in, sid)
+
+    @staticmethod
+    def _slot_knobs(p: dict) -> tuple:
+        return (
+            p.get("temperature", 0.0), p.get("top_k", 0),
+            p.get("top_p", 1.0), p.get("presence_penalty", 0.0),
+            p.get("frequency_penalty", 0.0),
+        )
+
+    @classmethod
+    def _rides_slots(cls, p: dict) -> bool:
+        """Whether a GENERATE frame flagged ``continuous`` can take the
+        slot engine at all: one prompt, scalar knobs, no beams, no
+        lookahead. Read off the frame alone, so that a chunk's intake can
+        tell before it handles one."""
+        return not (
+            len(p["prompts"]) != 1
+            or any(isinstance(v, (list, tuple)) for v in cls._slot_knobs(p))
+            or int(p.get("num_beams", 1)) > 1
+            or p.get("lookahead")
+        )
 
     def _generate_continuous(self, rt: "StageRuntime", p: dict,
                              prompts: list[list[int]]) -> bool:
@@ -1870,18 +1914,9 @@ class DistributedWorker:
         engine paths, so the flag can never fail a request."""
         from tensorlink_tpu.engine.sampling import SamplingParams
 
-        knobs = (
-            p.get("temperature", 0.0), p.get("top_k", 0),
-            p.get("top_p", 1.0), p.get("presence_penalty", 0.0),
-            p.get("frequency_penalty", 0.0),
-        )
-        if (
-            len(prompts) != 1
-            or any(isinstance(v, (list, tuple)) for v in knobs)
-            or int(p.get("num_beams", 1)) > 1
-            or p.get("lookahead")
-        ):
+        if not self._rides_slots(p):
             return False
+        knobs = self._slot_knobs(p)
         if self.draining is not None:
             # admission fence: this worker is shedding its slots — redirect
             # the request to the drain destination (the client re-issues
@@ -2280,6 +2315,10 @@ class DistributedWorker:
                 tensor_parallel=int(
                     getattr(ml, "tensor_parallel", 1) or 1
                 ),
+                # while the device runs a chunk, the job's next requests
+                # are taken off the work queue and prepared for the one
+                # after it (``_intake``)
+                intake=functools.partial(self._intake, rt),
             )
         except PagedUnsupported as e:
             # a DECLARED refusal (sliding window, a model TP can't shard):
@@ -2371,6 +2410,12 @@ class DistributedWorker:
             # fault site "worker.cont_step" (core/faults.py): one count per
             # decode chunk over a continuously-batched slot set
             self.faults.inject("worker.cont_step", job_id)
+        # set again while the chunk runs, and only then: a request its
+        # intake takes in queues no ``cont_continue`` of its own, the one
+        # below goes to the queue's tail when the chunk is over; whatever
+        # ends the chunk (an injected error before it included) leaves
+        # the flag clear, so the next request resumes the engine
+        rt.cont_scheduled = True
         try:
             more = rt.cont.step_chunk()
         except FaultCrash:
@@ -2381,6 +2426,8 @@ class DistributedWorker:
             rt.cont = None
             self._gc_kv_pools()  # release a now-tenantless shared pool
             return
+        finally:
+            rt.cont_scheduled = False
         # steady-state prefill→decode handoff: ship every slot the chunk
         # froze at its prefill boundary BEFORE deciding whether to
         # requeue — a frozen slot is invisible to step_chunk's has_work,
@@ -2391,6 +2438,65 @@ class DistributedWorker:
         self._run_handoffs(rt)
         if more or (rt.cont is not None and rt.cont.has_work()):
             self._schedule_cont(rt)
+
+    def _intake(self, rt: "StageRuntime", result):
+        """The slot engine's intake (``ContinuousEngine.intake``), run
+        from a chunk's wait while the device computes ``result``: yields,
+        one by one, the GENERATE frames of this job that arrive on the
+        work queue and take the slot engine, each as the call that
+        handles it (``_generate``, answered for like any work item); the
+        engine submits it there and prepares its admission for the next
+        chunk. It blocks in the work queue's own get: the bridge puts
+        ``CHUNK_DONE`` there when ``result`` is ready, which ends it. So
+        does the first item of any other kind (a DRAIN, a MIGRATE, a
+        ``load_stage``, another job's frame or chunk, a GENERATE of a
+        static path or one that re-attaches): it is held, and the run
+        loop handles it first when the chunk has returned, so the queue's
+        order is kept item for item. The chunk's ``cont_continue`` goes
+        to the queue's tail only then: a frame that came too late for the
+        intake is still taken before the next chunk, and no request joins
+        a later chunk than with no intake at all. An engine stepped by
+        hand, and not by the run loop's ``cont_continue`` (which
+        ``cont_scheduled`` stands for while its chunk runs), has no
+        intake: nobody would handle what it held."""
+        if not rt.cont_scheduled:
+            return
+        token = self.bridge.watch(result)
+        while True:
+            item = self.bridge.get_work(timeout=1.0)
+            if item is None:
+                continue
+            kind, payload = item
+            if kind == CHUNK_DONE:
+                if payload == token:
+                    return
+                continue  # an earlier chunk's, whose intake had ended
+            if self._taken_in(rt, kind, payload):
+                yield functools.partial(self._handle_guarded, kind, payload)
+                continue
+            self._held = item
+            return
+
+    def _taken_in(self, rt: "StageRuntime", kind: str, payload) -> bool:
+        """Whether a chunk's intake handles a work item in place. A frame
+        the test itself cannot read (a peer's body is relayed unchecked:
+        no ``prompts``, a ``num_beams`` that is no number) is another
+        kind: held, it fails under the run loop's own error reply and
+        takes no stream but its own with it, where a raise from inside
+        ``step_chunk`` would close the engine."""
+        try:
+            return bool(
+                kind == proto.GENERATE
+                and payload.get("job_id") == rt.job_id
+                and payload.get("continuous")
+                # a re-attach answers for a live stream at once, which
+                # takes a chunk's edge (``flush_stream`` with no step in
+                # flight)
+                and not payload.get("reattach")
+                and self._rides_slots(payload)
+            )
+        except Exception:
+            return False
 
     # -- live slot migration + drain (docs/FAILURE_MODEL.md) -------------
     # DRAIN (validator → this worker): fence admissions, then move every

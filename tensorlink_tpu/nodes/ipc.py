@@ -16,6 +16,12 @@ Three queues:
   with a *blocking* get (no polling; the reference's main_loop polls five
   queues per module per tick, ml/worker.py:1386-1435).
 
+A fourth kind of ``work`` item comes from this process itself:
+``(CHUNK_DONE, token)``, posted by :meth:`MLBridge.watch`'s thread when a
+device value the ML loop handed it is ready. A loop that waits for the
+next request OR the end of the chunk its device runs blocks in the one
+``get_work`` it always blocked in (ml/worker.py::_intake).
+
 Payloads may contain numpy arrays (pickled efficiently by mp via buffer
 protocol). jax arrays must be converted to numpy before crossing.
 """
@@ -32,6 +38,10 @@ from dataclasses import dataclass, field
 from typing import Any, Callable
 
 from tensorlink_tpu.core.trace import stamp
+
+
+# the kind of the work item that says a watched device value is ready
+CHUNK_DONE = "_chunk_done"
 
 
 class RemoteError(RuntimeError):
@@ -57,6 +67,11 @@ class MLBridge:
         self._rid = itertools.count(1)
         self._dispatcher: threading.Thread | None = None
         self._closed = threading.Event()
+        # watch(): the values handed over, and the thread that waits for
+        # them (started with the first)
+        self._watched: queue_mod.SimpleQueue = queue_mod.SimpleQueue()
+        self._watcher: threading.Thread | None = None
+        self._watch_token = itertools.count(1)
 
     def start(self) -> None:
         if self._dispatcher:
@@ -108,8 +123,45 @@ class MLBridge:
         except queue_mod.Empty:
             return None
 
+    def watch(self, result) -> int:
+        """Have ``(CHUNK_DONE, token)`` put on the work queue when
+        ``result`` (a device value in flight) is ready, and return the
+        token: the caller's next ``get_work`` then blocks until a work
+        item is there or the chunk is done, whichever comes first, with
+        no timed poll. The wait for the device is another thread's
+        (``block_until_ready`` releases the interpreter); everything the
+        caller does with what it gets stays on its own thread. A value
+        whose computation failed is reported ready: its reader's own
+        fetch raises."""
+        if self._watcher is None or not self._watcher.is_alive():
+            self._watcher = threading.Thread(
+                target=self._watch_loop, name="ipc-watch", daemon=True
+            )
+            self._watcher.start()
+        token = next(self._watch_token)
+        self._watched.put((token, result))
+        return token
+
+    def _watch_loop(self) -> None:
+        while True:
+            item = self._watched.get()
+            if item is None:
+                return
+            token, result = item
+            try:
+                result.block_until_ready()
+            # tlint: disable=TL005(the step's error is its reader's to raise: the driver's fetch of the same value does)
+            except Exception:
+                pass
+            del item, result  # the value is the driver's alone again
+            try:
+                self.q.work.put((CHUNK_DONE, token))
+            except (OSError, EOFError, ValueError, queue_mod.Full):
+                return  # the queue went with the node
+
     def close(self) -> None:
         self._closed.set()
+        self._watched.put(None)
 
 
 class NetBridge:

@@ -276,6 +276,9 @@ LEGACY_ENGINE_KEYS = (
     # slot binds and clears that rode a chunk's control buffer / device
     # calls the admission and retirement path still made
     "slot_binds_packed", "admit_device_calls",
+    # intake ahead: requests submitted / of those inside a chunk's wait,
+    # admissions an ahead round prepared, the intake's host microseconds
+    "submitted", "submitted_ahead", "admitted_ahead", "chunk_us_intake",
 )
 PHASES = ("between", "admit", "pack", "dispatch", "wait", "drain",
           "deliver", "post")
