@@ -1,0 +1,14 @@
+"""gated_delta_flops for the gated_delta_chunk kernel alone: the passes that
+kernel does not run read 0 (gated_delta_flops.by_pass, kernel="chunk")."""
+
+from __future__ import annotations
+
+from benchmarks.bytes_fns import gated_delta_flops
+
+
+def by_pass(chunks: list[dict], model: dict) -> list[float]:
+    return gated_delta_flops.by_pass(chunks, model, kernel="chunk")
+
+
+def gated_delta_chunk_flops(chunks: list[dict], model: dict) -> float:
+    return sum(by_pass(chunks, model))

@@ -119,7 +119,10 @@ from .paged import (
     unpack_results,
 )
 from .latent import restore_window, take_window, window_snapshot_pool
-from .sala import restore_snapshot, take_snapshot, zero_state
+from .sala import (
+    held as held_arrays, restore_snapshot, snapshot_pool, take_snapshot,
+    tree_bytes, zero_state,
+)
 from .sampling import SamplingParams, penalized, sample
 from .spec import SpecController
 from .scheduler import (
@@ -755,12 +758,15 @@ class ContinuousEngine:
         # ... as does a model whose window layers hold a ring a slot
         # (engine/latent.py): the same rule, counted under ``window_*``
         # ... and a model whose short-convolution layers hold a tail a
-        # slot: the same rule again, counted under ``conv_*``. One property
-        # says which (``ModelConfig.slot_state``)
-        held = engine.cfg.slot_state
-        self._ring = held == "gqa_window"
-        self._tail = held == "conv"
-        self._stateful = held is not None
+        # slot: the same rule again, counted under ``conv_*``
+        # ... and a model whose gated delta-rule layers hold a state AND a
+        # tail a slot, two arrays in one snapshot, counted under
+        # ``state_*``. One property says which (``ModelConfig.slot_state``)
+        kind = engine.cfg.slot_state
+        self._ring = kind == "gqa_window"
+        self._tail = kind == "conv"
+        self._delta = kind == "gated_delta"
+        self._stateful = kind is not None
         self._snap_counts, what, states = {
             None: ("state", "latent pages", ""),
             "lightning": ("state", "pages and recurrent states",
@@ -769,7 +775,10 @@ class ContinuousEngine:
                            "window rings"),
             "conv": ("conv", "pages and convolution tails",
                      "convolution tails"),
-        }[held]
+            "gated_delta": ("state", "pages, recurrent states and "
+                            "convolution tails",
+                            "recurrent states and convolution tails"),
+        }[kind]
         self._held_what = what
         if self._latent:
             asked = str(kv_quant or "none")
@@ -804,6 +813,9 @@ class ContinuousEngine:
                 "a model with short-convolution layers does not draft: a "
                 "rejected draft row would have moved the slot's tail"
                 if self._tail else
+                "a model with gated delta-rule layers does not draft: a "
+                "rejected draft row would have advanced the slot's state "
+                "and moved its tail" if self._delta else
                 "a model with recurrent layers does not draft: a rejected "
                 "draft row would have advanced the slot's state")
         if int(prefill_chunk) <= 0:
@@ -938,9 +950,7 @@ class ContinuousEngine:
                 self._snaps = window_snapshot_pool(
                     self.cache, n, self.cfg.ring_window)
             else:
-                st = self.cache.state
-                self._snaps = jnp.zeros((n,) + st.shape[:1] + st.shape[2:],
-                                        st.dtype)
+                self._snaps = snapshot_pool(self.cache, n)
             self._snap_free = list(range(n))
             self.prefix.on_drop = self._drop_snapshot
         # -- tiered prefix cache (docs/SERVING.md "Tiered prefix cache") -
@@ -2037,7 +2047,7 @@ class ContinuousEngine:
             else:
                 self.cache = restore_snapshot(self.cache, self._snaps, *place)
             self._count(f"{self._snap_counts}_snapshots_restored")
-        elif self._ring or self._tail:
+        elif self._ring or self._tail or self._delta:
             return  # ... and the ragged pass reads zeros before position 0
         else:
             self.cache = zero_state(self.cache, np.int32(slot))
@@ -2081,7 +2091,8 @@ class ContinuousEngine:
                 np.int32(pos))
         else:
             self._snaps = take_snapshot(
-                self._snaps, self.cache.state, np.int32(slot), np.int32(idx))
+                self._snaps, held_arrays(self.cache), np.int32(slot),
+                np.int32(idx))
         req.snaps[pos] = idx
         self._count(f"{self._snap_counts}_snapshots_taken")
 
@@ -3437,6 +3448,25 @@ class ContinuousEngine:
                 raise AssertionError(
                     f"tail conservation violated: {c.state.shape} for "
                     f"{want}")
+        if self._delta:
+            # one state and one tail a gated-delta layer and slot, and a
+            # snapshot pool that holds both under every place
+            c, gd = self.cache, self.cfg.latent_of("gated_delta")
+            n = self.cfg.layer_kinds.count("gated_delta")
+            want = {"state": (n, self.max_slots, gd.key_dim,
+                              gd.n_heads * gd.value_dim),
+                    "tail": (n, self.max_slots, gd.tail, gd.conv_width)}
+            got = {k: a.shape for k, a in held_arrays(c).items()}
+            # [places, layers, ...]: every array under the same places
+            pool = {} if self._snaps is None else {
+                k: a.shape for k, a in self._snaps.items()}
+            places = {v[0] for v in pool.values()}
+            if got != want or len(places) > 1 or any(
+                    v[1:] != want[k][:1] + want[k][2:]
+                    for k, v in pool.items()) or set(pool) - set(want):
+                raise AssertionError(
+                    f"state conservation violated: a slot holds {got} for "
+                    f"{want}, the snapshot pool {pool}")
         if self._snaps is not None:
             self._check_snapshot_conservation()
 
@@ -3449,7 +3479,8 @@ class ContinuousEngine:
         problems = []
         if len(owned) != len(set(owned)):
             problems.append("a snapshot place has two owners")
-        if len(owned) != self._snaps.shape[0]:
+        n_places = jax.tree.leaves(self._snaps)[0].shape[0]
+        if len(owned) != n_places:
             problems.append("leak: the owners do not sum to the pool")
         for idx, node in self._snap_nodes.items():
             if node.snap != idx or self.prefix._by_page.get(node.page) is not node:
@@ -3459,7 +3490,7 @@ class ContinuousEngine:
                 "snapshot conservation violated: " + "; ".join(problems)
                 + f" [free={len(self._snap_free)} "
                 f"trie={len(self._snap_nodes)} slots={len(held)} vs "
-                f"total={self._snaps.shape[0]}]"
+                f"total={n_places}]"
             )
 
     def _pages_in_transit(self) -> int:
@@ -3483,10 +3514,11 @@ class ContinuousEngine:
         (test-pinned; see docs/SERVING.md "Telemetry")."""
         out = dict(self.stats)
         # the slots' states, or the tails of a model with conv layers
+        # ... or the states AND tails of one with gated-delta layers
         held = self.cache.state_bytes if self._stateful else 0
         state_bytes, tail_bytes = (0, held) if self._tail else (held, 0)
-        snap_bytes = 0 if self._snaps is None else (
-            self._snaps.size * self._snaps.dtype.itemsize)
+        delta_tails = self.cache.tail_bytes if self._delta else 0
+        snap_bytes = 0 if self._snaps is None else tree_bytes(self._snaps)
         ring_bytes = self.cache.ring_bytes if self._ring else 0
         # the snapshot pool is the states', the windows' or the tails':
         # (bytes, places held) under the name its counters carry
@@ -3558,7 +3590,7 @@ class ContinuousEngine:
             # states and snapshots together (0 for other models)
             "lightning_state_bytes": state_bytes,
             "state_snapshot_bytes": of_states[0],
-            "state_pool_bytes": state_bytes + of_states[0],
+            "state_pool_bytes": state_bytes + delta_tails + of_states[0],
             "state_snapshots_resident": of_states[1],
             # a model whose window layers hold a ring a slot: the rings,
             # and the rings and their snapshots together

@@ -83,6 +83,24 @@ live row; a continuation step is ``kernel`` taps. No page describes the
 tail: a prefix hit restores a snapshot of it (``engine/sala.py``'s
 ``take_snapshot`` / ``restore_snapshot``, generic over a state's trailing
 dims) under the rule of the recurrent states.
+
+**Gated delta-rule layers** (kind ``gated_delta``,
+models/base.py::GatedDelta) stand beside ``gqa_full`` layers too. A slot
+holds TWO arrays of them, a layer: the float32 state (``state`` ``[Lg, S,
+dk, H dv]``: ops/gated_delta.py has the recurrence and why the heads lie
+along the lanes) and the tail of the convolution in front of q, k and v
+(``tail`` ``[Lg, S, kernel - 1, conv_width]``, in the activations' dtype).
+Both passes update both in place: a continuation step is one position
+(``gated_delta_step``), the ragged pass the chunkwise form over a slot's
+live rows (``gated_delta_chunk``), state and tail written at the slot's
+last live row, a slot without rows keeps both, a slot whose block starts
+at position 0 starts from zeros whatever the arrays hold (so an admission
+without a prefix makes no device call). A snapshot is both arrays, taken
+and restored together (``ModelConfig.slot_arrays``, engine/sala.py).
+
+**The norm's place** (``ModelConfig.norm_position``): ``"post"`` norms what
+a branch ADDS (``x + norm(op(x))``, the OLMo family's block) in the
+``gqa_full`` and ``gated_delta`` kinds, where ``"pre"`` norms its input.
 """
 
 from __future__ import annotations
@@ -96,11 +114,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..models.base import (
-    CONV_KIND, GQA_KINDS, SALA_KINDS, GqaAttn, LatentAttn, ModelConfig,
+    CONV_KIND, GATED_DELTA, GQA_KINDS, SALA_KINDS, GqaAttn, LatentAttn,
+    ModelConfig,
 )
 from ..models.latent import (
     _rms,
     EXPERT_STACKS,
+    GATED_DELTA_SCOPE,
     INDEX_SELECT,
     LATENT_ATTN,
     MOE,
@@ -113,6 +133,9 @@ from ..models.latent import (
     absorbed_query,
     attend_absorbed,
     attend_materialised,
+    gated_delta_in,
+    gated_delta_out,
+    gated_delta_qkv,
     gated_mlp,
     gqa_qkv,
     index_scores,
@@ -128,6 +151,12 @@ from ..models.latent import (
 )
 from ..models.quant import matmul as _mm
 from ..models.sala import is_sala, step_stats
+from ..ops.gated_delta import (
+    gated_delta_chunk,
+    gated_delta_chunk_ref,
+    gated_delta_step,
+    gated_delta_step_ref,
+)
 from ..ops.attention import (
     paged_attention,
     paged_attention_ref,
@@ -200,6 +229,10 @@ class LatentPagedCache:
     # Hkv, page, hd]`` (module docstring); not page pools of the trie
     wk: jax.Array | None = None
     wv: jax.Array | None = None
+    # ``gated_delta`` layers: the convolution's tails ``[Lg, S, kernel - 1,
+    # conv_width]`` beside their float32 states in ``state`` ``[Lg, S, dk,
+    # H dv]``
+    tail: jax.Array | None = None
 
     POOLS = ("full", "index", "slide", "k", "v", "ksum")  # page axis 1
 
@@ -237,6 +270,13 @@ class LatentPagedCache:
             sc = sizes[CONV_KIND]
             extra["state"] = jnp.zeros(
                 (n[CONV_KIND], max_slots, sc.tail, sc.width), dt)
+        if n.get(GATED_DELTA):
+            gd = sizes[GATED_DELTA]
+            extra["state"] = jnp.zeros(
+                (n[GATED_DELTA], max_slots, gd.key_dim,
+                 gd.n_heads * gd.value_dim), jnp.float32)
+            extra["tail"] = jnp.zeros(
+                (n[GATED_DELTA], max_slots, gd.tail, gd.conv_width), dt)
 
         def pool(kind, width):
             # no pool for a kind, or a selector, the model has not: a
@@ -276,10 +316,16 @@ class LatentPagedCache:
 
     @property
     def state_bytes(self) -> int:
-        """The lightning layers' states (the conv layers' tails) of every
-        slot."""
+        """The lightning or gated-delta layers' states (the conv layers'
+        tails) of every slot."""
         return 0 if self.state is None else (
             self.state.size * self.state.dtype.itemsize)
+
+    @property
+    def tail_bytes(self) -> int:
+        """The gated-delta layers' tails of every slot."""
+        return 0 if self.tail is None else (
+            self.tail.size * self.tail.dtype.itemsize)
 
     @property
     def ring_pages(self) -> int:
@@ -357,22 +403,29 @@ def unsupported(cfg: ModelConfig) -> str | None:
     each kind in use with its sizes."""
     kinds = set(cfg.layer_kinds)
     sizes = dict(cfg.latent)
-    served = {"full", "sliding"} | set(SALA_KINDS) | set(GQA_KINDS) | {
-        CONV_KIND}
+    beside = {CONV_KIND, GATED_DELTA}  # kinds that stand beside gqa_full
+    served = {"full", "sliding"} | set(SALA_KINDS) | set(GQA_KINDS) | beside
     if not kinds <= served or not kinds <= set(sizes):
         return (f"layer kinds {sorted(kinds)} with sizes for "
                 f"{sorted(sizes)} (served: full, sliding, sparse, "
-                "lightning, gqa_full, gqa_window, conv)")
-    if kinds & (set(GQA_KINDS) | {CONV_KIND}):
-        if kinds - set(GQA_KINDS) - {CONV_KIND}:
-            return ("grouped-query or short-convolution layers beside "
-                    "latent or sparse layers")
+                "lightning, gated_delta, gqa_full, gqa_window, conv)")
+    if kinds & (set(GQA_KINDS) | beside):
+        if kinds - set(GQA_KINDS) - beside:
+            return ("grouped-query, short-convolution or gated-delta "
+                    "layers beside latent or sparse layers")
         if "gqa_full" not in kinds:
-            return ("window or short-convolution layers without a full "
-                    "layer (no page pool)")
+            return ("window, short-convolution or gated-delta layers "
+                    "without a full layer (no page pool)")
         if CONV_KIND in kinds and "gqa_window" in kinds:
             return ("short-convolution layers beside window layers (a "
                     "slot's snapshot holds a ring or a tail, not both)")
+        if GATED_DELTA in kinds and kinds & {CONV_KIND, "gqa_window"}:
+            return ("gated-delta layers beside short-convolution or window "
+                    "layers (a slot's snapshot holds a ring, a tail, or a "
+                    "state and its tail: one of them)")
+        if GATED_DELTA in kinds and sizes[GATED_DELTA].n_heads % 2:
+            return ("an odd number of gated-delta heads (the kernels take "
+                    "the heads two at a time)")
         if sizes["gqa_full"].window is not None:
             return "a window on the gqa_full layers"
         if "gqa_window" in kinds and sizes["gqa_window"].window is None:
@@ -706,12 +759,12 @@ def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     ga = cfg.latent_of(kind)
     ap = lp["attn"]
 
-    def project(x, cos, sin):
-        h = _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
-        return gqa_qkv(h, ap, ga, cos, sin, eps=cfg.norm_eps)
+    def project(x, *rope):  # a kind without positions has no table
+        return gqa_qkv(_pre(x, lp, cfg), ap, ga, *(rope or (None, None)),
+                       eps=cfg.norm_eps)
 
     with jax.named_scope("attn"):
-        q = _by_tile(project, ctx, x, *ctx.rope[kind])
+        q = _by_tile(project, ctx, x, *ctx.rope.get(kind, ()))
         qs, k, v = (_expand(q[n], ctx) for n in ("q", "k", "v"))
     if kind == "gqa_window":
         names, bt = ("wk", "wv"), ctx.ring_bt
@@ -732,8 +785,54 @@ def _gqa_attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
         if beside:
             o = o[..., ga.head_dim:]
     with jax.named_scope("attn"):
-        added = _attn_out(_collect(o, ctx), q.get("gate"), ap, x.dtype, ctx)
+        added = _post(
+            _attn_out(_collect(o, ctx), q.get("gate"), ap, x.dtype, ctx),
+            lp, ctx)
     return added, pools._replace(**{names[0]: kp, names[1]: vp})
+
+
+def _pre(x, lp, cfg: ModelConfig):
+    """A branch's input: normed, unless the model norms what the branch
+    adds (module docstring)."""
+    if cfg.norm_position == "post":
+        return x
+    return _rms(x, lp["ln1"]["scale"], cfg.norm_eps)
+
+
+def _post(added, lp, ctx: _Ctx):
+    """What a branch adds, normed where the model norms that."""
+    cfg = ctx.cfg
+    if cfg.norm_position != "post":
+        return added
+    return _by_tile(
+        lambda a: _rms(a, lp["ln1"]["scale"], cfg.norm_eps), ctx, added)
+
+
+def _begins(ctx: _Ctx):
+    """The slots whose block is their sequence's first ``[S]``: no position
+    lies before it."""
+    return (ctx.positions[:, 0] == 0) & (ctx.n_valid > 0)
+
+
+def _conv_pass(z, tail, taps, ctx: _Ctx):
+    """The depthwise causal convolution of either pass over ``z`` (``x``'s
+    layout) behind the slots' carried ``tail`` ``[S, kernel - 1, W]``:
+    ``(c, tail)``, ``c`` float32 in ``x``'s layout and the tails as the
+    pass leaves them."""
+    if ctx.plan is None:  # a continuation step: ``kernel`` taps
+        zc = jnp.concatenate([tail, z.astype(tail.dtype)], axis=1)
+        c = short_conv_taps(zc, taps, 1)
+        return c, jnp.where(ctx.row_ok[:, :, None], zc[:, 1:], tail)
+    # a slot's rows behind its carried tail; no position lies before a
+    # sequence's first
+    tail = jnp.where(_begins(ctx)[:, None, None], 0, tail)
+    zb = _expand(z, ctx).astype(tail.dtype)  # [S, C, W]
+    zc = jnp.concatenate([tail, zb], axis=1)
+    c = _collect(short_conv_taps(zc, taps, zb.shape[1]), ctx)
+    # the tail behind the slot's last live row (a slot without rows keeps
+    # its own)
+    at = ctx.n_valid[:, None] + jnp.arange(tail.shape[1])[None, :]
+    return c, jnp.take_along_axis(zc, at[:, :, None], axis=1)
 
 
 def _short_conv(x, lp, li, pools: tuple, ctx: _Ctx):
@@ -743,30 +842,55 @@ def _short_conv(x, lp, li, pools: tuple, ctx: _Ctx):
     cfg = ctx.cfg
     ap = lp["attn"]
     tail = pools.state[li]  # [S, kernel - 1, width]
-    n_tail = tail.shape[1]
     with jax.named_scope(SHORT_CONV):
         z, gate = _by_tile(lambda x: short_conv_in(
             _rms(x, lp["ln1"]["scale"], cfg.norm_eps), ap), ctx, x)
-        if ctx.plan is None:  # a continuation step: ``kernel`` taps
-            zc = jnp.concatenate([tail, z.astype(tail.dtype)], axis=1)
-            c = short_conv_taps(zc, ap["taps"], 1)
-            new = jnp.where(ctx.row_ok[:, :, None], zc[:, 1:], tail)
-        else:
-            # a slot's rows behind its carried tail; no position lies
-            # before a sequence's first
-            begins = (ctx.positions[:, 0] == 0) & (ctx.n_valid > 0)
-            tail = jnp.where(begins[:, None, None], 0, tail)
-            zb = _expand(z, ctx).astype(tail.dtype)  # [S, C, width]
-            zc = jnp.concatenate([tail, zb], axis=1)
-            c = _collect(short_conv_taps(zc, ap["taps"], zb.shape[1]), ctx)
-            # the tail behind the slot's last live row (a slot without
-            # rows keeps its own)
-            at = ctx.n_valid[:, None] + jnp.arange(n_tail)[None, :]
-            new = jnp.take_along_axis(zc, at[:, :, None], axis=1)
+        c, new = _conv_pass(z, tail, ap["taps"], ctx)
         added = _by_tile(lambda gate, c: _mm(
             (gate.astype(jnp.float32) * c).astype(x.dtype), ap["w_out"]),
             ctx, gate, c)
     return added, pools._replace(state=pools.state.at[li].set(new))
+
+
+def _gated_delta(x, lp, li, pools: tuple, ctx: _Ctx):
+    """:func:`_attention` for a ``gated_delta`` layer (module docstring):
+    what the layer adds to ``x``, and the slots' states and tails as the
+    pass leaves them (layer ``li`` of ``pools.state`` / ``pools.tail``)."""
+    cfg = ctx.cfg
+    gd = cfg.latent_of(GATED_DELTA)
+    ap = lp["attn"]
+    state = pools.state
+    with jax.named_scope(SHORT_CONV):
+        z, gate, g, beta = _by_tile(
+            lambda x: gated_delta_in(_pre(x, lp, cfg), ap, gd), ctx, x)
+        c, tail = _conv_pass(z, pools.tail[li], ap["taps"], ctx)
+    with jax.named_scope(GATED_DELTA_SCOPE):
+        q, k, v = _by_tile(lambda c: gated_delta_qkv(c, gd), ctx, c)
+        if ctx.plan is None:  # a continuation step
+            args = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+            active = ctx.row_ok[:, 0]
+            if ctx.kernel:
+                o, state = gated_delta_step(*args, state, active, li)
+            else:
+                o, new = gated_delta_step_ref(*args, state[li], active)
+                state = state.at[li].set(new)
+            o = o[:, None]  # [S, 1, H, dv]
+        else:
+            args = tuple(_expand(a, ctx) for a in (q, k, v, g, beta))
+            if ctx.kernel:
+                o, state = gated_delta_chunk(
+                    *args, state, ctx.n_valid, _begins(ctx), li)
+            else:
+                o, new = gated_delta_chunk_ref(
+                    *args, state[li], ctx.n_valid, _begins(ctx))
+                state = state.at[li].set(new)
+            o = _collect(o, ctx)
+    with jax.named_scope("attn"):
+        added = _post(_by_tile(
+            lambda o, gate: gated_delta_out(
+                o, gate, ap, cfg.norm_eps, x.dtype), ctx, o, gate), lp, ctx)
+    return added, pools._replace(
+        state=state, tail=pools.tail.at[li].set(tail))
 
 
 def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
@@ -777,6 +901,8 @@ def _attention(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     cfg = ctx.cfg
     if kind == CONV_KIND:
         return _short_conv(x, lp, li, pools, ctx)
+    if kind == GATED_DELTA:
+        return _gated_delta(x, lp, li, pools, ctx)
     if kind in SALA_KINDS:
         from . import sala
 
@@ -819,15 +945,20 @@ def _layer(x, lp, kind: str, li, pools: tuple, ctx: _Ctx):
     S, T, d = x.shape
     added, pools = _attention(x, lp, kind, li, pools, ctx)
 
+    post = cfg.norm_position == "post"  # norm what the branch adds
+
     def normed(x, added):
         with jax.named_scope("attn"):
             x = x + added
-        return x, _rms(x, lp["ln2"]["scale"], cfg.norm_eps)
+        return x, (x if post else _rms(x, lp["ln2"]["scale"], cfg.norm_eps))
 
     def dense(x, added):
         x, h = normed(x, added)
         with jax.named_scope("mlp"):
-            return x + gated_mlp(h, lp["mlp"])
+            y = gated_mlp(h, lp["mlp"])
+            if post:
+                y = _rms(y, lp["ln2"]["scale"], cfg.norm_eps)
+            return x + y
 
     if "mlp" in lp:
         return _by_tile(dense, ctx, x, added), pools
@@ -864,6 +995,7 @@ class Pools(NamedTuple):
     state: jax.Array | None = None
     wk: jax.Array | None = None
     wv: jax.Array | None = None
+    tail: jax.Array | None = None
 
 
 def cache_pools(cache: LatentPagedCache) -> Pools:

@@ -87,30 +87,70 @@ def init_pools(cfg, max_slots: int, n_pages: int, page_size: int, dt) -> dict:
     return out
 
 
-# -- a slot's state and its snapshots (engine/continuous.py) ---------------
-# A snapshot is one slot's state of every lightning layer, ``[Ll, H, hd,
-# hd]``; the engine's pool of them is ``[N, Ll, H, hd, hd]``.
+# -- what a slot holds whole, and its snapshots (engine/continuous.py) -----
+# A slot of a model with recurrent layers holds, beside its pages, the
+# arrays ``ModelConfig.slot_arrays`` names (a lightning layer's states; a
+# conv layer's tails; a gated-delta layer's states AND tails), each ``[L,
+# S, ...]``. A snapshot is one slot's part of every one of them, taken and
+# restored TOGETHER; the engine's pool is a dict of ``[N, L, ...]`` arrays
+# under the same names. These are the entry points for all of them; where
+# a model holds ONE array, the array itself stands for ``{"state": array}``
+# (in :func:`held`'s result and as the pool).
+
+
+def _named(cache) -> dict:
+    return {n: getattr(cache, n) for n in ("state", "tail")
+            if getattr(cache, n, None) is not None}
+
+
+def held(cache):
+    """The arrays ``cache`` holds a slot at a time, by field name (the
+    array, where it is one)."""
+    named = _named(cache)
+    return named["state"] if set(named) == {"state"} else named
+
+
+def snapshot_pool(cache, n: int):
+    """``n`` empty places for a snapshot of everything :func:`held`."""
+    return jax.tree.map(
+        lambda a: jnp.zeros((n,) + a.shape[:1] + a.shape[2:], a.dtype),
+        held(cache))
+
+
+def tree_bytes(tree) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
 
 
 # tlint: one-program
 @partial(jax.jit, donate_argnames=("snaps",))
 def take_snapshot(snaps, state, slot, idx):
-    """``slot``'s state into place ``idx`` of the snapshot pool."""
-    return snaps.at[idx].set(state[:, slot])
+    """``slot``'s part of ``state`` (an array, or :func:`held`'s dict) into
+    place ``idx`` of the snapshot pool ``snaps`` (of the same form)."""
+    return jax.tree.map(lambda sn, st: sn.at[idx].set(st[:, slot]),
+                        snaps, state)
+
+
+def _set_slot(cache, slot, value):
+    """``value(name, array)`` as ``slot``'s part of every array held."""
+    return replace(cache, **{
+        name: a.at[:, slot].set(value(name, a))
+        for name, a in _named(cache).items()})
 
 
 # tlint: one-program
 @partial(jax.jit, donate_argnames=("cache",))
 def restore_snapshot(cache, snaps, slot, idx):
-    """Place ``idx`` of the snapshot pool as ``slot``'s state."""
-    return replace(cache, state=cache.state.at[:, slot].set(snaps[idx]))
+    """Place ``idx`` of the snapshot pool as what ``slot`` holds."""
+    if not isinstance(snaps, dict):
+        snaps = {"state": snaps}
+    return _set_slot(cache, slot, lambda name, _: snaps[name][idx])
 
 
 # tlint: one-program
 @partial(jax.jit, donate_argnames=("cache",))
 def zero_state(cache, slot):
     """``slot`` starts a sequence: no position has been seen."""
-    return replace(cache, state=cache.state.at[:, slot].set(0.0))
+    return _set_slot(cache, slot, lambda _, a: jnp.zeros((), a.dtype))
 
 
 # ---------------------------------------------------------------------------

@@ -217,6 +217,9 @@ class GqaAttn:
     # an RMSNorm with a learned weight over the ``head_dim`` dims of every
     # query and key head, before the rotation
     qk_norm: bool = False
+    # ... or over the WHOLE query and key projections (the OLMo family's);
+    # ``rope_dim`` 0: no rotation (order comes from other layers)
+    qk_norm_full: bool = False
 
     @property
     def softmax_scale(self) -> float:
@@ -230,7 +233,8 @@ class GqaAttn:
     def param_count(self, d: int) -> int:
         q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
         return (d * q + 2 * d * kv + (d * self.n_heads if self.gate else 0)
-                + (2 * self.head_dim if self.qk_norm else 0) + q * d)
+                + (2 * self.head_dim if self.qk_norm else 0)
+                + (q + kv if self.qk_norm_full else 0) + q * d)
 
 
 @dataclass(frozen=True)
@@ -259,6 +263,55 @@ class ShortConv:
         return d * 3 * self.width + self.width * self.kernel + self.width * d
 
 
+@dataclass(frozen=True)
+class GatedDelta:
+    """Sizes of a gated delta-rule layer (kind ``"gated_delta"``; Gated
+    DeltaNet, arXiv:2412.06464): ``n_heads`` heads of keys ``key_dim`` and
+    values ``value_dim`` wide, a depthwise causal convolution of ``kernel``
+    taps and SiLU in front of q, k and v, a decay and a step size a head
+    that are data, a float32 state ``[key_dim, n_heads value_dim]`` a slot
+    (ops/gated_delta.py has the recurrence and the layout), an RMSNorm a
+    head and a SiLU gate on the output. ``neg_eigval``: the step size is
+    ``2 sigmoid`` (a transition's eigenvalue may go negative), else
+    ``sigmoid``. What a slot holds of such a layer is the state AND the
+    last ``kernel - 1`` positions of the convolution's input, its *tail*
+    (engine/latent.py): no page describes either."""
+
+    n_heads: int
+    key_dim: int
+    value_dim: int
+    kernel: int
+    neg_eigval: bool = True
+
+    @property
+    def rope_dim(self) -> int:
+        """No positions: the recurrence and the convolution are causal by
+        order."""
+        return 0
+
+    @property
+    def tail(self) -> int:
+        return self.kernel - 1
+
+    @property
+    def conv_width(self) -> int:
+        """Channels the convolution runs over: q, k and v."""
+        return self.n_heads * (2 * self.key_dim + self.value_dim)
+
+    @property
+    def state_bytes(self) -> int:
+        """One slot's state of one layer, float32."""
+        return self.n_heads * self.key_dim * self.value_dim * 4
+
+    def param_count(self, d: int) -> int:
+        H, v = self.n_heads, self.n_heads * self.value_dim
+        # q, k, v and the output gate; the decay's and the step size's
+        # projections; the taps; A_log, dt_bias; the output norm; w_o
+        return (d * (self.conv_width + v) + 2 * d * H
+                + self.conv_width * self.kernel + 2 * H + self.value_dim
+                + v * d)
+
+
 # layer kinds whose cache is not a latent row: engine/sala.py serves them
 SALA_KINDS = ("sparse", "lightning")
 # ... and the grouped-query kinds of a model whose layers differ
@@ -267,6 +320,8 @@ GQA_KINDS = ("gqa_full", "gqa_window")
 # ... beside which gated short-convolution layers may stand
 # (engine/latent.py): a tail a slot and layer
 CONV_KIND = "conv"
+# ... or gated delta-rule layers: a state AND a tail a slot and layer
+GATED_DELTA = "gated_delta"
 
 
 @dataclass(frozen=True)
@@ -396,18 +451,28 @@ class ModelConfig:
         """What a slot of the engine holds of this model that no page
         chain describes, by the kind of layer that carries it: the
         ``"lightning"`` layers' float32 states, the ``"gqa_window"``
-        layers' rings of keys and values, the ``"conv"`` layers' tails;
-        None: pages alone. THE answer to "does a slot hold a state": what
+        layers' rings of keys and values, the ``"conv"`` layers' tails,
+        the ``"gated_delta"`` layers' states and tails; None: pages alone
+        (:attr:`slot_arrays` names the arrays). THE answer to "does a slot hold a state": what
         reuses or moves a slot of such a model restores a snapshot and
         replays, or refuses (engine/continuous.py, parallel/planner.py)."""
-        return next((k for k in ("lightning", "gqa_window", CONV_KIND)
+        return next((k for k in ("lightning", "gqa_window", CONV_KIND,
+                                 GATED_DELTA)
                      if k in self.layer_kinds), None)
 
     @property
+    def slot_arrays(self) -> tuple:
+        """The arrays of the engine's cache a slot holds whole beside its
+        pages, by field name (engine/latent.py::LatentPagedCache): what a
+        snapshot takes and restores TOGETHER (engine/sala.py). A ring is
+        not one of them (its snapshot is a window of pages)."""
+        return {"lightning": ("state",), CONV_KIND: ("state",),
+                GATED_DELTA: ("state", "tail")}.get(self.slot_state, ())
+
+    @property
     def recurrent(self) -> bool:
-        """A slot's state is one array a kind (states, tails), snapshotted
-        whole."""
-        return self.slot_state in ("lightning", CONV_KIND)
+        """A slot holds arrays (states, tails) that are snapshotted whole."""
+        return bool(self.slot_arrays)
 
     @property
     def ring_window(self) -> int | None:
@@ -498,7 +563,9 @@ class ModelConfig:
         n = (1 if self.tie_embeddings else 2) * v * d + d
         for i, kind in enumerate(self.layer_kinds):
             n += self.latent_of(kind).param_count(d) + 2 * d
-            n += 3 * d * self.d_ff if i < self.n_dense_layers else moe
+            # no experts at all: every layer keeps the dense MLP
+            dense = i < self.n_dense_layers or not self.n_experts
+            n += 3 * d * self.d_ff if dense else moe
         return n
 
     def held_param_count(self) -> int:
@@ -520,6 +587,8 @@ def _latent_attn(d: dict):
         return SparseAttn(**d)
     if "q_rank" in d:
         return LatentAttn(**d)
+    if "key_dim" in d:
+        return GatedDelta(**d)
     if "kernel" in d:
         return ShortConv(**d)
     return (GqaAttn if "n_kv_heads" in d else LinearAttn)(**d)

@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .base import GqaAttn, LatentAttn, ModelConfig, ShortConv
+from .base import GatedDelta, GqaAttn, LatentAttn, ModelConfig, ShortConv
 from .quant import matmul as _mm
 from .transformer import apply_rope, rope_tables
 
@@ -56,6 +56,7 @@ LATENT_ATTN = "tlink.latent_attn"
 WINDOW_ATTN = "tlink.window_attn"
 INDEX_SELECT = "tlink.index_select"
 SHORT_CONV = "tlink.short_conv"
+GATED_DELTA_SCOPE = "tlink.gated_delta"
 MOE = "tlink.moe"
 
 # what a step counts of its own routing and selection, in this order, in
@@ -161,6 +162,25 @@ def _init_tree(key, cfg: ModelConfig, dt) -> dict:
                 "w_out": dense(stack, la.width, d),
             }
         H = la.n_heads
+        if isinstance(la, GatedDelta):
+            # q, k and v in one projection (the convolution runs over its
+            # channels in that order), the output gate, the decay's and
+            # the step size's inputs; ``A_log`` / ``dt_bias`` (float32)
+            # seeded so that a head forgets a tenth to a quarter a position
+            v = H * la.value_dim
+            f32 = lambda *shape, at, scale: at + draw(  # noqa: E731
+                tuple(stack) + shape, scale, jnp.float32)
+            return {
+                "w_qkv": dense(stack, d, la.conv_width),
+                "taps": dense(stack, la.kernel, la.conv_width,
+                              scale=la.kernel**-0.5),
+                "w_a": dense(stack, d, H), "w_b": dense(stack, d, H),
+                "A_log": f32(H, at=0.0, scale=0.5),
+                "dt_bias": f32(H, at=-2.0, scale=0.5),
+                "w_g": dense(stack, d, v),
+                "o_norm": 1 + dense(stack, la.value_dim, scale=0.1),
+                "wo": dense(stack, v, d),
+            }
         if isinstance(la, GqaAttn):
             # fan-in scale: unit-RMS inputs give q and k entries of order
             # one and scores of order one under 1 / sqrt(head_dim)
@@ -173,6 +193,10 @@ def _init_tree(key, cfg: ModelConfig, dt) -> dict:
                 **({"q_norm": 1 + dense(stack, la.head_dim, scale=0.1),
                     "k_norm": 1 + dense(stack, la.head_dim, scale=0.1)}
                    if la.qk_norm else {}),
+                # ... or over the whole projection
+                **({"q_norm": 1 + dense(stack, q, scale=0.1),
+                    "k_norm": 1 + dense(stack, kv, scale=0.1)}
+                   if la.qk_norm_full else {}),
                 "wo": dense(stack, q, d),
             }
         p = {
@@ -241,10 +265,11 @@ def _init_tree(key, cfg: ModelConfig, dt) -> dict:
     return {
         "embed": {"tok": dense((), cfg.vocab_size, d, scale=0.02)},
         "lead": [layer(k, True) for k in pat.lead],
+        # a model without experts keeps the dense MLP in every layer
         "periods": tuple(
-            layer(k, False, (pat.n_periods,)) for k in pat.period
+            layer(k, not cfg.n_experts, (pat.n_periods,)) for k in pat.period
         ),
-        "tail": [layer(k, False) for k in pat.tail],
+        "tail": [layer(k, not cfg.n_experts) for k in pat.tail],
         "final_norm": {"scale": ones((), d)},
         # a tied head is the embedding (transformer._logits)
         **({} if cfg.tie_embeddings
@@ -295,17 +320,28 @@ def gqa_qkv(h, ap: dict, ga: GqaAttn, cos, sin, eps: float = 0.0) -> dict:
     """The projections of one grouped-query layer over ``h`` ``[B, T, d]``:
     ``q`` ``[B, T, H, hd]`` and ``k`` ``[B, T, Hkv, hd]``, each head
     normalised first where the kind has ``qk_norm`` (``eps``: the
-    model's), the first ``rope_dim`` dims of each head rotated, ``v`` ``[B,
-    T, Hkv, hd]`` and, with a gate, ``gate`` ``[B, T, H]`` (float32)."""
+    model's), or the whole projections where it has ``qk_norm_full``, the
+    first ``rope_dim`` dims of each head rotated (``cos`` None: none),
+    ``v`` ``[B, T, Hkv, hd]`` and, with a gate, ``gate`` ``[B, T, H]``
+    (float32)."""
     B, T = h.shape[:2]
     hd = ga.head_dim
-    q = _mm(h, ap["wq"]).reshape(B, T, ga.n_heads, hd)
-    k = _mm(h, ap["wk"]).reshape(B, T, ga.n_kv_heads, hd)
-    if "q_norm" in ap:
+
+    def project(w, norm, n_heads):
+        p = _mm(h, ap[w])
+        if ga.qk_norm_full:  # over the whole projection
+            p = _rms(p, ap[norm], eps)
+        return p.reshape(B, T, n_heads, hd)
+
+    q = project("wq", "q_norm", ga.n_heads)
+    k = project("wk", "k_norm", ga.n_kv_heads)
+    if "q_norm" in ap and not ga.qk_norm_full:
         q, k = _rms(q, ap["q_norm"], eps), _rms(k, ap["k_norm"], eps)
+    if cos is not None:
+        q = _rope_prefix(q, cos, sin, ga.rope_dim)
+        k = _rope_prefix(k, cos, sin, ga.rope_dim)
     out = {
-        "q": _rope_prefix(q, cos, sin, ga.rope_dim),
-        "k": _rope_prefix(k, cos, sin, ga.rope_dim),
+        "q": q, "k": k,
         "v": _mm(h, ap["wv"]).reshape(B, T, ga.n_kv_heads, hd),
     }
     if "w_g" in ap:
@@ -328,6 +364,47 @@ def short_conv_taps(zc, taps, n: int):
     w = taps.astype(jnp.float32)
     return sum(w[j] * zc[:, j:j + n].astype(jnp.float32)
                for j in range(w.shape[0]))
+
+
+def gated_delta_in(h, ap: dict, gd: GatedDelta):
+    """A gated delta-rule layer's input side over ``h`` ``[B, T, d]``:
+    ``(z [B, T, conv_width], gate [B, T, H dv], g, beta [B, T, H])``: the
+    convolution's input (q, k and v's channels, in that order), the output
+    gate before its SiLU, the log of the decay ``g = -exp(A_log) softplus(h
+    W_a + dt_bias)`` and the step size ``beta = (2) sigmoid(h W_b)`` (both
+    float32)."""
+    f32 = jnp.float32
+    a = _mm(h, ap["w_a"]).astype(f32) + ap["dt_bias"].astype(f32)
+    g = -jnp.exp(ap["A_log"].astype(f32)) * jax.nn.softplus(a)
+    beta = jax.nn.sigmoid(_mm(h, ap["w_b"]).astype(f32))
+    return (_mm(h, ap["w_qkv"]), _mm(h, ap["w_g"]), g,
+            beta * (2.0 if gd.neg_eigval else 1.0))
+
+
+L2_EPS = 1e-6  # under the root of q's and k's l2 norm
+
+
+def gated_delta_qkv(c, gd: GatedDelta):
+    """The convolution's output ``c`` ``[.., conv_width]`` (float32) as
+    ``q`` / ``k`` ``[.., H, dk]`` and ``v`` ``[.., H, dv]``: SiLU, then a
+    head at a time ``q = l2norm(q) dk^-0.5``, ``k = l2norm(k)``."""
+    H, dk = gd.n_heads, gd.key_dim
+    x = jax.nn.silu(c)
+    q, k, v = jnp.split(x, (H * dk, 2 * H * dk), axis=-1)
+
+    def l2(a):
+        a = a.reshape(a.shape[:-1] + (H, dk))
+        return a * lax.rsqrt((a * a).sum(-1, keepdims=True) + L2_EPS)
+
+    return l2(q) * dk**-0.5, l2(k), v.reshape(v.shape[:-1] + (H, -1))
+
+
+def gated_delta_out(o, gate, ap: dict, eps: float, dtype):
+    """What the layer adds: the heads' reads ``o`` ``[.., H, dv]`` (float32)
+    through the RMSNorm a head, times ``silu(gate)``, through ``wo``."""
+    y = _rms(o, ap["o_norm"], eps)
+    y = y.reshape(gate.shape) * jax.nn.silu(gate.astype(jnp.float32))
+    return _mm(y.astype(dtype), ap["wo"])
 
 
 def latent_qkv(h, ap: dict, la: LatentAttn, eps: float, cos, sin) -> dict:
@@ -695,7 +772,8 @@ __all__ = [
     "EXPERT_STACKS", "INDEX_SELECT", "LATENT_ATTN", "MOE", "N_MOE_STATS",
     "STEP_STATS",
     "WINDOW_ATTN",
-    "SHORT_CONV",
+    "SHORT_CONV", "GATED_DELTA_SCOPE", "gated_delta_in", "gated_delta_out",
+    "gated_delta_qkv",
     "Pattern", "absorbed_output", "absorbed_query", "attend_absorbed",
     "attend_materialised", "gated_mlp", "gqa_qkv", "index_scores",
     "init_params",

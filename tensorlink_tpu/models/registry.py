@@ -16,7 +16,8 @@ from typing import Any, Callable
 import jax.numpy as jnp
 
 from .base import (
-    GqaAttn, LatentAttn, LinearAttn, ModelConfig, ShortConv, SparseAttn,
+    GatedDelta, GqaAttn, LatentAttn, LinearAttn, ModelConfig, ShortConv,
+    SparseAttn,
 )
 
 # tlint: disable=TL006(family registry — populated at import, read-only after)
@@ -500,6 +501,93 @@ def _lfm2_moe(d: dict) -> ModelConfig:
         moe_norm_topk=bool(d.get("norm_topk_prob", True)),
         moe_norm_eps=1e-6,
         moe_scale=float(d.get("routed_scaling_factor", 1.0)),
+    )
+
+
+# tlint: disable=TL006(read-only table)
+_OLMO_HYBRID_KINDS = {"linear_attention": "gated_delta",
+                      "full_attention": "gqa_full"}
+
+
+@register_family("olmo_hybrid")
+def _olmo_hybrid(d: dict) -> ModelConfig:
+    """Olmo-Hybrid: gated delta-rule layers (``linear_*`` keys: heads, key
+    and value widths, the taps of the causal convolution in front of q, k
+    and v, ``linear_allow_neg_eigval``: a step size of ``2 sigmoid``) and
+    full attention layers by ``layer_types``, the OLMo family's block in
+    both: an RMSNorm over the WHOLE query and key projections, and the
+    norm AFTER each branch (``x + norm(op(x))``), none before; a dense
+    SwiGLU in every layer, the head untied. ``rope_parameters.rope_theta``
+    null: the attention layers rotate nothing (order comes from the
+    recurrent layers); a number: rotate-half over the whole head.
+    ``head_dim``: ``hidden_size / num_attention_heads`` where the keys do
+    not name it. The per-layer list is read at its first
+    ``num_hidden_layers`` entries (a stage of a pipeline holds a run of
+    layers)."""
+    L = d["num_hidden_layers"]
+    types = list(d["layer_types"])[:L]
+    if len(types) != L:
+        raise ValueError(
+            f"olmo_hybrid: layer_types names {len(types)} layers, "
+            f"num_hidden_layers {L}")
+    unknown = sorted(set(types) - set(_OLMO_HYBRID_KINDS))
+    if unknown:
+        raise ValueError(
+            f"olmo_hybrid: layer_types {unknown} (built: "
+            f"{sorted(_OLMO_HYBRID_KINDS)})")
+    if d.get("attention_bias"):
+        raise ValueError("olmo_hybrid: attention_bias is not built")
+    if d.get("hidden_act", "silu") != "silu":
+        raise ValueError(
+            f"olmo_hybrid: hidden_act {d['hidden_act']!r} is not built "
+            "(silu is)")
+    hidden, heads = d["hidden_size"], d["num_attention_heads"]
+    hd = int(d.get("head_dim") or hidden // heads)
+    kv = d["num_key_value_heads"]
+    if heads % kv:
+        raise ValueError(
+            f"olmo_hybrid: {heads} query heads over {kv} kv heads (built: "
+            "whole groups)")
+    sizes = []
+    if "linear_attention" in types:
+        kh, vh = d["linear_num_key_heads"], d["linear_num_value_heads"]
+        if kh != vh:
+            raise ValueError(
+                f"olmo_hybrid: linear_num_key_heads {kh} != "
+                f"linear_num_value_heads {vh} is not built (one key head a "
+                "value head is: a state a head)")
+        taps = int(d["linear_conv_kernel_dim"])
+        if taps < 2:
+            raise ValueError(
+                f"olmo_hybrid: linear_conv_kernel_dim {taps} is not built "
+                "(two taps or more: a slot carries the last "
+                "linear_conv_kernel_dim - 1 positions)")
+        sizes.append(("gated_delta", GatedDelta(
+            n_heads=kh, key_dim=d["linear_key_head_dim"],
+            value_dim=d["linear_value_head_dim"], kernel=taps,
+            neg_eigval=bool(d.get("linear_allow_neg_eigval", False)),
+        )))
+    if "full_attention" in types:
+        theta = (d.get("rope_parameters") or {}).get(
+            "rope_theta", d.get("rope_theta"))
+        sizes.append(("gqa_full", GqaAttn(
+            n_heads=heads, n_kv_heads=kv, head_dim=hd,
+            rope_dim=hd if theta else 0, rope_theta=float(theta or 0.0),
+            gate=False, qk_norm_full=True,
+        )))
+    return ModelConfig(
+        family="olmo_hybrid",
+        vocab_size=d["vocab_size"],
+        d_model=hidden,
+        n_layers=L,
+        n_heads=heads, n_kv_heads=kv, head_dim=hd,
+        d_ff=d["intermediate_size"],
+        max_seq_len=d.get("max_position_embeddings", 4096),
+        norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        norm_position="post",
+        layer_kinds=tuple(_OLMO_HYBRID_KINDS[t] for t in types),
+        latent=tuple(sizes),
     )
 
 

@@ -145,7 +145,8 @@ class MemoryEstimate:
             "gqa_full" in cfg.layer_kinds
         ):
             parts = cls.state_parts(cfg, batch, seq_len)
-            kv = sum(parts.values())
+            # ``tails`` is a part of ``states``, not one more
+            kv = sum(v for k, v in parts.items() if k != "tails")
             act = batch * min(seq_len, PREFILL_BLOCK) * (
                 8 * cfg.d_model + 2 * cfg.d_ff) * pb
             total = int((params + act + kv) * 1.1)
@@ -179,7 +180,11 @@ class MemoryEstimate:
         (engine/latent.py): ``pages`` the ``gqa_full`` layers' keys and
         values, ``states`` the ``gqa_window`` layers' rings (the window
         and one prefill block a slot, whatever the context) and
-        ``snapshots`` the engine's pool of window snapshots."""
+        ``snapshots`` the engine's pool of window snapshots; beside
+        ``gqa_full`` layers, ``conv`` layers hold a tail a slot and
+        ``gated_delta`` layers a float32 state and a tail (``states``: both;
+        a snapshot is both, ``tails`` says what part of ``states`` the
+        tails are)."""
         pb = _dtype_bytes(cfg.dtype)
         sizes = dict(cfg.latent)
         if "gqa_full" in cfg.layer_kinds:
@@ -202,6 +207,18 @@ class MemoryEstimate:
                 tail = n["conv"] * sc.tail * sc.width * pb
                 rings = batch * tail
                 snaps = (seq_len // PREFILL_BLOCK + 2 * batch) * tail
+            if n.get("gated_delta"):
+                gd = sizes["gated_delta"]
+                tail = n["gated_delta"] * gd.tail * gd.conv_width * pb
+                one = n["gated_delta"] * gd.state_bytes + tail
+                rings = batch * one
+                # the engine's default stride: 32 prefill chunks, or an
+                # eighth of the context where that is less
+                stride = min(32 * PREFILL_BLOCK, max(
+                    seq_len // 8 // PREFILL_BLOCK, 1) * PREFILL_BLOCK)
+                snaps = (seq_len // stride + 2 * batch) * one
+                return {"pages": int(pages), "states": int(rings),
+                        "snapshots": int(snaps), "tails": int(batch * tail)}
             return {"pages": int(pages), "states": int(rings),
                     "snapshots": int(snaps)}
         n_sparse = cfg.layer_kinds.count("sparse")
@@ -607,6 +624,8 @@ def plan_sharding(
             "gqa_window": ("window rings", "window snapshots"),
             "conv": ("convolution tails", "tail snapshots"),
             "lightning": ("recurrent states", "state snapshots"),
+            "gated_delta": ("recurrent states and convolution tails",
+                            "state snapshots"),
         }[cfg.slot_state]
         raise AssignmentError(
             f"{model_name or cfg.family} does not fit one worker: it needs "

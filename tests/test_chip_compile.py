@@ -675,3 +675,71 @@ def test_lfm2_step_fits_one_v5e_and_pads_no_pool(v5e):
               if " while(" in ln]
     assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)
             for ln in whiles] == list(STEP_PHASES)
+
+
+def test_olmo_hybrid_step_fits_one_v5e_and_copies_no_state(v5e):
+    """The benchmark's ``olmo-hybrid-7b-l16`` at its published widths (layers
+    0-15, 8 slots x 8,192 positions of bf16 pages for the 4 full layers at
+    30 kv heads and ONE query head a kv head, a float32 state ``[96, 30 x
+    192]`` and a bf16 tail of 3 positions a slot for the 12 gated-delta
+    layers): the step compiles for one described v5e with both recurrence
+    kernels and the walk at G = 1 in both passes, weights + pages + states
+    + temporaries fit the chip, and NOTHING copies or pads the state array
+    (its layout holds whole lane rows). ~90 s: the one program the cell
+    serves from."""
+    import json
+    import re
+    from pathlib import Path
+
+    from tensorlink_tpu.engine.latent import LatentPagedCache
+    from tensorlink_tpu.engine.paged import (
+        STEP_PHASES, paged_ragged_step, tiled_rows)
+    from tensorlink_tpu.models.registry import config_from_hf
+    from tensorlink_tpu.models.transformer import init_params
+
+    hf = json.loads((Path(__file__).parent.parent / "benchmarks" / "configs"
+                     / "olmo-hybrid-7b-l16.json").read_text())
+    cfg = config_from_hf(hf)
+    slots = hf["deployment"]["ml"]["cont_max_slots"]
+    params = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: LatentPagedCache.init(
+        cfg, slots, page_size=PAGE, max_len=hf["deployment"]["seq_len"]))
+    assert cache.k.shape == cache.v.shape == (4, 1 + 8 * 512, 30, 16, 128)
+    assert cache.state.shape == (12, 8, 96, 5760)
+    assert cache.state.dtype == jnp.float32
+    assert cache.tail.shape == (12, 8, 3, 11520)
+    place = _on(v5e)
+    ops = _packed_operands(cfg, params, cache, place, place)
+    # the program that serves: the tiled pass, the one wide program
+    compiled = paged_ragged_step.lower(
+        *ops, cfg, 8, 1, True, tiled_rows(slots, 128)).compile()
+    text = compiled.as_text()
+    for name in ("gqa_full_attention", "gated_delta_step",
+                 "gated_delta_chunk"):
+        assert text.count(name) >= 1, name
+    ma = compiled.memory_analysis()
+    weights = _nbytes(ops[0])
+    # bf16 but the float32 A_log and dt_bias: 2 more bytes x 60 x 12 layers
+    assert weights == 2 * cfg.held_param_count() + 2 * 60 * 12
+    pool = _nbytes((cache.k, cache.v))
+    assert 4.0e9 < pool < 4.05e9
+    assert _nbytes(cache.state) == 8 * 12 * 2_211_840
+    assert _nbytes(cache.tail) == 8 * 12 * 69_120
+    print(f"olmo-hybrid-7b-l16 on a described v5e: arguments "
+          f"{ma.argument_size_in_bytes / 1e9:.3f} GB, temp "
+          f"{ma.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"{text.count('tpu_custom_call')} kernel calls")
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM, ma
+    assert ma.temp_size_in_bytes < pool, ma
+    # the stack of states or one layer's, copied or padded
+    for shape in (cache.state.shape, cache.state.shape[1:],
+                  (1,) + cache.state.shape[1:], cache.k.shape):
+        shape = "[" + ",".join(map(str, shape)) + "]"
+        moved = re.findall(
+            rf"^\s*\S+ = \w+{re.escape(shape)}\S* (?:copy|pad)\(.*$", text,
+            re.M)
+        assert not moved, moved[:2]
+    whiles = [ln for ln in text[text.index("ENTRY"):].splitlines()
+              if " while(" in ln]
+    assert [re.search(r'op_name="[^"]*/(tlink\.\w+)/while"', ln).group(1)
+            for ln in whiles] == list(STEP_PHASES)
