@@ -31,6 +31,7 @@ from tensorlink_tpu.api.schemas import (
     JobRequest,
     ValidationError,
 )
+from tensorlink_tpu.core import serialization as ser
 from tensorlink_tpu.core.logging import get_logger
 from tensorlink_tpu.core.metrics import MetricsRegistry, render_prometheus
 from tensorlink_tpu.core.trace import (
@@ -126,6 +127,13 @@ class TensorlinkAPI:
             "tlink_http_inflight", "generations in flight",
             fn=lambda: self._inflight,
         )
+        # this process's side of the wire: int lists (a prompt's ids)
+        # framed as one array and read back (core/serialization.py)
+        for key in ser.counters():
+            self.metrics.gauge(
+                f"tlink_{key}", "TLTS int lists framed as one array",
+                fn=lambda key=key: ser.counters()[key],
+            )
 
     # -- lifecycle ------------------------------------------------------
     def start(self) -> "TensorlinkAPI":

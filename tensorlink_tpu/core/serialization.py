@@ -12,6 +12,13 @@ an array table (dtype, shape, offset, nbytes). The payload is the raw
 little-endian array bytes, 64-byte aligned so a receiver can map them
 zero-copy into jax/numpy. bfloat16 and fp8 ride on ``ml_dtypes``.
 
+A ``list`` of :data:`PACK_MIN_INTS` or more plain Python ``int``s (a request's
+token ids) rides the payload as ONE ``int32`` array (``int64`` when a value
+needs it) under ``{"__ints__": i}`` and comes back a ``list`` of ``int``s:
+list in, list out, never walked element by element in Python. A frame that
+holds such a list says version 2 in its version byte; every other frame is
+byte for byte the version-1 frame it always was, and ``decode`` reads both.
+
 Custom structured objects (KV caches, model outputs) register with
 :func:`register_struct` — symmetric named encode/decode, never code execution.
 """
@@ -19,6 +26,7 @@ Custom structured objects (KV caches, model outputs) register with
 from __future__ import annotations
 
 import json
+import threading
 from typing import Any, Callable
 
 import numpy as np
@@ -36,7 +44,50 @@ except ImportError:  # pragma: no cover
 
 MAGIC = b"TLTS"
 VERSION = 1
+# a frame that holds a packed int list (``__ints__``): a peer that predates
+# the marker refuses it by its version byte, not as a malformed node
+VERSION_PACKED = 2
+VERSIONS_READ = (VERSION, VERSION_PACKED)
 _ALIGN = 64
+# Shortest list the array path takes. Under it the element path is as fast
+# or faster (the array path's fixed cost: the type test, one conversion,
+# a table entry, an aligned copy; measured crossover in docs/SERVING.md,
+# "Wire"), and a ``TOKEN`` frame's 1-8 ids or a request's ``eos_ids`` keep
+# the frame they had. A constant of the codec: no config field reads it.
+PACK_MIN_INTS = 32
+_INT32 = np.iinfo(np.int32)
+_INT_DTYPES = (np.dtype(np.int32), np.dtype(np.int64))
+
+# this process's packed lists: {tlts_lists_packed, tlts_ints_packed} count
+# on the encode side, tlts_lists_unpacked on the decode side (read through
+# :func:`counters`; API pool threads and the engine's thread both frame)
+# tlint: disable=TL006(process counters — every write under _COUNTS_LOCK)
+_COUNTS = {"tlts_lists_packed": 0, "tlts_ints_packed": 0,
+           "tlts_lists_unpacked": 0}  #: guarded by _COUNTS_LOCK
+_COUNTS_LOCK = threading.Lock()
+
+
+def counters() -> dict[str, int]:
+    """This process's count of int lists framed as one array
+    (``tlts_lists_packed``, ``tlts_ints_packed`` their elements) and read
+    back (``tlts_lists_unpacked``)."""
+    with _COUNTS_LOCK:
+        return dict(_COUNTS)
+
+
+def _pack_ints(x: list) -> np.ndarray | None:
+    """``x`` as one int32 / int64 array when every element is a plain
+    ``int`` (no ``bool``, no NumPy scalar) that int64 holds; else None and
+    the caller walks it element by element as before."""
+    if set(map(type, x)) != {int}:  # at C speed: no Python-level loop
+        return None
+    try:
+        a = np.fromiter(x, np.int64, len(x))
+    except OverflowError:  # a value beyond int64 keeps the element path
+        return None
+    if _INT32.min <= int(a.min()) and int(a.max()) <= _INT32.max:
+        a = a.astype(np.int32)
+    return a
 
 # name -> (to_tree, from_tree); to_tree returns a JSON-able tree possibly
 # containing arrays, from_tree reconstructs the object.
@@ -80,16 +131,19 @@ def encode(obj: Any) -> memoryview:
     required, e.g. ctypes ``c_char_p``)."""
     arrays: list[np.ndarray] = []
     table: list[dict[str, Any]] = []
+    packed: list[int] = []  # the length of each list framed as an array
+
+    def add_array(a: np.ndarray) -> int:
+        arrays.append(a)
+        table.append({"dtype": _dtype_name(a.dtype), "shape": list(a.shape)})
+        return len(arrays) - 1
 
     def walk(x: Any) -> Any:
         if _is_array(x):
             a = np.asarray(x)
             if not a.flags.c_contiguous:
                 a = np.ascontiguousarray(a)
-            idx = len(arrays)
-            arrays.append(a)
-            table.append({"dtype": _dtype_name(a.dtype), "shape": list(a.shape)})
-            return {"__arr__": idx}
+            return {"__arr__": add_array(a)}
         if isinstance(x, (np.generic,)):
             return walk(np.asarray(x))
         if isinstance(x, bytes):
@@ -99,6 +153,11 @@ def encode(obj: Any) -> memoryview:
         if isinstance(x, tuple):
             return {"__tuple__": [walk(v) for v in x]}
         if isinstance(x, list):
+            if len(x) >= PACK_MIN_INTS:
+                a = _pack_ints(x)
+                if a is not None:
+                    packed.append(len(x))
+                    return {"__ints__": add_array(a)}
             return [walk(v) for v in x]
         if x is None or isinstance(x, (bool, int, str)):
             return x
@@ -131,7 +190,7 @@ def encode(obj: Any) -> memoryview:
     buf = np.empty(prefix + offset, np.uint8)
     mv = memoryview(buf)
     mv[0:4] = MAGIC
-    mv[4] = VERSION
+    mv[4] = VERSION_PACKED if packed else VERSION
     mv[5:9] = len(header).to_bytes(4, "little")
     mv[9:prefix] = header
     pos = 0
@@ -145,6 +204,10 @@ def encode(obj: Any) -> memoryview:
                 a.reshape(-1).view(np.uint8),
             )
         pos = meta["offset"] + n
+    if packed:
+        with _COUNTS_LOCK:
+            _COUNTS["tlts_lists_packed"] += len(packed)
+            _COUNTS["tlts_ints_packed"] += sum(packed)
     return mv
 
 
@@ -156,7 +219,7 @@ def decode(data: bytes | memoryview, *, copy: bool = False) -> Any:
         raise ValueError(f"truncated TLTS frame: {len(mv)} bytes")
     if bytes(mv[:4]) != MAGIC:
         raise ValueError("bad magic: not a TLTS frame")
-    if mv[4] != VERSION:
+    if mv[4] not in VERSIONS_READ:
         raise ValueError(f"unsupported TLTS version {mv[4]}")
     hlen = int.from_bytes(mv[5:9], "little")
     if 9 + hlen > len(mv):
@@ -164,7 +227,9 @@ def decode(data: bytes | memoryview, *, copy: bool = False) -> Any:
     header = json.loads(bytes(mv[9 : 9 + hlen]).decode())
     payload = mv[9 + hlen :]
 
-    def get_array(i: int) -> np.ndarray:
+    unpacked = 0
+
+    def view(i: int) -> np.ndarray:
         meta = header["arrays"][i]
         dt = _dtype_from_name(meta["dtype"])
         if meta["offset"] + meta["nbytes"] > len(payload):
@@ -173,13 +238,22 @@ def decode(data: bytes | memoryview, *, copy: bool = False) -> Any:
                 f"{meta['offset'] + meta['nbytes']}, payload has {len(payload)}"
             )
         raw = payload[meta["offset"] : meta["offset"] + meta["nbytes"]]
-        a = np.frombuffer(raw, dtype=dt).reshape(meta["shape"])
-        return a.copy() if copy else a
+        return np.frombuffer(raw, dtype=dt).reshape(meta["shape"])
 
     def walk(x: Any) -> Any:
+        nonlocal unpacked
         if isinstance(x, dict):
             if "__arr__" in x:
-                return get_array(x["__arr__"])
+                a = view(x["__arr__"])
+                return a.copy() if copy else a
+            if "__ints__" in x:
+                # fresh Python ints: nothing of the list aliases the buffer
+                a = view(x["__ints__"])
+                if a.dtype not in _INT_DTYPES or a.ndim != 1:
+                    raise ValueError(
+                        f"malformed node: __ints__ of {a.dtype}{list(a.shape)}")
+                unpacked += 1
+                return a.tolist()
             if "__bytes__" in x:
                 return bytes.fromhex(x["__bytes__"])
             if "__dict__" in x:
@@ -196,7 +270,11 @@ def decode(data: bytes | memoryview, *, copy: bool = False) -> Any:
             return [walk(v) for v in x]
         return x
 
-    return walk(header["tree"])
+    out = walk(header["tree"])
+    if unpacked:
+        with _COUNTS_LOCK:
+            _COUNTS["tlts_lists_unpacked"] += unpacked
+    return out
 
 
 def content_digest(obj: Any) -> str:

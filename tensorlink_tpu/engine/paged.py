@@ -655,9 +655,12 @@ class PrefixCache:
 
     # -- lookup ----------------------------------------------------------
     def _blocks(self, tokens, limit: int):
+        # a page's key is the slice itself: ``submit`` made the prompt a
+        # list of ints once, and NumPy integers hash and compare as the
+        # ints they hold, so a key built either way finds the same node
         p = self.page_size
         for i in range(0, (limit // p) * p, p):
-            yield tuple(int(t) for t in tokens[i : i + p])
+            yield tuple(tokens[i : i + p])
 
     def match(self, tokens, limit: int) -> list[_TrieNode]:
         """Longest chain of cached full pages covering ``tokens[:limit]``.

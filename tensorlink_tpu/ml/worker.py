@@ -32,6 +32,7 @@ from typing import Any
 
 import numpy as np
 
+from tensorlink_tpu.core import serialization as ser
 from tensorlink_tpu.core.faults import FaultCrash, FaultPlan
 from tensorlink_tpu.core.logging import get_logger
 from tensorlink_tpu.nodes.ipc import CHUNK_DONE
@@ -1605,8 +1606,6 @@ class DistributedWorker:
         payload — the coworkers answer a slim ack."""
         import jax
 
-        from tensorlink_tpu.core import serialization as ser
-
         rt = self._runtime(p["job_id"])
         op = p.get("op", "save")
         mirror = bool(p.get("mirror"))
@@ -2090,8 +2089,10 @@ class DistributedWorker:
                 "continuous": True,
                 # engine occupancy + prefix-cache counters ride every
                 # response so the validator's /stats can surface them
-                # without a dedicated polling RPC
-                "serving": cont.serving_snapshot(),
+                # without a dedicated polling RPC; beside them this
+                # process's side of the wire (prompts' ids read back as
+                # one array: ``tlts_lists_unpacked``)
+                "serving": {**cont.serving_snapshot(), **ser.counters()},
             }
             if resume_base is not None:
                 body["reattached"] = True

@@ -25,6 +25,7 @@ import time
 from pathlib import Path
 from typing import Any, Awaitable, Callable
 
+from tensorlink_tpu.core import serialization as ser
 from tensorlink_tpu.core.logging import get_logger
 from tensorlink_tpu.crypto import identity as crypto
 from tensorlink_tpu.p2p import protocol as proto
@@ -675,6 +676,9 @@ class P2PNode:
                 for nid, c in self.connections.items()
             },
             "dht_keys": len(self.dht.store_map),
+            # this process's int lists framed as one array and read back
+            # (core/serialization.py): both bridges and TCP frame here
+            "wire": ser.counters(),
             "uptime_s": time.monotonic() - getattr(self, "_t0", time.monotonic()),
         }
 

@@ -144,6 +144,60 @@ def test_prefix_match_walks_longest_chain():
     assert pc.match(toks[4:], limit=8) == []
 
 
+def _parents_blocks(tokens, limit, p):
+    """``PrefixCache._blocks`` as it stood until PR 58: an ``int()`` an id."""
+    for i in range(0, (limit // p) * p, p):
+        yield tuple(int(t) for t in tokens[i : i + p])
+
+
+@pytest.mark.parametrize("as_given", [
+    list,
+    lambda ids: [np.int32(t) for t in ids],
+    lambda ids: np.asarray(ids, np.int32),
+    lambda ids: np.asarray(ids, np.int64),
+    tuple,
+], ids=["ints", "int32-scalars", "int32-array", "int64-array", "tuple"])
+def test_prefix_keys_from_a_slice_find_what_the_parents_keys_built(as_given):
+    """A page's key is the slice itself: it hashes and compares equal to
+    the key the per-element ``int()`` built, NumPy integers included, so
+    ``match`` finds every node of a trie built before and ``insert`` under
+    such a key adopts nothing twice."""
+    p = 16
+    ids = [int(t) for t in np.random.default_rng(2).integers(0, 150_000, 20 * p + 5)]
+    pc = PrefixCache(p)
+    node = None
+    for i, block in enumerate(_parents_blocks(ids, len(ids), p)):
+        node, adopted = pc.insert(node, block, 100 + i)
+        assert adopted
+    tokens = as_given(ids)
+    keys = list(pc._blocks(tokens, len(ids)))
+    want = list(_parents_blocks(ids, len(ids), p))
+    assert keys == want and [hash(k) for k in keys] == [hash(k) for k in want]
+    assert [n.page for n in pc.match(tokens, len(ids))] == list(range(100, 120))
+    assert [n.page for n in pc.match(tokens, 7 * p + 3)] == list(range(100, 107))
+    # a re-insert under the slice's key is the resident node, not a second one
+    again, adopted = pc.insert(None, keys[0], 999)
+    assert not adopted and again.page == 100
+    # a new chain inserted under slice keys is found by the parent's keys too
+    fresh = as_given([t + 1 for t in ids])
+    node = None
+    for i, block in enumerate(pc._blocks(fresh, 4 * p)):
+        node, adopted = pc.insert(node, block, 200 + i)
+        assert adopted
+    assert node.key_hash == list(_chain(pc, [t + 1 for t in ids], 4 * p))[-1]
+    assert [n.page for n in pc.match([t + 1 for t in ids], 4 * p)] == [
+        200, 201, 202, 203]
+
+
+def _chain(pc, ids, limit):
+    from tensorlink_tpu.engine.paged import chain_hash
+
+    h = ""
+    for block in _parents_blocks(ids, limit, pc.page_size):
+        h = chain_hash(h, block)
+        yield h
+
+
 def test_prefix_partial_match_picks_longest_cow_candidate():
     pc = PrefixCache(4)
     toks = [1, 2, 3, 4, 5, 6, 7, 8]

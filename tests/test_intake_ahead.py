@@ -856,3 +856,76 @@ def test_counters_and_the_span_attribute_equal_counts_by_hand(tiny_engine):
             pre["t0"], abs=1e-6)
     assert ahead == [False, True, True, False]
     ce.close()
+
+
+# -- (h) the wire: a long prompt's ids cross as one array ----------------------
+def test_a_4k_prompt_crosses_both_bridges_as_one_array_and_reaches_submit():
+    """A GENERATE frame of 4,096 ids goes the way a node's goes: the
+    validator's ML side puts it on its ``cmd`` ring, its network process
+    reads it and frames it for TCP, the worker's network process reads
+    that and puts it on the ``work`` ring, the worker's run loop reads it
+    and ``submit`` gets the ids it left with, plain ints; each of the three
+    framings packed the prompt as one array and each reading unpacked it."""
+    from tensorlink_tpu.core import serialization as ser
+    from tensorlink_tpu.core.ring import RingChannel, ring_supported
+    from tensorlink_tpu.nodes.ipc import BridgeQueues, NetBridge
+
+    if not ring_supported():
+        pytest.skip("native tlring not buildable here: the bridges pickle")
+    cfg = ModelConfig(
+        family="llama", vocab_size=128, d_model=32, n_layers=1, n_heads=2,
+        n_kv_heads=2, head_dim=16, d_ff=48, max_seq_len=4224,
+        dtype=jnp.float32, tie_embeddings=False,
+    )
+    eng = GenerationEngine(
+        cfg, init_params(cfg, jax.random.PRNGKey(0)), seq_buckets=(8, 32),
+        batch_buckets=(1,), max_seq_len=4224)
+    ce = _cont(eng, max_slots=2, page_size=64, prefill_chunk=1024)
+    w, rt = _worker(ce)
+    rings = [RingChannel(1 << 20) for _ in range(4)]
+    val = MLBridge(BridgeQueues(cmd=rings[0], resp=rings[1], work=rings[2]))
+    w.bridge.q = types.SimpleNamespace(cmd=None, resp=None, work=rings[3])
+    ids = [int(t) for t in np.random.default_rng(4).integers(0, 128, 4096)]
+    seen: list = []
+    submit, step = ce.submit, ce.step_chunk
+
+    def submit_seen(prompt, **kw):
+        seen.append(prompt)
+        return submit(prompt, **kw)
+
+    def step_then_stop(**kw):
+        more = step(**kw)
+        if not more:
+            rings[3].put(("_stop", None))
+        return more
+
+    ce.submit, ce.step_chunk = submit_seen, step_then_stop
+    try:
+        before = ser.counters()
+        body = {"job_id": "j", "prompts": [[int(t) for t in ids]],
+                "max_new_tokens": 4, "continuous": True, "seed": 1,
+                "eos_ids": [], "temperature": 0.0}
+        val.notify("tensor_request", {"tag": proto.GENERATE, "body": body})
+        _, verb, payload = rings[0].get(timeout=5)  # the validator's net side
+        assert verb == "tensor_request"
+        tcp = bytes(ser.encode(payload["body"]))
+        assert tcp[4] == ser.VERSION_PACKED and len(tcp) < 5 * 4096
+        arrived = ser.decode(tcp, copy=True)  # the worker's net side
+        NetBridge(w.bridge.q).post_work(
+            proto.GENERATE, {**arrived, "peer": "p0", "rid": "r1"})
+        w.run()
+        after = ser.counters()
+    finally:
+        ce.close()
+        for r in rings:
+            r.release()
+    assert len(seen) == 1 and seen[0] == ids
+    assert type(seen[0]) is list and set(map(type, seen[0])) == {int}
+    moved = {k: after[k] - before[k] for k in after}
+    assert moved == {"tlts_lists_packed": 3, "tlts_ints_packed": 3 * 4096,
+                     "tlts_lists_unpacked": 3}
+    got = _answers(w)["r1"]
+    assert len(got["sequences"][0]) == 4
+    # the worker's stats carry its process's side of the wire
+    assert got["serving"]["tlts_lists_unpacked"] >= 3
+    assert got["serving"]["prefill_tokens"] >= 4096

@@ -61,6 +61,33 @@ def test_oversize_spills_to_file():
         ch.release()
 
 
+@pytest.mark.parametrize("capacity", [1 << 20, 1 << 16],
+                         ids=["in-the-ring", "spilled-to-a-file"])
+def test_a_prompt_of_13k_ids_crosses_as_one_array(capacity):
+    """A GENERATE body as the bridges carry it (``(rid, verb, payload)``):
+    the ids come back the list of ints they were, framed as one array."""
+    from tensorlink_tpu.core import serialization as ser
+
+    ids = [int(v) for v in np.random.default_rng(3).integers(0, 150_000, 13_000)]
+    body = {"job_id": "j", "prompts": [ids], "max_new_tokens": 64,
+            "eos_ids": [2], "seed": 1, "continuous": True}
+    ch = RingChannel(capacity)
+    try:
+        before = ser.counters()
+        ch.put((7, "generate", body))
+        rid, verb, got = ch.get(timeout=5)
+        after = ser.counters()
+    finally:
+        ch.release()
+    assert (rid, verb) == (7, "generate")
+    assert got == body and type(got["prompts"][0]) is list
+    assert set(map(type, got["prompts"][0])) == {int}
+    assert got["eos_ids"] == [2]
+    moved = {k: after[k] - before[k] for k in after}
+    assert moved == {"tlts_lists_packed": 1, "tlts_ints_packed": 13_000,
+                     "tlts_lists_unpacked": 1}
+
+
 def test_close_unblocks_reader():
     ch = RingChannel(1 << 16)
     try:
