@@ -262,6 +262,9 @@ def _sample_rows(logits, keys, temp, top_k, top_p, pres, freq, counts):
 CHUNK_PHASES = (
     "admit", "pack", "dispatch", "wait", "drain", "deliver", "post",
 )
+# parts of wait's stream stage and intake that lay behind the driver's first
+# sight of the step's result ready (step_chunk): counted like the phases
+LATE_PARTS = ("late_stream", "late_intake")
 
 FLIGHT_CAPACITY = 1024  # chunks the flight recorder keeps
 MIGRATION_TTL_S = 120.0  # an engine's ``migration_ttl_s`` starts here
@@ -559,6 +562,16 @@ _ENGINE_COUNTERS = (
     ("chunk_us_intake", "tlink_engine_chunk_us_intake_total",
      "host microseconds in the intake's frames and ahead rounds (a "
      "sub-span of wait)"),
+    # the inside of a chunk's wait: the stream stage, then the intake, then
+    # the fetch. What of the first two lay behind the moment the driver
+    # first saw the step's result ready: the device was done, and the
+    # driver's own serial work kept it from the fetch
+    ("chunk_us_late_stream", "tlink_engine_chunk_us_late_stream_total",
+     "host microseconds of the in-flight stream stage after the driver "
+     "first saw the chunk's result ready"),
+    ("chunk_us_late_intake", "tlink_engine_chunk_us_late_intake_total",
+     "host microseconds of the intake (work and blocking) after the driver "
+     "first saw the chunk's result ready"),
 )
 
 
@@ -1254,6 +1267,15 @@ class ContinuousEngine:
         # inside a chunk's intake: a request submitted now arrived while
         # the chunk ``recorder.next_step`` names was in flight
         self.taking_in = False
+        # inside a chunk's wait, until the driver has seen the step's
+        # result ready: the result's ``is_ready`` (``_seen_ready`` asks it),
+        # and the monotonic stamp of the first True
+        self._ready_poll = None
+        self._ready_t: float | None = None
+        # ... and where the driver's work began that ended there: the last
+        # "not yet" in the stream stage (at first the wait's start), a take's
+        # start, the stamp itself where the driver had been blocked
+        self._unready_t = 0.0
         if pool is not None:
             # per-tenant pool occupancy: these render under the model's
             # label at /metrics (the registry-per-model grouping), which
@@ -1763,6 +1785,9 @@ class ContinuousEngine:
                 req.error = e
                 self._finish(req, finished=False)
             raise
+        if in_flight:
+            # (entries follow one another: this one began at the last ask)
+            self._seen_ready(self._unready_t)
         return sent
 
     def _admit_one(self, req: ContinuousRequest, slot: int, *,
@@ -4259,18 +4284,46 @@ class ContinuousEngine:
                     self._note_first_call(
                         (blk.shape[1], flat), programs, ph["dispatch"]
                     )
-                with _Phase(ph, "wait"):
-                    # the device runs this chunk: the one before it leaves
-                    # for its callbacks meanwhile, nothing below reads
-                    # what they return but a stop (settled next)
-                    self.flush_stream(in_flight=True)
-                    if self.intake is not None:
-                        # ... and what arrives meanwhile is taken in and
-                        # prepared for the chunk after this one
-                        self._take_in(out)
-                    # the chunk's one fetch, and the one value that
-                    # blocks: the device is done here
-                    out = np.asarray(out)
+                with _Phase(ph, "wait") as wait:
+                    # the wait laid out end to end: the stream stage, the
+                    # intake, the fetch. The driver asks the result whether
+                    # it is ready at the boundaries it passes on the way
+                    # (``_seen_ready``: no sync) up to the first True
+                    self._ready_poll = getattr(out, "is_ready", None)
+                    self._ready_t = None
+                    self._unready_t = wait.t0
+                    try:
+                        # the device runs this chunk: the one before it
+                        # leaves for its callbacks meanwhile, nothing below
+                        # reads what they return but a stop (settled next)
+                        self.flush_stream(in_flight=True)
+                        streamed = time.monotonic()
+                        if self.intake is not None:
+                            # ... and what arrives meanwhile is taken in and
+                            # prepared for the chunk after this one
+                            self._take_in(out)
+                        # where the intake ends (on CHUNK_DONE: ready) and
+                        # the driver comes to the fetch
+                        self._seen_ready()
+                    finally:
+                        # (a callback that raised leaves no hold on the
+                        # result in flight)
+                        self._ready_poll = None
+                    with _Phase(ph, "fetch") as fetch:
+                        # the chunk's one fetch, and the one value that
+                        # blocks: the device is done here
+                        out = np.asarray(out)
+                # what of the wait lay behind the first sight of a ready
+                # result: the driver's own work kept it from the fetch
+                ready = self._ready_t
+                if ready is None:  # it blocked in the fetch, or cannot say
+                    ph.update(late_stream=0.0, late_intake=0.0, late_max=0.0)
+                else:
+                    ph["late_stream"] = max(0.0, streamed - ready)
+                    ph["late_intake"] = fetch.t0 - max(ready, streamed)
+                    # ... and at most that with the entry or take whose end
+                    # first saw it: the result turned ready inside it
+                    ph["late_max"] = fetch.t0 - self._unready_t
                 with _Phase(ph, "drain"):
                     # the host's copy split (the step's own counts of a
                     # patterned model rode it too)
@@ -4389,11 +4442,14 @@ class ContinuousEngine:
                 self._chunk_exit_t = None
             return more
         ph["between"] = between
+        counted = ("between",) + CHUNK_PHASES + LATE_PARTS
         us = {
-            k: int(ph[k] * 1e6 + 0.5) for k in ("between",) + CHUNK_PHASES
+            # (fetch: the end of wait, what the driver blocked for the
+            # device; late_max: the lateness's upper bound; record alone)
+            k: int(ph[k] * 1e6 + 0.5) for k in counted + ("fetch", "late_max")
         }
-        for k, v in us.items():
-            self._count(f"chunk_us_{k}", v)
+        for k in counted:
+            self._count(f"chunk_us_{k}", us[k])
         # host_ms and chunk_ms keep their meaning: step_chunk's entry to
         # the dispatch, and the dispatch to the end of the drain
         self.recorder.record(
@@ -4430,9 +4486,37 @@ class ContinuousEngine:
                     take()
                     self._admit_ahead()
                 busy += time.monotonic() - t0
+                self._seen_ready(t0)
         finally:
             self.taking_in = False
             self._count("chunk_us_intake", int(busy * 1e6 + 0.5))
+
+    # tlint: hot-path
+    def _seen_ready(self, since: float | None = None) -> None:
+        """Inside a chunk's wait, at a boundary the driver passes anyway
+        (a stream callback returned, an intake take and its ahead round
+        are through, the intake ended): ask the step's result whether it
+        is ready, and stamp the first True. ``is_ready`` blocks for
+        nothing and syncs nothing; a result over a mesh is ready when
+        every shard is. Not asked again after the first True, and never
+        of a result that has no ``is_ready``. The stamp is an upper bound
+        of the moment the device was done, late by at most the entry or
+        take before it: the lateness read from it is a lower bound.
+        ``since`` is where that entry or take began (None: the driver had
+        been blocked, or had just asked): ``_unready_t`` keeps it, and the
+        lateness of the driver's own work is at most what lay behind it
+        (the record's ``late_max_ms``)."""
+        poll = self._ready_poll
+        if poll is None:
+            return
+        ready = poll()
+        now = time.monotonic()
+        if ready:
+            self._ready_t = now
+            self._ready_poll = None
+            self._unready_t = now if since is None else since
+        else:
+            self._unready_t = now
 
     # tlint: hot-path
     def _settle(self, grants, completing, handoff_done, emit, n_spec,

@@ -25,7 +25,14 @@ and an ahead round prepares their admission into free slots; the next
   admission return every page and reference;
 - (f) the wait returns at once when the result is ready and nothing
   arrives: no sleep, no timeout under a second;
-- (g) the counters and the span attribute equal counts made by hand.
+- (g) the counters and the span attribute equal counts made by hand;
+- (i) the inside of the wait: the driver asks the step's result whether it
+  is ready after each stream callback's entry and each take, stamps the
+  first True and asks no more; what of the stream stage and of the intake
+  lay behind the stamp is the record's ``late_stream_ms`` /
+  ``late_intake_ms`` (0 when the result was not ready before the fetch,
+  or has no ``is_ready``), ``late_max_ms`` is that with the entry or take
+  whose end first saw it, and ``fetch_ms`` is what the fetch blocked.
 """
 
 from __future__ import annotations
@@ -929,3 +936,276 @@ def test_a_4k_prompt_crosses_both_bridges_as_one_array_and_reaches_submit():
     # the worker's stats carry its process's side of the wire
     assert got["serving"]["tlts_lists_unpacked"] >= 3
     assert got["serving"]["prefill_tokens"] >= 4096
+
+
+# -- (i) the inside of the wait ------------------------------------------------
+NAP = 0.01
+
+
+class _Gate:
+    """What a test holds of the step in flight: whether its result is ready
+    (the test sets it), how often the driver asked, and where it was on the
+    clock at the boundaries the lateness is read between."""
+
+    def __init__(self):
+        self.ready = self.seen = False
+        self.asked = 0
+        self.fetch_t0 = 0.0
+
+
+class _Blind:
+    """A step's result as a stub hands it on: fetched, never asked."""
+
+    def __init__(self, out, gate):
+        self.out, self.gate = out, gate
+
+    def copy_to_host_async(self):
+        self.out.copy_to_host_async()
+
+    def __array__(self, dtype=None, copy=None):
+        self.gate.fetch_t0 = time.monotonic()
+        time.sleep(NAP)  # the driver blocks for the device
+        return np.asarray(self.out)
+
+
+class _Result(_Blind):
+    """... and one whose readiness the test's gate controls."""
+
+    def is_ready(self):
+        assert not self.gate.seen, "asked again after the first True"
+        self.gate.asked += 1
+        self.gate.seen = self.gate.ready
+        return self.gate.ready
+
+
+def _gated(monkeypatch, kind=_Result) -> list:
+    """Every step's result goes through ``kind``; the gates, a dispatch."""
+    from tensorlink_tpu.engine import continuous
+
+    gates: list = []
+    step = continuous.paged_ragged_step
+
+    def gated_step(*ops, **kw):
+        out, cache, counts = step(*ops, **kw)
+        gates.append(_Gate())
+        return kind(out, gates[-1]), cache, counts
+
+    gated_step._cache_size = step._cache_size  # the engine counts programs
+    monkeypatch.setattr(continuous, "paged_ragged_step", gated_step)
+    return gates
+
+
+def _streaming(ce, gates, ready_at):
+    """One request whose reader naps a token; the result of the chunk in
+    flight turns ready inside the ``ready_at``-th callback of that chunk's
+    stream stage (from 0; None: never). Returns, a dispatched chunk, where
+    each callback of its in-flight stage ended (``cbs``) and where the
+    stage had (``end``)."""
+    marks: dict = {}
+    flush = ce.flush_stream
+    staged = {"on": False}
+
+    def cb(tok):
+        m = marks.setdefault(len(gates), {"cbs": []})
+        if staged["on"]:
+            if len(m["cbs"]) == ready_at:
+                gates[-1].ready = True
+            time.sleep(NAP)
+            m["cbs"].append(time.monotonic())
+        return False
+
+    def flush_stream(*a, **kw):
+        staged["on"] = bool(kw.get("in_flight"))
+        flush(*a, **kw)
+        if staged["on"]:
+            marks.setdefault(len(gates), {"cbs": []})["end"] = (
+                time.monotonic())
+        staged["on"] = False
+
+    ce.flush_stream = flush_stream
+    ce.submit([1, 2, 3], max_new_tokens=12, seed=1, stream_cb=cb)
+    return marks
+
+
+@pytest.mark.parametrize("ready_at", [0, 3, None],
+                         ids=["first_callback", "last_callback", "never"])
+def test_late_stream_is_the_stage_behind_the_first_sight_of_ready(
+        tiny_engine, monkeypatch, ready_at):
+    """A chunk's stage hands a stream's four tokens on in two entries (its
+    next token, then the rest): the driver asks behind each. Ready inside
+    the first callback: the three callbacks of the second entry are late.
+    Ready inside the last: the stamp falls where the stage ends. Never
+    ready: zeros, and the driver asked at every boundary it passed."""
+    gates = _gated(monkeypatch)
+    ce = _cont(tiny_engine)
+    marks = _streaming(ce, gates, ready_at)
+    ce.run_until_idle()
+    recs = ce.recorder.records()
+    assert len(recs) == len(gates) == 3
+    # the first chunk's wait has nothing to stream: one question, at the fetch
+    assert (gates[0].asked, recs[0]["late_stream_ms"]) == (1, 0.0)
+    for k in (2, 3):
+        rec, gate, m = recs[k - 1], gates[k - 1], marks[k]
+        assert len(m["cbs"]) == 4
+        assert rec["fetch_ms"] >= NAP * 1e3
+        assert rec["stream_ms"] >= 4 * NAP * 1e3
+        if k == 2:  # the last chunk's record holds the flush behind it too
+            assert rec["stream_ms"] + rec["fetch_ms"] <= rec["wait_ms"] + 0.1
+        late = rec["late_stream_ms"] + rec["late_intake_ms"]
+        if ready_at is None:
+            assert gate.asked == 3 and not gate.seen
+            assert late == rec["late_max_ms"] == 0.0
+            continue
+        seen_by = 0 if ready_at == 0 else 1  # the entry whose end saw it
+        assert gate.asked == seen_by + 1 and gate.seen
+        # at most: that entry too (one callback, or three), where the
+        # result did turn ready; the stage before it is not in it
+        naps = 1 if ready_at == 0 else 3
+        assert naps * NAP * 1e3 <= rec["late_max_ms"] - late
+        if ready_at == 0:  # from the wait's start
+            assert rec["late_max_ms"] <= rec["wait_ms"] - rec["fetch_ms"] + 2e-3
+        else:  # from the first entry's end
+            assert rec["late_max_ms"] <= (gate.fetch_t0 - m["cbs"][0]) * 1e3
+        # the stamp lies behind that entry's last callback, the fetch's
+        # start before the result's own mark of it (the test's mark of the
+        # stage's end lies BEFORE the engine's: it bounds nothing from
+        # above, and a loaded machine parts the two)
+        assert late <= (gate.fetch_t0 - m["cbs"][ready_at]) * 1e3
+        assert rec["late_stream_ms"] >= (3 * NAP * 1e3 if ready_at == 0 else 0)
+        # no intake: the driver came to the fetch where the stage ended
+        assert rec["late_intake_ms"] <= (gate.fetch_t0 - m["end"]) * 1e3 + 0.1
+    s = ce.stats
+    assert s["chunk_us_late_stream"] == round(
+        sum(r["late_stream_ms"] for r in recs) * 1e3)
+    assert s["chunk_us_late_intake"] == round(
+        sum(r["late_intake_ms"] for r in recs) * 1e3)
+    ce.close()
+
+
+@pytest.mark.parametrize("ready_in", ["first_take", "stream_stage", "never"])
+def test_late_intake_is_the_intake_behind_the_first_sight_of_ready(
+        tiny_engine, monkeypatch, ready_in):
+    """Three takes of the second chunk's intake nap each. Ready inside the
+    first: the two that follow are late. Ready while the stage before the
+    intake still streamed: all three are, and the stage's rest besides.
+    Never ready: zeros."""
+    gates = _gated(monkeypatch)
+    ce = _cont(tiny_engine, max_slots=2)
+    ends: list = []
+
+    def take(i):
+        if ready_in == "first_take" and i == 0:
+            gates[-1].ready = True
+        time.sleep(NAP)
+        ends.append(time.monotonic())
+
+    ce.intake = _Script({2: [functools.partial(take, i) for i in range(3)]})
+    marks = _streaming(ce, gates, 0 if ready_in == "stream_stage" else None)
+    ce.step_chunk()
+    ce.step_chunk()
+    rec, gate, m = ce.recorder.records()[1], gates[1], marks[2]
+    assert rec["intake_ms"] >= 3 * NAP * 1e3
+    late = rec["late_stream_ms"] + rec["late_intake_ms"]
+    if ready_in == "never":
+        assert gate.asked == 2 + 3 + 1 and not gate.seen
+        assert late == rec["late_max_ms"] == 0.0
+    elif ready_in == "first_take":
+        assert gate.asked == 2 + 1
+        assert rec["late_stream_ms"] == 0.0
+        assert 2 * NAP * 1e3 <= rec["late_intake_ms"] <= (
+            gate.fetch_t0 - ends[0]) * 1e3
+        # at most: the first take too, and nothing of the stage before it
+        assert NAP * 1e3 <= rec["late_max_ms"] - late
+        assert rec["late_max_ms"] <= (gate.fetch_t0 - m["end"]) * 1e3
+    else:
+        assert gate.asked == 1
+        assert rec["late_stream_ms"] >= 3 * NAP * 1e3
+        assert 3 * NAP * 1e3 <= rec["late_intake_ms"] <= (
+            gate.fetch_t0 - m["cbs"][-1]) * 1e3
+        # at most: the stage's first entry too, from the wait's start
+        assert NAP * 1e3 <= rec["late_max_ms"] - late
+        assert rec["late_max_ms"] <= rec["wait_ms"] - rec["fetch_ms"] + 2e-3
+    assert (rec["late_stream_ms"] + rec["late_intake_ms"] + rec["fetch_ms"]
+            <= rec["wait_ms"] + 2e-3)
+    assert ce.stats["chunk_us_late_intake"] == round(
+        rec["late_intake_ms"] * 1e3)
+    ce.close()
+
+
+def test_a_result_without_is_ready_records_zeros(tiny_engine, monkeypatch):
+    """A stub's result says nothing of its readiness: the driver asks
+    nothing, and every lateness reads 0 whatever the stage and the intake
+    took; the fetch is timed all the same."""
+    gates = _gated(monkeypatch, kind=_Blind)
+    ce = _cont(tiny_engine)
+    ce.intake = _Script({2: [functools.partial(time.sleep, NAP)]})
+    _streaming(ce, gates, None)
+    ce.run_until_idle()
+    recs = ce.recorder.records()
+    assert len(recs) == 3 and recs[1]["intake_ms"] >= NAP * 1e3
+    for r in recs:
+        assert r["late_stream_ms"] == r["late_intake_ms"] == 0.0
+        assert r["late_max_ms"] == 0.0
+        assert r["fetch_ms"] >= NAP * 1e3
+    assert ce.stats["chunk_us_late_stream"] == 0
+    assert ce.stats["chunk_us_late_intake"] == 0
+    ce.close()
+
+
+def test_a_device_result_is_seen_ready_and_asked_no_more(tiny_engine):
+    """The real thing: a device array answers ``is_ready``; once the step
+    is through, the driver's next question stamps it, and what the stage
+    still handed on behind the stamp is late."""
+    ce = _cont(tiny_engine)
+    asked: list = []
+    from tensorlink_tpu.engine import continuous
+
+    seen = continuous.ContinuousEngine._seen_ready
+
+    def seen_ready(self, since=None):
+        asked.append((self._chunk_step, self._ready_poll is not None))
+        if self._ready_poll is not None:
+            # what the device would be by now on any machine
+            self._ready_poll.__self__.block_until_ready()
+        seen(self, since)
+
+    ce._seen_ready = types.MethodType(seen_ready, ce)
+    ce.submit([1, 2, 3], max_new_tokens=12, seed=1,
+              stream_cb=lambda tok: time.sleep(NAP))
+    ce.run_until_idle()
+    recs = ce.recorder.records()
+    assert len(recs) == 3
+    for r in recs[1:]:
+        # ready at the first entry's end: the second entry's three naps
+        assert r["late_stream_ms"] >= 3 * NAP * 1e3
+        assert r["late_stream_ms"] <= r["late_max_ms"] <= r["wait_ms"]
+        assert r["late_stream_ms"] <= r["stream_ms"]
+        # asked once with a result to ask, then at each boundary for nothing
+        assert [a for c, a in asked if c == r["step"]] == [True, False, False]
+    assert ce._ready_poll is None
+    ce.close()
+
+
+def test_a_callback_that_raises_in_the_wait_leaves_no_hold_on_the_result(
+        tiny_engine):
+    """A stream callback that raises inside a chunk's in-flight stage ends
+    that ``step_chunk``: the driver keeps no ``is_ready`` of the result it
+    left in flight, and the chunks that follow stamp as ever."""
+    ce = _cont(tiny_engine)
+    got: list = []
+
+    def cb(tok):
+        got.append(tok)
+        if len(got) == 2:  # the second chunk's stage, its result in flight
+            raise RuntimeError("reader gone")
+
+    ce.submit([1, 2, 3], max_new_tokens=12, seed=1, stream_cb=cb)
+    ce.step_chunk()
+    with pytest.raises(RuntimeError, match="reader gone"):
+        ce.step_chunk()
+    assert ce._ready_poll is None
+    ce.run_until_idle()
+    assert ce._ready_poll is None
+    for r in ce.recorder.records():
+        assert r["late_stream_ms"] + r["late_intake_ms"] <= r["late_max_ms"]
+    ce.close()

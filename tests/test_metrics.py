@@ -279,6 +279,9 @@ LEGACY_ENGINE_KEYS = (
     # intake ahead: requests submitted / of those inside a chunk's wait,
     # admissions an ahead round prepared, the intake's host microseconds
     "submitted", "submitted_ahead", "admitted_ahead", "chunk_us_intake",
+    # the inside of a chunk's wait: what of its stream stage and of its
+    # intake lay behind the driver's first sight of the result ready
+    "chunk_us_late_stream", "chunk_us_late_intake",
 )
 PHASES = ("between", "admit", "pack", "dispatch", "wait", "drain",
           "deliver", "post")
@@ -348,17 +351,30 @@ def test_chunk_phase_counters_grow_by_the_records_values(tiny_engine):
     ce.run_until_idle()
     s1, recs = ce.stats, ce.recorder.records()[n0:]
     assert len(recs) >= 2
-    for ph in PHASES:
+    for ph in PHASES + ("late_stream", "late_intake"):
         grew = s1[f"chunk_us_{ph}"] - s0[f"chunk_us_{ph}"]
         assert isinstance(grew, int)
         assert grew == round(sum(r[f"{ph}_ms"] for r in recs) * 1e3), ph
     assert s1["chunk_us_wait"] > s0["chunk_us_wait"]
+    # the inside of wait: the fetch is marked like a phase, and with the
+    # stream stage before it lies inside wait (the last chunk's record
+    # also holds the stage that follows it with no step in flight)
+    for r in recs:
+        assert 0.0 < r["fetch_ms"] <= r["wait_ms"]
+        assert 0.0 <= r["late_stream_ms"] <= r["stream_ms"] + 0.1
+        late = r["late_stream_ms"] + r["late_intake_ms"]
+        assert late <= r["late_max_ms"]  # the lower bound and the upper
+        assert r["late_max_ms"] + r["fetch_ms"] <= r["wait_ms"] + 2e-3
+    for r in recs[:-1]:
+        assert r["fetch_ms"] + r["stream_ms"] <= r["wait_ms"] + 1e-3
     fams = parse_exposition(ce.metrics.render({"model": "tiny"}))
-    sample = fams["tlink_engine_chunk_us_wait_total"]["samples"][0]
-    assert float(sample.rsplit(" ", 1)[1]) == s1["chunk_us_wait"]
+    for ph in ("wait", "late_stream", "late_intake"):
+        sample = fams[f"tlink_engine_chunk_us_{ph}_total"]["samples"][0]
+        assert float(sample.rsplit(" ", 1)[1]) == s1[f"chunk_us_{ph}"]
     assert "tlink_engine_ragged_rows_computed_total" in fams
     snap = ce.serving_snapshot()
-    assert snap["chunk_us_deliver"] == s1["chunk_us_deliver"]
+    for ph in ("deliver", "late_stream", "late_intake"):
+        assert snap[f"chunk_us_{ph}"] == s1[f"chunk_us_{ph}"]
     assert "host_gap_ms" not in snap
     ce.close()
 

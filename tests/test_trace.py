@@ -501,9 +501,17 @@ def test_profiler_trace_holds_chunks_joined_to_records_by_id(
         inside = [e for e in events
                   if e[0] != "tlink:chunk" and a <= e[1] and e[2] <= b]
         streams = [e for e in inside if e[0] == "tlink:stream"]
-        inside = [e for e in inside if e[0] != "tlink:stream"]
+        fetches = [e for e in inside if e[0] == "tlink:fetch"]
+        inside = [e for e in inside
+                  if e[0] not in ("tlink:stream", "tlink:fetch")]
         assert [e[0] for e in inside] == [f"tlink:{p}" for p in PHASES]
         assert all(x[2] <= y[1] for x, y in zip(inside, inside[1:]))
+        # the fetch is wait's last sub-span, behind the stream stage
+        (wait,) = [e for e in inside if e[0] == "tlink:wait"]
+        ((_n, fa, fb, _s),) = fetches
+        assert wait[1] <= fa and fb <= wait[2]
+        assert all(sb <= fa for _n, sa, sb, _s in streams if sa >= wait[1]
+                   and sb <= wait[2])
         # the stream stage is a sub-span: of wait while a step is in
         # flight, of deliver when none follows
         for _n, sa, sb, _s in streams:
@@ -953,6 +961,7 @@ def test_a_request_behind_a_running_chunk_waits_for_it_and_names_it(
     ``work_wait``, outside the engine's ``queue_wait``, for what is left
     of them: the chunk's own wait takes it in (the worker's intake) and
     prepares its admission, and the span names that chunk's record. One
+    that arrives when the wait has begun is taken there at once. One
     that arrives too late for the intake is taken when ``step_chunk`` has
     returned, and names the chunk it waited out."""
     import threading
@@ -991,14 +1000,23 @@ def test_a_request_behind_a_running_chunk_waits_for_it_and_names_it(
     rec_end = rec["t0"] + sum(
         rec[f"{p}_ms"] for p in CHUNK_PHASES) / 1e3
     wait_end = wait["t0"] + wait["dur_ms"] / 1e3
-    if wait_end < rec_end:
+    until_wait = rec["t0"] + sum(
+        rec[f"{p}_ms"] for p in ("admit", "pack", "dispatch")) / 1e3
+    if wait_end < rec_end and wait["t0"] < until_wait:
         # taken in by that chunk's wait: it was put on the queue during
         # the chunk's pack (a quarter of a second) and waited that out
-        until_wait = rec["t0"] + sum(
-            rec[f"{p}_ms"] for p in ("admit", "pack", "dispatch")) / 1e3
         assert until_wait - 1e-3 <= wait_end
         left_ms = (until_wait - max(wait["t0"], rec["t0"])) * 1e3
         assert wait["dur_ms"] >= left_ms - 1.0 and left_ms > 0.0
+        assert rec["intake_ms"] > 0.0
+        assert by["admission"]["ahead"] is True
+    elif wait_end < rec_end:
+        # ... or it came when that chunk's wait had begun (on a loaded CPU
+        # the step outlasts the pack): the intake took it where it arrived,
+        # inside the wait, behind no pack and at most a stream stage
+        in_wait_end = until_wait + (rec["wait_ms"] - rec["fetch_ms"]) / 1e3
+        assert wait_end <= in_wait_end + 1e-3  # before the fetch
+        assert wait["dur_ms"] < 250.0
         assert rec["intake_ms"] > 0.0
         assert by["admission"]["ahead"] is True
     else:
