@@ -422,6 +422,12 @@ _ENGINE_COUNTERS = (
      "KV pages the attention passes of a chunk walk (contexts as packed)"),
     ("attn_pages_capacity", "tlink_engine_attn_pages_capacity_total",
      "page slots of those passes (slots x pages per slot)"),
+    # the walk's two heights (ops/attention.py::_short_positions): a slot
+    # with ONE row in the ragged pass walks a row block of one position
+    ("ragged_slots_live", "tlink_engine_ragged_slots_live_total",
+     "slots with a row in a chunk's ragged pass"),
+    ("ragged_slots_single", "tlink_engine_ragged_slots_single_total",
+     "of those, slots with exactly one row (the walk's short row block)"),
     # the tensor-parallel step's activation gathers (docs/SHARDING.md):
     # from the shapes the host packed, per dispatched chunk; 0 at tp=1
     ("tp_gather_bytes", "tlink_engine_tp_gather_bytes_total",
@@ -4382,6 +4388,9 @@ class ContinuousEngine:
                         "attn_pages_capacity",
                         n_exec * blk.shape[0] * self.cache.pages_per_slot,
                     )
+                    self._count("ragged_slots_live", int((n_valid > 0).sum()))
+                    self._count(
+                        "ragged_slots_single", int((n_valid == 1).sum()))
                     # the epilogue's work follows the slots: the walk is
                     # as long as the longest emitting draft, and every
                     # call sorts only if some packed slot samples

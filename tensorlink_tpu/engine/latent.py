@@ -728,7 +728,7 @@ def _gqa_attend(q, kp, vp, li, bt, ga: GqaAttn, ctx: _Ctx, name: str):
     under the table ``bt`` through the page walk, from the window's first
     key on where the kind has one: a continuation step as one query a
     slot, the ragged pass as every slot's block in one call (a decode row
-    in it costs a row block); ``[S, T, H, hd]``."""
+    in it walks the short row block: one position); ``[S, T, H, hd]``."""
     kw = dict(scale=ga.softmax_scale, window=ga.window)
     kernel = ctx.kernel and kp.dtype == q.dtype
 

@@ -499,6 +499,21 @@ def _positions_per_row_block(C: int, G: int, rows: int = _MIN_TILE) -> int:
     return max(within) if within else min(whole, default=C)
 
 
+def _short_positions(cb: int) -> int:
+    """Chunk positions of the walk's SHORT row block, the height a slot
+    with no more live positions walks at in place of its first tall block
+    (``cb`` positions): ONE position, ``G`` rows, a continuation step's
+    own block. It starts at row 0 of the slot's query block, a static
+    slice, so no tile edge binds it ("any height will do"), and Mosaic
+    compiles it for every group size the presets have (1, 4, 6, 7, 9, 64,
+    128: tests/test_chip_compile.py; were one refused, the fallback
+    would be the smallest whole-tile height, ``min(whole)`` of
+    :func:`_positions_per_row_block`). 0 where the tall block is one
+    position already (every continuation step: ``C`` = 1): such a call
+    traces no second body and stays the program it was."""
+    return 1 if cb > 1 else 0
+
+
 def _heads_per_block(
     Hkv: int, CG: int, R: int, T: int, hd: int, q_itemsize: int,
     kv_row_bytes: int,
@@ -607,11 +622,25 @@ def _paged_walk_kernel(
     v_width: int | None = None,
     row_groups: bool = False,
     per_head: bool = False,
+    cb_s: int = 0,
 ):
     """One slot and one block of ``hb`` kv heads of the walk (grid
     ``(slot, head block)``): row blocks of ``cb`` chunk positions up to
     the slot's last valid query, each against KV blocks of ``ppb`` pages
     up to its own causal limit, double-buffered.
+
+    ``cb_s`` (:func:`_short_positions`): a row block is as tall as the
+    slot's live rows need. A slot with at most ``cb_s`` valid positions (a
+    decode row in a packed block) walks its one row block ``cb_s·G`` rows
+    tall: the first rows of its query block and of the scratch, the same
+    KV blocks, copies and mask, so that a KV block costs it the scores
+    and weights of ``G`` rows and not of ``cb·G`` (64 to 144, 512 in a
+    latent walk). The rows behind are zero-filled. A scalar branch on
+    ``nv`` around the WHOLE walk: every other slot walks the code it
+    walked before, and a row's scores, softmax and output do not depend
+    on the rows beside it. (A branch around each KV block's arithmetic
+    alone would trace the copies once, but it cost a full block 3-30% on
+    the chip: PERF.md section 6, PR 61.) 0: one body (``C`` = 1).
 
     ``row_groups``: the grid has a third axis over groups of whole row
     blocks, and this step holds one group's query rows (a slot's rows do
@@ -718,11 +747,13 @@ def _paged_walk_kernel(
             return slice(None)
         return pl.ds(pl.multiple_of(lb * R, R), R)
 
-    def row_block(lb, carry):
+    def row_block(lb, carry, q_row=q_row, k_col=k_col, m_ref=m_ref,
+                  l_ref=l_ref, acc_ref=acc_ref, rows_of=rows_of, rb0=rb0):
+        # the tall block's by default; the short one's: walk_short
         rb = lb if rb0 is None else rb0 + lb
         _, kv_len, n_pages, n_kb = trips(rb)
         rows = rows_of(lb)
-        q = q_ref[0, :, rows, :]  # [Hkv, R, hd]
+        q = q_ref[0, :, rows, :]  # [Hkv, rows, hd]
         if not native_dot:
             q = q.astype(jnp.float32) * scale
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -812,11 +843,34 @@ def _paged_walk_kernel(
         o_ref[0, :, rows_of(lb), :] = jnp.zeros((Hkv, R, vw), o_ref.dtype)
         return carry
 
-    n_rb = trips(0)[0]
-    if rb0 is not None:  # the slot's live row blocks that lie in this group
-        n_rb = jnp.clip(n_rb - rb0, 0, CG // R)
-    jax.lax.fori_loop(0, n_rb, row_block, 0)
-    jax.lax.fori_loop(n_rb, CG // R, dead_row_block, 0)
+    def walk_tall():
+        n_rb = trips(0)[0]
+        if rb0 is not None:  # the slot's live row blocks in this group
+            n_rb = jnp.clip(n_rb - rb0, 0, CG // R)
+        jax.lax.fori_loop(0, n_rb, row_block, 0)
+        jax.lax.fori_loop(n_rb, CG // R, dead_row_block, 0)
+
+    if not cb_s:  # the query block is one position: nothing is shorter
+        return walk_tall()
+
+    def walk_short():
+        # row block 0 at the short height (its trips, its window start
+        # and its mask are the tall block's: none depends on the height
+        # while nv <= cb_s), over a zero-filled output block
+        jax.lax.fori_loop(0, CG // R, dead_row_block, 0)
+        h = cb_s * G
+        own = (slice(None), slice(0, h))  # the scratch's first h rows
+        row_block(
+            0, 0, q_row=jax.lax.broadcasted_iota(jnp.int32, (h, T), 0),
+            k_col=jax.lax.broadcasted_iota(jnp.int32, (h, T), 1),
+            m_ref=m_ref.at[own], l_ref=l_ref.at[own],
+            acc_ref=acc_ref.at[own], rows_of=lambda lb: slice(0, h),
+            rb0=None)
+
+    short = (nv > 0) & (nv <= cb_s)
+    if row_groups:  # the slot's other groups hold no live row
+        short &= pl.program_id(2) == 0
+    jax.lax.cond(short, walk_short, walk_tall)
 
 
 def _paged_walk(
@@ -886,9 +940,11 @@ def _paged_walk(
     if CG % QR:
         raise ValueError(f"{CG} query rows in groups of {QR}")
     quantized = k_scale is not None
+    cb_s = _short_positions(cb)
     kernel = functools.partial(
         _paged_walk_kernel, scale=scale, page=page, ppb=ppb, G=G, cb=cb,
         quantized=quantized, packed=quantized and hdk * 2 == hd,
+        **({"cb_s": cb_s} if cb_s else {}),
         **({"window": window} if window is not None else {}),
         **({"shared_kv": True} if shared_kv else {}),
         **({"v_width": vw} if latent else {}),
@@ -1001,11 +1057,12 @@ def ragged_paged_attention(
     to ``n_valid`` and each walks KV blocks of whole pages (8 pages of
     16: a 128-wide score tile, the block's kv heads in one batched
     matmul) up to its own causal limit, the next block's page copies in flight under this
-    block's arithmetic. So a decode row in the block costs a decode row,
-    a mid-prefill slot its chunk against its context, a padding slot
-    its zero output. ONE compiled program serves every (prefill/decode
-    mix, offset, length, page assignment) — slot roles are data, not
-    shape. Speculative verify slots (k+1 valid rows at a decode slot's
+    block's arithmetic. A slot with ONE valid position walks a row block
+    of that one position (``G`` rows: :func:`_short_positions`). So a
+    decode row in the block costs a decode row, a mid-prefill slot its
+    chunk against its context, a padding slot its zero output. ONE
+    compiled program serves every (prefill/decode mix, offset, length,
+    page assignment) — slot roles are data, not shape. Speculative verify slots (k+1 valid rows at a decode slot's
     current start) ride the same causal ``q_pos`` masking — see the
     reference's "Verify mode" note."""
     S, C, Hq, hd = q.shape

@@ -17,6 +17,7 @@ compile for a described device is written to it but cannot be read back
 without the chip — it would warn and compile again.)
 """
 
+import functools
 import os
 
 import jax
@@ -166,6 +167,93 @@ def test_walk_compiles_at_other_head_shapes(v5e, heads, kernel, mode):
             A.ragged_paged_attention, _q(v5e, S, 128, hq, hd), kv, kv,
             _i32(v5e, S, N_PP), _i32(v5e, S), _i32(v5e, S), **scales,
         )
+
+
+def _bf16_pool(dev, pages, hkv, width, layers=4):
+    return jax.ShapeDtypeStruct(
+        (layers, pages, hkv, PAGE, width), jnp.bfloat16, sharding=dev)
+
+
+# the ragged pass's walk of each cell, as its engine calls it: (slots,
+# pages a slot, C, Hq, Hkv, row width, pools, further keywords)
+_CELL_WALKS = (
+    ("qwen3-4b-int8-C16", (8, 256, 16, 32, 8, 128, "int8", {})),
+    ("qwen3-4b-int8-C128", (8, 256, 128, 32, 8, 128, "int8", {})),
+    ("olmo-30-heads-G1", (8, 512, 128, 30, 30, 128, "kv", {})),
+    # a head's 64 keys beside its 64 values in one row: one pool
+    ("lfm2-heads-of-64", (16, 1024, 128, 32, 8, 128, "k", {})),
+    ("laguna-G6", (16, 1024, 128, 48, 8, 128, "kv", {})),
+    ("laguna-window-G9", (16, 41, 128, 72, 8, 128, "kv", {"window": 512})),
+    # one slot a call, 128 heads on one latent row (engine/latent.py)
+    ("latent-128-heads", (1, 1024, 128, 128, 1, 576, "k", {
+        "latent": (512, 512, 512, 2048)})),
+)
+
+
+@pytest.mark.parametrize(
+    "walk", [w for _, w in _CELL_WALKS], ids=[n for n, _ in _CELL_WALKS])
+def test_two_height_walk_compiles_at_the_cells_shapes(v5e, walk):
+    """The ragged pass's walk at each cell's published heads, slots and
+    context: the kernel that holds a tall and a short row block behind a
+    scalar branch (``G`` = 1, 4, 6, 9, 128 rows in the short one)
+    compiles for the described v5e, and its trace holds both bodies
+    (four matrix products)."""
+    slots, n_pp, C, hq, hkv, width, pool, kw = walk
+    pages = 1 + slots * n_pp
+    scales = {"layer": _i32(v5e)}
+    if pool == "int8":  # qwen3-4b's 36 layers of int8 pages and scales
+        k = v = jax.ShapeDtypeStruct(
+            (36, pages, hkv, PAGE, width), jnp.int8, sharding=v5e)
+        scales["k_scale"] = scales["v_scale"] = jax.ShapeDtypeStruct(
+            (36, pages, hkv, PAGE), jnp.float32, sharding=v5e)
+    else:
+        k = _bf16_pool(v5e, pages, hkv, width)
+        v = k if pool == "kv" else None
+    args = (_q(v5e, slots, C, hq, width), k, v, _i32(v5e, slots, n_pp),
+            _i32(v5e, slots), _i32(v5e, slots))
+    _compiles_with_kernel(A.ragged_paged_attention, *args, **scales, **kw)
+    traced = jax.make_jaxpr(functools.partial(
+        A.ragged_paged_attention, scale=SCALE, **kw))(*args, **scales)
+    assert str(traced).count("dot_general") == 4
+
+
+@pytest.mark.parametrize(
+    "call", ["decode-int8", "decode-G1", "latent-first-rows", "window",
+             "block-sparse"])
+def test_a_one_position_call_traces_one_body(v5e, call):
+    """Where the query block is one position (every continuation step,
+    the latent walk's first rows, the block-sparse walk: ``per_head`` is
+    always such a call, so that variant stays on the tall path) nothing
+    is shorter: the kernel's trace holds ONE body (two matrix products,
+    no branch on the slot's count) and compiles. (PR 61's builder
+    compared these calls' lowered and compiled texts with the parent's,
+    locations off as the benchmark runs them: the same, byte for
+    byte.)"""
+    bt, n = _i32(v5e, S, N_PP), _i32(v5e, S)
+    if call == "decode-int8":
+        kv, kw = _pages(v5e, "int8", 8, layers=36)
+        fn, args = A.paged_attention, (_q(v5e, S, 32, HD), kv, kv, bt, n)
+    elif call == "decode-G1":
+        kv = _bf16_pool(v5e, P, 30, HD)
+        fn, args = A.paged_attention, (_q(v5e, S, 30, HD), kv, kv, bt, n)
+        kw = {"layer": _i32(v5e)}
+    elif call == "latent-first-rows":
+        fn, args = A.paged_attention, (
+            _q(v5e, S, 128, 576), _bf16_pool(v5e, P, 1, 576), None, bt, n)
+        kw = {"layer": _i32(v5e), "latent": (512, 512, 512, 2048)}
+    elif call == "window":
+        fn, args = A.paged_attention, (
+            _q(v5e, S, 64, 576), _bf16_pool(v5e, P, 1, 576), None, bt, n)
+        kw = {"layer": _i32(v5e), "window": 513}
+    else:
+        kv = _bf16_pool(v5e, P, 2, HD)
+        fn, args = A.block_sparse_attention, (
+            _q(v5e, S, 32, HD), kv, kv, _i32(v5e, S, 2, 64), _i32(v5e, S, 2))
+        kw = {"layer": _i32(v5e)}
+    _compiles_with_kernel(fn, *args, **kw)
+    traced = str(jax.make_jaxpr(
+        functools.partial(fn, scale=SCALE, **kw))(*args))
+    assert traced.count("dot_general") == 2
 
 
 def test_flash_kernel_compiles_for_v5e(v5e):

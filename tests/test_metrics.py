@@ -236,6 +236,9 @@ LEGACY_ENGINE_KEYS = (
     # the paged kernels' live-span walk (ROADMAP S7): pages walked /
     # page slots of the same passes
     "attn_pages_live", "attn_pages_capacity",
+    # the walk's two heights: slots with a row in the ragged pass / those
+    # with exactly one (they walk the short row block)
+    "ragged_slots_live", "ragged_slots_single",
     # the tensor-parallel step's activation gathers (0 at tp = 1)
     "tp_gather_bytes", "tp_gather_calls",
     # a patterned model's step (engine/latent.py): routing and selection
@@ -412,6 +415,35 @@ def test_page_counters_follow_the_slot_contexts(tiny_engine):
     assert ce.stats["attn_pages_capacity"] == 6 * S * n_pp
     fams = parse_exposition(ce.metrics.render({"model": "tiny"}))
     assert "tlink_engine_attn_pages_live_total" in fams
+    ce.run_until_idle()
+    ce.close()
+
+
+def test_slot_counters_tell_single_rows_from_grants(tiny_engine):
+    """How often the walk's short row block engages: a chunk adds the
+    slots with a row in its ragged pass to ``ragged_slots_live`` and
+    those with exactly ONE to ``ragged_slots_single`` (from the
+    ``n_valid`` the host packed): seven decoding slots beside one grant
+    read 7 of 8."""
+    from tensorlink_tpu.engine.continuous import ContinuousEngine
+
+    ce = ContinuousEngine(
+        tiny_engine, max_slots=8, page_size=8, chunk_steps=2,
+        prefill_chunk=16,
+    )
+    for i in range(7):
+        ce.submit([3 + i, 4, 5], max_new_tokens=12, seed=i)
+    ce.step_chunk()  # seven prompts of three rows each: none single
+    s = dict(ce.stats)
+    assert (s["ragged_slots_live"], s["ragged_slots_single"]) == (7, 0)
+    ce.submit(list(range(1, 11)), max_new_tokens=4, seed=9)
+    ce.step_chunk()  # seven decode rows and a grant of ten
+    d = {k: ce.stats[k] - s[k] for k in s}
+    assert (d["ragged_slots_live"], d["ragged_slots_single"]) == (8, 7)
+    ce.step_chunk(admit_only=True)  # dispatches nothing: counts nothing
+    assert ce.stats["ragged_slots_live"] == 15
+    fams = parse_exposition(ce.metrics.render({"model": "tiny"}))
+    assert "tlink_engine_ragged_slots_single_total" in fams
     ce.run_until_idle()
     ce.close()
 

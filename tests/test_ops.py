@@ -1307,3 +1307,98 @@ def test_latent_walk_at_128_heads_blocks_of_rows(C, starts, nv):
                                rtol=2e-5, atol=2e-5)
     for s in range(S):
         assert np.abs(np.asarray(out[s, nv[s]:])).max(initial=0) == 0
+
+
+def test_short_row_block_is_one_position_where_the_tall_one_is_more():
+    """The walk's second height (:func:`_short_positions`) from the tall
+    one's positions: one position (``G`` rows, a continuation step's
+    block) wherever the tall block holds more, none where the query
+    block is one position already (a ``C`` = 1 call keeps one body)."""
+    from tensorlink_tpu.ops.attention import (
+        _positions_per_row_block, _short_positions)
+
+    def short_rows(C, G, rows=128):
+        return _short_positions(_positions_per_row_block(C, G, rows)) * G
+
+    # (C, G) of the cells' ragged passes: 128, 64, 128, 96, 112, 144 rows
+    # of the tall block come down to G
+    for C, G in ((128, 4), (16, 4), (128, 1), (128, 6), (128, 7), (128, 9)):
+        assert short_rows(C, G) == G, (C, G)
+    assert short_rows(16, 128, rows=512) == 128  # a latent walk: 512 -> 128
+    # a continuation step, whatever its group: nothing is shorter
+    for G in (1, 4, 6, 7, 9, 64, 128):
+        assert short_rows(1, G) == 0 and short_rows(1, G, rows=512) == 0
+
+
+@pytest.mark.parametrize(
+    "G,C,kind",
+    [(G, C, "plain") for G in (1, 4, 6, 7, 9) for C in (16, 128)]
+    + [(4, 16, "int8"), (4, 128, "int8"), (4, 16, "window"),
+       (9, 128, "window"), (128, 8, "latent"), (128, 8, "latent-groups")],
+)
+def test_walk_height_follows_a_slots_live_rows(monkeypatch, G, C, kind):
+    """A slot with one live position walks a row block ``G`` rows tall, a
+    slot with more the tall block, in one call: against the references
+    at this file's tolerances, zero in every row past a slot's live rows,
+    and against the one-height kernel (the same call with
+    ``_short_positions`` answering 0): bit for bit in every slot that
+    walks tall, and in the short ones as far as this backend can show
+    (XLA's CPU dot orders a contraction by the operand's height, so a
+    row of ``G`` differs from the same row of 128 in the last bit or two;
+    the MXU does not, and there it IS bit for bit: PERF.md section 5,
+    PR 61). Over the cells' group sizes, both widths of the ladder, int8
+    pages, a window, and a latent cache's walk with and without row
+    groups."""
+    from tensorlink_tpu.ops import attention as A
+
+    rng = np.random.default_rng(61)
+    # slots of one packed block: padding, a decode row deep in its context,
+    # a decode row at position 0, two verify rows, a full prefill block
+    starts, nv = [0, 150, 0, 37, 9], [0, 1, 1, 2, C]
+    S, page, n_pp, Hkv = len(nv), 64, 3, 1 if G > 4 else 2
+    P = 1 + S * n_pp
+    bt = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(S, n_pp)
+                     .astype(np.int32))
+    st, nvj = jnp.asarray(starts, jnp.int32), jnp.asarray(nv, jnp.int32)
+    kw, ref_kw = {}, {}
+    if kind.startswith("latent"):
+        # v_width, KV tile, rows a row block (two positions), rows a grid
+        # step: all of a slot's, or four positions a group
+        hd, scale = 256, 0.07
+        kw["latent"] = (128, 64, 256, C * G if kind == "latent" else 512)
+        kp = jnp.asarray(rng.normal(size=(2, P, 1, page, hd)), jnp.float32)
+        kw["layer"] = jnp.int32(1)  # a latent walk reads a stacked pool
+        vp, ref_k, ref_v = None, kp[1], kp[1]
+    elif kind == "int8":
+        hd, scale = 32, 32**-0.5
+        _, _, kp, ks, vp, vs = _quantized_pages(rng, P, Hkv, page, hd)
+        kw.update(k_scale=ks, v_scale=vs)
+        ref_kw, ref_k, ref_v = dict(kw), kp, vp
+    else:
+        hd, scale = 32, 32**-0.5
+        kp = jnp.asarray(rng.normal(size=(P, Hkv, page, hd)), jnp.float32)
+        ref_k = kp
+        vp = ref_v = jnp.asarray(
+            rng.normal(size=(P, Hkv, page, hd)), jnp.float32)
+        if kind == "window":
+            kw["window"] = ref_kw["window"] = 33
+    q = jnp.asarray(rng.normal(size=(S, C, Hkv * G, hd)), jnp.float32)
+    ref = A.ragged_paged_attention_ref(
+        q, ref_k, ref_v, bt, st, nvj, scale=scale, **ref_kw)
+    if "latent" in kw:
+        ref = ref[..., :kw["latent"][0]]
+    got = np.asarray(A.ragged_paged_attention(
+        q, kp, vp, bt, st, nvj, scale=scale, interpret=True, **kw))
+    np.testing.assert_allclose(got, np.asarray(ref), rtol=2e-5, atol=2e-5)
+    for s in range(S):
+        assert np.abs(got[s, nv[s]:]).max(initial=0) == 0
+    # a new name is a new trace: the kernel with the tall height alone
+    monkeypatch.setattr(A, "_short_positions", lambda cb: 0)
+    tall = np.asarray(A.ragged_paged_attention(
+        q, kp, vp, bt, st, nvj, scale=scale, interpret=True,
+        name="one_height", **kw))
+    for s in range(S):
+        if nv[s] == 1:
+            np.testing.assert_allclose(got[s], tall[s], rtol=1e-6, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(got[s], tall[s])
